@@ -111,7 +111,7 @@ DEFAULT_CFG: Dict[str, Any] = {
     # which keeps the client-vmapped hot path on dense MXU ops (ops/layers.py)
     "conv_impl": None,
     # lax.scan unroll factor for the local-step loop (1 = no unrolling);
-    # latency-bound rounds can gain from fewer loop trips, A/B in tpu_ab.py
+    # latency-bound rounds can gain from fewer loop trips (not measured)
     "scan_unroll": 1,
     # fused masked-SGD optimizer epilogue + flat scan carry
     # (ops/fused_update.py): collapse the per-step grad normalise/mask/clip/

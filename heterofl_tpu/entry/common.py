@@ -244,15 +244,9 @@ class FedExperiment:
         # dimension); 0/absent keeps the (clients, data) mesh and the
         # vmap arms placement
         n_arms_axis = max(1, int(cfg["mesh"].get("arms", 1) or 1))
-        try:
-            self.mesh = make_mesh(n_clients, n_data, n_arms=n_arms_axis)
-        except (ValueError, AssertionError):
-            if n_arms_axis > 1:
-                # an explicit arms mesh axis must not silently degrade to
-                # the vmap placement -- the user asked for one arm per
-                # device-row group, and the device count cannot honor it
-                raise
-            self.mesh = make_mesh(len(jax.devices()), 1)
+        # a cfg['mesh'] the devices cannot honour raises here; only
+        # clients == 0 means "use all devices"
+        self.mesh = make_mesh(n_clients, n_data, n_arms=n_arms_axis)
         self.engine = RoundEngine(self.model, cfg, self.mesh)
         self.evaluator = Evaluator(self.model, cfg, self.mesh, seed=seed)
         self.scheduler = make_scheduler(cfg)
@@ -278,6 +272,7 @@ class FedExperiment:
         self._ss_fetched = 0     # ... and its fetched-state twin
         self._round_times: List[float] = []  # steady-state round durations (ETA)
         self._first_round_done = False
+        self._first_round_time = None  # the compile-bearing first dispatch
         # staging/dispatch telemetry + async metric fetch (parallel/staging.py):
         # per-round metric sums stay on device and are drained every
         # cfg['metrics_fetch_every'] rounds (eval boundaries flush)
@@ -756,6 +751,7 @@ class FedExperiment:
             self._round_times.append(dt)
         else:
             self._first_round_done = True  # exclude the compile round
+            self._first_round_time = dt
         for tag0, ms_host in due:
             self._log_train_round(logger, tag0["epoch"], tag0["lr"], tag0["dt"],
                                   tag0["phases"], ms_host,
@@ -1019,6 +1015,7 @@ class FedExperiment:
             self._round_times.extend([dt / k] * k)
         else:
             self._first_round_done = True  # exclude the compile superstep
+            self._first_round_time = dt
         for tag0, out in due:
             self._log_superstep(logger, tag0, out)
         return params
@@ -1496,7 +1493,9 @@ class FedExperiment:
                 params, epoch, pivot = self._recover_rollback(
                     logger, trip, pivot_mode)
         return {"params": params, "bn_state": getattr(self, "bn_state", {}),
-                "logger": logger, "data_split": data_split, "label_split": label_split}
+                "logger": logger, "data_split": data_split, "label_split": label_split,
+                "first_round_time": self._first_round_time,
+                "round_times": list(self._round_times)}
 
     def _run_iteration(self, logger, pivot_metric, pivot_mode, pivot, epoch,
                        n_rounds, eval_interval, data_split, label_split,

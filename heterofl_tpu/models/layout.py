@@ -18,7 +18,7 @@ conventions an explicit, enforceable policy:
   moment vectors ((C,) trailing) ride the same rule.  ``check_policy``
   audits a model's spec table against it.
 * **Pinned program-entry layouts**: ``param_formats`` emits per-leaf
-  ``jax.experimental.layout.Layout`` objects (row-major major-to-minor --
+  ``jax.experimental.layout.Format`` objects (row-major major-to-minor --
   the policy above makes row-major the compute layout) and ``pin_params``
   commits a params tree with them, so the jitted round/superstep programs
   specialise on exactly that layout and the scan carry is never re-laid
@@ -67,23 +67,23 @@ def check_policy(specs: Dict[str, Any],
 
 
 def param_formats(params, mesh=None, spec=None):
-    """Per-leaf pinned-layout ``Layout`` objects for a params tree: the
+    """Per-leaf pinned-layout ``Format`` objects for a params tree: the
     policy's row-major major-to-minor order (identity permutation), with
     the mesh's replicated sharding attached when given.
 
     Row-major IS the policy: :func:`check_policy` guarantees the lane axis
     is already trailing, so pinning row-major pins lanes."""
-    from jax.experimental.layout import DeviceLocalLayout, Layout
+    from jax.experimental.layout import Format, Layout
     from jax.sharding import (NamedSharding, PartitionSpec as P,
                               SingleDeviceSharding)
 
     if mesh is not None:
         sh = NamedSharding(mesh, P() if spec is None else spec)
-    else:  # Layout requires a concrete sharding alongside a concrete DLL
+    else:  # Format requires a concrete sharding alongside a concrete Layout
         sh = SingleDeviceSharding(jax.devices()[0])
 
     def one(a):
-        return Layout(DeviceLocalLayout(tuple(range(a.ndim))), sh)
+        return Format(Layout(major_to_minor=tuple(range(a.ndim))), sh)
 
     return jax.tree_util.tree_map(one, params)
 
@@ -112,7 +112,7 @@ class ParamPinner:
     """Per-engine layout pin with the Format tree cached.
 
     The formats are static per (param shapes, mesh), so rebuilding the
-    per-leaf Layout objects every dispatch would be per-round host work on
+    per-leaf Format objects every dispatch would be per-round host work on
     exactly the steady-state path the staging layer keeps free of per-call
     wraps; the engines construct ONE pinner and call it at their params
     commit.  Validates the policy at construction (loud config errors at

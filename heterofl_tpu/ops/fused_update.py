@@ -38,8 +38,11 @@ primitive over ONE flattened-tree buffer:
   the way the masked-vs-sliced engines do (float association level).
 * ``'pallas'`` (what ``True`` resolves to on TPU): a flattened-tree Pallas
   TPU kernel over the lane-packed ``[rows, 128]`` reshape -- phase 0
-  accumulates the global-norm sum of squares in VMEM scratch (the
+  accumulates the global-norm sum of squares in an SMEM scalar (the
   two-phase reduction), phase 1 is the single elementwise update pass.
+  The step's three scalars (denom, lr, has) ride in a ``(1, 3)`` SMEM
+  operand: Mosaic stores no scalar to VMEM, and the 2-D shape is what
+  lets ``vmap`` over client slots window it per slot.
   Elementwise bits match the reference chain exactly; the norm reduction
   is associated per block instead of per leaf, so when clipping actually
   engages the scale may differ in the last ulp (tests pin bit-identity in
@@ -159,7 +162,7 @@ def _fused_sgd_kernel(g_ref, p_ref, b_ref, m_ref, s_ref, p_out, b_out, acc,
 
     @pl.when(jnp.logical_and(phase == 0, i == 0))
     def _():
-        acc[:] = jnp.zeros_like(acc)
+        acc[0] = 0.0
 
     # block-padding rows may hold undefined VMEM: `where` them out, never
     # multiply (the pallas_norm.py lesson)
@@ -171,11 +174,11 @@ def _fused_sgd_kernel(g_ref, p_ref, b_ref, m_ref, s_ref, p_out, b_out, acc,
 
     @pl.when(phase == 0)
     def _():
-        acc[0, 0] += jnp.sum(gm * gm)
+        acc[0] += jnp.sum(gm * gm)
 
     @pl.when(phase == 1)
     def _():
-        total = jnp.sqrt(acc[0, 0])
+        total = jnp.sqrt(acc[0])
         scale = jnp.minimum(1.0, max_norm / (total + 1e-6))
         pv = jnp.where(rowmask, p_ref[:], 0.0)
         bv = jnp.where(rowmask, b_ref[:], 0.0)
@@ -213,7 +216,7 @@ def _pallas_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd,
             pl.BlockSpec((bm, LANE), lambda p, i: (i, 0)),
             pl.BlockSpec((bm, LANE), lambda p, i: (i, 0)),
             pl.BlockSpec((bm, LANE), lambda p, i: (i, 0)),
-            pl.BlockSpec((1, 3), lambda p, i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bm, LANE), lambda p, i: (i, 0)),
@@ -223,7 +226,7 @@ def _pallas_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd,
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
+        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
     )(pack(gf), pack(pf), pack(bf), pack(mf), scal)
     return p2.reshape(-1)[:spec.total], b2.reshape(-1)[:spec.total]

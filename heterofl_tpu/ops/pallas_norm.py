@@ -12,7 +12,7 @@ zeroes their output exactly like the XLA path.
 
 Opt-in via ``cfg['pallas_norm'] = True`` (see models/norms.py); the XLA path
 still serves running/collect modes and cross-device (sync-BN) reductions.
-Measured A/B vs the XLA op: scripts/tpu_ab.py.
+Not measured against the XLA op on the chip since PRs 1-18.
 """
 
 from __future__ import annotations

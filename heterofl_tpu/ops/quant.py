@@ -78,16 +78,25 @@ def _quant_pack_xla(x, scale, key, qmax: int, bias: int):
 
 def _quant_pack_kernel(x_ref, s_ref, u_ref, w_out, q_out, *, qmax: int,
                        bias: int):
-    # one elementwise pass: scale -> stochastic round -> clip -> bias ->
-    # 4-lane pack (the [bm, 128] block reshapes to [bm, 32, 4] word groups;
-    # flat order is preserved, so the packed words match pack_lanes exactly)
+    # one pass: scale -> stochastic round -> clip -> bias -> 4-lane pack.
+    # Word j of a row packs lanes 4j..4j+3 (flat order, so the words match
+    # pack_lanes exactly).  Mosaic has no [bm, 128] -> [bm, 32, 4] shape
+    # cast, so the lane de-interleave is four one-hot selection matmuls on
+    # the MXU: every operand is an integer below 2**8, exact at any matmul
+    # precision.
     q = jnp.clip(jnp.floor(x_ref[:] / s_ref[:] + u_ref[:]),
                  -qmax, qmax).astype(jnp.int32)
     q_out[:] = q
-    qb = (q + bias).reshape(q.shape[0], LANE // 4, 4)
-    w = qb[:, :, 0]
-    for i in range(1, 4):
-        w = jnp.bitwise_or(w, jnp.left_shift(qb[:, :, i], i * 8))
+    qb = (q + bias).astype(jnp.float32)
+    src = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE // 4), 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE // 4), 1)
+    w = None
+    for i in range(4):
+        sel = (src == 4 * word + i).astype(jnp.float32)
+        lane = jnp.dot(qb, sel,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+        w = lane if w is None else jnp.bitwise_or(
+            w, jnp.left_shift(lane, i * 8))
     w_out[:] = w
 
 

@@ -34,8 +34,9 @@ def make_mesh(n_clients: Optional[int] = None, n_data: int = 1,
     devices = list(devices if devices is not None else jax.devices())
     n_arms = max(1, int(n_arms))
     if n_clients is None:
-        assert len(devices) % (n_data * n_arms) == 0, \
-            "device count not divisible by data x arms axes"
+        if len(devices) % (n_data * n_arms):
+            raise ValueError(f"{len(devices)} devices not divisible by "
+                             f"data x arms axes ({n_data} x {n_arms})")
         n_clients = len(devices) // (n_data * n_arms)
     need = n_clients * n_data * n_arms
     if need > len(devices):

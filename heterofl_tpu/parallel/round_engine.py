@@ -23,18 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.7 new API
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
 from ..chaos import resolve_poison_cfg
 from ..compress import make_codec, resid_slots, resolve_codec_cfg
 from ..config import resolve_prefetch_depth
@@ -58,6 +46,11 @@ from ..models.spec import count_masks as make_count_masks, mask_params, param_ma
 from ..ops.augment import augment_cifar, normalize_image
 from ..ops.fused_update import FlatSpec, fused_sgd_flat, resolve_fused_mode
 from ..utils.optim import clip_by_global_norm, make_optimizer, make_traced_lr_fn
+
+
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _ceil_div(a: int, b: int) -> int:

@@ -246,7 +246,7 @@ class PlacementCache:
         ``device_put`` reuses the source buffer as a shard whenever the
         target mesh contains the source's device, so donating its output
         deletes the source array out from under the caller (measured on
-        jax 0.4.37 CPU; ``may_alias=False`` does not prevent it).  A jitted
+        XLA:CPU; ``may_alias=False`` does not prevent it).  A jitted
         ``x + 0`` with explicit ``out_shardings`` always materialises fresh
         buffers, and as a compiled program it dispatches asynchronously --
         the broadcast overlaps with other levels' work."""

@@ -14,6 +14,7 @@ from collections import Counter
 from typing import Any, Iterable, List, Set, Tuple
 
 import jax
+from jax.extend import core as jex_core
 
 #: primitive names that call back into the host (banned in round programs:
 #: one callback serialises the whole fused round on the host boundary)
@@ -28,21 +29,61 @@ def _sub_jaxprs(params: dict) -> Iterable[Any]:
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for item in vs:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jex_core.Jaxpr):
                 yield item
 
 
 def iter_eqns(jaxpr) -> Iterable[Any]:
     """Yield every eqn of ``jaxpr`` (a ``Jaxpr`` or ``ClosedJaxpr``),
     recursing into all sub-jaxprs."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
         for sub in _sub_jaxprs(eqn.params):
             yield from iter_eqns(sub)
+
+
+def _is_collective(name: str) -> bool:
+    return any(name == p or name.startswith(p + "_")
+               for p in COLLECTIVE_PRIMITIVES)
+
+
+def iter_collective_launches(jaxpr) -> Iterable[Tuple[Any, List[Any]]]:
+    """Yield ``(first eqn, operand vars)`` per collective LAUNCH, recursing
+    into all sub-jaxprs.
+
+    The installed jax binds a pytree collective -- ``lax.psum((sums,
+    counts), axis)`` -- as one eqn PER LEAF, back to back, and leaves the
+    joining to XLA's all-reduce combiner.  A launch is therefore a maximal
+    run of adjacent eqns of one jaxpr body with the same primitive and the
+    same params, none of which reads another member's output: what the
+    program asks to be reduced together.  A reduction that depends on an
+    earlier one, or is separated from it by any other op, is a second
+    launch."""
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
+        jaxpr = jaxpr.jaxpr
+    head, operands, outs = None, [], set()
+    for eqn in jaxpr.eqns:
+        joins = (head is not None and eqn.primitive is head.primitive
+                 and eqn.params == head.params
+                 and not any(isinstance(v, jex_core.Var) and v in outs
+                             for v in eqn.invars))
+        if head is not None and not joins:
+            yield head, operands
+            head, operands, outs = None, [], set()
+        if _is_collective(eqn.primitive.name):
+            if head is None:
+                head = eqn
+            operands.extend(eqn.invars)
+            outs.update(eqn.outvars)
+        else:
+            for sub in _sub_jaxprs(eqn.params):
+                yield from iter_collective_launches(sub)
+    if head is not None:
+        yield head, operands
 
 
 def provenance(eqn) -> str:
@@ -76,6 +117,7 @@ def random_bind_files(jaxpr, package_root: str) -> Set[str]:
     SALT_REGISTRY models -- randomness appearing in an unmodeled package
     file has no declared provenance."""
     import os
+    import re
 
     root = os.path.abspath(package_root)
     files: Set[str] = set()
@@ -83,8 +125,8 @@ def random_bind_files(jaxpr, package_root: str) -> Set[str]:
         name = eqn.primitive.name
         if not any(name.startswith(p) for p in RANDOM_PRIMITIVE_PREFIXES):
             continue
-        prov = provenance(eqn)  # "path:line (fn)"
-        path = os.path.abspath(prov.rsplit(":", 1)[0])
+        prov = provenance(eqn)  # "path:line:col (fn)"
+        path = os.path.abspath(re.sub(r"(:\d+)+( \(.*\))?$", "", prov))
         if path.startswith(root + os.sep):
             files.add(os.path.relpath(path, root).replace(os.sep, "/"))
     return files
@@ -135,31 +177,28 @@ def collective_axes(eqn) -> Tuple[str, ...]:
 
 
 def count_collectives(jaxpr) -> Tuple[Counter, Set[str]]:
-    """(per-primitive bind counts, all axis names seen).  A ``psum`` over
-    ``(sums, counts)`` is ONE bind -- the budget the engines are audited
-    against counts collective launches, not leaves."""
+    """(per-primitive launch counts, all axis names seen).  A ``psum`` over
+    ``(sums, counts)`` is ONE launch (:func:`iter_collective_launches`) --
+    the budget the engines are audited against counts launches, not
+    leaves."""
     counts: Counter = Counter()
     axes: Set[str] = set()
-    for eqn in iter_eqns(jaxpr):
-        name = eqn.primitive.name
-        if any(name == p or name.startswith(p + "_") for p in COLLECTIVE_PRIMITIVES):
-            counts[name] += 1
-            axes.update(collective_axes(eqn))
+    for eqn, _ in iter_collective_launches(jaxpr):
+        counts[eqn.primitive.name] += 1
+        axes.update(collective_axes(eqn))
     return counts, axes
 
 
 def count_psum_over(jaxpr, axis: str = "clients") -> int:
-    """psum binds whose axes include ``axis`` (the global-collective
+    """psum launches whose axes include ``axis`` (the global-collective
     budget; a data-axis psum inside intra-client DP is not a global one)."""
-    n = 0
-    for eqn in iter_eqns(jaxpr):
-        if eqn.primitive.name == "psum" and axis in collective_axes(eqn):
-            n += 1
-    return n
+    return sum(1 for eqn, _ in iter_collective_launches(jaxpr)
+               if eqn.primitive.name == "psum"
+               and axis in collective_axes(eqn))
 
 
 def collective_payload_rows(jaxpr) -> List[dict]:
-    """One priced row per collective bind: primitive, sorted axis names,
+    """One priced row per collective launch: primitive, sorted axis names,
     per-participant payload bytes (sum of operand aval bytes -- under
     ``shard_map`` the operands are per-device values, so this is exactly
     what each participant contributes to the wire), operand shapes/dtypes,
@@ -168,14 +207,11 @@ def collective_payload_rows(jaxpr) -> List[dict]:
     import numpy as np
 
     rows = []
-    for eqn in iter_eqns(jaxpr):
+    for eqn, invars in iter_collective_launches(jaxpr):
         name = eqn.primitive.name
-        if not any(name == p or name.startswith(p + "_")
-                   for p in COLLECTIVE_PRIMITIVES):
-            continue
         payload = 0
         operands = []
-        for v in eqn.invars:
+        for v in invars:
             aval = getattr(v, "aval", None)
             dt = getattr(aval, "dtype", None)
             if dt is None:
@@ -232,19 +268,15 @@ def reshard_ops(compiled_text: str) -> dict:
 
 
 def count_psum_joint(jaxpr, axes: Tuple[str, ...] = ("clients", "data")) -> int:
-    """psum binds whose axis set includes ALL of ``axes`` -- the eval
+    """psum launches whose axis set includes ALL of ``axes`` -- the eval
     phase's whole-mesh reductions (sBN moments, Global metric sums) reduce
     over ``(clients, data)`` jointly, while every training-round psum binds
     a single axis, so this cleanly separates the eval-fused superstep's
     collective budget from the one-global-psum-per-training-round
     invariant."""
-    n = 0
-    for eqn in iter_eqns(jaxpr):
-        if eqn.primitive.name == "psum":
-            seen = collective_axes(eqn)
-            if all(a in seen for a in axes):
-                n += 1
-    return n
+    return sum(1 for eqn, _ in iter_collective_launches(jaxpr)
+               if eqn.primitive.name == "psum"
+               and all(a in collective_axes(eqn) for a in axes))
 
 
 # ---------------------------------------------------------------------------

@@ -51,10 +51,12 @@ OPTIONAL_MEMORY_FIELDS = ("generated_code_size_in_bytes",
 #: CARRY_COPIES param-shaped carry buffers (params, momentum, update sums,
 #: count masks, double-buffered across the donation boundary), the psum
 #: payload, and one materialised copy of the staged operands.  Sized so the
-#: green matrix sits at <= ~0.5x of budget (measured on the audit widths)
-#: and a 10x blowup trips unconditionally; the ratchet holds the tight
-#: line.
-TEMP_FACTOR = 2.5
+#: green matrix sits well inside the budget and a 10x blowup trips
+#: unconditionally; the ratchet holds the tight line.  (Re-pinned 2.5 ->
+#: 3.5 with PR 23: XLA:CPU of jaxlib 0.9.0 assigns the two k8 eval-fused
+#: programs 14.7 MB of temp against the old 12.85 MB bound -- the same
+#: programs, a different buffer assignment.)
+TEMP_FACTOR = 3.5
 ACT_WORKING_SET = 3
 CARRY_COPIES = 8
 TEMP_SLACK = 1 << 20

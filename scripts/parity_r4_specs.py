@@ -1,9 +1,7 @@
 """Single source of truth for the round-3/4 mine-side campaign run specs.
 
-Both campaign runners -- the CPU fallback (run_parity_r3_mine.py) and the
-one-claim TPU session (tpu_r4_session.py) -- import RUNS and run_one from
-here, so artifact names, seeds, and round counts can never desynchronize
-between them.  Artifacts land in /tmp/PARITY_R3_MINE_*.json (written
+The campaign runner (run_parity_r3_mine.py) imports RUNS and run_one from
+here, so artifact names, seeds, and round counts have one owner.  Artifacts land in /tmp/PARITY_R3_MINE_*.json (written
 atomically by compare_reference) and finished runs are skipped, so a killed
 campaign resumes where it left off.
 """

@@ -3,15 +3,18 @@ superstep program.
 
 The contracts under test:
 
-* **arms=1 == unbatched, bitwise**: an E=1 arms program with the identity
-  arm (seed ``None``) produces bit-identical params and metrics to the
-  plain superstep -- the arms axis is pure structure.
+* **arms=1 == unbatched**: an E=1 arms program with the identity arm
+  (seed ``None``) produces the plain superstep's params and metrics -- the
+  arms axis is pure structure.
 * **arm i == solo**: arm *i* of a batched run equals an ``arms=1`` run
   carrying the same seed/lr_scale (same stream derivation,
-  ``fed.core.arm_stream_keys``) -- BITWISE for the masked engine across
+  ``fed.core.arm_stream_keys``) for the masked engine across
   {replicated, sharded} x K x +-eval, including the int8 EF-residual
-  carry and the stacked telemetry probes.  The grouped span engine is
-  pinned at an explicit association tolerance instead (GROUPED_ARM_TOL):
+  carry and the stacked telemetry probes.  Both held bitwise until jaxlib
+  0.9.0; they are now pinned at MASKED_ARM_TOL (see there for what was
+  measured and why no program-side cause exists).  The grouped span engine
+  has always been pinned at an explicit association tolerance
+  (GROUPED_ARM_TOL):
   XLA:CPU batch-lowers the small SLICED per-level convs with a different
   accumulation order once the arms axis batches them (measured ~3e-7
   relative on single weights), so bitwise equality would be a
@@ -97,18 +100,29 @@ def _fused(setup, cfg):
 
 
 #: the grouped arm-vs-solo association tolerance (see module docstring):
-#: explicit and pinned, NOT a convenience fudge -- masked stays bitwise
+#: explicit and pinned, NOT a convenience fudge
 GROUPED_ARM_TOL = dict(rtol=3e-6, atol=1e-7)
+
+#: the masked arm-vs-solo / arms=1-vs-unbatched / mesh-vs-vmap tolerance.
+#: These were bitwise contracts up to jaxlib 0.4.x.  XLA:CPU of jaxlib 0.9.0
+#: lowers the arms-BATCHED program's reductions with a different association
+#: from the unbatched one: after ONE round one element of an 8-wide leaf is
+#: off by one f32 ulp (7.5e-9), and 8 rounds of SGD carry that to 7.6e-7
+#: relative / 1.2e-7 absolute at worst on params and train metrics, 2.2e-6
+#: absolute on the eval-fused sBN moments (measured, PR 23).  The two programs
+#: are the same jaxpr up to batching, the gap survives
+#: ``--xla_cpu_use_fusion_emitters=false`` (so it is not only the new
+#: emitters' FMA contraction, which is what moved the fused-update unit
+#: test), and nothing on the program side selects the association -- so the
+#: contract is restated as <= 1 ulp per round, amplified over K rounds.
+MASKED_ARM_TOL = dict(rtol=3e-6, atol=5e-6)
 
 
 def _assert_arm_close(p_batched, e, p_solo, out_batched, out_solo, k,
-                      tol=None):
+                      tol=MASKED_ARM_TOL):
     def eq(a, b, msg):
-        a, b = np.asarray(a), np.asarray(b)
-        if tol is None:
-            np.testing.assert_array_equal(a, b, err_msg=msg)
-        else:
-            np.testing.assert_allclose(a, b, err_msg=msg, **tol)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   err_msg=msg, **tol)
 
     for name in p_solo:
         eq(p_batched[name][e], p_solo[name][0], name)
@@ -286,7 +300,7 @@ def test_refusals(setup):
 
 
 # ---------------------------------------------------------------------------
-# E=1 == unbatched, bitwise (the identity-arm contract)
+# E=1 == unbatched (the identity-arm contract, at MASKED_ARM_TOL)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("k", [1, 8])
@@ -301,13 +315,15 @@ def test_e1_bitwise_unbatched_masked(setup, k):
                                    data=data)
     out1 = pm1.fetch()
     for name in p_ref:
-        np.testing.assert_array_equal(np.asarray(p1[name][0]),
-                                      np.asarray(p_ref[name]), err_msg=name)
+        np.testing.assert_allclose(np.asarray(p1[name][0]),
+                                   np.asarray(p_ref[name]), err_msg=name,
+                                   **MASKED_ARM_TOL)
     for r in range(k):
         for name in METRICS:
-            np.testing.assert_array_equal(
+            np.testing.assert_allclose(
                 np.asarray(out1["arms"][0][r][name]),
-                np.asarray(ms_ref[r][name]), err_msg=f"{r}/{name}")
+                np.asarray(ms_ref[r][name]), err_msg=f"{r}/{name}",
+                **MASKED_ARM_TOL)
 
 
 @pytest.mark.slow
@@ -422,9 +438,11 @@ MESH_ARMS = {"count": 4, "seeds": [None, 7, 9, 11],
 
 def test_mesh_arms_placement_bitwise(setup):
     """Arms laid over a dedicated mesh axis (make_mesh(n_arms=E): each
-    arm's federation on its own device rows, executing concurrently) are
-    BITWISE-identical to the vmap placement -- and therefore to solo runs:
-    the placement is pure layout, never semantics."""
+    arm's federation on its own device rows, executing concurrently) equal
+    the vmap placement -- and therefore solo runs -- at MASKED_ARM_TOL
+    (bitwise until jaxlib 0.9.0; the mesh program is unbatched per device
+    row, the vmap one batched): the placement is pure layout, never
+    semantics."""
     cfg, model, data = setup["cfg"], setup["model"], setup["data"]
     k, E = 4, 4
     eng_v = RoundEngine(model, dict(cfg, arms=MESH_ARMS), make_mesh(2, 1))
@@ -438,15 +456,16 @@ def test_mesh_arms_placement_bitwise(setup):
                                       data=data)
     out_m = pm_m.fetch()
     for name in p_v:
-        np.testing.assert_array_equal(np.asarray(p_m[name]),
-                                      np.asarray(p_v[name]), err_msg=name)
+        np.testing.assert_allclose(np.asarray(p_m[name]),
+                                   np.asarray(p_v[name]), err_msg=name,
+                                   **MASKED_ARM_TOL)
     for e in range(E):
         for r in range(k):
             for nm in METRICS:
-                np.testing.assert_array_equal(
+                np.testing.assert_allclose(
                     np.asarray(out_m["arms"][e][r][nm]),
                     np.asarray(out_v["arms"][e][r][nm]),
-                    err_msg=f"arm {e} round {r} {nm}")
+                    err_msg=f"arm {e} round {r} {nm}", **MASKED_ARM_TOL)
 
 
 def test_mesh_arms_refusals(setup):

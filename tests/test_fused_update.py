@@ -110,9 +110,20 @@ def test_xla_fallback_bit_identical(gscale, has):
 
 
 def test_pallas_kernel_bit_identical_no_clip():
-    """Interpret-mode kernel forward bit-identity vs the reference chain in
-    the no-clip regime (elementwise path is exactly the reference's; the
-    clip scale is exactly 1.0 in both)."""
+    """Interpret-mode kernel vs the reference chain in the no-clip regime
+    (elementwise path is exactly the reference's; the clip scale is exactly
+    1.0 in both), to two f32 ulps.
+
+    This was bit-identical up to jaxlib 0.4.x.  XLA:CPU of jaxlib 0.9.0
+    emits the reference chain through its new fusion emitters, which
+    contract ``momentum * buf + g`` and ``+ wd * p`` into FMAs, while the
+    interpreted kernel's loads and stores keep those ops apart: the
+    reference equals an FMA emulation on all 288 elements, the kernel a
+    non-FMA one on 286, and ``--xla_cpu_use_fusion_emitters=false`` makes
+    the two bit-identical again (measured, PR 23).  The contraction is the
+    compiler's choice, not the program's, so the contract is restated as
+    <= 2 ulps: one per contracted multiply-add of the chain (61/288 momentum
+    entries differ, by at most 1.5e-8)."""
     p, g, b, m = _rand_trees(gscale=1e-3)
     has = jnp.asarray(True)
     rp, rb = jax.jit(lambda *a: _reference_chain(*a, 0.9, 5e-4, has))(
@@ -120,8 +131,10 @@ def test_pallas_kernel_bit_identical_no_clip():
     fp, fb = jax.jit(lambda *a: masked_sgd_step(
         *a, momentum=0.9, weight_decay=5e-4, has=has, mode="pallas",
         interpret=True))(p, g, b, m, jnp.float32(37.0), jnp.float32(0.05))
-    _assert_tree_equal(fp, rp)
-    _assert_tree_equal(fb, rb)
+    for got, ref in ((fp, rp), (fb, rb)):
+        for k in ref:
+            np.testing.assert_array_max_ulp(np.asarray(got[k]),
+                                            np.asarray(ref[k]), maxulp=2)
 
 
 def test_pallas_kernel_clip_engaged_value_agreement():

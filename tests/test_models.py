@@ -241,8 +241,7 @@ def test_pin_params_cpu_passthrough_and_formats():
         pin_params(params, mesh=None, policy="fastest")
     fmts = param_formats(params)
     for k, v in params.items():
-        dll = fmts[k].device_local_layout
-        assert tuple(dll.major_to_minor) == tuple(range(v.ndim)), k
+        assert tuple(fmts[k].layout.major_to_minor) == tuple(range(v.ndim)), k
 
 
 def test_conv_dimension_numbers_one_owner():

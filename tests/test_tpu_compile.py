@@ -1,0 +1,126 @@
+"""The Pallas kernels, compiled by the chip's own compiler at real widths.
+
+Interpret mode says nothing about what Mosaic accepts (scalar stores to
+VMEM, shape casts, block tiling), so each kernel is AOT-compiled here, with
+``interpret=False`` and shapes only, for a v5e that is described and not
+attached.  A compile that passes is not a run: ``chip_smoke.py`` is the run.
+
+Everything that describes the chip lives in the two fixtures below -- nothing
+at import time, so every xdist worker collects the same tests and only the
+worker given this file loads the TPU's library.  Keep these tests in this one
+file, and compile in the test's own process.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from heterofl_tpu import config as C
+from heterofl_tpu.models import make_model
+from heterofl_tpu.utils.compile_cache import no_persistent_cache
+
+SLOTS = 10  # the flagship's active clients, all on one device
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _flat_size(model_name):
+    """Flat f32 size of a full-width model's param tree, from its factory."""
+    cfg = C.default_cfg()
+    cfg["control"] = C.parse_control_name(
+        "1_100_0.1_iid_fix_a1-b1-c1-d1-e1_bn_1_1")
+    cfg["model_name"] = model_name
+    cfg["data_name"] = "WikiText2" if model_name == "transformer" else "CIFAR10"
+    cfg = C.process_control(cfg)
+    cfg["classes_size"] = 10
+    cfg["num_tokens"] = 33278  # WikiText-2's vocabulary
+    shapes = jax.eval_shape(make_model(cfg).init, jax.random.key(0))
+    return sum(v.size for v in shapes.values())
+
+
+def _compile(fn, *avals):
+    """Compile for the described chip, and see that Mosaic did."""
+    with no_persistent_cache():
+        text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text  # no XLA stand-in, no interpreter
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["bare", "vmap10"])
+@pytest.mark.parametrize("model_name", ["resnet18", "transformer"])
+def test_fused_sgd_kernel_compiles(one_chip, model_name, vmapped):
+    """The default path's kernel, bare and as the round calls it: under
+    ``vmap`` over the client slots with batched ``has``/``denom``."""
+    from heterofl_tpu.ops.fused_update import FlatSpec, fused_sgd_flat
+
+    total = _flat_size(model_name)
+    assert total > (11_000_000 if model_name == "resnet18" else 15_000_000)
+    spec = FlatSpec({"w": (total,)})
+
+    def step(p, g, b, m, n, lr, has):
+        return fused_sgd_flat(spec, p, {"w": g}, b, {"w": m}, n, lr,
+                              momentum=0.9, weight_decay=5e-4, has=has,
+                              mode="pallas", interpret=False)
+
+    def sds(shape, dt=jnp.float32):
+        lead = (SLOTS,) if vmapped else ()
+        return jax.ShapeDtypeStruct(lead + shape, dt, sharding=one_chip)
+
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    fn = step
+    if vmapped:
+        fn = jax.vmap(step, in_axes=(0, 0, 0, 0, 0, None, 0))
+    _compile(fn, sds((total,)), sds((total,)), sds((total,)), sds((total,)),
+             sds(()), lr, sds((), jnp.bool_))
+
+
+def test_quant_pack_kernel_compiles(one_chip):
+    """The int8 codec's quantise+pack pass at ResNet-18's flat size."""
+    from heterofl_tpu.ops.quant import quantize_pack
+
+    total = _flat_size("resnet18")
+    flat = jax.ShapeDtypeStruct((total,), jnp.float32, sharding=one_chip)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    _compile(lambda x, s, k: quantize_pack(x, s, k, 63, 64, mode="pallas",
+                                           interpret=False), flat, flat, key)
+
+
+@pytest.mark.parametrize("n,h,c", [(10, 32, 64), (10, 4, 512)],
+                         ids=["first-stage", "last-stage"])
+def test_pallas_norm_compiles(one_chip, n, h, c):
+    """Fused batch norm forward + backward at ResNet-18's first and last
+    stage shapes for batch 10, bare and under ``vmap`` over client slots."""
+    from heterofl_tpu.ops.pallas_norm import batch_norm_pallas
+
+    def grads(x, g, b, sw):
+        return jax.grad(
+            lambda x_, g_, b_: jnp.sum(batch_norm_pallas(
+                x_, g_, b_, sample_weight=sw, interpret=False) ** 2),
+            argnums=(0, 1, 2))(x, g, b)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    _compile(grads, sds(n, h, h, c), sds(c), sds(c), sds(n))
+    _compile(jax.vmap(grads), sds(SLOTS, n, h, h, c), sds(SLOTS, c),
+             sds(SLOTS, c), sds(SLOTS, n))

@@ -86,15 +86,14 @@ def test_program_wire_prices_psum_payload():
     """One psum bind over a (sums, counts) pair is priced at the summed
     per-participant operand bytes, attributed to the training axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = _tiny_mesh()
 
     def f(a, b):
         return jax.lax.psum((a, b), "clients")
 
-    sm = shard_map(f, mesh=mesh, in_specs=(P("clients"), P("clients")),
-                   out_specs=(P(), P()), check_rep=False)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P("clients"), P("clients")),
+                   out_specs=(P(), P()), check_vma=False)
     x = np.ones((4, 8), np.float32)  # per-device (2, 8) f32 = 64 bytes
     jaxpr = jax.jit(sm).trace(x, x).jaxpr
     rows = collective_payload_rows(jaxpr)
@@ -131,7 +130,6 @@ def test_wire_unbudgeted_collective_trips(setup):
     still shows up by its payload: bytes outside the train/eval buckets
     are zero in every green program."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = _tiny_mesh()
 
@@ -139,8 +137,8 @@ def test_wire_unbudgeted_collective_trips(setup):
         s = jax.lax.psum((a, b), "clients")
         return s, jax.lax.pmax(a, "clients")  # the smuggled reduction
 
-    sm = shard_map(f, mesh=mesh, in_specs=(P("clients"), P("clients")),
-                   out_specs=((P(), P()), P()), check_rep=False)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P("clients"), P("clients")),
+                   out_specs=((P(), P()), P()), check_vma=False)
     x = np.ones((4, 8), np.float32)
     wire = program_wire(jax.jit(sm).trace(x, x).jaxpr, mesh)
     assert wire["other_bytes"] == 64  # the per-device pmax operand
@@ -512,7 +510,7 @@ def cli(monkeypatch, tmp_path):
     import heterofl_tpu.staticcheck.audit as audit_mod
 
     state = {"report": _mini_report()}
-    monkeypatch.setattr(cli_mod, "_scrub_env_for_cpu_audit", lambda: None)
+    monkeypatch.setattr(cli_mod, "_pin_cpu_for_audit", lambda: None)
     monkeypatch.setattr(audit_mod, "run_audit",
                         lambda **kw: copy.deepcopy(state["report"]))
     out = str(tmp_path / "STATICCHECK.json")
