@@ -13,6 +13,7 @@ from typing import Dict, Optional, Tuple
 import jax.numpy as jnp
 
 from ..config import NORM_TYPES  # noqa: F401  (canonical registry, re-exported)
+from ..obs.trace import scope
 from ..ops.layers import batch_norm, dynamic_group_norm
 
 
@@ -51,9 +52,10 @@ def apply_norm(norm_type: str, x: jnp.ndarray, g: Optional[jnp.ndarray],
     if norm_type == "in":
         # GroupNorm(C, C): per-sample per-channel stats over spatial dims.
         axes = tuple(range(1, x.ndim - 1))
-        mean = jnp.mean(x, axis=axes, keepdims=True)
-        var = jnp.mean((x - mean) ** 2, axis=axes, keepdims=True)
-        return (x - mean) / jnp.sqrt(var + 1e-5) * g + b, None
+        with scope("norm"):
+            mean = jnp.mean(x, axis=axes, keepdims=True)
+            var = jnp.mean((x - mean) ** 2, axis=axes, keepdims=True)
+            return (x - mean) / jnp.sqrt(var + 1e-5) * g + b, None
     if norm_type == "ln":
         return dynamic_group_norm(x, g, b, 1, mask, k), None
     if norm_type == "gn":

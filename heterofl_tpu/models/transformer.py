@@ -28,6 +28,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
 from ..ops.layers import cross_entropy, embed, linear as _linear, masked_layer_norm, masked_logits, scaler
 from .base import ModelDef, normal_init, uniform_fan_in
 from .spec import Group, ParamSpec
@@ -168,19 +169,20 @@ def _make_apply(num_tokens, E, H, F, num_layers, dropout_rate, bptt, mask_rate, 
             q = sc(linear(x, params[f"{p}.mha.q.w"], params[f"{p}.mha.q.b"]))
             k = sc(linear(x, params[f"{p}.mha.k.w"], params[f"{p}.mha.k.b"]))
             v = sc(linear(x, params[f"{p}.mha.v.w"], params[f"{p}.mha.v.b"]))
-            q, k, v = heads_split(q), heads_split(k), heads_split(v)
-            if compute_dtype is not None:
-                q, k, v = (t.astype(compute_dtype) for t in (q, k, v))
             attn_fn = attn_override if attn_override is not None else attn_impl
-            if attn_fn is not None:
-                o = attn_fn(q, k, v, temp).astype(jnp.float32)
-            else:
-                scores = jnp.einsum("nhqd,nhkd->nhqk", q, k).astype(jnp.float32) / temp
-                attn = jax.nn.softmax(scores, axis=-1)
+            with scope("attn"):
+                q, k, v = heads_split(q), heads_split(k), heads_split(v)
                 if compute_dtype is not None:
-                    attn = attn.astype(compute_dtype)
-                o = jnp.einsum("nhqk,nhkd->nhqd", attn, v).astype(jnp.float32)
-            o = o.transpose(0, 2, 1, 3).reshape(N, S, E)
+                    q, k, v = (t.astype(compute_dtype) for t in (q, k, v))
+                if attn_fn is not None:
+                    o = attn_fn(q, k, v, temp).astype(jnp.float32)
+                else:
+                    scores = jnp.einsum("nhqd,nhkd->nhqk", q, k).astype(jnp.float32) / temp
+                    attn = jax.nn.softmax(scores, axis=-1)
+                    if compute_dtype is not None:
+                        attn = attn.astype(compute_dtype)
+                    o = jnp.einsum("nhqk,nhkd->nhqd", attn, v).astype(jnp.float32)
+                o = o.transpose(0, 2, 1, 3).reshape(N, S, E)
             o = sc(linear(o, params[f"{p}.mha.o.w"], params[f"{p}.mha.o.b"]))
             x = ln(f"{p}.norm1", x + dropout(o, 1 + 3 * i))
             h = dropout(jax.nn.gelu(sc(linear(x, params[f"{p}.ff.l1.w"], params[f"{p}.ff.l1.b"])),

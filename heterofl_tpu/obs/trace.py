@@ -1,7 +1,7 @@
 """Run tracing (ISSUE 10): one Chrome-trace + events-JSONL recorder per run.
 
-The driver already times its phases (``PhaseTimer``: stage / dispatch /
-compute / fetch / eval) and jax can annotate device traces
+The driver already times its phases (``PhaseTimer``: sample / stage /
+dispatch / fetch) and jax can annotate device traces
 (``jax.profiler``), but the three clocks never met in one artifact: a
 stall was a number in a phase table, not a visible gap on a timeline.
 :class:`TraceRecorder` unifies them:
@@ -22,15 +22,82 @@ stall was a number in a phase table, not a visible gap on a timeline.
 
 Host-side only (stdlib + lazy jax import for the annotation); the traced
 programs are never touched -- recording is pure driver bookkeeping.
+
+The DEVICE side of the same picture is the scope vocabulary below
+(ISSUE 26): :func:`scope` enters ``jax.named_scope`` with one of
+:data:`SCOPES`, so every instruction of the round program carries the path
+of the part it belongs to in its ``op_name`` metadata
+(``.../round/local_train/.../jvp(step/model)/conv/conv_general_dilated``)
+and a ``cfg['profile_dir']`` device trace can be split by part.  A scope is
+trace-time metadata: no run-time cost, no switch.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import time
 from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, Optional
+
+#: Device-side scope vocabulary: every name the programs enter with
+#: :func:`scope`.  Names are ``op_name`` path components and nest as the
+#: program nests them: ``round/local_train`` encloses the scan of ``step/*``,
+#: ``step/model`` (entered INSIDE the differentiated function, so autodiff
+#: marks its forward ``jvp(step/model)`` and its backward
+#: ``transpose(jvp(step/model))``) encloses the layer leaves, ``step/update``
+#: encloses ``update/*``, ``round/aggregate`` encloses ``psum``.
+#: benchmark/scope_reduce.py reads device time by these names (PERF.md
+#: section 3 lists which metric reads which).
+SCOPES = (
+    "round/gather", "round/local_train", "round/aggregate", "psum",
+    "step/batch", "augment", "step/unflatten", "step/model", "step/update",
+    "conv", "linear", "embed", "norm", "attn", "loss",
+    "update/flatten", "update/pack", "update/kernel", "update/unpack",
+    "eval/sbn", "eval/users", "eval/global",
+)
+
+#: Version of the vocabulary AND of where it is entered.  jax keeps metadata
+#: out of the persistent compile cache's key, so a program whose only change
+#: is a scope would load the executable cached before the change, without
+#: the new names, silently; ``utils.compile_cache`` folds this number into
+#: the key.  Bump it with every change to :data:`SCOPES` or to where a scope
+#: is entered (one cold compile per program, once).
+SCOPE_VERSION = 1
+
+#: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
+KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
+
+
+def _known(name: str) -> str:
+    if name not in SCOPES:
+        raise ValueError(f"Not valid scope: {name!r} (obs.trace.SCOPES)")
+    return name
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES` (a typo
+    raises at trace time instead of filing device time under a name no
+    metric reads)."""
+    import jax
+
+    return jax.named_scope(_known(name))
+
+
+def scoped(name: str):
+    """Decorator form of :func:`scope`: the whole function runs under the
+    scope, entered anew at each call (each trace)."""
+    _known(name)
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
+
 
 #: events.jsonl schema, version 1: required fields -> type.  ``dur_s`` is
 #: present exactly on complete ("X") events; ``args`` is a flat JSON
@@ -154,16 +221,9 @@ class TraceRecorder:
 
     # -- finish --------------------------------------------------------
 
-    def sync(self) -> str:
-        """Flush + fsync the artifacts WITHOUT closing the recorder: the
-        rollback path's durability twin of :meth:`close` (ISSUE 15
-        satellite -- the abort path closes, but a rollback continues the
-        run, and each recovery attempt must still leave the trip evidence
-        on disk: events.jsonl fsync'd with the trip instant as its last
-        line, trace.json a point-in-time snapshot).  Returns the trace
-        path; no-op after close."""
-        if self.closed:
-            return self.trace_path
+    def _write_trace(self) -> None:
+        """Flush + fsync the JSONL stream and write ``trace.json`` (fsync'd)
+        from the events recorded so far."""
         self._jsonl.flush()
         os.fsync(self._jsonl.fileno())
         with open(self.trace_path, "w") as f:
@@ -174,6 +234,17 @@ class TraceRecorder:
             f.write("\n")
             f.flush()
             os.fsync(f.fileno())
+
+    def sync(self) -> str:
+        """Flush + fsync the artifacts WITHOUT closing the recorder: the
+        rollback path's durability twin of :meth:`close` (ISSUE 15
+        satellite -- the abort path closes, but a rollback continues the
+        run, and each recovery attempt must still leave the trip evidence
+        on disk: events.jsonl fsync'd with the trip instant as its last
+        line, trace.json a point-in-time snapshot).  Returns the trace
+        path; no-op after close."""
+        if not self.closed:
+            self._write_trace()
         return self.trace_path
 
     def close(self) -> str:
@@ -189,15 +260,6 @@ class TraceRecorder:
         if self.closed:
             return self.trace_path
         self.closed = True
-        self._jsonl.flush()
-        os.fsync(self._jsonl.fileno())
+        self._write_trace()
         self._jsonl.close()
-        with open(self.trace_path, "w") as f:
-            json.dump({"traceEvents": self._events,
-                       "displayTimeUnit": "ms",
-                       "metadata": {"clock": "perf_counter",
-                                    "t0_wall": self._t0_wall}}, f)
-            f.write("\n")
-            f.flush()
-            os.fsync(f.fileno())
         return self.trace_path

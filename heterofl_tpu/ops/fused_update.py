@@ -60,6 +60,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
+
 
 #: lane width of the flattened-tree packing (TPU vector lane count)
 LANE = 128
@@ -138,15 +140,17 @@ def _xla_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd, max_norm,
     # different association/contraction on XLA:CPU); the fusion win comes
     # from the FLAT CARRY (O(1) loop-carried buffers instead of O(leaves),
     # zero-copy leaf views in, one flatten out)
-    pt, bt = spec.unflatten(pf), spec.unflatten(bf)
-    gm = {k: (grads[k] / denom) * masks[k] for k in spec.names}
-    gm, _ = clip_by_global_norm(gm, max_norm)
-    nb = {k: momentum * bt[k] + gm[k] + wd * pt[k] for k in spec.names}
-    np_ = {k: pt[k] - lr * nb[k] for k in spec.names}
-    if has is not None:
-        np_ = {k: jnp.where(has, np_[k], pt[k]) for k in spec.names}
-        nb = {k: jnp.where(has, nb[k], bt[k]) for k in spec.names}
-    return spec.flatten(np_), spec.flatten(nb)
+    with scope("update/kernel"):  # the per-leaf chain stands in for the kernel
+        pt, bt = spec.unflatten(pf), spec.unflatten(bf)
+        gm = {k: (grads[k] / denom) * masks[k] for k in spec.names}
+        gm, _ = clip_by_global_norm(gm, max_norm)
+        nb = {k: momentum * bt[k] + gm[k] + wd * pt[k] for k in spec.names}
+        np_ = {k: pt[k] - lr * nb[k] for k in spec.names}
+        if has is not None:
+            np_ = {k: jnp.where(has, np_[k], pt[k]) for k in spec.names}
+            nb = {k: jnp.where(has, nb[k], bt[k]) for k in spec.names}
+    with scope("update/flatten"):
+        return spec.flatten(np_), spec.flatten(nb)
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +198,8 @@ def _pallas_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    gf, mf = spec.flatten(grads), spec.flatten(masks)
+    with scope("update/flatten"):
+        gf, mf = spec.flatten(grads), spec.flatten(masks)
     rows = -(-spec.total // LANE)
     pad = rows * LANE - spec.total
 
@@ -203,11 +208,13 @@ def _pallas_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd,
             flat = jnp.concatenate([flat, jnp.zeros(pad, jnp.float32)])
         return flat.reshape(rows, LANE)
 
-    has_val = jnp.float32(1.0) if has is None else has.astype(jnp.float32)
-    scal = jnp.stack([denom, lr.astype(jnp.float32), has_val]).reshape(1, 3)
+    with scope("update/pack"):
+        has_val = jnp.float32(1.0) if has is None else has.astype(jnp.float32)
+        scal = jnp.stack([denom, lr.astype(jnp.float32), has_val]).reshape(1, 3)
+        packed = (pack(gf), pack(pf), pack(bf), pack(mf))
     bm = min(block_rows, max(1, rows))
     nm = pl.cdiv(rows, bm)
-    p2, b2 = pl.pallas_call(
+    kernel = pl.pallas_call(
         partial(_fused_sgd_kernel, momentum=momentum, wd=wd,
                 max_norm=max_norm, rows_total=rows, block_rows=bm),
         grid=(2, nm),
@@ -228,8 +235,12 @@ def _pallas_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd,
         ],
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
-    )(pack(gf), pack(pf), pack(bf), pack(mf), scal)
-    return p2.reshape(-1)[:spec.total], b2.reshape(-1)[:spec.total]
+        name="fused_sgd",
+    )
+    with scope("update/kernel"):
+        p2, b2 = kernel(*packed, scal)
+    with scope("update/unpack"):
+        return p2.reshape(-1)[:spec.total], b2.reshape(-1)[:spec.total]
 
 
 # ---------------------------------------------------------------------------

@@ -22,11 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.trace import scope, scoped
+
 #: THE conv dimension-number convention (models/layout.py re-exports it as
 #: part of the explicit layout policy; one owner, two consumers)
 CONV_DIMENSION_NUMBERS: Tuple[str, str, str] = ("NHWC", "HWIO", "NHWC")
 
 
+@scoped("conv")
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray] = None,
            stride: int = 1, padding: int = 1,
            compute_dtype: Optional[jnp.dtype] = None,
@@ -77,6 +80,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray] = None,
     return y
 
 
+@scoped("linear")
 def linear(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray] = None,
            compute_dtype: Optional[jnp.dtype] = None) -> jnp.ndarray:
     if compute_dtype is not None:
@@ -89,6 +93,7 @@ def linear(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray] = None,
     return y
 
 
+@scoped("embed")
 def embed(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     return jnp.take(table, ids, axis=0)
 
@@ -109,6 +114,7 @@ def global_avg_pool(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.mean(x, axis=(1, 2))
 
 
+@scoped("norm")
 def batch_norm(x: jnp.ndarray, g: jnp.ndarray, b: jnp.ndarray, *,
                mode: str = "batch",
                running: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
@@ -197,6 +203,7 @@ def batch_norm(x: jnp.ndarray, g: jnp.ndarray, b: jnp.ndarray, *,
     return y, None
 
 
+@scoped("norm")
 def masked_layer_norm(x: jnp.ndarray, g: jnp.ndarray, b: jnp.ndarray,
                       mask: jnp.ndarray, k, eps: float = 1e-5) -> jnp.ndarray:
     """LayerNorm over the last axis counting only the ``k`` active dims.
@@ -213,6 +220,7 @@ def masked_layer_norm(x: jnp.ndarray, g: jnp.ndarray, b: jnp.ndarray,
     return (xm - mean) / jnp.sqrt(var + eps) * g + b
 
 
+@scoped("norm")
 def dynamic_group_norm(x: jnp.ndarray, g: jnp.ndarray, b: jnp.ndarray,
                        num_groups: int, mask: jnp.ndarray, k,
                        eps: float = 1e-5) -> jnp.ndarray:
@@ -254,9 +262,11 @@ def masked_logits(out: jnp.ndarray, label_mask: Optional[jnp.ndarray], enabled: 
     (ref models/conv.py:66-69 -- zero fill, *not* -inf)."""
     if label_mask is None or not enabled:
         return out
-    return jnp.where(label_mask == 0, 0.0, out)
+    with scope("loss"):
+        return jnp.where(label_mask == 0, 0.0, out)
 
 
+@scoped("loss")
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
                   sample_weight: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Mean cross entropy; class axis is the LAST axis of ``logits``.

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 
+from ..obs.trace import scoped
+
 
 def _vmem(shape):
     from jax.experimental.pallas import tpu as pltpu
@@ -131,6 +133,7 @@ def _call_fwd(x2, w, g, b, eps, bm, interpret):
         ],
         scratch_shapes=[_vmem((1, C)), _vmem((1, C)), _vmem((1, 1))],
         interpret=interpret,
+        name="masked_bn_fwd",
     )(x2, w, g.reshape(1, C), b.reshape(1, C))
 
 
@@ -159,6 +162,7 @@ def _call_bwd(x2, w, g, dy, stats, bm, interpret):
         ],
         scratch_shapes=[_vmem((1, C)), _vmem((1, C))],
         interpret=interpret,
+        name="masked_bn_bwd",
     )(x2, w, g.reshape(1, C), dy, stats)
 
 
@@ -182,6 +186,7 @@ def _bn2d_bwd(eps, bm, interpret, res, dy):
 _bn2d.defvjp(_bn2d_fwd, _bn2d_bwd)
 
 
+@scoped("norm")
 def batch_norm_pallas(x: jnp.ndarray, g: jnp.ndarray, b: jnp.ndarray,
                       sample_weight: Optional[jnp.ndarray] = None,
                       eps: float = 1e-5, block_m: int = 2048,

@@ -131,6 +131,7 @@ def _quant_pack_pallas(x, scale, key, qmax: int, bias: int, block_rows: int,
         out_shape=[jax.ShapeDtypeStruct((rows, LANE // 4), jnp.int32),
                    jax.ShapeDtypeStruct((rows, LANE), jnp.int32)],
         interpret=interpret,
+        name="int8_pack",
     )(pack2d(x), pack2d(scale, fill=1.0), pack2d(u))
     words = -(-n // 4)
     return w2.reshape(-1)[:words], q2.reshape(-1)[:n]

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..data.datasets import DATASET_STATS
 from ..models.base import ModelDef
 from .round_engine import _ceil_div, _shard_map
+from ..obs.trace import scoped
 from .staging import PlacementCache
 
 
@@ -68,6 +69,7 @@ class Evaluator:
 
     # -------------------- sBN recalibration --------------------
 
+    @scoped("eval/sbn")
     def _sbn_body(self, params, xb, wb):
         """Per-device sBN moment accumulation (pure; runs under any
         ``shard_map`` whose mesh carries the ``clients``/``data`` axes):
@@ -153,6 +155,7 @@ class Evaluator:
         correct = jnp.sum((jnp.argmax(out["score"], -1) == y) * w)
         return {"loss_sum": loss * n, "score_sum": correct, "n": n}
 
+    @scoped("eval/users")
     def _users_body(self, params, bn_state, key, valid, x, y, m, lm):
         """Per-device "Local" eval core (pure, shard_map-reusable): vmap this
         device's user shards through their batched test sets; per-user keys
@@ -227,6 +230,7 @@ class Evaluator:
         # staticcheck: allow(no-asarray): the eval-boundary D2H fetch point
         return {k: np.asarray(v)[:u] for k, v in out.items()}
 
+    @scoped("eval/global")
     def _global_body(self, params, bn_state, key, *data):
         """Per-device "Global" eval core (pure, shard_map-reusable): scan
         this device's slice of the batched test set and psum the metric sums

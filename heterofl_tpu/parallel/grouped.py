@@ -77,6 +77,7 @@ from ..chaos import resolve_poison_cfg
 from ..obs import resolve_quarantine_cfg, resolve_telemetry_cfg, split_probes
 from ..obs.hist import round_hists
 from ..obs.probes import round_probes
+from ..obs.trace import scope
 from ..ops.fused_update import FlatSpec
 from ..sched import resolve_schedule_cfg
 from ..sched.buffer import _SchedBufCarry, buffered_combine
@@ -484,11 +485,16 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
                     jax.random.fold_in(fkey, u), self.failure_rate)
             )(ugid).astype(jnp.float32)
             valid = valid * alive
-        sub = extract_sliced_jnp(params, gm.specs, gm.groups, wr)
-        slot_keys = client_stream_keys(key, ugid)
-        lm = lm_all if local_data else lm_all[ugid]
+        with scope("round/gather"):
+            sub = extract_sliced_jnp(params, gm.specs, gm.groups, wr)
+            slot_keys = client_stream_keys(key, ugid)
+            lm = lm_all if local_data else lm_all[ugid]
+            if self.is_lm:
+                rows = data[0] if local_data else data[0][ugid]
+            else:
+                xs, ys, sms = (data[0], data[1], data[2]) if local_data \
+                    else (data[0][ugid], data[1][ugid], data[2][ugid])
         if self.is_lm:
-            rows = data[0] if local_data else data[0][ugid]
             if self._sched_spec.has_deadline:
                 # deadline stragglers (ISSUE 9): the masked engine's exact
                 # per-client budget draw (same round key + global uid, same
@@ -497,36 +503,38 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
                     int(rows.shape[-1]), eng_l.bptt)
                 limits = deadline_steps(key, ugid, total_steps,
                                         self._sched_spec.deadline_min_frac)
-                trained, ms = jax.vmap(
-                    lambda r_, l_, k_, lim_: eng_l._local_train_lm(
-                        sub, 1.0, r_, l_, k_, lr, scaler_rate=wr,
-                        data_axis=data_axis, n_data=n_data, step_limit=lim_)
-                )(rows, lm, slot_keys, limits)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda r_, l_, k_, lim_: eng_l._local_train_lm(
+                            sub, 1.0, r_, l_, k_, lr, scaler_rate=wr,
+                            data_axis=data_axis, n_data=n_data, step_limit=lim_)
+                    )(rows, lm, slot_keys, limits)
             else:
-                trained, ms = jax.vmap(
-                    lambda r_, l_, k_: eng_l._local_train_lm(
-                        sub, 1.0, r_, l_, k_, lr, scaler_rate=wr,
-                        data_axis=data_axis, n_data=n_data)
-                )(rows, lm, slot_keys)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda r_, l_, k_: eng_l._local_train_lm(
+                            sub, 1.0, r_, l_, k_, lr, scaler_rate=wr,
+                            data_axis=data_axis, n_data=n_data)
+                    )(rows, lm, slot_keys)
         else:
-            xs, ys, sms = (data[0], data[1], data[2]) if local_data \
-                else (data[0][ugid], data[1][ugid], data[2][ugid])
             if self._sched_spec.has_deadline:
                 total_steps = eng_l.local_epochs * _ceil_div(
                     int(xs.shape[1]), eng_l.batch_size)
                 limits = deadline_steps(key, ugid, total_steps,
                                         self._sched_spec.deadline_min_frac)
-                trained, ms = jax.vmap(
-                    lambda x_, y_, m_, l_, k_, lim_: eng_l._local_train_vision(
-                        sub, 1.0, x_, y_, m_, l_, k_, lr, scaler_rate=wr,
-                        data_axis=data_axis, n_data=n_data, step_limit=lim_)
-                )(xs, ys, sms, lm, slot_keys, limits)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda x_, y_, m_, l_, k_, lim_: eng_l._local_train_vision(
+                            sub, 1.0, x_, y_, m_, l_, k_, lr, scaler_rate=wr,
+                            data_axis=data_axis, n_data=n_data, step_limit=lim_)
+                    )(xs, ys, sms, lm, slot_keys, limits)
             else:
-                trained, ms = jax.vmap(
-                    lambda x_, y_, m_, l_, k_: eng_l._local_train_vision(
-                        sub, 1.0, x_, y_, m_, l_, k_, lr, scaler_rate=wr,
-                        data_axis=data_axis, n_data=n_data)
-                )(xs, ys, sms, lm, slot_keys)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda x_, y_, m_, l_, k_: eng_l._local_train_vision(
+                            sub, 1.0, x_, y_, m_, l_, k_, lr, scaler_rate=wr,
+                            data_axis=data_axis, n_data=n_data)
+                    )(xs, ys, sms, lm, slot_keys)
         if self._poison is not None:
             # chaos NaN poison (ISSUE 15): same (round, uid) table and
             # injection point as the masked engine -- the update goes
@@ -543,10 +551,11 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
         # counted sums in SLICED shape (within the slice the width mask is
         # all-ones by construction; only the label-split restriction remains)
         sub_shapes = {k: v.shape for k, v in sub.items()}
-        cms = jax.vmap(lambda l_, v_: jax.tree_util.tree_map(
-            lambda m: m * v_,
-            make_count_masks(sub_shapes, model_l.specs, model_l.groups, 1.0, l_)))(
-            lm, valid)
+        with scope("round/aggregate"):
+            cms = jax.vmap(lambda l_, v_: jax.tree_util.tree_map(
+                lambda m: m * v_,
+                make_count_masks(sub_shapes, model_l.specs, model_l.groups, 1.0, l_)))(
+                lm, valid)
         ok = None
         if self._quarantine.enabled:
             # client-update quarantine (ISSUE 15): gate this level's slots
@@ -564,8 +573,9 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
             trained = {k: jnp.where(ok.reshape((-1,) + (1,) * (v.ndim - 1)),
                                     v, jnp.zeros((), v.dtype))
                        for k, v in trained.items()}
-        sum_l = {k: jnp.sum(trained[k] * cms[k], axis=0) for k in sub}
-        cnt_l = {k: jnp.sum(cms[k], axis=0) for k in sub}
+        with scope("round/aggregate"):
+            sum_l = {k: jnp.sum(trained[k] * cms[k], axis=0) for k in sub}
+            cnt_l = {k: jnp.sum(cms[k], axis=0) for k in sub}
         if ok is not None:
             okf = ok.astype(jnp.float32)
             ms = {k: jnp.where(ok, v, jnp.zeros((), v.dtype)) * valid
@@ -600,9 +610,11 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
                                                 data, n_data, data_axis)
             # ONE psum bind for the level's sums+counts (bit-compatible with
             # two binds; staticcheck audits the one-collective budget)
-            sum_l, cnt_l = jax.lax.psum((sum_l, cnt_l), "clients")
-            sum_l = embed_sliced_jnp(sum_l, gm.specs, gm.groups, wr)
-            cnt_l = embed_sliced_jnp(cnt_l, gm.specs, gm.groups, wr)
+            with scope("round/aggregate"):
+                with scope("psum"):
+                    sum_l, cnt_l = jax.lax.psum((sum_l, cnt_l), "clients")
+                sum_l = embed_sliced_jnp(sum_l, gm.specs, gm.groups, wr)
+                cnt_l = embed_sliced_jnp(cnt_l, gm.specs, gm.groups, wr)
             return sum_l, cnt_l, ms
 
         data_specs = (P(), P()) if self.is_lm else (P(), P(), P(), P())
@@ -634,9 +646,10 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
             return self._combine_progs[n_levels]
 
         def merge(params, sums, cnts):
-            summed = jax.tree_util.tree_map(lambda *xs: sum(xs), *sums)
-            counts = jax.tree_util.tree_map(lambda *xs: sum(xs), *cnts)
-            return combine_counted(params, summed, counts)
+            with scope("round/aggregate"):
+                summed = jax.tree_util.tree_map(lambda *xs: sum(xs), *sums)
+                counts = jax.tree_util.tree_map(lambda *xs: sum(xs), *cnts)
+                return combine_counted(params, summed, counts)
 
         prog = jax.jit(merge, donate_argnums=(0,))
         self._combine_progs[n_levels] = prog
@@ -1203,25 +1216,29 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
                     # partials; EF residual re-injected next round
                     from ..compress.codecs import compressed_psum
 
-                    tot_s, tot_c, nr = compressed_psum(
-                        self._codec(p), "clients", p, tot_s, tot_c, rs, key,
-                        cmax)
+                    with scope("round/aggregate"), scope("psum"):
+                        tot_s, tot_c, nr = compressed_psum(
+                            self._codec(p), "clients", p, tot_s, tot_c, rs,
+                            key, cmax)
                 else:
                     # THE single global psum of the fused round (the PR 2
                     # invariant, audited by staticcheck): one bind joins the
                     # level sums AND counts across the whole clients axis
-                    tot_s, tot_c = jax.lax.psum((tot_s, tot_c), "clients")
+                    with scope("round/aggregate"), scope("psum"):
+                        tot_s, tot_c = jax.lax.psum((tot_s, tot_c), "clients")
                 if buffered:
                     # buffered-async aggregation (ISSUE 9): this round's
                     # reduction lands NEXT round, staleness-weighted; the
                     # previous round's buffered update applies now
-                    new_p, nb = buffered_combine(p, sb, tot_s, tot_c,
-                                                 FlatSpec.of(p),
-                                                 self._sched_spec.staleness)
+                    with scope("round/aggregate"):
+                        new_p, nb = buffered_combine(
+                            p, sb, tot_s, tot_c, FlatSpec.of(p),
+                            self._sched_spec.staleness)
                     ms = attach_probes(ms, p, new_p, tot_s, tot_c, nb_=nb,
                                        uids_=srow, key_=key, ts_=hist_ts)
                     return (new_p, nb), ms
-                new_p = combine_counted(p, tot_s, tot_c)
+                with scope("round/aggregate"):
+                    new_p = combine_counted(p, tot_s, tot_c)
                 ms = attach_probes(ms, p, new_p, tot_s, tot_c,
                                    nr_=nr if codec else None,
                                    uids_=srow, key_=key, ts_=hist_ts)

@@ -30,6 +30,7 @@ from ..multi import resolve_arms_cfg
 from ..obs import resolve_quarantine_cfg, resolve_telemetry_cfg, split_probes
 from ..obs.hist import round_hists
 from ..obs.probes import round_probes
+from ..obs.trace import scope, scoped
 from ..data.datasets import DATASET_STATS
 from ..fed.core import (arm_stream_keys, client_stream_keys, combine_counted,
                         failure_stream_key, round_rates, round_users)
@@ -55,6 +56,15 @@ def _shard_map(f, mesh, in_specs, out_specs):
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _leaf_views(spec, p):
+    """What the step's model is differentiated w.r.t.: the flat carry's
+    per-leaf views (``step/unflatten``), or the tree carry itself."""
+    if spec is None:
+        return p
+    with scope("step/unflatten"):
+        return spec.unflatten(p)
 
 
 def _bucket_pow2(n: int) -> int:
@@ -545,6 +555,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
     # per-client local training (pure; vmapped across clients)
     # ------------------------------------------------------------------
 
+    @scoped("augment")
     def _prep_vision_batch(self, x_u8, w, key, train=True):
         if self.augment and train:
             x_u8 = augment_cifar(key, x_u8)
@@ -575,10 +586,11 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         the ``lax.scan`` carry as ONE lane-packed flat f32 buffer each
         (ops/fused_update.py FlatSpec) -- the carry shrinks from O(leaves)
         loop-carried buffers to O(1) with a pinned packed layout, the model
-        fwd/bwd consumes zero-copy leaf views unflattened inside the step
-        (and is differentiated w.r.t. those views, so the per-leaf grads
-        and norm terms are the reference chain's), and the optimizer tail
-        runs in the flat domain.  ``masks`` are the hoisted loop-invariant
+        fwd/bwd consumes leaf views unflattened inside the step (views; on
+        the chip a copy per leaf, see ``carry_ms.step``; it is
+        differentiated w.r.t. those views, so the per-leaf grads and norm
+        terms are the reference chain's), and the optimizer tail runs in
+        the flat domain.  ``masks`` are the hoisted loop-invariant
         grad masks, or None under the ``_masks_in_body`` regression
         knob."""
         gmasks = None if self._masks_in_body else \
@@ -592,6 +604,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         # would be a dead loop-carried value
         return pf, jnp.zeros_like(pf), spec, gmasks
 
+    @scoped("step/update")
     def _apply_update(self, p, grads, opt, masks, spec, wr, n_glob, lr,
                       has=None):
         """The per-step optimizer epilogue: mean-normalise + width-mask +
@@ -673,35 +686,39 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
 
         def step(carry, t):
             p, opt, acc = carry
-            e, s = t // S, t % S
-            ids = jax.lax.dynamic_slice(perms, (e, s * B), (1, B))[0]
-            w = jax.lax.dynamic_slice(wpad, (s * B,), (B,)) * sm[ids]
-            has = (jnp.sum(w) > 0)  # global batch weight BEFORE any sharding
-            n_glob = jnp.sum(w)
-            live = None
-            if step_limit is not None:
-                # deadline straggler (ISSUE 9): steps past this client's
-                # budget are no-ops -- update skipped, metrics zeroed
-                live = t < step_limit
-                has = jnp.logical_and(has, live)
-            aug_key = jax.random.fold_in(key, 2 + t)
-            if data_axis is not None and n_data > 1:
-                # this device's slice of the client's batch, with the
-                # augmentation key decorrelated across slices
-                d = jax.lax.axis_index(data_axis)
-                ids = jnp.concatenate([ids, ids[: bp - B]]) if bp > B else ids
-                w = jnp.concatenate([w, jnp.zeros(bp - B, jnp.float32)]) if bp > B else w
-                ids = jax.lax.dynamic_slice(ids, (d * b_loc,), (b_loc,))
-                w = jax.lax.dynamic_slice(w, (d * b_loc,), (b_loc,))
-                aug_key = jax.random.fold_in(aug_key, d)
-            img = self._prep_vision_batch(x[ids], w, aug_key)
-            batch = {"img": img, "label": y[ids]}
+            with scope("step/batch"):
+                e, s = t // S, t % S
+                ids = jax.lax.dynamic_slice(perms, (e, s * B), (1, B))[0]
+                w = jax.lax.dynamic_slice(wpad, (s * B,), (B,)) * sm[ids]
+                has = (jnp.sum(w) > 0)  # global batch weight BEFORE any sharding
+                n_glob = jnp.sum(w)
+                live = None
+                if step_limit is not None:
+                    # deadline straggler (ISSUE 9): steps past this client's
+                    # budget are no-ops -- update skipped, metrics zeroed
+                    live = t < step_limit
+                    has = jnp.logical_and(has, live)
+                aug_key = jax.random.fold_in(key, 2 + t)
+                if data_axis is not None and n_data > 1:
+                    # this device's slice of the client's batch, with the
+                    # augmentation key decorrelated across slices
+                    d = jax.lax.axis_index(data_axis)
+                    ids = jnp.concatenate([ids, ids[: bp - B]]) if bp > B else ids
+                    w = jnp.concatenate([w, jnp.zeros(bp - B, jnp.float32)]) if bp > B else w
+                    ids = jax.lax.dynamic_slice(ids, (d * b_loc,), (b_loc,))
+                    w = jax.lax.dynamic_slice(w, (d * b_loc,), (b_loc,))
+                    aug_key = jax.random.fold_in(aug_key, d)
+                img = self._prep_vision_batch(x[ids], w, aug_key)
+                batch = {"img": img, "label": y[ids]}
 
             def loss_fn(pt):
-                out, _ = model.apply(pt, batch, train=True, width_rate=wr, scaler_rate=sr,
-                                     label_mask=lm, sample_weight=w,
-                                     rng=jax.random.fold_in(key, 5000 + t),
-                                     bn_axis=data_axis if n_data > 1 else None)
+                # entered INSIDE the differentiated function: the forward
+                # is jvp(step/model), the backward transpose(jvp(step/model))
+                with scope("step/model"):
+                    out, _ = model.apply(pt, batch, train=True, width_rate=wr, scaler_rate=sr,
+                                         label_mask=lm, sample_weight=w,
+                                         rng=jax.random.fold_in(key, 5000 + t),
+                                         bn_axis=data_axis if n_data > 1 else None)
                 n_loc = jnp.sum(w)
                 # weighted-SUM form so cross-device reduction recovers the
                 # exact full-batch mean gradient
@@ -711,7 +728,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             # the per-leaf VIEWS, so grads come back per-leaf -- the norm
             # terms then reduce over the reference chain's exact arrays
             (lsum, score), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                spec.unflatten(p) if spec is not None else p)
+                _leaf_views(spec, p))
             correct = jnp.sum((jnp.argmax(score, -1) == y[ids]) * w)
             if data_axis is not None and n_data > 1:
                 grads, lsum, correct = jax.lax.psum((grads, lsum, correct), data_axis)
@@ -765,23 +782,26 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
 
         def step(carry, t):
             p, opt, acc = carry
-            s = t % S
-            lab = jax.lax.dynamic_slice(rows_p, (0, s * bptt), (R, bptt))
-            w = jax.lax.dynamic_slice(wpos, (0, s * bptt), (R, bptt))
-            batch = {"label": lab}
-            extra = {}
-            if seq_sharded:
-                d = jax.lax.axis_index(data_axis)
-                off = d * s_loc
-                lab = jax.lax.dynamic_slice(lab, (0, off), (R, s_loc))
-                w = jax.lax.dynamic_slice(w, (0, off), (R, s_loc))
-                batch = {"label": lab, "pos_offset": off, "seq_full": bptt}
-                extra = {"attn_override": lambda q, k, v, temp: attn(q, k, v, temperature=temp)}
+            with scope("step/batch"):
+                s = t % S
+                lab = jax.lax.dynamic_slice(rows_p, (0, s * bptt), (R, bptt))
+                w = jax.lax.dynamic_slice(wpos, (0, s * bptt), (R, bptt))
+                batch = {"label": lab}
+                extra = {}
+                if seq_sharded:
+                    d = jax.lax.axis_index(data_axis)
+                    off = d * s_loc
+                    lab = jax.lax.dynamic_slice(lab, (0, off), (R, s_loc))
+                    w = jax.lax.dynamic_slice(w, (0, off), (R, s_loc))
+                    batch = {"label": lab, "pos_offset": off, "seq_full": bptt}
+                    extra = {"attn_override": lambda q, k, v, temp: attn(q, k, v, temperature=temp)}
 
             def loss_fn(pt):
-                out, _ = model.apply(pt, batch, train=True, width_rate=wr,
-                                     scaler_rate=sr, label_mask=lm, sample_weight=w,
-                                     rng=jax.random.fold_in(key, 5000 + t), **extra)
+                # inside the differentiated function (see _local_train_vision)
+                with scope("step/model"):
+                    out, _ = model.apply(pt, batch, train=True, width_rate=wr,
+                                         scaler_rate=sr, label_mask=lm, sample_weight=w,
+                                         rng=jax.random.fold_in(key, 5000 + t), **extra)
                 # weighted-SUM form so the cross-shard reduction recovers the
                 # exact full-window mean gradient
                 n_loc = jnp.sum(w)
@@ -789,7 +809,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
 
             # per-leaf grads even under the flat carry (see _local_train_vision)
             (lsum, n_loc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                spec.unflatten(p) if spec is not None else p)
+                _leaf_views(spec, p))
             if seq_sharded:
                 grads, lsum, n_glob = jax.lax.psum((grads, lsum, n_loc), data_axis)
             else:
@@ -856,20 +876,22 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                 lambda u: jax.random.bernoulli(jax.random.fold_in(fkey, u), failure_rate)
             )(ugid).astype(jnp.float32)
             valid = valid * alive
-        uidx = None if user_loc is None else jnp.maximum(user_loc, 0)
-        if dynamic:
-            # the shared per-round rate stream (fed.core.round_rates):
-            # re-roll ALL users, index the active ones (ref fed.py:15-24)
-            rates_abs = round_rates(key, cfg, ugid)
-        else:
-            rates_abs = data[-1][ugid]  # fix_rates passed as last data arg
-        wr = rates_abs / self.global_rate
-        slot_keys = client_stream_keys(key, ugid)
+        with scope("round/gather"):
+            uidx = None if user_loc is None else jnp.maximum(user_loc, 0)
+            if dynamic:
+                # the shared per-round rate stream (fed.core.round_rates):
+                # re-roll ALL users, index the active ones (ref fed.py:15-24)
+                rates_abs = round_rates(key, cfg, ugid)
+            else:
+                rates_abs = data[-1][ugid]  # fix_rates passed as last data arg
+            wr = rates_abs / self.global_rate
+            slot_keys = client_stream_keys(key, ugid)
 
         if self.is_lm:
             all_rows, all_lm = data[0], data[1]
-            rows = all_rows if uidx is None else all_rows[uidx]
-            lm = all_lm if uidx is None else all_lm[uidx]
+            with scope("round/gather"):
+                rows = all_rows if uidx is None else all_rows[uidx]
+                lm = all_lm if uidx is None else all_lm[uidx]
             n_data = mesh.shape["data"]
             if self._sched_spec.has_deadline:
                 # deadline stragglers (ISSUE 9): per-client step budgets
@@ -879,42 +901,47 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                     int(rows.shape[-1]), self.bptt)
                 limits = deadline_steps(key, ugid, total_steps,
                                         self._sched_spec.deadline_min_frac)
-                trained, ms = jax.vmap(
-                    lambda w_, r_, l_, k_, lim_: self._local_train_lm(
-                        params, w_, r_, l_, k_, lr,
-                        data_axis="data" if n_data > 1 else None,
-                        n_data=n_data, step_limit=lim_)
-                )(wr, rows, lm, slot_keys, limits)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda w_, r_, l_, k_, lim_: self._local_train_lm(
+                            params, w_, r_, l_, k_, lr,
+                            data_axis="data" if n_data > 1 else None,
+                            n_data=n_data, step_limit=lim_)
+                    )(wr, rows, lm, slot_keys, limits)
             else:
-                trained, ms = jax.vmap(
-                    lambda w_, r_, l_, k_: self._local_train_lm(
-                        params, w_, r_, l_, k_, lr,
-                        data_axis="data" if n_data > 1 else None, n_data=n_data)
-                )(wr, rows, lm, slot_keys)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda w_, r_, l_, k_: self._local_train_lm(
+                            params, w_, r_, l_, k_, lr,
+                            data_axis="data" if n_data > 1 else None, n_data=n_data)
+                    )(wr, rows, lm, slot_keys)
         else:
             all_x, all_y, all_m, all_lm = data[0], data[1], data[2], data[3]
             if uidx is None:
                 xs, ys, sms, lm = all_x, all_y, all_m, all_lm
             else:
-                xs, ys, sms, lm = all_x[uidx], all_y[uidx], all_m[uidx], all_lm[uidx]
+                with scope("round/gather"):
+                    xs, ys, sms, lm = all_x[uidx], all_y[uidx], all_m[uidx], all_lm[uidx]
             n_data = mesh.shape["data"]
             if self._sched_spec.has_deadline:
                 total_steps = self.local_epochs * _ceil_div(
                     int(xs.shape[1]), self.batch_size)
                 limits = deadline_steps(key, ugid, total_steps,
                                         self._sched_spec.deadline_min_frac)
-                trained, ms = jax.vmap(
-                    lambda w_, x_, y_, m_, l_, k_, lim_: self._local_train_vision(
-                        params, w_, x_, y_, m_, l_, k_, lr,
-                        data_axis="data" if n_data > 1 else None,
-                        n_data=n_data, step_limit=lim_)
-                )(wr, xs, ys, sms, lm, slot_keys, limits)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda w_, x_, y_, m_, l_, k_, lim_: self._local_train_vision(
+                            params, w_, x_, y_, m_, l_, k_, lr,
+                            data_axis="data" if n_data > 1 else None,
+                            n_data=n_data, step_limit=lim_)
+                    )(wr, xs, ys, sms, lm, slot_keys, limits)
             else:
-                trained, ms = jax.vmap(
-                    lambda w_, x_, y_, m_, l_, k_: self._local_train_vision(
-                        params, w_, x_, y_, m_, l_, k_, lr,
-                        data_axis="data" if n_data > 1 else None, n_data=n_data)
-                )(wr, xs, ys, sms, lm, slot_keys)
+                with scope("round/local_train"):
+                    trained, ms = jax.vmap(
+                        lambda w_, x_, y_, m_, l_, k_: self._local_train_vision(
+                            params, w_, x_, y_, m_, l_, k_, lr,
+                            data_axis="data" if n_data > 1 else None, n_data=n_data)
+                    )(wr, xs, ys, sms, lm, slot_keys)
 
         if self._poison is not None:
             # chaos NaN poison (ISSUE 15): the matched (round, uid) slots'
@@ -929,9 +956,10 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
 
             trained = poison_updates(trained, self._poison, epoch, user_glob)
         shapes = {k: v.shape for k, v in params.items()}
-        cms = jax.vmap(lambda w_, l_, v_: jax.tree_util.tree_map(
-            lambda m: m * v_, make_count_masks(shapes, model.specs, model.groups, w_, l_)))(
-            wr, lm, valid)
+        with scope("round/aggregate"):
+            cms = jax.vmap(lambda w_, l_, v_: jax.tree_util.tree_map(
+                lambda m: m * v_, make_count_masks(shapes, model.specs, model.groups, w_, l_)))(
+                wr, lm, valid)
         ok = None
         if self._quarantine.enabled:
             # client-update quarantine (ISSUE 15 tentpole): the gate folds
@@ -951,38 +979,41 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             trained = {k: jnp.where(ok.reshape((-1,) + (1,) * (v.ndim - 1)),
                                     v, jnp.zeros((), v.dtype))
                        for k, v in trained.items()}
-        summed = {k: jnp.sum(trained[k] * cms[k], axis=0) for k in params}
-        counts = {k: jnp.sum(cms[k], axis=0) for k in params}
-        codec = self._codec(params)
-        if codec is None:
-            # ONE psum bind for sums+counts: the round's single global
-            # collective (per-leaf addends are identical to two separate
-            # psums, so this is bit-compatible; staticcheck audits the
-            # exactly-one-psum budget)
-            summed, counts = jax.lax.psum((summed, counts), "clients")
-            new_resid = None
-        else:
-            # wire codec (ISSUE 8): quantise this device's partial -> the
-            # SAME single psum bind carries the packed payload -> dequantise;
-            # the error-feedback residual re-injects the compression error
-            # next round.  cmax = this device's slot count (it bounds the
-            # partial-sum magnitude, sizing the shared quantisation grid).
-            from ..compress.codecs import compressed_psum
+        with scope("round/aggregate"):
+            summed = {k: jnp.sum(trained[k] * cms[k], axis=0) for k in params}
+            counts = {k: jnp.sum(cms[k], axis=0) for k in params}
+            codec = self._codec(params)
+            if codec is None:
+                # ONE psum bind for sums+counts: the round's single global
+                # collective (per-leaf addends are identical to two separate
+                # psums, so this is bit-compatible; staticcheck audits the
+                # exactly-one-psum budget)
+                with scope("psum"):
+                    summed, counts = jax.lax.psum((summed, counts), "clients")
+                new_resid = None
+            else:
+                # wire codec (ISSUE 8): quantise this device's partial -> the
+                # SAME single psum bind carries the packed payload -> dequantise;
+                # the error-feedback residual re-injects the compression error
+                # next round.  cmax = this device's slot count (it bounds the
+                # partial-sum magnitude, sizing the shared quantisation grid).
+                from ..compress.codecs import compressed_psum
 
-            summed, counts, new_resid = compressed_psum(
-                codec, "clients", params, summed, counts, resid, key,
-                int(user_glob.shape[0]))
-        if self._sched_spec.buffered:
-            # buffered-async aggregation (ISSUE 9): this cohort's reduction
-            # lands NEXT round (staleness-weighted); the previous round's
-            # buffered update applies now.  The single-psum wire contract
-            # is untouched -- buffering happens after the reduction.
-            new_params, new_buf = buffered_combine(
-                params, sched_buf, summed, counts, FlatSpec.of(params),
-                self._sched_spec.staleness)
-        else:
-            new_params = combine_counted(params, summed, counts)
-            new_buf = None
+                with scope("psum"):
+                    summed, counts, new_resid = compressed_psum(
+                        codec, "clients", params, summed, counts, resid, key,
+                        int(user_glob.shape[0]))
+            if self._sched_spec.buffered:
+                # buffered-async aggregation (ISSUE 9): this cohort's reduction
+                # lands NEXT round (staleness-weighted); the previous round's
+                # buffered update applies now.  The single-psum wire contract
+                # is untouched -- buffering happens after the reduction.
+                new_params, new_buf = buffered_combine(
+                    params, sched_buf, summed, counts, FlatSpec.of(params),
+                    self._sched_spec.staleness)
+            else:
+                new_params = combine_counted(params, summed, counts)
+                new_buf = None
         if ok is not None:
             # a quarantined client's metric sums may themselves be NaN
             # (its training diverged): select-sanitise, then mask like a

@@ -29,7 +29,7 @@ Four pieces remove that tax:
   ``fetch_every`` rounds (default 1 = reference parity), so round ``t+1``
   dispatches while round ``t``'s sums transfer, and ``flush()`` drains at
   eval boundaries (and before the driver exits).
-* :class:`PhaseTimer` -- wall-clock stage/dispatch/compute/fetch breakdown,
+* :class:`PhaseTimer` -- wall-clock sample/stage/dispatch/fetch breakdown,
   threaded into ``bench.py``'s ``extra`` dict and the fed drivers' per-round
   info line, so placement regressions show up as a phase shift instead of
   an undifferentiated slowdown.
@@ -308,11 +308,14 @@ class PhaseTimer:
 
     Phases are free-form names; the engines use ``stage`` (host packing +
     placement-cache lookups), ``dispatch`` (program calls returning) and
-    ``fetch`` (D2H metric assembly); the driver and bench.py add
-    ``sample`` (the host cohort draw, ISSUE 11 -- its own phase so the
-    O(population) -> O(active) sampler win is visible per round instead of
-    hiding inside ``stage``) and bench.py ``compute``
-    (block_until_ready).  Cheap enough to leave always on.
+    ``fetch`` (D2H metric assembly -- where the host waits for the device);
+    the driver adds ``sample`` (the host cohort draw, ISSUE 11 -- its own
+    phase so the O(population) -> O(active) sampler win is visible per
+    round instead of hiding inside ``stage``).  The benchmark
+    reads ``sample`` + ``dispatch`` as ``host_ms.round`` and ``stage`` as
+    ``stage_ms.round``; device time has no phase here -- it is split by the
+    program's own scopes (``obs.trace.SCOPES``).  Cheap enough to leave
+    always on.
 
     ``trace`` (ISSUE 10): attach an :class:`~..obs.trace.TraceRecorder`
     and every finished phase is ALSO filed as a complete event on the

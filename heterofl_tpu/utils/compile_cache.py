@@ -80,12 +80,42 @@ def install_cache_counters() -> dict:
     return counters
 
 
+def key_scope_version() -> str:
+    """Fold ``obs.trace.SCOPE_VERSION`` into every persistent-cache key and
+    return the string folded in.
+
+    The cache key is computed from the program with its debug info stripped
+    (``jax_compilation_cache_include_metadata_in_key`` is off, and has to
+    stay off: line numbers are metadata, so every edit would recompile
+    every program).  ``jax.named_scope`` names ARE metadata: a program that
+    differs from a cached one only by a scope gets the cached executable,
+    whose device events carry the old names (measured on the v5e, PERF.md
+    PR 26).  jax's key has one slot for the embedding program,
+    ``cache_key.custom_hook``; the scope vocabulary's version goes there,
+    so a bump of the version misses the cache once and nothing else ever
+    does."""
+    from jax._src import cache_key
+
+    from ..obs.trace import SCOPE_VERSION
+
+    tag = f"heterofl_tpu.scopes.v{SCOPE_VERSION}"
+    if not hasattr(cache_key, "custom_hook"):  # a jax without the slot
+        import warnings
+
+        warnings.warn("jax's compile-cache key has no custom_hook: a cached "
+                      "program may carry stale scope names (PERF.md, PR 26)")
+        return ""
+    cache_key.custom_hook = lambda: tag
+    return tag
+
+
 def enable_persistent_cache(path: Optional[str] = None) -> str:
     """Point jax at a persistent compilation cache and return the dir.
 
     Safe to call before or after ``import jax``: the env var covers a
     not-yet-imported jax (and any child processes), and a live config
-    update covers an already-imported one.
+    update covers an already-imported one.  Every key of the cache carries
+    the scope vocabulary's version (:func:`key_scope_version`).
     """
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or path or default_cache_dir()
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", path)
@@ -94,6 +124,7 @@ def enable_persistent_cache(path: Optional[str] = None) -> str:
         import jax
 
         jax.config.update("jax_compilation_cache_dir", path)
+    key_scope_version()
     return path
 
 

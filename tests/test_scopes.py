@@ -1,0 +1,163 @@
+"""The round program names its parts (ISSUE 26): every scope of
+``obs.trace.SCOPES`` reaches the ``op_name`` metadata of the instructions it
+covers, autodiff splits ``step/model`` into forward and backward, the Pallas
+kernels carry their ``name=``, and a scope is a name only -- the optimised
+program is the same instruction for instruction with ``jax.named_scope``
+patched away.
+
+Compiles here run with the persistent compile cache OFF: metadata is outside
+the cache key, so a warm cache would hand back whichever of the two programs
+was compiled first (the cache trap of PERF.md).
+"""
+
+import collections
+import contextlib
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from heterofl_tpu.models import make_model
+from heterofl_tpu.obs import trace
+from heterofl_tpu.parallel import GroupedRoundEngine, RoundEngine, make_mesh
+from heterofl_tpu.parallel.evaluation import Evaluator
+from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns
+from heterofl_tpu.utils.compile_cache import no_persistent_cache
+
+from test_round import _lm_setup, _vision_setup
+
+ROUND = ["round/gather", "round/local_train", "round/aggregate",
+         "round/aggregate/psum"]
+STEP = ["step/batch", "step/unflatten", "step/update", "update/flatten",
+        "update/pack", "update/kernel", "update/unpack", "linear", "norm", "loss"]
+CASES = {
+    "vision": STEP + ["conv", "step/batch/augment"],
+    "lm": STEP + ["embed", "attn"],
+    "grouped": STEP + ["conv", "step/batch/augment"],
+}
+
+
+def _program(case):
+    """(jitted round program, its arguments) at a tiny size, Pallas update
+    kernel (interpreted on the CPU) in the step."""
+    if case == "lm":
+        cfg, data = _lm_setup()
+        users = np.arange(4, dtype=np.int32)
+    else:
+        cfg, _, data = _vision_setup()
+        users = np.array([0, 2, 4, 6], np.int32)
+    cfg["fused_update"] = "pallas"
+    key, lr, mesh = jax.random.key(0), np.float32(0.1), make_mesh(2, 1)
+    if case == "grouped":
+        eng = GroupedRoundEngine(cfg, mesh)
+        params = eng.global_model.init(jax.random.key(0))
+        rate = float(max(cfg["model_rate"]))
+        return eng._level_prog(rate, 2), (params, key, lr, users[:2], *data)
+    model = make_model(cfg)
+    eng = RoundEngine(model, cfg, mesh)
+    fix = (eng.fix_rates,) if eng.fix_rates is not None else ()
+    return eng._build_train(), (model.init(jax.random.key(0)), key, lr, users,
+                                users, *data, *fix)
+
+
+def _op_names(prog, args):
+    """The ``op_name`` of every located operation of the lowered program."""
+    text = prog.lower(*args).as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+def _opcode_counts(prog, args):
+    text = prog.lower(*args).compile().as_text()
+    return collections.Counter(
+        m.group(1) for m in re.finditer(
+            r"^\s+(?:ROOT )?\S+ = .*?\s([a-z][\w\-]*)\(", text, re.M))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_every_scope_reaches_the_op_names(case):
+    prog, args = _program(case)
+    # a leading slash: inside the scan body's own function the lowering
+    # writes paths relative to it, before XLA inlines the call
+    names = ["/" + n for n in _op_names(prog, args)]
+    for scope in ROUND + CASES[case]:
+        assert any(f"/{scope}/" in n for n in names), \
+            f"{case}: no op_name carries {scope!r}"
+    # entered inside the differentiated function: autodiff marks the sides
+    assert any("/jvp(step/model)/" in n for n in names)
+    assert any("/transpose(jvp(step/model))/" in n for n in names)
+    leaf = "conv" if case != "lm" else "embed"
+    assert any(f"/jvp(step/model)/{leaf}/" in n for n in names)
+    assert any(f"/transpose(jvp(step/model))/{leaf}/" in n for n in names)
+    # the optimizer tail is outside the differentiated function
+    assert not any("jvp(step/update" in n or "jvp(step/batch" in n for n in names)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_update_kernel_carries_its_name(case):
+    prog, args = _program(case)
+    kernels = [e.params["name"] for e in
+               iter_eqns(jax.make_jaxpr(prog)(*args).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert kernels and set(kernels) == {"fused_sgd"}, kernels
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_scope_is_a_name_and_nothing_else(case, monkeypatch):
+    """Instruction count per opcode of the optimised program, with and
+    without the scopes."""
+    with no_persistent_cache():
+        scoped = _opcode_counts(*_program(case))
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        plain_prog, plain_args = _program(case)
+        assert not any("round/" in n or "step/" in n
+                       for n in _op_names(plain_prog, plain_args))
+        plain = _opcode_counts(plain_prog, plain_args)
+    assert sum(scoped.values()) > 500
+    assert scoped == plain
+
+
+def test_eval_bodies_are_scoped():
+    cfg, ds, _ = _vision_setup()
+    model = make_model(cfg)
+    params = model.init(jax.random.key(0))
+    ev = Evaluator(model, cfg, make_mesh(2, 1))
+    x = ds["test"].data[:80].reshape(4, 20, 28, 28, 1)
+    y = ds["test"].target[:80].reshape(4, 20)
+    w = np.ones((4, 20), np.float32)
+    key = jax.random.key(0)
+    def scopes(prog, *args):  # relative inside a scan body (see above)
+        return ["/" + n for n in _op_names(prog, args)]
+
+    sbn = scopes(ev._build_sbn(), params, x, w)
+    assert any("/eval/sbn/while/" in n for n in sbn) and any("/norm/" in n for n in sbn)
+    bn = jax.eval_shape(ev._build_sbn(), params, x, w)
+    glob = scopes(ev._build_global(), params, bn, key, x, y, w)
+    assert any("/eval/global/while/" in n for n in glob) and any("/conv/" in n for n in glob)
+    users = scopes(ev._build_users(), params, bn, key, np.ones(2, np.float32),
+                   x.reshape(2, 2, 20, 28, 28, 1), y.reshape(2, 2, 20),
+                   w.reshape(2, 2, 20), np.ones((2, 10), np.float32))
+    assert any("/eval/users/" in n for n in users) and any("/loss/" in n for n in users)
+
+
+def test_the_vocabulary_is_closed():
+    assert len(set(trace.SCOPES)) == len(trace.SCOPES)
+    with pytest.raises(ValueError, match="Not valid scope"):
+        trace.scope("step/modle")
+    with pytest.raises(ValueError, match="Not valid scope"):
+        trace.scoped("convolution")
+    with trace.scope("round/gather"):
+        pass
+
+
+@pytest.mark.parametrize("kernel, module", [
+    ("fused_sgd", "fused_update"), ("masked_bn_fwd", "pallas_norm"),
+    ("masked_bn_bwd", "pallas_norm"), ("int8_pack", "quant")])
+def test_every_pallas_call_is_named(kernel, module):
+    import importlib
+    import inspect
+
+    src = inspect.getsource(importlib.import_module(f"heterofl_tpu.ops.{module}"))
+    assert kernel in trace.KERNELS and f'name="{kernel}"' in src
+    assert src.count("pallas_call(") == src.count("        name=")

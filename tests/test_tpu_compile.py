@@ -58,11 +58,14 @@ def _flat_size(model_name):
     return sum(v.size for v in shapes.values())
 
 
-def _compile(fn, *avals):
-    """Compile for the described chip, and see that Mosaic did."""
+def _compile(fn, *avals, kernels):
+    """Compile for the described chip, and see that Mosaic did and that the
+    kernels' ``name=`` reached the program (a device trace finds them by it)."""
     with no_persistent_cache():
         text = jax.jit(fn).lower(*avals).compile().as_text()
     assert "tpu_custom_call" in text  # no XLA stand-in, no interpreter
+    for name in kernels:
+        assert name in text, f"no instruction of the program carries {name!r}"
 
 
 @pytest.mark.parametrize("vmapped", [False, True], ids=["bare", "vmap10"])
@@ -90,7 +93,7 @@ def test_fused_sgd_kernel_compiles(one_chip, model_name, vmapped):
     if vmapped:
         fn = jax.vmap(step, in_axes=(0, 0, 0, 0, 0, None, 0))
     _compile(fn, sds((total,)), sds((total,)), sds((total,)), sds((total,)),
-             sds(()), lr, sds((), jnp.bool_))
+             sds(()), lr, sds((), jnp.bool_), kernels=("fused_sgd",))
 
 
 def test_quant_pack_kernel_compiles(one_chip):
@@ -102,7 +105,8 @@ def test_quant_pack_kernel_compiles(one_chip):
     key = jax.eval_shape(lambda: jax.random.key(0))
     key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
     _compile(lambda x, s, k: quantize_pack(x, s, k, 63, 64, mode="pallas",
-                                           interpret=False), flat, flat, key)
+                                           interpret=False), flat, flat, key,
+             kernels=("int8_pack",))
 
 
 @pytest.mark.parametrize("n,h,c", [(10, 32, 64), (10, 4, 512)],
@@ -121,6 +125,7 @@ def test_pallas_norm_compiles(one_chip, n, h, c):
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    _compile(grads, sds(n, h, h, c), sds(c), sds(c), sds(n))
+    bn = ("masked_bn_fwd", "masked_bn_bwd")
+    _compile(grads, sds(n, h, h, c), sds(c), sds(c), sds(n), kernels=bn)
     _compile(jax.vmap(grads), sds(SLOTS, n, h, h, c), sds(SLOTS, c),
-             sds(SLOTS, c), sds(SLOTS, n))
+             sds(SLOTS, c), sds(SLOTS, n), kernels=bn)
