@@ -17,8 +17,9 @@ the script with a traceback.  One chip, in order:
 3. the same entry again, resuming that checkpoint for one round more on the
    grouped engine, then ``entry.test_classifier_fed.main`` on the result;
 4. on a ``FedExperiment`` built as ``run_main`` builds it: the lowered round
-   program holds the Pallas kernel (``tpu_custom_call``), the layout pinner
-   is active, and a round of smallest-width clients leaves everything
+   program carries the parameter and momentum leaves through the step (none
+   of the flat carry's plumbing scopes, no ``tpu_custom_call``), the layout
+   pinner is active, and a round of smallest-width clients leaves everything
    outside their slice bit-for-bit untouched.
 
 ``--chips 4`` runs none of that: only the flagship round on the default 4x1
@@ -271,21 +272,32 @@ def one_chip(out_dir):
 
 
 def check_round_program(exp):
-    """The K=1 program ``run_main``'s engine dispatches on this backend holds
-    the Pallas kernel (not the XLA tail, not interpret mode) and commits its
-    params through the layout pinner."""
+    """The K=1 program ``run_main``'s engine dispatches on this backend
+    carries the parameter and momentum leaves through the local step as the
+    model reads them (``fused_update: True`` resolves to the tree carry with
+    the per-leaf chain on a TPU, PR 27): none of the flat carry's plumbing
+    scopes, no update kernel; and it commits its params through the layout
+    pinner."""
     eng = exp.engine
-    text = eng._build_train().lower(*round_program_args(exp)).as_text()
-    if "tpu_custom_call" not in text:
-        raise AssertionError("round program holds no tpu_custom_call: the "
-                             "Pallas fused update was not selected")
-    if eng._fused_mode != "pallas":
-        raise AssertionError(f"fused mode {eng._fused_mode!r}, not 'pallas'")
+    if eng._fused_mode is not None:
+        raise AssertionError(f"fused mode {eng._fused_mode!r} on the TPU "
+                             "backend, not the tree carry (None)")
+    text = eng._build_train().lower(*round_program_args(exp)).as_text(
+        debug_info=True)
+    for scope in ("update/flatten", "update/pack", "update/unpack",
+                  "step/unflatten"):
+        if scope in text:
+            raise AssertionError(f"round program holds the flat carry's {scope}")
+    if "step/update" not in text or "step/model" not in text:
+        raise AssertionError("round program lost its step/update or step/model scope")
+    if "tpu_custom_call" in text:
+        raise AssertionError("round program holds a tpu_custom_call: no "
+                             "kernel belongs in the tree-carry step")
     if not eng._pin.active:
         raise AssertionError("ParamPinner inactive on the TPU backend")
-    print("chip_smoke: round program holds tpu_custom_call; fused mode "
-          f"{eng._fused_mode}; ParamPinner.active {eng._pin.active}",
-          flush=True)
+    print("chip_smoke: round program carries the leaves (fused mode "
+          f"{eng._fused_mode}), no plumbing scope, no tpu_custom_call; "
+          f"ParamPinner.active {eng._pin.active}", flush=True)
 
 
 def check_masked_suffix(exp):

@@ -113,17 +113,22 @@ DEFAULT_CFG: Dict[str, Any] = {
     # lax.scan unroll factor for the local-step loop (1 = no unrolling);
     # latency-bound rounds can gain from fewer loop trips (not measured)
     "scan_unroll": 1,
-    # fused masked-SGD optimizer epilogue + flat scan carry
-    # (ops/fused_update.py): collapse the per-step grad normalise/mask/clip/
-    # momentum/update/has-gate tail into one fused primitive and carry
-    # params/momentum through the local-step scan as single lane-packed
-    # buffers.  True = Pallas TPU kernel on TPU, flat-carry XLA fallback
-    # elsewhere; False = the seed program (tree carry + reference op chain);
-    # "xla"/"pallas" force an implementation.  The primitive and the
-    # engines' STEP results are bit-identical to the reference chain
-    # (tests/test_fused_update.py); long multi-step trajectories agree at
-    # float-association level, like the masked-vs-sliced engine contract.
-    # Non-SGD optimizers always use the reference chain.
+    # the local step's optimizer epilogue and the layout the scan carries
+    # params/momentum in (ops/fused_update.py).  True = chosen by the
+    # backend the program is compiled for: on a TPU the tree carry with the
+    # per-leaf reference chain (the leaves where the model reads them; the
+    # Pallas kernel and its flat carry were what True meant there until PR
+    # 27, and the v5e showed the flat carry's flatten / pack / unpack /
+    # unflatten at 60.0 of 78.6 ms a ResNet-18 step and 107.9 of 129.4 ms an
+    # LM step, the kernel itself at 8.2 / 14.0: ledger, PR 26); elsewhere
+    # the flat-carry XLA form (one lane-packed buffer each through the
+    # scan).  False = the seed program (tree carry + reference op chain) on
+    # every backend; "xla"/"pallas" force a flat-carry implementation.  The
+    # primitive and the engines' STEP results are bit-identical to the
+    # reference chain on the CPU (tests/test_fused_update.py); long
+    # multi-step trajectories agree at float-association level, like the
+    # masked-vs-sliced engine contract.  Non-SGD optimizers always use the
+    # reference chain.
     "fused_update": True,
     # explicit layout policy (models/layout.py): "auto" pins the params
     # carry's device layouts (row-major; width axes lane-packed minor-most)

@@ -1,34 +1,44 @@
 """Fused masked-SGD optimizer epilogue + flat scan carry for the hot step.
 
 The local-step tail of both round engines (``parallel/round_engine.py``,
-``_local_train_vision``/``_local_train_lm``) was a long chain of tiny
-elementwise ops executed 250 times per round: grad mean-normalise, width
-``param_mask`` multiply, ``clip_by_global_norm``, the SGD momentum /
-weight-decay update, and (vision) the two ``has``-gated ``jnp.where``
-tree_maps that skip all-padding batches -- all PER LEAF, and the
-``lax.scan`` carried every param/momentum leaf separately (one loop-carry
-copy + several kernels per leaf per step).  At HeteroFL's shapes the round
-is per-step-LATENCY-bound, not FLOP-bound (MEASUREMENTS.md: ~20 ms/step,
-BN stack ~35-40%, bf16 buys nothing), so every extra kernel in the scan
-body is a direct tax on the critical path -- the kernel-layer twin of the
-comms overheads targeted by arXiv:1610.05492.
+``_local_train_vision``/``_local_train_lm``) is a chain of elementwise ops
+executed every local step: grad mean-normalise, width ``param_mask``
+multiply, ``clip_by_global_norm``, the SGD momentum / weight-decay update,
+and (vision) the two ``has``-gated ``jnp.where`` tree_maps that skip
+all-padding batches -- all PER LEAF, with the ``lax.scan`` carrying every
+param/momentum leaf separately.  This module holds the other form: the
+tail as one primitive over ONE flattened-tree buffer.
 
-``cfg['fused_update']`` replaces that tail with a fused masked-update
-primitive over ONE flattened-tree buffer:
+What the chip said about the two (TPU v5e, the benchmark's cells; ledger,
+PR 26): the idea here was that the round is per-step-latency-bound
+("~20 ms/step", MEASUREMENTS.md, an older tree) and that every kernel
+taken out of the scan body is time won.  Measured, the flat carry COSTS
+the step: flatten / pack / unpack / unflatten (``carry_ms.step``) were
+60.0 of the 78.6 ms ResNet-18 step and 107.9 of the LM's 129.4, the Pallas
+kernel itself 8.2 / 14.0 ms at 328-330 GB/s (40 % of 819 GB/s), the model
+8.7 / 3.7.  A leaf view of a 1-D buffer is a copy on a tiled layout, a
+``[rows, 128]`` reshape with rows no multiple of 8 is a relayout, and the
+``vmap`` over ten client slots pads the kernel's slot axis to 16.  So
+since PR 27 ``cfg['fused_update']: True`` resolves on a TPU to the tree
+carry with the per-leaf chain (:func:`resolve_fused_mode`), and the forms
+below run where they are asked for by name, and off the TPU:
 
 * :class:`FlatSpec` packs a param tree into a single contiguous f32 vector
-  (row-major leaf order; each leaf a contiguous segment, so per-leaf views
-  are zero-copy slices).  The engines carry ``(params_flat, momentum_flat)``
+  (row-major leaf order; each leaf a contiguous segment; a per-leaf view is
+  a slice + reshape -- free on XLA:CPU, a copy per leaf per step on the
+  TPU's tiled layouts).  The engines carry ``(params_flat, momentum_flat)``
   through the scan -- the carry tuple shrinks from O(leaves) to O(1)
-  buffers with a pinned packed layout, and the model fwd/bwd sees ordinary
-  leaf views unflattened inside the step.
+  buffers, and the model fwd/bwd sees ordinary leaf views unflattened
+  inside the step.  Its once-a-round users (the wire codecs, the scheduler's
+  buffer, ``ops/quant.py``, ``staticcheck/audit.py``) are outside the scan.
 * ``'xla'`` (what ``True`` resolves to off-TPU): every numeric op of the
   epilogue stays PER-LEAF -- literally the reference chain's ops on the
   reference chain's arrays (a reduce over a flat-buffer view and a
   flat-concat elementwise tail were both measured to lower with a
-  different association/contraction on XLA:CPU) -- and the fusion win
-  comes from the flat carry alone.  Bit-identity vs the reference chain
-  is proven by tests for the full engine matrix at the repo's standard
+  different association/contraction on XLA:CPU) -- and only the carry is
+  flat (no gain from it was ever measured on a chip).  Bit-identity vs the
+  reference chain is proven by tests for the full engine matrix at the
+  repo's standard
   test config (conv + transformer; masked x replicated/sharded, grouped
   x span/slices, K in {1, 8}, with/without the eval mask).  On much
   deeper bodies (ResNet-18: 56 leaves, ~400 fusions/step) XLA's global
@@ -36,7 +46,7 @@ primitive over ONE flattened-tree buffer:
   body, which SGD then amplifies chaotically -- a single local step is
   still bitwise exact (pinned by test), multi-round trajectories agree
   the way the masked-vs-sliced engines do (float association level).
-* ``'pallas'`` (what ``True`` resolves to on TPU): a flattened-tree Pallas
+* ``'pallas'`` (only by name since PR 27): a flattened-tree Pallas
   TPU kernel over the lane-packed ``[rows, 128]`` reshape -- phase 0
   accumulates the global-norm sum of squares in an SMEM scalar (the
   two-phase reduction), phase 1 is the single elementwise update pass.
@@ -70,16 +80,21 @@ LANE = 128
 def resolve_fused_mode(cfg: Dict[str, Any]) -> Optional[str]:
     """Map ``cfg['fused_update']`` to an implementation name or None.
 
-    ``True`` (the default) resolves by backend: the Pallas kernel on TPU,
-    the XLA fallback elsewhere.  ``False`` keeps the reference op chain.
-    Non-SGD optimizers always keep the reference chain (the fused primitive
-    implements exactly torch-parity SGD momentum + weight decay).
+    ``True`` (the default) resolves by the backend the program is compiled
+    for: on TPU to None -- the tree carry with the per-leaf reference chain,
+    the leaves where the model reads them and the gradients arrive (the
+    module docstring has what the flat carry cost on the v5e) -- and to the
+    flat-carry XLA form elsewhere.
+    ``False`` keeps the reference op chain on every backend; ``"xla"`` /
+    ``"pallas"`` force a flat-carry implementation.  Non-SGD optimizers
+    always keep the reference chain (the fused primitive implements exactly
+    torch-parity SGD momentum + weight decay).
     """
     fu = cfg.get("fused_update", True)
     if not fu or cfg.get("optimizer_name") != "SGD":
         return None
     if fu is True:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return None if jax.default_backend() == "tpu" else "xla"
     if fu in ("xla", "pallas"):
         return fu
     raise ValueError(f"Not valid fused_update: {fu!r} "
@@ -137,9 +152,9 @@ def _xla_flat(spec, pf, grads, bf, masks, denom, lr, momentum, wd, max_norm,
     # ops on the reference chain's arrays, so the whole update is the same
     # f32 bit pattern by construction (both a reduce over a flat-buffer
     # view and a flat-concat elementwise tail were measured to lower with
-    # different association/contraction on XLA:CPU); the fusion win comes
-    # from the FLAT CARRY (O(1) loop-carried buffers instead of O(leaves),
-    # zero-copy leaf views in, one flatten out)
+    # different association/contraction on XLA:CPU); only the CARRY is flat
+    # (O(1) loop-carried buffers instead of O(leaves); leaf views in, one
+    # flatten out -- copies on the chip, see the module docstring)
     with scope("update/kernel"):  # the per-leaf chain stands in for the kernel
         pt, bt = spec.unflatten(pf), spec.unflatten(bf)
         gm = {k: (grads[k] / denom) * masks[k] for k in spec.names}

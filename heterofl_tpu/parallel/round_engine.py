@@ -416,9 +416,10 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         # overhead; 1 = no unrolling (identical program)
         self.scan_unroll = int(cfg.get("scan_unroll", 1) or 1)
         self._opt_init, self._opt_update = make_optimizer(cfg)
-        # fused masked-SGD epilogue (ISSUE 5 tentpole): None = the reference
-        # op chain; 'xla'/'pallas' = ops/fused_update.py.  Resolved once at
-        # construction so the scan body is shape-stable per engine.
+        # the step's carry layout and epilogue: None = tree carry + the
+        # reference op chain (a TPU's default, PR 27); 'xla'/'pallas' = flat
+        # carry + ops/fused_update.py.  Resolved once at construction so the
+        # scan body is shape-stable per engine.
         self._fused_mode = resolve_fused_mode(cfg)
         self._momentum = cfg.get("momentum", 0.0)
         self._weight_decay = cfg.get("weight_decay", 0.0)
@@ -582,17 +583,28 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         """(scan-carry params, opt state, FlatSpec-or-None, epilogue masks)
         for one client's local run.
 
-        With the fused epilogue on, the params and momentum buffers ride
-        the ``lax.scan`` carry as ONE lane-packed flat f32 buffer each
-        (ops/fused_update.py FlatSpec) -- the carry shrinks from O(leaves)
-        loop-carried buffers to O(1) with a pinned packed layout, the model
-        fwd/bwd consumes leaf views unflattened inside the step (views; on
-        the chip a copy per leaf, see ``carry_ms.step``; it is
+        ``_fused_mode`` None (what ``fused_update: True`` resolves to on a
+        TPU, and ``False`` everywhere): the scan carries the parameter and
+        momentum TREES, each leaf in the layout the model reads it in and
+        its gradient arrives in; ``_leaf_views`` is the identity and
+        ``_apply_update`` runs the reference chain per leaf in place.
+
+        ``'xla'`` / ``'pallas'`` (the flat carry; ``True`` off the TPU):
+        the params and momentum buffers ride the ``lax.scan`` carry as ONE
+        flat f32 buffer each (ops/fused_update.py FlatSpec) -- O(1)
+        loop-carried buffers instead of O(leaves); the model fwd/bwd
+        consumes leaf views unflattened inside the step (it is
         differentiated w.r.t. those views, so the per-leaf grads and norm
         terms are the reference chain's), and the optimizer tail runs in
-        the flat domain.  ``masks`` are the hoisted loop-invariant
-        grad masks, or None under the ``_masks_in_body`` regression
-        knob."""
+        the flat domain.  The views are NOT free on the chip: every step
+        copies each leaf out of the 1-D buffer's tiled layout, and the
+        kernel's operands are flattened, padded and reshaped to [rows, 128]
+        and back -- ``carry_ms.step`` 60.0 of 78.6 ms a ResNet-18 step,
+        107.9 of 129.4 ms an LM step on the v5e (ledger, PR 26), which is
+        why a TPU no longer resolves to it (PR 27).
+
+        ``masks`` are the hoisted loop-invariant grad masks, or None under
+        the ``_masks_in_body`` regression knob."""
         gmasks = None if self._masks_in_body else \
             self._grad_masks({k: v.shape for k, v in p.items()}, wr)
         if self._fused_mode is None:
@@ -612,10 +624,11 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         all-padding batches).
 
         ``spec`` non-None selects the fused masked-SGD primitive over the
-        flat carry (ops/fused_update.py -- Pallas on TPU, flat XLA fallback
-        elsewhere, both bit-identical to this reference chain on the clip
-        decision and elementwise tail); None keeps the reference op chain
-        (non-SGD optimizers always do).  ``masks=None`` re-materialises
+        flat carry (ops/fused_update.py -- ``'xla'``, the default off the
+        TPU, or ``'pallas'``, both bit-identical to this reference chain on
+        the clip decision and elementwise tail); None keeps the reference
+        op chain on the carried leaves (the default on a TPU since PR 27;
+        non-SGD optimizers always).  ``masks=None`` re-materialises
         the masks here, inside the scan body (the ``_masks_in_body``
         regression knob)."""
         if spec is not None:

@@ -196,16 +196,25 @@ def test_zero_width_mask_rows_at_level_e(mode):
         assert np.all(np.asarray(fp[k])[inactive] == 0.0), k
 
 
-def test_resolve_fused_mode():
+@pytest.mark.parametrize("backend, on", [
+    # True resolves by backend: the flat XLA form on the CPU test mesh ...
+    ("cpu", "xla"),
+    # ... and the tree carry with the per-leaf chain on the chip (PR 27:
+    # the flat carry's plumbing was 76 % / 83 % of the step there)
+    ("tpu", None)])
+def test_resolve_fused_mode(backend, on, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_fused_mode({"fused_update": True,
+                               "optimizer_name": "SGD"}) == on
+    assert resolve_fused_mode({"optimizer_name": "SGD"}) == on  # the default
+    # everything else means the same on every backend
     assert resolve_fused_mode({"fused_update": False,
                                "optimizer_name": "SGD"}) is None
     assert resolve_fused_mode({"fused_update": True,
                                "optimizer_name": "Adam"}) is None
-    # True resolves by backend: xla on the CPU test mesh
-    assert resolve_fused_mode({"fused_update": True,
-                               "optimizer_name": "SGD"}) == "xla"
-    assert resolve_fused_mode({"fused_update": "pallas",
-                               "optimizer_name": "SGD"}) == "pallas"
+    for forced in ("xla", "pallas"):
+        assert resolve_fused_mode({"fused_update": forced,
+                                   "optimizer_name": "SGD"}) == forced
     with pytest.raises(ValueError, match="fused_update"):
         resolve_fused_mode({"fused_update": "turbo", "optimizer_name": "SGD"})
 

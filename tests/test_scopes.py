@@ -38,15 +38,24 @@ CASES = {
 }
 
 
+def _case(case):
+    """(cfg, cohort, data stacks) of a tiny case."""
+    if case == "lm":
+        cfg, data = _lm_setup()
+        return cfg, np.arange(4, dtype=np.int32), data
+    cfg, _, data = _vision_setup()
+    return cfg, np.array([0, 2, 4, 6], np.int32), data
+
+
+def _round_args(eng, params, users, data):
+    fix = (eng.fix_rates,) if eng.fix_rates is not None else ()
+    return (params, jax.random.key(0), np.float32(0.1), users, users, *data, *fix)
+
+
 def _program(case):
     """(jitted round program, its arguments) at a tiny size, Pallas update
     kernel (interpreted on the CPU) in the step."""
-    if case == "lm":
-        cfg, data = _lm_setup()
-        users = np.arange(4, dtype=np.int32)
-    else:
-        cfg, _, data = _vision_setup()
-        users = np.array([0, 2, 4, 6], np.int32)
+    cfg, users, data = _case(case)
     cfg["fused_update"] = "pallas"
     key, lr, mesh = jax.random.key(0), np.float32(0.1), make_mesh(2, 1)
     if case == "grouped":
@@ -56,9 +65,8 @@ def _program(case):
         return eng._level_prog(rate, 2), (params, key, lr, users[:2], *data)
     model = make_model(cfg)
     eng = RoundEngine(model, cfg, mesh)
-    fix = (eng.fix_rates,) if eng.fix_rates is not None else ()
-    return eng._build_train(), (model.init(jax.random.key(0)), key, lr, users,
-                                users, *data, *fix)
+    return eng._build_train(), _round_args(eng, model.init(jax.random.key(0)),
+                                           users, data)
 
 
 def _op_names(prog, args):
@@ -116,6 +124,51 @@ def test_a_scope_is_a_name_and_nothing_else(case, monkeypatch):
         plain = _opcode_counts(plain_prog, plain_args)
     assert sum(scoped.values()) > 500
     assert scoped == plain
+
+
+PLUMBING = ["update/flatten", "update/pack", "update/unpack", "step/unflatten"]
+
+
+@pytest.mark.parametrize("case", ["vision", "lm"])
+def test_the_step_on_the_chip_carries_the_leaves(case, monkeypatch):
+    """With the mode a TPU backend resolves ``fused_update: True`` to, the
+    K=1 round program's local-step scan carries one buffer per parameter
+    leaf and per momentum leaf, and nothing in it flattens, packs, unpacks
+    or unflattens them (ISSUE 27: that plumbing was 76 % / 83 % of the step
+    on the v5e)."""
+    cfg, users, data = _case(case)
+    assert cfg.get("fused_update", True) is True
+    cfg["layout_policy"] = "none"  # as every benchmark cell: no pin to probe
+    model = make_model(cfg)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        eng = RoundEngine(model, cfg, make_mesh(2, 1))
+    assert eng._fused_mode is None
+    params = model.init(jax.random.key(0))
+    prog, args = eng._build_train(), _round_args(eng, params, users, data)
+
+    names = ["/" + n for n in _op_names(prog, args)]
+    for gone in PLUMBING + ["update/kernel"]:
+        assert not any(f"/{gone}/" in n for n in names), \
+            f"{case}: an op_name carries {gone!r}"
+    for kept in ("step/update", "jvp(step/model)", "transpose(jvp(step/model))"):
+        assert any(f"/{kept}/" in n for n in names), f"{case}: no {kept!r}"
+
+    eqns = list(iter_eqns(jax.make_jaxpr(prog)(*args).jaxpr))
+    assert not any(e.primitive.name == "pallas_call" for e in eqns)
+    scans = [e for e in eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1  # the local-step loop, under the client vmap
+    nc, nk = scans[0].params["num_consts"], scans[0].params["num_carry"]
+    # [slots, *leaf] each: the vmapped clients' axis leads
+    carried = collections.Counter(
+        tuple(v.aval.shape[1:]) for v in scans[0].invars[nc:nc + nk])
+    leaves = collections.Counter(tuple(v.shape) for v in params.values())
+    total = sum(int(np.prod(v.shape)) for v in params.values())
+    assert (total,) not in carried  # no flat buffer of FlatSpec.total
+    for shape, n in leaves.items():
+        assert carried[shape] == 2 * n, (shape, carried[shape], n)  # params + momentum
+    # what else rides: the optimizer's step counter and the three metric sums
+    assert sum(carried.values()) - 2 * len(params) == carried[()] == 4
 
 
 def test_eval_bodies_are_scoped():
