@@ -91,7 +91,8 @@ def module_table(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[in
     from ..models import RESNET_BLOCKS, make_model, scaled_hidden
 
     model = make_model(cfg, model_rate=model_rate)
-    params = model.init(jax.random.key(0))
+    # shapes only: a large family's init would allocate gigabytes for a count
+    params = jax.eval_shape(model.init, jax.random.key(0))
     psize = {k: int(np.prod(v.shape)) for k, v in params.items()}
     if batch_size is None:
         bs = cfg["batch_size"]["train"] if isinstance(cfg["batch_size"], dict) \
@@ -168,6 +169,32 @@ def module_table(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[in
         add("avgpool", (bs, h, w, in_planes), (bs, in_planes), 0, bs * h * w * in_planes)
         add("linear", (bs, in_planes), (bs, cfg["classes_size"]), mods("linear"),
             bs * in_planes * cfg["classes_size"])
+    elif kind == "kanana2":
+        # one row per matrix leaf (a linear's MACs = tokens x its size; a
+        # routed expert sees top_k / n_experts of the tokens) plus the two
+        # attention matmuls; norms, RoPE, softmax and the router's top-k are
+        # not matmul-like and are left out, as the benchmark's FLOP file
+        # (benchmark/flops/kanana2.py) leaves them out
+        a = cfg["kanana2"]
+        T, H = cfg["bptt"], a["num_attention_heads"]
+        ntok = bs * T
+        shapes = {k: tuple(v.shape) for k, v in params.items()}
+        share = a["num_experts_per_tok"] / a["n_routed_experts"]
+        for name in sorted(shapes):
+            shp = shapes[name]
+            if len(shp) != 2:
+                add(name, (bs, T, shp[0]), (bs, T, shp[0]), psize[name], ntok * shp[0] * 2)
+            elif name.startswith("embedding."):
+                add("embedding", (bs, T), (bs, T, shp[1]), psize[name], ntok * shp[1])
+            else:
+                toks = ntok * share if ".moe.e" in name else ntok
+                add(name[:-2] if name.endswith(".w") else name, (bs, T, shp[0]),
+                    (bs, T, shp[1]), psize[name], toks * shp[0] * shp[1])
+        for i in range(a["num_hidden_layers"]):
+            dq = (shapes[f"l{i}.attn.q.n.w"][1] + shapes[f"l{i}.attn.q.r.w"][1]) // H
+            dv = shapes[f"l{i}.attn.kv_b.v.w"][1] // H
+            add(f"l{i}.attn.qk", (bs, T, H * dq), (bs, H, T, T), 0, bs * H * T * (T + 1) // 2 * dq)
+            add(f"l{i}.attn.av", (bs, H, T, T), (bs, T, H * dv), 0, bs * H * T * (T + 1) // 2 * dv)
     else:  # transformer
         from ..config import ceil_width
 

@@ -39,7 +39,10 @@ CONTROL_KEYS = (
 # data/ import these rather than re-declaring them.
 NORM_TYPES = ("bn", "in", "ln", "gn", "none")
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
-               "resnet152", "transformer")
+               "resnet152", "transformer", "kanana2")
+#: the families that train on token rows (next- or masked-token loss): the
+#: drivers' and engines' LM paths key on this, not on one family's name
+LM_MODEL_NAMES = ("transformer", "kanana2")
 # Feature-axis value registries (ISSUE 18): THE declared domains of the
 # engine/placement/store/pod axes, consumed by the axis validators below and
 # by staticcheck's config-lattice pass (staticcheck/lattice.py enumerates
@@ -110,6 +113,16 @@ DEFAULT_CFG: Dict[str, Any] = {
     # become grouped convs); "im2col" = patch-extraction + batched matmul,
     # which keeps the client-vmapped hot path on dense MXU ops (ops/layers.py)
     "conv_impl": None,
+    # cohort chunking (ISSUE 28): how many client slots of a device train AT
+    # ONCE inside the round program.  None (default) = all of them, one vmap
+    # over the whole cohort -- every program byte-identical to the unchunked
+    # engines.  An int c trains c slots at a time: a lax.scan over slots/c
+    # chunks carries the aggregate's sums and counts, so the round holds c
+    # (not slots) copies of the masked model, its momentum and its gradients.
+    # The round's result is the unchunked round's (same per-slot streams,
+    # same single psum); a model whose one client is a third of the chip runs
+    # at c = 1.  Masked engine only; c must divide the slots a device holds.
+    "round_chunk": None,
     # lax.scan unroll factor for the local-step loop (1 = no unrolling);
     # latency-bound rounds can gain from fewer loop trips (not measured)
     "scan_unroll": 1,
@@ -457,6 +470,31 @@ def process_control(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "num_layers": 4,
         "dropout": 0.2,
     }
+    # Kanana-2-30B-A3B (model_type deepseek_v3): the published shape
+    # (huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json).
+    # ``expert_share`` = [index, of]: this process holds experts
+    # [index * n/of, (index + 1) * n/of) of every expert layer -- one chip's
+    # share of an ``of``-way expert-parallel deployment; the router keeps all
+    # ``n_routed_experts`` columns.  [0, 1] holds every expert.
+    cfg["kanana2"] = {
+        "hidden_size": 2048,
+        "num_hidden_layers": 48,
+        "first_k_dense_replace": 1,
+        "intermediate_size": 6144,
+        "moe_intermediate_size": 768,
+        "n_routed_experts": 128,
+        "n_shared_experts": 2,
+        "num_experts_per_tok": 6,
+        "routed_scaling_factor": 2.448,
+        "num_attention_heads": 32,
+        "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64,
+        "v_head_dim": 128,
+        "kv_lora_rank": 512,
+        "rope_theta": 1000000.0,
+        "rms_norm_eps": 1e-6,
+        "expert_share": [0, 1],
+    }
     # Per-dataset hyperparameters (ref src/utils.py:150-212).
     data_name = cfg["data_name"]
     split = cfg["data_split_mode"]
@@ -583,6 +621,7 @@ def validator_chain():
 
     return [
         ("resolve_strategy_cfg", resolve_strategy_cfg),
+        ("resolve_chunk_cfg", resolve_chunk_cfg),
         ("resolve_placement_cfg", resolve_placement_cfg),
         ("resolve_store_cfg", resolve_store_cfg),
         ("resolve_superstep_cfg", resolve_superstep_cfg),
@@ -609,6 +648,27 @@ def resolve_strategy_cfg(cfg: Dict[str, Any]) -> str:
         raise ValueError(f"Not valid strategy: {strategy!r} "
                          f"(one of {STRATEGIES})")
     return strategy
+
+
+def resolve_chunk_cfg(cfg: Dict[str, Any]):
+    """Validate ``cfg['round_chunk']`` and return it (ISSUE 28): None (all
+    slots at once) or an int >= 1.  THE one validator of the chunk axis; the
+    masked engine owns the chunked round core, so the other engines refuse
+    here instead of silently training the whole cohort at once."""
+    chunk = cfg.get("round_chunk")
+    if chunk is None:
+        return None
+    if not isinstance(chunk, int) or isinstance(chunk, bool) or chunk < 1:
+        raise ValueError(f"Not valid round_chunk: {chunk!r} (None = the "
+                         f"whole cohort at once, or an int >= 1 of slots "
+                         f"trained at a time)")
+    strategy = resolve_strategy_cfg(cfg)
+    if strategy != "masked":
+        raise ValueError(
+            f"Not valid round_chunk={chunk} with strategy={strategy!r}: the "
+            f"chunked cohort scan lives in the masked engine's round core; "
+            f"the {strategy} engine would silently train every slot at once")
+    return chunk
 
 
 def resolve_placement_cfg(cfg: Dict[str, Any]):
@@ -784,9 +844,10 @@ def resolve_eval_cohort(cfg: Dict[str, Any]):
             f"eager store already densifies the population, so its local "
             f"eval is O(num_users) either way -- eval_cohort needs "
             f"client_store='stream'")
-    if cfg.get("model_name") == "transformer":
+    if cfg.get("model_name") in LM_MODEL_NAMES:
         raise ValueError(
-            f"Not valid eval_cohort={ec} with model_name='transformer': "
+            f"Not valid eval_cohort={ec} with model_name="
+            f"{cfg.get('model_name')!r}: "
             f"eval_cohort samples the per-user Local eval, which only "
             f"vision experiments run (LM evaluates Global only)")
     return ec

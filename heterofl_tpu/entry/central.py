@@ -35,7 +35,7 @@ class CentralEngine:
         self.model = model
         self.cfg = cfg
         self.mesh = mesh
-        self.is_lm = model.meta.get("kind") == "transformer"
+        self.is_lm = model.is_lm
         self.norm_stats = cfg.get("norm_stats") or DATASET_STATS.get(cfg["data_name"])
         self.augment = cfg["data_name"].startswith("CIFAR")
         self._opt_init, self._opt_update = make_optimizer(cfg)
@@ -132,7 +132,7 @@ class CentralExperiment:
 
         _maybe_compute_norm_stats(cfg, self.dataset)
         self.tag = C.make_model_tag(seed, cfg)
-        self.kind = "transformer" if cfg["model_name"] == "transformer" else "vision"
+        self.kind = "transformer" if cfg["model_name"] in C.LM_MODEL_NAMES else "vision"
         self.model = make_model(cfg)
         self.mesh = make_mesh(len(jax.devices()), 1)
         self.engine = CentralEngine(self.model, cfg, self.mesh)

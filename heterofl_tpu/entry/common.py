@@ -225,7 +225,7 @@ class FedExperiment:
         self.cfg = cfg
         self.seed = seed
         self.tag = C.make_model_tag(seed, cfg)
-        self.kind = "transformer" if cfg["model_name"] == "transformer" else "vision"
+        self.kind = "transformer" if cfg["model_name"] in C.LM_MODEL_NAMES else "vision"
         self.rng = np.random.default_rng(seed)
         self.host_key = jax.random.key(seed)
 

@@ -68,16 +68,14 @@ def validate_width_geometry(model: ModelDef, cfg: Dict[str, Any]) -> None:
     silently degrades, here it would NaN).  Raises with the minimal fix."""
     rates = {float(r) / cfg["global_model_rate"] for r in cfg["model_rate"]}
     for name, g in model.groups.items():
-        if g.kind != "per_head":
-            continue
-        hd = g.size // g.num_heads
         for wr in sorted(rates):
-            if g.num_heads * math.ceil(hd * wr) != math.ceil(g.size * wr):
+            widths = g.rule.coupled_width(g, wr)
+            if widths is not None and widths[0] != widths[1]:
                 raise ValueError(
                     f"width geometry: group {name!r} (size {g.size}, "
                     f"{g.num_heads} heads) is inconsistent at rate {wr:g}: "
-                    f"per-head slice keeps {g.num_heads * math.ceil(hd * wr)} "
-                    f"dims but the width slice keeps {math.ceil(g.size * wr)}; "
+                    f"per-head slice keeps {widths[0]} "
+                    f"dims but the width slice keeps {widths[1]}; "
                     f"pick embedding_size so embedding*rate is a multiple-safe "
                     f"size (e.g. embedding_size*min_rate >= num_heads and "
                     f"head_dim divisible by 1/min_rate)")
@@ -280,7 +278,7 @@ def snap_to_levels(rates, levels, rtol: float = 1e-5, atol: float = 1e-8) -> np.
 #: (grad wrt inputs + grad wrt weights); everything else (norms, relu,
 #: pools) back-propagates at ~1x its forward cost.
 _MATMUL_LIKE = ("conv", "linear", "shortcut", "mha", "ff.l", "dec.l",
-                "embedding", "qk", "av")
+                "embedding", "qk", "av", "attn.", "mlp.", "moe.", "head")
 
 #: optimizer + width/label masking + clipping cost per parameter per step
 #: (SGD momentum update, weight decay, mask multiply, global-norm terms)
@@ -483,45 +481,15 @@ def combine_counted(global_params: Dict[str, jnp.ndarray],
 # power the mesh-native rate-grouped engine (parallel/grouped.py).
 # ---------------------------------------------------------------------------
 
-def _per_head_counts(group: Group, width_rate: float) -> tuple:
-    hd = group.size // group.num_heads
-    return hd, int(math.ceil(hd * width_rate))
-
-
 def slice_axis(v: jnp.ndarray, group: Group, width_rate: float, axis: int) -> jnp.ndarray:
-    """Slice one tensor axis to its active prefix at a static ``width_rate``."""
-    if group.kind == "full":
-        return v
-    if group.kind == "prefix":
-        k = int(math.ceil(group.size * width_rate))
-        return jax.lax.slice_in_dim(v, 0, k, axis=axis)
-    if group.kind == "per_head":
-        hd, kh = _per_head_counts(group, width_rate)
-        shp = v.shape
-        v = v.reshape(shp[:axis] + (group.num_heads, hd) + shp[axis + 1:])
-        v = jax.lax.slice_in_dim(v, 0, kh, axis=axis + 1)
-        return v.reshape(shp[:axis] + (group.num_heads * kh,) + shp[axis + 1:])
-    raise ValueError(group.kind)
+    """Slice one tensor axis to its active entries at a static ``width_rate``
+    (the group's own rule, ``models.spec.GROUP_RULES``)."""
+    return group.rule.slice(v, group, width_rate, axis)
 
 
 def pad_axis(v: jnp.ndarray, group: Group, width_rate: float, axis: int) -> jnp.ndarray:
     """Zero-pad one sliced axis back to full size (inverse of :func:`slice_axis`)."""
-    if group.kind == "full":
-        return v
-    pads = [(0, 0)] * v.ndim
-    if group.kind == "prefix":
-        k = int(math.ceil(group.size * width_rate))
-        pads[axis] = (0, group.size - k)
-        return jnp.pad(v, pads)
-    if group.kind == "per_head":
-        hd, kh = _per_head_counts(group, width_rate)
-        shp = v.shape
-        v = v.reshape(shp[:axis] + (group.num_heads, kh) + shp[axis + 1:])
-        pads = [(0, 0)] * v.ndim
-        pads[axis + 1] = (0, hd - kh)
-        v = jnp.pad(v, pads)
-        return v.reshape(shp[:axis] + (group.size,) + shp[axis + 1:])
-    raise ValueError(group.kind)
+    return group.rule.pad(v, group, width_rate, axis)
 
 
 def extract_sliced_jnp(params: Dict[str, jnp.ndarray], specs: Dict[str, ParamSpec],
@@ -554,16 +522,7 @@ def embed_sliced_jnp(sliced: Dict[str, jnp.ndarray], specs: Dict[str, ParamSpec]
 
 def active_indices(group: Group, width_rate: float) -> np.ndarray:
     """Concrete active index set of a group at a given rate (host-side)."""
-    if group.kind == "full":
-        return np.arange(group.size)
-    if group.kind == "prefix":
-        k = int(math.ceil(group.size * width_rate))
-        return np.arange(group.size)[:k]
-    if group.kind == "per_head":
-        hd = group.size // group.num_heads
-        kh = int(math.ceil(hd * width_rate))
-        return (np.arange(group.size).reshape(group.num_heads, hd)[:, :kh]).reshape(-1)
-    raise ValueError(group.kind)
+    return group.rule.indices(group, width_rate)
 
 
 def extract_sliced(params: Dict[str, np.ndarray], specs: Dict[str, ParamSpec],

@@ -41,7 +41,7 @@ class SlicedFederation:
         self.cfg = cfg
         self.global_rate = cfg["global_model_rate"]
         self.global_model = make_model(cfg)
-        self.is_lm = self.global_model.meta.get("kind") == "transformer"
+        self.is_lm = self.global_model.is_lm
         self.levels: Dict[float, Tuple[Any, Any]] = {}
         self._fns: Dict[float, Any] = {}
         for rate in sorted(set(float(r) for r in cfg["model_rate"]), reverse=True):

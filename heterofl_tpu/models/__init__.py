@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional
 from ..config import MODEL_NAMES, ceil_width, scaled_hidden  # noqa: F401
 from .base import ModelDef  # noqa: F401
 from .conv import make_conv
+from .kanana2 import make_kanana2
 from .resnet import make_resnet
 from .spec import Group, ParamSpec, count_masks, mask_params, param_mask  # noqa: F401
 from .transformer import make_transformer
@@ -33,10 +34,11 @@ RESNET_BLOCKS = {
 # the canonical registry lives in config (jax-free for analysis tooling); keep
 # it in lockstep with the families actually buildable here.  A hard raise, not
 # an assert: the guard must survive `python -O` (advisor r3).
-if MODEL_NAMES != ("conv",) + tuple(RESNET_BLOCKS) + ("transformer",):
+_BUILDABLE = ("conv",) + tuple(RESNET_BLOCKS) + ("transformer", "kanana2")
+if MODEL_NAMES != _BUILDABLE:
     raise ImportError(
         f"config.MODEL_NAMES {MODEL_NAMES!r} out of lockstep with buildable "
-        f"families {('conv',) + tuple(RESNET_BLOCKS) + ('transformer',)!r}")
+        f"families {_BUILDABLE!r}")
 
 
 def parse_compute_dtype(cd):
@@ -80,6 +82,9 @@ def make_model(cfg: Dict[str, Any], model_rate: Optional[float] = None) -> Model
             cfg["num_tokens"], ceil_width(t["embedding_size"], model_rate), t["num_heads"],
             ceil_width(t["hidden_size"], model_rate), t["num_layers"], t["dropout"],
             cfg["bptt"], cfg["mask_rate"], mask=cfg["mask"], compute_dtype=compute_dtype)
+    elif name == "kanana2":
+        model = make_kanana2(cfg["num_tokens"], cfg["kanana2"], model_rate,
+                             mask=cfg["mask"], compute_dtype=compute_dtype)
     else:
         raise ValueError("Not valid model name")
     model.meta["model_rate"] = model_rate

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List
 import jax
 import jax.numpy as jnp
 
+from ..config import LM_MODEL_NAMES as LM_KINDS
 from .spec import Group, ParamSpec
 
 
@@ -28,6 +29,11 @@ class ModelDef:
     groups: Dict[str, Group]
     bn_sites: List[str] = field(default_factory=list)  # prefixes carrying sBN state
     meta: Dict[str, Any] = field(default_factory=dict)
+
+    @property
+    def is_lm(self) -> bool:
+        """Trains on token rows (the engines' LM path), whatever the family."""
+        return self.meta.get("kind") in LM_KINDS
 
     def init_bn_state(self) -> Dict[str, Any]:
         """Zeroed running (mean, var) per BN site, matching fresh

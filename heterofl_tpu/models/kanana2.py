@@ -1,0 +1,295 @@
+"""Kanana-2-30B-A3B (``model_type: deepseek_v3``) with HeteroFL width scaling.
+
+The published block (huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601
+``config.json``): pre-norm decoder layers of multi-head LATENT attention
+(no query bottleneck; keys and values up-projected from a 512-wide latent
+with its own RMSNorm; 128 no-position + 64 rotary query/key dims a head, one
+rotary key head shared by all heads, interleaved RoPE) and a feed-forward
+that is a dense SwiGLU in the first ``first_k_dense_replace`` layers and, in
+the rest, ``n_routed_experts`` SwiGLU experts (sigmoid router, top-k,
+``noaux_tc`` selection bias, normalised and scaled weights) plus one SwiGLU
+of ``n_shared_experts * moe_intermediate_size`` that every token takes;
+RMSNorm, no biases, no dropout, untied embedding and head; next-token loss.
+``x`` is ``[T, D]``, ``rms(x, g) = x / sqrt(mean(x^2) + eps) * g``:
+
+  h = rms(x, g1); q = h Wq -> [T, H, dn + dr]; c, k_r = h Wkv_a -> [512], [dr]
+  c = rms(c, g_kv); [k_n | v] = c Wkv_b -> [T, H, dn + dv]
+  q_r, k_r = rope(., pos); scores = (q_n k_n^T + q_r k_r^T) / sqrt(dn + dr)
+  x = x + concat_heads(softmax_causal(scores) v) Wo
+  h = rms(x, g2); dense: x = x + (silu(h Wg) * (h Wu)) Wd
+  experts: s = sigmoid(h Wr); sel = top_k(s + b); w = s[sel] / sum * scale
+           x = x + sum_{e in sel, held} w_e expert_e(h) + shared(h)
+  logits = rms(x, g_f) W_head
+
+The expert layer is told what it holds (``expert_share = (index, of)``:
+experts ``[index * n/of, (index + 1) * n/of)``); it routes over all ``n`` and
+computes its own experts' part (``ops.layers.moe_experts``).  With
+``of == 1`` the model is the published one.
+
+HeteroFL slicing (the paper defines none for this family; stated in the
+benchmark configuration's ``assumed``): ``emb`` prefix of the hidden size
+(embedding columns, every norm gain, every matrix's model-side axis, router
+rows); per-head prefixes of the no-position, rotary (whole pairs) and value
+dims, so the published ``q_proj`` / ``kv_a_proj_with_mqa`` / ``kv_b_proj``
+are held as column-split leaves (``q.n`` | ``q.r``, ``kv_a.c`` | ``kv_a.r``,
+``kv_b.k`` | ``kv_b.v``: a column permutation of the published matrices);
+``kv_lora`` prefix of the latent with its masked norm; ``ffn`` / ``shared`` /
+``expert`` prefixes of the three feed-forward widths; the expert axis (one
+leaf per expert), the router's columns, its selection bias and the
+vocabulary are never sliced; embedding rows and head columns carry the label
+axis.  Softmax scale ``1/sqrt(active dn + active dr)``; a Scaler after every
+sliced linear except the router and the head (categorical outputs); none on
+the embedding look-up.
+
+Every expert is its own three leaves (``l{i}.moe.e{j}.{g,u,d}.w``), so a
+fan-in initialiser (``init`` below, ``benchmark/weights.py``) sees each
+expert's true fan-in.  ``apply`` stacks a layer's held experts for
+``moe_experts`` and the expert layers, which are alike, for one ``lax.scan``
+over them: the program holds one expert layer's code whatever the depth.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+
+from ..obs.trace import scope
+from ..ops.layers import (causal_latent_attention, cross_entropy, embed,
+                          linear as _linear, masked_logits, masked_rms_norm,
+                          moe_experts, moe_route, rope_interleaved, scaler,
+                          swiglu)
+from .base import ModelDef, normal_init, uniform_fan_in
+from .spec import Group, ParamSpec
+
+#: positions a block of the head-and-loss takes (memory, not mathematics)
+LOSS_BLOCK = 1024
+
+
+def held_experts(arch) -> range:
+    """The routed experts this share holds."""
+    index, of = (int(v) for v in arch["expert_share"])
+    n = int(arch["n_routed_experts"])
+    if of < 1 or n % of or not 0 <= index < of:
+        raise ValueError(f"Not valid expert_share: {arch['expert_share']!r} "
+                         f"(index, of) with of dividing n_routed_experts={n}")
+    return range(index * (n // of), (index + 1) * (n // of))
+
+
+def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
+                 mask: bool = True, compute_dtype=None) -> ModelDef:
+    """``arch``: ``cfg['kanana2']`` (config.process_control) at the GLOBAL widths;
+    ``model_rate`` builds the dense sub-model a client at that rate holds
+    (the sliced strategy and the equivalence tests)."""
+    from ..config import ceil_width
+
+    def cw(n, multiple=1):
+        k = ceil_width(n, model_rate)
+        return -(-k // multiple) * multiple
+
+    D = cw(arch["hidden_size"])
+    L, L_dense = int(arch["num_hidden_layers"]), int(arch["first_k_dense_replace"])
+    F, Fe = cw(arch["intermediate_size"]), cw(arch["moe_intermediate_size"])
+    Fs = cw(arch["moe_intermediate_size"] * arch["n_shared_experts"])
+    E, K = int(arch["n_routed_experts"]), int(arch["num_experts_per_tok"])
+    H = int(arch["num_attention_heads"])
+    dn, dv = cw(arch["qk_nope_head_dim"]), cw(arch["v_head_dim"])
+    dr = cw(arch["qk_rope_head_dim"], 2)
+    R = cw(arch["kv_lora_rank"])
+    # staticcheck: allow(no-float-coercion): build-time config scalars
+    theta, eps = float(arch["rope_theta"]), float(arch["rms_norm_eps"])
+    # staticcheck: allow(no-float-coercion): build-time config scalar
+    scaling = float(arch["routed_scaling_factor"])
+    held = held_experts(arch)
+    if dr % 2:
+        raise ValueError(f"rotary width {dr} is not whole pairs")
+
+    groups = {
+        "emb": Group("emb", D),
+        "q_nope": Group("q_nope", H * dn, kind="per_head", num_heads=H, coupled=False),
+        "q_rope": Group("q_rope", H * dr, kind="per_head", num_heads=H,
+                        multiple=2, coupled=False),
+        "k_rope": Group("k_rope", dr, kind="per_head", num_heads=1,
+                        multiple=2, coupled=False),
+        "v_head": Group("v_head", H * dv, kind="per_head", num_heads=H, coupled=False),
+        "kv_lora": Group("kv_lora", R),
+        "ffn": Group("ffn", F),
+        "shared": Group("shared", Fs),
+        "expert": Group("expert", Fe),
+        "router": Group("router", E, kind="full"),
+        "vocab": Group("vocab", num_tokens, kind="full"),
+    }
+
+    specs: Dict[str, ParamSpec] = {
+        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
+        "norm.g": ParamSpec({0: "emb"}),
+        "head.w": ParamSpec({0: "emb"}, label_axis=1),
+    }
+    shapes: Dict[str, tuple] = {
+        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens)}
+
+    def add(name, shape, axis_groups):
+        shapes[name] = shape
+        specs[name] = ParamSpec(axis_groups)
+
+    def add_ffn(prefix, width, group):
+        add(f"{prefix}.g.w", (D, width), {0: "emb", 1: group})
+        add(f"{prefix}.u.w", (D, width), {0: "emb", 1: group})
+        add(f"{prefix}.d.w", (width, D), {0: group, 1: "emb"})
+
+    for i in range(L):
+        p = f"l{i}"
+        add(f"{p}.norm1.g", (D,), {0: "emb"})
+        add(f"{p}.attn.q.n.w", (D, H * dn), {0: "emb", 1: "q_nope"})
+        add(f"{p}.attn.q.r.w", (D, H * dr), {0: "emb", 1: "q_rope"})
+        add(f"{p}.attn.kv_a.c.w", (D, R), {0: "emb", 1: "kv_lora"})
+        add(f"{p}.attn.kv_a.r.w", (D, dr), {0: "emb", 1: "k_rope"})
+        add(f"{p}.attn.kv_norm.g", (R,), {0: "kv_lora"})
+        add(f"{p}.attn.kv_b.k.w", (R, H * dn), {0: "kv_lora", 1: "q_nope"})
+        add(f"{p}.attn.kv_b.v.w", (R, H * dv), {0: "kv_lora", 1: "v_head"})
+        add(f"{p}.attn.o.w", (H * dv, D), {0: "v_head", 1: "emb"})
+        add(f"{p}.norm2.g", (D,), {0: "emb"})
+        if i < L_dense:
+            add_ffn(f"{p}.mlp", F, "ffn")
+        else:
+            add(f"{p}.moe.router.w", (D, E), {0: "emb", 1: "router"})
+            add(f"{p}.moe.router.b", (E,), {0: "router"})
+            add_ffn(f"{p}.moe.shared", Fs, "shared")
+            for j in held:
+                add_ffn(f"{p}.moe.e{j}", Fe, "expert")
+
+    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
+        names = sorted(shapes)
+        params = {}
+        for name, k in zip(names, jax.random.split(key, len(names))):
+            shape = shapes[name]
+            if len(shape) == 1:  # norm gains 1; the selection bias 0
+                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
+            elif name.startswith("embedding."):
+                params[name] = normal_init(k, shape, 1.0)
+            else:
+                params[name] = uniform_fan_in(k, shape, shape[0])
+        return params
+
+    linear = partial(_linear, compute_dtype=compute_dtype)
+
+    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
+              label_mask=None, bn_mode: str = "batch", bn_state=None,
+              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
+        if "pos_offset" in batch or attn_override is not None:
+            raise ValueError("kanana2 has no sequence-sharded path (mesh "
+                             "'data' axis must be 1)")
+        labels = batch["label"]
+        N, S = labels.shape
+        T = N * S
+        act = {g: groups[g].active_count(width_rate).astype(jnp.float32)
+               for g in ("emb", "kv_lora", "q_nope", "q_rope")}
+        emb_mask, lora_mask = groups["emb"].mask(width_rate), groups["kv_lora"].mask(width_rate)
+        scale = 1.0 / jnp.sqrt((act["q_nope"] + act["q_rope"]) / H)
+        pos = jnp.arange(S)
+
+        def sc(x):
+            return scaler(x, scaler_rate, train)
+
+        def rms(g, x):
+            return masked_rms_norm(x, g, emb_mask, act["emb"], eps)
+
+        def attention(lp, h):
+            with scope("mla"):
+                qn = sc(linear(h, lp["attn.q.n.w"])).reshape(N, S, H, dn)
+                qr = sc(linear(h, lp["attn.q.r.w"])).reshape(N, S, H, dr)
+                c = sc(linear(h, lp["attn.kv_a.c.w"]))
+                kr = sc(linear(h, lp["attn.kv_a.r.w"]))
+                c = masked_rms_norm(c, lp["attn.kv_norm.g"], lora_mask, act["kv_lora"], eps)
+                kn = sc(linear(c, lp["attn.kv_b.k.w"])).reshape(N, S, H, dn)
+                v = sc(linear(c, lp["attn.kv_b.v.w"])).reshape(N, S, H, dv)
+            qr, kr = rope_interleaved(qr, pos, theta), rope_interleaved(kr, pos, theta)
+            if compute_dtype is not None:
+                qn, qr, kn, kr, v = (t.astype(compute_dtype) for t in (qn, qr, kn, kr, v))
+            o = causal_latent_attention(qn, qr, kn, kr, v, scale)
+            with scope("mla"):
+                o = o.astype(jnp.float32).reshape(N, S, H * dv)
+                return sc(linear(o, lp["attn.o.w"]))
+
+        def ffn(lp, prefix, h):
+            return swiglu(h, lp[f"{prefix}.g.w"], lp[f"{prefix}.u.w"], lp[f"{prefix}.d.w"],
+                          sc, compute_dtype)
+
+        # a layer keeps only its input for the backward: the model is sized
+        # so that parameters, not activations, fill the chip
+        @jax.checkpoint
+        def dense_layer(x, lp):
+            x = x + attention(lp, rms(lp["norm1.g"], x))
+            return x + ffn(lp, "mlp", rms(lp["norm2.g"], x))
+
+        @jax.checkpoint
+        def expert_layer(x, lp):
+            x = x + attention(lp, rms(lp["norm1.g"], x))
+            hf = rms(lp["norm2.g"], x).reshape(T, D)
+            sel, w = moe_route(hf, lp["moe.router.w"], lp["moe.router.b"], K, scaling)
+            y, counters = moe_experts(hf, sel, w, [lp[f"moe.e.{m}.w"] for m in "gud"],
+                                      held[0], sc, compute_dtype)
+            with scope("moe/shared"):
+                y = y + ffn(lp, "moe.shared", hf)
+            return x + y.reshape(N, S, D), counters
+
+        def leaves(i):
+            """Layer ``i``'s leaves without their prefix, its held experts'
+            stacked on a leading axis."""
+            lp = {k[len(f"l{i}."):]: v for k, v in params.items()
+                  if k.startswith(f"l{i}.") and ".moe.e" not in k}
+            if i >= L_dense:
+                for m in "gud":
+                    lp[f"moe.e.{m}.w"] = jnp.stack([params[f"l{i}.moe.e{j}.{m}.w"] for j in held])
+            return lp
+
+        x = embed(params["embedding.tok.w"], labels)
+        for i in range(L_dense):
+            x = dense_layer(x, leaves(i))
+        counters = None
+        if L > L_dense:
+            # the expert layers are alike: one scan over their stacked leaves,
+            # so the program holds one layer's code whatever the depth
+            rest = [leaves(i) for i in range(L_dense, L)]
+            x, per_layer = jax.lax.scan(
+                expert_layer, x, {k: jnp.stack([lp[k] for lp in rest]) for k in rest[0]})
+            counters = jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), per_layer)
+        xn = rms(params["norm.g"], x)
+        # the logits a caller may read (evaluation does not, training does
+        # not: then the compiler drops them)
+        out = masked_logits(linear(xn, params["head.w"]), label_mask, mask)  # [N, S, V]
+        # next token inside each row: position t predicts t + 1; the last
+        # position of a window has no target.  The loss takes the head a
+        # block of positions at a time, each block under jax.checkpoint, so
+        # that [T, V] logits are never held (T = 4,096, V = 16,032: 263 MB a
+        # copy, and cross entropy keeps several)
+        w = jnp.ones((N, S), jnp.float32) if sample_weight is None else \
+            jnp.broadcast_to(sample_weight, (N, S)).astype(jnp.float32)
+        tgt = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1).reshape(T)
+        wt = jnp.concatenate([w[:, 1:] * w[:, :-1], jnp.zeros((N, 1), jnp.float32)],
+                             axis=1).reshape(T)
+        c = LOSS_BLOCK if T % LOSS_BLOCK == 0 else T
+
+        def block_nll(xs):
+            x_c, t_c, w_c = xs
+            lg = masked_logits(linear(x_c, params["head.w"]), label_mask, mask)
+            return cross_entropy(lg, t_c, w_c) * jnp.sum(w_c)  # the block's weighted sum
+
+        sums = jax.lax.map(jax.checkpoint(block_nll),
+                           (xn.reshape(T // c, c, D), tgt.reshape(T // c, c),
+                            wt.reshape(T // c, c)))
+        loss = jnp.sum(sums) / jnp.maximum(jnp.sum(wt), 1e-12)
+        res = {"score": out, "loss": loss}
+        if counters is not None:
+            res["counters"] = {f"moe_{k}": v for k, v in counters.items()}
+        return res, {}
+
+    meta = {"bn_sizes": {}, "kind": "kanana2", "num_tokens": num_tokens,
+            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes)}
+    if L > L_dense:
+        # what apply's "counters" holds (summed over the expert layers); the
+        # engines carry them as obs_ probes when telemetry is on
+        meta["counters"] = {"moe_tokens": (len(held),), "moe_assign": (3,)}
+    return ModelDef("kanana2", init, apply, specs, groups, [], meta)

@@ -364,11 +364,20 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # (each device counts its own gated slots) sum across
                 # devices -- and across levels on the grouped span layout
                 rec["quarantined"] = int(round(float(x.sum())))
+            elif base.startswith("moe_"):
+                # an expert layer's counters (ISSUE 28): per-device sums
+                # over that device's valid slots, steps and expert layers
+                rec[base] = [float(c) for c in x.sum(axis=0)]
             elif base == "nonfinite":
                 rec["nonfinite"] = int(x[0, 0])
             elif base.endswith("_sq"):
                 rec[base[:-3] + "_norm"] = float(np.sqrt(x[0, 0]))
             else:  # pragma: no cover - future probes default to replicated
                 rec[base] = float(x[0, 0])
+        if "moe_assign" in rec:
+            # (pairs routed, pairs on held experts, held pairs not computed)
+            routed, held, lost = rec["moe_assign"]
+            rec["moe_held_share"] = held / routed if routed else 0.0
+            rec["moe_dropped"] = int(round(lost))
         rounds.append(rec)
     return clean, rounds

@@ -48,6 +48,7 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
     """Count events.jsonl records by name; surface the watchdog trips."""
     counts: Dict[str, int] = {}
     watchdog: List[Dict[str, Any]] = []
+    moe = {"rounds": 0, "tokens": None, "routed": 0.0, "held": 0.0, "dropped": 0}
     with open(events_path) as f:
         for line in f:
             line = line.strip()
@@ -58,8 +59,22 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
             counts[name] = counts.get(name, 0) + 1
             if name == "watchdog":
                 watchdog.append(rec.get("args", {}))
-    return {"path": events_path, "events_by_name": counts,
-            "watchdog_trips": watchdog[:16]}
+            args = rec.get("args", {})
+            if name == "probes" and "moe_assign" in args:
+                # an expert layer's counters (obs.split_probes), summed
+                # over the run's rounds
+                moe["rounds"] += 1
+                tok = args.get("moe_tokens", [])
+                moe["tokens"] = list(tok) if moe["tokens"] is None else \
+                    [a + b for a, b in zip(moe["tokens"], tok)]
+                moe["routed"] += args["moe_assign"][0]
+                moe["held"] += args["moe_assign"][1]
+                moe["dropped"] += int(args.get("moe_dropped", 0))
+    out = {"path": events_path, "events_by_name": counts,
+           "watchdog_trips": watchdog[:16]}
+    if moe["rounds"]:
+        out["moe"] = moe
+    return out
 
 
 def build_report(ledger_path: str,
@@ -111,6 +126,15 @@ def render_text(rep: Dict[str, Any]) -> str:
         lines.append(f"events -- {ev['path']}")
         lines.append("  " + "  ".join(f"{k}:{v}" for k, v in
                                       sorted(ev["events_by_name"].items())))
+        moe = ev.get("moe")
+        if moe:
+            share = moe["held"] / moe["routed"] if moe["routed"] else 0.0
+            lines.append(
+                f"  expert layers over {moe['rounds']} rounds: (token, expert) "
+                f"pairs on held experts {moe['held']:g} of {moe['routed']:g} "
+                f"({100.0 * share:.2f} %), dropped {moe['dropped']}")
+            lines.append("    tokens per held expert: " + " ".join(
+                f"{t:g}" for t in moe["tokens"]))
         if ev["watchdog_trips"]:
             lines.append(f"  WATCHDOG TRIPPED {len(ev['watchdog_trips'])}x: "
                          f"{ev['watchdog_trips'][0]}")

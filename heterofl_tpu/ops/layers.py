@@ -16,6 +16,7 @@ kernels -- the native layouts for XLA:TPU.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -281,3 +282,235 @@ def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
     w = jnp.broadcast_to(sample_weight.reshape(sample_weight.shape + (1,) * (nll.ndim - sample_weight.ndim)),
                          nll.shape)
     return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-12)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention + shared-and-routed experts (models/kanana2.py)
+# ---------------------------------------------------------------------------
+
+@scoped("norm")
+def masked_rms_norm(x: jnp.ndarray, g: jnp.ndarray, mask: jnp.ndarray, k,
+                    eps: float = 1e-6) -> jnp.ndarray:
+    """RMSNorm over the last axis counting only the ``k`` active dims
+    (``x / sqrt(mean(x^2) + eps) * g`` of the sliced sub-model).  ``g`` is
+    zero at masked dims, which zeroes the output there."""
+    xm = x * mask
+    ms = jnp.sum(xm * xm, axis=-1, keepdims=True) / k
+    return xm / jnp.sqrt(ms + eps) * g
+
+
+@scoped("rope")
+def rope_interleaved(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary embedding on interleaved pairs ``(2i, 2i+1)`` of the last axis
+    (``rope_interleave: true``), ``theta_i = theta^(-2i/d)`` with ``d`` the
+    FULL rotary width: a client's sliced prefix of whole pairs keeps the
+    frequencies of the pairs it holds, and zeros (masked pairs) stay zeros.
+    ``x`` ``[N, S, ..., d]``, ``pos`` ``[S]``.  Written as ``x cos + swap(x) sin``
+    with ``swap`` two lane rotations and a select, not a strided gather."""
+    d = x.shape[-1]
+    inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]           # [S, d]
+    view = [1] * x.ndim
+    view[1], view[-1] = x.shape[1], d
+    cos, sin = jnp.cos(ang).reshape(view), jnp.sin(ang).reshape(view)
+    even = (jnp.arange(d) % 2) == 0
+    swapped = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+    return x * cos + swapped * sin
+
+
+def swiglu(x, wg, wu, wd, sc, compute_dtype=None):
+    """``sc((silu(sc(x wg)) * sc(x wu)) wd)``; ``sc`` the HeteroFL Scaler."""
+    h = jax.nn.silu(sc(linear(x, wg, compute_dtype=compute_dtype))) \
+        * sc(linear(x, wu, compute_dtype=compute_dtype))
+    return sc(linear(h, wd, compute_dtype=compute_dtype))
+
+
+#: query rows a block of the causal attention takes (memory, not mathematics)
+ATTN_BLOCK = 256
+
+
+@scoped("attn")
+def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
+    """Causal softmax attention of latent-attention heads, the score /
+    softmax / value part only: ``scores = (qn kn^T + qr kr^T) * scale``.
+
+    ``qn``/``kn`` ``[N, S, H, dn]`` (no-position dims), ``qr`` ``[N, S, H,
+    dr]`` and ``kr`` ``[N, S, dr]`` (rotary dims, ONE key head shared by all
+    query heads), ``v`` ``[N, S, H, dv]``.  Runs in query blocks of ``block``
+    rows against the keys up to the block's end, each block under
+    ``jax.checkpoint``: no ``[S, S]`` score matrix of a whole row is ever
+    held, in the forward or for the backward, and key blocks above the
+    diagonal are never computed.  Softmax in float32."""
+    S = qn.shape[1]
+    outs = []
+    for start in range(0, S, block):
+        end = min(start + block, S)
+
+        def one(qn_b, qr_b, kn_b, kr_b, v_b, start=start, end=end):
+            s = jnp.einsum("nqhd,nkhd->nhqk", qn_b, kn_b) \
+                + jnp.einsum("nqhd,nkd->nhqk", qr_b, kr_b)
+            s = s.astype(jnp.float32) * scale
+            keep = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
+            s = jnp.where(keep, s, -jnp.inf)
+            return jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, axis=-1), v_b)
+
+        outs.append(jax.checkpoint(one)(qn[:, start:end], qr[:, start:end],
+                                        kn[:, :end], kr[:, :end], v[:, :end]))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+@scoped("moe/router")
+def moe_route(h, w_router, bias, top_k: int, scaling: float):
+    """Sigmoid router over ALL experts (``scoring_func: sigmoid``,
+    ``topk_method: noaux_tc`` with one group): ``s = sigmoid(h Wr)`` in
+    float32 at "highest" matmul precision, ``sel = top_k(s + bias)`` (the
+    selection bias is read here only; no gradient reaches it),
+    ``w = s[sel] / sum(s[sel]) * scaling``.  ``h`` ``[T, D]``.  Returns
+    ``(sel [T, k] int32, w [T, k])``."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    _, sel = lax.top_k(s + lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    return sel.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+
+
+#: rows of ONE expert that a step of the grouped loop computes
+MOE_TILE = 256
+
+
+def _gather_rows(x, idx):
+    """``x[idx]`` along the first axis, zero where ``idx < 0``."""
+    out = x[jnp.maximum(idx, 0)]
+    return jnp.where((idx >= 0).reshape(idx.shape + (1,) * (out.ndim - idx.ndim)), out, 0)
+
+
+def _expert_tile(compute_dtype, x, wg, wu, wd, inv):
+    return swiglu(x, wg, wu, wd, lambda v: v * inv, compute_dtype)
+
+
+def _tile_weights(e, *stacked):
+    return tuple(lax.dynamic_index_in_dim(s, e, 0, keepdims=False) for s in stacked)
+
+
+# The routed experts' part over RAGGED groups: the (token, held expert) pairs
+# lie sorted by expert, each expert's group padded to whole tiles, and a loop
+# with as many steps as there are tiles IN USE takes one tile through its
+# expert.  ``rows`` ``[tiles * tile]``: the pair (t * K + k) of a row, -1 for
+# padding; ``slot`` ``[T, K]``: the row of a pair, -1 for a pair held elsewhere.
+# The map is known in both directions, so both directions are gathers: no
+# scatter-add, forward or backward.  A trip count known only at run time has
+# no reverse-mode rule, hence the custom one: the backward is the same loop,
+# each tile's cotangents from ``jax.vjp`` of the tile.
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped_swiglu(compute_dtype, tile, h, w, rows, slot, tile_expert, n_tiles, wg, wu, wd, inv):
+    """``(out, computed)``: ``out[t] = sum_k w[t, k] * expert(h[t])`` over the
+    pairs with a row; ``computed`` counts the pairs the loop took."""
+    K = w.shape[1]
+
+    def step(i, carry):
+        y, done = carry
+        with scope("moe/dispatch"):
+            r = lax.dynamic_slice(rows, (i * tile,), (tile,))
+            x_t = _gather_rows(h, r // K)
+        with scope("moe/experts"):
+            y_t = _expert_tile(compute_dtype, x_t, *_tile_weights(tile_expert[i], wg, wu, wd), inv)
+        return (lax.dynamic_update_slice(y, y_t, (i * tile, 0)),
+                done + jnp.sum((r >= 0).astype(jnp.int32)))
+
+    y, done = lax.fori_loop(0, n_tiles, step,
+                            (jnp.zeros((rows.shape[0], h.shape[1]), h.dtype), jnp.int32(0)))
+    with scope("moe/dispatch"):
+        return sum(w[:, k, None] * _gather_rows(y, slot[:, k]) for k in range(K)), done
+
+
+def _grouped_swiglu_fwd(compute_dtype, tile, *args):
+    return _grouped_swiglu(compute_dtype, tile, *args), args
+
+
+def _grouped_swiglu_bwd(compute_dtype, tile, res, cts):
+    h, w, rows, slot, tile_expert, n_tiles, wg, wu, wd, inv = res
+    dout, K = cts[0], w.shape[1]
+    w_flat = w.reshape(-1)
+
+    def step(i, carry):
+        dx, dw_rows, *dws = carry
+        with scope("moe/dispatch"):
+            r = lax.dynamic_slice(rows, (i * tile,), (tile,))
+            x_t, d_t = _gather_rows(h, r // K), _gather_rows(dout, r // K)
+            w_t = _gather_rows(w_flat, r)
+        with scope("moe/experts"):
+            e = tile_expert[i]
+            y_t, vjp = jax.vjp(partial(_expert_tile, compute_dtype), x_t,
+                               *_tile_weights(e, wg, wu, wd), inv)
+            dx_t, *dws_t, _ = vjp(d_t * w_t[:, None])
+            dws = [lax.dynamic_update_index_in_dim(
+                acc, lax.dynamic_index_in_dim(acc, e, 0, keepdims=False) + d, e, 0)
+                for acc, d in zip(dws, dws_t)]
+        return (lax.dynamic_update_slice(dx, dx_t, (i * tile, 0)),
+                lax.dynamic_update_slice(dw_rows, jnp.sum(y_t * d_t, axis=-1), (i * tile,)),
+                *dws)
+
+    dx, dw_rows, dwg, dwu, dwd = lax.fori_loop(
+        0, n_tiles, step,
+        (jnp.zeros((rows.shape[0], h.shape[1]), h.dtype), jnp.zeros(rows.shape, w.dtype),
+         jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd)))
+    with scope("moe/dispatch"):
+        dh = sum(_gather_rows(dx, slot[:, k]) for k in range(K))
+        dw = _gather_rows(dw_rows, slot)
+    return dh, dw, None, None, None, None, dwg, dwu, dwd, jnp.zeros_like(inv)
+
+
+_grouped_swiglu.defvjp(_grouped_swiglu_fwd, _grouped_swiglu_bwd)
+
+
+def moe_experts(h, sel, w, experts, first: int, sc, compute_dtype=None,
+                tile: int = MOE_TILE):
+    """The routed part of an expert layer for the experts HELD here:
+    ``y[t] = sum over k with sel[t, k] held of w[t, k] * expert(h[t])``.
+
+    ``experts``: the held experts' ``(wg, wu, wd)`` stacked on a leading axis
+    (``[held, D, F]`` x 2, ``[held, F, D]``); they are experts ``[first,
+    first + held)`` of the layer.  What absent experts would add is left out
+    (the caller's share of an expert-parallel layer; nothing here stands in
+    for the other shares).
+
+    No capacity and no dropped token: every (token, held expert) pair is
+    computed, whatever the router did.  The pairs are sorted by expert, each
+    expert's group padded to whole tiles of ``tile`` rows, and
+    :func:`_grouped_swiglu` runs the tiles in use, so the work follows the
+    pairs there are and not a bound on them (the bound, all ``T * K`` pairs
+    on held experts, is only the row count of the index arrays and of the
+    loop's result buffer).
+
+    Returns ``(y [T, D], counters)``: ``tokens`` ``[held]`` pairs per held
+    expert, ``assign`` ``[3]`` = (pairs routed, pairs on held experts, pairs
+    on held experts that were not computed -- always 0)."""
+    T, K = sel.shape
+    wg, wu, wd = experts
+    held, A = wg.shape[0], T * K
+    with scope("moe/dispatch"):
+        local = sel.reshape(A) - first
+        is_held = (local >= 0) & (local < held)
+        e = jnp.where(is_held, local, held)                       # held = none
+        onehot = (e[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+        counts = jnp.sum(onehot, axis=0)                          # [held]
+        pos = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+        order = jnp.argsort(e, stable=True).astype(jnp.int32)     # pairs by expert
+        starts = jnp.cumsum(counts) - counts                      # of a group in `order`
+        tiles = -(-counts // tile)                                # tiles of a group
+        ends = jnp.cumsum(tiles)
+        n_rows = (A // tile + held) * tile                        # >= any sum of padded groups
+        slot = jnp.where(is_held, (ends - tiles)[jnp.minimum(e, held - 1)] * tile + pos, -1)
+        tile_expert = jnp.minimum(jnp.searchsorted(ends, jnp.arange(n_rows // tile),
+                                                   side="right"), held - 1).astype(jnp.int32)
+        row = jnp.arange(n_rows, dtype=jnp.int32)
+        ex = tile_expert[row // tile]
+        rank = row - (ends - tiles)[ex] * tile                    # of a row in its group
+        rows = jnp.where(rank < counts[ex], order[jnp.minimum(starts[ex] + rank, A - 1)], -1)
+    y, computed = _grouped_swiglu(compute_dtype, tile, h, w.astype(h.dtype), rows,
+                                  slot.reshape(T, K), tile_expert, ends[-1], wg, wu, wd,
+                                  sc(jnp.ones((), h.dtype)))
+    n_held = jnp.sum(counts)
+    assign_ct = jnp.stack([jnp.int32(A), n_held, n_held - computed]).astype(jnp.float32)
+    return y, {"tokens": counts.astype(jnp.float32), "assign": assign_ct}

@@ -48,7 +48,7 @@ class Evaluator:
         base = jax.random.key(seed)
         self._users_key = jax.random.fold_in(base, 0)
         self._global_key = jax.random.fold_in(base, 1)
-        self.is_lm = model.meta.get("kind") == "transformer"
+        self.is_lm = model.is_lm
         self.norm_stats = cfg.get("norm_stats") or DATASET_STATS.get(cfg["data_name"])
         self.bptt = cfg.get("bptt", 64)
         self._sbn = None

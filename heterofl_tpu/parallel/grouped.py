@@ -117,7 +117,7 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
         # layout pinning (ISSUE 5 pass 2), same cached pinner as the
         # masked engine
         self._pin = ParamPinner(mesh, cfg.get("layout_policy", "auto"))
-        self.is_lm = self.global_model.meta.get("kind") == "transformer"
+        self.is_lm = self.global_model.is_lm
         self.failure_rate = float(cfg.get("client_failure_rate", 0.0) or 0.0)  # staticcheck: allow(no-float-coercion): constructor-time config scalar
         self.levels: Dict[float, Tuple[Any, RoundEngine]] = {}
         for rate in sorted({float(r) for r in cfg["model_rate"]}, reverse=True):  # staticcheck: allow(no-float-coercion): constructor-time config parse

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..chaos import resolve_poison_cfg
 from ..compress import make_codec, resid_slots, resolve_codec_cfg
-from ..config import resolve_prefetch_depth
+from ..config import resolve_chunk_cfg, resolve_prefetch_depth
 from ..multi import resolve_arms_cfg
 from ..obs import resolve_quarantine_cfg, resolve_telemetry_cfg, split_probes
 from ..obs.hist import round_hists
@@ -400,7 +400,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         ne = cfg["num_epochs"]
         self.local_epochs = ne["local"] if isinstance(ne, dict) else 1
         self.batch_size = cfg["batch_size"]["train"]
-        self.is_lm = model.meta.get("kind") == "transformer"
+        self.is_lm = model.is_lm
         self.bptt = cfg.get("bptt", 64)
         self.norm_stats = cfg.get("norm_stats") or DATASET_STATS.get(cfg["data_name"])
         self.augment = cfg["data_name"].startswith("CIFAR")
@@ -415,6 +415,9 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         # while-loop trips with more fusion scope per trip can shave per-step
         # overhead; 1 = no unrolling (identical program)
         self.scan_unroll = int(cfg.get("scan_unroll", 1) or 1)
+        # cohort chunking (ISSUE 28): slots of a device trained at once;
+        # None = all, the one-vmap round every other program is
+        self._chunk = resolve_chunk_cfg(cfg)
         self._opt_init, self._opt_update = make_optimizer(cfg)
         # the step's carry layout and epilogue: None = tree carry + the
         # reference op chain (a TPU's default, PR 27); 'xla'/'pallas' = flat
@@ -818,10 +821,11 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                 # weighted-SUM form so the cross-shard reduction recovers the
                 # exact full-window mean gradient
                 n_loc = jnp.sum(w)
-                return out["loss"] * n_loc, n_loc
+                return out["loss"] * n_loc, \
+                    (n_loc, out.get("counters") if counted else None)
 
             # per-leaf grads even under the flat carry (see _local_train_vision)
-            (lsum, n_loc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (lsum, (n_loc, ctr)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 _leaf_views(spec, p))
             if seq_sharded:
                 grads, lsum, n_glob = jax.lax.psum((grads, lsum, n_loc), data_axis)
@@ -836,15 +840,30 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             n = np.float32(R)  # static trace-time constant, not a device wrap
             if live is not None:
                 n = n * live.astype(jnp.float32)  # deadline: truncated steps
-            acc = (acc[0] + loss * n, acc[1] + jnp.exp(loss) * n, acc[2] + n)
+            acc = (acc[0] + loss * n, acc[1] + jnp.exp(loss) * n, acc[2] + n) + acc[3:]
+            if counted:
+                # the model's own counters (an expert layer's tokens per held
+                # expert, ...): summed over the steps that ran
+                g = 1.0 if live is None else live.astype(jnp.float32)
+                acc = acc[:3] + ({k: acc[3][k] + ctr[k] * g for k in ctr},)
             return (p, opt, acc), None
 
+        # counters a model declares (meta['counters']: name -> shape) ride the
+        # metrics as obs_ probes when telemetry is on; otherwise the program
+        # is the one without them
+        counted = self._obs_on and bool(model.meta.get("counters"))
         acc0 = (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
+        if counted:
+            acc0 += ({k: jnp.zeros(shape, jnp.float32)
+                      for k, shape in model.meta["counters"].items()},)
         (p, _, acc), _ = jax.lax.scan(step, (p, opt, acc0), jnp.arange(E * S),
                                       unroll=self.scan_unroll)
         if spec is not None:
             p = spec.unflatten(p)
-        return p, {"loss_sum": acc[0], "score_sum": acc[1], "n": acc[2]}
+        ms = {"loss_sum": acc[0], "score_sum": acc[1], "n": acc[2]}
+        if counted:
+            ms.update({"obs_" + k: v for k, v in acc[3].items()})
+        return p, ms
 
     # ------------------------------------------------------------------
     # the round program
@@ -900,34 +919,16 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             wr = rates_abs / self.global_rate
             slot_keys = client_stream_keys(key, ugid)
 
+        n_data = mesh.shape["data"]
+        limits = None
         if self.is_lm:
             all_rows, all_lm = data[0], data[1]
             with scope("round/gather"):
                 rows = all_rows if uidx is None else all_rows[uidx]
                 lm = all_lm if uidx is None else all_lm[uidx]
-            n_data = mesh.shape["data"]
-            if self._sched_spec.has_deadline:
-                # deadline stragglers (ISSUE 9): per-client step budgets
-                # from the shared (round key, uid) stream -- the grouped
-                # engine draws the identical budgets in _level_core
-                total_steps = self.local_epochs * _ceil_div(
-                    int(rows.shape[-1]), self.bptt)
-                limits = deadline_steps(key, ugid, total_steps,
-                                        self._sched_spec.deadline_min_frac)
-                with scope("round/local_train"):
-                    trained, ms = jax.vmap(
-                        lambda w_, r_, l_, k_, lim_: self._local_train_lm(
-                            params, w_, r_, l_, k_, lr,
-                            data_axis="data" if n_data > 1 else None,
-                            n_data=n_data, step_limit=lim_)
-                    )(wr, rows, lm, slot_keys, limits)
-            else:
-                with scope("round/local_train"):
-                    trained, ms = jax.vmap(
-                        lambda w_, r_, l_, k_: self._local_train_lm(
-                            params, w_, r_, l_, k_, lr,
-                            data_axis="data" if n_data > 1 else None, n_data=n_data)
-                    )(wr, rows, lm, slot_keys)
+            sdata = (rows, lm)
+            total_steps = self.local_epochs * _ceil_div(
+                int(rows.shape[-1]), self.bptt)
         else:
             all_x, all_y, all_m, all_lm = data[0], data[1], data[2], data[3]
             if uidx is None:
@@ -935,66 +936,64 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             else:
                 with scope("round/gather"):
                     xs, ys, sms, lm = all_x[uidx], all_y[uidx], all_m[uidx], all_lm[uidx]
-            n_data = mesh.shape["data"]
-            if self._sched_spec.has_deadline:
-                total_steps = self.local_epochs * _ceil_div(
-                    int(xs.shape[1]), self.batch_size)
-                limits = deadline_steps(key, ugid, total_steps,
-                                        self._sched_spec.deadline_min_frac)
-                with scope("round/local_train"):
-                    trained, ms = jax.vmap(
-                        lambda w_, x_, y_, m_, l_, k_, lim_: self._local_train_vision(
-                            params, w_, x_, y_, m_, l_, k_, lr,
-                            data_axis="data" if n_data > 1 else None,
-                            n_data=n_data, step_limit=lim_)
-                    )(wr, xs, ys, sms, lm, slot_keys, limits)
-            else:
-                with scope("round/local_train"):
-                    trained, ms = jax.vmap(
-                        lambda w_, x_, y_, m_, l_, k_: self._local_train_vision(
-                            params, w_, x_, y_, m_, l_, k_, lr,
-                            data_axis="data" if n_data > 1 else None, n_data=n_data)
-                    )(wr, xs, ys, sms, lm, slot_keys)
+            sdata = (xs, ys, sms, lm)
+            total_steps = self.local_epochs * _ceil_div(
+                int(xs.shape[1]), self.batch_size)
+        if self._sched_spec.has_deadline:
+            # deadline stragglers (ISSUE 9): per-client step budgets
+            # from the shared (round key, uid) stream -- the grouped
+            # engine draws the identical budgets in _level_core
+            limits = deadline_steps(key, ugid, total_steps,
+                                    self._sched_spec.deadline_min_frac)
+        if self._poison is not None and epoch is None:
+            raise ValueError(
+                "chaos_poison needs the round epoch threaded into the "
+                "round core (pass epoch= to train_round)")
+        slots = int(user_glob.shape[0])
+        chunk = self._chunk_of(slots)
+        per_slot = (wr, valid, user_glob, slot_keys, limits) + sdata
+        if chunk == slots:
+            summed, counts, ms, ok = self._train_slots(
+                params, lr, epoch, n_data, *per_slot)
+        else:
+            # chunked cohort (ISSUE 28): `chunk` slots train at a time; the
+            # scan carries the aggregate's sums and counts, so the round
+            # holds `chunk` (not `slots`) copies of model, momentum and
+            # gradients.  The per-slot streams and the one psum below are
+            # the unchunked round's.
+            def chunk_body(summed, sl):
+                with scope("round/chunk"):
+                    s_, _, ms_, ok_ = self._train_slots(
+                        params, lr, epoch, n_data, *sl, one=(chunk == 1),
+                        want_counts=False)
+                return jax.tree_util.tree_map(jnp.add, summed, s_), (ms_, ok_)
 
-        if self._poison is not None:
-            # chaos NaN poison (ISSUE 15): the matched (round, uid) slots'
-            # updates go non-finite BEFORE aggregation -- the adversarial-
-            # client model the quarantine gate / watchdog rollback recover
-            # from.  Padding slots (uid -1) never match.
-            if epoch is None:
-                raise ValueError(
-                    "chaos_poison needs the round epoch threaded into the "
-                    "round core (pass epoch= to train_round)")
-            from ..chaos.inject import poison_updates
+            zeros = {k: jnp.zeros(v.shape, v.dtype) for k, v in params.items()}
+            summed, (ms, ok) = jax.lax.scan(
+                chunk_body, zeros, jax.tree_util.tree_map(
+                    lambda x: x.reshape((slots // chunk, chunk) + x.shape[1:]),
+                    per_slot))
+            ms, ok = jax.tree_util.tree_map(
+                lambda x: x.reshape((slots,) + x.shape[2:]), (ms, ok))
+            # the counts need no training result (a slot's level, labels and
+            # gates give them), so they are not carried beside the sums
+            # through the training loop: a loop of their own, slot by slot,
+            # after it, when no client's model, momentum or gradients live
+            gate = valid if ok is None else valid * ok.astype(jnp.float32)
+            shapes = {k: v.shape for k, v in params.items()}
 
-            trained = poison_updates(trained, self._poison, epoch, user_glob)
-        shapes = {k: v.shape for k, v in params.items()}
+            def count_body(counts, sl):
+                w_, l_, g_ = sl
+                with scope("round/aggregate"):
+                    cm = make_count_masks(shapes, model.specs, model.groups, w_, l_)
+                    return {k: counts[k] + cm[k] * g_ for k in counts}, None
+
+            counts, _ = jax.lax.scan(count_body, zeros, (wr, sdata[-1], gate))
+        # a model's own counters (obs_ leaves of the per-slot metrics): this
+        # device's sum over its valid slots, finished on the host like every
+        # per-device partial (obs.split_probes)
+        counters = {k: ms.pop(k) for k in [k for k in ms if k.startswith("obs_")]}
         with scope("round/aggregate"):
-            cms = jax.vmap(lambda w_, l_, v_: jax.tree_util.tree_map(
-                lambda m: m * v_, make_count_masks(shapes, model.specs, model.groups, w_, l_)))(
-                wr, lm, valid)
-        ok = None
-        if self._quarantine.enabled:
-            # client-update quarantine (ISSUE 15 tentpole): the gate folds
-            # into BOTH the sums and the counts BEFORE the single global
-            # psum below -- a quarantined client is a zero-count
-            # participant, and the where-select sanitises its (possibly
-            # NaN) trained values so NaN * 0-count cannot poison the sum.
-            # All-clean rounds are bit-identical: the gate multiplies by
-            # 1.0 and the select returns the unchanged value.
-            from ..obs.probes import quarantine_gate
-
-            ok = quarantine_gate(trained, params, cms,
-                                 self._quarantine.max_norm)
-            okf = ok.astype(jnp.float32)
-            cms = {k: cms[k] * okf.reshape((-1,) + (1,) * (cms[k].ndim - 1))
-                   for k in cms}
-            trained = {k: jnp.where(ok.reshape((-1,) + (1,) * (v.ndim - 1)),
-                                    v, jnp.zeros((), v.dtype))
-                       for k, v in trained.items()}
-        with scope("round/aggregate"):
-            summed = {k: jnp.sum(trained[k] * cms[k], axis=0) for k in params}
-            counts = {k: jnp.sum(cms[k], axis=0) for k in params}
             codec = self._codec(params)
             if codec is None:
                 # ONE psum bind for sums+counts: the round's single global
@@ -1042,6 +1041,9 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         else:
             ms = {k: v * valid for k, v in ms.items()}
             ms["rate"] = rates_abs * valid
+        for k, v in counters.items():
+            gate = valid if ok is None else valid * ok.astype(jnp.float32)
+            ms[k] = jnp.sum(v * gate.reshape((-1,) + (1,) * (v.ndim - 1)), axis=0)
         if self._obs_on:
             # in-program health probes (ISSUE 10): derived from the
             # already-reduced aggregates and the replicated carries --
@@ -1067,6 +1069,85 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                               if self._sched_spec.has_deadline else None),
                     sched_buf=new_buf)}
         return new_params, ms, new_resid, new_buf
+
+    def _chunk_of(self, slots: int) -> int:
+        """Slots of a device that train at once: ``cfg['round_chunk']`` (None
+        = all of them).  It must divide the slots a device holds."""
+        chunk = self._chunk
+        if chunk is None or chunk >= slots:
+            return slots
+        if slots % chunk:
+            raise ValueError(
+                f"round_chunk={chunk} does not divide the {slots} slots a "
+                f"device holds this round")
+        return chunk
+
+    def _train_slots(self, params, lr, epoch, n_data, wr, valid, user_glob,
+                     slot_keys, limits, *sdata, one: bool = False,
+                     want_counts: bool = True):
+        """Local training and the aggregate's partial sums over a set of
+        slots (all of a device's, or one chunk of them): ``(summed, counts,
+        per-slot metric sums, quarantine gate or None)``.  ``sdata``: the
+        slots' gathered data, ``(rows, lm)`` or ``(x, y, sample mask, lm)``;
+        ``limits``: their deadline step budgets or None.  ``one``: a chunk
+        of ONE slot runs without the client vmap (under which a
+        ``lax.cond`` computes both branches)."""
+        model = self.model
+        lm = sdata[-1]
+        data_axis = "data" if n_data > 1 else None
+        if one:
+            def vmap(f):
+                take = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)  # noqa: E731
+                return lambda *a: jax.tree_util.tree_map(
+                    lambda x: x[None], f(*take(a)))
+        else:
+            vmap = jax.vmap
+        # limits None (no deadline) maps to step_limit=None: the lockstep body
+        train = self._local_train_lm if self.is_lm else self._local_train_vision
+        with scope("round/local_train"):
+            trained, ms = vmap(
+                lambda w_, k_, lim_, *d: train(
+                    params, w_, *d, k_, lr, data_axis=data_axis, n_data=n_data,
+                    step_limit=lim_)
+            )(wr, slot_keys, limits, *sdata)
+
+        if self._poison is not None:
+            # chaos NaN poison (ISSUE 15): the matched (round, uid) slots'
+            # updates go non-finite BEFORE aggregation -- the adversarial-
+            # client model the quarantine gate / watchdog rollback recover
+            # from.  Padding slots (uid -1) never match.
+            from ..chaos.inject import poison_updates
+
+            trained = poison_updates(trained, self._poison, epoch, user_glob)
+        shapes = {k: v.shape for k, v in params.items()}
+        with scope("round/aggregate"):
+            cms = jax.vmap(lambda w_, l_, v_: jax.tree_util.tree_map(
+                lambda m: m * v_, make_count_masks(shapes, model.specs, model.groups, w_, l_)))(
+                wr, lm, valid)
+        ok = None
+        if self._quarantine.enabled:
+            # client-update quarantine (ISSUE 15 tentpole): the gate folds
+            # into BOTH the sums and the counts BEFORE the single global
+            # psum -- a quarantined client is a zero-count
+            # participant, and the where-select sanitises its (possibly
+            # NaN) trained values so NaN * 0-count cannot poison the sum.
+            # All-clean rounds are bit-identical: the gate multiplies by
+            # 1.0 and the select returns the unchanged value.
+            from ..obs.probes import quarantine_gate
+
+            ok = quarantine_gate(trained, params, cms,
+                                 self._quarantine.max_norm)
+            okf = ok.astype(jnp.float32)
+            cms = {k: cms[k] * okf.reshape((-1,) + (1,) * (cms[k].ndim - 1))
+                   for k in cms}
+            trained = {k: jnp.where(ok.reshape((-1,) + (1,) * (v.ndim - 1)),
+                                    v, jnp.zeros((), v.dtype))
+                       for k, v in trained.items()}
+        with scope("round/aggregate"):
+            summed = {k: jnp.sum(trained[k] * cms[k], axis=0) for k in params}
+            counts = {k: jnp.sum(cms[k], axis=0) for k in params} \
+                if want_counts else None
+        return summed, counts, ms, ok
 
     def _data_specs(self) -> Tuple[P, ...]:
         """shard_map in_specs of the ``data`` tuple (incl. the fix-rates
@@ -1160,6 +1241,16 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             + (P("clients"), P("clients")) + self._data_specs(),
             out_specs=(P(), P("clients")),
         )
+        if self._chunk is not None:
+            # chunked cohort: the global parameters are read by every chunk
+            # and once more by the counted average, so an output that must
+            # live in their (donated) buffer cannot also be the buffer the
+            # sums grow in, and the chip's compiler keeps one more
+            # parameter-sized temporary: 14.9 GB of temporaries donated
+            # against 11.8 + 1.7 of output for the 425 M-parameter cell
+            # (compiled for a described v5e, PR 28).
+            # staticcheck: allow(jit-needs-donation): measured, see above
+            return jax.jit(fn)
         return jax.jit(fn, donate_argnums=(0,))
 
     def _build_superstep(self, k: int, per_dev: int, in_jit: bool,

@@ -2,7 +2,7 @@
 
 The repo's feature axes (engine x placement x codec x scheduler x
 telemetry x ledger x arms x quarantine x sampler x store x pod x
-eval-cohort) multiply into a lattice of ~10^5 nominally-expressible
+eval-cohort x chunk) multiply into a lattice of ~10^5 nominally-expressible
 configs.  Before this pass, the only exhaustiveness statement was
 social: each subsystem promised its validator refused "the bad combos"
 and the audit compiled "the good ones".  This module makes the
@@ -72,6 +72,7 @@ AXES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("sampler", ("prp", "perm")),
     ("eval_cohort", ("off", "c8")),
     ("pod", ("local", "pod")),
+    ("chunk", ("all", "c1")),
 )
 
 #: cfg skeleton every lattice point is written over: the non-axis keys
@@ -110,6 +111,7 @@ def point_cfg(point: Dict[str, str]) -> Dict[str, Any]:
     cfg["sampler"] = point["sampler"]
     cfg["eval_cohort"] = None if point["eval_cohort"] == "off" else 8
     cfg["strict_placement"] = point["pod"] == "pod"
+    cfg["round_chunk"] = None if point["chunk"] == "all" else 1
     return cfg
 
 
@@ -130,6 +132,7 @@ AXIS_CFG_KEYS: Dict[str, Tuple[str, ...]] = {
     "sampler": ("sampler",),
     "eval_cohort": ("eval_cohort",),
     "pod": ("strict_placement",),
+    "chunk": ("round_chunk",),
 }
 
 # ---------------------------------------------------------------------------
@@ -145,6 +148,9 @@ AXIS_CFG_KEYS: Dict[str, Tuple[str, ...]] = {
 #: (lattice-silent-fallback).  Ordering does not matter: any validating
 #: rule clears a point.
 REFUSAL_RULES: Tuple[Dict[str, Any], ...] = (
+    {"id": "chunk-needs-masked",
+     "when": {"engine": ("grouped", "sliced"), "chunk": "c1"},
+     "owner": "resolve_chunk_cfg", "keys": ("round_chunk", "strategy")},
     {"id": "grouped-sharded",
      "when": {"engine": "grouped", "placement": "sharded"},
      "owner": "resolve_placement_cfg", "keys": ("data_placement", "strategy")},
@@ -389,6 +395,14 @@ CONTRACTS: Dict[str, Dict[str, Any]] = {
                 "same (cohort size is a staging shape)",
         "evidence": ("program:masked/stream/k8-eval1",
                      "test:tests/test_sched.py")},
+    "chunked-cohort-scan": {
+        "note": "round_chunk trains the cohort c slots at a time inside the "
+                "masked engine's round core: a scan over the chunks carries "
+                "the aggregate's sums, the counts follow in a loop of their "
+                "own, the per-slot streams and the one psum are the "
+                "unchunked round's; every rider acts on the sums and "
+                "counts after the scan (the superstep scans the same core)",
+        "evidence": ("test:tests/test_round.py",)},
     "pod-placement-pinned": {
         "note": "strict_placement pins the pod layout: multi-process "
                 "slices refuse instead of silently falling back to span; "
@@ -417,6 +431,7 @@ RIDER_CONTRACTS: Dict[Tuple[str, str], str] = {
     ("sampler", "perm"): "sampler-stream-commitment",
     ("eval_cohort", "c8"): "eval-cohort-sampled-local",
     ("pod", "pod"): "pod-placement-pinned",
+    ("chunk", "c1"): "chunked-cohort-scan",
 }
 
 # ---------------------------------------------------------------------------

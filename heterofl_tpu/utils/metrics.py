@@ -16,6 +16,8 @@ from typing import Dict, Iterable
 
 import numpy as np
 
+from ..config import LM_MODEL_NAMES
+
 
 def accuracy(score, label, topk: int = 1) -> float:
     """Top-k accuracy in percent (ref metrics.py:7-13). Class axis is last."""
@@ -71,7 +73,7 @@ def summarize_sums(sums: Dict[str, np.ndarray], kind: str, prefix: str = "Local-
         return {}
     loss = float(np.sum(sums["loss_sum"])) / n
     out = {prefix + "Loss": loss}
-    if kind == "transformer":
+    if kind in LM_MODEL_NAMES:
         out[prefix + "Perplexity"] = float(np.sum(sums["score_sum"])) / n
     else:
         out[prefix + "Accuracy"] = float(np.sum(sums["score_sum"])) / n * 100.0

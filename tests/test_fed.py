@@ -17,7 +17,7 @@ from heterofl_tpu.fed import (
     sample_model_rates,
 )
 from heterofl_tpu.models import make_model
-from heterofl_tpu.models.spec import mask_params
+from heterofl_tpu.models.spec import Group, ParamSpec, mask_params
 
 from test_models import small_cfg
 
@@ -191,3 +191,112 @@ def test_sample_model_rates_fix_and_dynamic():
     draws = np.asarray(sample_model_rates(jax.random.key(1), cfg_d, jnp.arange(1000)))
     assert set(np.unique(draws).tolist()) <= {1.0, 0.0625}
     assert 0.35 < np.mean(draws == 1.0) < 0.65
+
+
+# ---------------------------------------------------------------------------
+# a group carries its own rule (models/spec.GROUP_RULES, ISSUE 28)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("group", [
+    Group("emb", 24), Group("qkv", 24, kind="per_head", num_heads=4),
+    Group("rope", 32, kind="per_head", num_heads=4, multiple=2, coupled=False),
+    Group("k_rope", 8, kind="per_head", num_heads=1, multiple=2, coupled=False),
+    Group("vocab", 7, kind="full")], ids=lambda g: g.name)
+@pytest.mark.parametrize("rate", [1.0, 0.5, 0.25, 0.0625])
+def test_group_rule_is_one_rule_in_four_forms(group, rate):
+    """Mask, active count, host index set and static slice / zero-pad of a
+    group are the same cut, whatever its kind."""
+    from heterofl_tpu.fed.core import active_indices, pad_axis, slice_axis
+
+    mask = np.asarray(group.mask(rate))
+    idx = active_indices(group, rate)
+    assert int(group.active_count(rate)) == len(idx) == int(mask.sum())
+    np.testing.assert_array_equal(np.flatnonzero(mask), idx)
+    v = jnp.arange(3 * group.size, dtype=jnp.float32).reshape(3, group.size) + 1.0
+    cut = slice_axis(v, group, rate, 1)
+    np.testing.assert_array_equal(cut, np.asarray(v)[:, idx])
+    np.testing.assert_array_equal(pad_axis(cut, group, rate, 1), np.asarray(v) * mask)
+    if group.multiple > 1:  # rotary dims travel in whole pairs, per head
+        per_head = mask.reshape(group.num_heads, -1).sum(axis=1)
+        assert (per_head % group.multiple == 0).all() and per_head.min() >= group.multiple
+
+
+def test_group_rules_are_a_registry_and_geometry_asks_the_rule():
+    from heterofl_tpu.fed.core import validate_width_geometry
+    from heterofl_tpu.models import make_model
+    from heterofl_tpu.models.spec import GROUP_RULES, GroupRule
+
+    with pytest.raises(ValueError, match="Not valid group kind"):
+        Group("x", 8, kind="strided").mask(0.5)
+
+    class EveryOther(GroupRule):  # a new kind is one object, no engine edit
+        def mask(self, g, width_rate):
+            return (jnp.arange(g.size) % 2 == 0).astype(jnp.float32)
+
+        def active_count(self, g, width_rate):
+            return jnp.int32((g.size + 1) // 2)
+
+    GROUP_RULES["every_other"] = EveryOther()
+    try:
+        g = Group("x", 6, kind="every_other")
+        spec = {"w": ParamSpec({0: "x"})}
+        out = mask_params({"w": jnp.ones((6, 2))}, spec, {"x": g}, 0.5)
+        np.testing.assert_array_equal(out["w"][:, 0], [1, 0, 1, 0, 1, 0])
+    finally:
+        del GROUP_RULES["every_other"]
+    # latent attention's per-head groups have an axis of their own: the
+    # coupled-prefix check is not theirs, at any level
+    from benchmark.tests import tiny_kanana2 as tiny
+
+    cfg = tiny.program_cfg()
+    model = make_model(cfg)
+    assert not model.groups["q_rope"].coupled
+    validate_width_geometry(model, cfg)
+
+
+def test_kanana2_counts_follow_width_and_labels_only():
+    """A client counts for every element of its slice: for a held expert
+    whether or not a token reached it, never for the router's columns or the
+    selection bias's entries it... holds all of; embedding rows and head
+    columns follow the labels."""
+    from benchmark.reference import kanana2 as ref
+    from benchmark.tests import tiny_kanana2 as tiny
+    from heterofl_tpu.models import make_model
+    from heterofl_tpu.models.spec import count_masks
+
+    cfg = tiny.program_cfg()
+    model = make_model(cfg)
+    shapes = dict(model.meta["shapes"])
+    labels = np.zeros(cfg["num_tokens"], np.float32)
+    labels[::3] = 1.0
+    for rate in (1.0, 0.25, 0.0625):
+        cm = count_masks(shapes, model.specs, model.groups, rate, jnp.asarray(labels))
+        index = ref.index(shapes, tiny.reference_model(cfg), rate)
+        for k, shape in shapes.items():
+            want = np.zeros(shape, np.float32)
+            want[np.ix_(*index[k])] = 1.0
+            if k in ref.LABEL_AXES:
+                view = [1] * len(shape)
+                view[ref.LABEL_AXES[k]] = -1
+                want = want * labels.reshape(view)
+            np.testing.assert_array_equal(np.asarray(cm[k]), want, err_msg=f"{k} @ {rate}")
+        assert np.asarray(cm["l1.moe.router.w"]).sum(axis=0).min() > 0  # all 16 columns
+        assert np.asarray(cm["l1.moe.router.b"]).all()
+
+
+def test_level_tables_know_the_kanana2_family():
+    """`level_param_table` counts the sliced sub-model's own leaves (from
+    shapes: nothing is initialised), the FLOP table falls with the level,
+    and the grouped engine refuses the family at config resolution."""
+    from benchmark.tests import tiny_kanana2 as tiny
+    from heterofl_tpu.fed.core import level_byte_table, level_flop_table, level_param_table
+    from heterofl_tpu.models import make_model
+
+    cfg = tiny.program_cfg()
+    counts = level_param_table(cfg)
+    for rate, n in counts.items():
+        shapes = jax.eval_shape(make_model(cfg, rate).init, jax.random.key(0))
+        assert n == sum(int(np.prod(v.shape)) for v in shapes.values()), rate
+    assert level_byte_table(cfg)[1.0]["wire_bytes"] == 2 * 4 * counts[1.0]
+    flops = level_flop_table(cfg)
+    assert sorted(flops.values(), reverse=True) == [flops[r] for r in sorted(flops, reverse=True)]

@@ -5,6 +5,7 @@ import pytest
 
 from heterofl_tpu import config as C
 from heterofl_tpu.models import make_model
+from heterofl_tpu.models.spec import mask_params
 
 
 def small_cfg(model_name="conv", data_name="MNIST", norm="bn", control="1_10_0.5_iid_fix_a1_bn_1_1"):
@@ -251,3 +252,150 @@ def test_conv_dimension_numbers_one_owner():
     from heterofl_tpu.ops.layers import CONV_DIMENSION_NUMBERS as B
 
     assert A is B == ("NHWC", "HWIO", "NHWC")
+
+
+# ---------------------------------------------------------------------------
+# Kanana-2 (latent attention, shared + routed experts; ISSUE 28) against the
+# benchmark's plain reference, at a tiny size
+# ---------------------------------------------------------------------------
+
+def _kanana_case(seed=1, **arch):
+    """(cfg, model, seeded params with the gains and the selection bias moved
+    off their constants, tokens, a label mask with holes)."""
+    from benchmark.tests import tiny_kanana2 as tiny
+
+    cfg = tiny.program_cfg(**arch)
+    model = make_model(cfg)
+    params = model.init(jax.random.key(seed))
+    keys = jax.random.split(jax.random.key(seed + 1), len(params))
+    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
+              for (k, v), kk in zip(sorted(params.items()), keys)}
+    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
+                                cfg["num_tokens"])
+    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
+    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
+
+
+@pytest.mark.parametrize("rate", [1.0, 0.5, 0.25, 0.125, 0.0625])
+def test_kanana2_masked_model_is_the_references_dense_submodel(rate):
+    """Loss and gradients of the masked full-width model at rate r against the
+    plain reference on the sliced sub-model: rate 1 is the published layer
+    (a), every other level HeteroFL's slice of it (b).  float32 on both
+    sides, so the two differ by summation order alone -- amplified by the
+    Scaler's 1/r after each of ~30 linears and, at a near-tie of two router
+    scores, by a different expert choice; 1e-3 of a leaf's largest gradient
+    holds both, a missing or mis-sliced term is off by 1e-1 or more."""
+    from benchmark.reference import common, kanana2 as ref
+
+    cfg, model, params, tokens, lm, rm = _kanana_case()
+
+    def system_loss(p):
+        pm = mask_params(p, model.specs, model.groups, rate)
+        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
+                             scaler_rate=rate, label_mask=lm)
+        return out["loss"]
+
+    loss, grads = jax.value_and_grad(system_loss)(params)
+    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
+    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
+    ref_loss, ref_grads = jax.value_and_grad(
+        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm)))(sub)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    inside = common.take(grads, index)
+    for k, g in ref_grads.items():
+        g = np.asarray(g)
+        np.testing.assert_allclose(inside[k], g, atol=1e-3 * np.abs(g).max() + 1e-9,
+                                   err_msg=k)
+        outside = np.ones(grads[k].shape, bool)
+        outside[np.ix_(*index[k])] = False
+        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
+
+
+def _stacked_experts(params, held, layer=1):
+    return [jnp.stack([params[f"l{layer}.moe.e{j}.{m}.w"] for j in held]) for m in "gud"]
+
+
+def test_kanana2_the_shares_add_up():
+    """(c) The routed parts that all four shares compute, with what every
+    share computes alike (attention, the shared experts) counted once, are
+    the uncut layer: per expert layer, y(whole) - shared = sum over shares of
+    (y(share) - shared)."""
+    from heterofl_tpu.ops import layers as L
+
+    cfg, model, _, tokens, _, _ = _kanana_case(expert_share=[0, 1])
+    whole = model.init(jax.random.key(3))
+    h = jax.random.normal(jax.random.key(4), (tokens.size, cfg["kanana2"]["hidden_size"]))
+    arch, sc = cfg["kanana2"], (lambda x: x)
+    sel, w = L.moe_route(h, whole["l1.moe.router.w"], whole["l1.moe.router.b"] + 0.05,
+                         arch["num_experts_per_tok"], arch["routed_scaling_factor"])
+
+    def routed(first, n):
+        return L.moe_experts(h, sel, w, _stacked_experts(whole, range(first, first + n)),
+                             first, sc, tile=8)
+
+    y_whole, c_whole = routed(0, 16)
+    parts = [routed(4 * i, 4) for i in range(4)]
+    np.testing.assert_allclose(sum(y for y, _ in parts), y_whole, rtol=1e-5, atol=1e-6)
+    assert float(c_whole["assign"][1]) == sel.size  # every pair lands somewhere
+    assert sum(float(c["assign"][1]) for _, c in parts) == sel.size
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(c["tokens"]) for _, c in parts]), c_whole["tokens"])
+    # and through the model: a share's logits differ from the whole model's
+    # by what the absent experts add, so the four shares' layers are not alike
+    share = make_model(dict(cfg, kanana2=dict(arch, expert_share=[1, 4])))
+    sub = {k: whole[k] for k in share.meta["shapes"]}
+    out_s, _ = share.apply(sub, {"label": tokens}, train=False)
+    out_w, _ = model.apply(whole, {"label": tokens}, train=False)
+    assert np.abs(np.asarray(out_s["score"]) - np.asarray(out_w["score"])).max() > 1e-4
+
+
+@pytest.mark.parametrize("tile", [8, 256])
+def test_kanana2_no_token_is_dropped_when_one_expert_takes_everything(tile):
+    """(d) A selection bias that sends every token to held expert 5 (and two
+    more choices each): its group is every token, four tiles of 8 rows (or
+    one of 256), the counters say that every pair was computed, and result
+    and gradients are the plain per-expert sum's."""
+    from benchmark.reference import kanana2 as ref
+    from heterofl_tpu.ops import layers as L
+
+    cfg, model, params, tokens, _, rm = _kanana_case()
+    arch = cfg["kanana2"]
+    t = tokens.size
+    h = jax.random.normal(jax.random.key(5), (t, arch["hidden_size"]))
+    bias = jnp.zeros(arch["n_routed_experts"]).at[5].set(100.0)
+    sel, w = L.moe_route(h, params["l1.moe.router.w"], bias,
+                         arch["num_experts_per_tok"], arch["routed_scaling_factor"])
+    assert bool(jnp.all(jnp.any(sel == 5, axis=-1)))
+    held = model.meta["held_experts"]
+
+    def grouped(h, w, experts):
+        return L.moe_experts(h, sel, w, experts, held[0], lambda x: x / 0.5, tile=tile)
+
+    def plain(h, w, experts):  # one expert at a time over all tokens
+        p = {f"e{j}.{m}.w": experts[i][n] for n, j in enumerate(held) for i, m in enumerate("gud")}
+        return sum(jnp.sum(jnp.where(sel == j, w, 0.0), -1)[:, None]
+                   * ref._ffn(p, f"e{j}", h, 0.5) for j in held)
+
+    experts = _stacked_experts(params, held)
+    y, c = grouped(h, w, experts)
+    assert float(c["tokens"][5 - held[0]]) == t and float(c["assign"][2]) == 0.0
+    np.testing.assert_allclose(y, plain(h, w, experts), rtol=1e-5, atol=1e-6)
+    probe = jax.random.normal(jax.random.key(6), y.shape)
+    got = jax.grad(lambda *a: jnp.sum(grouped(*a)[0] * probe), argnums=(0, 1, 2))(h, w, experts)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * probe), argnums=(0, 1, 2))(h, w, experts)
+    for g, r in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5 * float(jnp.abs(r).max()))
+
+
+def test_causal_latent_attention_in_blocks_is_the_attention_in_one():
+    """The query blocks are memory, not mathematics: 16 positions in blocks
+    of 8 (and of 5, a ragged last block) against one block."""
+    from heterofl_tpu.ops import layers as L
+
+    ks = jax.random.split(jax.random.key(7), 5)
+    qn, kn, v = (jax.random.normal(k, (2, 16, 4, 16)) for k in ks[:3])
+    qr, kr = jax.random.normal(ks[3], (2, 16, 4, 8)), jax.random.normal(ks[4], (2, 16, 8))
+    whole = L.causal_latent_attention(qn, qr, kn, kr, v, 0.2, block=16)
+    for block in (8, 5):
+        np.testing.assert_allclose(L.causal_latent_attention(qn, qr, kn, kr, v, 0.2, block=block),
+                                   whole, rtol=1e-5, atol=1e-6)

@@ -452,6 +452,33 @@ def test_report_renders_snapshot(tmp_path, capsys):
         R.find_ledger(str(tmp_path / "empty"))
 
 
+def test_report_prints_the_expert_layers_counters(tmp_path, capsys):
+    """`probes` events that carry an expert layer's counters (ISSUE 28) sum
+    over the run's rounds into one line of the report, dropped pairs
+    included (always 0)."""
+    from heterofl_tpu.obs import report as R
+    from heterofl_tpu.obs.trace import TraceRecorder
+
+    led = ClientLedger(10, [1.0])
+    led.update(1, np.arange(4), np.ones(4, np.float32), np.ones(4, np.float32),
+               np.ones(4, np.float32))
+    run_dir = tmp_path / "trace" / "run0"
+    led.save(str(run_dir / "ledger.npz"))
+    rec = TraceRecorder(str(run_dir))
+    for epoch in (1, 2):
+        rec.instant("probes", cat="obs", args={
+            "epoch": epoch, "moe_tokens": [10.0, 20.0, 30.0, 36.0],
+            "moe_assign": [1536.0, 96.0, 0.0], "moe_held_share": 0.0625,
+            "moe_dropped": 0})
+    rec.close()
+    assert R.main([str(run_dir)]) == 0
+    text = capsys.readouterr().out
+    assert "pairs on held experts 192 of 3072 (6.25 %), dropped 0" in text
+    assert "tokens per held expert: 20 40 60 72" in text
+    assert R.main([str(run_dir), "--json"]) == 0
+    assert json.loads(capsys.readouterr().out)["events"]["moe"]["rounds"] == 2
+
+
 def test_watchdog_abort_preserves_evidence_on_disk(tmp_path):
     """The durability satellite: after an induced abort the LAST events
     record is the watchdog instant, the Chrome trace is written, and the

@@ -214,3 +214,49 @@ def test_every_pallas_call_is_named(kernel, module):
     src = inspect.getsource(importlib.import_module(f"heterofl_tpu.ops.{module}"))
     assert kernel in trace.KERNELS and f'name="{kernel}"' in src
     assert src.count("pallas_call(") == src.count("        name=")
+
+
+# ---------------------------------------------------------------------------
+# the scopes ISSUE 28 added (obs.trace.EXTRA_SCOPES)
+# ---------------------------------------------------------------------------
+
+def _kanana_program(chunk):
+    from test_round import _chunk_case
+
+    cfg, data = _chunk_case("kanana2")
+    cfg = dict(cfg, round_chunk=chunk, layout_policy="none")
+    model = make_model(cfg)
+    eng = RoundEngine(model, cfg, make_mesh(2, 1))
+    users = np.arange(8, dtype=np.int32)
+    return eng._build_train(), _round_args(eng, model.init(jax.random.key(0)), users, data)
+
+
+def test_the_expert_layers_and_the_chunk_carry_their_names():
+    """Every scope of `EXTRA_SCOPES` reaches the chunked Kanana-2 round's
+    `op_name`s, nested as the program nests them: `mla`, `rope`, `attn` and
+    the four `moe/*` under `step/model`, forward and backward; `attn` holds
+    the scores and no projection; `round/chunk` encloses the local training
+    of a chunk; the unchunked program has no `round/chunk`."""
+    assert not set(trace.EXTRA_SCOPES) & set(trace.SCOPES)
+    prog, args = _kanana_program(2)
+    # of the COMPILED program: the expert layers are the body of a scan, a
+    # function of its own whose paths the lowering writes relative to it and
+    # XLA completes when it inlines the call
+    names = ["/" + n for n in re.findall(
+        r'op_name="([^"]+)"', prog.lower(*args).compile().as_text())]
+    for s in trace.EXTRA_SCOPES:
+        assert any(f"/{s}/" in n for n in names), f"no op_name carries {s!r}"
+    for s in ("mla", "rope", "attn", "moe/router", "moe/dispatch", "moe/experts",
+              "moe/shared"):
+        for wrap in ("jvp(step/model)", "transpose(jvp(step/model))"):
+            if s == "moe/router" and wrap.startswith("transpose"):
+                continue  # top-k has no backward; the scores' lies under it
+            assert any(f"/{wrap}/" in n and f"/{s}/" in n for n in names), (s, wrap)
+    assert any("/round/chunk/round/local_train/" in n for n in names)
+    assert any("/round/chunk/round/aggregate/" in n for n in names)
+    attn = [n for n in names if "/attn/" in n]
+    assert attn and not any("/linear/" in n for n in attn)
+    plain, plain_args = _kanana_program(None)
+    assert not any("round/chunk" in n for n in _op_names(plain, plain_args))
+    with pytest.raises(ValueError, match="Not valid scope"):
+        trace.scope("moe/expert")
