@@ -336,11 +336,29 @@ def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
 
     ``qn``/``kn`` ``[N, S, H, dn]`` (no-position dims), ``qr`` ``[N, S, H,
     dr]`` and ``kr`` ``[N, S, dr]`` (rotary dims, ONE key head shared by all
-    query heads), ``v`` ``[N, S, H, dv]``.  Runs in query blocks of ``block``
-    rows against the keys up to the block's end, each block under
-    ``jax.checkpoint``: no ``[S, S]`` score matrix of a whole row is ever
-    held, in the forward or for the backward, and key blocks above the
-    diagonal are never computed.  Softmax in float32."""
+    query heads), ``v`` ``[N, S, H, dv]``.  Softmax in float32, float32 out.
+
+    On a TPU, where the positions make whole tiles and the head dims fill
+    the lanes (``pallas_attention.tile_for``), the fused kernels of
+    ops/pallas_attention.py: a score tile lives in VMEM only.  Elsewhere (the
+    CPU; a client's narrow slice at its own widths)
+    :func:`blockwise_latent_attention` in query blocks of ``block`` rows."""
+    if jax.default_backend() == "tpu":
+        from . import pallas_attention  # jax's Pallas: a second of import, paid where it is used
+
+        tile = pallas_attention.tile_for(qn.shape[1], qn.shape[-1], qr.shape[-1], v.shape[-1])
+        if tile is not None:
+            return pallas_attention.fused_latent_attention(
+                qn, qr, kn, kr, v, scale, block_q=tile, block_k=tile)
+    return blockwise_latent_attention(qn, qr, kn, kr, v, scale, block)
+
+
+def blockwise_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
+    """:func:`causal_latent_attention` in plain ``jnp`` (and the fused
+    kernels' oracle): query blocks of ``block`` rows against the keys up to
+    the block's end, each block under ``jax.checkpoint``: no ``[S, S]`` score
+    matrix of a whole row is ever held, in the forward or for the backward,
+    and key blocks above the diagonal are never computed."""
     S = qn.shape[1]
     outs = []
     for start in range(0, S, block):

@@ -1,0 +1,270 @@
+"""Pallas TPU kernels for causal latent attention (the ``attn`` scope).
+
+The score / softmax / value part of ``ops.layers.causal_latent_attention``
+as one fused online-softmax ("flash") kernel with a hand-written backward,
+so a score tile lives in VMEM only: the blockwise ``jnp`` form writes every
+query block's float32 scores ``[N, H, block, <=S]`` to HBM and reads them
+back for the mask, the max, the exponent, the sum and the value product.
+
+* ``latent_attn_fwd``: a (row, head, query tile) runs over the key tiles up
+  to the diagonal (tiles above it are neither fetched nor computed; only
+  tiles the diagonal crosses are masked), ``s = qn kn^T + qr kr^T`` with
+  running max, sum and value accumulator in float32; writes the output and
+  one float32 log-sum-exp a (row, head, position).
+* ``latent_attn_bwd``: from ``q``, ``k``, ``v``, the log-sum-exp,
+  ``delta = sum(o * do)`` and ``do`` a (row, head, key tile) runs over the
+  query tiles from the diagonal down, recomputes a tile's probabilities
+  (transposed, so the row statistics broadcast along sublanes) and
+  accumulates ``dkn, dkr, dv`` in the key tile's output blocks and ``dqn,
+  dqr`` in an output block that holds the head's whole sequence.  The rotary key is ONE head:
+  the kernel writes its gradient a head, the caller sums over heads.
+
+Precision: operands of every product bfloat16, accumulated in float32 (what
+the chip's default precision makes of float32 operands in the ``jnp`` form);
+scores, mask, max, exponent, sum, log-sum-exp, ``delta`` and every
+accumulator float32.  The softmax scale is not a kernel operand: the caller
+multiplies ``q`` by it in float32 before the cast, which also carries a
+per-client scale through ``vmap`` and leaves its gradient to autodiff.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: lanes of a vector register: the row statistics are kept replicated over them
+LANES = 128
+#: what a masked score is set to (finite, 0.7 of float32's largest: no ``inf - inf``)
+MASKED = -2.38e38
+
+_NT = (((1,), (1,)), ((), ()))  # a b^T
+_NN = (((1,), (0,)), ((), ()))  # a b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _over_lanes(x, n):
+    """``x`` ``[rows, LANES]`` (replicated) as ``[rows, n]``, ``n`` whole lanes."""
+    return jnp.tile(x, (1, n // LANES))
+
+
+def _causal(s, q0, k0, keys_first: bool):
+    """``s`` with the pairs (query < key) masked; ``s`` ``[tq, tk]``, or
+    ``[tk, tq]`` if ``keys_first``; ``q0``/``k0`` the tile's first positions."""
+    q = q0 + lax.broadcasted_iota(jnp.int32, s.shape, 1 if keys_first else 0)
+    k = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 0 if keys_first else 1)
+    return jnp.where(q >= k, s, MASKED)
+
+
+def _when_needed(i, j, tq, tk, step):
+    """Run ``step(masked)`` for the tile (query tile ``i``, key tile ``j``)
+    unless it lies above the diagonal; masked only if the diagonal crosses it."""
+    needed = j * tk <= i * tq + tq - 1
+    crossed = j * tk + tk - 1 > i * tq
+    pl.when(jnp.logical_and(needed, crossed))(partial(step, True))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(crossed)))(partial(step, False))
+
+
+def _fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, m_s, l_s, *,
+                tq: int, tk: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_s[...] = jnp.full_like(m_s, MASKED)
+        l_s[...] = jnp.zeros_like(l_s)
+        o_ref[...] = jnp.zeros_like(o_ref)  # the value accumulator until the last key tile
+
+    def step(masked):
+        s = _dot(qn_ref[...], kn_ref[...], _NT) + _dot(qr_ref[...], kr_ref[...], _NT)
+        if masked:
+            s = _causal(s, i * tq, j * tk, keys_first=False)
+        m_prev = m_s[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _over_lanes(m_next, tk))
+        alpha = jnp.exp(m_prev - m_next)
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=-1, keepdims=True)
+        m_s[...] = m_next
+        v = v_ref[...]
+        o_ref[...] = _over_lanes(alpha, v.shape[-1]) * o_ref[...] \
+            + _dot(p.astype(v.dtype), v, _NN)
+
+    _when_needed(i, j, tq, tk, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l = l_s[...]
+        o_ref[...] = o_ref[...] / _over_lanes(l, o_ref.shape[-1])
+        lse_ref[...] = m_s[...] + jnp.log(l)
+
+
+def _bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref, *, tq: int, tk: int):
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(jnp.logical_and(j == 0, i == 0))
+    def _():  # a head's whole sequence, resident over (j, i)
+        dqn_ref[...] = jnp.zeros_like(dqn_ref)
+        dqr_ref[...] = jnp.zeros_like(dqr_ref)
+
+    @pl.when(i == 0)
+    def _():  # a key tile's, resident over i
+        dkn_ref[...] = jnp.zeros_like(dkn_ref)
+        dkr_ref[...] = jnp.zeros_like(dkr_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    def step(masked):
+        qn, qr, kn, kr = qn_ref[...], qr_ref[...], kn_ref[...], kr_ref[...]
+        do = do_ref[...]
+        st = _dot(kn, qn, _NT) + _dot(kr, qr, _NT)               # [tk, tq]
+        if masked:
+            st = _causal(st, i * tq, j * tk, keys_first=True)
+        pt = jnp.exp(st - lse_ref[...])                           # lse [1, tq]
+        dv_ref[...] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v_ref[...], do, _NT) - delta_ref[...])
+        ds = dst.T.astype(kn.dtype)                               # [tq, tk]
+        dst = dst.astype(qn.dtype)
+        dkn_ref[...] += _dot(dst, qn, _NN)
+        dkr_ref[...] += _dot(dst, qr, _NN)
+        rows = pl.ds(pl.multiple_of(i * tq, tq), tq)
+        dqn_ref[rows, :] += _dot(ds, kn, _NN)
+        dqr_ref[rows, :] += _dot(ds, kr, _NN)
+
+    _when_needed(i, j, tq, tk, step)
+
+
+def _params(vmem_mb):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=vmem_mb << 20)
+
+
+def _head_tile(t, d, tile_of):
+    """A ``[t, d]`` tile of a ``[N, H, S, d]`` operand; ``tile_of(a, b)`` the
+    tile's index along ``S`` at the grid's last two coordinates."""
+    return pl.BlockSpec((None, None, t, d), lambda n, h, a, b: (n, h, tile_of(a, b), 0))
+
+
+def _call_fwd(qn, qr, kn, kr, v, tq, tk, interpret):
+    N, H, S, dn = qn.shape
+    dr, dv = qr.shape[-1], v.shape[-1]
+
+    def query(i, j):
+        return i
+
+    def key(i, j):  # a tile above the diagonal is never fetched
+        return jnp.minimum(j, (i * tq + tq - 1) // tk)
+
+    return pl.pallas_call(
+        partial(_fwd_kernel, tq=tq, tk=tk),
+        grid=(N, H, S // tq, S // tk),
+        in_specs=[
+            _head_tile(tq, dn, query), _head_tile(tq, dr, query),
+            _head_tile(tk, dn, key),
+            pl.BlockSpec((None, tk, dr), lambda n, h, i, j: (n, key(i, j), 0)),
+            _head_tile(tk, dv, key),
+        ],
+        out_specs=[_head_tile(tq, dv, query), _head_tile(tq, LANES, query)],
+        out_shape=[jax.ShapeDtypeStruct((N, H, S, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((N, H, S, LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tq, LANES), jnp.float32)] * 2,
+        compiler_params=_params(32),
+        interpret=interpret,
+        name="latent_attn_fwd",
+    )(qn, qr, kn, kr, v)
+
+
+def _call_bwd(qn, qr, kn, kr, v, do, lse, delta, tq, tk, interpret):
+    N, H, S, dn = qn.shape
+    dr, dv = qr.shape[-1], v.shape[-1]
+
+    def key(j, i):
+        return j
+
+    def query(j, i):  # a tile above the diagonal is never fetched
+        return jnp.maximum(i, (j * tk) // tq)
+
+    def row_stat():
+        return pl.BlockSpec((None, None, 1, tq), lambda n, h, j, i: (n, h, 0, query(j, i)))
+
+    def whole(d):
+        return pl.BlockSpec((None, None, S, d), lambda n, h, j, i: (n, h, 0, 0))
+
+    f32 = jnp.float32
+    return pl.pallas_call(
+        partial(_bwd_kernel, tq=tq, tk=tk),
+        grid=(N, H, S // tk, S // tq),
+        in_specs=[
+            _head_tile(tq, dn, query), _head_tile(tq, dr, query),
+            _head_tile(tk, dn, key),
+            pl.BlockSpec((None, tk, dr), lambda n, h, j, i: (n, j, 0)),
+            _head_tile(tk, dv, key), _head_tile(tq, dv, query),
+            row_stat(), row_stat(),
+        ],
+        out_specs=[whole(dn), whole(dr), _head_tile(tk, dn, key),
+                   _head_tile(tk, dr, key), _head_tile(tk, dv, key)],
+        out_shape=[jax.ShapeDtypeStruct((N, H, S, d), f32) for d in (dn, dr, dn, dr, dv)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="latent_attn_bwd",
+    )(qn, qr, kn, kr, v, do, lse, delta)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash(qn, qr, kn, kr, v, tq, tk, interpret):
+    """Heads first: ``qn``/``kn`` ``[N, H, S, dn]``, ``qr`` ``[N, H, S, dr]``,
+    ``v`` and the result ``[N, H, S, dv]``; ``kr`` ``[N, S, dr]``; ``q``
+    already scaled.  Float32 in, out and in every gradient."""
+    return _flash_fwd(qn, qr, kn, kr, v, tq, tk, interpret)[0]
+
+
+def _flash_fwd(qn, qr, kn, kr, v, tq, tk, interpret):
+    ops = tuple(x.astype(jnp.bfloat16) for x in (qn, qr, kn, kr, v))
+    o, lse = _call_fwd(*ops, tq, tk, interpret)
+    return o, (ops, o, lse[..., 0])
+
+
+def _flash_bwd(tq, tk, interpret, res, do):
+    ops, o, lse = res
+    delta = jnp.sum(o * do, axis=-1)
+    dqn, dqr, dkn, dkr, dv = _call_bwd(
+        *ops, do.astype(jnp.bfloat16), lse[:, :, None, :], delta[:, :, None, :],
+        tq, tk, interpret)
+    return dqn, dqr, dkn, jnp.sum(dkr, axis=1), dv
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+#: positions a tile takes, queries and keys alike: the largest that divides ``S``
+TILES = (512, 256, 128)
+
+
+def tile_for(S: int, dn: int, dr: int, dv: int):
+    """The tile the kernels take at these shapes, None if they take none:
+    whole tiles of positions, and head dims that fill the lanes (the rotary
+    ones half of them); a client's narrow slice at its own widths does not."""
+    if dn % LANES or dv % LANES or dr % (LANES // 2):
+        return None
+    return next((t for t in TILES if S % t == 0), None)
+
+
+def fused_latent_attention(qn, qr, kn, kr, v, scale, *, block_q: int, block_k: int,
+                           interpret: bool = False):
+    """``ops.layers.causal_latent_attention`` through the kernels above, its
+    operands and result in its layouts (``[N, S, H, d]``; ``kr`` ``[N, S,
+    dr]``), float32 out; tiles of ``block_q`` queries by ``block_k`` keys."""
+    def heads_first(x):
+        return jnp.swapaxes(x.astype(jnp.float32), 1, 2)
+
+    o = _flash(heads_first(qn) * scale, heads_first(qr) * scale, heads_first(kn),
+               kr.astype(jnp.float32), heads_first(v), block_q, block_k, interpret)
+    return jnp.swapaxes(o, 1, 2)

@@ -1,0 +1,132 @@
+"""The fused causal latent attention (ops/pallas_attention.py) against its
+oracle, the ``jnp`` form ``ops.layers.blockwise_latent_attention``:
+on the CPU the kernels run in interpret mode, which says that the tiling,
+the skipped and the masked tiles, the online softmax and the hand-written
+backward are right; what Mosaic accepts is tests/test_tpu_compile.py's.
+
+Operands are bfloat16-representable and the scale a power of two, so the
+kernel's casts are exact and what is left is its bfloat16 probabilities
+against the oracle's float32 ones: 2^-9 of a term, held to 1 % of the largest
+element (a wrong mask, a dropped tile or a missing head moves O(1))."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from heterofl_tpu.ops import layers as L
+from heterofl_tpu.ops import pallas_attention as PA
+
+SCALE = 0.125
+
+
+def _operands(key, N=1, S=256, H=2, dn=128, dr=64, dv=128, active=None):
+    """qn, qr, kn, kr, v and a probe of the output's shape; with ``active =
+    (an, ar, av)`` the head dims past those counts are zero, as a narrow
+    client's are under the masked engine."""
+    shapes = [(N, S, H, dn), (N, S, H, dr), (N, S, H, dn), (N, S, dr), (N, S, H, dv),
+              (N, S, H, dv)]
+    ops = [jax.random.normal(k, s).astype(jnp.bfloat16).astype(jnp.float32)
+           for k, s in zip(jax.random.split(key, 6), shapes)]
+    if active is not None:
+        an, ar, av = active
+        keep = [an, ar, an, ar, av, av]
+        ops = [jnp.where(jnp.arange(x.shape[-1]) < c, x, 0.0) for x, c in zip(ops, keep)]
+    return ops[:5], ops[5]
+
+
+def _out_and_grads(attention, ops, probe, scale):
+    def loss(*a):
+        o = attention(*a, scale)
+        return jnp.sum(o * probe), o
+
+    grads, o = jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*ops)
+    return (o,) + grads
+
+
+def _fused(block_q, block_k):
+    return lambda *a: PA.fused_latent_attention(*a, block_q=block_q, block_k=block_k,
+                                                interpret=True)
+
+
+def _oracle(*a):
+    return L.blockwise_latent_attention(*a, block=64)
+
+
+def _close(got, want):
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-2 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("case", [
+    "tiles-128x128", "tiles-256x128", "tiles-128x256", "tiles-256x256", "tiles-384x128",
+    "narrow-client", "vmap-clients", "rotary-key-heads"])
+def test_fused_attention_is_the_blockwise_attention(case):
+    """Output and the gradients of all five operands: at several query and
+    key tiles (the diagonal crosses a tile, lies on its corner, or a query
+    tile spans three key tiles); with zero-suffix head dims; under ``vmap``
+    over clients with a per-client scale; and the shared rotary key's
+    gradient, the sum over heads of what each head's own copy would get."""
+    if case.startswith("tiles-"):
+        bq, bk = (int(t) for t in case[len("tiles-"):].split("x"))
+        ops, probe = _operands(jax.random.key(1), S=768 if bq == 384 else 256)
+        _close(_out_and_grads(_fused(bq, bk), ops, probe, SCALE),
+               _out_and_grads(_oracle, ops, probe, SCALE))
+    elif case == "narrow-client":
+        ops, probe = _operands(jax.random.key(2), active=(8, 4, 8))
+        got = _out_and_grads(_fused(128, 128), ops, probe, 0.25)
+        _close(got, _out_and_grads(_oracle, ops, probe, 0.25))
+        o, dqn, dqr, dkn, dkr, dv = got
+        assert not np.any(o[..., 8:]) and not np.any(dkn[..., 8:]) and not np.any(dkr[..., 4:])
+    elif case == "vmap-clients":
+        clients = [_operands(k, S=128) for k in jax.random.split(jax.random.key(3), 3)]
+        ops = [jnp.stack(x) for x in zip(*(c[0] for c in clients))]
+        probe = jnp.stack([c[1] for c in clients])
+        scales = jnp.asarray([0.125, 0.25, 0.0625])
+
+        def over_clients(attention):
+            return jax.vmap(lambda o, p, s: _out_and_grads(attention, o, p, s))(ops, probe, scales)
+
+        _close(over_clients(_fused(128, 128)), over_clients(_oracle))
+    else:
+        H = 4
+        ops, probe = _operands(jax.random.key(4), S=128, H=H)
+        qn, qr, kn, kr, v = ops
+
+        def own_copy_a_head(kr_heads):  # plain attention, one rotary key a head
+            s = (jnp.einsum("nqhd,nkhd->nhqk", qn, kn)
+                 + jnp.einsum("nqhd,nkhd->nhqk", qr, kr_heads)) * SCALE
+            s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+            return jnp.sum(jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, -1), v) * probe)
+
+        a_head = jax.grad(own_copy_a_head)(jnp.repeat(kr[:, :, None], H, axis=2))
+        dkr = _out_and_grads(_fused(128, 128), ops, probe, SCALE)[4]
+        _close([dkr], [jnp.sum(a_head, axis=2)])
+
+
+def _calls(S, dn, dr, dv, backend, monkeypatch):
+    """Names of the ``pallas_call``s in ``causal_latent_attention``'s program
+    at these shapes when jax reports ``backend``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    shapes = [(1, S, 2, dn), (1, S, 2, dr), (1, S, 2, dn), (1, S, dr), (1, S, 2, dv)]
+    jaxpr = jax.make_jaxpr(lambda *a: L.causal_latent_attention(*a, 0.1))(
+        *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
+    text = str(jaxpr)
+    assert text.count("pallas_call") == text.count("latent_attn_")
+    return re.findall(r"latent_attn_\w+", text)
+
+
+@pytest.mark.parametrize("S, dn, dr, dv, backend, fused", [
+    (512, 128, 64, 128, "tpu", True),     # the benchmark cell's head dims
+    (384, 256, 128, 128, "tpu", True),    # 128-position tiles
+    (512, 128, 64, 128, "cpu", False),    # the CPU takes the jnp form
+    (500, 128, 64, 128, "tpu", False),    # positions that make no whole tile
+    (512, 8, 4, 8, "tpu", False),         # a rate-1/16 client's own widths
+    (512, 128, 64, 64, "tpu", False),     # value dims that do not fill the lanes
+], ids=["cell-dims", "tile-128", "cpu", "ragged-positions", "narrow-widths", "half-lane-values"])
+def test_which_form_runs_is_decided_by_backend_and_shapes(S, dn, dr, dv, backend, fused,
+                                                          monkeypatch):
+    assert _calls(S, dn, dr, dv, backend, monkeypatch) == (["latent_attn_fwd"] if fused else [])

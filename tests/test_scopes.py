@@ -206,13 +206,15 @@ def test_the_vocabulary_is_closed():
 
 @pytest.mark.parametrize("kernel, module", [
     ("fused_sgd", "fused_update"), ("masked_bn_fwd", "pallas_norm"),
-    ("masked_bn_bwd", "pallas_norm"), ("int8_pack", "quant")])
+    ("masked_bn_bwd", "pallas_norm"), ("int8_pack", "quant"),
+    ("latent_attn_fwd", "pallas_attention"), ("latent_attn_bwd", "pallas_attention")])
 def test_every_pallas_call_is_named(kernel, module):
     import importlib
     import inspect
 
     src = inspect.getsource(importlib.import_module(f"heterofl_tpu.ops.{module}"))
-    assert kernel in trace.KERNELS and f'name="{kernel}"' in src
+    assert kernel in trace.KERNELS + trace.EXTRA_KERNELS and f'name="{kernel}"' in src
+    assert not set(trace.KERNELS) & set(trace.EXTRA_KERNELS)
     assert src.count("pallas_call(") == src.count("        name=")
 
 

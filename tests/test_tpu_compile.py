@@ -66,6 +66,7 @@ def _compile(fn, *avals, kernels):
     assert "tpu_custom_call" in text  # no XLA stand-in, no interpreter
     for name in kernels:
         assert name in text, f"no instruction of the program carries {name!r}"
+    return text
 
 
 @pytest.mark.parametrize("vmapped", [False, True], ids=["bare", "vmap10"])
@@ -129,3 +130,40 @@ def test_pallas_norm_compiles(one_chip, n, h, c):
     _compile(grads, sds(n, h, h, c), sds(c), sds(c), sds(n), kernels=bn)
     _compile(jax.vmap(grads), sds(SLOTS, n, h, h, c), sds(SLOTS, c),
              sds(SLOTS, c), sds(SLOTS, n), kernels=bn)
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["cell", "vmap10"])
+def test_latent_attention_kernels_compile_under_attn(one_chip, vmapped, monkeypatch):
+    """The fused causal latent attention, forward and backward, at the
+    Kanana-2 cell's shapes (2 rows x 2,048 positions, 32 heads, 128 | 64 | 128
+    head dims), as the model calls it (the described chip is not the default
+    backend, so the test steers the one question the function asks), bare and
+    under ``vmap`` over client slots with a per-client scale; and both custom
+    calls carry the ``attn`` scope, forward and transposed, by which the
+    traced run's metrics find them."""
+    import re
+
+    from heterofl_tpu.ops.layers import causal_latent_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, S, H, dn, dr, dv = 2, 2048, 32, 128, 64, 128
+
+    def grads(qn, qr, kn, kr, v, scale):
+        return jax.grad(lambda *a: jnp.sum(causal_latent_attention(*a, scale) ** 2),
+                        argnums=(0, 1, 2, 3, 4))(qn, qr, kn, kr, v)
+
+    lead = (SLOTS,) if vmapped else ()
+    avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip)
+             for s in ((N, S, H, dn), (N, S, H, dr), (N, S, H, dn), (N, S, dr),
+                       (N, S, H, dv), ())]
+    text = _compile(jax.vmap(grads) if vmapped else grads, *avals,
+                    kernels=("latent_attn_fwd", "latent_attn_bwd"))
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    op_names = sorted(re.search(r'op_name="([^"]*)"', line).group(1) for line in calls)
+    assert len(op_names) == 2
+    assert re.search(r"jvp\(attn\)\)?/latent_attn_fwd/pallas_call$", op_names[0])
+    assert re.search(r"transpose\((vmap\()?jvp\(attn\)\)+/latent_attn_bwd/pallas_call$", op_names[1])
+    # no [rows, heads, queries, keys] score block goes through HBM
+    assert not [m for m in re.findall(r"f32\[(?:10,)?2,32,(\d+),(\d+)\]", text)
+                if int(m[0]) > 1 and int(m[1]) > max(dn, dv)]
