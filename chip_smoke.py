@@ -10,17 +10,16 @@ prints no result.  Nothing is caught on the way -- a phase that raises ends
 the script with a traceback.  One chip, in order:
 
 1. every Pallas kernel against its XLA reference at ResNet-18's real sizes
-   (fused masked-SGD, int8 quantise+pack, fused batch norm fwd/bwd);
+   (int8 quantise+pack, fused batch norm fwd/bwd);
 2. ``entry.train_classifier_fed.main``: 3 masked-engine rounds of the
    README's flagship control (full-width ResNet-18, CIFAR-10 shapes from a
    seed, 100 users, 10 active, 5 local epochs x batch 10 x 500 samples);
 3. the same entry again, resuming that checkpoint for one round more on the
    grouped engine, then ``entry.test_classifier_fed.main`` on the result;
 4. on a ``FedExperiment`` built as ``run_main`` builds it: the lowered round
-   program carries the parameter and momentum leaves through the step (none
-   of the flat carry's plumbing scopes, no ``tpu_custom_call``), the layout
-   pinner is active, and a round of smallest-width clients leaves everything
-   outside their slice bit-for-bit untouched.
+   program holds its ``step/model`` and ``step/update`` scopes and no
+   ``tpu_custom_call``, and a round of smallest-width clients leaves
+   everything outside their slice bit-for-bit untouched.
 
 ``--chips 4`` runs none of that: only the flagship round on the default 4x1
 mesh and on one device, compared at COMPARE_LR (see the constants below for
@@ -98,46 +97,12 @@ def check_kernels(total=11_173_962):
     import jax.numpy as jnp
     import numpy as np
 
-    from heterofl_tpu.ops.fused_update import FlatSpec, fused_sgd_flat
     from heterofl_tpu.ops.layers import batch_norm
     from heterofl_tpu.ops.pallas_norm import batch_norm_pallas
     from heterofl_tpu.ops.quant import quantize_pack, unpack_lanes
 
     ks = jax.random.split(jax.random.key(0), 8)
-    spec = FlatSpec({"w": (total,)})
     p = jax.random.normal(ks[0], (total,))
-    g = jax.random.normal(ks[1], (total,)) * 1e-3
-    b = jax.random.normal(ks[2], (total,)) * 0.1
-    m = (jax.random.uniform(ks[3], (total,)) < 0.5).astype(jnp.float32)
-
-    def sgd(mode):
-        return jax.jit(lambda p_, g_, b_, m_: fused_sgd_flat(
-            spec, p_, {"w": g_}, b_, {"w": m_}, jnp.float32(10.0),
-            jnp.float32(0.1), momentum=0.9, weight_decay=5e-4,
-            has=jnp.asarray(True), mode=mode))(p, g, b, m)
-
-    for got, ref in zip(sgd("pallas"), sgd("xla")):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=1e-6, atol=1e-7)
-
-    # ... and as the round calls it: under vmap over client slots, each
-    # with its own buffers, denominator and has-gate (one slot skipped, one
-    # clipped) -- a norm that leaked from slot to slot would show here
-    scales = jnp.asarray([1.0, 1.0, 3e3])[:, None]
-    denoms = jnp.asarray([10.0, 5.0, 20.0])
-    gates = jnp.asarray([True, False, True])
-
-    def sgd_slots(mode):
-        return jax.jit(jax.vmap(lambda s_, n_, h_: fused_sgd_flat(
-            spec, p * s_, {"w": g * s_}, b, {"w": m}, n_, jnp.float32(0.1),
-            momentum=0.9, weight_decay=5e-4, has=h_, mode=mode)))(
-                scales, denoms, gates)
-
-    for got, ref in zip(sgd_slots("pallas"), sgd_slots("xla")):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-6)
-    print("chip_smoke: kernel fused_sgd (pallas vs xla, bare and under "
-          f"vmap over 3 slots, {total} f32): ok", flush=True)
 
     # the pack is exact (the words unpack to the kernel's own grid values);
     # the grid values may differ from XLA's by one level where the two
@@ -272,32 +237,18 @@ def one_chip(out_dir):
 
 
 def check_round_program(exp):
-    """The K=1 program ``run_main``'s engine dispatches on this backend
-    carries the parameter and momentum leaves through the local step as the
-    model reads them (``fused_update: True`` resolves to the tree carry with
-    the per-leaf chain on a TPU, PR 27): none of the flat carry's plumbing
-    scopes, no update kernel; and it commits its params through the layout
-    pinner."""
-    eng = exp.engine
-    if eng._fused_mode is not None:
-        raise AssertionError(f"fused mode {eng._fused_mode!r} on the TPU "
-                             "backend, not the tree carry (None)")
-    text = eng._build_train().lower(*round_program_args(exp)).as_text(
+    """The K=1 program ``run_main``'s engine dispatches: the local step's
+    model and update are there under their names, and no kernel is (the step
+    carries the parameter and momentum leaves and updates them per leaf)."""
+    text = exp.engine._build_train().lower(*round_program_args(exp)).as_text(
         debug_info=True)
-    for scope in ("update/flatten", "update/pack", "update/unpack",
-                  "step/unflatten"):
-        if scope in text:
-            raise AssertionError(f"round program holds the flat carry's {scope}")
     if "step/update" not in text or "step/model" not in text:
         raise AssertionError("round program lost its step/update or step/model scope")
     if "tpu_custom_call" in text:
         raise AssertionError("round program holds a tpu_custom_call: no "
-                             "kernel belongs in the tree-carry step")
-    if not eng._pin.active:
-        raise AssertionError("ParamPinner inactive on the TPU backend")
-    print("chip_smoke: round program carries the leaves (fused mode "
-          f"{eng._fused_mode}), no plumbing scope, no tpu_custom_call; "
-          f"ParamPinner.active {eng._pin.active}", flush=True)
+                             "kernel belongs in the local step")
+    print("chip_smoke: round program holds step/model and step/update, no "
+          "tpu_custom_call", flush=True)
 
 
 def check_masked_suffix(exp):

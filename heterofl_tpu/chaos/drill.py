@@ -2,7 +2,7 @@
 plan and assert the recovery contract (ISSUE 15).
 
 The drill is the chaos harness's executable spec, shared verbatim by the
-CLI, the tests and ``bench.py``'s ``BENCH_CHAOS`` pass:
+CLI and the tests:
 
 * **kill drills** (:func:`run_kill_drill`): run a small synthetic
   federation uninterrupted, then run it again with a

@@ -37,9 +37,9 @@ Codecs (``cfg['wire_codec']``):
   Wire: 2 bytes/element = 25% of dense.
 
 This module is import-light (no jax): the analytic byte accounting below
-is THE single source of truth consumed by ``fed.core.level_codec_byte_table``,
-the staticcheck wire budget (equality against traced psum operand avals)
-and ``bench.py``'s ``extra.wire`` -- there is no second bytes formula.
+is THE single source of truth consumed by ``fed.core.level_codec_byte_table``
+and the staticcheck wire budget (equality against traced psum operand
+avals) -- there is no second bytes formula.
 The jax codec implementations live in :mod:`.codecs`.
 """
 

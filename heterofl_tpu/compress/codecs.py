@@ -2,7 +2,7 @@
 
 Every codec transforms one device's partial aggregation contribution --
 the flat ``(update sums, count masks)`` pair in the
-:class:`~..ops.fused_update.FlatSpec` layout -- into a payload pytree that
+:class:`~..ops.flatspec.FlatSpec` layout -- into a payload pytree that
 rides ONE ``jax.lax.psum`` bind, then decodes the accumulated payload back
 to flat sums/counts.  The contract every codec must keep:
 

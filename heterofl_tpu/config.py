@@ -126,28 +126,6 @@ DEFAULT_CFG: Dict[str, Any] = {
     # lax.scan unroll factor for the local-step loop (1 = no unrolling);
     # latency-bound rounds can gain from fewer loop trips (not measured)
     "scan_unroll": 1,
-    # the local step's optimizer epilogue and the layout the scan carries
-    # params/momentum in (ops/fused_update.py).  True = chosen by the
-    # backend the program is compiled for: on a TPU the tree carry with the
-    # per-leaf reference chain (the leaves where the model reads them; the
-    # Pallas kernel and its flat carry were what True meant there until PR
-    # 27, and the v5e showed the flat carry's flatten / pack / unpack /
-    # unflatten at 60.0 of 78.6 ms a ResNet-18 step and 107.9 of 129.4 ms an
-    # LM step, the kernel itself at 8.2 / 14.0: ledger, PR 26); elsewhere
-    # the flat-carry XLA form (one lane-packed buffer each through the
-    # scan).  False = the seed program (tree carry + reference op chain) on
-    # every backend; "xla"/"pallas" force a flat-carry implementation.  The
-    # primitive and the engines' STEP results are bit-identical to the
-    # reference chain on the CPU (tests/test_fused_update.py); long
-    # multi-step trajectories agree at float-association level, like the
-    # masked-vs-sliced engine contract.  Non-SGD optimizers always use the
-    # reference chain.
-    "fused_update": True,
-    # explicit layout policy (models/layout.py): "auto" pins the params
-    # carry's device layouts (row-major; width axes lane-packed minor-most)
-    # at the program boundary on TPU backends and passes through on CPU;
-    # "pinned" forces the pin, "none" disables it.
-    "layout_policy": "auto",
     "param_dtype": "float32",
     "compute_dtype": "float32",  # set "bfloat16" to run matmuls/convs in bf16
     "mesh": {"clients": 0, "data": 1},  # 0 => use all available devices
@@ -260,8 +238,7 @@ DEFAULT_CFG: Dict[str, Any] = {
     # "perm" is the legacy full jax.random.permutation(num_users) draw,
     # bit-for-bit identical to the pre-ISSUE-11 stream (parity tests, old
     # trajectory reproduction).  The two are different streams: switching
-    # re-baselines every seeded trajectory, and bench.py refuses to
-    # compare records across them without BENCH_ALLOW_STREAM_CHANGE=1.
+    # re-baselines every seeded trajectory.
     "sampler": "prp",
     # schedule commitment (ISSUE 11): None (default) = stateless sampler,
     # the schedule is a pure function of the key stream and streaming

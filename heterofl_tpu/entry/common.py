@@ -167,8 +167,8 @@ def stage_local_eval(xu: np.ndarray, yu: np.ndarray, mu: np.ndarray,
                      batch_size: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Per-user test shards ``[U, N, ...]`` -> batched ``[U, S, B, ...]``
     (tail padded with zero-weight samples): THE Local-eval operand layout,
-    shared by the driver, the staticcheck eval-fused audit and bench.py so
-    their committed operands cannot drift apart."""
+    shared by the driver and the staticcheck eval-fused audit so their
+    committed operands cannot drift apart."""
     u, n = xu.shape[0], xu.shape[1]
     b = min(batch_size, n)
     s = math.ceil(n / b)
@@ -184,9 +184,8 @@ def stage_local_eval(xu: np.ndarray, yu: np.ndarray, mu: np.ndarray,
 def stage_eval_operands(cfg, train_set, test_set, test_split, lm):
     """THE vision eval-operand assembly -- ``(sbn_batches, local_eval,
     global_eval)`` exactly as the driver commits them -- shared by
-    :meth:`FedExperiment.stage`, the staticcheck eval-fused audit and
-    bench.py, so the audited/benched operand layout cannot drift from the
-    driver's."""
+    :meth:`FedExperiment.stage` and the staticcheck eval-fused audit, so
+    the audited operand layout cannot drift from the driver's."""
     users = cfg["num_users"]
     sbn = _batch_array(train_set.data, cfg["batch_size"]["train"])
     b = cfg["batch_size"]["test"]
@@ -1859,7 +1858,7 @@ def run_main(description: str, model_default: str, data_default: str,
 
     initialize_distributed()  # no-op single-host; joins the pod otherwise
     # persistent XLA compilation cache: repeated experiments skip the ~40s
-    # flagship-round compile (BENCH_r05 compile_sec); operator env wins
+    # flagship-round compile; operator env wins
     enable_persistent_cache()
     parser = build_cli(description)
     args = parser.parse_args(argv)

@@ -211,7 +211,7 @@ def superstep_user_schedule(host_key: jax.Array, epoch0: int, k: int,
     """Host-side ``[k, A]`` active-user draw from THE superstep sampling
     stream (:func:`round_users` at per-round keys ``fold_in(host_key,
     epoch0 + r)``): the one host twin of the masked engine's in-jit draw.
-    Shared by the fed drivers, ``bench.py``, the streaming cohort staging
+    Shared by the fed drivers, the streaming cohort staging
     and the equivalence tests -- a private copy of this loop is how the
     superstep stream silently forks.
 
@@ -386,8 +386,7 @@ def level_codec_byte_table(cfg: Dict[str, Any], codec: str,
     level's flat element count, priced by the one formula in
     :func:`~..compress.codec_payload_bytes`.  THE single source the
     staticcheck wire budget enforces by equality against the traced psum
-    operand avals AND ``bench.py``'s ``extra.wire`` records -- there is no
-    second bytes formula.  ``n_leaves`` (the param-tree leaf count) only
+    operand avals -- there is no second bytes formula.  ``n_leaves`` (the param-tree leaf count) only
     affects the ``signsgd`` scale vector; the fused rounds of both engines
     reduce at the level-a (global) footprint, so their budget is this
     table's top-rate entry."""

@@ -30,8 +30,7 @@ treat them as the tuning signal for codec/schedule choices):
   through ``entry/common.py`` and ``Logger.emit``.
 * **Watchdog** (:mod:`.watchdog`): non-finite counts and a loss-spike
   detector (vs a rolling median) surfaced at fetch boundaries -- loud
-  warning by default, configurable abort.  ``bench.py`` refuses to record
-  a telemetry A/B whose watchdog fired.
+  warning by default, configurable abort.
 
 This module is import-light (numpy only): config validation and the
 host-side probe assembly live here; :mod:`.probes` is hot-path jax code

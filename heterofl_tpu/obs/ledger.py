@@ -8,7 +8,7 @@ availability-debiasing and loss-prioritized-sampling follow-ons need:
 
 * resident state is a handful of O(num_users) SMALL-int arrays -- about
   ``17 + 2 * levels`` bytes per user (27 B at the 5-level flagship mix,
-  under the ~32 B/user acceptance line measured by ``BENCH_LEDGER``);
+  under the ~32 B/user acceptance line);
 * every update is **O(active)**: one fetch folds one cohort's uid rows
   (drawn from THE one sampling stream -- the host twin of the in-jit
   draw, contract-tested bit-identical) plus the per-slot ``rate`` /
@@ -155,8 +155,8 @@ class ClientLedger:
 
     @property
     def nbytes(self) -> int:
-        """Resident bytes of the per-user arrays (the BENCH_LEDGER
-        acceptance number: <= ~32 bytes/user at 1e6 users)."""
+        """Resident bytes of the per-user arrays (the acceptance number:
+        <= ~32 bytes/user at 1e6 users)."""
         return sum(getattr(self, f).nbytes for f in LEDGER_FIELDS)
 
     @property

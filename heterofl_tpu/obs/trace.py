@@ -46,10 +46,18 @@ from typing import Any, Dict, Optional
 #: program nests them: ``round/local_train`` encloses the scan of ``step/*``,
 #: ``step/model`` (entered INSIDE the differentiated function, so autodiff
 #: marks its forward ``jvp(step/model)`` and its backward
-#: ``transpose(jvp(step/model))``) encloses the layer leaves, ``step/update``
-#: encloses ``update/*``, ``round/aggregate`` encloses ``psum``.
+#: ``transpose(jvp(step/model))``) encloses the layer leaves,
+#: ``round/aggregate`` encloses ``psum``.
 #: benchmark/scope_reduce.py reads device time by these names (PERF.md
 #: section 3 lists which metric reads which).
+#: Unentered since PR 30: ``step/unflatten``, ``update/flatten``,
+#: ``update/pack``, ``update/kernel``, ``update/unpack`` and the kernel name
+#: ``fused_sgd`` below belonged to the flat carry and its fused update, which
+#: are deleted; no program enters them.  They stay letter for letter because
+#: benchmark/tests/test_scope_reduce.py holds benchmark/scope_reduce.py's
+#: ``SCOPES`` / ``KERNELS`` equal to these: the ``benchmark`` PR of PERF.md
+#: section 7 drops them on both sides (and bumps :data:`SCOPE_VERSION`; this
+#: PR moved no scope a program enters, so it owed no bump).
 SCOPES = (
     "round/gather", "round/local_train", "round/aggregate", "psum",
     "step/batch", "augment", "step/unflatten", "step/model", "step/update",

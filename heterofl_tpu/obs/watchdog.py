@@ -20,8 +20,7 @@ a loud ``warnings.warn`` plus a structured obs event through the caller's
 emit hook (``Logger.emit`` in the driver); ``abort`` additionally raises
 :class:`WatchdogError` AFTER recording/emitting, so the trace and log
 carry the evidence the abort is based on.  ``Watchdog.fired`` accumulates
-every trip -- ``bench.py`` refuses to record a telemetry A/B whose
-watchdog fired.
+every trip.
 
 Host-side, numpy-only: nothing here runs under trace.
 """

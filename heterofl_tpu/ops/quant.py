@@ -10,8 +10,8 @@ operand aval (``int32[ceil(N/lanes_per_word)]``) is then literally the
 compressed payload, which is what lets ``staticcheck/wire.py`` price the
 compressed round by equality exactly like the dense one.
 
-The quantise+pack hot pass also has a Pallas TPU fast path mirroring
-``ops/fused_update.py``'s flat-tree layout: one kernel over the
+The quantise+pack hot pass also has a Pallas TPU fast path over
+``ops/flatspec.py``'s flat-tree layout: one kernel over the
 lane-packed ``[rows, 128]`` reshape fuses scale/noise/clip/round and the
 4-lane pack into a single VMEM pass (off-TPU it runs in interpreter mode
 for tests; the XLA path is the default elsewhere and is bit-identical by
@@ -26,7 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .fused_update import LANE
+from .flatspec import LANE
 
 
 def pack_lanes(q: jnp.ndarray, lane_bits: int) -> jnp.ndarray:

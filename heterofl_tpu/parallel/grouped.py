@@ -71,14 +71,13 @@ from ..fed.core import (arm_stream_keys, client_stream_keys, combine_counted,
 from ..fed.sampling import resolve_sampler_cfg
 from ..models import make_model
 from ..multi import resolve_arms_cfg
-from ..models.layout import ParamPinner
 from ..models.spec import count_masks as make_count_masks
 from ..chaos import resolve_poison_cfg
 from ..obs import resolve_quarantine_cfg, resolve_telemetry_cfg, split_probes
 from ..obs.hist import round_hists
 from ..obs.probes import round_probes
 from ..obs.trace import scope
-from ..ops.fused_update import FlatSpec
+from ..ops.flatspec import FlatSpec
 from ..sched import resolve_schedule_cfg
 from ..sched.buffer import _SchedBufCarry, buffered_combine
 from ..sched.deadline import deadline_steps
@@ -114,9 +113,6 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
             raise ValueError(f"Not valid level_placement: {self.level_placement!r}")
         self.global_rate = cfg["global_model_rate"]
         self.global_model = make_model(cfg)
-        # layout pinning (ISSUE 5 pass 2), same cached pinner as the
-        # masked engine
-        self._pin = ParamPinner(mesh, cfg.get("layout_policy", "auto"))
         self.is_lm = self.global_model.is_lm
         self.failure_rate = float(cfg.get("client_failure_rate", 0.0) or 0.0)  # staticcheck: allow(no-float-coercion): constructor-time config scalar
         self.levels: Dict[float, Tuple[Any, RoundEngine]] = {}
@@ -399,7 +395,7 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
 
     def _map_layout(self, params) -> Dict[str, Any]:
         """Per-level flat layout of the per-level codec map: each level's
-        sliced :class:`~..ops.fused_update.FlatSpec` plus the LOSSY levels'
+        sliced :class:`~..ops.flatspec.FlatSpec` plus the LOSSY levels'
         offsets into one concatenated ``[2, total_lossy]`` error-feedback
         carry (row 1 is only written by ``topk``; the quantising codecs use
         row 0).  Cached by the global param shapes -- a trace-time
@@ -733,8 +729,8 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
             # commit the globals once: an uncommitted init tree would give
             # every level program AND the combine a second specialization on
             # round 2, when the combined outputs come back mesh-committed
-            # (staticcheck recompile audit); layout pinned by the same policy
-            global_params = self._staging.commit(self._pin(global_params))
+            # (staticcheck recompile audit)
+            global_params = self._staging.commit(global_params)
 
         sums, cnts, ms_levels, positions = [], [], [], []
         for rate in level_order:
@@ -1490,7 +1486,7 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
                 lr_args = (self._staging.scalar(lr),) if lr_arg else ()
                 eval_args = tuple(fused_eval.ops) if eval_mask is not None else ()
                 epoch0_dev = self._staging.scalar(epoch0, dtype=np.int32)
-                global_params = self._staging.commit(self._pin(global_params))
+                global_params = self._staging.commit(global_params)
                 carry_args = self._carry_args(global_params)
                 prog = self._superstep_prog(k, per_dev, mode,
                                             eval_mask=eval_mask,
@@ -1537,8 +1533,8 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
                     lr_args = ()
                 eval_args = tuple(fused_eval.ops) if eval_mask is not None else ()
                 epoch0_dev = self._staging.scalar(epoch0, dtype=np.int32)
-                # commit the params carry (see train_round), layout pinned
-                global_params = self._staging.commit(self._pin(global_params))
+                # commit the params carry (see train_round)
+                global_params = self._staging.commit(global_params)
                 carry_args = self._carry_args(global_params)
                 if arms and mode != "span":  # pragma: no cover - slices
                     raise ValueError(  # refused at construction already

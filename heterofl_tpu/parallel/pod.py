@@ -11,8 +11,6 @@ The probe is the shared engine behind three consumers:
   :func:`~..staticcheck.wire.dcn_axes_of` classifying the clients axis as
   DCN from the real process grid and the traced program carrying exactly
   ONE dense reduction per training round, zero reshards.
-* ``bench.py BENCH_POD=1`` -- records 2-process rounds/sec and
-  per-process checkpoint-write time into ``extra.pod``.
 * CI (``tier1.yml``) -- the distributed smoke step drives the same child.
 
 Each child process joins the distributed runtime (coordinator on process

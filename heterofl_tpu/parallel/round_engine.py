@@ -42,10 +42,9 @@ from .ring_attention import ring_attention
 from .staging import (ClientStore, CohortStager, PendingMetrics, PhaseTimer,
                       PlacementCache, SlotPacker, StagedCohort)
 from ..models.base import ModelDef
-from ..models.layout import ParamPinner
 from ..models.spec import count_masks as make_count_masks, mask_params, param_mask
 from ..ops.augment import augment_cifar, normalize_image
-from ..ops.fused_update import FlatSpec, fused_sgd_flat, resolve_fused_mode
+from ..ops.flatspec import FlatSpec
 from ..utils.optim import clip_by_global_norm, make_optimizer, make_traced_lr_fn
 
 
@@ -56,15 +55,6 @@ def _shard_map(f, mesh, in_specs, out_specs):
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
-
-
-def _leaf_views(spec, p):
-    """What the step's model is differentiated w.r.t.: the flat carry's
-    per-leaf views (``step/unflatten``), or the tree carry itself."""
-    if spec is None:
-        return p
-    with scope("step/unflatten"):
-        return spec.unflatten(p)
 
 
 def _bucket_pow2(n: int) -> int:
@@ -265,7 +255,7 @@ class _WireCodecCarry:
 
     def _codec(self, params):
         """The engine's wire codec over these param shapes (None = dense);
-        built once.  The FlatSpec mirrors ops/fused_update's flat layout --
+        built once over the params' FlatSpec (ops/flatspec.py) --
         for the grouped engine these are the GLOBAL shapes (its fused
         superstep's single psum joins the embedded level partials at global
         shape, the same layout the masked engine compresses)."""
@@ -419,22 +409,6 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         # None = all, the one-vmap round every other program is
         self._chunk = resolve_chunk_cfg(cfg)
         self._opt_init, self._opt_update = make_optimizer(cfg)
-        # the step's carry layout and epilogue: None = tree carry + the
-        # reference op chain (a TPU's default, PR 27); 'xla'/'pallas' = flat
-        # carry + ops/fused_update.py.  Resolved once at construction so the
-        # scan body is shape-stable per engine.
-        self._fused_mode = resolve_fused_mode(cfg)
-        self._momentum = cfg.get("momentum", 0.0)
-        self._weight_decay = cfg.get("weight_decay", 0.0)
-        # debug/regression knob: re-materialise the per-param grad masks
-        # inside the scan body (the pre-hoist program) -- exists so the
-        # staticcheck step-body budget can prove it catches the regression
-        self._masks_in_body = bool(cfg.get("_masks_in_body", False))
-        # layout pinning (ISSUE 5 pass 2): commit the params carry with the
-        # models/layout.py policy so the superstep scan carry enters every
-        # dispatch in the compute layout (TPU; identity on the CPU mesh);
-        # the pinner caches the static Format tree across dispatches
-        self._pin = ParamPinner(mesh, cfg.get("layout_policy", "auto"))
         # wire codec (ISSUE 8): compress the aggregation payload inside the
         # round program -- quantise -> ONE global psum -> dequantise, with
         # the error-feedback residual as an extra donated carry.  'dense'
@@ -573,76 +547,32 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         """Per-param width-activity masks for the gradient epilogue.
 
         Loop-INVARIANT: they depend only on (shape, spec, wr), all fixed for
-        one client's whole local run, so the callers hoist them OUT of the
-        ``lax.scan`` step body (ISSUE 5 satellite) -- the seed program
-        re-materialised every mask (iota + compare + broadcast per sliced
-        axis per leaf) 250 times per round.  The staticcheck step-body
-        kernel budget regression-tests the hoist."""
+        one client's whole local run, so :meth:`_local_setup` builds them
+        OUT of the ``lax.scan`` step body -- the seed program re-materialised
+        every mask (iota + compare + broadcast per sliced axis per leaf) 250
+        times per round, and left it to each compiler to move them out."""
         model = self.model
         return {k: param_mask(shape, model.specs[k], model.groups, wr)
                 for k, shape in shapes.items()}
 
     def _local_setup(self, p, wr):
-        """(scan-carry params, opt state, FlatSpec-or-None, epilogue masks)
-        for one client's local run.
+        """(scan-carry params, optimizer state, hoisted grad masks) for one
+        client's local run.
 
-        ``_fused_mode`` None (what ``fused_update: True`` resolves to on a
-        TPU, and ``False`` everywhere): the scan carries the parameter and
-        momentum TREES, each leaf in the layout the model reads it in and
-        its gradient arrives in; ``_leaf_views`` is the identity and
-        ``_apply_update`` runs the reference chain per leaf in place.
-
-        ``'xla'`` / ``'pallas'`` (the flat carry; ``True`` off the TPU):
-        the params and momentum buffers ride the ``lax.scan`` carry as ONE
-        flat f32 buffer each (ops/fused_update.py FlatSpec) -- O(1)
-        loop-carried buffers instead of O(leaves); the model fwd/bwd
-        consumes leaf views unflattened inside the step (it is
-        differentiated w.r.t. those views, so the per-leaf grads and norm
-        terms are the reference chain's), and the optimizer tail runs in
-        the flat domain.  The views are NOT free on the chip: every step
-        copies each leaf out of the 1-D buffer's tiled layout, and the
-        kernel's operands are flattened, padded and reshaped to [rows, 128]
-        and back -- ``carry_ms.step`` 60.0 of 78.6 ms a ResNet-18 step,
-        107.9 of 129.4 ms an LM step on the v5e (ledger, PR 26), which is
-        why a TPU no longer resolves to it (PR 27).
-
-        ``masks`` are the hoisted loop-invariant grad masks, or None under
-        the ``_masks_in_body`` regression knob."""
-        gmasks = None if self._masks_in_body else \
-            self._grad_masks({k: v.shape for k, v in p.items()}, wr)
-        if self._fused_mode is None:
-            return p, self._opt_init(p), None, gmasks
-        spec = FlatSpec.of(p)
-        pf = spec.flatten(p)
-        # the fused opt state is JUST the flat momentum buffer: SGD never
-        # reads the OptState step counter, so carrying it through the scan
-        # would be a dead loop-carried value
-        return pf, jnp.zeros_like(pf), spec, gmasks
+        The scan carries the parameter and momentum TREES: each leaf in the
+        layout the model reads it in and its gradient arrives in, so the
+        step's update runs per leaf in place (on the v5e a flat carry's
+        flatten / pack / unpack / unflatten were 60.0 of 78.6 ms a ResNet-18
+        step and 107.9 of 129.4 ms an LM step: ledger, PR 26; gone on the
+        chip with PR 27, everywhere with PR 30)."""
+        masks = self._grad_masks({k: v.shape for k, v in p.items()}, wr)
+        return p, self._opt_init(p), masks
 
     @scoped("step/update")
-    def _apply_update(self, p, grads, opt, masks, spec, wr, n_glob, lr,
-                      has=None):
-        """The per-step optimizer epilogue: mean-normalise + width-mask +
-        global-norm clip + optimizer update (+ ``has`` gating for
-        all-padding batches).
-
-        ``spec`` non-None selects the fused masked-SGD primitive over the
-        flat carry (ops/fused_update.py -- ``'xla'``, the default off the
-        TPU, or ``'pallas'``, both bit-identical to this reference chain on
-        the clip decision and elementwise tail); None keeps the reference
-        op chain on the carried leaves (the default on a TPU since PR 27;
-        non-SGD optimizers always).  ``masks=None`` re-materialises
-        the masks here, inside the scan body (the ``_masks_in_body``
-        regression knob)."""
-        if spec is not None:
-            if masks is None:
-                masks = self._grad_masks(spec.shapes, wr)
-            return fused_sgd_flat(
-                spec, p, grads, opt, masks, n_glob, lr,
-                momentum=self._momentum, weight_decay=self._weight_decay,
-                has=has, mode=self._fused_mode)
-        if masks is None:
-            masks = self._grad_masks({k: g.shape for k, g in grads.items()}, wr)
+    def _apply_update(self, p, grads, opt, masks, n_glob, lr, has=None):
+        """The per-step optimizer epilogue, per leaf: mean-normalise +
+        width-mask + global-norm clip + optimizer update (+ ``has`` gating
+        for all-padding batches and steps past a deadline)."""
         grads = {k: g / jnp.maximum(n_glob, 1e-6) for k, g in grads.items()}
         grads = {k: g * masks[k] for k, g in grads.items()}
         grads, _ = clip_by_global_norm(grads, 1.0)
@@ -677,7 +607,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         SB = S * B
         sr = wr if scaler_rate is None else scaler_rate
         p = mask_params(params, model.specs, model.groups, wr)
-        p, opt, spec, emasks = self._local_setup(p, wr)
+        p, opt, emasks = self._local_setup(p, wr)
         ekeys = jax.random.split(jax.random.fold_in(key, 1), E)
         # Shuffle, then stable-sort the *real* samples (sm==1) to the front:
         # batches are dense like the reference's DataLoader over the true
@@ -740,16 +670,12 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                 # exact full-batch mean gradient
                 return out["loss"] * n_loc, out["score"]
 
-            # under the fused flat carry the model is differentiated w.r.t.
-            # the per-leaf VIEWS, so grads come back per-leaf -- the norm
-            # terms then reduce over the reference chain's exact arrays
-            (lsum, score), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                _leaf_views(spec, p))
+            (lsum, score), grads = jax.value_and_grad(loss_fn, has_aux=True)(p)
             correct = jnp.sum((jnp.argmax(score, -1) == y[ids]) * w)
             if data_axis is not None and n_data > 1:
                 grads, lsum, correct = jax.lax.psum((grads, lsum, correct), data_axis)
-            p, opt = self._apply_update(p, grads, opt, emasks, spec, wr,
-                                        n_glob, lr, has=has)
+            p, opt = self._apply_update(p, grads, opt, emasks, n_glob, lr,
+                                        has=has)
             if live is not None:
                 g = live.astype(jnp.float32)
                 lsum, correct, n_glob = lsum * g, correct * g, n_glob * g
@@ -759,8 +685,6 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         acc0 = (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
         (p, _, acc), _ = jax.lax.scan(step, (p, opt, acc0), jnp.arange(E * S),
                                       unroll=self.scan_unroll)
-        if spec is not None:
-            p = spec.unflatten(p)
         return p, {"loss_sum": acc[0], "score_sum": acc[1], "n": acc[2]}
 
     def _local_train_lm(self, params, wr, rows, lm, key, lr, scaler_rate=None,
@@ -786,7 +710,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
         rows_p = jnp.pad(rows, ((0, 0), (0, pad)))
         wpos = jnp.pad(jnp.ones((R, T), jnp.float32), ((0, 0), (0, pad)))
         p = mask_params(params, model.specs, model.groups, wr)
-        p, opt, spec, emasks = self._local_setup(p, wr)
+        p, opt, emasks = self._local_setup(p, wr)
 
         seq_sharded = data_axis is not None and n_data > 1
         if seq_sharded:
@@ -824,17 +748,15 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                 return out["loss"] * n_loc, \
                     (n_loc, out.get("counters") if counted else None)
 
-            # per-leaf grads even under the flat carry (see _local_train_vision)
-            (lsum, (n_loc, ctr)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                _leaf_views(spec, p))
+            (lsum, (n_loc, ctr)), grads = jax.value_and_grad(loss_fn, has_aux=True)(p)
             if seq_sharded:
                 grads, lsum, n_glob = jax.lax.psum((grads, lsum, n_loc), data_axis)
             else:
                 n_glob = n_loc
             loss = lsum / jnp.maximum(n_glob, 1e-6)
             live = None if step_limit is None else (t < step_limit)
-            p, opt = self._apply_update(p, grads, opt, emasks, spec, wr,
-                                        n_glob, lr, has=live)
+            p, opt = self._apply_update(p, grads, opt, emasks, n_glob, lr,
+                                        has=live)
             # Logger weight: rows per window (ref train_transformer_fed.py
             # appends with input['label'].size(0)); Perplexity = exp(window CE).
             n = np.float32(R)  # static trace-time constant, not a device wrap
@@ -858,8 +780,6 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                       for k, shape in model.meta["counters"].items()},)
         (p, _, acc), _ = jax.lax.scan(step, (p, opt, acc0), jnp.arange(E * S),
                                       unroll=self.scan_unroll)
-        if spec is not None:
-            p = spec.unflatten(p)
         ms = {"loss_sum": acc[0], "score_sum": acc[1], "n": acc[2]}
         if counted:
             ms.update({"obs_" + k: v for k, v in acc[3].items()})
@@ -1672,7 +1592,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                 lr_args = (self._staging.scalar(lr),) if lr_arg else ()
                 eval_args = tuple(fused_eval.ops) if eval_mask is not None else ()
                 epoch0_dev = self._staging.scalar(epoch0, dtype=np.int32)
-                params = self._staging.commit(self._pin(params))
+                params = self._staging.commit(params)
                 carry_args = self._carry_args(params)
                 pkey = (k, per_dev, "stream", a, eval_mask, lr_arg)
                 prog = self._superstep_progs.get(pkey)
@@ -1768,12 +1688,11 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             epoch0_dev = self._staging.scalar(epoch0, dtype=np.int32)
             # commit the params carry: an uncommitted init tree would
             # specialise this program once and recompile on round 2 when the
-            # outputs come back mesh-committed (staticcheck recompile audit);
-            # the layout pin rides the same commit (models/layout.py policy).
+            # outputs come back mesh-committed (staticcheck recompile audit).
             # Under the mesh arms placement the stacked axis commits sharded
             # over the 'arms' rows (each arm's params live on its own rows)
             params = self._staging.commit(
-                self._pin(params),
+                params,
                 spec=P("arms") if (arms and self._arms_mesh) else P())
             carry_args = self._carry_args(params)
             trace_args = ()
@@ -1895,9 +1814,8 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
 
     def program_cache_size(self) -> int:
         """Total compiled specializations across this engine's train
-        programs (round + superstep).  bench.py samples the growth per timed
-        round to flag fresh-compile rounds and exclude them from the
-        steady-state average."""
+        programs (round + superstep): the staticcheck recompile audit and
+        the streaming tests hold it flat across repeated dispatches."""
         progs = ([self._train] if self._train is not None else []) \
             + list(self._superstep_progs.values())
         return sum(p._cache_size() for p in progs)
@@ -1964,9 +1882,8 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             ug = self._staging.put(user_glob, spec=P("clients"))
             ul = ug if user_loc is user_glob else self._staging.put(user_loc, spec=P("clients"))
             # commit params so dispatch 1 and the steady state share ONE
-            # program specialization (see train_superstep); layout pinned
-            # by the same policy
-            params = self._staging.commit(self._pin(params))
+            # program specialization (see train_superstep)
+            params = self._staging.commit(params)
             carry_args = self._carry_args(params)
             ep_args = ()
             if self._poison is not None:

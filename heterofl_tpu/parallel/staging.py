@@ -30,8 +30,8 @@ Four pieces remove that tax:
   dispatches while round ``t``'s sums transfer, and ``flush()`` drains at
   eval boundaries (and before the driver exits).
 * :class:`PhaseTimer` -- wall-clock sample/stage/dispatch/fetch breakdown,
-  threaded into ``bench.py``'s ``extra`` dict and the fed drivers' per-round
-  info line, so placement regressions show up as a phase shift instead of
+  threaded into the fed drivers' per-round info line (and read by
+  ``benchmark/``'s host-phase metrics), so placement regressions show up as a phase shift instead of
   an undifferentiated slowdown.
 
 Streaming population staging (ISSUE 6) adds the input-side twins:

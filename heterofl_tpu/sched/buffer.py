@@ -4,7 +4,7 @@ With ``cfg['schedule']['aggregation']='buffered'`` the server applies
 cohort k's update while cohort k+1 trains: inside the fused K-round scan
 the carry grows a second buffer holding the PREVIOUS round's reduced
 ``(update sums, count masks)`` pair -- flat, in the
-:class:`~..ops.fused_update.FlatSpec` layout, stacked ``[2, total]`` --
+:class:`~..ops.flatspec.FlatSpec` layout, stacked ``[2, total]`` --
 and each round (a) trains its cohort on params that do NOT yet include the
 in-flight update (the simulated overlap) and (b) applies the buffered
 one-round-stale update with the staleness-discounted mixing weight
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import staleness_weight
-from ..ops.fused_update import FlatSpec
+from ..ops.flatspec import FlatSpec
 
 #: rounds the in-scan buffer holds an update before it lands: the carry is
 #: depth-1 by construction (cohort k's update applies while k+1 trains)

@@ -27,8 +27,7 @@ Two fronts, one gate (ISSUE 3):
   re-pins after an intentional change).
 
 CLI: ``python -m heterofl_tpu.staticcheck --json`` (exits non-zero on any
-finding; writes the ``STATICCHECK.json`` artifact ``bench.py`` folds into
-``extra.staticcheck``).
+finding; writes the ``STATICCHECK.json`` artifact, which CI keeps).
 
 This module stays import-light (no jax): the CLI must pin the platform to
 cpu before any backend initialises, and the lint front must be usable

@@ -2,9 +2,8 @@
 
 Runs the AST lint (jax-free, milliseconds) and then the program audit
 (lowers/compiles the flagship program matrix on a CPU mesh).  Exits 0 only
-when both fronts are clean; writes the ``STATICCHECK.json`` artifact that
-``bench.py`` folds into ``extra.staticcheck`` (and refuses to record
-against when stale-failed).
+when both fronts are clean; writes the ``STATICCHECK.json`` artifact (CI
+keeps it; nothing else reads it).
 
 The CPU pin below MUST run before jax initialises: the audit never claims
 an accelerator (on a machine with a chip, one process holds it and this is

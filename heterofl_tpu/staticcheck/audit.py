@@ -104,34 +104,35 @@ PSUM_BUDGET = 1
 #: collective)
 EVAL_PSUM_BUDGET = 2
 
-#: the ISSUE 5 hot-step budget: max INSTRUCTIONS per iteration of the
-#: LOCAL-STEP scan body (optimized HLO, CPU-mesh lowering) for the two
-#: programs on the level-a critical path.  Sized from the fused-epilogue
-#: bodies (masked 143, grouped level-a 137 at the audit widths) with +5
-#: headroom, and BELOW the reference-op-chain body (176) -- so an op-soup
-#: regression (un-hoisting the masks + un-fusing the epilogue, or any new
-#: per-leaf chain of comparable size) fails the audit the same way a
-#: second psum would.
-#: (restated with PR 23, on XLA:CPU of jaxlib 0.9.0: this used to count
-#: FUSION launches -- 55, then 69 of the same body after toolchain drift --
-#: but the installed build emits 96 fusions for the fused epilogue and 95
-#: for the reference chain, so the fusion count no longer tells them
-#: apart; the body's instruction count, 143 against 176, still does.
-#: Both stay recorded per program and ratcheted by the baseline.)
+#: the hot-step budget: max INSTRUCTIONS per iteration of the LOCAL-STEP
+#: scan body (optimized HLO, CPU-mesh lowering) for the two programs on the
+#: level-a critical path.  Sized from the bodies of the one local step there
+#: is -- the parameter and momentum leaves carried as the model reads them,
+#: the width masks hoisted out of the scan, the update per leaf (masked 176,
+#: grouped level-a 171 at the audit widths, XLA:CPU of jaxlib 0.9.0) -- with
+#: +5 headroom.  What it guards is GROWTH of the per-step body by a new
+#: per-leaf chain: one more reduce per leaf (the gradients' norm taken a
+#: second time) gives 188 and fails the audit the same way a second psum
+#: would.  What it does NOT see (tests/test_staticcheck.py pins both): masks
+#: re-materialised in the step -- loop-invariant ones XLA:CPU moves out
+#: itself (176), step-dependent ones fuse into the update's fusions (173) --
+#: and the chip's time: the count was green while the v5e's step went from
+#: ~20 to 78.6 ms under the flat carry (ROADMAP.md).  Fusions and
+#: instructions stay recorded per program and ratcheted by the baseline.
 STEP_BODY_BUDGET = {
-    "masked/replicated/k1": 148,
-    "grouped/span/level-1/k1": 142,
+    "masked/replicated/k1": 181,
+    "grouped/span/level-1/k1": 176,
     # ISSUE 10: the health probes live at ROUND level (post-psum), never
     # inside the local-step scan body -- the telemetry-on k1 program is
     # held to the SAME step-body budget as its dense twin
-    "masked/replicated/k1-telemetry": 148,
+    "masked/replicated/k1-telemetry": 181,
     # ISSUE 12: the cohort histograms are round-level bucketing over the
     # already-emitted per-slot metric sums -- same unchanged step body
-    "masked/replicated/k1-hist": 148,
+    "masked/replicated/k1-hist": 181,
     # ISSUE 15: the quarantine gate lives at ROUND level (after local
     # training, folded into the counted sums before the psum), never
     # inside the local-step scan body -- same unchanged step body
-    "masked/replicated/k1-quarantine": 148,
+    "masked/replicated/k1-quarantine": 181,
 }
 
 
@@ -204,7 +205,7 @@ def build_setup(flagship: bool = False, seed: int = 0) -> Dict[str, Any]:
                                    split["train"], lsplit, 10)
 
     # analytic per-level byte/shape table (ISSUE 7): the wire and HBM
-    # budgets' source of truth -- the SAME table bench.py's extra.wire reads
+    # budgets' source of truth
     from ..fed.core import level_byte_table
 
     return {"cfg": cfg, "data": data, "model": model, "params": params,
@@ -582,7 +583,7 @@ def _codec_targets(setup) -> List[Tuple[str, Any, Tuple, Dict[str, Any]]]:
 
     from ..compress import LOSSY_CODECS, resid_slots
     from ..fed.core import level_codec_byte_table
-    from ..ops.fused_update import FlatSpec
+    from ..ops.flatspec import FlatSpec
     from ..parallel import GroupedRoundEngine, RoundEngine, shard_client_data
     from ..utils.optim import make_traced_lr_fn
 
@@ -700,7 +701,7 @@ def _sched_targets(setup) -> List[Tuple[str, Any, Tuple, Dict[str, Any]]]:
     import jax
 
     from ..fed.core import level_codec_map_byte_table
-    from ..ops.fused_update import FlatSpec
+    from ..ops.flatspec import FlatSpec
     from ..parallel import GroupedRoundEngine, RoundEngine
     from ..parallel.grouped import _bucket_pow2
     from ..sched import markov_trace
@@ -973,7 +974,7 @@ def _obs_targets(setup) -> List[Tuple[str, Any, Tuple, Dict[str, Any]]]:
 
     from ..compress import resid_slots
     from ..fed.core import level_codec_byte_table
-    from ..ops.fused_update import FlatSpec
+    from ..ops.flatspec import FlatSpec
     from ..parallel import GroupedRoundEngine, RoundEngine
     from ..parallel.grouped import _bucket_pow2
     from ..utils.optim import make_traced_lr_fn
@@ -1131,7 +1132,7 @@ def _obs_hist_targets(setup) -> List[Tuple[str, Any, Tuple, Dict[str, Any]]]:
 
     from ..compress import resid_slots
     from ..fed.core import level_codec_byte_table
-    from ..ops.fused_update import FlatSpec
+    from ..ops.flatspec import FlatSpec
     from ..parallel import GroupedRoundEngine, RoundEngine
     from ..parallel.grouped import _bucket_pow2
     from ..utils.optim import make_traced_lr_fn
@@ -1368,9 +1369,8 @@ def audit_program(name: str, prog, args: Tuple, expect: Dict[str, Any],
                  f"{rep.step_body['instructions']} instructions per "
                  f"scan-body iteration (body {rep.step_body['body']}, "
                  f"{rep.step_body['fusions']} fusions), budget is "
-                 f"{rep.step_body_budget}: the per-step op soup has "
-                 f"regressed (un-hoisted masks / un-fused epilogue / a new "
-                 f"per-leaf chain)")
+                 f"{rep.step_body_budget}: the per-step body has grown "
+                 f"(a new per-leaf chain)")
     rep.donated = donation_marks(lowered_text)
     rep.aliased = aliased_outputs(compiled_text)
     if rep.donated != expect["donated"]:

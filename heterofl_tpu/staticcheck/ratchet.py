@@ -10,9 +10,7 @@ and memory bytes and FLOPs (small relative headroom for compiler/platform
 variance).  ``python -m heterofl_tpu.staticcheck --diff-baseline``
 structurally diffs a fresh audit against it and exits 2 on any regression
 (1 stays the audit/lint failure code); ``--update-baseline`` re-pins after
-an intentional change.  ``bench.py`` refuses to record a run whose
-artifact carries a regressed ratchet section, the same way it refuses a
-failing audit.
+an intentional change.
 
 jax-free: the diff works on report dicts, so CI and tests can exercise it
 without lowering anything.

@@ -102,8 +102,7 @@ class AuditReport:
     lint: List[Finding] = field(default_factory=list)
     #: baseline-ratchet diff (ISSUE 7: staticcheck/ratchet.py).  ``checked``
     #: is False unless the CLI ran ``--diff-baseline``; a regressed ratchet
-    #: keeps ``ok`` True (the audit itself is green) but exits 2 and makes
-    #: bench.py refuse to record.
+    #: keeps ``ok`` True (the audit itself is green) but exits 2.
     ratchet: Dict[str, Any] = field(default_factory=lambda: {"checked": False})
     generated_at: Optional[str] = None
 

@@ -31,9 +31,8 @@ joint (clients, data) reductions are budgeted separately
 payload set.  ``wire-dcn`` holds cross-slice bytes to the per-program DCN
 budget (zero today).
 
-Import-light on purpose (no jax at module level): ``bench.py``'s
-``extra.wire`` record and the report plumbing use the analytic half
-without booting a backend.
+Import-light on purpose (no jax at module level): the report plumbing uses
+the analytic half without booting a backend.
 """
 
 from __future__ import annotations
@@ -214,7 +213,7 @@ def check_wire(rep, wire: Dict[str, Any], expected_train_bytes: int,
 def link_split(payload_bytes: int, participants: int,
                processes: int = 1) -> Dict[str, int]:
     """Analytic per-link ICI-vs-DCN byte split of one bidirectional-ring
-    all-reduce (ISSUE 17 satellite: ``bench.py``'s ``extra.wire`` record).
+    all-reduce (ISSUE 17 satellite).
 
     A ring over ``p`` participants has ``p`` links, each carrying the same
     ``2 (p-1)/p x payload`` bytes (reduce-scatter + all-gather, the
@@ -237,45 +236,4 @@ def link_split(payload_bytes: int, participants: int,
         "ici_links": ici_links,
         "dcn_bytes_total": dcn_links * per_link,
         "ici_bytes_total": ici_links * per_link,
-    }
-
-
-def codec_round_wire(codec: str, payload_bytes: int, dense_bytes: int,
-                     participants: int) -> Dict[str, Any]:
-    """The analytic COMPRESSED-aggregation wire record for one training
-    round under ``codec`` (ISSUE 8): what ``bench.py`` writes into
-    ``extra.wire`` alongside the dense baseline.  ``payload_bytes`` must
-    come from :func:`~..fed.core.level_codec_byte_table` -- the same table
-    the staticcheck wire budget enforces by equality against the traced
-    psum operand avals, so there is no second bytes formula."""
-    return {
-        "format": codec,
-        "payload_bytes_per_round": int(payload_bytes),
-        "dense_bytes_per_round": int(dense_bytes),
-        "ratio_vs_dense": round(payload_bytes / dense_bytes, 6),
-        "reduction_x": round(dense_bytes / payload_bytes, 3),
-        "ring_allreduce_bytes_per_device":
-            ring_allreduce_bytes(payload_bytes, participants),
-        "participants": int(participants),
-    }
-
-
-def dense_round_wire(param_bytes: int, participants: int,
-                     count_bytes: Optional[int] = None) -> Dict[str, Any]:
-    """The analytic dense-aggregation wire record for one training round:
-    what ``bench.py`` writes into ``extra.wire`` so the compressed-
-    aggregation frontier lands against a recorded dense baseline.  One
-    global reduction of the update sums plus the count masks (both
-    param-shaped f32 -> ``count_bytes`` defaults to ``param_bytes``)."""
-    if count_bytes is None:
-        count_bytes = param_bytes
-    payload = param_bytes + count_bytes
-    return {
-        "format": "dense-f32",
-        "param_bytes": int(param_bytes),
-        "count_bytes": int(count_bytes),
-        "payload_bytes_per_round": int(payload),
-        "ring_allreduce_bytes_per_device":
-            ring_allreduce_bytes(payload, participants),
-        "participants": int(participants),
     }

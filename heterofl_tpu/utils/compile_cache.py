@@ -1,9 +1,10 @@
 """Persistent XLA compilation-cache wiring.
 
-BENCH_r05 measured ``compile_sec`` 40.3s for the flagship round program --
-about one full CPU round.  A warm persistent cache amortises that across
-bench runs, tier-1 test sessions and repeated experiments, so the fed entry
-drivers, ``tests/conftest.py`` and ``bench.py`` all route through here.
+The flagship round program takes about 40 s to compile on XLA:CPU -- about
+one full CPU round -- and ~47 s for a v5e.  A warm persistent cache amortises
+that across benchmark runs, tier-1 test sessions and repeated experiments, so
+the fed entry drivers, ``tests/conftest.py``, ``chip_smoke.py`` and
+``benchmark/run.py`` all route through here.
 
 The default cache dir is fingerprinted by the host CPU's feature flags:
 XLA:CPU AOT entries embed machine features, and loading a cache written on

@@ -12,14 +12,13 @@ the grouped engine's slices row allocation).  CPU-safe: nothing is
 executed, only compiled.  Prints one JSON line; run under
 JAX_PLATFORMS=cpu (see tests/conftest.py).
 
-MFU column: set BENCH_PEAK_FLOPS (hardware peak in FLOP/s -- the SAME knob
-bench.py's extra.mfu consumes, e.g. 2.75e14 for one v4 chip in bf16 x
-devices) and the account gains `mfu`: the ideal round seconds at peak per
-engine (flops / peak) and the per-engine `mfu_x_round_sec` factor -- divide
-by a measured round time to get achieved utilisation, so the FLOP account
-and the bench speak one unit.
+MFU column: set PEAK_FLOPS (hardware peak in FLOP/s, e.g. 1.97e14
+for one v5e chip in bf16, as `benchmark/peaks.json` has it, x devices) and
+the account gains `mfu`: the ideal round seconds at peak per engine (flops /
+peak) -- divide by a measured round time (`round_s` of a benchmark cell) to
+get achieved utilisation.
 
-Usage: [SMALL=1] [BENCH_PEAK_FLOPS=...] python scripts/grouped_flops.py
+Usage: [SMALL=1] [PEAK_FLOPS=...] python scripts/grouped_flops.py
        (SMALL=1: test widths)
 """
 
@@ -72,15 +71,14 @@ def main():
     account = flop_account(cfg, data, mesh, user_idx, rates_vec[user_idx])
     mfu = None
     try:
-        peak = float(os.environ.get("BENCH_PEAK_FLOPS") or 0) or None
+        peak = float(os.environ.get("PEAK_FLOPS") or 0) or None
     except ValueError:
-        print(f"grouped_flops: ignoring malformed BENCH_PEAK_FLOPS="
-              f"{os.environ['BENCH_PEAK_FLOPS']!r}", file=sys.stderr)
+        print(f"grouped_flops: ignoring malformed PEAK_FLOPS="
+              f"{os.environ['PEAK_FLOPS']!r}", file=sys.stderr)
         peak = None
     if peak:
         # the FLOP-time floor per engine; divide by a MEASURED round time
-        # to get achieved MFU (bench.py's extra.mfu does exactly that with
-        # its own wall clock)
+        # to get achieved MFU
         mfu = {"peak_flops": peak,
                "ideal_round_sec_at_peak": {
                    "masked": account["masked_flops_per_round"] / peak,

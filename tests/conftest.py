@@ -17,8 +17,8 @@ if "xla_force_host_platform_device_count" not in flags:
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
 # Persistent XLA compile cache for the test gate: repeat tier-1 runs skip
-# the expensive round-program compiles (BENCH_r05 measured 40.3s for the
-# flagship program).  The dir is CPU-feature-fingerprinted per host; an
+# the expensive round-program compiles (about 40 s for the flagship
+# program on XLA:CPU).  The dir is CPU-feature-fingerprinted per host; an
 # operator-set JAX_COMPILATION_CACHE_DIR wins (utils/compile_cache.py).
 from heterofl_tpu.utils.compile_cache import enable_persistent_cache  # noqa: E402
 

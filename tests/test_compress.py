@@ -49,7 +49,7 @@ from heterofl_tpu.compress import (CODEC_NAMES, LOSSY_CODECS, TOPK_BLOCKS,
                                    make_codec, resid_slots,
                                    resolve_codec_cfg)
 from heterofl_tpu.models import make_model
-from heterofl_tpu.ops.fused_update import FlatSpec
+from heterofl_tpu.ops.flatspec import FlatSpec
 from heterofl_tpu.ops.quant import (pack_lanes, quantize_pack,
                                     stochastic_round, unpack_lanes)
 from heterofl_tpu.parallel import GroupedRoundEngine, RoundEngine, make_mesh
@@ -80,6 +80,22 @@ def _assert_trees_equal(a, b, msg=""):
 # ---------------------------------------------------------------------------
 # the analytic half: byte formula, registry, config validation
 # ---------------------------------------------------------------------------
+
+def test_flatspec_roundtrip_and_order():
+    """The flat layout every codec packs: sorted-key leaf order (jax's own
+    dict-flatten order), contiguous segments, an exact round trip."""
+    rng = np.random.default_rng(0)
+    p = {k: jnp.asarray(rng.normal(size=s), jnp.float32) for k, s in {
+        "blk.conv.w": (3, 3, 4, 8), "blk.norm.g": (8,), "blk.norm.b": (8,),
+        "fc.w": (8, 10), "fc.b": (10,)}.items()}
+    spec = FlatSpec.of(p)
+    assert spec.names == sorted(p)
+    flat = spec.flatten(p)
+    assert flat.shape == (spec.total,)
+    _assert_trees_equal(_host(spec.unflatten(flat)), _host(p))
+    for k in p:
+        np.testing.assert_array_equal(np.asarray(spec.leaf(flat, k)), np.asarray(p[k]))
+
 
 def test_codec_payload_bytes_formula():
     n, leaves = 1000, 7

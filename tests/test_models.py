@@ -194,12 +194,12 @@ def test_augment_cifar_shapes_and_determinism():
 
 
 # ---------------------------------------------------------------------------
-# the explicit layout/dtype policy (ISSUE 5 pass 2)
+# the lane policy of the parameter tables (models/layout.py)
 # ---------------------------------------------------------------------------
 
-def test_layout_policy_every_family_compliant():
+def test_lane_policy_every_family_compliant():
     """Trailing axes are feature axes (width-group or label) for every
-    model family -- the lane-packing convention models/layout.py pins."""
+    model family -- the lane-packing convention models/layout.py states."""
     from heterofl_tpu.models import layout as L
 
     for name in ("conv", "resnet18", "resnet50", "transformer"):
@@ -212,7 +212,7 @@ def test_layout_policy_every_family_compliant():
         assert bad == {}, (name, bad)
 
 
-def test_layout_policy_flags_transposed_weight():
+def test_lane_policy_flags_transposed_weight():
     """A torch-style [out, in] weight (reduction axis in the lanes) fails
     the policy audit."""
     from heterofl_tpu.models import layout as L
@@ -222,27 +222,6 @@ def test_layout_policy_flags_transposed_weight():
                           {"w": (8, 10)}) == {"w": 1}
     assert L.check_policy({"w": ParamSpec(axis_groups={1: "h"})},
                           {"w": (10, 8)}) == {}
-
-
-def test_pin_params_cpu_passthrough_and_formats():
-    """On the CPU test mesh pin_params is the identity (XLA:CPU ignores
-    custom layouts); the Format objects themselves pin row-major
-    major-to-minor, and an unknown policy raises."""
-    import pytest
-
-    from heterofl_tpu.models.layout import param_formats, pin_params
-
-    cfg = small_cfg("conv")
-    model = make_model(cfg)
-    params = model.init(jax.random.key(0))
-    pinned = pin_params(params, mesh=None, policy="auto")
-    assert all(pinned[k] is params[k] for k in params)
-    assert pin_params(params, mesh=None, policy="none") is params
-    with pytest.raises(ValueError, match="layout_policy"):
-        pin_params(params, mesh=None, policy="fastest")
-    fmts = param_formats(params)
-    for k, v in params.items():
-        assert tuple(fmts[k].layout.major_to_minor) == tuple(range(v.ndim)), k
 
 
 def test_conv_dimension_numbers_one_owner():

@@ -209,7 +209,7 @@ def test_hist_deadline_steps_match_host_reference_exactly():
 
 
 def test_hist_stale_under_buffered_counts_whole_carry():
-    from heterofl_tpu.ops.fused_update import FlatSpec
+    from heterofl_tpu.ops.flatspec import FlatSpec
 
     cfg, ds, data = _vision_setup()
     model = make_model(cfg)

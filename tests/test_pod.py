@@ -4,8 +4,8 @@ partition logic, the per-process shard checkpoint format, and the
 analytic per-link ICI-vs-DCN split.
 
 The slow half spawns distributed subprocesses through
-``heterofl_tpu.parallel.pod`` (the same engine ``bench.py BENCH_POD=1``
-and the CI smoke step drive); the fast half unit-tests the pure pieces:
+``heterofl_tpu.parallel.pod`` (the same engine the CI smoke step
+drives); the fast half unit-tests the pure pieces:
 ``link_split`` values, shard-blocks assembly + its corruption modes, the
 sharded ``copy_best`` mirror, and the multi-host resume guard's
 single-process degenerate case.
@@ -29,7 +29,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
-# fast: analytic per-link wire split (bench.py's extra.wire record)
+# fast: analytic per-link wire split
 # ---------------------------------------------------------------------------
 
 def test_link_split_two_process_blocks():

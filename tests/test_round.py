@@ -90,6 +90,157 @@ def test_round_deterministic():
         np.testing.assert_allclose(np.asarray(p1[k]), np.asarray(p2[k]), rtol=1e-6, err_msg=k)
 
 
+# ---------------------------------------------------------------------------
+# the local step's update (RoundEngine._local_setup / _apply_update) against
+# the reference's step, stated here in NumPy float64
+# ---------------------------------------------------------------------------
+
+LEVELS = {"a": 1.0, "e": 0.0625}
+
+
+def _step_case(family, level, optimizer="SGD"):
+    """(engine, masked params, its width rate) of a tiny model at one level."""
+    cfg = small_cfg(family, data_name="WikiText2" if family == "transformer" else "MNIST")
+    cfg["optimizer_name"] = optimizer
+    model = make_model(cfg)
+    wr = LEVELS[level]
+    p = mask_params(model.init(jax.random.key(0)), model.specs, model.groups, wr)
+    return RoundEngine(model, cfg, mesh=None), p, wr
+
+
+def _active(eng, p, wr):
+    """Where a level's sub-model lives, without the engine's own masks: what
+    ``mask_params`` keeps of a tree of ones."""
+    ones = {k: jnp.ones_like(v) for k, v in p.items()}
+    kept = mask_params(ones, eng.model.specs, eng.model.groups, wr)
+    return {k: np.asarray(v) != 0.0 for k, v in kept.items()}
+
+
+def _normalised_grads(g, active, n):
+    """The batch-mean gradient of the sub-model after ``clip_grad_norm_(1)``
+    (ref train_classifier_fed.py:205), and its norm before the clip."""
+    g = {k: np.asarray(v, np.float64) / max(n, 1e-6) * active[k] for k, v in g.items()}
+    total = np.sqrt(sum((v ** 2).sum() for v in g.values()))
+    scale = min(1.0, 1.0 / (total + 1e-6))
+    return {k: v * scale for k, v in g.items()}, total
+
+
+def _random_grads(p, seed, scale):
+    rng = np.random.default_rng(seed)
+    return {k: jnp.asarray(rng.normal(size=v.shape) * scale, jnp.float32)
+            for k, v in p.items()}
+
+
+def _update(eng, has):
+    gate = {} if has is None else {"has": jnp.asarray(has)}
+    return jax.jit(lambda p, g, opt, masks, n, lr: eng._apply_update(
+        p, g, opt, masks, n, lr, **gate))
+
+
+@pytest.mark.parametrize("level", sorted(LEVELS))
+@pytest.mark.parametrize("has", [None, True, False])
+@pytest.mark.parametrize("clip", [False, True], ids=["noclip", "clip"])
+@pytest.mark.parametrize("family", ["conv", "transformer"])
+def test_apply_update_is_the_references_step(family, clip, has, level):
+    """Two consecutive steps (the second with momentum behind it): mean-
+    normalise, width mask, ``clip_grad_norm_(1)``, torch SGD with momentum
+    0.9 and weight decay 5e-4.  Outside the level's slice the parameters
+    stay exactly zero and so does the momentum; an all-padding batch
+    (``has`` False) moves nothing, not by weight decay either."""
+    eng, p, wr = _step_case(family, level)
+    active = _active(eng, p, wr)
+    p, opt, masks = eng._local_setup(p, wr)
+    n, lr, momentum, wd = 7.0, 0.05, 0.9, 5e-4
+    assert (eng.cfg["momentum"], eng.cfg["weight_decay"]) == (momentum, wd)
+    update = _update(eng, has)
+    for step in range(2):
+        g = _random_grads(p, 10 + step, 1e2 if clip else 1e-3)
+        g64, total = _normalised_grads(g, active, n)
+        assert (total > 1.0) == clip, total
+        new_p, new_opt = update(p, g, opt, masks, jnp.float32(n), jnp.float32(lr))
+        if has is False:
+            for k in p:
+                np.testing.assert_array_equal(np.asarray(new_p[k]), np.asarray(p[k]), err_msg=k)
+                np.testing.assert_array_equal(np.asarray(new_opt.slots[k]),
+                                              np.asarray(opt.slots[k]), err_msg=k)
+            assert int(new_opt.step) == int(opt.step)
+            has, update = True, _update(eng, True)  # the next step is a real one
+            new_p, new_opt = update(p, g, opt, masks, jnp.float32(n), jnp.float32(lr))
+        for k in p:
+            buf = momentum * np.asarray(opt.slots[k], np.float64) + g64[k] \
+                + wd * np.asarray(p[k], np.float64)
+            np.testing.assert_allclose(np.asarray(new_opt.slots[k]), buf,
+                                       rtol=1e-5, atol=1e-7, err_msg=k)
+            np.testing.assert_allclose(np.asarray(new_p[k]),
+                                       np.asarray(p[k], np.float64) - lr * buf,
+                                       rtol=1e-5, atol=1e-7, err_msg=k)
+            assert np.all(np.asarray(new_p[k])[~active[k]] == 0.0), k
+            assert np.all(np.asarray(new_opt.slots[k])[~active[k]] == 0.0), k
+            if level == "a":
+                assert active[k].all(), k
+        if level == "e":  # whole rows of zero width: most of the tree is inactive
+            assert sum(a.sum() for a in active.values()) \
+                < 0.2 * sum(a.size for a in active.values())
+        p, opt = new_p, new_opt
+    assert int(opt.step) == 2
+
+
+@pytest.mark.parametrize("level", sorted(LEVELS))
+@pytest.mark.parametrize("family", ["conv", "resnet18", "transformer"])
+def test_local_setup_hoists_the_masks(family, level):
+    """What the scan closes over: the masked tree as it came, a zero
+    optimizer state of the same leaves, and per leaf the width mask
+    ``param_mask`` gives -- built once, outside the step."""
+    from heterofl_tpu.models.spec import param_mask
+
+    eng, p, wr = _step_case(family, level)
+    carry, opt, masks = eng._local_setup(p, wr)
+    assert carry is p
+    assert sorted(masks) == sorted(opt.slots) == sorted(p)
+    active = _active(eng, p, wr)
+    for k, v in p.items():
+        assert masks[k].shape == v.shape and opt.slots[k].shape == v.shape
+        np.testing.assert_array_equal(
+            np.asarray(masks[k]),
+            np.asarray(param_mask(v.shape, eng.model.specs[k], eng.model.groups, wr)),
+            err_msg=k)
+        np.testing.assert_array_equal(np.asarray(masks[k]) != 0.0, active[k], err_msg=k)
+        assert not np.asarray(opt.slots[k]).any()
+    # nothing of them is rebuilt in the step: its jaxpr holds no iota
+    update = _update(eng, True)
+    g = _random_grads(p, 0, 1e-3)
+    text = str(jax.make_jaxpr(update)(p, g, opt, masks, jnp.float32(1.0), jnp.float32(0.1)))
+    assert "iota" not in text
+
+
+@pytest.mark.parametrize("optimizer", ["RMSprop", "Adam", "Adamax"])
+def test_every_optimizer_takes_the_one_chain(optimizer):
+    """No optimizer has a step of its own: each gets the normalised, masked,
+    clipped gradient, and the ``has`` gate holds its whole state."""
+    from heterofl_tpu.utils.optim import make_optimizer
+
+    eng, p, wr = _step_case("conv", "e", optimizer)
+    active = _active(eng, p, wr)
+    p, opt, masks = eng._local_setup(p, wr)
+    g = _random_grads(p, 3, 1e2)
+    n, lr = jnp.float32(4.0), jnp.float32(0.01)
+    g64, total = _normalised_grads(g, active, 4.0)
+    assert total > 1.0
+    want_p, want_opt = jax.jit(make_optimizer(eng.cfg)[1])(
+        p, {k: jnp.asarray(v, jnp.float32) for k, v in g64.items()}, opt, lr)
+    got_p, got_opt = _update(eng, True)(p, g, opt, masks, n, lr)
+    for got, want in zip(jax.tree_util.tree_leaves((got_p, got_opt)),
+                         jax.tree_util.tree_leaves((want_p, want_opt))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6)
+    for k in p:
+        assert np.all(np.asarray(got_p[k])[~active[k]] == 0.0), k
+    assert any(np.asarray(got_p[k] != p[k]).any() for k in p)
+    held_p, held_opt = _update(eng, False)(p, g, opt, masks, n, lr)
+    for got, want in zip(jax.tree_util.tree_leaves((held_p, held_opt)),
+                         jax.tree_util.tree_leaves((p, opt))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.slow
 def test_dynamic_mode_round():
     cfg, ds, data = _vision_setup(control="1_8_0.5_iid_dynamic_a1-e1_bn_1_1")

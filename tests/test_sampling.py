@@ -184,7 +184,7 @@ def test_chi_square_uniform_cohort_frequencies():
 
 def test_prp_and_perm_are_different_streams():
     """The re-baseline is real: the two samplers draw different cohorts at
-    the same key (which is why bench.py refuses cross-stream comparisons)."""
+    the same key (records drawn under one are not comparable with the other's)."""
     got_prp = np.asarray(round_users(HOST_KEY, 100, 10, sampler="prp"))
     got_perm = np.asarray(round_users(HOST_KEY, 100, 10, sampler="perm"))
     assert (got_prp != got_perm).any()

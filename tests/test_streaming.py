@@ -386,8 +386,8 @@ def test_population_1e6_flagship_superstep():
     the flagship CIFAR10/ResNet-18 config on the 8-device CPU mesh through
     the streaming store -- cohort staging time and bytes match a 1e4-user
     store (population-independent), and one streamed superstep trains.
-    (The bench's BENCH_POPULATION axis records the RSS/stage-time table;
-    this is the in-suite twin, slow-marked.)"""
+    (Slow-marked; the RSS/stage-time table of MEASUREMENTS.md came from a
+    CPU run of the same shape.)"""
     import time
 
     from heterofl_tpu import config as C

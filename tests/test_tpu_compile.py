@@ -69,34 +69,6 @@ def _compile(fn, *avals, kernels):
     return text
 
 
-@pytest.mark.parametrize("vmapped", [False, True], ids=["bare", "vmap10"])
-@pytest.mark.parametrize("model_name", ["resnet18", "transformer"])
-def test_fused_sgd_kernel_compiles(one_chip, model_name, vmapped):
-    """The default path's kernel, bare and as the round calls it: under
-    ``vmap`` over the client slots with batched ``has``/``denom``."""
-    from heterofl_tpu.ops.fused_update import FlatSpec, fused_sgd_flat
-
-    total = _flat_size(model_name)
-    assert total > (11_000_000 if model_name == "resnet18" else 15_000_000)
-    spec = FlatSpec({"w": (total,)})
-
-    def step(p, g, b, m, n, lr, has):
-        return fused_sgd_flat(spec, p, {"w": g}, b, {"w": m}, n, lr,
-                              momentum=0.9, weight_decay=5e-4, has=has,
-                              mode="pallas", interpret=False)
-
-    def sds(shape, dt=jnp.float32):
-        lead = (SLOTS,) if vmapped else ()
-        return jax.ShapeDtypeStruct(lead + shape, dt, sharding=one_chip)
-
-    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    fn = step
-    if vmapped:
-        fn = jax.vmap(step, in_axes=(0, 0, 0, 0, 0, None, 0))
-    _compile(fn, sds((total,)), sds((total,)), sds((total,)), sds((total,)),
-             sds(()), lr, sds((), jnp.bool_), kernels=("fused_sgd",))
-
-
 def test_quant_pack_kernel_compiles(one_chip):
     """The int8 codec's quantise+pack pass at ResNet-18's flat size."""
     from heterofl_tpu.ops.quant import quantize_pack
