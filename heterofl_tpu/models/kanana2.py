@@ -58,9 +58,9 @@ import jax.numpy as jnp
 
 from ..obs.trace import scope
 from ..ops.layers import (causal_latent_attention, cross_entropy, embed,
-                          linear as _linear, masked_logits, masked_rms_norm,
-                          moe_experts, moe_route, rope_interleaved, scaler,
-                          swiglu)
+                          heads_linear, linear as _linear, linear_heads,
+                          masked_logits, masked_rms_norm, moe_experts, moe_route,
+                          rope_interleaved, rope_swap, scaler, swiglu)
 from .base import ModelDef, normal_init, uniform_fan_in
 from .spec import Group, ParamSpec
 
@@ -76,6 +76,49 @@ def held_experts(arch) -> range:
         raise ValueError(f"Not valid expert_share: {arch['expert_share']!r} "
                          f"(index, of) with of dividing n_routed_experts={n}")
     return range(index * (n // of), (index + 1) * (n // of))
+
+
+def latent_attention_shapes(D: int, H: int, dn: int, dr: int, dv: int, R: int) -> Dict[str, tuple]:
+    """The leaves :func:`latent_attention` reads, a layer's ``attn.*``: hidden
+    size ``D``, ``H`` heads of ``dn`` no-position, ``dr`` rotary and ``dv``
+    value dims, latent width ``R``."""
+    return {"attn.q.n.w": (D, H * dn), "attn.q.r.w": (D, H * dr),
+            "attn.kv_a.c.w": (D, R), "attn.kv_a.r.w": (D, dr), "attn.kv_norm.g": (R,),
+            "attn.kv_b.k.w": (R, H * dn), "attn.kv_b.v.w": (R, H * dv), "attn.o.w": (H * dv, D)}
+
+
+def latent_attention(lp, h, *, heads: int, theta: float, scale, sc, kv_norm,
+                     compute_dtype=None):
+    """A layer's latent attention on the normed ``h`` ``[N, S, D]``; ``lp`` the
+    layer's leaves, ``sc`` the Scaler, ``kv_norm(c, g)`` the latent's norm.
+
+    Heads first from end to end: the per-head projections write ``[N, H, S,
+    d]`` (``linear_heads`` on the stored ``[K, H * d]`` leaves), the rotary
+    turn, the attention and the output projection read it, so no activation
+    is transposed or sliced between ``mla`` and the attention kernels,
+    forward or backward.  The one rotary key head ``kr`` stays ``[N, S, dr]``."""
+    linear = partial(_linear, compute_dtype=compute_dtype)
+    per_head = partial(linear_heads, heads=heads, compute_dtype=compute_dtype)
+    pos = jnp.arange(h.shape[1])
+    with scope("mla"):
+        qn = sc(per_head(h, lp["attn.q.n.w"]))
+        # the rotary query and its pair swap: the products of the weight and
+        # of the swapped weight (ops.layers.rope_swap says why)
+        qr = sc(per_head(h, lp["attn.q.r.w"]))
+        qr_swapped = sc(per_head(h, rope_swap(lp["attn.q.r.w"])))
+        c = sc(linear(h, lp["attn.kv_a.c.w"]))
+        kr = sc(linear(h, lp["attn.kv_a.r.w"]))
+        c = kv_norm(c, lp["attn.kv_norm.g"])
+        kn = sc(per_head(c, lp["attn.kv_b.k.w"]))
+        v = sc(per_head(c, lp["attn.kv_b.v.w"]))
+    qr = rope_interleaved(qr, qr_swapped, pos, theta, axis=2)
+    # one key head: its swap is cheap where it is, a second product reads ``h`` again
+    kr = rope_interleaved(kr, rope_swap(kr), pos, theta)
+    if compute_dtype is not None:
+        qn, qr, kn, kr, v = (t.astype(compute_dtype) for t in (qn, qr, kn, kr, v))
+    o = causal_latent_attention(qn, qr, kn, kr, v, scale)
+    with scope("mla"):
+        return sc(heads_linear(o.astype(jnp.float32), lp["attn.o.w"], compute_dtype))
 
 
 def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
@@ -139,17 +182,17 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         add(f"{prefix}.u.w", (D, width), {0: "emb", 1: group})
         add(f"{prefix}.d.w", (width, D), {0: group, 1: "emb"})
 
+    attn_groups = {
+        "attn.q.n.w": {0: "emb", 1: "q_nope"}, "attn.q.r.w": {0: "emb", 1: "q_rope"},
+        "attn.kv_a.c.w": {0: "emb", 1: "kv_lora"}, "attn.kv_a.r.w": {0: "emb", 1: "k_rope"},
+        "attn.kv_norm.g": {0: "kv_lora"},
+        "attn.kv_b.k.w": {0: "kv_lora", 1: "q_nope"}, "attn.kv_b.v.w": {0: "kv_lora", 1: "v_head"},
+        "attn.o.w": {0: "v_head", 1: "emb"}}
     for i in range(L):
         p = f"l{i}"
         add(f"{p}.norm1.g", (D,), {0: "emb"})
-        add(f"{p}.attn.q.n.w", (D, H * dn), {0: "emb", 1: "q_nope"})
-        add(f"{p}.attn.q.r.w", (D, H * dr), {0: "emb", 1: "q_rope"})
-        add(f"{p}.attn.kv_a.c.w", (D, R), {0: "emb", 1: "kv_lora"})
-        add(f"{p}.attn.kv_a.r.w", (D, dr), {0: "emb", 1: "k_rope"})
-        add(f"{p}.attn.kv_norm.g", (R,), {0: "kv_lora"})
-        add(f"{p}.attn.kv_b.k.w", (R, H * dn), {0: "kv_lora", 1: "q_nope"})
-        add(f"{p}.attn.kv_b.v.w", (R, H * dv), {0: "kv_lora", 1: "v_head"})
-        add(f"{p}.attn.o.w", (H * dv, D), {0: "v_head", 1: "emb"})
+        for name, shape in latent_attention_shapes(D, H, dn, dr, dv, R).items():
+            add(f"{p}.{name}", shape, attn_groups[name])
         add(f"{p}.norm2.g", (D,), {0: "emb"})
         if i < L_dense:
             add_ffn(f"{p}.mlp", F, "ffn")
@@ -188,7 +231,6 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                for g in ("emb", "kv_lora", "q_nope", "q_rope")}
         emb_mask, lora_mask = groups["emb"].mask(width_rate), groups["kv_lora"].mask(width_rate)
         scale = 1.0 / jnp.sqrt((act["q_nope"] + act["q_rope"]) / H)
-        pos = jnp.arange(S)
 
         def sc(x):
             return scaler(x, scaler_rate, train)
@@ -196,22 +238,10 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         def rms(g, x):
             return masked_rms_norm(x, g, emb_mask, act["emb"], eps)
 
-        def attention(lp, h):
-            with scope("mla"):
-                qn = sc(linear(h, lp["attn.q.n.w"])).reshape(N, S, H, dn)
-                qr = sc(linear(h, lp["attn.q.r.w"])).reshape(N, S, H, dr)
-                c = sc(linear(h, lp["attn.kv_a.c.w"]))
-                kr = sc(linear(h, lp["attn.kv_a.r.w"]))
-                c = masked_rms_norm(c, lp["attn.kv_norm.g"], lora_mask, act["kv_lora"], eps)
-                kn = sc(linear(c, lp["attn.kv_b.k.w"])).reshape(N, S, H, dn)
-                v = sc(linear(c, lp["attn.kv_b.v.w"])).reshape(N, S, H, dv)
-            qr, kr = rope_interleaved(qr, pos, theta), rope_interleaved(kr, pos, theta)
-            if compute_dtype is not None:
-                qn, qr, kn, kr, v = (t.astype(compute_dtype) for t in (qn, qr, kn, kr, v))
-            o = causal_latent_attention(qn, qr, kn, kr, v, scale)
-            with scope("mla"):
-                o = o.astype(jnp.float32).reshape(N, S, H * dv)
-                return sc(linear(o, lp["attn.o.w"]))
+        attention = partial(
+            latent_attention, heads=H, theta=theta, scale=scale, sc=sc,
+            kv_norm=lambda c, g: masked_rms_norm(c, g, lora_mask, act["kv_lora"], eps),
+            compute_dtype=compute_dtype)
 
         def ffn(lp, prefix, h):
             return swiglu(h, lp[f"{prefix}.g.w"], lp[f"{prefix}.u.w"], lp[f"{prefix}.d.w"],

@@ -94,6 +94,33 @@ def linear(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray] = None,
     return y
 
 
+def _product(spec: str, x, w, compute_dtype):
+    """``einsum(spec, x, w)`` as :func:`linear` takes its product."""
+    if compute_dtype is None:
+        return jnp.einsum(spec, x, w)
+    return jnp.einsum(spec, x.astype(compute_dtype), w.astype(compute_dtype)).astype(jnp.float32)
+
+
+@scoped("linear")
+def linear_heads(x: jnp.ndarray, w: jnp.ndarray, heads: int,
+                 compute_dtype: Optional[jnp.dtype] = None) -> jnp.ndarray:
+    """:func:`linear` whose output columns are ``heads`` heads, written heads
+    first: ``x`` ``[N, S, K]``, ``w`` ``[K, heads * d]`` -> ``[N, heads, S,
+    d]``.  The product itself emits the layout the attention reads (the
+    compiler folds the order of a product's result into the product, and the
+    transposed one into its cotangents), where ``linear`` + ``reshape`` +
+    ``swapaxes`` writes the activation and then copies it."""
+    return _product("nsk,khd->nhsd", x, w.reshape(w.shape[0], heads, -1), compute_dtype)
+
+
+@scoped("linear")
+def heads_linear(x: jnp.ndarray, w: jnp.ndarray,
+                 compute_dtype: Optional[jnp.dtype] = None) -> jnp.ndarray:
+    """:func:`linear` over the concatenated heads of a heads-first ``x``:
+    ``x`` ``[N, H, S, d]``, ``w`` ``[H * d, K]`` -> ``[N, S, K]``."""
+    return _product("nhsd,hdk->nsk", x, w.reshape(x.shape[1], x.shape[3], -1), compute_dtype)
+
+
 @scoped("embed")
 def embed(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     return jnp.take(table, ids, axis=0)
@@ -300,21 +327,37 @@ def masked_rms_norm(x: jnp.ndarray, g: jnp.ndarray, mask: jnp.ndarray, k,
 
 
 @scoped("rope")
-def rope_interleaved(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rope_swap(x: jnp.ndarray) -> jnp.ndarray:
+    """The rotary turn's pair swap on the last axis: ``(x[2i], x[2i+1]) ->
+    (-x[2i+1], x[2i])``, as two lane rotations and a select, not a strided
+    gather.  Linear and within pairs of columns, so ``swap(h W) = h swap(W)``
+    (a ``[K, H * d]`` weight's heads hold whole pairs): for the rotary QUERY
+    the model swaps the weight and takes a second product.  Rotating that
+    activation instead costs more than the product: the chip's compiler turns
+    a rotation of a 64-wide last axis into four slices (``[.., 63]``, ``[..,
+    1]``) written out at 128 lanes each, 0.27 GB a turn of the cell's ``[2,
+    32, 2048, 64]``, and the turn, its recomputation and its backward were 3.0
+    ms of a layer's 14.2 (PERF.md, PR 31)."""
+    even = (jnp.arange(x.shape[-1]) % 2) == 0
+    return jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+
+
+@scoped("rope")
+def rope_interleaved(x: jnp.ndarray, swapped: jnp.ndarray, pos: jnp.ndarray, theta: float,
+                     axis: int = 1) -> jnp.ndarray:
     """Rotary embedding on interleaved pairs ``(2i, 2i+1)`` of the last axis
-    (``rope_interleave: true``), ``theta_i = theta^(-2i/d)`` with ``d`` the
-    FULL rotary width: a client's sliced prefix of whole pairs keeps the
-    frequencies of the pairs it holds, and zeros (masked pairs) stay zeros.
-    ``x`` ``[N, S, ..., d]``, ``pos`` ``[S]``.  Written as ``x cos + swap(x) sin``
-    with ``swap`` two lane rotations and a select, not a strided gather."""
+    (``rope_interleave: true``): ``x cos + swapped sin`` with ``swapped`` the
+    pair swap of ``x`` (:func:`rope_swap`), ``theta_i = theta^(-2i/d)`` and
+    ``d`` the FULL rotary width: a client's sliced prefix of whole pairs keeps
+    the frequencies of the pairs it holds, and zeros (masked pairs) stay
+    zeros.  ``x`` ``[N, S, ..., d]`` and ``pos`` ``[S]``, the positions on
+    ``axis`` (2 for heads-first ``[N, H, S, d]``)."""
     d = x.shape[-1]
     inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / d)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]           # [S, d]
     view = [1] * x.ndim
-    view[1], view[-1] = x.shape[1], d
+    view[axis], view[-1] = x.shape[axis], d
     cos, sin = jnp.cos(ang).reshape(view), jnp.sin(ang).reshape(view)
-    even = (jnp.arange(d) % 2) == 0
-    swapped = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
     return x * cos + swapped * sin
 
 
@@ -334,9 +377,11 @@ def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
     """Causal softmax attention of latent-attention heads, the score /
     softmax / value part only: ``scores = (qn kn^T + qr kr^T) * scale``.
 
-    ``qn``/``kn`` ``[N, S, H, dn]`` (no-position dims), ``qr`` ``[N, S, H,
+    Heads first, the layout :func:`linear_heads` writes and the kernels read:
+    ``qn``/``kn`` ``[N, H, S, dn]`` (no-position dims), ``qr`` ``[N, H, S,
     dr]`` and ``kr`` ``[N, S, dr]`` (rotary dims, ONE key head shared by all
-    query heads), ``v`` ``[N, S, H, dv]``.  Softmax in float32, float32 out.
+    query heads), ``v`` and the result ``[N, H, S, dv]``.  Softmax in
+    float32, float32 out.
 
     On a TPU, where the positions make whole tiles and the head dims fill
     the lanes (``pallas_attention.tile_for``), the fused kernels of
@@ -346,7 +391,7 @@ def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
     if jax.default_backend() == "tpu":
         from . import pallas_attention  # jax's Pallas: a second of import, paid where it is used
 
-        tile = pallas_attention.tile_for(qn.shape[1], qn.shape[-1], qr.shape[-1], v.shape[-1])
+        tile = pallas_attention.tile_for(qn.shape[2], qn.shape[-1], qr.shape[-1], v.shape[-1])
         if tile is not None:
             return pallas_attention.fused_latent_attention(
                 qn, qr, kn, kr, v, scale, block_q=tile, block_k=tile)
@@ -359,22 +404,22 @@ def blockwise_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK
     the block's end, each block under ``jax.checkpoint``: no ``[S, S]`` score
     matrix of a whole row is ever held, in the forward or for the backward,
     and key blocks above the diagonal are never computed."""
-    S = qn.shape[1]
+    S = qn.shape[2]
     outs = []
     for start in range(0, S, block):
         end = min(start + block, S)
 
         def one(qn_b, qr_b, kn_b, kr_b, v_b, start=start, end=end):
-            s = jnp.einsum("nqhd,nkhd->nhqk", qn_b, kn_b) \
-                + jnp.einsum("nqhd,nkd->nhqk", qr_b, kr_b)
+            s = jnp.einsum("nhqd,nhkd->nhqk", qn_b, kn_b) \
+                + jnp.einsum("nhqd,nkd->nhqk", qr_b, kr_b)
             s = s.astype(jnp.float32) * scale
             keep = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
             s = jnp.where(keep, s, -jnp.inf)
-            return jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, axis=-1), v_b)
+            return jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, axis=-1), v_b)
 
-        outs.append(jax.checkpoint(one)(qn[:, start:end], qr[:, start:end],
-                                        kn[:, :end], kr[:, :end], v[:, :end]))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        outs.append(jax.checkpoint(one)(qn[:, :, start:end], qr[:, :, start:end],
+                                        kn[:, :, :end], kr[:, :end], v[:, :, :end]))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
 
 
 @scoped("moe/router")

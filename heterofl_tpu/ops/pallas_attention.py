@@ -260,11 +260,8 @@ def tile_for(S: int, dn: int, dr: int, dv: int):
 def fused_latent_attention(qn, qr, kn, kr, v, scale, *, block_q: int, block_k: int,
                            interpret: bool = False):
     """``ops.layers.causal_latent_attention`` through the kernels above, its
-    operands and result in its layouts (``[N, S, H, d]``; ``kr`` ``[N, S,
-    dr]``), float32 out; tiles of ``block_q`` queries by ``block_k`` keys."""
-    def heads_first(x):
-        return jnp.swapaxes(x.astype(jnp.float32), 1, 2)
-
-    o = _flash(heads_first(qn) * scale, heads_first(qr) * scale, heads_first(kn),
-               kr.astype(jnp.float32), heads_first(v), block_q, block_k, interpret)
-    return jnp.swapaxes(o, 1, 2)
+    operands and result in its layouts (heads first, ``[N, H, S, d]``; ``kr``
+    ``[N, S, dr]``), float32 out; tiles of ``block_q`` queries by ``block_k``
+    keys."""
+    qn, qr, kn, kr, v = (x.astype(jnp.float32) for x in (qn, qr, kn, kr, v))
+    return _flash(qn * scale, qr * scale, kn, kr, v, block_q, block_k, interpret)

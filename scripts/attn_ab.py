@@ -1,14 +1,25 @@
 """Attention alone on the chip: the score / softmax / value part of the
-Kanana-2 cell (`[2, 2048, 32, 128 | 64 | 128]`, float32 in), forward and
-forward + backward, the blockwise `jnp` form against the fused kernels at
-several tiles and against the library's splash attention.  One minute:
+Kanana-2 cell (heads first, `[2, 32, 2048, 128 | 64 | 128]`, float32 in),
+forward and forward + backward, the blockwise `jnp` form against the fused
+kernels at several tiles and against the library's splash attention; and the
+layer's whole latent-attention BLOCK (projections, rotary turn, attention,
+output projection; `[2, 2048, 2048]` in and out), as the model writes it
+(`block_heads_first`: `models.kanana2.latent_attention`) against the
+formulation before PR 31 (`block_swapaxes`: `linear` to `[N, S, H * d]`,
+`reshape`, the rotary pair swap on the activation, `swapaxes`), which is
+where the layout work round the kernels shows: the attention-alone rows start
+at the kernels' operands.  Two minutes:
 
     chiprun -- python scripts/attn_ab.py [form,form,...]   # times, on the chip
+    chiprun -- python scripts/attn_ab.py ops form,form     # and each form's device operations
     JAX_PLATFORMS=cpu python scripts/attn_ab.py aot        # compiles for a described v5e
 
 Milliseconds are host-clock over 5 calls, best of 3, layout work included;
-`gap` is the largest difference from the `jnp` form over the largest element,
-for the output's probe sum and the five gradients.
+a block's forward + backward runs under `jax.checkpoint` as a layer of the
+model does (forward, rematerialised forward, backward; gradients of the
+eight leaves and the input).  `gap` is the largest difference from the first
+form of its kind (`jnp`, `block_swapaxes`) over the largest element, for the
+output's probe sum and every gradient.
 """
 import json
 import os
@@ -22,11 +33,14 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from heterofl_tpu.models.kanana2 import latent_attention, latent_attention_shapes  # noqa: E402
 from heterofl_tpu.ops import layers as L  # noqa: E402
 from heterofl_tpu.ops import pallas_attention as PA  # noqa: E402
 
 N, S, H, DN, DR, DV = 2, 2048, 32, 128, 64, 128
-SHAPES = [(N, S, H, DN), (N, S, H, DR), (N, S, H, DN), (N, S, DR), (N, S, H, DV), (N, S, H, DV)]
+SHAPES = [(N, H, S, DN), (N, H, S, DR), (N, H, S, DN), (N, S, DR), (N, H, S, DV), (N, H, S, DV)]
+D, R, THETA = 2048, 512, 1e6  # the block: hidden size, latent width, rope_theta
+LEAVES = latent_attention_shapes(D, H, DN, DR, DV, R)
 
 
 def fused(tile):
@@ -45,19 +59,44 @@ def splash(block, fused_bwd):
                                 block_sizes=sizes, head_shards=1, q_seq_shards=1)
 
     def f(qn, qr, kn, kr, v, scale):
-        def heads_first(x):
-            return jnp.swapaxes(x, 1, 2).astype(jnp.bfloat16)
-
         q = jnp.concatenate([qn, qr], -1) * scale
-        k = jnp.concatenate([kn, jnp.broadcast_to(kr[:, :, None, :], qr.shape)], -1)
-        o = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
-        return jnp.swapaxes(o, 1, 2).astype(jnp.float32)
+        k = jnp.concatenate([kn, jnp.broadcast_to(kr[:, None], qr.shape)], -1)
+        o = jax.vmap(kernel)(*(x.astype(jnp.bfloat16) for x in (q, k, v)))
+        return o.astype(jnp.float32)
     return f
+
+
+def _kv_norm(c, g):
+    return L.masked_rms_norm(c, g, jnp.ones((R,)), jnp.float32(R))
+
+
+def block_heads_first(lp, h, scale):
+    return latent_attention(lp, h, heads=H, theta=THETA, scale=scale, sc=lambda x: x,
+                            kv_norm=_kv_norm)
+
+
+def block_swapaxes(lp, h, scale):
+    """The block as `models/kanana2.py` wrote it before PR 31."""
+    def heads_first(x, d):
+        return jnp.swapaxes(x.reshape(N, S, H, d), 1, 2)
+
+    qn, qr = L.linear(h, lp["attn.q.n.w"]), L.linear(h, lp["attn.q.r.w"])
+    c, kr = L.linear(h, lp["attn.kv_a.c.w"]), L.linear(h, lp["attn.kv_a.r.w"])
+    c = _kv_norm(c, lp["attn.kv_norm.g"])
+    kn, v = L.linear(c, lp["attn.kv_b.k.w"]), L.linear(c, lp["attn.kv_b.v.w"])
+    pos = jnp.arange(S)
+    qr = qr.reshape(N, S, H, DR)  # the rotary turn's pair swap on the activation
+    qr = L.rope_interleaved(qr, L.rope_swap(qr), pos, THETA).reshape(N, S, H * DR)
+    kr = L.rope_interleaved(kr, L.rope_swap(kr), pos, THETA)
+    o = L.causal_latent_attention(heads_first(qn, DN), heads_first(qr, DR), heads_first(kn, DN),
+                                  kr, heads_first(v, DV), scale)
+    return L.linear(jnp.swapaxes(o, 1, 2).reshape(N, S, H * DV), lp["attn.o.w"])
 
 
 FORMS = {"jnp": L.blockwise_latent_attention, "fused256": fused(256), "fused512": fused(512),
          "fused1024": fused(1024), "splash512": splash(512, False),
          "splash1024f": splash(1024, True)}
+BLOCKS = {"block_swapaxes": block_swapaxes, "block_heads_first": block_heads_first}
 
 
 def fwd(f):
@@ -72,19 +111,62 @@ def fwd_bwd(f):
     return g
 
 
+def block_fwd(f):
+    return lambda lp, h, w, scale: f(lp, h, scale)
+
+
+def block_fwd_bwd(f):
+    def g(lp, h, w, scale):
+        loss, grads = jax.value_and_grad(
+            lambda lp_, h_: jnp.sum(jax.checkpoint(f)(lp_, h_, scale) * w), argnums=(0, 1))(lp, h)
+        return (loss, grads[1]) + tuple(grads[0][k] for k in sorted(grads[0]))
+    return g
+
+
+def device_ops(fn, args, calls=3):
+    """(device ms a call, [(ms a call, operation)]) of ``fn`` by the device's
+    own clock: the self times of a traced run's operations, largest first."""
+    import glob
+    import tempfile
+
+    from benchmark.trace_reduce import load_xplane, self_times
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(calls):
+                r = fn(*args)
+            jax.block_until_ready(r)
+        trace = load_xplane(glob.glob(os.path.join(d, "plugins/profile/*/*.xplane.pb"))[0])
+    events = [e for plane in trace["planes"] if plane["name"].startswith("/device:")
+              for line in plane["lines"] for e in line["events"]]
+    times = self_times(events, 0.0, float("inf"))
+    rows = sorted(((ns / calls / 1e6, name) for name, ns in times.items()), reverse=True)
+    return sum(ms for ms, _ in rows), rows
+
+
 def main(argv):
-    aot = argv[:1] == ["aot"]
-    names = argv[-1].split(",") if argv and argv[-1] != "aot" else list(FORMS)
+    aot, ops = argv[:1] == ["aot"], argv[:1] == ["ops"]
+    names = argv[-1].split(",") if argv and argv[-1] not in ("aot", "ops") \
+        else list(FORMS) + list(BLOCKS)
     if aot:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
 
         one = SingleDeviceSharding(topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2").devices[0])
-        avals = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one) for s in SHAPES + [()]]
+
+        def sds(shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+        jax.default_backend = lambda: "tpu"  # the one question causal_latent_attention asks
+        avals = [sds(s) for s in SHAPES + [()]]
+        block_avals = [{k: sds(s) for k, s in LEAVES.items()}, sds((N, S, D)), sds((N, S, D)),
+                       sds(())]
         for name in names:
             t = time.time()
-            c = jax.jit(fwd_bwd(FORMS[name])).lower(*avals).compile()
+            fn, avals_ = (block_fwd_bwd(BLOCKS[name]), block_avals) if name in BLOCKS \
+                else (fwd_bwd(FORMS[name]), avals)
+            c = jax.jit(fn).lower(*avals_).compile()
             print(f"{name}: compiled in {time.time() - t:.1f}s, temporaries "
                   f"{c.memory_analysis().temp_size_in_bytes >> 20} MB, "
                   f"{c.as_text().count('tpu_custom_call')} custom calls", flush=True)
@@ -92,28 +174,43 @@ def main(argv):
     print(jax.devices(), flush=True)
     if jax.default_backend() != "tpu":
         raise SystemExit("attn_ab times the chip; without one, `aot` compiles for it")
+    scale = jnp.float32(1.0 / np.sqrt(DN + DR))
     args = [jax.random.normal(k, s, jnp.float32)
-            for k, s in zip(jax.random.split(jax.random.key(29), 6), SHAPES)]
-    args.append(jnp.float32(1.0 / np.sqrt(DN + DR)))
-    out, ref = {}, None
+            for k, s in zip(jax.random.split(jax.random.key(29), 6), SHAPES)] + [scale]
+    keys = jax.random.split(jax.random.key(31), len(LEAVES) + 2)
+    lp = {n: jax.random.normal(k, s, jnp.float32) / np.sqrt(s[0])
+          for (n, s), k in zip(LEAVES.items(), keys)}
+    lp["attn.kv_norm.g"] = jnp.ones((R,), jnp.float32)
+    block_args = [lp] + [jax.random.normal(k, (N, S, D), jnp.float32) for k in keys[-2:]] + [scale]
+    out, refs = {}, {}
     for name in names:
         rec = {}
-        for kind, wrap in (("fwd", fwd), ("fwdbwd", fwd_bwd)):
-            fn = jax.jit(wrap(FORMS[name]))
-            r = jax.block_until_ready(fn(*args))
+        block = name in BLOCKS
+        form, args_, wraps = (BLOCKS[name], block_args, (block_fwd, block_fwd_bwd)) if block \
+            else (FORMS[name], args, (fwd, fwd_bwd))
+        for kind, wrap in zip(("fwd", "fwdbwd"), wraps):
+            fn = jax.jit(wrap(form))
+            r = jax.block_until_ready(fn(*args_))
             best = []
             for _ in range(3):
                 t = time.time()
                 for _ in range(5):
-                    r = fn(*args)
+                    r = fn(*args_)
                 jax.block_until_ready(r)
                 best.append((time.time() - t) / 5 * 1e3)
             rec[kind + "_ms"] = round(min(best), 3)
+        if ops:  # of the forward + backward, the loop's last ``fn``
+            total, rows = device_ops(fn, args_)
+            rec["device_ms"], rec["device_ops"] = round(total, 3), rows
+            print(f"{name}: forward + backward {total:.3f} device ms a call; largest operations:")
+            for ms_, op in rows[:14]:
+                print(f"  {ms_:8.3f}  {op}")
         r = [np.asarray(x) for x in r]
-        if name == "jnp":
-            ref = r
-        elif ref is not None:
-            rec["gap"] = [float(np.max(np.abs(a - b)) / np.max(np.abs(b))) for a, b in zip(r, ref)]
+        if name in ("jnp", "block_swapaxes"):
+            refs[block] = r
+        elif block in refs:
+            rec["gap"] = [float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+                          for a, b in zip(r, refs[block])]
         out[name] = rec
         print(name, json.dumps(rec), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)

@@ -366,14 +366,41 @@ def test_kanana2_no_token_is_dropped_when_one_expert_takes_everything(tile):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5 * float(jnp.abs(r).max()))
 
 
+@pytest.mark.parametrize("heads", [1, 4], ids=["one-key-head", "four-query-heads"])
+def test_rope_swap_of_the_weight_is_the_swap_of_the_product(heads):
+    """``swap(h W) = h swap(W)`` exactly (a column of the swapped weight is a
+    column of the weight, negated or not), heads holding whole pairs; and the
+    turn built from it is a rotation: it keeps each pair's norm, leaves
+    position 0 alone, and a zero (masked) pair stays zero."""
+    from heterofl_tpu.ops import layers as L
+
+    d, theta = 8, 1e4
+    kh, kw = jax.random.split(jax.random.key(8))
+    h = jax.random.normal(kh, (2, 6, 16))
+    w = jax.random.normal(kw, (16, heads * d)).at[:, 2:4].set(0.0)
+    x, swapped = h @ w, h @ L.rope_swap(w)
+    np.testing.assert_array_equal(swapped, L.rope_swap(x))
+    np.testing.assert_array_equal(swapped[..., 0::2], -x[..., 1::2])
+    x, swapped = (t.reshape(2, 6, heads, d) for t in (x, swapped))
+    y = L.rope_interleaved(x, swapped, jnp.arange(6), theta)
+    np.testing.assert_allclose(y[:, 0], x[:, 0], rtol=1e-6)
+    pairs = lambda t: jnp.sum(t.reshape(2, 6, heads, d // 2, 2) ** 2, -1)  # noqa: E731
+    np.testing.assert_allclose(pairs(y), pairs(x), rtol=1e-5, atol=1e-6)
+    assert not np.any(y[:, :, 0, 2:4])
+    # heads first, the positions on axis 2: the same turn
+    y_hf = L.rope_interleaved(jnp.swapaxes(x, 1, 2), jnp.swapaxes(swapped, 1, 2),
+                              jnp.arange(6), theta, axis=2)
+    np.testing.assert_array_equal(jnp.swapaxes(y_hf, 1, 2), y)
+
+
 def test_causal_latent_attention_in_blocks_is_the_attention_in_one():
     """The query blocks are memory, not mathematics: 16 positions in blocks
     of 8 (and of 5, a ragged last block) against one block."""
     from heterofl_tpu.ops import layers as L
 
     ks = jax.random.split(jax.random.key(7), 5)
-    qn, kn, v = (jax.random.normal(k, (2, 16, 4, 16)) for k in ks[:3])
-    qr, kr = jax.random.normal(ks[3], (2, 16, 4, 8)), jax.random.normal(ks[4], (2, 16, 8))
+    qn, kn, v = (jax.random.normal(k, (2, 4, 16, 16)) for k in ks[:3])
+    qr, kr = jax.random.normal(ks[3], (2, 4, 16, 8)), jax.random.normal(ks[4], (2, 16, 8))
     whole = L.causal_latent_attention(qn, qr, kn, kr, v, 0.2, block=16)
     for block in (8, 5):
         np.testing.assert_allclose(L.causal_latent_attention(qn, qr, kn, kr, v, 0.2, block=block),

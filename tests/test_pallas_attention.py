@@ -23,11 +23,12 @@ SCALE = 0.125
 
 
 def _operands(key, N=1, S=256, H=2, dn=128, dr=64, dv=128, active=None):
-    """qn, qr, kn, kr, v and a probe of the output's shape; with ``active =
-    (an, ar, av)`` the head dims past those counts are zero, as a narrow
-    client's are under the masked engine."""
-    shapes = [(N, S, H, dn), (N, S, H, dr), (N, S, H, dn), (N, S, dr), (N, S, H, dv),
-              (N, S, H, dv)]
+    """qn, qr, kn, kr, v (heads first, the one rotary key ``[N, S, dr]``) and a
+    probe of the output's shape; with ``active = (an, ar, av)`` the head dims
+    past those counts are zero, as a narrow client's are under the masked
+    engine."""
+    shapes = [(N, H, S, dn), (N, H, S, dr), (N, H, S, dn), (N, S, dr), (N, H, S, dv),
+              (N, H, S, dv)]
     ops = [jax.random.normal(k, s).astype(jnp.bfloat16).astype(jnp.float32)
            for k, s in zip(jax.random.split(key, 6), shapes)]
     if active is not None:
@@ -97,21 +98,21 @@ def test_fused_attention_is_the_blockwise_attention(case):
         qn, qr, kn, kr, v = ops
 
         def own_copy_a_head(kr_heads):  # plain attention, one rotary key a head
-            s = (jnp.einsum("nqhd,nkhd->nhqk", qn, kn)
-                 + jnp.einsum("nqhd,nkhd->nhqk", qr, kr_heads)) * SCALE
+            s = (jnp.einsum("nhqd,nhkd->nhqk", qn, kn)
+                 + jnp.einsum("nhqd,nhkd->nhqk", qr, kr_heads)) * SCALE
             s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
-            return jnp.sum(jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, -1), v) * probe)
+            return jnp.sum(jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, -1), v) * probe)
 
-        a_head = jax.grad(own_copy_a_head)(jnp.repeat(kr[:, :, None], H, axis=2))
+        a_head = jax.grad(own_copy_a_head)(jnp.repeat(kr[:, None], H, axis=1))
         dkr = _out_and_grads(_fused(128, 128), ops, probe, SCALE)[4]
-        _close([dkr], [jnp.sum(a_head, axis=2)])
+        _close([dkr], [jnp.sum(a_head, axis=1)])
 
 
 def _calls(S, dn, dr, dv, backend, monkeypatch):
     """Names of the ``pallas_call``s in ``causal_latent_attention``'s program
     at these shapes when jax reports ``backend``."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    shapes = [(1, S, 2, dn), (1, S, 2, dr), (1, S, 2, dn), (1, S, dr), (1, S, 2, dv)]
+    shapes = [(1, 2, S, dn), (1, 2, S, dr), (1, 2, S, dn), (1, S, dr), (1, 2, S, dv)]
     jaxpr = jax.make_jaxpr(lambda *a: L.causal_latent_attention(*a, 0.1))(
         *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
     text = str(jaxpr)
@@ -130,3 +131,70 @@ def _calls(S, dn, dr, dv, backend, monkeypatch):
 def test_which_form_runs_is_decided_by_backend_and_shapes(S, dn, dr, dv, backend, fused,
                                                           monkeypatch):
     assert _calls(S, dn, dr, dv, backend, monkeypatch) == (["latent_attn_fwd"] if fused else [])
+
+
+@pytest.mark.parametrize("rate, compute_dtype", [(1.0, None), (0.25, None), (0.5, jnp.bfloat16)],
+                         ids=["rate-1", "rate-1/4", "bfloat16-operands"])
+def test_attention_block_is_the_reshape_and_swapaxes_formulation(rate, compute_dtype):
+    """``models.kanana2.latent_attention`` (head-indexed products, the rotary
+    turn on ``[N, H, S, dr]`` with the pair swap taken on the weight, the
+    output projection from ``[N, H, S, dv]``) against the formulation it
+    replaced, written out here: ``linear`` to ``[N, S, H * d]``, ``reshape``
+    to heads, the pair swap by rotating the activation's lanes, ``swapaxes``
+    to heads first, and back for the output projection.  The same products in
+    another order of their results: the block's output and every gradient to
+    float32 round-off."""
+    from heterofl_tpu.models.kanana2 import latent_attention, latent_attention_shapes
+
+    N, S, D, H, dn, dr, dv, R = 2, 24, 32, 4, 16, 8, 16, 12
+    theta, scale = 1e4, 0.2
+    shapes = latent_attention_shapes(D, H, dn, dr, dv, R)
+    lp = {n: jax.random.normal(k, s) / np.sqrt(s[0])
+          for (n, s), k in zip(shapes.items(), jax.random.split(jax.random.key(5), len(shapes)))}
+    lp["attn.kv_norm.g"] = jnp.linspace(0.5, 1.5, R)
+    h, probe = (jax.random.normal(k, (N, S, D)) for k in jax.random.split(jax.random.key(6)))
+
+    def sc(x):
+        return x / rate
+
+    def kv_norm(c, g):
+        return L.masked_rms_norm(c, g, jnp.ones((R,)), jnp.float32(R))
+
+    def swapaxes_form(lp, h):
+        def lin(x, w):
+            return L.linear(x, w, compute_dtype=compute_dtype)
+
+        def heads_first(x, d):
+            return jnp.swapaxes(x.reshape(N, S, H, d), 1, 2)
+
+        qn, qr = sc(lin(h, lp["attn.q.n.w"])), sc(lin(h, lp["attn.q.r.w"]))
+        c, kr = sc(lin(h, lp["attn.kv_a.c.w"])), sc(lin(h, lp["attn.kv_a.r.w"]))
+        c = kv_norm(c, lp["attn.kv_norm.g"])
+        kn, v = sc(lin(c, lp["attn.kv_b.k.w"])), sc(lin(c, lp["attn.kv_b.v.w"]))
+        pos = jnp.arange(S)
+        qr = qr.reshape(N, S, H, dr)  # the turn on the activation: its lanes rotated
+        qr = L.rope_interleaved(qr, L.rope_swap(qr), pos, theta).reshape(N, S, H * dr)
+        kr = L.rope_interleaved(kr, L.rope_swap(kr), pos, theta)
+        ops = [heads_first(qn, dn), heads_first(qr, dr), heads_first(kn, dn), kr,
+               heads_first(v, dv)]
+        if compute_dtype is not None:
+            ops = [x.astype(compute_dtype) for x in ops]
+        o = L.causal_latent_attention(*ops, scale).astype(jnp.float32)
+        return sc(lin(jnp.swapaxes(o, 1, 2).reshape(N, S, H * dv), lp["attn.o.w"]))
+
+    def heads_first_form(lp, h):
+        return latent_attention(lp, h, heads=H, theta=theta, scale=scale, sc=sc,
+                                kv_norm=kv_norm, compute_dtype=compute_dtype)
+
+    def out_and_grads(block):
+        def loss(lp, h):
+            y = block(lp, h)
+            return jnp.sum(y * probe), y
+
+        (dlp, dh), y = jax.grad(loss, argnums=(0, 1), has_aux=True)(lp, h)
+        return [y, dh] + [dlp[k] for k in sorted(dlp)]
+
+    tol = 1e-5 if compute_dtype is None else 2e-2  # bfloat16 rounds a result where it is written
+    for got, want in zip(out_and_grads(heads_first_form), out_and_grads(swapaxes_form)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(got, want, rtol=0, atol=tol * float(jnp.abs(want).max()))

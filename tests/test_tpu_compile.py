@@ -12,7 +12,9 @@ file, and compile in the test's own process.
 """
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,13 +110,11 @@ def test_pallas_norm_compiles(one_chip, n, h, c):
 def test_latent_attention_kernels_compile_under_attn(one_chip, vmapped, monkeypatch):
     """The fused causal latent attention, forward and backward, at the
     Kanana-2 cell's shapes (2 rows x 2,048 positions, 32 heads, 128 | 64 | 128
-    head dims), as the model calls it (the described chip is not the default
-    backend, so the test steers the one question the function asks), bare and
-    under ``vmap`` over client slots with a per-client scale; and both custom
-    calls carry the ``attn`` scope, forward and transposed, by which the
-    traced run's metrics find them."""
-    import re
-
+    head dims, heads first), as the model calls it (the described chip is not
+    the default backend, so the test steers the one question the function
+    asks), bare and under ``vmap`` over client slots with a per-client scale;
+    and both custom calls carry the ``attn`` scope, forward and transposed, by
+    which the traced run's metrics find them."""
     from heterofl_tpu.ops.layers import causal_latent_attention
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -126,8 +126,8 @@ def test_latent_attention_kernels_compile_under_attn(one_chip, vmapped, monkeypa
 
     lead = (SLOTS,) if vmapped else ()
     avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip)
-             for s in ((N, S, H, dn), (N, S, H, dr), (N, S, H, dn), (N, S, dr),
-                       (N, S, H, dv), ())]
+             for s in ((N, H, S, dn), (N, H, S, dr), (N, H, S, dn), (N, S, dr),
+                       (N, H, S, dv), ())]
     text = _compile(jax.vmap(grads) if vmapped else grads, *avals,
                     kernels=("latent_attn_fwd", "latent_attn_bwd"))
     calls = [line for line in text.splitlines()
@@ -139,3 +139,72 @@ def test_latent_attention_kernels_compile_under_attn(one_chip, vmapped, monkeypa
     # no [rows, heads, queries, keys] score block goes through HBM
     assert not [m for m in re.findall(r"f32\[(?:10,)?2,32,(\d+),(\d+)\]", text)
                 if int(m[0]) > 1 and int(m[1]) > max(dn, dv)]
+
+
+#: bytes of an element, for the shapes a relayout of the block can have
+_ITEMSIZE = {"f32": 4, "bf16": 2}
+
+
+def _standalone_relayouts(text, floor=4 << 20):
+    """(bytes written, instruction) of every ``copy`` / ``transpose`` of at
+    least ``floor`` bytes that is an instruction of its own in the entry
+    computation (one inside a fusion is that fusion's own read or write)."""
+    found = []
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = (\w+)\[([\d,]+)\]\S* (?:copy|transpose)\(", line)
+        if m and m.group(1) in _ITEMSIZE:
+            size = _ITEMSIZE[m.group(1)] * math.prod(int(d) for d in m.group(2).split(","))
+            if size >= floor:
+                found.append((size, line.strip()))
+    return found
+
+
+def test_latent_attention_block_feeds_the_kernels_without_relayouts(one_chip, monkeypatch):
+    """A layer's whole latent attention (``models.kanana2.latent_attention``:
+    projections, rotary turn, attention, output projection) at the Kanana-2
+    cell's shapes, under ``jax.checkpoint`` and ``jax.grad`` as a layer of the
+    model runs it: (a) both kernels are there under ``attn``; (b) the
+    projections write ``qn``, ``qr``, ``kn``, ``v`` and read their gradients
+    in the kernels' heads-first layout, so no activation of theirs is copied
+    on its own, in the forward, the rematerialised forward or the backward.
+
+    The formulation before (PR 29: ``linear`` to ``[N, S, H * d]``, ``reshape``,
+    ``swapaxes``) compiled to 22 standalone copies of 4 MB or more writing
+    788.5 MB a layer pass, every operand twice (``bf16[2,2048,4096]{1,2,0}``
+    then ``bf16[2,2048,32,128]{3,1,2,0}``) in each of the three passes.  This
+    one compiles to 17 writing 260.0 MB: the weights' bfloat16 and gradient
+    relayouts (192.9 MB; 4-34 MB each; 33.5 MB of it the rotary query weight
+    and its pair-swapped twin, without which it was 16 writing 226.5 MB) and
+    ONE activation, the attention's float32 result ``o`` in the
+    rematerialised forward (67.1 MB), which the output projection's weight
+    gradient reads with the positions minor."""
+    from heterofl_tpu.models.kanana2 import latent_attention, latent_attention_shapes
+    from heterofl_tpu.ops.layers import masked_rms_norm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, S, D, H, dn, dr, dv, R = 2, 2048, 2048, 32, 128, 64, 128, 512
+    shapes = latent_attention_shapes(D, H, dn, dr, dv, R)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    @jax.checkpoint
+    def block(lp, h, scale, rate):
+        return latent_attention(
+            lp, h, heads=H, theta=1e6, scale=scale, sc=lambda x: x / rate,
+            kv_norm=lambda c, g: masked_rms_norm(c, g, jnp.ones((R,)), jnp.float32(R)))
+
+    text = _compile(jax.grad(lambda *a: jnp.sum(block(*a) ** 2), argnums=(0, 1)),
+                    {k: sds(*s) for k, s in shapes.items()}, sds(N, S, D), sds(), sds(),
+                    kernels=("latent_attn_fwd", "latent_attn_bwd"))
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 3  # forward, rematerialised forward, backward
+    assert all(re.search(r"attn\)*/latent_attn_(fwd|bwd)/pallas_call$", c) for c in calls), calls
+    relayouts = _standalone_relayouts(text)
+    listing = "\n".join(line for _, line in relayouts)
+    assert sum(size for size, _ in relayouts) <= 265e6, listing
+    # of the activations ([2, 32, 2048, d] or [2, 2048, 32 * d] in any order) only ``o``
+    activations = [line for size, line in relayouts
+                   if re.search(r"\[2,(32,2048|2048,32|2048),\d+\]", line)]
+    assert len(activations) <= 1 and all("f32[2,32,2048,128]" in a for a in activations), listing
