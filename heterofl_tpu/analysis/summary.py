@@ -40,7 +40,7 @@ def profile_model(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[i
             else cfg["batch_size"]
     else:
         bs = batch_size
-    if model.meta["kind"] == "transformer":
+    if model.is_lm:
         batch = {"label": jnp.zeros((bs, cfg["bptt"]), jnp.int32)}
     else:
         batch = {"img": jnp.zeros((bs,) + tuple(cfg["data_shape"]), jnp.float32),
@@ -169,32 +169,35 @@ def module_table(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[in
         add("avgpool", (bs, h, w, in_planes), (bs, in_planes), 0, bs * h * w * in_planes)
         add("linear", (bs, in_planes), (bs, cfg["classes_size"]), mods("linear"),
             bs * in_planes * cfg["classes_size"])
-    elif kind == "kanana2":
-        # one row per matrix leaf (a linear's MACs = tokens x its size; a
-        # routed expert sees top_k / n_experts of the tokens) plus the two
-        # attention matmuls; norms, RoPE, softmax and the router's top-k are
-        # not matmul-like and are left out, as the benchmark's FLOP file
-        # (benchmark/flops/kanana2.py) leaves them out
-        a = cfg["kanana2"]
-        T, H = cfg["bptt"], a["num_attention_heads"]
+    elif "profile" in model.meta:
+        # a family that describes itself (kanana2, lfm2): one row per matrix
+        # leaf (a linear's MACs = tokens x its size; a routed expert sees
+        # top_k / n_experts of the tokens; a depthwise tap leaf [taps,
+        # channels] is its size too) plus the two attention matmuls of each
+        # attention layer and, for a tied head, the head's product; norms,
+        # gates, RoPE, softmax and the router's top-k are not matmul-like and
+        # are left out, as the benchmark's FLOP files (benchmark/flops/) leave
+        # them out
+        prof = model.meta["profile"]
+        T = cfg["bptt"]
         ntok = bs * T
         shapes = {k: tuple(v.shape) for k, v in params.items()}
-        share = a["num_experts_per_tok"] / a["n_routed_experts"]
         for name in sorted(shapes):
             shp = shapes[name]
             if len(shp) != 2:
                 add(name, (bs, T, shp[0]), (bs, T, shp[0]), psize[name], ntok * shp[0] * 2)
-            elif name.startswith("embedding."):
+            elif name.startswith("embedding.") or name == prof.get("tied_head"):
                 add("embedding", (bs, T), (bs, T, shp[1]), psize[name], ntok * shp[1])
             else:
-                toks = ntok * share if ".moe.e" in name else ntok
+                toks = ntok * prof["routed_share"] if ".moe.e" in name else ntok
                 add(name[:-2] if name.endswith(".w") else name, (bs, T, shp[0]),
                     (bs, T, shp[1]), psize[name], toks * shp[0] * shp[1])
-        for i in range(a["num_hidden_layers"]):
-            dq = (shapes[f"l{i}.attn.q.n.w"][1] + shapes[f"l{i}.attn.q.r.w"][1]) // H
-            dv = shapes[f"l{i}.attn.kv_b.v.w"][1] // H
-            add(f"l{i}.attn.qk", (bs, T, H * dq), (bs, H, T, T), 0, bs * H * T * (T + 1) // 2 * dq)
-            add(f"l{i}.attn.av", (bs, H, T, T), (bs, T, H * dv), 0, bs * H * T * (T + 1) // 2 * dv)
+        if "tied_head" in prof:
+            V, D = shapes[prof["tied_head"]]
+            add("head", (bs, T, D), (bs, T, V), 0, ntok * D * V)
+        for site, (H, dq, dv) in prof["attention"].items():
+            add(f"{site}.qk", (bs, T, H * dq), (bs, H, T, T), 0, bs * H * T * (T + 1) // 2 * dq)
+            add(f"{site}.av", (bs, H, T, T), (bs, T, H * dv), 0, bs * H * T * (T + 1) // 2 * dv)
     else:  # transformer
         from ..config import ceil_width
 

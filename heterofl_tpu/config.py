@@ -39,10 +39,10 @@ CONTROL_KEYS = (
 # data/ import these rather than re-declaring them.
 NORM_TYPES = ("bn", "in", "ln", "gn", "none")
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
-               "resnet152", "transformer", "kanana2")
+               "resnet152", "transformer", "kanana2", "lfm2")
 #: the families that train on token rows (next- or masked-token loss): the
 #: drivers' and engines' LM paths key on this, not on one family's name
-LM_MODEL_NAMES = ("transformer", "kanana2")
+LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2")
 # Feature-axis value registries (ISSUE 18): THE declared domains of the
 # engine/placement/store/pod axes, consumed by the axis validators below and
 # by staticcheck's config-lattice pass (staticcheck/lattice.py enumerates
@@ -470,6 +470,33 @@ def process_control(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "kv_lora_rank": 512,
         "rope_theta": 1000000.0,
         "rms_norm_eps": 1e-6,
+        "expert_share": [0, 1],
+    }
+    # LFM2-8B-A1B (model_type lfm2_moe): the published shape
+    # (huggingface.co/LiquidAI/LFM2-8B-A1B config.json); ``head_dim`` =
+    # hidden_size / num_attention_heads and ``conv_dim`` = hidden_size are the
+    # family's convention, not keys of that file.  ``expert_share`` as above.
+    cfg["lfm2"] = {
+        "hidden_size": 2048,
+        "num_hidden_layers": 24,
+        "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv",
+                        "full_attention", "conv", "conv", "conv", "full_attention",
+                        "conv", "conv", "conv", "full_attention", "conv", "conv",
+                        "conv", "full_attention", "conv", "conv", "full_attention",
+                        "conv", "conv"],
+        "num_dense_layers": 2,
+        "intermediate_size": 7168,
+        "moe_intermediate_size": 1792,
+        "num_experts": 32,
+        "num_experts_per_tok": 4,
+        "routed_scaling_factor": 1.0,
+        "num_attention_heads": 32,
+        "num_key_value_heads": 8,
+        "head_dim": 64,
+        "conv_dim": 2048,
+        "conv_L_cache": 3,
+        "rope_theta": 1000000.0,
+        "norm_eps": 1e-5,
         "expert_share": [0, 1],
     }
     # Per-dataset hyperparameters (ref src/utils.py:150-212).

@@ -79,6 +79,22 @@ def validate_width_geometry(model: ModelDef, cfg: Dict[str, Any]) -> None:
                     f"pick embedding_size so embedding*rate is a multiple-safe "
                     f"size (e.g. embedding_size*min_rate >= num_heads and "
                     f"head_dim divisible by 1/min_rate)")
+    # grouped-query slicing: the query heads, the key/value heads and the
+    # head norms' gains keep the same dims of a head, in whole rotary pairs
+    families: Dict[str, list] = {}
+    for name, g in model.groups.items():
+        if g.family:
+            families.setdefault(g.family, []).append((name, g))
+    for family, members in families.items():
+        for wr in sorted(rates):
+            kept = {name: g.rule.head_width(g, wr) for name, g in members}
+            odd = [name for name, g in members if kept[name] is None or kept[name] % g.multiple]
+            if odd or len(set(kept.values())) != 1:
+                raise ValueError(
+                    f"width geometry: head family {family!r} is inconsistent at "
+                    f"rate {wr:g}: its groups keep {kept} dims a head (they must "
+                    f"agree, in whole multiples of each group's `multiple`); give "
+                    f"them one head size, one rule and one `multiple`")
 
 
 ROUND_RATE_SALT = 7

@@ -55,3 +55,27 @@ def uniform_fan_in(key: jax.Array, shape, fan_in: int) -> jnp.ndarray:
 
 def normal_init(key: jax.Array, shape, std: float) -> jnp.ndarray:
     return std * jax.random.normal(key, shape, jnp.float32)
+
+
+def held_experts(expert_share, n: int) -> range:
+    """The routed experts a share ``(index, of)`` of an ``of``-way
+    expert-parallel layer of ``n`` experts holds: ``[index * n/of, (index +
+    1) * n/of)``."""
+    index, of = (int(v) for v in expert_share)
+    if of < 1 or n % of or not 0 <= index < of:
+        raise ValueError(f"Not valid expert_share: {list(expert_share)!r} "
+                         f"(index, of) with of dividing the {n} routed experts")
+    return range(index * (n // of), (index + 1) * (n // of))
+
+
+def layer_leaves(params: Dict[str, jnp.ndarray], i: int, held=None) -> Dict[str, jnp.ndarray]:
+    """Layer ``i``'s leaves (``l{i}.*``) without their prefix; with ``held``
+    (an expert layer), its held experts' ``moe.e{j}.{g,u,d}.w`` stacked on a
+    leading axis as ``moe.e.{g,u,d}.w``, in that order."""
+    pre = f"l{i}."
+    lp = {k[len(pre):]: v for k, v in params.items()
+          if k.startswith(pre) and ".moe.e" not in k}
+    if held is not None:
+        for m in "gud":
+            lp[f"moe.e.{m}.w"] = jnp.stack([params[f"{pre}moe.e{j}.{m}.w"] for j in held])
+    return lp

@@ -57,25 +57,12 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import scope
-from ..ops.layers import (causal_latent_attention, cross_entropy, embed,
-                          heads_linear, linear as _linear, linear_heads,
-                          masked_logits, masked_rms_norm, moe_experts, moe_route,
+from ..ops.layers import (causal_latent_attention, embed, heads_linear,
+                          linear as _linear, linear_heads, masked_logits,
+                          masked_rms_norm, moe_experts, moe_route, next_token_loss,
                           rope_interleaved, rope_swap, scaler, swiglu)
-from .base import ModelDef, normal_init, uniform_fan_in
+from .base import ModelDef, held_experts, layer_leaves, normal_init, uniform_fan_in
 from .spec import Group, ParamSpec
-
-#: positions a block of the head-and-loss takes (memory, not mathematics)
-LOSS_BLOCK = 1024
-
-
-def held_experts(arch) -> range:
-    """The routed experts this share holds."""
-    index, of = (int(v) for v in arch["expert_share"])
-    n = int(arch["n_routed_experts"])
-    if of < 1 or n % of or not 0 <= index < of:
-        raise ValueError(f"Not valid expert_share: {arch['expert_share']!r} "
-                         f"(index, of) with of dividing n_routed_experts={n}")
-    return range(index * (n // of), (index + 1) * (n // of))
 
 
 def latent_attention_shapes(D: int, H: int, dn: int, dr: int, dv: int, R: int) -> Dict[str, tuple]:
@@ -88,9 +75,11 @@ def latent_attention_shapes(D: int, H: int, dn: int, dr: int, dv: int, R: int) -
 
 
 def latent_attention(lp, h, *, heads: int, theta: float, scale, sc, kv_norm,
-                     compute_dtype=None):
+                     compute_dtype=None, rope_dim=None):
     """A layer's latent attention on the normed ``h`` ``[N, S, D]``; ``lp`` the
-    layer's leaves, ``sc`` the Scaler, ``kv_norm(c, g)`` the latent's norm.
+    layer's leaves, ``sc`` the Scaler, ``kv_norm(c, g)`` the latent's norm,
+    ``rope_dim`` the GLOBAL model's rotary width (None: the leaves' own; a
+    sliced sub-model's pairs keep the global frequencies).
 
     Heads first from end to end: the per-head projections write ``[N, H, S,
     d]`` (``linear_heads`` on the stored ``[K, H * d]`` leaves), the rotary
@@ -111,9 +100,9 @@ def latent_attention(lp, h, *, heads: int, theta: float, scale, sc, kv_norm,
         c = kv_norm(c, lp["attn.kv_norm.g"])
         kn = sc(per_head(c, lp["attn.kv_b.k.w"]))
         v = sc(per_head(c, lp["attn.kv_b.v.w"]))
-    qr = rope_interleaved(qr, qr_swapped, pos, theta, axis=2)
+    qr = rope_interleaved(qr, qr_swapped, pos, theta, axis=2, full=rope_dim)
     # one key head: its swap is cheap where it is, a second product reads ``h`` again
-    kr = rope_interleaved(kr, rope_swap(kr), pos, theta)
+    kr = rope_interleaved(kr, rope_swap(kr), pos, theta, full=rope_dim)
     if compute_dtype is not None:
         qn, qr, kn, kr, v = (t.astype(compute_dtype) for t in (qn, qr, kn, kr, v))
     o = causal_latent_attention(qn, qr, kn, kr, v, scale)
@@ -145,7 +134,7 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
     theta, eps = float(arch["rope_theta"]), float(arch["rms_norm_eps"])
     # staticcheck: allow(no-float-coercion): build-time config scalar
     scaling = float(arch["routed_scaling_factor"])
-    held = held_experts(arch)
+    held = held_experts(arch["expert_share"], E)
     if dr % 2:
         raise ValueError(f"rotary width {dr} is not whole pairs")
 
@@ -241,7 +230,7 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         attention = partial(
             latent_attention, heads=H, theta=theta, scale=scale, sc=sc,
             kv_norm=lambda c, g: masked_rms_norm(c, g, lora_mask, act["kv_lora"], eps),
-            compute_dtype=compute_dtype)
+            compute_dtype=compute_dtype, rope_dim=int(arch["qk_rope_head_dim"]))
 
         def ffn(lp, prefix, h):
             return swiglu(h, lp[f"{prefix}.g.w"], lp[f"{prefix}.u.w"], lp[f"{prefix}.d.w"],
@@ -266,14 +255,7 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             return x + y.reshape(N, S, D), counters
 
         def leaves(i):
-            """Layer ``i``'s leaves without their prefix, its held experts'
-            stacked on a leading axis."""
-            lp = {k[len(f"l{i}."):]: v for k, v in params.items()
-                  if k.startswith(f"l{i}.") and ".moe.e" not in k}
-            if i >= L_dense:
-                for m in "gud":
-                    lp[f"moe.e.{m}.w"] = jnp.stack([params[f"l{i}.moe.e{j}.{m}.w"] for j in held])
-            return lp
+            return layer_leaves(params, i, held if i >= L_dense else None)
 
         x = embed(params["embedding.tok.w"], labels)
         for i in range(L_dense):
@@ -287,37 +269,23 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 expert_layer, x, {k: jnp.stack([lp[k] for lp in rest]) for k in rest[0]})
             counters = jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), per_layer)
         xn = rms(params["norm.g"], x)
-        # the logits a caller may read (evaluation does not, training does
-        # not: then the compiler drops them)
-        out = masked_logits(linear(xn, params["head.w"]), label_mask, mask)  # [N, S, V]
-        # next token inside each row: position t predicts t + 1; the last
-        # position of a window has no target.  The loss takes the head a
-        # block of positions at a time, each block under jax.checkpoint, so
-        # that [T, V] logits are never held (T = 4,096, V = 16,032: 263 MB a
-        # copy, and cross entropy keeps several)
-        w = jnp.ones((N, S), jnp.float32) if sample_weight is None else \
-            jnp.broadcast_to(sample_weight, (N, S)).astype(jnp.float32)
-        tgt = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1).reshape(T)
-        wt = jnp.concatenate([w[:, 1:] * w[:, :-1], jnp.zeros((N, 1), jnp.float32)],
-                             axis=1).reshape(T)
-        c = LOSS_BLOCK if T % LOSS_BLOCK == 0 else T
 
-        def block_nll(xs):
-            x_c, t_c, w_c = xs
-            lg = masked_logits(linear(x_c, params["head.w"]), label_mask, mask)
-            return cross_entropy(lg, t_c, w_c) * jnp.sum(w_c)  # the block's weighted sum
+        def head(x_):
+            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
 
-        sums = jax.lax.map(jax.checkpoint(block_nll),
-                           (xn.reshape(T // c, c, D), tgt.reshape(T // c, c),
-                            wt.reshape(T // c, c)))
-        loss = jnp.sum(sums) / jnp.maximum(jnp.sum(wt), 1e-12)
-        res = {"score": out, "loss": loss}
+        # the logits [N, S, V] a caller may read (evaluation does not, training
+        # does not: then the compiler drops them); the loss takes the head in
+        # blocks of positions
+        res = {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight)}
         if counters is not None:
             res["counters"] = {f"moe_{k}": v for k, v in counters.items()}
         return res, {}
 
     meta = {"bn_sizes": {}, "kind": "kanana2", "num_tokens": num_tokens,
-            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes)}
+            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes),
+            # what analysis.summary.module_table cannot read off the leaves
+            "profile": {"routed_share": K / E,
+                        "attention": {f"l{i}.attn": (H, dn + dr, dv) for i in range(L)}}}
     if L > L_dense:
         # what apply's "counters" holds (summed over the expert layers); the
         # engines carry them as obs_ probes when telemetry is on

@@ -65,6 +65,11 @@ class GroupRule:
         (``g.coupled``), else None: ``validate_width_geometry``'s check."""
         return None
 
+    def head_width(self, g: "Group", width_rate: float):
+        """Dims of ONE head this group keeps at a static rate, for a group of
+        heads (None otherwise): groups of one ``g.family`` must agree."""
+        return None
+
 
 class _FullRule(GroupRule):
     """Never sliced (output layers, ref fed.py:43-44,85-87; an expert axis,
@@ -164,6 +169,9 @@ class _PerHeadRule(GroupRule):
         return (g.num_heads * self._keep(g, width_rate),
                 int(math.ceil(g.size * width_rate)))
 
+    def head_width(self, g, width_rate):
+        return self._keep(g, width_rate)
+
 
 #: kind -> rule.  A model family with a new way to cut an axis registers its
 #: rule here (``GROUP_RULES["my_kind"] = MyRule()``) and names the kind in its
@@ -184,6 +192,12 @@ class Group:
     #: two kept counts must agree at every level; False when the heads have
     #: an axis of their own (latent attention's per-head dims)
     coupled: bool = True
+    #: per_head: groups of one family cut heads that meet in one product
+    #: (grouped-query attention: 32 query heads, 8 key/value heads and the
+    #: per-head norms' gains all hold the dims of ONE head shape), so they
+    #: must keep the same dims a head, in whole multiples, at every level
+    #: (``fed.core.validate_width_geometry``); "" = a family of one
+    family: str = ""
 
     @property
     def rule(self) -> GroupRule:

@@ -81,13 +81,24 @@ EXTRA_SCOPES = (
     "round/chunk",
 )
 
+#: What ISSUE 32 added: the two mixers of models/lfm2.py.  ``shortconv`` = a
+#: conv mixer whole (its two projections file under ``shortconv/linear``),
+#: ``shortconv/gate`` = the two elementwise gates and the depthwise taps
+#: between the projections (``ops.layers.short_conv``); ``gqa`` = grouped-query
+#: attention's projections and per-head norms around ``rope`` and ``attn``,
+#: as ``mla`` is for latent attention.  A third tuple because
+#: benchmark/scope_reduce_moe.py mirrors :data:`EXTRA_SCOPES` name for name
+#: as benchmark/scope_reduce.py mirrors :data:`SCOPES`; read through
+#: benchmark/scope_reduce_lfm2.py.
+MIXER_SCOPES = ("shortconv", "shortconv/gate", "gqa")
+
 #: Version of the vocabulary AND of where it is entered.  jax keeps metadata
 #: out of the persistent compile cache's key, so a program whose only change
 #: is a scope would load the executable cached before the change, without
 #: the new names, silently; ``utils.compile_cache`` folds this number into
 #: the key.  Bump it with every change to :data:`SCOPES` or to where a scope
 #: is entered (one cold compile per program, once).
-SCOPE_VERSION = 3
+SCOPE_VERSION = 4
 
 #: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
 KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
@@ -100,7 +111,7 @@ EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd")
 
 
 def _known(name: str) -> str:
-    if name not in SCOPES and name not in EXTRA_SCOPES:
+    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES:
         raise ValueError(f"Not valid scope: {name!r} (obs.trace.SCOPES)")
     return name
 

@@ -311,8 +311,41 @@ def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
     return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-12)
 
 
+#: positions a block of the head-and-loss takes (memory, not mathematics)
+LOSS_BLOCK = 1024
+
+
+def next_token_loss(xn: jnp.ndarray, labels: jnp.ndarray, logits_of,
+                    sample_weight: Optional[jnp.ndarray] = None,
+                    block: int = LOSS_BLOCK) -> jnp.ndarray:
+    """Mean next-token cross entropy inside each row: position ``t`` predicts
+    ``t + 1``; the last position of a window has no target.  ``xn`` ``[N, S,
+    D]`` the final normed states, ``labels`` ``[N, S]``, ``logits_of(x)`` the
+    head with its label masking on a block ``[block, D]``.  The head is taken
+    a block of positions at a time, each block under ``jax.checkpoint``, so
+    that ``[T, V]`` logits are never held (T = 4,096, V = 16,032: 263 MB a
+    copy, and cross entropy keeps several)."""
+    N, S = labels.shape
+    T, D = N * S, xn.shape[-1]
+    w = jnp.ones((N, S), jnp.float32) if sample_weight is None else \
+        jnp.broadcast_to(sample_weight, (N, S)).astype(jnp.float32)
+    tgt = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1).reshape(T)
+    wt = jnp.concatenate([w[:, 1:] * w[:, :-1], jnp.zeros((N, 1), jnp.float32)],
+                         axis=1).reshape(T)
+    c = block if T % block == 0 else T
+
+    def block_nll(xs):
+        x_c, t_c, w_c = xs
+        return cross_entropy(logits_of(x_c), t_c, w_c) * jnp.sum(w_c)  # the block's weighted sum
+
+    sums = lax.map(jax.checkpoint(block_nll),
+                   (xn.reshape(T // c, c, D), tgt.reshape(T // c, c), wt.reshape(T // c, c)))
+    return jnp.sum(sums) / jnp.maximum(jnp.sum(wt), 1e-12)
+
+
 # ---------------------------------------------------------------------------
-# Latent attention + shared-and-routed experts (models/kanana2.py)
+# Latent attention + shared-and-routed experts (models/kanana2.py); gated
+# short convolution + grouped-query attention (models/lfm2.py)
 # ---------------------------------------------------------------------------
 
 @scoped("norm")
@@ -344,16 +377,17 @@ def rope_swap(x: jnp.ndarray) -> jnp.ndarray:
 
 @scoped("rope")
 def rope_interleaved(x: jnp.ndarray, swapped: jnp.ndarray, pos: jnp.ndarray, theta: float,
-                     axis: int = 1) -> jnp.ndarray:
+                     axis: int = 1, full: Optional[int] = None) -> jnp.ndarray:
     """Rotary embedding on interleaved pairs ``(2i, 2i+1)`` of the last axis
     (``rope_interleave: true``): ``x cos + swapped sin`` with ``swapped`` the
-    pair swap of ``x`` (:func:`rope_swap`), ``theta_i = theta^(-2i/d)`` and
-    ``d`` the FULL rotary width: a client's sliced prefix of whole pairs keeps
-    the frequencies of the pairs it holds, and zeros (masked pairs) stay
-    zeros.  ``x`` ``[N, S, ..., d]`` and ``pos`` ``[S]``, the positions on
-    ``axis`` (2 for heads-first ``[N, H, S, d]``)."""
+    pair swap of ``x`` (:func:`rope_swap`), ``theta_i = theta^(-2i/full)`` and
+    ``full`` the GLOBAL model's rotary width (default: the last axis, which it
+    is in the masked full-width model): a client's sliced prefix of whole
+    pairs keeps the frequencies of the pairs it holds, and zeros (masked
+    pairs) stay zeros.  ``x`` ``[N, S, ..., d]`` and ``pos`` ``[S]``, the
+    positions on ``axis`` (2 for heads-first ``[N, H, S, d]``)."""
     d = x.shape[-1]
-    inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / d)
+    inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / (full or d))
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]           # [S, d]
     view = [1] * x.ndim
     view[axis], view[-1] = x.shape[axis], d
@@ -398,43 +432,92 @@ def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
     return blockwise_latent_attention(qn, qr, kn, kr, v, scale, block)
 
 
-def blockwise_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
-    """:func:`causal_latent_attention` in plain ``jnp`` (and the fused
-    kernels' oracle): query blocks of ``block`` rows against the keys up to
-    the block's end, each block under ``jax.checkpoint``: no ``[S, S]`` score
-    matrix of a whole row is ever held, in the forward or for the backward,
-    and key blocks above the diagonal are never computed."""
-    S = qn.shape[2]
+def _causal_blocks(scores, values, qs, ks, v, scale, block: int):
+    """The one blockwise causal softmax loop: query blocks of ``block`` rows
+    against the keys up to the block's end, each block under
+    ``jax.checkpoint``, so no ``[S, S]`` score matrix of a whole row is ever
+    held, in the forward or for the backward, and key blocks above the
+    diagonal are never computed.  Every operand has its positions on axis -2;
+    ``scores(*q_blocks, *k_blocks)`` gives ``[..., q, k]`` and ``values(p,
+    v_block)`` the block's result, positions on axis -2 again."""
+    S = v.shape[-2]
     outs = []
     for start in range(0, S, block):
         end = min(start + block, S)
 
-        def one(qn_b, qr_b, kn_b, kr_b, v_b, start=start, end=end):
-            s = jnp.einsum("nhqd,nhkd->nhqk", qn_b, kn_b) \
-                + jnp.einsum("nhqd,nkd->nhqk", qr_b, kr_b)
-            s = s.astype(jnp.float32) * scale
+        def one(qs_b, ks_b, v_b, start=start, end=end):
+            s = scores(*qs_b, *ks_b).astype(jnp.float32) * scale
             keep = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
             s = jnp.where(keep, s, -jnp.inf)
-            return jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, axis=-1), v_b)
+            return values(jax.nn.softmax(s, axis=-1), v_b)
 
-        outs.append(jax.checkpoint(one)(qn[:, :, start:end], qr[:, :, start:end],
-                                        kn[:, :, :end], kr[:, :end], v[:, :, :end]))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
+        outs.append(jax.checkpoint(one)(tuple(q[..., start:end, :] for q in qs),
+                                        tuple(k[..., :end, :] for k in ks), v[..., :end, :]))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-2)
+
+
+def blockwise_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
+    """:func:`causal_latent_attention` in plain ``jnp`` (and the fused
+    kernels' oracle), block by block (:func:`_causal_blocks`)."""
+    return _causal_blocks(
+        lambda qn_b, qr_b, kn_b, kr_b: jnp.einsum("nhqd,nhkd->nhqk", qn_b, kn_b)
+        + jnp.einsum("nhqd,nkd->nhqk", qr_b, kr_b),
+        lambda p, v_b: jnp.einsum("nhqk,nhkd->nhqd", p, v_b),
+        (qn, qr), (kn, kr), v, scale, block)
+
+
+@scoped("attn")
+def causal_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
+    """Causal softmax attention of grouped-query heads, the score / softmax /
+    value part only, heads first: ``q`` ``[N, H, S, d]``, ``k`` and ``v``
+    ``[N, Hkv, S, d]`` with ``H`` a multiple of ``Hkv``: query heads ``[g *
+    H/Hkv, (g + 1) * H/Hkv)`` read key/value head ``g``, which is never
+    repeated in memory.  Softmax in float32, float32 out ``[N, H, S, d]``.
+    The same block loop as latent attention's ``jnp`` form; no fused kernel
+    yet (``pallas_attention.tile_for`` wants 128-wide heads)."""
+    N, H, S, d = q.shape
+    kv = k.shape[1]
+    o = _causal_blocks(
+        lambda q_b, k_b: jnp.einsum("ngjqd,ngkd->ngjqk", q_b, k_b),
+        lambda p, v_b: jnp.einsum("ngjqk,ngkd->ngjqd", p, v_b),
+        (q.reshape(N, kv, H // kv, S, d),), (k,), v, scale, block)
+    return o.reshape(N, H, S, v.shape[-1])
+
+
+def short_conv(b, c, u, taps):
+    """The gated short convolution between a conv mixer's two projections
+    (``model_type: lfm2_moe``): ``z = b * u``; ``y[t] = sum_j taps[j] * z[t -
+    (L - 1) + j]``, depthwise, causal, zero to the left of a row's first
+    position; result ``c * y``.  ``b``, ``c``, ``u`` ``[N, S, D]``, ``taps``
+    ``[L, D]`` (tap ``j`` of channel ``d``: the published ``conv.weight[d, 0,
+    j]``).  Rows never mix and a channel reads only itself, so a masked
+    channel stays zero.  Shifted slices, not ``lax.conv``: elementwise work
+    that the chip's compiler fuses with the gates around it (no standalone
+    copy in the compiled mixer, tests/test_tpu_compile.py; 1.5 ms a step)."""
+    with scope("shortconv/gate"):
+        L, S = taps.shape[0], b.shape[1]
+        z = jnp.pad(b * u, ((0, 0), (L - 1, 0), (0, 0)))
+        y = sum(taps[j] * lax.slice_in_dim(z, j, j + S, axis=1) for j in range(L))
+        return c * y
 
 
 @scoped("moe/router")
-def moe_route(h, w_router, bias, top_k: int, scaling: float):
+def moe_route(h, w_router, bias, top_k: int, scaling: float, sum_eps: float = 0.0):
     """Sigmoid router over ALL experts (``scoring_func: sigmoid``,
     ``topk_method: noaux_tc`` with one group): ``s = sigmoid(h Wr)`` in
     float32 at "highest" matmul precision, ``sel = top_k(s + bias)`` (the
     selection bias is read here only; no gradient reaches it),
-    ``w = s[sel] / sum(s[sel]) * scaling``.  ``h`` ``[T, D]``.  Returns
-    ``(sel [T, k] int32, w [T, k])``."""
+    ``w = s[sel] / (sum(s[sel]) + sum_eps) * scaling`` (``lfm2_moe`` adds 1e-6
+    to the sum, ``deepseek_v3`` nothing).  ``h`` ``[T, D]``.  Returns ``(sel
+    [T, k] int32, w [T, k])``."""
     s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
                                precision=lax.Precision.HIGHEST))
     _, sel = lax.top_k(s + lax.stop_gradient(bias), top_k)
     w = jnp.take_along_axis(s, sel, axis=-1)
-    return sel.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    if sum_eps:
+        total = total + sum_eps
+    return sel.astype(jnp.int32), w / total * scaling
 
 
 #: rows of ONE expert that a step of the grouped loop computes

@@ -236,3 +236,16 @@ def test_the_expert_layers_and_the_chunk_carry_their_names():
     assert not any("round/chunk" in n for n in _op_names(plain, plain_args))
     with pytest.raises(ValueError, match="Not valid scope"):
         trace.scope("moe/expert")
+
+
+def test_the_mixers_scopes_are_a_vocabulary_of_their_own():
+    """`MIXER_SCOPES` (ISSUE 32) is disjoint from the two older tuples, which
+    the accepted benchmark mirrors name for name; `scope()` takes all three,
+    and a scope of two words names its parent (`shortconv/gate` lies inside
+    `shortconv`).  That the LFM2 round enters them: tests/test_lfm2.py."""
+    assert trace.MIXER_SCOPES == ("shortconv", "shortconv/gate", "gqa")
+    assert not set(trace.MIXER_SCOPES) & set(trace.SCOPES + trace.EXTRA_SCOPES)
+    for s in trace.SCOPES + trace.EXTRA_SCOPES + trace.MIXER_SCOPES:
+        with trace.scope(s):
+            pass
+    assert trace.SCOPE_VERSION >= 4  # bumped with the new names (the compile cache's key)

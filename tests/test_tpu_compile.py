@@ -208,3 +208,64 @@ def test_latent_attention_block_feeds_the_kernels_without_relayouts(one_chip, mo
     activations = [line for size, line in relayouts
                    if re.search(r"\[2,(32,2048|2048,32|2048),\d+\]", line)]
     assert len(activations) <= 1 and all("f32[2,32,2048,128]" in a for a in activations), listing
+
+
+@pytest.mark.parametrize("mixer", ["shortconv", "gqa"])
+def test_lfm2_mixers_compile_with_their_relayouts_listed(one_chip, monkeypatch, mixer):
+    """A layer's mixer of the LFM2 cell (``models.lfm2.conv_mixer`` /
+    ``gq_attention``) at the cell's shapes (2 rows x 2,048 positions, hidden
+    2,048; 32 query heads on 8 key/value heads of 64), under ``jax.checkpoint``
+    and ``jax.grad`` as a layer of the model runs it, for the described chip:
+    counts, not times.  The standalone copies of 4 MB or more a layer pass
+    (forward, rematerialised forward and backward together) are what the first
+    ``perf_opt`` on this cell starts from:
+
+    - ``shortconv``: none.  The gates and the taps fuse into elementwise
+      fusions around the four products; no ``[2, 2048, 2048]`` activation and
+      no weight is copied on its own.
+    - ``gqa``: 6 copies writing 75.5 MB: ONE activation, the gradient of the
+      grouped query ``f32[2, 8, 4, 2048, 64]`` on its way back from the block
+      loop's per-block pieces (``attn/add_any``, 33.6 MB), which a fused
+      kernel writing ``dq`` whole would not make; the rest the weights' own
+      (``q`` / ``o`` in bfloat16 for the rematerialised forward 2 x 8.4 MB,
+      the three gradient relayouts 16.8 + 2 x 4.2 MB).
+    """
+    from heterofl_tpu.models.lfm2 import conv_mixer, gq_attention
+    from heterofl_tpu.ops.layers import masked_rms_norm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, S, D, H, Hkv, hd = 2, 2048, 2048, 32, 8, 64
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    if mixer == "shortconv":
+        shapes = {**{f"conv.in.{m}.w": (D, D) for m in "bcu"},
+                  "conv.taps.w": (3, D), "conv.out.w": (D, D)}
+
+        def run(lp, h, scale, rate):
+            return conv_mixer(lp, h, sc=lambda x: x / rate)
+    else:
+        shapes = {"attn.q.w": (D, H * hd), "attn.k.w": (D, Hkv * hd), "attn.v.w": (D, Hkv * hd),
+                  "attn.q_norm.g": (hd,), "attn.k_norm.g": (hd,), "attn.o.w": (H * hd, D)}
+
+        def run(lp, h, scale, rate):
+            return gq_attention(
+                lp, h, heads=H, kv_heads=Hkv, head_dim=hd, theta=1e6, scale=scale,
+                sc=lambda x: x / rate,
+                head_norm=lambda x, g: masked_rms_norm(x, g, jnp.ones((hd,)), jnp.float32(hd)))
+
+    block = jax.checkpoint(run)
+    with no_persistent_cache():
+        text = jax.jit(jax.grad(lambda *a: jnp.sum(block(*a) ** 2), argnums=(0, 1))).lower(
+            {k: sds(*s) for k, s in shapes.items()}, sds(N, S, D), sds(), sds()).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in {"shortconv": ("shortconv/linear", "shortconv/shortconv/gate"),
+                  "gqa": ("gqa/linear", "gqa/norm", "rope", "attn")}[mixer]:
+        assert any(f"/{scope}/" in "/" + n for n in names), scope
+    relayouts = _standalone_relayouts(text)
+    listing = "\n".join(f"{size / 1e6:.1f} MB  {line[:160]}" for size, line in relayouts)
+    count, written = {"shortconv": (0, 0), "gqa": (6, 76e6)}[mixer]
+    assert len(relayouts) <= count and sum(s for s, _ in relayouts) <= written, listing
+    if mixer == "shortconv":  # no activation of the gated convolution on its own
+        assert not [line for _, line in relayouts if re.search(r"\[2,2048,2048\]", line)], listing
