@@ -103,11 +103,11 @@ SCOPE_VERSION = 4
 #: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
 KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
 
-#: The kernels ISSUE 29 added (ops/pallas_attention.py, under ``attn``): a
-#: tuple of its own for the reason :data:`EXTRA_SCOPES` is one (the accepted
+#: The kernels ISSUES 29 and 34 added (ops/pallas_attention.py, under ``attn``):
+#: a tuple of its own for the reason :data:`EXTRA_SCOPES` is one (the accepted
 #: benchmark mirrors :data:`KERNELS` name for name).  The accepted reader drops
 #: the component and files the kernel's time under ``attn``.
-EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd")
+EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_bwd")
 
 
 def _known(name: str) -> str:

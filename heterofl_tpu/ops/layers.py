@@ -473,8 +473,25 @@ def causal_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
     ``[N, Hkv, S, d]`` with ``H`` a multiple of ``Hkv``: query heads ``[g *
     H/Hkv, (g + 1) * H/Hkv)`` read key/value head ``g``, which is never
     repeated in memory.  Softmax in float32, float32 out ``[N, H, S, d]``.
-    The same block loop as latent attention's ``jnp`` form; no fused kernel
-    yet (``pallas_attention.tile_for`` wants 128-wide heads)."""
+
+    On a TPU, where the positions make whole tiles and the head dim is a
+    multiple of 64 (``pallas_attention.gq_tile_for``), the fused kernels
+    ``gq_attn_fwd`` / ``gq_attn_bwd``: a score tile lives in VMEM only.
+    Elsewhere (the CPU; a client's narrow slice at its own widths)
+    :func:`blockwise_gq_attention` in query blocks of ``block`` rows."""
+    if jax.default_backend() == "tpu":
+        from . import pallas_attention
+
+        tile = pallas_attention.gq_tile_for(q.shape[2], q.shape[-1])
+        if tile is not None:
+            return pallas_attention.fused_gq_attention(q, k, v, scale, block_q=tile, block_k=tile)
+    return blockwise_gq_attention(q, k, v, scale, block)
+
+
+def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
+    """:func:`causal_gq_attention` in plain ``jnp`` (and the fused kernels'
+    oracle): latent attention's block loop (:func:`_causal_blocks`) with the
+    query heads grouped by the key/value head they read."""
     N, H, S, d = q.shape
     kv = k.shape[1]
     o = _causal_blocks(

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for causal latent attention (the ``attn`` scope).
+"""Pallas TPU kernels for causal attention (``attn``): latent, then (below) grouped-query.
 
 The score / softmax / value part of ``ops.layers.causal_latent_attention``
 as one fused online-softmax ("flash") kernel with a hand-written backward,
@@ -265,3 +265,221 @@ def fused_latent_attention(qn, qr, kn, kr, v, scale, *, block_q: int, block_k: i
     keys."""
     qn, qr, kn, kr, v = (x.astype(jnp.float32) for x in (qn, qr, kn, kr, v))
     return _flash(qn * scale, qr * scale, kn, kr, v, block_q, block_k, interpret)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention (``ops.layers.causal_gq_attention``): kernels
+# ``gq_attn_fwd`` / ``gq_attn_bwd``.  Two things differ from the kernels above.
+#
+# POSITIONS ON THE LANES.  The operands are ``[N, H, d, S]``.  With a 64-wide
+# head dim on the lanes instead, half of every tile is empty, a ``[.., S,
+# 64]`` array is stored 128 wide in HBM, and the custom call's layout is
+# pushed back through the projections, the head norms and the rotary turn,
+# which then ran 3.9 ms a layer pass slower than in the layout the compiler
+# gives them when left alone, positions minor (PERF.md, PR 34).  So scores
+# are keys-first in both kernels, ``[tk, queries]``: the row statistics are
+# ``[1, queries]`` rows that broadcast along sublanes, the max and the sum
+# run over sublanes, and no tile is ever transposed.
+#
+# THE GROUP IN ONE GRID STEP.  A step takes the tiles of ALL the query heads
+# that read one key/value head, side by side on the lanes (``[G, d, tq] ->
+# [d, G * tq]``): a key/value tile is fetched once a group and never repeated
+# in HBM, and ``dk`` / ``dv`` of a key/value head are the products' own sum
+# over the group's columns.  One product a score against a key head a group,
+# where latent attention's is two against one shared rotary key: bodies of
+# their own, the helpers above shared.
+# ---------------------------------------------------------------------------
+
+_TN = (((0,), (0,)), ((), ()))  # a^T b
+
+
+def _side_by_side(ref):
+    """A group's tiles ``[G, d, t]`` as ``[d, G * t]``."""
+    return jnp.concatenate([ref[g] for g in range(ref.shape[0])], axis=1)
+
+
+def _group_causal(st, q0, k0, tq):
+    """:func:`_causal`, keys first, for a group's tiles side by side: column
+    ``c`` of the ``G * tq`` is position ``q0 + c % tq``."""
+    q = q0 + lax.rem(lax.broadcasted_iota(jnp.int32, st.shape, 1), tq)
+    k = k0 + lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    return jnp.where(q >= k, st, MASKED)
+
+
+def _gq_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, m_s, l_s, acc_s, *, tq: int, tk: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        q_s[...] = _side_by_side(q_ref)          # resident over the key tiles
+        m_s[...] = jnp.full_like(m_s, MASKED)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    def step(masked):
+        st = _dot(k_ref[...], q_s[...], _TN)                          # [tk, G * tq]
+        if masked:
+            st = _group_causal(st, i * tq, j * tk, tq)
+        m_prev = m_s[...]                                             # [1, G * tq]
+        m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_next)
+        alpha = jnp.exp(m_prev - m_next)
+        l_s[...] = alpha * l_s[...] + jnp.sum(pt, axis=0, keepdims=True)
+        m_s[...] = m_next
+        v = v_ref[...]
+        acc_s[...] = alpha * acc_s[...] + _dot(v, pt.astype(v.dtype), _NN)  # [d, G * tq]
+
+    _when_needed(i, j, tq, tk, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l = l_s[...]
+        o = acc_s[...] / l
+        for g in range(o_ref.shape[0]):
+            o_ref[g] = o[:, g * tq:(g + 1) * tq]
+        lse_ref[...] = m_s[...] + jnp.log(l)
+
+
+def _gq_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *,
+                   tq: int, tk: int):
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(jnp.logical_and(j == 0, i == 0))
+    def _():  # the group's whole sequence, resident over (j, i)
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    @pl.when(i == 0)
+    def _():  # a key tile's, resident over i
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    def step(masked):
+        q, do, k = _side_by_side(q_ref), _side_by_side(do_ref), k_ref[...]
+        st = _dot(k, q, _TN)                                          # [tk, G * tq]
+        if masked:
+            st = _group_causal(st, i * tq, j * tk, tq)
+        pt = jnp.exp(st - lse_ref[...])                               # lse [1, G * tq]
+        dv_ref[...] += _dot(do, pt.astype(do.dtype), _NT)             # [d, tk]
+        dst = (pt * (_dot(v_ref[...], do, _TN) - delta_ref[...])).astype(q.dtype)
+        dk_ref[...] += _dot(q, dst, _NT)
+        dq = _dot(k, dst, _NN)                                        # [d, G * tq]
+        cols = pl.ds(pl.multiple_of(i * tq, tq), tq)
+        for g in range(dq_ref.shape[0]):
+            dq_ref[g, :, cols] += dq[:, g * tq:(g + 1) * tq]
+
+    _when_needed(i, j, tq, tk, step)
+
+
+def _lane_tile(heads, d, t, tile_of):
+    """The ``[d, t]`` tiles of ``heads`` adjacent heads (None: of one, the
+    head axis squeezed) in a ``[N, H, d, S]`` operand; the grid's second
+    coordinate counts blocks of ``heads``, ``tile_of(a, b)`` is the tile's index
+    along ``S`` at its last two."""
+    return pl.BlockSpec((None, heads, d, t), lambda n, g, a, b: (n, g, 0, tile_of(a, b)))
+
+
+def _row_stat(rows, tile_of):
+    """A query tile's row statistic (log-sum-exp, ``delta``) of a group, its
+    heads side by side: ``[1, G * tq]`` of ``[N, Hkv, S // tq, 1, G * tq]``,
+    what the forward writes and the backward broadcasts along keys."""
+    return pl.BlockSpec((None, None, None, 1, rows), lambda n, g, a, b: (n, g, tile_of(a, b), 0, 0))
+
+
+def _call_gq_fwd(q, k, v, tq, tk, interpret):
+    N, H, d, S = q.shape
+    G = H // k.shape[1]
+
+    def query(i, j):
+        return i
+
+    def key(i, j):  # a tile above the diagonal is never fetched
+        return jnp.minimum(j, (i * tq + tq - 1) // tk)
+
+    return pl.pallas_call(
+        partial(_gq_fwd_kernel, tq=tq, tk=tk),
+        grid=(N, H // G, S // tq, S // tk),
+        in_specs=[_lane_tile(G, d, tq, query), _lane_tile(None, d, tk, key),
+                  _lane_tile(None, d, tk, key)],
+        out_specs=[_lane_tile(G, d, tq, query), _row_stat(G * tq, query)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((N, H // G, S // tq, 1, G * tq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, G * tq), q.dtype)]
+        + [pltpu.VMEM((1, G * tq), jnp.float32)] * 2 + [pltpu.VMEM((d, G * tq), jnp.float32)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="gq_attn_fwd",
+    )(q, k, v)
+
+
+def _call_gq_bwd(q, k, v, do, lse, delta, tq, tk, interpret):
+    N, H, d, S = q.shape
+    G = H // k.shape[1]
+
+    def key(j, i):
+        return j
+
+    def query(j, i):  # a tile above the diagonal is never fetched
+        return jnp.maximum(i, (j * tk) // tq)
+
+    f32 = jnp.float32
+    return pl.pallas_call(
+        partial(_gq_bwd_kernel, tq=tq, tk=tk),
+        grid=(N, H // G, S // tk, S // tq),
+        in_specs=[_lane_tile(G, d, tq, query), _lane_tile(None, d, tk, key),
+                  _lane_tile(None, d, tk, key), _lane_tile(G, d, tq, query),
+                  _row_stat(G * tq, query), _row_stat(G * tq, query)],
+        out_specs=[pl.BlockSpec((None, G, d, S), lambda n, g, j, i: (n, g, 0, 0)),
+                   _lane_tile(None, d, tk, key), _lane_tile(None, d, tk, key)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32), jax.ShapeDtypeStruct(k.shape, f32),
+                   jax.ShapeDtypeStruct(v.shape, f32)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="gq_attn_bwd",
+    )(q, k, v, do, lse, delta)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gq_flash(q, k, v, tq, tk, interpret):
+    """Positions on the lanes: ``q`` and the result ``[N, H, d, S]``, ``k`` and
+    ``v`` ``[N, Hkv, d, S]``; ``q`` already scaled.  Float32 in, out and in
+    every gradient."""
+    return _gq_flash_fwd(q, k, v, tq, tk, interpret)[0]
+
+
+def _gq_flash_fwd(q, k, v, tq, tk, interpret):
+    ops = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    o, lse = _call_gq_fwd(*ops, tq, tk, interpret)
+    return o, (ops, o, lse)
+
+
+def _gq_flash_bwd(tq, tk, interpret, res, do):
+    (q, k, v), o, lse = res
+    N, H, _, S = q.shape
+    kv = k.shape[1]
+    delta = jnp.sum(o * do, axis=2).reshape(N, kv, H // kv, S // tq, tq)
+    delta = jnp.swapaxes(delta, 2, 3).reshape(lse.shape)  # the log-sum-exp's layout (_row_stat)
+    return _call_gq_bwd(q, k, v, do.astype(jnp.bfloat16), lse, delta, tq, tk, interpret)
+
+
+_gq_flash.defvjp(_gq_flash_fwd, _gq_flash_bwd)
+
+
+def gq_tile_for(S: int, d: int):
+    """:func:`tile_for` for grouped-query heads: whole tiles of positions and
+    head dims of whole half-lanes (64: the block is the array's whole last
+    dim); a client's narrow slice at its own widths takes none."""
+    if d % (LANES // 2):
+        return None
+    return next((t for t in TILES if S % t == 0), None)
+
+
+def fused_gq_attention(q, k, v, scale, *, block_q: int, block_k: int, interpret: bool = False):
+    """``ops.layers.causal_gq_attention`` through the kernels above, its
+    operands and result in its layouts (heads first, ``[N, H, S, d]``; query
+    heads ``[g * H/Hkv, (g + 1) * H/Hkv)`` read key/value head ``g``), float32
+    out; tiles of ``block_q`` queries a head by ``block_k`` keys.  The
+    kernels' own layout has the positions minor: the swaps here are the
+    compiler's to fold into whoever writes ``q``, ``k``, ``v`` and reads the
+    result."""
+    qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 2, 3) for x in (q, k, v))
+    return jnp.swapaxes(_gq_flash(qt * scale, kt, vt, block_q, block_k, interpret), 2, 3)

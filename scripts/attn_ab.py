@@ -34,6 +34,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from heterofl_tpu.models.kanana2 import latent_attention, latent_attention_shapes  # noqa: E402
+from heterofl_tpu.models.lfm2 import gq_attention  # noqa: E402
 from heterofl_tpu.ops import layers as L  # noqa: E402
 from heterofl_tpu.ops import pallas_attention as PA  # noqa: E402
 
@@ -41,6 +42,10 @@ N, S, H, DN, DR, DV = 2, 2048, 32, 128, 64, 128
 SHAPES = [(N, H, S, DN), (N, H, S, DR), (N, H, S, DN), (N, S, DR), (N, H, S, DV), (N, H, S, DV)]
 D, R, THETA = 2048, 512, 1e6  # the block: hidden size, latent width, rope_theta
 LEAVES = latent_attention_shapes(D, H, DN, DR, DV, R)
+HKV, HD = 8, 64  # the LFM2 cell's grouped-query heads
+GQ_SHAPES = [(N, H, S, HD), (N, HKV, S, HD), (N, HKV, S, HD), (N, H, S, HD)]
+GQ_LEAVES = {"attn.q.w": (D, H * HD), "attn.k.w": (D, HKV * HD), "attn.v.w": (D, HKV * HD),
+             "attn.q_norm.g": (HD,), "attn.k_norm.g": (HD,), "attn.o.w": (H * HD, D)}
 
 
 def fused(tile):
@@ -93,20 +98,43 @@ def block_swapaxes(lp, h, scale):
     return L.linear(jnp.swapaxes(o, 1, 2).reshape(N, S, H * DV), lp["attn.o.w"])
 
 
+def gq_fused(tq, tk=None):
+    return lambda *a: PA.fused_gq_attention(*a, block_q=tq, block_k=tk or tq)
+
+
+def gq_block(tile):
+    """`models.lfm2.gq_attention` with the kernels at ``tile``, or with the
+    block loop (None): the script answers the one question the layer asks."""
+    def f(lp, h, scale):
+        asked, PA.gq_tile_for = PA.gq_tile_for, lambda *a: tile
+        try:
+            return gq_attention(
+                lp, h, heads=H, kv_heads=HKV, head_dim=HD, theta=THETA, scale=scale,
+                sc=lambda x: x,
+                head_norm=lambda x, g: L.masked_rms_norm(x, g, jnp.ones((HD,)), jnp.float32(HD)))
+        finally:
+            PA.gq_tile_for = asked
+    return f
+
+
 FORMS = {"jnp": L.blockwise_latent_attention, "fused256": fused(256), "fused512": fused(512),
          "fused1024": fused(1024), "splash512": splash(512, False),
          "splash1024f": splash(1024, True)}
 BLOCKS = {"block_swapaxes": block_swapaxes, "block_heads_first": block_heads_first}
-
-
+GQ_FORMS = {"gq_jnp": L.blockwise_gq_attention, "gq_fused512": gq_fused(512),
+            "gq_fused256": gq_fused(256), "gq_fused128": gq_fused(128),
+            "gq_fused256x512": gq_fused(256, 512), "gq_fused512x256": gq_fused(512, 256)}
+GQ_BLOCKS = {"gq_block_jnp": gq_block(None), "gq_block512": gq_block(512),
+             "gq_block256": gq_block(256), "gq_block128": gq_block(128)}
 def fwd(f):
-    return lambda qn, qr, kn, kr, v, w, scale: f(qn, qr, kn, kr, v, scale)
+    return lambda *a: f(*a[:-2], a[-1])  # operands, probe, scale
 
 
 def fwd_bwd(f):
-    def g(qn, qr, kn, kr, v, w, scale):
-        loss, grads = jax.value_and_grad(lambda *a: jnp.sum(f(*a, scale) * w),
-                                         argnums=(0, 1, 2, 3, 4))(qn, qr, kn, kr, v)
+    def g(*a):
+        *ops, w, scale = a
+        loss, grads = jax.value_and_grad(lambda *o: jnp.sum(f(*o, scale) * w),
+                                         argnums=tuple(range(len(ops))))(*ops)
         return (loss,) + grads
     return g
 
@@ -144,10 +172,36 @@ def device_ops(fn, args, calls=3):
     return sum(ms for ms, _ in rows), rows
 
 
+#: a kind of row: its forms (the first is the one the others' `gap` is taken from), its
+#: (forward, forward + backward) wrappers, its operands' shapes or its block's leaves, and
+#: the head dims of its softmax scale
+KINDS = [(FORMS, (fwd, fwd_bwd), SHAPES, DN + DR),
+         (BLOCKS, (block_fwd, block_fwd_bwd), LEAVES, DN + DR),
+         (GQ_FORMS, (fwd, fwd_bwd), GQ_SHAPES, HD),
+         (GQ_BLOCKS, (block_fwd, block_fwd_bwd), GQ_LEAVES, HD)]
+
+
+def _asked(names, make, leaf, gain, scale):
+    """(the names asked of a kind, its forms, its wrappers, its arguments) for
+    every kind with a name asked: ``make(shape)`` makes an operand,
+    ``leaf(shape)`` a weight, ``gain(shape)`` a norm's gain and
+    ``scale(head_dims)`` the softmax scale (shapes alone under `aot`)."""
+    for forms, wraps, shapes, width in KINDS:
+        asked = [n for n in names if n in forms]
+        if not asked:
+            continue
+        if isinstance(shapes, dict):  # a block: its leaves, the input, the probe
+            args = [{n: (gain if n.endswith(".g") else leaf)(s) for n, s in shapes.items()},
+                    make((N, S, D)), make((N, S, D))]
+        else:
+            args = [make(s) for s in shapes]
+        yield asked, forms, wraps, args + [scale(width)]
+
+
 def main(argv):
     aot, ops = argv[:1] == ["aot"], argv[:1] == ["ops"]
     names = argv[-1].split(",") if argv and argv[-1] not in ("aot", "ops") \
-        else list(FORMS) + list(BLOCKS)
+        else [name for forms, *_ in KINDS for name in forms]
     if aot:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -158,61 +212,55 @@ def main(argv):
         def sds(shape):
             return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
 
-        jax.default_backend = lambda: "tpu"  # the one question causal_latent_attention asks
-        avals = [sds(s) for s in SHAPES + [()]]
-        block_avals = [{k: sds(s) for k, s in LEAVES.items()}, sds((N, S, D)), sds((N, S, D)),
-                       sds(())]
-        for name in names:
-            t = time.time()
-            fn, avals_ = (block_fwd_bwd(BLOCKS[name]), block_avals) if name in BLOCKS \
-                else (fwd_bwd(FORMS[name]), avals)
-            c = jax.jit(fn).lower(*avals_).compile()
-            print(f"{name}: compiled in {time.time() - t:.1f}s, temporaries "
-                  f"{c.memory_analysis().temp_size_in_bytes >> 20} MB, "
-                  f"{c.as_text().count('tpu_custom_call')} custom calls", flush=True)
+        jax.default_backend = lambda: "tpu"  # the one question the attention functions ask
+        for asked, forms, wraps, avals in _asked(names, sds, sds, sds, lambda width: sds(())):
+            for name in asked:
+                t = time.time()
+                c = jax.jit(wraps[1](forms[name])).lower(*avals).compile()
+                print(f"{name}: compiled in {time.time() - t:.1f}s, temporaries "
+                      f"{c.memory_analysis().temp_size_in_bytes >> 20} MB, "
+                      f"{c.as_text().count('tpu_custom_call')} custom calls", flush=True)
         return
     print(jax.devices(), flush=True)
     if jax.default_backend() != "tpu":
         raise SystemExit("attn_ab times the chip; without one, `aot` compiles for it")
-    scale = jnp.float32(1.0 / np.sqrt(DN + DR))
-    args = [jax.random.normal(k, s, jnp.float32)
-            for k, s in zip(jax.random.split(jax.random.key(29), 6), SHAPES)] + [scale]
-    keys = jax.random.split(jax.random.key(31), len(LEAVES) + 2)
-    lp = {n: jax.random.normal(k, s, jnp.float32) / np.sqrt(s[0])
-          for (n, s), k in zip(LEAVES.items(), keys)}
-    lp["attn.kv_norm.g"] = jnp.ones((R,), jnp.float32)
-    block_args = [lp] + [jax.random.normal(k, (N, S, D), jnp.float32) for k in keys[-2:]] + [scale]
-    out, refs = {}, {}
-    for name in names:
-        rec = {}
-        block = name in BLOCKS
-        form, args_, wraps = (BLOCKS[name], block_args, (block_fwd, block_fwd_bwd)) if block \
-            else (FORMS[name], args, (fwd, fwd_bwd))
-        for kind, wrap in zip(("fwd", "fwdbwd"), wraps):
-            fn = jax.jit(wrap(form))
-            r = jax.block_until_ready(fn(*args_))
-            best = []
-            for _ in range(3):
-                t = time.time()
-                for _ in range(5):
-                    r = fn(*args_)
-                jax.block_until_ready(r)
-                best.append((time.time() - t) / 5 * 1e3)
-            rec[kind + "_ms"] = round(min(best), 3)
-        if ops:  # of the forward + backward, the loop's last ``fn``
-            total, rows = device_ops(fn, args_)
-            rec["device_ms"], rec["device_ops"] = round(total, 3), rows
-            print(f"{name}: forward + backward {total:.3f} device ms a call; largest operations:")
-            for ms_, op in rows[:14]:
-                print(f"  {ms_:8.3f}  {op}")
-        r = [np.asarray(x) for x in r]
-        if name in ("jnp", "block_swapaxes"):
-            refs[block] = r
-        elif block in refs:
-            rec["gap"] = [float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-                          for a, b in zip(r, refs[block])]
-        out[name] = rec
-        print(name, json.dumps(rec), flush=True)
+    keys = iter(jax.random.split(jax.random.key(29), 64))
+
+    def normal(shape):
+        return jax.random.normal(next(keys), shape, jnp.float32)
+
+    out = {}
+    for asked, forms, wraps, args_ in _asked(
+            names, normal, lambda s: normal(s) / np.sqrt(s[0]), lambda s: jnp.ones(s, jnp.float32),
+            lambda width: jnp.float32(1.0 / np.sqrt(width))):
+        ref = None
+        for name in asked:
+            rec = {}
+            for kind, wrap in zip(("fwd", "fwdbwd"), wraps):
+                fn = jax.jit(wrap(forms[name]))
+                r = jax.block_until_ready(fn(*args_))
+                best = []
+                for _ in range(3):
+                    t = time.time()
+                    for _ in range(5):
+                        r = fn(*args_)
+                    jax.block_until_ready(r)
+                    best.append((time.time() - t) / 5 * 1e3)
+                rec[kind + "_ms"] = round(min(best), 3)
+            if ops:  # of the forward + backward, the loop's last ``fn``
+                total, rows = device_ops(fn, args_)
+                rec["device_ms"], rec["device_ops"] = round(total, 3), rows
+                print(f"{name}: forward + backward {total:.3f} device ms a call; largest operations:")
+                for ms_, op in rows[:14]:
+                    print(f"  {ms_:8.3f}  {op}")
+            r = [np.asarray(x) for x in r]
+            if name == next(iter(forms)):
+                ref = r
+            elif ref is not None:
+                rec["gap"] = [float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+                              for a, b in zip(r, ref)]
+            out[name] = rec
+            print(name, json.dumps(rec), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/attn_ab.json", "w") as f:
         json.dump(out, f, indent=1)

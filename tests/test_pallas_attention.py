@@ -1,5 +1,6 @@
-"""The fused causal latent attention (ops/pallas_attention.py) against its
-oracle, the ``jnp`` form ``ops.layers.blockwise_latent_attention``:
+"""The fused causal attentions (ops/pallas_attention.py) against their
+oracles, the ``jnp`` forms ``ops.layers.blockwise_latent_attention`` and
+``blockwise_gq_attention``:
 on the CPU the kernels run in interpret mode, which says that the tiling,
 the skipped and the masked tiles, the online softmax and the hand-written
 backward are right; what Mosaic accepts is tests/test_tpu_compile.py's.
@@ -22,20 +23,44 @@ from heterofl_tpu.ops import pallas_attention as PA
 SCALE = 0.125
 
 
-def _operands(key, N=1, S=256, H=2, dn=128, dr=64, dv=128, active=None):
+def _normal(key, shapes, keep=None):
+    """bfloat16-representable operands of ``shapes``; with ``keep`` (one count
+    a shape) the head dims past the count are zero, as a narrow client's are
+    under the masked engine."""
+    ops = [jax.random.normal(k, s).astype(jnp.bfloat16).astype(jnp.float32)
+           for k, s in zip(jax.random.split(key, len(shapes)), shapes)]
+    if keep is not None:
+        ops = [jnp.where(jnp.arange(x.shape[-1]) < c, x, 0.0) for x, c in zip(ops, keep)]
+    return ops[:-1], ops[-1]
+
+
+def _latent_operands(key, N=1, S=256, H=2, dn=128, dr=64, dv=128, active=None):
     """qn, qr, kn, kr, v (heads first, the one rotary key ``[N, S, dr]``) and a
-    probe of the output's shape; with ``active = (an, ar, av)`` the head dims
-    past those counts are zero, as a narrow client's are under the masked
-    engine."""
+    probe of the output's shape; ``active = (an, ar, av)`` active head dims."""
     shapes = [(N, H, S, dn), (N, H, S, dr), (N, H, S, dn), (N, S, dr), (N, H, S, dv),
               (N, H, S, dv)]
-    ops = [jax.random.normal(k, s).astype(jnp.bfloat16).astype(jnp.float32)
-           for k, s in zip(jax.random.split(key, 6), shapes)]
-    if active is not None:
-        an, ar, av = active
-        keep = [an, ar, an, ar, av, av]
-        ops = [jnp.where(jnp.arange(x.shape[-1]) < c, x, 0.0) for x, c in zip(ops, keep)]
-    return ops[:5], ops[5]
+    an, ar, av = active or (None,) * 3
+    return _normal(key, shapes, active and [an, ar, an, ar, av, av])
+
+
+def _gq_operands(key, N=1, S=256, H=4, Hkv=2, d=64, active=None):
+    """q, k, v (heads first, ``Hkv`` key/value heads for ``H`` query heads) and
+    a probe of the output's shape; ``active`` active head dims."""
+    shapes = [(N, H, S, d), (N, Hkv, S, d), (N, Hkv, S, d), (N, H, S, d)]
+    return _normal(key, shapes, active and [active] * 4)
+
+
+#: family -> (operands, the kernels in interpret mode at a tile, the oracle)
+FAMILIES = {
+    "latent": (_latent_operands,
+               lambda bq, bk: lambda *a: PA.fused_latent_attention(
+                   *a, block_q=bq, block_k=bk, interpret=True),
+               lambda *a: L.blockwise_latent_attention(*a, block=64)),
+    "gq": (_gq_operands,
+           lambda bq, bk: lambda *a: PA.fused_gq_attention(
+               *a, block_q=bq, block_k=bk, interpret=True),
+           lambda *a: L.blockwise_gq_attention(*a, block=64)),
+}
 
 
 def _out_and_grads(attention, ops, probe, scale):
@@ -43,17 +68,8 @@ def _out_and_grads(attention, ops, probe, scale):
         o = attention(*a, scale)
         return jnp.sum(o * probe), o
 
-    grads, o = jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*ops)
+    grads, o = jax.grad(loss, argnums=tuple(range(len(ops))), has_aux=True)(*ops)
     return (o,) + grads
-
-
-def _fused(block_q, block_k):
-    return lambda *a: PA.fused_latent_attention(*a, block_q=block_q, block_k=block_k,
-                                                interpret=True)
-
-
-def _oracle(*a):
-    return L.blockwise_latent_attention(*a, block=64)
 
 
 def _close(got, want):
@@ -62,28 +78,39 @@ def _close(got, want):
         np.testing.assert_allclose(g, w, rtol=0, atol=1e-2 * float(jnp.abs(w).max()))
 
 
-@pytest.mark.parametrize("case", [
-    "tiles-128x128", "tiles-256x128", "tiles-128x256", "tiles-256x256", "tiles-384x128",
-    "narrow-client", "vmap-clients", "rotary-key-heads"])
+_SHARED = ["tiles-128x128", "tiles-256x128", "tiles-128x256", "tiles-256x256", "tiles-384x128",
+           "narrow-client", "vmap-clients"]
+
+
+@pytest.mark.parametrize("case", _SHARED + ["rotary-key-heads"] + ["gq-" + c for c in _SHARED]
+                         + ["gq-group-1", "gq-group-4", "gq-group-8"])
 def test_fused_attention_is_the_blockwise_attention(case):
-    """Output and the gradients of all five operands: at several query and
-    key tiles (the diagonal crosses a tile, lies on its corner, or a query
-    tile spans three key tiles); with zero-suffix head dims; under ``vmap``
-    over clients with a per-client scale; and the shared rotary key's
-    gradient, the sum over heads of what each head's own copy would get."""
+    """Output and the gradients of every operand, latent attention's kernels
+    and (``gq-``) grouped-query attention's: at several query and key tiles
+    (the diagonal crosses a tile, lies on its corner, or a query tile spans
+    three key tiles); with zero-suffix head dims, which stay zeros; under
+    ``vmap`` over clients with a per-client scale; the shared rotary key's
+    gradient, the sum over heads of what each head's own copy would get; and
+    a key/value head's gradients, the sum over its group of 1, 4 or 8 query
+    heads of what a copy a query head would get."""
+    family, case = ("gq", case[len("gq-"):]) if case.startswith("gq-") else ("latent", case)
+    operands, fused, oracle = FAMILIES[family]
     if case.startswith("tiles-"):
         bq, bk = (int(t) for t in case[len("tiles-"):].split("x"))
-        ops, probe = _operands(jax.random.key(1), S=768 if bq == 384 else 256)
-        _close(_out_and_grads(_fused(bq, bk), ops, probe, SCALE),
-               _out_and_grads(_oracle, ops, probe, SCALE))
+        ops, probe = operands(jax.random.key(1), S=768 if bq == 384 else 256)
+        _close(_out_and_grads(fused(bq, bk), ops, probe, SCALE),
+               _out_and_grads(oracle, ops, probe, SCALE))
     elif case == "narrow-client":
-        ops, probe = _operands(jax.random.key(2), active=(8, 4, 8))
-        got = _out_and_grads(_fused(128, 128), ops, probe, 0.25)
-        _close(got, _out_and_grads(_oracle, ops, probe, 0.25))
-        o, dqn, dqr, dkn, dkr, dv = got
-        assert not np.any(o[..., 8:]) and not np.any(dkn[..., 8:]) and not np.any(dkr[..., 4:])
+        ops, probe = operands(jax.random.key(2), active=(8, 4, 8) if family == "latent" else 8)
+        got = _out_and_grads(fused(128, 128), ops, probe, 0.25)
+        _close(got, _out_and_grads(oracle, ops, probe, 0.25))
+        if family == "latent":
+            o, dqn, dqr, dkn, dkr, dv = got
+            assert not np.any(o[..., 8:]) and not np.any(dkn[..., 8:]) and not np.any(dkr[..., 4:])
+        else:
+            assert not any(np.any(x[..., 8:]) for x in got)  # o, dq, dk, dv
     elif case == "vmap-clients":
-        clients = [_operands(k, S=128) for k in jax.random.split(jax.random.key(3), 3)]
+        clients = [operands(k, S=128) for k in jax.random.split(jax.random.key(3), 3)]
         ops = [jnp.stack(x) for x in zip(*(c[0] for c in clients))]
         probe = jnp.stack([c[1] for c in clients])
         scales = jnp.asarray([0.125, 0.25, 0.0625])
@@ -91,10 +118,10 @@ def test_fused_attention_is_the_blockwise_attention(case):
         def over_clients(attention):
             return jax.vmap(lambda o, p, s: _out_and_grads(attention, o, p, s))(ops, probe, scales)
 
-        _close(over_clients(_fused(128, 128)), over_clients(_oracle))
-    else:
+        _close(over_clients(fused(128, 128)), over_clients(oracle))
+    elif case == "rotary-key-heads":
         H = 4
-        ops, probe = _operands(jax.random.key(4), S=128, H=H)
+        ops, probe = operands(jax.random.key(4), S=128, H=H)
         qn, qr, kn, kr, v = ops
 
         def own_copy_a_head(kr_heads):  # plain attention, one rotary key a head
@@ -104,33 +131,63 @@ def test_fused_attention_is_the_blockwise_attention(case):
             return jnp.sum(jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, -1), v) * probe)
 
         a_head = jax.grad(own_copy_a_head)(jnp.repeat(kr[:, None], H, axis=1))
-        dkr = _out_and_grads(_fused(128, 128), ops, probe, SCALE)[4]
+        dkr = _out_and_grads(fused(128, 128), ops, probe, SCALE)[4]
         _close([dkr], [jnp.sum(a_head, axis=1)])
+    else:
+        G, Hkv = int(case[len("group-"):]), 2
+        (q, k, v), probe = operands(jax.random.key(5), N=2, S=128, H=G * Hkv, Hkv=Hkv)
+
+        def own_copy_a_head(q, k_heads, v_heads):  # plain attention, every head its own k, v
+            s = jnp.einsum("nhqd,nhkd->nhqk", q, k_heads) * SCALE
+            s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+            o = jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, -1), v_heads)
+            return jnp.sum(o * probe), o
+
+        (dq, dk, dv), o = jax.grad(own_copy_a_head, argnums=(0, 1, 2), has_aux=True)(
+            q, jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1))
+        over_group = [x.reshape(2, Hkv, G, 128, 64).sum(axis=2) for x in (dk, dv)]
+        _close(_out_and_grads(fused(128, 128), (q, k, v), probe, SCALE), [o, dq] + over_group)
 
 
-def _calls(S, dn, dr, dv, backend, monkeypatch):
-    """Names of the ``pallas_call``s in ``causal_latent_attention``'s program
-    at these shapes when jax reports ``backend``."""
+def _calls(family, S, dims, backend, monkeypatch):
+    """Names of the ``pallas_call``s in the family's attention function's
+    program (``causal_latent_attention`` at head dims ``dn, dr, dv``,
+    ``causal_gq_attention`` at ``d``) when jax reports ``backend``."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    shapes = [(1, 2, S, dn), (1, 2, S, dr), (1, 2, S, dn), (1, S, dr), (1, 2, S, dv)]
-    jaxpr = jax.make_jaxpr(lambda *a: L.causal_latent_attention(*a, 0.1))(
+    if family == "latent":
+        dn, dr, dv = dims
+        attention = L.causal_latent_attention
+        shapes = [(1, 2, S, dn), (1, 2, S, dr), (1, 2, S, dn), (1, S, dr), (1, 2, S, dv)]
+    else:
+        attention = L.causal_gq_attention
+        shapes = [(1, 8, S, dims), (1, 2, S, dims), (1, 2, S, dims)]
+    jaxpr = jax.make_jaxpr(lambda *a: attention(*a, 0.1))(
         *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
     text = str(jaxpr)
-    assert text.count("pallas_call") == text.count("latent_attn_")
-    return re.findall(r"latent_attn_\w+", text)
+    assert text.count("pallas_call") == text.count("_attn_")
+    return re.findall(r"\w+_attn_\w+", text)
 
 
-@pytest.mark.parametrize("S, dn, dr, dv, backend, fused", [
-    (512, 128, 64, 128, "tpu", True),     # the benchmark cell's head dims
-    (384, 256, 128, 128, "tpu", True),    # 128-position tiles
-    (512, 128, 64, 128, "cpu", False),    # the CPU takes the jnp form
-    (500, 128, 64, 128, "tpu", False),    # positions that make no whole tile
-    (512, 8, 4, 8, "tpu", False),         # a rate-1/16 client's own widths
-    (512, 128, 64, 64, "tpu", False),     # value dims that do not fill the lanes
-], ids=["cell-dims", "tile-128", "cpu", "ragged-positions", "narrow-widths", "half-lane-values"])
-def test_which_form_runs_is_decided_by_backend_and_shapes(S, dn, dr, dv, backend, fused,
+@pytest.mark.parametrize("family, S, dims, backend, fused", [
+    ("latent", 512, (128, 64, 128), "tpu", True),     # the Kanana-2 cell's head dims
+    ("latent", 384, (256, 128, 128), "tpu", True),    # 128-position tiles
+    ("latent", 512, (128, 64, 128), "cpu", False),    # the CPU takes the jnp form
+    ("latent", 500, (128, 64, 128), "tpu", False),    # positions that make no whole tile
+    ("latent", 512, (8, 4, 8), "tpu", False),         # a rate-1/16 client's own widths
+    ("latent", 512, (128, 64, 64), "tpu", False),     # value dims that do not fill the lanes
+    ("gq", 2048, 64, "tpu", True),                    # the LFM2 cell's head dim and positions
+    ("gq", 384, 128, "tpu", True),                    # 128-position tiles, whole-lane heads
+    ("gq", 2048, 64, "cpu", False),
+    ("gq", 500, 64, "tpu", False),
+    ("gq", 2048, 4, "tpu", False),                    # a rate-1/16 client's own width
+    ("gq", 2048, 32, "tpu", False),                   # a rate-1/2 client's
+], ids=["cell-dims", "tile-128", "cpu", "ragged-positions", "narrow-widths", "half-lane-values",
+        "gq-cell-dims", "gq-tile-128", "gq-cpu", "gq-ragged-positions", "gq-narrow-widths",
+        "gq-half-width"])
+def test_which_form_runs_is_decided_by_backend_and_shapes(family, S, dims, backend, fused,
                                                           monkeypatch):
-    assert _calls(S, dn, dr, dv, backend, monkeypatch) == (["latent_attn_fwd"] if fused else [])
+    kernel = {"latent": "latent_attn_fwd", "gq": "gq_attn_fwd"}[family]
+    assert _calls(family, S, dims, backend, monkeypatch) == ([kernel] if fused else [])
 
 
 @pytest.mark.parametrize("rate, compute_dtype", [(1.0, None), (0.25, None), (0.5, jnp.bfloat16)],

@@ -181,7 +181,8 @@ def test_the_vocabulary_is_closed():
 @pytest.mark.parametrize("kernel, module", [
     ("masked_bn_fwd", "pallas_norm"),
     ("masked_bn_bwd", "pallas_norm"), ("int8_pack", "quant"),
-    ("latent_attn_fwd", "pallas_attention"), ("latent_attn_bwd", "pallas_attention")])
+    ("latent_attn_fwd", "pallas_attention"), ("latent_attn_bwd", "pallas_attention"),
+    ("gq_attn_fwd", "pallas_attention"), ("gq_attn_bwd", "pallas_attention")])
 def test_every_pallas_call_is_named(kernel, module):
     import importlib
     import inspect

@@ -106,39 +106,51 @@ def test_pallas_norm_compiles(one_chip, n, h, c):
              sds(SLOTS, c), sds(SLOTS, n), kernels=bn)
 
 
-@pytest.mark.parametrize("vmapped", [False, True], ids=["cell", "vmap10"])
-def test_latent_attention_kernels_compile_under_attn(one_chip, vmapped, monkeypatch):
-    """The fused causal latent attention, forward and backward, at the
-    Kanana-2 cell's shapes (2 rows x 2,048 positions, 32 heads, 128 | 64 | 128
-    head dims, heads first), as the model calls it (the described chip is not
-    the default backend, so the test steers the one question the function
-    asks), bare and under ``vmap`` over client slots with a per-client scale;
-    and both custom calls carry the ``attn`` scope, forward and transposed, by
-    which the traced run's metrics find them."""
-    from heterofl_tpu.ops.layers import causal_latent_attention
+@pytest.mark.parametrize("family, vmapped", [("latent", False), ("latent", True), ("gq", False),
+                                             ("gq", True)],
+                         ids=["cell", "vmap10", "gq-cell", "gq-vmap10"])
+def test_attention_kernels_compile_under_attn(one_chip, family, vmapped, monkeypatch):
+    """The fused causal attentions, forward and backward, as the models call
+    them (the described chip is not the default backend, so the test steers
+    the one question the functions ask), bare and under ``vmap`` over client
+    slots with a per-client scale: latent attention at the Kanana-2 cell's
+    shapes (2 rows x 2,048 positions, 32 heads, 128 | 64 | 128 head dims,
+    heads first) and grouped-query attention at the LFM2 cell's (32 query
+    heads on 8 key/value heads of 64); and both custom calls carry the
+    ``attn`` scope, forward and transposed, by which the traced run's metrics
+    find them."""
+    from heterofl_tpu.ops.layers import causal_gq_attention, causal_latent_attention
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    N, S, H, dn, dr, dv = 2, 2048, 32, 128, 64, 128
+    N, S, H, dn, dr, dv, Hkv, hd = 2, 2048, 32, 128, 64, 128, 8, 64
+    attention, shapes, widest = {
+        "latent": (causal_latent_attention, ((N, H, S, dn), (N, H, S, dr), (N, H, S, dn),
+                                             (N, S, dr), (N, H, S, dv)), max(dn, dv)),
+        "gq": (causal_gq_attention, ((N, H, S, hd), (N, Hkv, S, hd), (N, Hkv, S, hd)), hd),
+    }[family]
+    fwd, bwd = {"latent": ("latent_attn_fwd", "latent_attn_bwd"),
+                "gq": ("gq_attn_fwd", "gq_attn_bwd")}[family]
 
-    def grads(qn, qr, kn, kr, v, scale):
-        return jax.grad(lambda *a: jnp.sum(causal_latent_attention(*a, scale) ** 2),
-                        argnums=(0, 1, 2, 3, 4))(qn, qr, kn, kr, v)
+    def grads(*a):
+        *ops, scale = a
+        return jax.grad(lambda *o: jnp.sum(attention(*o, scale) ** 2),
+                        argnums=tuple(range(len(ops))))(*ops)
 
     lead = (SLOTS,) if vmapped else ()
     avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip)
-             for s in ((N, H, S, dn), (N, H, S, dr), (N, H, S, dn), (N, S, dr),
-                       (N, H, S, dv), ())]
-    text = _compile(jax.vmap(grads) if vmapped else grads, *avals,
-                    kernels=("latent_attn_fwd", "latent_attn_bwd"))
+             for s in shapes + ((),)]
+    text = _compile(jax.vmap(grads) if vmapped else grads, *avals, kernels=(fwd, bwd))
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
     op_names = sorted(re.search(r'op_name="([^"]*)"', line).group(1) for line in calls)
     assert len(op_names) == 2
-    assert re.search(r"jvp\(attn\)\)?/latent_attn_fwd/pallas_call$", op_names[0])
-    assert re.search(r"transpose\((vmap\()?jvp\(attn\)\)+/latent_attn_bwd/pallas_call$", op_names[1])
-    # no [rows, heads, queries, keys] score block goes through HBM
-    assert not [m for m in re.findall(r"f32\[(?:10,)?2,32,(\d+),(\d+)\]", text)
-                if int(m[0]) > 1 and int(m[1]) > max(dn, dv)]
+    assert re.search(rf"jvp\(attn\)\)?/{fwd}/pallas_call$", op_names[0])
+    assert re.search(rf"transpose\((vmap\()?jvp\(attn\)\)+/{bwd}/pallas_call$", op_names[1])
+    # no [rows, heads, queries, keys] score block goes through HBM: nothing of 128 or
+    # more queries is wider than ``widest``, the kernels' own results (a head's dims;
+    # the grouped kernels' are [2, 32, 64, 2048], positions minor)
+    assert not [m for m in re.findall(r"f32\[(?:10,)?2,(?:32|8,4),(\d+),(\d+)\]", text)
+                if int(m[0]) >= 128 and int(m[1]) > widest]
 
 
 #: bytes of an element, for the shapes a relayout of the block can have
@@ -217,18 +229,19 @@ def test_lfm2_mixers_compile_with_their_relayouts_listed(one_chip, monkeypatch, 
     2,048; 32 query heads on 8 key/value heads of 64), under ``jax.checkpoint``
     and ``jax.grad`` as a layer of the model runs it, for the described chip:
     counts, not times.  The standalone copies of 4 MB or more a layer pass
-    (forward, rematerialised forward and backward together) are what the first
-    ``perf_opt`` on this cell starts from:
+    (forward, rematerialised forward and backward together):
 
     - ``shortconv``: none.  The gates and the taps fuse into elementwise
       fusions around the four products; no ``[2, 2048, 2048]`` activation and
       no weight is copied on its own.
-    - ``gqa``: 6 copies writing 75.5 MB: ONE activation, the gradient of the
-      grouped query ``f32[2, 8, 4, 2048, 64]`` on its way back from the block
-      loop's per-block pieces (``attn/add_any``, 33.6 MB), which a fused
-      kernel writing ``dq`` whole would not make; the rest the weights' own
-      (``q`` / ``o`` in bfloat16 for the rematerialised forward 2 x 8.4 MB,
-      the three gradient relayouts 16.8 + 2 x 4.2 MB).
+    - ``gqa``: 5 copies writing 41.9 MB, every one a weight's own (``q`` /
+      ``o`` in bfloat16 for the rematerialised forward 2 x 8.4 MB, the three
+      gradient relayouts 16.8 + 2 x 4.2 MB) and no activation: the fused
+      kernels (PR 34) write ``dq`` whole, where the block loop's per-block
+      pieces came back through a 33.6 MB ``attn/add_any`` copy of the grouped
+      query's gradient (6 copies, 75.5 MB).  Three custom calls under
+      ``attn`` (forward, rematerialised forward, backward) and no float32
+      ``[..., 256, <= 2048]`` score block of the block loop in the program.
     """
     from heterofl_tpu.models.lfm2 import conv_mixer, gq_attention
     from heterofl_tpu.ops.layers import masked_rms_norm
@@ -265,7 +278,14 @@ def test_lfm2_mixers_compile_with_their_relayouts_listed(one_chip, monkeypatch, 
         assert any(f"/{scope}/" in "/" + n for n in names), scope
     relayouts = _standalone_relayouts(text)
     listing = "\n".join(f"{size / 1e6:.1f} MB  {line[:160]}" for size, line in relayouts)
-    count, written = {"shortconv": (0, 0), "gqa": (6, 76e6)}[mixer]
+    count, written = {"shortconv": (0, 0), "gqa": (5, 42e6)}[mixer]
     assert len(relayouts) <= count and sum(s for s, _ in relayouts) <= written, listing
     if mixer == "shortconv":  # no activation of the gated convolution on its own
         assert not [line for _, line in relayouts if re.search(r"\[2,2048,2048\]", line)], listing
+    else:
+        calls = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line]
+        assert len(calls) == 3 and all(
+            re.search(r"attn\)*/gq_attn_(fwd|bwd)/pallas_call$", c) for c in calls), calls
+        assert not re.search(r"f32\[2,8,4,256,\d+\]", text)  # the block loop's scores
+        assert not [line for _, line in relayouts if "/attn/" in line], listing  # add_any: gone
