@@ -39,10 +39,10 @@ CONTROL_KEYS = (
 # data/ import these rather than re-declaring them.
 NORM_TYPES = ("bn", "in", "ln", "gn", "none")
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
-               "resnet152", "transformer", "kanana2", "lfm2")
+               "resnet152", "transformer", "kanana2", "lfm2", "keye")
 #: the families that train on token rows (next- or masked-token loss): the
 #: drivers' and engines' LM paths key on this, not on one family's name
-LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2")
+LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2", "keye")
 # Feature-axis value registries (ISSUE 18): THE declared domains of the
 # engine/placement/store/pod axes, consumed by the axis validators below and
 # by staticcheck's config-lattice pass (staticcheck/lattice.py enumerates
@@ -497,6 +497,28 @@ def process_control(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "conv_L_cache": 3,
         "rope_theta": 1000000.0,
         "norm_eps": 1e-5,
+        "expert_share": [0, 1],
+    }
+    # Keye-VL-2.0-30B-A3B's language model (model_type KeyeVL2): the
+    # published shape (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B
+    # config.json); ``index_*`` are its ``sa_config`` (indexer_head_dim,
+    # indexer_num_heads on one key head, topk) under DeepSeek-V3.2's flat
+    # names, because an override merges one level deep.  ``expert_share`` as
+    # above; the benchmark's cut is 5 layers and [0, 16] (8 of 128 experts).
+    cfg["keye"] = {
+        "hidden_size": 2048,
+        "num_hidden_layers": 48,
+        "moe_intermediate_size": 768,
+        "num_experts": 128,
+        "num_experts_per_tok": 8,
+        "num_attention_heads": 32,
+        "num_key_value_heads": 4,
+        "head_dim": 128,
+        "index_n_heads": 16,
+        "index_head_dim": 64,
+        "index_topk": 2048,
+        "rope_theta": 10000000.0,
+        "rms_norm_eps": 1e-6,
         "expert_share": [0, 1],
     }
     # Per-dataset hyperparameters (ref src/utils.py:150-212).

@@ -19,6 +19,7 @@ from ..config import MODEL_NAMES, ceil_width, scaled_hidden  # noqa: F401
 from .base import ModelDef  # noqa: F401
 from .conv import make_conv
 from .kanana2 import make_kanana2
+from .keye import make_keye
 from .lfm2 import make_lfm2
 from .resnet import make_resnet
 from .spec import Group, ParamSpec, count_masks, mask_params, param_mask  # noqa: F401
@@ -35,7 +36,7 @@ RESNET_BLOCKS = {
 # the canonical registry lives in config (jax-free for analysis tooling); keep
 # it in lockstep with the families actually buildable here.  A hard raise, not
 # an assert: the guard must survive `python -O` (advisor r3).
-_BUILDABLE = ("conv",) + tuple(RESNET_BLOCKS) + ("transformer", "kanana2", "lfm2")
+_BUILDABLE = ("conv",) + tuple(RESNET_BLOCKS) + ("transformer", "kanana2", "lfm2", "keye")
 if MODEL_NAMES != _BUILDABLE:
     raise ImportError(
         f"config.MODEL_NAMES {MODEL_NAMES!r} out of lockstep with buildable "
@@ -88,6 +89,9 @@ def make_model(cfg: Dict[str, Any], model_rate: Optional[float] = None) -> Model
                              mask=cfg["mask"], compute_dtype=compute_dtype)
     elif name == "lfm2":
         model = make_lfm2(cfg["num_tokens"], cfg["lfm2"], model_rate,
+                          mask=cfg["mask"], compute_dtype=compute_dtype)
+    elif name == "keye":
+        model = make_keye(cfg["num_tokens"], cfg["keye"], model_rate,
                           mask=cfg["mask"], compute_dtype=compute_dtype)
     else:
         raise ValueError("Not valid model name")

@@ -68,6 +68,19 @@ def held_experts(expert_share, n: int) -> range:
     return range(index * (n // of), (index + 1) * (n // of))
 
 
+def expert_tile(tokens: int, top_k: int, experts: int) -> int:
+    """Rows a step of the expert loop (``ops.layers.moe_experts``) takes:
+    twice an expert's expected group (``tokens * top_k / experts`` pairs), in
+    whole ``MOE_TILE``s.  An expert is then one step a pass unless its load
+    doubles: its float32 weights are read once, and the loop's trip count
+    stops following the seed's routing (at 256 rows, half an expected group
+    of the LFM2 cell, two seeds' rounds lay 2.7 % apart on the chip and 0.4 %
+    at 1,024, no slower; PERF.md, PR 32)."""
+    from ..ops.layers import MOE_TILE
+
+    return MOE_TILE * max(1, -(-2 * tokens * top_k // (experts * MOE_TILE)))
+
+
 def layer_leaves(params: Dict[str, jnp.ndarray], i: int, held=None) -> Dict[str, jnp.ndarray]:
     """Layer ``i``'s leaves (``l{i}.*``) without their prefix; with ``held``
     (an expert layer), its held experts' ``moe.e{j}.{g,u,d}.w`` stacked on a

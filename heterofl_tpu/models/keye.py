@@ -1,0 +1,280 @@
+"""Keye-VL-2.0-30B-A3B's language model (``model_type: KeyeVL2``) with
+HeteroFL width scaling.
+
+The published block (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B
+``config.json``): pre-norm decoder layers, all alike, of GROUPED-QUERY
+ATTENTION (``H`` query heads on ``Hkv`` key/value heads, RMSNorm on every
+query and key head, half-split RoPE over the whole head) whose softmax runs
+over the keys a LEARNED INDEXER chooses for each query (``sa_config``: ``Hi``
+small heads against ONE shared key head, ``topk`` keys a query, as
+DeepSeek-V3.2's sparse attention), and ``num_experts`` SwiGLU experts
+(softmax router over all of them, top-k, renormalised, NO shared expert and
+no selection bias); RMSNorm, no biases, no dropout, untied embedding and
+head; next-token loss.  ``x`` is ``[S, D]`` a row, ``rms(x, g) = x /
+sqrt(mean(x^2) + eps) * g``:
+
+  h  = rms(x, g1)
+  indexer (no gradient enters or leaves it):
+      qI = rope(h Wq_I) -> [S, Hi, di];  kI = rope(layernorm(h Wk_I)) -> [S, di]
+      wI = (h Ww_I) * Hi^-1/2 * di^-1/2 -> [S, Hi]
+      I[t, s] = sum_j wI[t, j] * relu(qI[t, j] . kI[s])       for s <= t
+      S_t = the min(t + 1, topk) keys of largest I[t, :]      (ties to the lower position)
+  attention:  q = rope(rms_head(h Wq)), k = rope(rms_head(h Wk)), v = h Wv
+      o[t, a] = sum_{s in S_t} softmax_{s in S_t}(q[t, a] . k[s, g(a)] / sqrt(d)) v[s, g(a)]
+      x = x + concat_a(o[t, a]) Wo
+  experts:  y = rms(x, g2);  p = softmax(y Wr);  sel = top_k(p);  w = p[sel] / sum(p[sel])
+      x = x + sum_{e in sel, held} w_e (silu(y Wg_e) * (y Wu_e)) Wd_e
+  logits = rms(x, g_f) W_head
+
+On text the three position streams of the published ``mrope_section`` are
+equal and the turn is half-split RoPE, which is what is built; the vision
+tower is not part of this model.  A row no longer than ``topk`` selects every
+causal key: the model then takes ``causal_gq_attention`` and runs no indexer
+(a static shape test, the same mathematics).
+
+The expert layer is told what it holds (``expert_share = (index, of)``, as
+``kanana2``); with ``of == 1`` the model is the published one.
+
+HeteroFL slicing (the paper defines none for this family; stated in the
+benchmark configuration's ``assumed``): ``emb`` prefix of the hidden size;
+per-head prefixes in whole rotary pairs of the ``d`` dims of the query heads,
+the key/value heads and the two head norms' gains (one ``family`` of groups,
+``head``), and of the ``di`` dims of the indexer's query heads, its key head
+and that head's LayerNorm (a second family, ``index``); as in ``lfm2`` the
+rotary leaves are STORED with each head's pairs adjacent (stored ``2i`` =
+published ``i``, stored ``2i + 1`` = published ``i + d/2``) and turned by
+``rope_interleaved``; ``expert`` prefix of an expert's width; never sliced:
+the expert axis, the router's columns, the indexer's ``Hi`` per-head weights,
+``topk``, the vocabulary.  Softmax scale ``1/sqrt(active head dims)``; a
+Scaler after every sliced linear except the router, the head and the
+indexer's three (their outputs are read by a top-k only, which a positive
+factor does not move).  The indexer's leaves get no gradient by construction
+(the pre-training term that trains a published indexer is not in
+``config.json``) and are sliced, carried, decayed and aggregated like every
+leaf.
+
+The layers are alike, so the whole depth is one ``lax.scan`` over their
+stacked leaves, each layer under ``jax.checkpoint``.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..obs.trace import scope
+from ..ops.layers import (causal_gq_attention, embed, linear as _linear, masked_layer_norm,
+                          masked_logits, masked_rms_norm, moe_experts, moe_route,
+                          next_token_loss, rope_interleaved, rope_swap, scaler, select_keys,
+                          selected_gq_attention)
+from .base import (ModelDef, expert_tile, held_experts, layer_leaves, normal_init,
+                   uniform_fan_in)
+from .lfm2 import gq_attention
+from .spec import Group, ParamSpec
+
+#: query rows a block of the selection and of the selected attention takes,
+#: where ``topk`` is not smaller: the published kernel's ``q_chunk_size``
+#: (memory, not mathematics; the blocks before ``topk`` then carry no mask)
+QUERY_BLOCK = 512
+
+#: the layer's counters under the names they ride the metrics by
+COUNTERS = {"tokens": "moe_tokens", "assign": "moe_assign",
+            "selected": "sparse_selected", "kept_share": "sparse_kept_share"}
+
+
+def index_keys(lp, h, *, heads: int, head_dim: int, theta: float, topk: int, block: int,
+               key_norm):
+    """A layer's indexer on the normed ``h`` ``[N, S, D]``: the per-block
+    choice of keys and its counts (``ops.layers.select_keys``).  Float32 at
+    "highest" matmul precision throughout, as a router's product is: the
+    scores decide a discrete set.  ``key_norm(x, g, b)`` the LayerNorm over
+    the key head's dims, ``head_dim`` the GLOBAL model's."""
+    h = lax.stop_gradient(h.astype(jnp.float32))
+    w = {k: lax.stop_gradient(lp[f"idx.{k}"].astype(jnp.float32))
+         for k in ("q.w", "k.w", "w.w", "k_norm.g", "k_norm.b")}
+    pos = jnp.arange(h.shape[1])
+    highest = lax.Precision.HIGHEST
+    with scope("sparse/index"):
+        qi = jnp.einsum("nsk,khd->nhsd", h, w["q.w"].reshape(h.shape[-1], heads, -1),
+                        precision=highest)
+        ki = key_norm(jnp.einsum("nsk,kd->nsd", h, w["k.w"], precision=highest),
+                      w["k_norm.g"], w["k_norm.b"])
+        wi = jnp.einsum("nsk,kh->nhs", h, w["w.w"], precision=highest) \
+            * (heads ** -0.5 * head_dim ** -0.5)
+        qi = rope_interleaved(qi, rope_swap(qi), pos, theta, axis=2, full=head_dim)
+        ki = rope_interleaved(ki, rope_swap(ki), pos, theta, axis=1, full=head_dim)
+    return select_keys(qi, ki, wi, topk, block)
+
+
+def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
+              mask: bool = True, compute_dtype=None) -> ModelDef:
+    """``arch``: ``cfg['keye']`` (config.process_control) at the GLOBAL widths;
+    ``model_rate`` builds the dense sub-model a client at that rate holds
+    (the sliced strategy and the equivalence tests)."""
+    from ..config import ceil_width
+
+    def cw(n, multiple=1):
+        k = ceil_width(n, model_rate)
+        return -(-k // multiple) * multiple
+
+    D, Fe = cw(arch["hidden_size"]), cw(arch["moe_intermediate_size"])
+    L = int(arch["num_hidden_layers"])
+    E, K = int(arch["num_experts"]), int(arch["num_experts_per_tok"])
+    H, Hkv = int(arch["num_attention_heads"]), int(arch["num_key_value_heads"])
+    Hi, topk = int(arch["index_n_heads"]), int(arch["index_topk"])
+    hd, di = cw(arch["head_dim"], 2), cw(arch["index_head_dim"], 2)
+    # staticcheck: allow(no-float-coercion): build-time config scalars
+    theta, eps = float(arch["rope_theta"]), float(arch["rms_norm_eps"])
+    held = held_experts(arch["expert_share"], E)
+    if H % Hkv:
+        raise ValueError(f"{H} query heads do not divide over {Hkv} key/value heads")
+
+    def heads(name, n, width, family):
+        return Group(name, n * width, kind="per_head", num_heads=n, multiple=2,
+                     coupled=False, family=family)
+
+    groups = {
+        "emb": Group("emb", D),
+        "q_head": heads("q_head", H, hd, "head"),
+        "kv_head": heads("kv_head", Hkv, hd, "head"),
+        "head": heads("head", 1, hd, "head"),
+        "iq_head": heads("iq_head", Hi, di, "index"),
+        "ik_head": heads("ik_head", 1, di, "index"),
+        "index": Group("index", Hi, kind="full"),
+        "expert": Group("expert", Fe),
+        "router": Group("router", E, kind="full"),
+    }
+    specs: Dict[str, ParamSpec] = {
+        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
+        "norm.g": ParamSpec({0: "emb"}),
+        "head.w": ParamSpec({0: "emb"}, label_axis=1),
+    }
+    shapes: Dict[str, tuple] = {
+        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens)}
+
+    def add(name, shape, axis_groups):
+        shapes[name] = shape
+        specs[name] = ParamSpec(axis_groups)
+
+    for i in range(L):
+        p = f"l{i}"
+        add(f"{p}.norm1.g", (D,), {0: "emb"})
+        add(f"{p}.attn.q.w", (D, H * hd), {0: "emb", 1: "q_head"})
+        add(f"{p}.attn.k.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
+        add(f"{p}.attn.v.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
+        add(f"{p}.attn.q_norm.g", (hd,), {0: "head"})
+        add(f"{p}.attn.k_norm.g", (hd,), {0: "head"})
+        add(f"{p}.attn.o.w", (H * hd, D), {0: "q_head", 1: "emb"})
+        add(f"{p}.idx.q.w", (D, Hi * di), {0: "emb", 1: "iq_head"})
+        add(f"{p}.idx.k.w", (D, di), {0: "emb", 1: "ik_head"})
+        add(f"{p}.idx.k_norm.g", (di,), {0: "ik_head"})
+        add(f"{p}.idx.k_norm.b", (di,), {0: "ik_head"})
+        add(f"{p}.idx.w.w", (D, Hi), {0: "emb", 1: "index"})
+        add(f"{p}.norm2.g", (D,), {0: "emb"})
+        add(f"{p}.moe.router.w", (D, E), {0: "emb", 1: "router"})
+        for j in held:
+            add(f"{p}.moe.e{j}.g.w", (D, Fe), {0: "emb", 1: "expert"})
+            add(f"{p}.moe.e{j}.u.w", (D, Fe), {0: "emb", 1: "expert"})
+            add(f"{p}.moe.e{j}.d.w", (Fe, D), {0: "expert", 1: "emb"})
+
+    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
+        names = sorted(shapes)
+        params = {}
+        for name, k in zip(names, jax.random.split(key, len(names))):
+            shape = shapes[name]
+            if len(shape) == 1:  # norm gains 1; the indexer's LayerNorm bias 0
+                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
+            elif name.startswith("embedding."):
+                params[name] = normal_init(k, shape, 1.0)
+            else:
+                params[name] = uniform_fan_in(k, shape, shape[0])
+        return params
+
+    linear = partial(_linear, compute_dtype=compute_dtype)
+
+    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
+              label_mask=None, bn_mode: str = "batch", bn_state=None,
+              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
+        if "pos_offset" in batch or attn_override is not None:
+            raise ValueError("keye has no sequence-sharded path (mesh "
+                             "'data' axis must be 1)")
+        labels = batch["label"]
+        N, S = labels.shape
+        T = N * S
+        act = {g: groups[g].active_count(width_rate).astype(jnp.float32)
+               for g in ("emb", "head", "ik_head")}
+        masks = {g: groups[g].mask(width_rate) for g in ("emb", "head", "ik_head")}
+
+        def sc(x):
+            return scaler(x, scaler_rate, train)
+
+        def rms(g, x):
+            return masked_rms_norm(x, g, masks["emb"], act["emb"], eps)
+
+        tile, block = expert_tile(T, K, E), min(QUERY_BLOCK, topk)
+        attention = partial(
+            gq_attention, heads=H, kv_heads=Hkv, head_dim=int(arch["head_dim"]), theta=theta,
+            scale=1.0 / jnp.sqrt(act["head"]), sc=sc, compute_dtype=compute_dtype,
+            head_norm=lambda x, g: masked_rms_norm(x, g, masks["head"], act["head"], eps))
+        indexer = partial(
+            index_keys, heads=Hi, head_dim=int(arch["index_head_dim"]), theta=theta, topk=topk,
+            block=block,
+            key_norm=lambda x, g, b: masked_layer_norm(x, g, b, masks["ik_head"],
+                                                       act["ik_head"], eps))
+        # floats, so that they ride the metrics beside the experts' counters
+        all_pairs = jnp.full((2,), N * (S * (S + 1) // 2), jnp.float32)
+
+        @jax.checkpoint
+        def layer(x, lp):
+            """``(x, leaves) -> (x, counters)``, the scan's body; it keeps only
+            its input for the backward."""
+            h = rms(lp["norm1.g"], x)
+            if S > topk:
+                select, pairs = indexer(lp, h)
+                x = x + attention(lp, h, attend=partial(selected_gq_attention, select=select,
+                                                        block=block))
+            else:  # every causal key is among the topk: no choice to make
+                pairs = all_pairs
+                x = x + attention(lp, h, attend=causal_gq_attention)
+            hf = rms(lp["norm2.g"], x).reshape(T, D)
+            sel, w = moe_route(hf, lp["moe.router.w"], None, K, 1.0, softmax=True)
+            y, counters = moe_experts(hf, sel, w, [lp[f"moe.e.{m}.w"] for m in "gud"],
+                                      held[0], sc, compute_dtype, tile=tile)
+            counters["selected"] = jnp.stack([pairs[0], jnp.float32(T)])
+            counters["kept_share"] = pairs
+            return x + y.reshape(N, S, D), counters
+
+        x = embed(params["embedding.tok.w"], labels)
+        run = [layer_leaves(params, i, held) for i in range(L)]
+        x, per_layer = lax.scan(layer, x, {k: jnp.stack([lp[k] for lp in run]) for k in run[0]})
+        counters = jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), per_layer)
+        xn = rms(params["norm.g"], x)
+
+        def head(x_):
+            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
+
+        # the logits [N, S, V] a caller may read (training does not: then the
+        # compiler drops them); the loss takes the head in blocks of positions
+        return {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight),
+                "counters": {COUNTERS[k]: v for k, v in counters.items()}}, {}
+
+    meta = {"bn_sizes": {}, "kind": "keye", "num_tokens": num_tokens,
+            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes),
+            # what analysis.summary.module_table cannot read off the leaves
+            # (the attention's two products at every causal pair: an upper bound
+            # where the indexer selects)
+            "profile": {"routed_share": K / E,
+                        "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
+            # what apply's "counters" holds (summed over the layers); the
+            # engines carry them as obs_ probes when telemetry is on.  The
+            # two sparse ones are (numerator, denominator) pairs:
+            # obs.split_probes finishes them as selected keys a query and
+            # selected over causal pairs
+            "counters": {"moe_tokens": (len(held),), "moe_assign": (3,),
+                         "sparse_selected": (2,), "sparse_kept_share": (2,)}}
+    return ModelDef("keye", init, apply, specs, groups, [], meta)
+

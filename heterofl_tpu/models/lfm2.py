@@ -64,11 +64,11 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import scope
-from ..ops.layers import (MOE_TILE, causal_gq_attention, embed, heads_linear,
+from ..ops.layers import (causal_gq_attention, embed, heads_linear,
                           linear as _linear, linear_heads, masked_logits, masked_rms_norm,
                           moe_experts, moe_route, next_token_loss, rope_interleaved,
                           rope_swap, scaler, short_conv, swiglu)
-from .base import ModelDef, held_experts, layer_leaves, normal_init, uniform_fan_in
+from .base import ModelDef, expert_tile, held_experts, layer_leaves, normal_init, uniform_fan_in
 from .spec import Group, ParamSpec
 
 #: what ``lfm2_moe`` adds to the sum of the chosen scores before dividing
@@ -84,11 +84,11 @@ def conv_mixer(lp, h, *, sc, compute_dtype=None):
 
 
 def gq_attention(lp, h, *, heads: int, kv_heads: int, head_dim: int, theta: float, scale,
-                 sc, head_norm, compute_dtype=None):
+                 sc, head_norm, compute_dtype=None, attend=causal_gq_attention):
     """A layer's grouped-query attention on the normed ``h`` ``[N, S, D]``,
-    heads first from the projections to the output projection;
-    ``head_norm(x, g)`` the RMSNorm over each head's dims, ``head_dim`` the
-    GLOBAL model's (the rotary frequencies' denominator at every width)."""
+    heads first from the projections to the output projection; ``head_norm(x,
+    g)`` the RMSNorm over each head's dims, ``head_dim`` the GLOBAL model's (the
+    rotary frequencies' denominator at every width), ``attend(q, k, v, scale)``."""
     q_heads = partial(linear_heads, heads=heads, compute_dtype=compute_dtype)
     kv = partial(linear_heads, heads=kv_heads, compute_dtype=compute_dtype)
     pos = jnp.arange(h.shape[1])
@@ -102,7 +102,7 @@ def gq_attention(lp, h, *, heads: int, kv_heads: int, head_dim: int, theta: floa
     k = rope_interleaved(k, rope_swap(k), pos, theta, axis=2, full=head_dim)
     if compute_dtype is not None:
         q, k, v = (t.astype(compute_dtype) for t in (q, k, v))
-    o = causal_gq_attention(q, k, v, scale)
+    o = attend(q, k, v, scale)
     with scope("gqa"):
         return sc(heads_linear(o.astype(jnp.float32), lp["attn.o.w"], compute_dtype))
 
@@ -236,13 +236,7 @@ def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 head_norm=lambda x, g: masked_rms_norm(x, g, head_mask, head_act, eps)),
         }
 
-        # rows a step of the expert loop takes: twice an expert's expected
-        # group (T * K / E pairs), in whole MOE_TILEs.  An expert is then one
-        # step a pass unless its load doubles: its float32 weights are read
-        # once, and the loop's trip count stops following the seed's routing
-        # (at 256 rows, half an expected group here, two seeds' rounds lay 2.7 %
-        # apart on the chip and 0.4 % at this size, no slower; PERF.md, PR 32)
-        tile = MOE_TILE * max(1, -(-2 * T * K // (E * MOE_TILE)))
+        tile = expert_tile(T, K, E)
 
         zero_counters = {"tokens": jnp.zeros((len(held),), jnp.float32),
                          "assign": jnp.zeros((3,), jnp.float32)}

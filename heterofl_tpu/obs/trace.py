@@ -92,13 +92,22 @@ EXTRA_SCOPES = (
 #: benchmark/scope_reduce_lfm2.py.
 MIXER_SCOPES = ("shortconv", "shortconv/gate", "gqa")
 
+#: What ISSUE 35 added: the learned sparse-attention indexer of
+#: models/keye.py.  ``sparse/index`` = the indexer's three projections, its
+#: key head's LayerNorm and turn, and the per-block ``relu``-weighted score;
+#: ``sparse/select`` = the exact top-k of a score block and the mask built
+#: from it (``ops.layers.select_keys``).  The attention they select for stays
+#: under ``gqa`` / ``rope`` / ``attn``.  A fourth tuple for the reason
+#: :data:`MIXER_SCOPES` is one; read through benchmark/scope_reduce_keye.py.
+SPARSE_SCOPES = ("sparse/index", "sparse/select")
+
 #: Version of the vocabulary AND of where it is entered.  jax keeps metadata
 #: out of the persistent compile cache's key, so a program whose only change
 #: is a scope would load the executable cached before the change, without
 #: the new names, silently; ``utils.compile_cache`` folds this number into
 #: the key.  Bump it with every change to :data:`SCOPES` or to where a scope
 #: is entered (one cold compile per program, once).
-SCOPE_VERSION = 4
+SCOPE_VERSION = 5
 
 #: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
 KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
@@ -111,7 +120,7 @@ EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_b
 
 
 def _known(name: str) -> str:
-    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES:
+    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES + SPARSE_SCOPES:
         raise ValueError(f"Not valid scope: {name!r} (obs.trace.SCOPES)")
     return name
 

@@ -345,7 +345,8 @@ def next_token_loss(xn: jnp.ndarray, labels: jnp.ndarray, logits_of,
 
 # ---------------------------------------------------------------------------
 # Latent attention + shared-and-routed experts (models/kanana2.py); gated
-# short convolution + grouped-query attention (models/lfm2.py)
+# short convolution + grouped-query attention (models/lfm2.py); a learned
+# sparse-attention indexer over grouped-query attention (models/keye.py)
 # ---------------------------------------------------------------------------
 
 @scoped("norm")
@@ -432,27 +433,33 @@ def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
     return blockwise_latent_attention(qn, qr, kn, kr, v, scale, block)
 
 
-def _causal_blocks(scores, values, qs, ks, v, scale, block: int):
+def _causal_blocks(scores, values, qs, ks, v, scale, block: int, select=None):
     """The one blockwise causal softmax loop: query blocks of ``block`` rows
     against the keys up to the block's end, each block under
     ``jax.checkpoint``, so no ``[S, S]`` score matrix of a whole row is ever
     held, in the forward or for the backward, and key blocks above the
     diagonal are never computed.  Every operand has its positions on axis -2;
     ``scores(*q_blocks, *k_blocks)`` gives ``[..., q, k]`` and ``values(p,
-    v_block)`` the block's result, positions on axis -2 again."""
+    v_block)`` the block's result, positions on axis -2 again.  ``select``
+    (:func:`select_keys`): per block None or a further mask ``[N, q, k]`` on
+    its scores, alike for every head."""
     S = v.shape[-2]
     outs = []
-    for start in range(0, S, block):
+    for i, start in enumerate(range(0, S, block)):
         end = min(start + block, S)
 
-        def one(qs_b, ks_b, v_b, start=start, end=end):
+        def one(qs_b, ks_b, v_b, *sel_b, start=start, end=end):
             s = scores(*qs_b, *ks_b).astype(jnp.float32) * scale
             keep = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
+            for m in sel_b:
+                keep = keep & m.reshape(m.shape[:1] + (1,) * (s.ndim - 3) + m.shape[1:])
             s = jnp.where(keep, s, -jnp.inf)
             return values(jax.nn.softmax(s, axis=-1), v_b)
 
+        sel_b = () if select is None or select[i] is None else (select[i],)
         outs.append(jax.checkpoint(one)(tuple(q[..., start:end, :] for q in qs),
-                                        tuple(k[..., :end, :] for k in ks), v[..., :end, :]))
+                                        tuple(k[..., :end, :] for k in ks), v[..., :end, :],
+                                        *sel_b))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-2)
 
 
@@ -488,7 +495,7 @@ def causal_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
     return blockwise_gq_attention(q, k, v, scale, block)
 
 
-def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
+def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK, select=None):
     """:func:`causal_gq_attention` in plain ``jnp`` (and the fused kernels'
     oracle): latent attention's block loop (:func:`_causal_blocks`) with the
     query heads grouped by the key/value head they read."""
@@ -497,8 +504,88 @@ def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
     o = _causal_blocks(
         lambda q_b, k_b: jnp.einsum("ngjqd,ngkd->ngjqk", q_b, k_b),
         lambda p, v_b: jnp.einsum("ngjqk,ngkd->ngjqd", p, v_b),
-        (q.reshape(N, kv, H // kv, S, d),), (k,), v, scale, block)
+        (q.reshape(N, kv, H // kv, S, d),), (k,), v, scale, block, select)
     return o.reshape(N, H, S, v.shape[-1])
+
+
+@scoped("attn")
+def selected_gq_attention(q, k, v, scale, select, block: int):
+    """:func:`causal_gq_attention` whose softmax runs over the keys a learned
+    indexer chose for each query (:func:`select_keys` in the same ``block``):
+    the ``jnp`` block loop with the selection as a further mask on each score
+    block, alike for every head.  The fused kernels know one mask, the
+    diagonal; a mask operand for them is the step after this form."""
+    return blockwise_gq_attention(q, k, v, scale, block, select)
+
+
+def top_k_mask(x, k: int):
+    """The ``k`` largest entries of each row of ``x`` ``[..., n]`` (float32,
+    ``n >= k``) as a 0/1 mask, equal values to the lower position (and -0.0
+    below 0.0): the set ``lax.top_k`` returns, without its sort and without
+    a scatter.  The k-th
+    largest VALUE is found bit by bit on the order-preserving integer image
+    of a float (32 counting passes over ``x``), then the position that cuts
+    the entries equal to it (``n.bit_length()`` more)."""
+    n = x.shape[-1]
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    top = jnp.uint32(1 << 31)
+    u = lax.bitcast_convert_type(bits ^ ((bits >> 31) & 0x7FFFFFFF), jnp.uint32) ^ top
+
+    def count(m):
+        return jnp.sum(m, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def value_bit(i, t):  # the largest t with k or more entries >= t
+        cand = t | lax.shift_right_logical(top, i.astype(jnp.uint32))
+        return jnp.where(count(u >= cand) >= k, cand, t)
+
+    kth = lax.fori_loop(0, 32, value_bit, jnp.zeros(u.shape[:-1] + (1,), jnp.uint32))
+    above, tie = u > kth, u == kth
+    need = k - count(above)  # of the entries equal to the k-th value, from the left
+    pos = lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
+
+    def position_bit(i, last):  # the largest position with fewer than `need` ties before it
+        cand = last | (jnp.int32(1 << (n.bit_length() - 1)) >> i)
+        return jnp.where(count(tie & (pos < cand)) < need, cand, last)
+
+    last = lax.fori_loop(0, n.bit_length(), position_bit,
+                         jnp.zeros(u.shape[:-1] + (1,), jnp.int32))
+    return above | (tie & (pos <= last))
+
+
+def select_keys(qi, ki, wi, topk: int, block: int):
+    """A learned sparse-attention indexer's choice of keys (``sa_config``):
+    ``I[t, s] = sum_j wi[t, j] * relu(qi[t, j] . ki[s])`` over the indexer's
+    heads ``j``, and for query ``t`` the ``min(t + 1, topk)`` causal keys of
+    largest ``I`` (equal scores to the lower position).  ``qi`` ``[N, Hi, S,
+    di]``, ``ki`` ``[N, S, di]`` (one key head), ``wi`` ``[N, Hi, S]``.
+
+    Query blocks of ``block`` rows against the keys up to the block's end, as
+    :func:`_causal_blocks` takes them: returns, per block, None where every
+    causal key is chosen (the block ends at or before ``topk``) or the 0/1
+    choice ``[N, q, k]`` (to be met with the causal mask), and the counts
+    ``[2]`` = (chosen causal pairs, causal pairs) in float32.  The scores,
+    ``[Hi, S, S]`` if held whole, live a block at a time, in float32 at
+    "highest" matmul precision: they decide a discrete set, as a router's
+    do.  No gradient: the choice is read as a mask."""
+    N, _, S, _ = qi.shape
+    qi, ki, wi = (lax.stop_gradient(t.astype(jnp.float32)) for t in (qi, ki, wi))
+    select, chosen = [], 0.0
+    for start in range(0, S, block):
+        end = min(start + block, S)
+        causal = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
+        if end <= topk:
+            select.append(None)
+            chosen += N * jnp.sum(causal, dtype=jnp.float32)
+            continue
+        with scope("sparse/index"):
+            s = jnp.einsum("nhqd,nkd->nhqk", qi[:, :, start:end], ki[:, :end],
+                           precision=lax.Precision.HIGHEST)
+            score = jnp.sum(jax.nn.relu(s) * wi[:, :, start:end, None], axis=1)
+        with scope("sparse/select"):
+            keep = top_k_mask(jnp.where(causal, score, -jnp.inf), topk)
+            select.append(keep)
+            chosen += jnp.sum(keep & causal, dtype=jnp.float32)
+    return select, jnp.stack([chosen, jnp.float32(N * (S * (S + 1) // 2))])
 
 
 def short_conv(b, c, u, taps):
@@ -519,17 +606,20 @@ def short_conv(b, c, u, taps):
 
 
 @scoped("moe/router")
-def moe_route(h, w_router, bias, top_k: int, scaling: float, sum_eps: float = 0.0):
-    """Sigmoid router over ALL experts (``scoring_func: sigmoid``,
-    ``topk_method: noaux_tc`` with one group): ``s = sigmoid(h Wr)`` in
-    float32 at "highest" matmul precision, ``sel = top_k(s + bias)`` (the
-    selection bias is read here only; no gradient reaches it),
+def moe_route(h, w_router, bias, top_k: int, scaling: float, sum_eps: float = 0.0,
+              softmax: bool = False):
+    """A router over ALL experts, its product in float32 at "highest" matmul
+    precision.  Sigmoid scoring (``scoring_func: sigmoid``, ``topk_method:
+    noaux_tc`` with one group): ``s = sigmoid(h Wr)``, ``sel = top_k(s +
+    bias)`` (the selection bias is read here only; no gradient reaches it),
     ``w = s[sel] / (sum(s[sel]) + sum_eps) * scaling`` (``lfm2_moe`` adds 1e-6
-    to the sum, ``deepseek_v3`` nothing).  ``h`` ``[T, D]``.  Returns ``(sel
-    [T, k] int32, w [T, k])``."""
-    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
-    _, sel = lax.top_k(s + lax.stop_gradient(bias), top_k)
+    to the sum, ``deepseek_v3`` nothing).  ``softmax``: ``s = softmax(h Wr)``
+    over all experts, ``bias`` None (``norm_topk_prob``: the same division).
+    ``h`` ``[T, D]``.  Returns ``(sel [T, k] int32, w [T, k])``."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    s = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(logits)
+    _, sel = lax.top_k(s if bias is None else s + lax.stop_gradient(bias), top_k)
     w = jnp.take_along_axis(s, sel, axis=-1)
     total = jnp.sum(w, axis=-1, keepdims=True)
     if sum_eps:

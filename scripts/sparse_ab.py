@@ -1,0 +1,143 @@
+"""The Keye cell's sparse-attention indexer on the chip, at the cell's shapes
+(one row of 8,192 positions, hidden 2,048, 16 indexer heads of 64, top-2,048)
+with the benchmark's seeded weights: how many (query, key) selections the
+program (`models.keye.index_keys`: `ops.layers.select_keys`, the exact top-k
+as counting passes) and the plain reference (`benchmark/reference/keye.py`:
+`lax.top_k` on its own scores) disagree on, layer by layer on one input; what
+the program's counters read; and what the selection costs against a sort.
+Three minutes:
+
+    chiprun -- python scripts/sparse_ab.py [seed]       # on the chip
+    JAX_PLATFORMS=cpu python scripts/sparse_ab.py tiny  # the same code at the tests' size
+
+Every layer's indexer reads the SAME input here (the normed embedding of the
+row), so that the two sides' choices differ by their own arithmetic alone and
+not by what earlier layers handed on.  Milliseconds are host-clock over 5
+calls, best of 3.  The numbers land in `chiprun_out/sparse_ab.json`.
+"""
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from benchmark import harness, weights  # noqa: E402
+from benchmark.reference import common, keye as ref  # noqa: E402
+from heterofl_tpu import config as C  # noqa: E402
+from heterofl_tpu.entry.common import build_cli, cfg_from_args  # noqa: E402
+from heterofl_tpu.models import keye, make_model  # noqa: E402
+from heterofl_tpu.models.base import layer_leaves  # noqa: E402
+from heterofl_tpu.ops import layers as L  # noqa: E402
+
+NAME = "keye-vl-2-30b-a3b.fix-a1-e1.train-8k"
+
+
+def timed(fn, *args):
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        for _ in range(5):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t) / 5)
+    return 1e3 * best
+
+
+def main(argv):
+    tiny = "tiny" in argv
+    seed = int(next((a for a in argv if a.isdigit()), 3500000101))
+    if tiny:
+        from benchmark.tests import tiny_keye
+
+        cfg = tiny_keye.program_cfg()
+        model_cfg = tiny_keye.reference_model(cfg)
+    else:
+        cell, config = harness.load_cell(NAME)
+        cfg = C.process_control(cfg_from_args(build_cli("sparse_ab").parse_args(
+            harness.experiment_argv(cell, config, seed, "/tmp/x", "/tmp/y"))))
+        cfg["num_tokens"] = cfg["classes_size"] = config["model"]["num_tokens"]
+        model_cfg = config["model"]
+    a, S, V = cfg["keye"], cfg["bptt"], cfg["num_tokens"]
+    block = min(keye.QUERY_BLOCK, a["index_topk"])  # the model's
+    model = make_model(cfg)
+    shapes = {k: tuple(v.shape) for k, v in jax.eval_shape(model.init, jax.random.key(0)).items()}
+    params = weights.make_params(shapes, seed)
+    tokens = jax.random.randint(jax.random.key(seed % (2 ** 31)), (1, S), 0, V)
+    arch = ref.arch_of(model_cfg)
+    eps = a["rms_norm_eps"]
+    h = ref._rms(params["embedding.tok.w"][tokens], params["l0.norm1.g"], eps)
+    causal = np.tril(np.ones((S, S), bool))
+
+    @jax.jit
+    def program(lp, h):
+        ones = jnp.ones((a["index_head_dim"],))
+        select, pairs = keye.index_keys(
+            lp, h, heads=a["index_n_heads"], head_dim=a["index_head_dim"], theta=a["rope_theta"],
+            topk=a["index_topk"], block=block,
+            key_norm=lambda x, g, b: L.masked_layer_norm(x, g, b, ones, ones.size, eps))
+        return [m for m in select if m is not None], pairs
+
+    @jax.jit
+    @common.highest
+    def reference(lp, h):
+        q_i, k_i, w_i = ref.indexer(lp, h, dict(arch))
+        return jax.lax.map(lambda s: ref.selected(ref.index_scores(
+            jax.lax.dynamic_slice_in_dim(q_i, s, block, 1), k_i,
+            jax.lax.dynamic_slice_in_dim(w_i, s, block, 1), s + jnp.arange(block)),
+            a["index_topk"]), jnp.arange(0, S, block))
+
+    out = {"seed": seed, "device": jax.devices()[0].device_kind, "positions": S,
+           "topk": a["index_topk"], "layers": []}
+    for i in range(a["num_hidden_layers"]):
+        lp = layer_leaves(params, i, model.meta["held_experts"])
+        masks, pairs = program(lp, h)
+        mine = causal.copy()
+        first = S - sum(m.shape[1] for m in masks)  # blocks before it keep every causal key
+        for m in masks:
+            rows = slice(first, first + m.shape[1])
+            mine[rows, :m.shape[2]] &= np.asarray(m[0])
+            first += m.shape[1]
+        theirs = np.asarray(reference(lp, h))[:, 0].reshape(S, S) & causal
+        differ = int(np.count_nonzero(mine != theirs))
+        out["layers"].append({"layer": i, "selected": int(mine.sum()), "reference": int(theirs.sum()),
+                              "pairs_that_differ": differ,
+                              "queries_that_differ": int(np.count_nonzero((mine != theirs).any(1))),
+                              "program_counts": [float(p) for p in pairs]})
+        print(f"layer {i}: program selects {mine.sum()} of {causal.sum()} causal pairs "
+              f"({mine.sum() / S:.1f} a query), reference {theirs.sum()}; they differ on {differ} "
+              f"pairs in {out['layers'][-1]['queries_that_differ']} queries", flush=True)
+
+    # what one forward pass of the model counts (the obs_ counters' source)
+    res, _ = jax.jit(lambda p, t: model.apply(p, {"label": t}, train=False))(params, tokens)
+    c = {k: np.asarray(v, np.float64) for k, v in res["counters"].items()}
+    out["sparse_selected"] = float(c["sparse_selected"][0] / c["sparse_selected"][1])
+    out["sparse_kept_share"] = float(c["sparse_kept_share"][0] / c["sparse_kept_share"][1])
+    print(f"counters of a forward pass: sparse_selected {out['sparse_selected']:.4f} keys a "
+          f"query, sparse_kept_share {out['sparse_kept_share']:.6f}", flush=True)
+
+    # the selection against a sort, on the last (widest) block's scores
+    lp = layer_leaves(params, 0, model.meta["held_experts"])
+    q_i, k_i, w_i = jax.jit(common.highest(lambda lp, h: ref.indexer(lp, h, dict(arch))))(lp, h)
+    scores = ref.index_scores(q_i[:, S - block:], k_i, w_i[:, S - block:], jnp.arange(S - block, S))
+    k = a["index_topk"]
+    out["ms"] = {
+        "top_k_mask": timed(jax.jit(lambda x: L.top_k_mask(x, k)), scores),
+        "lax.top_k": timed(jax.jit(lambda x: jax.lax.top_k(x, k)), scores),
+        "reference.selected": timed(jax.jit(lambda x: ref.selected(x, k)), scores),
+        "program.index_keys": timed(program, lp, h),
+        "reference.indexer+selected": timed(reference, lp, h)}
+    print("ms:", json.dumps(out["ms"]), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", "sparse_ab.json"), "w", encoding="utf-8") as f:
+        json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])

@@ -250,3 +250,20 @@ def test_the_mixers_scopes_are_a_vocabulary_of_their_own():
         with trace.scope(s):
             pass
     assert trace.SCOPE_VERSION >= 4  # bumped with the new names (the compile cache's key)
+
+
+def test_the_indexers_scopes_are_a_vocabulary_of_their_own():
+    """`SPARSE_SCOPES` (ISSUE 35) is disjoint from the three older tuples,
+    which the accepted benchmark's readers mirror name for name; `scope()`
+    takes all four and refuses a neighbour of theirs.  That the Keye round
+    enters them, in the forward and its recomputation only:
+    tests/test_keye.py."""
+    older = trace.SCOPES + trace.EXTRA_SCOPES + trace.MIXER_SCOPES
+    assert trace.SPARSE_SCOPES == ("sparse/index", "sparse/select")
+    assert not set(trace.SPARSE_SCOPES) & set(older)
+    for s in older + trace.SPARSE_SCOPES:
+        with trace.scope(s):
+            pass
+    with pytest.raises(ValueError, match="Not valid scope"):
+        trace.scope("sparse")
+    assert trace.SCOPE_VERSION >= 5
