@@ -70,7 +70,7 @@ from ..obs.trace import scope
 from ..ops.layers import (causal_gq_attention, embed, linear as _linear, masked_layer_norm,
                           masked_logits, masked_rms_norm, moe_experts, moe_route,
                           next_token_loss, rope_interleaved, rope_swap, scaler, select_keys,
-                          selected_gq_attention)
+                          selected_attention_tile, selected_gq_attention)
 from .base import (ModelDef, expert_tile, held_experts, layer_leaves, normal_init,
                    uniform_fan_in)
 from .lfm2 import gq_attention
@@ -83,7 +83,8 @@ QUERY_BLOCK = 512
 
 #: the layer's counters under the names they ride the metrics by
 COUNTERS = {"tokens": "moe_tokens", "assign": "moe_assign",
-            "selected": "sparse_selected", "kept_share": "sparse_kept_share"}
+            "selected": "sparse_selected", "kept_share": "sparse_kept_share",
+            "fused": "sparse_fused"}
 
 
 def index_keys(lp, h, *, heads: int, head_dim: int, theta: float, topk: int, block: int,
@@ -227,6 +228,9 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                                                        act["ik_head"], eps))
         # floats, so that they ride the metrics beside the experts' counters
         all_pairs = jnp.full((2,), N * (S * (S + 1) // 2), jnp.float32)
+        # query tiles of the selected attention, and those the fused kernels took
+        tiles = N * -(-S // block) if S > topk else 0
+        fused = tiles if selected_attention_tile(S, hd, H // Hkv) is not None else 0
 
         @jax.checkpoint
         def layer(x, lp):
@@ -246,6 +250,7 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                                       held[0], sc, compute_dtype, tile=tile)
             counters["selected"] = jnp.stack([pairs[0], jnp.float32(T)])
             counters["kept_share"] = pairs
+            counters["fused"] = jnp.stack([jnp.float32(fused), jnp.float32(tiles)])
             return x + y.reshape(N, S, D), counters
 
         x = embed(params["embedding.tok.w"], labels)
@@ -271,10 +276,12 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                         "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
             # what apply's "counters" holds (summed over the layers); the
             # engines carry them as obs_ probes when telemetry is on.  The
-            # two sparse ones are (numerator, denominator) pairs:
-            # obs.split_probes finishes them as selected keys a query and
-            # selected over causal pairs
+            # three sparse ones are (numerator, denominator) pairs:
+            # obs.split_probes finishes them as selected keys a query,
+            # selected over causal pairs, and the share of the selected
+            # attention's query tiles that the fused kernels took
             "counters": {"moe_tokens": (len(held),), "moe_assign": (3,),
-                         "sparse_selected": (2,), "sparse_kept_share": (2,)}}
+                         "sparse_selected": (2,), "sparse_kept_share": (2,),
+                         "sparse_fused": (2,)}}
     return ModelDef("keye", init, apply, specs, groups, [], meta)
 

@@ -112,11 +112,11 @@ SCOPE_VERSION = 5
 #: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
 KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
 
-#: The kernels ISSUES 29 and 34 added (ops/pallas_attention.py, under ``attn``):
-#: a tuple of its own for the reason :data:`EXTRA_SCOPES` is one (the accepted
-#: benchmark mirrors :data:`KERNELS` name for name).  The accepted reader drops
-#: the component and files the kernel's time under ``attn``.
-EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_bwd")
+#: The kernels ISSUES 29, 34 and 36 added (ops/pallas_attention.py, under
+#: ``attn``): a tuple of its own as :data:`EXTRA_SCOPES` is one (the benchmark
+#: mirrors :data:`KERNELS`); its reader files their time under ``attn``.
+EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_bwd",
+                 "sel_attn_fwd", "sel_attn_bwd")
 
 
 def _known(name: str) -> str:

@@ -511,11 +511,35 @@ def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK, select=None)
 @scoped("attn")
 def selected_gq_attention(q, k, v, scale, select, block: int):
     """:func:`causal_gq_attention` whose softmax runs over the keys a learned
-    indexer chose for each query (:func:`select_keys` in the same ``block``):
-    the ``jnp`` block loop with the selection as a further mask on each score
-    block, alike for every head.  The fused kernels know one mask, the
-    diagonal; a mask operand for them is the step after this form."""
+    indexer chose for each query (:func:`select_keys` in the same ``block``),
+    alike for every head.
+
+    On a TPU, where :func:`selected_attention_tile` finds tiles, the fused
+    kernels ``sel_attn_fwd`` / ``sel_attn_bwd``, which read the 0/1 choice a
+    mask tile beside ``q``, ``k``, ``v``: a score tile lives in VMEM only.
+    Elsewhere the ``jnp`` block loop with the selection as a further mask on
+    each score block (:func:`blockwise_gq_attention`, the kernels' oracle)."""
+    tile = selected_attention_tile(q.shape[2], q.shape[-1], q.shape[1] // k.shape[1])
+    if tile is not None:
+        from . import pallas_attention
+
+        return pallas_attention.fused_selected_attention(
+            q, k, v, scale, select, block, block_q=tile[0], block_k=tile[1])
     return blockwise_gq_attention(q, k, v, scale, block, select)
+
+
+def selected_attention_tile(S: int, d: int, group: int):
+    """The (query, key) tile :func:`selected_gq_attention` gives its fused
+    kernels at ``S`` positions and ``group`` query heads of ``d`` dims a
+    key/value head, None where it takes the block loop: off a TPU, or where
+    the shapes make no whole tiles (``pallas_attention.sel_tile_for``: head
+    dims a multiple of 128, positions of 128; a client's narrow slice at its
+    own widths takes none)."""
+    if jax.default_backend() != "tpu":
+        return None
+    from . import pallas_attention
+
+    return pallas_attention.sel_tile_for(S, d, group)
 
 
 def top_k_mask(x, k: int):

@@ -483,3 +483,258 @@ def fused_gq_attention(q, k, v, scale, *, block_q: int, block_k: int, interpret:
     result."""
     qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 2, 3) for x in (q, k, v))
     return jnp.swapaxes(_gq_flash(qt * scale, kt, vt, block_q, block_k, interpret), 2, 3)
+
+
+# ---------------------------------------------------------------------------
+# Selected grouped-query attention (``ops.layers.selected_gq_attention``):
+# kernels ``sel_attn_fwd`` / ``sel_attn_bwd``.  The grouped-query kernels'
+# layout and group (positions on the lanes, a key/value head's query heads
+# side by side), and two things of their own.
+#
+# THE SELECTION AS A MASK TILE.  A learned indexer's 0/1 choice (``ops.layers.
+# select_keys``) is ONE mask a row, alike for every head: ``[N, S keys, S
+# queries]`` int8, keys first as the scores are, read a ``[tk, tq]`` tile a
+# grid step beside ``q``, ``k``, ``v`` and met with the diagonal's mask where
+# the diagonal crosses the tile.  A query tile that ends at or before
+# ``first`` (the indexer's ``topk``: such a query keeps every causal key)
+# never looks at its mask tile.  The mask gets no gradient.
+#
+# A FULLY MASKED TILE IS HARMLESS.  Under the diagonal alone every query sees
+# key 0 in its first key tile, so the running max is real from the start.  A
+# selection can leave a query without a key in its first key tiles: its max
+# is still ``MASKED``, ``exp(MASKED - MASKED) = 1`` and the sum and the
+# accumulator collect garbage, until the first real score arrives and ``alpha
+# = exp(MASKED - real) = 0`` wipes both.  That is sound because every query
+# selects at least one causal key (``min(t + 1, topk) >= 1``); after it, a
+# masked score gives ``exp(MASKED - real) = 0``, as it does against the
+# log-sum-exp in the backward.
+#
+# THE BACKWARD RUNS QUERY TILES OUTER.  ``gq_attn_bwd`` keeps ``dq`` of a whole
+# group resident over the grid (``[G, d, S]`` float32: 2 MB at 4 heads of 64
+# on rows of 2,048, 34 MB at 8 heads of 128 on rows of 8,192, twice that
+# double-buffered).  A key/value head's ``dk`` and ``dv`` are ``G`` times smaller
+# (``[d, S]``: 4 MB each there), so here THEY are resident and a group's
+# ``dq`` tile is accumulated over the key tiles up to the diagonal, the grid
+# of the forward: five products a tile, one kernel.
+# ---------------------------------------------------------------------------
+
+def _selection_bias(sel_ref, selects, causal, q0, k0):
+    """0 where a pair (key, query) of the tile is kept, ``MASKED`` where not,
+    float32 ``[tk, tq]``: kept = chosen by the indexer (or ``selects`` false:
+    the query tile is one that keeps every causal key), and, if ``causal``
+    (the diagonal crosses the tile), query >= key."""
+    keep = sel_ref[...].astype(jnp.int32) + jnp.where(selects, 0, 1) > 0
+    if causal:
+        q = q0 + lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+        k = k0 + lax.broadcasted_iota(jnp.int32, keep.shape, 0)
+        keep = jnp.logical_and(keep, q >= k)
+    return jnp.where(keep, 0.0, MASKED)
+
+
+def _when_selected(i, j, tq, tk, first, step):
+    """:func:`_when_needed` with the selection: ``step(bias_of)`` for a needed
+    tile, ``bias_of`` None (nothing masked: below the diagonal in a query tile
+    that ends at or before ``first``) or what gives :func:`_selection_bias`
+    from the mask tile's ref."""
+    selects = i * tq + tq > first
+
+    def tile(crossed):
+        if crossed:
+            step(partial(_selection_bias, selects=selects, causal=True, q0=i * tq, k0=j * tk))
+        else:
+            pl.when(selects)(partial(step, partial(
+                _selection_bias, selects=True, causal=False, q0=None, k0=None)))
+            pl.when(jnp.logical_not(selects))(partial(step, None))
+
+    _when_needed(i, j, tq, tk, tile)
+
+
+def _sel_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, q_s, m_s, l_s, acc_s, *,
+                    tq: int, tk: int, first: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+    G = q_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        q_s[...] = _side_by_side(q_ref)          # resident over the key tiles
+        m_s[...] = jnp.full_like(m_s, MASKED)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    def step(bias_of):
+        st = _dot(k_ref[...], q_s[...], _TN)                          # [tk, G * tq]
+        if bias_of is not None:
+            st = st + jnp.tile(bias_of(sel_ref), (1, G))
+        m_prev = m_s[...]                                             # [1, G * tq]
+        m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_next)
+        alpha = jnp.exp(m_prev - m_next)
+        l_s[...] = alpha * l_s[...] + jnp.sum(pt, axis=0, keepdims=True)
+        m_s[...] = m_next
+        v = v_ref[...]
+        acc_s[...] = alpha * acc_s[...] + _dot(v, pt.astype(v.dtype), _NN)  # [d, G * tq]
+
+    _when_selected(i, j, tq, tk, first, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l = l_s[...]
+        o = acc_s[...] / l
+        for g in range(G):
+            o_ref[g] = o[:, g * tq:(g + 1) * tq]
+        lse_ref[...] = m_s[...] + jnp.log(l)
+
+
+def _sel_bwd_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref, delta_ref,
+                    dq_ref, dk_ref, dv_ref, q_s, do_s, dq_s, *, tq: int, tk: int, first: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+    G = q_ref.shape[0]
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _():  # the key/value head's whole sequence, resident over (i, j)
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(j == 0)
+    def _():  # the group's query tile, resident over the key tiles
+        q_s[...] = _side_by_side(q_ref)
+        do_s[...] = _side_by_side(do_ref)
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    def step(bias_of):
+        q, do, k = q_s[...], do_s[...], k_ref[...]
+        st = _dot(k, q, _TN)                                          # [tk, G * tq]
+        if bias_of is not None:
+            st = st + jnp.tile(bias_of(sel_ref), (1, G))
+        pt = jnp.exp(st - lse_ref[...])                               # lse [1, G * tq]
+        cols = pl.ds(pl.multiple_of(j * tk, tk), tk)
+        dv_ref[:, cols] += _dot(do, pt.astype(do.dtype), _NT)         # [d, tk]
+        dst = (pt * (_dot(v_ref[...], do, _TN) - delta_ref[...])).astype(q.dtype)
+        dk_ref[:, cols] += _dot(q, dst, _NT)
+        dq_s[...] += _dot(k, dst, _NN)                                # [d, G * tq]
+
+    _when_selected(i, j, tq, tk, first, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        for g in range(G):
+            dq_ref[g] = dq_s[:, g * tq:(g + 1) * tq]
+
+
+def _sel_specs(G, d, tq, tk):
+    """Block specs of a grid step (row, key/value head, query tile ``i``, key
+    tile ``j``), both kernels': a group's query tiles, the key/value tile (one
+    above the diagonal is never fetched), the mask tile, the row statistics."""
+    def query(i, j):
+        return i
+
+    def key(i, j):
+        return jnp.minimum(j, (i * tq + tq - 1) // tk)
+
+    return (_lane_tile(G, d, tq, query), _lane_tile(None, d, tk, key),
+            pl.BlockSpec((None, tk, tq), lambda n, g, i, j: (n, key(i, j), i)),
+            _row_stat(G * tq, query))
+
+
+def _call_sel_fwd(q, k, v, sel, tq, tk, first, interpret):
+    N, H, d, S = q.shape
+    G = H // k.shape[1]
+    group, kv, mask, stat = _sel_specs(G, d, tq, tk)
+    return pl.pallas_call(
+        partial(_sel_fwd_kernel, tq=tq, tk=tk, first=first),
+        grid=(N, H // G, S // tq, S // tk),
+        in_specs=[group, kv, kv, mask],
+        out_specs=[group, stat],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((N, H // G, S // tq, 1, G * tq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, G * tq), q.dtype)]
+        + [pltpu.VMEM((1, G * tq), jnp.float32)] * 2 + [pltpu.VMEM((d, G * tq), jnp.float32)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="sel_attn_fwd",
+    )(q, k, v, sel)
+
+
+def _call_sel_bwd(q, k, v, sel, do, lse, delta, tq, tk, first, interpret):
+    N, H, d, S = q.shape
+    G = H // k.shape[1]
+    group, kv, mask, stat = _sel_specs(G, d, tq, tk)
+    whole = pl.BlockSpec((None, None, d, S), lambda n, g, i, j: (n, g, 0, 0))
+    f32 = jnp.float32
+    return pl.pallas_call(
+        partial(_sel_bwd_kernel, tq=tq, tk=tk, first=first),
+        grid=(N, H // G, S // tq, S // tk),
+        in_specs=[group, kv, kv, mask, group, stat, stat],
+        out_specs=[group, whole, whole],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32), jax.ShapeDtypeStruct(k.shape, f32),
+                   jax.ShapeDtypeStruct(v.shape, f32)],
+        scratch_shapes=[pltpu.VMEM((d, G * tq), q.dtype)] * 2
+        + [pltpu.VMEM((d, G * tq), f32)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="sel_attn_bwd",
+    )(q, k, v, sel, do, lse, delta)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _sel_flash(q, k, v, sel, tq, tk, first, interpret):
+    """:func:`_gq_flash` over the keys ``sel`` keeps: ``sel`` ``[N, S keys, S
+    queries]`` int8, read for the query tiles that end after ``first``."""
+    return _sel_flash_fwd(q, k, v, sel, tq, tk, first, interpret)[0]
+
+
+def _sel_flash_fwd(q, k, v, sel, tq, tk, first, interpret):
+    ops = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    o, lse = _call_sel_fwd(*ops, sel, tq, tk, first, interpret)
+    return o, (ops, sel, o, lse)
+
+
+def _sel_flash_bwd(tq, tk, first, interpret, res, do):
+    (q, k, v), sel, o, lse = res
+    N, H, _, S = q.shape
+    kv = k.shape[1]
+    delta = jnp.sum(o * do, axis=2).reshape(N, kv, H // kv, S // tq, tq)
+    delta = jnp.swapaxes(delta, 2, 3).reshape(lse.shape)  # the log-sum-exp's layout (_row_stat)
+    return _call_sel_bwd(q, k, v, sel, do.astype(jnp.bfloat16), lse, delta, tq, tk, first,
+                         interpret) + (None,)
+
+
+_sel_flash.defvjp(_sel_flash_fwd, _sel_flash_bwd)
+
+
+def sel_tile_for(S: int, d: int, group: int):
+    """:func:`gq_tile_for` for the selected kernels, (query tile, key tile):
+    head dims that fill the lanes, whole tiles of positions, and a group's
+    query tiles side by side no wider than 4,096 columns (a score tile
+    ``[key tile, group * query tile]`` float32 is then 8 MB of VMEM; eight
+    heads at 512 were 3 % ahead of 256 in the layer's block, PERF.md, PR 36)."""
+    if d % LANES:
+        return None
+    tk = next((t for t in TILES if S % t == 0), None)
+    tq = next((t for t in TILES if S % t == 0 and group * t <= 4096), None)
+    return None if tq is None else (tq, tk)
+
+
+def fused_selected_attention(q, k, v, scale, select, block: int, *, block_q: int, block_k: int,
+                             interpret: bool = False):
+    """``ops.layers.selected_gq_attention`` through the kernels above, its
+    operands and result in its layouts (heads first, ``[N, H, S, d]``) and
+    ``select`` as ``ops.layers.select_keys`` gives it for query blocks of
+    ``block`` rows: per block None (every causal key) or the 0/1 choice ``[N,
+    q, keys up to the block's end]``.  The blocks become one keys-first int8
+    mask ``[N, S, S]`` (a byte a pair; what lies above the diagonal or in a
+    None block is never looked at)."""
+    N, _, S, _ = q.shape
+    cols, first = [], 0
+    for i, m in enumerate(select):
+        start, end = i * block, min((i + 1) * block, S)
+        if m is None:
+            cols.append(jnp.ones((N, S, end - start), jnp.int8))
+            first = end if first == start else first
+        else:
+            cols.append(jnp.pad(jnp.swapaxes(m, 1, 2).astype(jnp.int8),
+                                ((0, 0), (0, S - end), (0, 0))))
+    sel = jnp.concatenate(cols, axis=2)
+    qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 2, 3) for x in (q, k, v))
+    return jnp.swapaxes(_sel_flash(qt * scale, kt, vt, sel, block_q, block_k, first, interpret),
+                        2, 3)

@@ -4,10 +4,18 @@ with the benchmark's seeded weights: how many (query, key) selections the
 program (`models.keye.index_keys`: `ops.layers.select_keys`, the exact top-k
 as counting passes) and the plain reference (`benchmark/reference/keye.py`:
 `lax.top_k` on its own scores) disagree on, layer by layer on one input; what
-the program's counters read; and what the selection costs against a sort.
-Three minutes:
+the program's counters read; what the selection costs against a sort; and one
+layer's SELECTED ATTENTION under layer 0's selection, the `jnp` block loop
+(`ops.layers.blockwise_gq_attention(select=)`) against the fused kernels
+(`ops/pallas_attention.py` `sel_attn_fwd` / `sel_attn_bwd`) at the tile the
+rule gives (`sel_tile_for`) and at any others named, forward and forward +
+backward, alone and inside the layer's attention BLOCK (`models.lfm2.
+gq_attention`: projections, head norms, turn, attention, output projection,
+under `jax.checkpoint` as a layer runs it), which is where a kernel's operand
+layout shows in its neighbours.  Four minutes:
 
-    chiprun -- python scripts/sparse_ab.py [seed]       # on the chip
+    chiprun -- python scripts/sparse_ab.py [seed] [256x512,128x512]   # on the chip
+    chiprun -- python scripts/sparse_ab.py attn [seed] [tiles]        # the attention part alone
     JAX_PLATFORMS=cpu python scripts/sparse_ab.py tiny  # the same code at the tests' size
 
 Every layer's indexer reads the SAME input here (the normed embedding of the
@@ -19,6 +27,7 @@ import json
 import os
 import sys
 import time
+from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -33,7 +42,9 @@ from heterofl_tpu import config as C  # noqa: E402
 from heterofl_tpu.entry.common import build_cli, cfg_from_args  # noqa: E402
 from heterofl_tpu.models import keye, make_model  # noqa: E402
 from heterofl_tpu.models.base import layer_leaves  # noqa: E402
+from heterofl_tpu.models.lfm2 import gq_attention  # noqa: E402
 from heterofl_tpu.ops import layers as L  # noqa: E402
+from heterofl_tpu.ops import pallas_attention as PA  # noqa: E402
 
 NAME = "keye-vl-2-30b-a3b.fix-a1-e1.train-8k"
 
@@ -50,8 +61,60 @@ def timed(fn, *args):
     return 1e3 * best
 
 
+def attention_times(a, lp, h, select, block, tiles, interpret):
+    """Milliseconds of one layer's selected attention at the cell's shapes
+    under ``select``: alone (operands heads first, seeded) and in the layer's
+    block on ``h``; the block loop, then the kernels at each of ``tiles``."""
+    H, Hkv, d, eps = a["num_attention_heads"], a["num_key_value_heads"], a["head_dim"], \
+        a["rms_norm_eps"]
+    S, scale = h.shape[1], d ** -0.5
+    q, k, v, probe = (jax.random.normal(key, (1, n, S, d)) for key, n in
+                      zip(jax.random.split(jax.random.key(7), 4), (H, Hkv, Hkv, H)))
+    w = jax.random.normal(jax.random.key(8), h.shape)
+
+    forms = {"jnp": lambda q, k, v, scale: L.blockwise_gq_attention(q, k, v, scale, block, select)}
+    forms.update({f"fused{tq}x{tk}": partial(PA.fused_selected_attention, select=select,
+                                             block=block, block_q=tq, block_k=tk,
+                                             interpret=interpret) for tq, tk in tiles})
+
+    def alone(attend, grad):
+        def loss(q, k, v):
+            return jnp.sum(attend(q, k, v, scale) * probe)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)) if grad else loss)
+
+    def in_block(attend, grad):
+        ones = jnp.ones((d,))
+
+        @jax.checkpoint
+        def layer(lp, h):
+            return gq_attention(
+                lp, h, heads=H, kv_heads=Hkv, head_dim=d, theta=a["rope_theta"], scale=scale,
+                sc=lambda x: x, attend=attend,
+                head_norm=lambda x, g: L.masked_rms_norm(x, g, ones, ones.size, eps))
+
+        def loss(lp, h):
+            return jnp.sum(layer(lp, h) * w)
+        return jax.jit(jax.grad(loss, argnums=(0, 1)) if grad else loss)
+
+    attn = {n: x for n, x in lp.items() if n.startswith("attn.")}
+    out, first = {}, None
+    for name, attend in forms.items():
+        fb, blk = alone(attend, True), in_block(attend, True)
+        out[name] = {"fwd": timed(alone(attend, False), q, k, v), "fwd_bwd": timed(fb, q, k, v),
+                     "block_fwd": timed(in_block(attend, False), attn, h),
+                     "block_fwd_bwd": timed(blk, attn, h)}
+        got = jax.tree_util.tree_leaves((fb(q, k, v), blk(attn, h)))
+        first = first or got
+        out[name]["gap"] = max(float(jnp.abs(x - y).max() / jnp.abs(y).max())
+                               for x, y in zip(got, first))
+        print(f"selected attention {name}: " + json.dumps(out[name]), flush=True)
+    return out
+
+
 def main(argv):
     tiny = "tiny" in argv
+    tiles = [tuple(int(t) for t in pair.split("x"))
+             for a_ in argv if "x" in a_ for pair in a_.split(",")]
     seed = int(next((a for a in argv if a.isdigit()), 3500000101))
     if tiny:
         from benchmark.tests import tiny_keye
@@ -93,47 +156,64 @@ def main(argv):
             jax.lax.dynamic_slice_in_dim(w_i, s, block, 1), s + jnp.arange(block)),
             a["index_topk"]), jnp.arange(0, S, block))
 
+    def compare(out):
+        for i in range(a["num_hidden_layers"]):
+            lp = layer_leaves(params, i, model.meta["held_experts"])
+            masks, pairs = program(lp, h)
+            mine = causal.copy()
+            first = S - sum(m.shape[1] for m in masks)  # blocks before it keep every causal key
+            for m in masks:
+                rows = slice(first, first + m.shape[1])
+                mine[rows, :m.shape[2]] &= np.asarray(m[0])
+                first += m.shape[1]
+            theirs = np.asarray(reference(lp, h))[:, 0].reshape(S, S) & causal
+            differ = int(np.count_nonzero(mine != theirs))
+            out["layers"].append({"layer": i, "selected": int(mine.sum()), "reference": int(theirs.sum()),
+                                  "pairs_that_differ": differ,
+                                  "queries_that_differ": int(np.count_nonzero((mine != theirs).any(1))),
+                                  "program_counts": [float(p) for p in pairs]})
+            print(f"layer {i}: program selects {mine.sum()} of {causal.sum()} causal pairs "
+                  f"({mine.sum() / S:.1f} a query), reference {theirs.sum()}; they differ on {differ} "
+                  f"pairs in {out['layers'][-1]['queries_that_differ']} queries", flush=True)
+
+        # what one forward pass of the model counts (the obs_ counters' source)
+        res, _ = jax.jit(lambda p, t: model.apply(p, {"label": t}, train=False))(params, tokens)
+        c = {k: np.asarray(v, np.float64) for k, v in res["counters"].items()}
+        for name in ("sparse_selected", "sparse_kept_share", "sparse_fused"):
+            out[name] = float(c[name][0] / c[name][1])
+        print(f"counters of a forward pass: sparse_selected {out['sparse_selected']:.4f} keys a "
+              f"query, sparse_kept_share {out['sparse_kept_share']:.6f}, sparse_fused "
+              f"{out['sparse_fused']:.1f} of the query tiles through the kernels", flush=True)
+
+        # the selection against a sort, on the last (widest) block's scores
+        lp = layer_leaves(params, 0, model.meta["held_experts"])
+        q_i, k_i, w_i = jax.jit(common.highest(lambda lp, h: ref.indexer(lp, h, dict(arch))))(lp, h)
+        scores = ref.index_scores(q_i[:, S - block:], k_i, w_i[:, S - block:], jnp.arange(S - block, S))
+        k = a["index_topk"]
+        out["ms"] = {
+            "top_k_mask": timed(jax.jit(lambda x: L.top_k_mask(x, k)), scores),
+            "lax.top_k": timed(jax.jit(lambda x: jax.lax.top_k(x, k)), scores),
+            "reference.selected": timed(jax.jit(lambda x: ref.selected(x, k)), scores),
+            "program.index_keys": timed(program, lp, h),
+            "reference.indexer+selected": timed(reference, lp, h)}
+        print("ms:", json.dumps(out["ms"]), flush=True)
+
     out = {"seed": seed, "device": jax.devices()[0].device_kind, "positions": S,
            "topk": a["index_topk"], "layers": []}
-    for i in range(a["num_hidden_layers"]):
-        lp = layer_leaves(params, i, model.meta["held_experts"])
-        masks, pairs = program(lp, h)
-        mine = causal.copy()
-        first = S - sum(m.shape[1] for m in masks)  # blocks before it keep every causal key
-        for m in masks:
-            rows = slice(first, first + m.shape[1])
-            mine[rows, :m.shape[2]] &= np.asarray(m[0])
-            first += m.shape[1]
-        theirs = np.asarray(reference(lp, h))[:, 0].reshape(S, S) & causal
-        differ = int(np.count_nonzero(mine != theirs))
-        out["layers"].append({"layer": i, "selected": int(mine.sum()), "reference": int(theirs.sum()),
-                              "pairs_that_differ": differ,
-                              "queries_that_differ": int(np.count_nonzero((mine != theirs).any(1))),
-                              "program_counts": [float(p) for p in pairs]})
-        print(f"layer {i}: program selects {mine.sum()} of {causal.sum()} causal pairs "
-              f"({mine.sum() / S:.1f} a query), reference {theirs.sum()}; they differ on {differ} "
-              f"pairs in {out['layers'][-1]['queries_that_differ']} queries", flush=True)
-
-    # what one forward pass of the model counts (the obs_ counters' source)
-    res, _ = jax.jit(lambda p, t: model.apply(p, {"label": t}, train=False))(params, tokens)
-    c = {k: np.asarray(v, np.float64) for k, v in res["counters"].items()}
-    out["sparse_selected"] = float(c["sparse_selected"][0] / c["sparse_selected"][1])
-    out["sparse_kept_share"] = float(c["sparse_kept_share"][0] / c["sparse_kept_share"][1])
-    print(f"counters of a forward pass: sparse_selected {out['sparse_selected']:.4f} keys a "
-          f"query, sparse_kept_share {out['sparse_kept_share']:.6f}", flush=True)
-
-    # the selection against a sort, on the last (widest) block's scores
+    if "attn" not in argv:
+        compare(out)
+    # one layer's selected attention under layer 0's selection
     lp = layer_leaves(params, 0, model.meta["held_experts"])
-    q_i, k_i, w_i = jax.jit(common.highest(lambda lp, h: ref.indexer(lp, h, dict(arch))))(lp, h)
-    scores = ref.index_scores(q_i[:, S - block:], k_i, w_i[:, S - block:], jnp.arange(S - block, S))
-    k = a["index_topk"]
-    out["ms"] = {
-        "top_k_mask": timed(jax.jit(lambda x: L.top_k_mask(x, k)), scores),
-        "lax.top_k": timed(jax.jit(lambda x: jax.lax.top_k(x, k)), scores),
-        "reference.selected": timed(jax.jit(lambda x: ref.selected(x, k)), scores),
-        "program.index_keys": timed(program, lp, h),
-        "reference.indexer+selected": timed(reference, lp, h)}
-    print("ms:", json.dumps(out["ms"]), flush=True)
+    select = jax.jit(lambda lp, h: keye.index_keys(
+        lp, h, heads=a["index_n_heads"], head_dim=a["index_head_dim"], theta=a["rope_theta"],
+        topk=a["index_topk"], block=block,
+        key_norm=lambda x, g, b: L.masked_layer_norm(x, g, b, jnp.ones(x.shape[-1:]),
+                                                     x.shape[-1], eps))[0])(lp, h)
+    rule = PA.sel_tile_for(S, a["head_dim"], a["num_attention_heads"] // a["num_key_value_heads"])
+    print(f"the rule's tile at {S} positions: {rule}", flush=True)
+    tiles = ([] if rule is None else [rule]) + [t for t in tiles if t != rule]
+    out["tile"], out["attention_ms"] = rule, attention_times(
+        a, lp, h, select, block, tiles, interpret=jax.default_backend() != "tpu")
     os.makedirs("chiprun_out", exist_ok=True)
     with open(os.path.join("chiprun_out", "sparse_ab.json"), "w", encoding="utf-8") as f:
         json.dump(out, f, indent=1)

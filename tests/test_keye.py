@@ -563,24 +563,69 @@ def test_keye_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_roun
 
 def test_keye_counters_ride_the_metrics():
     """telemetry='on' carries the indexer's counters out beside the expert
-    layers': `obs_sparse_selected` and `obs_sparse_kept_share`, each a
-    (numerator, denominator) pair of sums a device, finished by
-    `obs.split_probes` as keys selected a query and selected over causal
-    pairs -- at 64 positions and ``topk`` 16: 904 / 64 and 904 / 2,080."""
+    layers': `obs_sparse_selected`, `obs_sparse_kept_share` and
+    `obs_sparse_fused`, each a (numerator, denominator) pair of sums a device,
+    finished by `obs.split_probes` as keys selected a query, selected over
+    causal pairs -- at 64 positions and ``topk`` 16: 904 / 64 and 904 / 2,080
+    -- and the share of the selected attention's query tiles that went
+    through the fused kernels: none on the CPU."""
     from heterofl_tpu.obs import split_probes
 
     cfg, data = _round_case()
     _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
     assert ms["obs_sparse_selected"].shape == ms["obs_sparse_kept_share"].shape == (2 * 2,)
+    # 8 clients x 2 layers x 2 rows x 4 query blocks of 16, none through the kernels
+    assert ms["obs_sparse_fused"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 2 * 2 * 4]
     assert ms["obs_moe_tokens"].shape == (2 * 4,) and ms["obs_moe_assign"].shape == (2 * 3,)
     clean, rounds = split_probes(dict(ms), 2)
     rec = rounds[0]
     assert rec["sparse_selected"] == pytest.approx(904 / 64, rel=1e-6)
     assert rec["sparse_kept_share"] == pytest.approx(904 / 2080, rel=1e-6)
+    assert rec["sparse_fused"] == 0.0
     # 8 clients x 1 step x (2 rows x 64 tokens) x top-2, in each of 2 layers
     assert rec["moe_assign"][0] == 8 * 128 * 2 * 2
     assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
     assert not [k for k in clean if k.startswith("obs_")]
+
+
+def test_keye_model_takes_the_selected_kernels_where_a_tpu_gives_them_tiles(monkeypatch):
+    """The model at shapes the fused kernels tile (heads of 128, rows of 256
+    positions, ``topk`` 128) with jax reporting a TPU -- the kernels in
+    interpret mode, the one thing steered here: loss and every leaf's
+    gradient are the block loop's of the same model on the CPU to the
+    kernels' bfloat16 probabilities, the indexer's leaves still get exactly
+    zero, and `sparse_fused` counts every query tile (2 layers x 2 rows x 2
+    blocks of 128), which `obs.split_probes` finishes as 1.0."""
+    from functools import partial
+
+    from heterofl_tpu.obs import split_probes
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    _, model, params, tokens, lm, _ = _keye_case(bptt=256, head_dim=128, index_topk=128)
+
+    def loss_grads_counters():
+        def loss(p):
+            out, _ = model.apply(p, {"label": tokens}, train=True, label_mask=lm)
+            return out["loss"], out["counters"]
+        (value, counters), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        return value, grads, counters
+
+    want, want_grads, on_cpu = loss_grads_counters()
+    assert [float(c) for c in on_cpu["sparse_fused"]] == [0.0, 8.0]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(PA, "fused_selected_attention",
+                        partial(PA.fused_selected_attention, interpret=True))
+    got, got_grads, counters = loss_grads_counters()
+    assert [float(c) for c in counters["sparse_fused"]] == [8.0, 8.0]
+    _, rounds = split_probes({"obs_sparse_fused": np.asarray(counters["sparse_fused"])}, 1)
+    assert rounds[0]["sparse_fused"] == 1.0
+    assert float(got) == pytest.approx(float(want), rel=2e-3)
+    for name, w in want_grads.items():
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(got_grads[name], w, rtol=0, atol=3e-2 * scale + 1e-12,
+                                   err_msg=name)
+        if ".idx." in name:
+            assert not np.any(got_grads[name]), name
 
 
 def test_keye_trains_and_evaluates_through_the_entry_point(tmp_path):

@@ -1,6 +1,6 @@
 """The fused causal attentions (ops/pallas_attention.py) against their
 oracles, the ``jnp`` forms ``ops.layers.blockwise_latent_attention`` and
-``blockwise_gq_attention``:
+``blockwise_gq_attention`` (with ``select=`` for the selected kernels):
 on the CPU the kernels run in interpret mode, which says that the tiling,
 the skipped and the masked tiles, the online softmax and the hand-written
 backward are right; what Mosaic accepts is tests/test_tpu_compile.py's.
@@ -149,20 +149,145 @@ def test_fused_attention_is_the_blockwise_attention(case):
         _close(_out_and_grads(fused(128, 128), (q, k, v), probe, SCALE), [o, dq] + over_group)
 
 
+# ---------------------------------------------------------------------------
+# the third family: grouped-query attention over the keys an indexer selected
+# (``sel_attn_fwd`` / ``sel_attn_bwd``), at the Keye cell's head geometry
+# scaled down: eight query heads of 128 a key/value head
+# ---------------------------------------------------------------------------
+
+def _sel_operands(key, N=1, S=1024, G=8, Hkv=1, d=128, active=None):
+    """q, k, v heads first and a probe of the output's shape."""
+    return _gq_operands(key, N=N, S=S, H=G * Hkv, Hkv=Hkv, d=d, active=active)
+
+
+def _indexer_selection(key, N, S, topk, block):
+    """What ``select_keys`` gives a random indexer: per query block of
+    ``block`` rows None (the block ends at or before ``topk``) or its 0/1
+    choice of ``topk`` of the keys up to the block's end."""
+    qi, ki, wi = (jax.random.normal(k, s) for k, s in
+                  zip(jax.random.split(key, 3), [(N, 2, S, 8), (N, S, 8), (N, 2, S)]))
+    return L.select_keys(qi, ki, wi, topk, block)[0]
+
+
+def _sel_fused(select, block, bq, bk):
+    return lambda *a: PA.fused_selected_attention(*a, select, block, block_q=bq, block_k=bk,
+                                                  interpret=True)
+
+
+def _sel_oracle(select, block):
+    return lambda *a: L.blockwise_gq_attention(*a, block, select)
+
+
+FAMILIES["sel"] = (_sel_operands, _sel_fused, _sel_oracle)
+
+
+@pytest.mark.parametrize("case", [
+    "tiles-512x512", "tiles-256x256", "tiles-128x128", "tiles-256x512-group-16", "tiles-128x256",
+    "masked-first-tiles", "keeps-every-key", "vmap-clients", "narrow-client"])
+def test_selected_kernels_are_the_block_loop_under_the_selection(case):
+    """Output and the gradients of ``q``, ``k``, ``v`` against
+    ``blockwise_gq_attention(select=)``: at every (query, key) tile the rule
+    ``sel_tile_for`` can return (rows of several tiles, ``topk`` of two query
+    tiles, so that tiles with and without a selection both occur, on and off
+    the diagonal), and at a key tile wider than the query tile's; for queries
+    NONE of whose selected keys lies in their first key tiles (the running
+    max is still ``MASKED`` there: what the sum collects is wiped by the
+    first real score); a selection that keeps every causal key, bit-equal in
+    the forward to the kernels given no selection at all; under ``vmap`` over
+    clients with a per-client selection and scale; zero-suffix head dims."""
+    operands, fused, oracle = FAMILIES["sel"]
+    if case.startswith("tiles-"):
+        bq, bk = (int(t) for t in case.split("-")[1].split("x"))
+        G = 16 if case.endswith("group-16") else 8
+        S = {"512x512": 1536, "256x256": 768, "128x128": 384, "256x512": 1024, "128x256": 512}[
+            f"{bq}x{bk}"]
+        assert PA.sel_tile_for(S, 128, G) == (bq, bk) or (bq, bk) == (128, 256)
+        ops, probe = operands(jax.random.key(1), S=S, G=G)
+        select = _indexer_selection(jax.random.key(11), 1, S, 2 * bq, bq)
+        assert select[1] is None and select[2] is not None
+        _close(_out_and_grads(fused(select, bq, bq, bk), ops, probe, SCALE),
+               _out_and_grads(oracle(select, bq), ops, probe, SCALE))
+    elif case == "masked-first-tiles":
+        # every query from 128 on selects the 128 keys that end with itself, so all of
+        # the key tiles before its diagonal's neighbour are fully masked for it
+        S, t = 512, 128
+        ops, probe = operands(jax.random.key(2), S=S)
+        window = (jnp.arange(S)[:, None] - jnp.arange(S)[None, :] < t)[None]
+        select = [None] + [window[:, i * t:(i + 1) * t, :(i + 1) * t] for i in range(1, S // t)]
+        assert not np.any(select[3][:, :, :2 * t])  # two whole tiles without a selected key
+        got = _out_and_grads(fused(select, t, t, t), ops, probe, SCALE)
+        _close(got, _out_and_grads(oracle(select, t), ops, probe, SCALE))
+        assert all(np.all(np.isfinite(x)) for x in got)
+    elif case == "keeps-every-key":
+        S, t = 512, 128
+        ops, probe = operands(jax.random.key(3), S=S)
+        every = [None, None] + [jnp.ones((1, t, (i + 1) * t), bool) for i in range(2, S // t)]
+        for bq, bk in ((128, 128), (128, 256)):
+            a = fused(every, t, bq, bk)(*ops, SCALE)
+            np.testing.assert_array_equal(a, fused([None] * (S // t), t, bq, bk)(*ops, SCALE))
+        _close(_out_and_grads(fused(every, t, t, t), ops, probe, SCALE),
+               _out_and_grads(lambda *a: L.blockwise_gq_attention(*a, t), ops, probe, SCALE))
+    elif case == "vmap-clients":
+        S, t = 384, 128
+        clients = [operands(k, S=S, G=2) for k in jax.random.split(jax.random.key(4), 3)]
+        ops = [jnp.stack(x) for x in zip(*(c[0] for c in clients))]
+        probe = jnp.stack([c[1] for c in clients])
+        scales = jnp.asarray([0.125, 0.25, 0.0625])
+        selects = [_indexer_selection(k, 1, S, t, t) for k in jax.random.split(jax.random.key(5), 3)]
+        masks = [jnp.stack(m) for m in zip(*(s[1:] for s in selects))]  # block 0 has none
+
+        def over_clients(attention):
+            return jax.vmap(lambda o, p, s, *m: _out_and_grads(attention([None, *m], t), o, p, s))(
+                ops, probe, scales, *masks)
+
+        _close(over_clients(lambda select, block: fused(select, block, t, t)), over_clients(oracle))
+    else:
+        S, t = 256, 128
+        ops, probe = operands(jax.random.key(6), S=S, active=8)
+        select = _indexer_selection(jax.random.key(7), 1, S, t, t)
+        got = _out_and_grads(fused(select, t, t, t), ops, probe, 0.25)
+        _close(got, _out_and_grads(oracle(select, t), ops, probe, 0.25))
+        assert not any(np.any(x[..., 8:]) for x in got)  # o, dq, dk, dv
+
+
+def _sel_shapes(S, d, topk=256, block=128):
+    """Shapes of ``selected_gq_attention``'s operands at 8 query heads on 2
+    key/value heads of ``d`` and of the selection's blocks (None: no mask)."""
+    return ([(1, 8, S, d), (1, 2, S, d), (1, 2, S, d)],
+            [None if start + block <= topk else (1, min(block, S - start), min(start + block, S))
+             for start in range(0, S, block)])
+
+
+def _sel_jaxpr(attention, S, d, block=128):
+    """The jaxpr of ``attention(q, k, v, 0.1, select, block)`` on shapes."""
+    shapes, blocks = _sel_shapes(S, d, block=block)
+
+    def run(q, k, v, *masks):
+        it = iter(masks)
+        return attention(q, k, v, 0.1, [None if b is None else next(it) for b in blocks], block)
+
+    return jax.make_jaxpr(run)(*(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes),
+                               *(jax.ShapeDtypeStruct(b, bool) for b in blocks if b is not None))
+
+
 def _calls(family, S, dims, backend, monkeypatch):
     """Names of the ``pallas_call``s in the family's attention function's
     program (``causal_latent_attention`` at head dims ``dn, dr, dv``,
-    ``causal_gq_attention`` at ``d``) when jax reports ``backend``."""
+    ``causal_gq_attention`` / ``selected_gq_attention`` at ``d``) when jax
+    reports ``backend``."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    if family == "latent":
-        dn, dr, dv = dims
-        attention = L.causal_latent_attention
-        shapes = [(1, 2, S, dn), (1, 2, S, dr), (1, 2, S, dn), (1, S, dr), (1, 2, S, dv)]
+    if family == "sel":
+        jaxpr = _sel_jaxpr(L.selected_gq_attention, S, dims)
     else:
-        attention = L.causal_gq_attention
-        shapes = [(1, 8, S, dims), (1, 2, S, dims), (1, 2, S, dims)]
-    jaxpr = jax.make_jaxpr(lambda *a: attention(*a, 0.1))(
-        *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
+        if family == "latent":
+            dn, dr, dv = dims
+            attention = L.causal_latent_attention
+            shapes = [(1, 2, S, dn), (1, 2, S, dr), (1, 2, S, dn), (1, S, dr), (1, 2, S, dv)]
+        else:
+            attention = L.causal_gq_attention
+            shapes = [(1, 8, S, dims), (1, 2, S, dims), (1, 2, S, dims)]
+        jaxpr = jax.make_jaxpr(lambda *a: attention(*a, 0.1))(
+            *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
     text = str(jaxpr)
     assert text.count("pallas_call") == text.count("_attn_")
     return re.findall(r"\w+_attn_\w+", text)
@@ -181,13 +306,33 @@ def _calls(family, S, dims, backend, monkeypatch):
     ("gq", 500, 64, "tpu", False),
     ("gq", 2048, 4, "tpu", False),                    # a rate-1/16 client's own width
     ("gq", 2048, 32, "tpu", False),                   # a rate-1/2 client's
+    ("sel", 1024, 128, "tpu", True),                  # the Keye cell's head dim
+    ("sel", 384, 256, "tpu", True),                   # 128-position tiles
+    ("sel", 1024, 128, "cpu", False),
+    ("sel", 500, 128, "tpu", False),
+    ("sel", 1024, 8, "tpu", False),                   # a rate-1/16 client's own width
+    ("sel", 1024, 64, "tpu", False),                  # a rate-1/2 client's: half the lanes
 ], ids=["cell-dims", "tile-128", "cpu", "ragged-positions", "narrow-widths", "half-lane-values",
         "gq-cell-dims", "gq-tile-128", "gq-cpu", "gq-ragged-positions", "gq-narrow-widths",
-        "gq-half-width"])
+        "gq-half-width", "sel-cell-dims", "sel-tile-128", "sel-cpu", "sel-ragged-positions",
+        "sel-narrow-widths", "sel-half-width"])
 def test_which_form_runs_is_decided_by_backend_and_shapes(family, S, dims, backend, fused,
                                                           monkeypatch):
-    kernel = {"latent": "latent_attn_fwd", "gq": "gq_attn_fwd"}[family]
+    kernel = {"latent": "latent_attn_fwd", "gq": "gq_attn_fwd", "sel": "sel_attn_fwd"}[family]
     assert _calls(family, S, dims, backend, monkeypatch) == ([kernel] if fused else [])
+
+
+@pytest.mark.parametrize("backend, d", [("cpu", 128), ("tpu", 8)], ids=["cpu", "narrow-slice"])
+def test_selected_attention_falls_back_to_the_parents_block_loop(backend, d, monkeypatch):
+    """Off a TPU, and for a client's narrow slice at its own widths on one,
+    ``selected_gq_attention`` traces to the program it was before the kernels:
+    ``blockwise_gq_attention`` with the selection, the jaxpr equal as text."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert L.selected_attention_tile(1024, d, 4) is None
+    got = _sel_jaxpr(L.selected_gq_attention, 1024, d)
+    want = _sel_jaxpr(lambda q, k, v, scale, select, block: L.blockwise_gq_attention(
+        q, k, v, scale, block, select), 1024, d)
+    assert str(got) == str(want) and "pallas_call" not in str(got)
 
 
 @pytest.mark.parametrize("rate, compute_dtype", [(1.0, None), (0.25, None), (0.5, jnp.bfloat16)],

@@ -106,39 +106,71 @@ def test_pallas_norm_compiles(one_chip, n, h, c):
              sds(SLOTS, c), sds(SLOTS, n), kernels=bn)
 
 
+#: the Keye cell's selected attention: one row of 8,192 positions, 32 query heads on 4
+#: key/value heads of 128, the indexer's top-2,048 in query blocks of 512
+KEYE = dict(N=1, S=8192, H=32, Hkv=4, hd=128, topk=2048, block=512)
+
+
+def _keye_selection(lead=()):
+    """(per block None or the shape of its 0/1 choice, as ``select_keys`` gives
+    them at the Keye cell's shape; ``rebuild(masks)`` the list with its Nones)."""
+    N, S, topk, block = (KEYE[k] for k in ("N", "S", "topk", "block"))
+    blocks = [None if end <= topk else lead + (N, block, end)
+              for end in range(block, S + block, block)]
+
+    def rebuild(masks):
+        it = iter(masks)
+        return [None if b is None else next(it) for b in blocks]
+
+    return [b for b in blocks if b is not None], rebuild
+
+
 @pytest.mark.parametrize("family, vmapped", [("latent", False), ("latent", True), ("gq", False),
-                                             ("gq", True)],
-                         ids=["cell", "vmap10", "gq-cell", "gq-vmap10"])
+                                             ("gq", True), ("sel", False), ("sel", True)],
+                         ids=["cell", "vmap10", "gq-cell", "gq-vmap10", "sel-cell", "sel-vmap10"])
 def test_attention_kernels_compile_under_attn(one_chip, family, vmapped, monkeypatch):
     """The fused causal attentions, forward and backward, as the models call
     them (the described chip is not the default backend, so the test steers
     the one question the functions ask), bare and under ``vmap`` over client
     slots with a per-client scale: latent attention at the Kanana-2 cell's
     shapes (2 rows x 2,048 positions, 32 heads, 128 | 64 | 128 head dims,
-    heads first) and grouped-query attention at the LFM2 cell's (32 query
-    heads on 8 key/value heads of 64); and both custom calls carry the
-    ``attn`` scope, forward and transposed, by which the traced run's metrics
-    find them."""
-    from heterofl_tpu.ops.layers import causal_gq_attention, causal_latent_attention
+    heads first), grouped-query attention at the LFM2 cell's (32 query
+    heads on 8 key/value heads of 64) and the selected attention at the Keye
+    cell's REAL shape (:data:`KEYE`, a per-client selection: a backward that
+    does not fit VMEM fails here, before any chip call); and both custom calls
+    carry the ``attn`` scope, forward and transposed, by which the traced
+    run's metrics find them."""
+    from heterofl_tpu.ops.layers import (causal_gq_attention, causal_latent_attention,
+                                         selected_gq_attention)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     N, S, H, dn, dr, dv, Hkv, hd = 2, 2048, 32, 128, 64, 128, 8, 64
+    lead = (SLOTS,) if vmapped else ()
+    masks, rebuild = _keye_selection()
+    K = KEYE
+
+    def selected(q, k, v, *rest):
+        return selected_gq_attention(q, k, v, rest[-1], rebuild(rest[:-1]), K["block"])
+
     attention, shapes, widest = {
         "latent": (causal_latent_attention, ((N, H, S, dn), (N, H, S, dr), (N, H, S, dn),
                                              (N, S, dr), (N, H, S, dv)), max(dn, dv)),
         "gq": (causal_gq_attention, ((N, H, S, hd), (N, Hkv, S, hd), (N, Hkv, S, hd)), hd),
+        "sel": (selected, ((K["N"], K["H"], K["S"], K["hd"]),)
+                + ((K["N"], K["Hkv"], K["S"], K["hd"]),) * 2, K["hd"]),
     }[family]
     fwd, bwd = {"latent": ("latent_attn_fwd", "latent_attn_bwd"),
-                "gq": ("gq_attn_fwd", "gq_attn_bwd")}[family]
+                "gq": ("gq_attn_fwd", "gq_attn_bwd"), "sel": ("sel_attn_fwd", "sel_attn_bwd")}[family]
 
     def grads(*a):
-        *ops, scale = a
-        return jax.grad(lambda *o: jnp.sum(attention(*o, scale) ** 2),
+        ops, rest = a[:len(shapes)], a[len(shapes):]
+        return jax.grad(lambda *o: jnp.sum(attention(*o, *rest) ** 2),
                         argnums=tuple(range(len(ops))))(*ops)
 
-    lead = (SLOTS,) if vmapped else ()
-    avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip)
-             for s in shapes + ((),)]
+    avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip) for s in shapes] \
+        + [jax.ShapeDtypeStruct(lead + m, bool, sharding=one_chip)
+           for m in (masks if family == "sel" else ())] \
+        + [jax.ShapeDtypeStruct(lead, jnp.float32, sharding=one_chip)]
     text = _compile(jax.vmap(grads) if vmapped else grads, *avals, kernels=(fwd, bwd))
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
@@ -148,9 +180,11 @@ def test_attention_kernels_compile_under_attn(one_chip, family, vmapped, monkeyp
     assert re.search(rf"transpose\((vmap\()?jvp\(attn\)\)+/{bwd}/pallas_call$", op_names[1])
     # no [rows, heads, queries, keys] score block goes through HBM: nothing of 128 or
     # more queries is wider than ``widest``, the kernels' own results (a head's dims;
-    # the grouped kernels' are [2, 32, 64, 2048], positions minor)
-    assert not [m for m in re.findall(r"f32\[(?:10,)?2,(?:32|8,4),(\d+),(\d+)\]", text)
-                if int(m[0]) >= 128 and int(m[1]) > widest]
+    # the grouped kernels' are [2, 32, 64, 2048], positions minor; the selected
+    # kernels' [1, 32, 128, 8192], where what is held is a block of 512 queries)
+    found = re.findall(r"f32\[(?:10,)?[12],(?:32|8,4|4,8),(\d+),(\d+)\]", text)
+    assert found and not [m for m in found if int(m[0]) >= (512 if family == "sel" else 128)
+                          and int(m[1]) > widest]
 
 
 #: bytes of an element, for the shapes a relayout of the block can have
@@ -289,3 +323,55 @@ def test_lfm2_mixers_compile_with_their_relayouts_listed(one_chip, monkeypatch, 
             re.search(r"attn\)*/gq_attn_(fwd|bwd)/pallas_call$", c) for c in calls), calls
         assert not re.search(r"f32\[2,8,4,256,\d+\]", text)  # the block loop's scores
         assert not [line for _, line in relayouts if "/attn/" in line], listing  # add_any: gone
+
+
+def test_keye_attention_layer_compiles_with_the_selected_kernels(one_chip, monkeypatch):
+    """A layer's indexer and selected attention of the Keye cell
+    (``models.keye.index_keys``, then ``models.lfm2.gq_attention`` with
+    ``selected_gq_attention``) at the cell's REAL shapes (:data:`KEYE`; hidden
+    2,048, an indexer of 16 heads of 64), under ``jax.checkpoint`` and
+    ``jax.grad`` as a layer of the model runs it, for the described chip: three
+    custom calls under ``attn`` (forward, rematerialised forward, backward),
+    ``sel_attn_fwd`` / ``sel_attn_bwd``, and none of the block loop's float32
+    score blocks ``[.., 512, k]`` with ``k`` > 512 under ``attn`` (the
+    indexer's own, under ``sparse/index``, stay: they decide the choice)."""
+    from functools import partial
+
+    from heterofl_tpu.models.keye import index_keys
+    from heterofl_tpu.models.lfm2 import gq_attention
+    from heterofl_tpu.ops.layers import masked_layer_norm, masked_rms_norm, selected_gq_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    K, D, Hi, di = KEYE, 2048, 16, 64
+    H, Hkv, hd = K["H"], K["Hkv"], K["hd"]
+    shapes = {"attn.q.w": (D, H * hd), "attn.k.w": (D, Hkv * hd), "attn.v.w": (D, Hkv * hd),
+              "attn.q_norm.g": (hd,), "attn.k_norm.g": (hd,), "attn.o.w": (H * hd, D),
+              "idx.q.w": (D, Hi * di), "idx.k.w": (D, di), "idx.w.w": (D, Hi),
+              "idx.k_norm.g": (di,), "idx.k_norm.b": (di,)}
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    @jax.checkpoint
+    def block(lp, h, scale, rate):
+        select, _ = index_keys(
+            lp, h, heads=Hi, head_dim=di, theta=1e7, topk=K["topk"], block=K["block"],
+            key_norm=lambda x, g, b: masked_layer_norm(x, g, b, jnp.ones((di,)), jnp.float32(di)))
+        return gq_attention(
+            lp, h, heads=H, kv_heads=Hkv, head_dim=hd, theta=1e7, scale=scale,
+            sc=lambda x: x / rate,
+            head_norm=lambda x, g: masked_rms_norm(x, g, jnp.ones((hd,)), jnp.float32(hd)),
+            attend=partial(selected_gq_attention, select=select, block=K["block"]))
+
+    text = _compile(jax.grad(lambda *a: jnp.sum(block(*a) ** 2), argnums=(0, 1)),
+                    {k: sds(*s) for k, s in shapes.items()}, sds(K["N"], K["S"], D), sds(), sds(),
+                    kernels=("sel_attn_fwd", "sel_attn_bwd"))
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 3 and all(
+        re.search(r"attn\)*/sel_attn_(fwd|bwd)/pallas_call$", c) for c in calls), calls
+    blocks = [line.strip()[:200] for line in text.splitlines()
+              if re.search(r"= f32\[[\d,]*512,(\d+)\]", line)
+              and int(re.search(r"= f32\[[\d,]*512,(\d+)\]", line).group(1)) > 512
+              and "attn" in line and "sparse/" not in line]
+    assert not blocks, "\n".join(blocks)
