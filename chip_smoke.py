@@ -371,11 +371,10 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     args = ap.parse_args(argv)
 
-    from heterofl_tpu.utils.compile_cache import (enable_persistent_cache,
-                                                  install_cache_counters)
+    from heterofl_tpu.obs import spans
+    from heterofl_tpu.utils.compile_cache import enable_persistent_cache
 
-    cache_dir = enable_persistent_cache()
-    counters = install_cache_counters()
+    cache_dir = enable_persistent_cache()  # ... and the span record's listeners
     import jax
 
     devs = jax.devices()
@@ -409,9 +408,12 @@ def main(argv=None):
                                      f"chip_smoke_{args.chips}_runs"),
                         dirs_exist_ok=True)
     peak = devs[0].memory_stats()["peak_bytes_in_use"]
+    counters = spans.RECORD.counters
     print(f"chip_smoke: total {time.time() - t0:.1f}s; compile cache requests "
-          f"{counters['requests']} hits {counters['hits']}; "
+          f"{counters['compile_requests']} hits {counters['compile_hits']}; "
           f"peak_bytes_in_use {peak} ({peak / 2**30:.2f} GiB)", flush=True)
+    print("chip_smoke: set-up and compile spans: "
+          + "; ".join(spans.table(spans.RECORD.summary())), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}), flush=True)

@@ -337,8 +337,9 @@ DEFAULT_CFG: Dict[str, Any] = {
     # tests exercise.  None (default) leaves every program untouched.
     "chaos_poison": None,
     # run tracing (obs/trace.py): a directory to write a Chrome-trace-event
-    # trace.json (PhaseTimer phases + driver events + jax.profiler
-    # annotations; open in Perfetto) and a schema'd events.jsonl per run.
+    # trace.json (set-up and compile spans from construction on, PhaseTimer
+    # phases, driver events, jax.profiler annotations; open in Perfetto)
+    # and a schema'd events.jsonl per run.
     # None = no tracing.  Independent of the probes (host-side only).
     "trace_dir": None,
     "profile_dir": None,  # write a jax.profiler trace of round 2 here

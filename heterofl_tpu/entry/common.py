@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import datetime
+import functools
 import json
 import math
 import os
@@ -28,8 +29,9 @@ from .. import config as C
 from ..chaos import resolve_poison_cfg
 from ..compress import resolve_codec_cfg
 from ..obs import (resolve_ledger_cfg, resolve_quarantine_cfg,
-                   resolve_telemetry_cfg, split_probes)
+                   resolve_telemetry_cfg, spans, split_probes)
 from ..obs.ledger import ClientLedger
+from ..obs.trace import recorder_from
 from ..obs.watchdog import (RETRY_SALT, Watchdog, WatchdogError,
                             WatchdogRollback)
 from ..data import (
@@ -212,6 +214,20 @@ def _maybe_compute_norm_stats(cfg: Dict[str, Any], dataset: Dict[str, Any]) -> N
     cfg["norm_stats"] = (tuple(float(x) for x in mean), tuple(float(x) for x in std))
 
 
+def _first_round_is_setup(method):
+    """The one call of a round method an experiment makes while
+    ``_first_round_done`` is false -- the compile-bearing one -- runs under
+    the set-up span ``setup/first_round`` (obs/spans.py); every later call
+    pays one attribute test."""
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        if self._first_round_done:
+            return method(self, *args, **kwargs)
+        with spans.span("setup/first_round", self.phase_timer):
+            return method(self, *args, **kwargs)
+    return wrapped
+
+
 class FedExperiment:
     """One federated experiment (one seed): owns the data staging, engine,
     evaluator, logger and checkpoint loop."""
@@ -221,21 +237,37 @@ class FedExperiment:
     _arms_capable = False
 
     def __init__(self, cfg: Dict[str, Any], seed: int):
+        self.tag = C.make_model_tag(seed, cfg)
+        # staging/dispatch telemetry (parallel/staging.py PhaseTimer)
+        self.phase_timer = PhaseTimer()
+        self.tracer = None  # obs.trace.TraceRecorder, attached by run()
+        built = len(spans.RECORD.spans)
+        try:
+            with spans.span("setup/experiment", self.phase_timer):
+                self._build(cfg, seed)
+        finally:
+            # construction's set-up and compile spans (obs/spans.py), for
+            # the run's recorder: its timeline begins with them
+            self._built_spans = spans.RECORD.spans[built:]
+
+    def _build(self, cfg: Dict[str, Any], seed: int):
+        """All of construction, under ``setup/experiment``."""
         self.cfg = cfg
         self.seed = seed
-        self.tag = C.make_model_tag(seed, cfg)
         self.kind = "transformer" if cfg["model_name"] in C.LM_MODEL_NAMES else "vision"
         self.rng = np.random.default_rng(seed)
         self.host_key = jax.random.key(seed)
 
-        dataset = fetch_dataset(cfg["data_name"], cfg["data_dir"], synthetic=cfg["synthetic"],
-                                seed=seed, synthetic_sizes=cfg.get("synthetic_sizes"),
-                                subset=cfg.get("subset", "label"))
-        self.cfg, self.dataset = process_dataset(cfg, dataset)
-        cfg = self.cfg
-        _maybe_compute_norm_stats(cfg, self.dataset)
-        self.model = make_model(cfg)
-        validate_width_geometry(self.model, cfg)
+        with spans.span("setup/dataset", self.phase_timer):
+            dataset = fetch_dataset(cfg["data_name"], cfg["data_dir"], synthetic=cfg["synthetic"],
+                                    seed=seed, synthetic_sizes=cfg.get("synthetic_sizes"),
+                                    subset=cfg.get("subset", "label"))
+            self.cfg, self.dataset = process_dataset(cfg, dataset)
+            cfg = self.cfg
+            _maybe_compute_norm_stats(cfg, self.dataset)
+        with spans.span("setup/model", self.phase_timer):
+            self.model = make_model(cfg)
+            validate_width_geometry(self.model, cfg)
         n_data = max(1, cfg["mesh"].get("data", 1))
         n_clients = cfg["mesh"].get("clients", 0) or None
         # arms mesh axis (ISSUE 14): cfg['mesh']['arms'] = E lays each
@@ -245,9 +277,10 @@ class FedExperiment:
         n_arms_axis = max(1, int(cfg["mesh"].get("arms", 1) or 1))
         # a cfg['mesh'] the devices cannot honour raises here; only
         # clients == 0 means "use all devices"
-        self.mesh = make_mesh(n_clients, n_data, n_arms=n_arms_axis)
-        self.engine = RoundEngine(self.model, cfg, self.mesh)
-        self.evaluator = Evaluator(self.model, cfg, self.mesh, seed=seed)
+        with spans.span("setup/engine", self.phase_timer):
+            self.mesh = make_mesh(n_clients, n_data, n_arms=n_arms_axis)
+            self.engine = RoundEngine(self.model, cfg, self.mesh)
+            self.evaluator = Evaluator(self.model, cfg, self.mesh, seed=seed)
         self.scheduler = make_scheduler(cfg)
         self.num_active = int(np.ceil(cfg["frac"] * cfg["num_users"]))
         if not 0 <= self.num_active <= cfg["num_users"]:
@@ -272,10 +305,9 @@ class FedExperiment:
         self._round_times: List[float] = []  # steady-state round durations (ETA)
         self._first_round_done = False
         self._first_round_time = None  # the compile-bearing first dispatch
-        # staging/dispatch telemetry + async metric fetch (parallel/staging.py):
-        # per-round metric sums stay on device and are drained every
-        # cfg['metrics_fetch_every'] rounds (eval boundaries flush)
-        self.phase_timer = PhaseTimer()
+        # async metric fetch (parallel/staging.py): per-round metric sums
+        # stay on device and are drained every cfg['metrics_fetch_every']
+        # rounds (eval boundaries flush)
         fetch_every = int(cfg.get("metrics_fetch_every", 1) or 1)
         eval_iv = max(1, int(cfg.get("eval_interval", 1) or 1))
         self.eval_interval = eval_iv
@@ -471,7 +503,6 @@ class FedExperiment:
         self.watchdog = Watchdog(self.obs_spec.watchdog) \
             if (self.obs_spec.probes and self.obs_spec.watchdog is not None) \
             else None
-        self.tracer = None  # obs.trace.TraceRecorder, built in run()
         # client-update quarantine (ISSUE 15): validated loudly here so a
         # quarantine config that cannot run fails at construction.  The
         # gate lives in the engines' round cores -- the sliced debug twin
@@ -563,13 +594,16 @@ class FedExperiment:
         elif cfg.get("strategy") == "grouped":
             from ..parallel.grouped import GroupedRoundEngine
 
-            self.alt_engine = GroupedRoundEngine(cfg, self.mesh)
+            with spans.span("setup/engine", self.phase_timer):
+                self.alt_engine = GroupedRoundEngine(cfg, self.mesh)
 
     # -- staging -------------------------------------------------------
 
     def make_splits(self):
-        return split_dataset(self.dataset, self.cfg["num_users"], self.cfg["data_split_mode"],
-                             self.rng, classes_size=self.cfg["classes_size"])
+        with spans.span("setup/split", self.phase_timer):
+            return split_dataset(self.dataset, self.cfg["num_users"],
+                                 self.cfg["data_split_mode"], self.rng,
+                                 classes_size=self.cfg["classes_size"])
 
     def _place(self, data):
         """Train stacks onto devices per ``cfg['data_placement']``."""
@@ -580,6 +614,10 @@ class FedExperiment:
         return tuple(jnp.asarray(a) for a in data)
 
     def stage(self, data_split, label_split):
+        with spans.span("setup/stage", self.phase_timer):
+            self._stage(data_split, label_split)
+
+    def _stage(self, data_split, label_split):
         cfg = self.cfg
         U = cfg["num_users"]
         if self.streaming:
@@ -590,37 +628,42 @@ class FedExperiment:
             # eval is the one remaining O(U) surface, so runs that never
             # evaluate (population benches) never pay it.
             tr = self.dataset["train"]
-            if self.kind == "vision":
-                self.store = ClientStore.from_split(
-                    tr.data, tr.target, data_split["train"], label_split,
-                    cfg["classes_size"])
-            else:
-                self.store = ClientStore.from_split(
-                    tr.token, None, data_split["train"], label_split,
-                    cfg["num_tokens"], kind="lm")
+            with spans.span("setup/stage/train", self.phase_timer):
+                if self.kind == "vision":
+                    self.store = ClientStore.from_split(
+                        tr.data, tr.target, data_split["train"], label_split,
+                        cfg["classes_size"])
+                else:
+                    self.store = ClientStore.from_split(
+                        tr.token, None, data_split["train"], label_split,
+                        cfg["num_tokens"], kind="lm")
             self.train_data = None
             self._eval_split = (data_split["test"], label_split)
             self._eval_staged = False
             return
         if self.kind == "vision":
             tr = self.dataset["train"]
-            x, y, m = stack_client_shards(tr.data, tr.target, data_split["train"], list(range(U)))
-            lm = label_split_masks(label_split, U, cfg["classes_size"])
-            self.train_data = self._place((x, y, m, lm))
+            with spans.span("setup/stage/train", self.phase_timer):
+                x, y, m = stack_client_shards(tr.data, tr.target, data_split["train"], list(range(U)))
+                lm = label_split_masks(label_split, U, cfg["classes_size"])
+                self.train_data = self._place((x, y, m, lm))
             # sBN recalibration batches over the whole train set, per-user
             # local eval shards, batched global test set -- the shared
             # assembly (audit/bench stage the same layout)
-            self.sbn_batches, self.local_eval, self.global_eval = \
-                stage_eval_operands(cfg, tr, self.dataset["test"],
-                                    data_split["test"], lm)
+            with spans.span("setup/stage/eval", self.phase_timer):
+                self.sbn_batches, self.local_eval, self.global_eval = \
+                    stage_eval_operands(cfg, tr, self.dataset["test"],
+                                        data_split["test"], lm)
         else:
             tr = self.dataset["train"]
-            rows = stack_client_token_rows(tr.token, data_split["train"], list(range(U)))
-            lm = label_split_masks(label_split, U, cfg["num_tokens"])
-            self.train_data = self._place((rows, lm))
+            with spans.span("setup/stage/train", self.phase_timer):
+                rows = stack_client_token_rows(tr.token, data_split["train"], list(range(U)))
+                lm = label_split_masks(label_split, U, cfg["num_tokens"])
+                self.train_data = self._place((rows, lm))
             te = self.dataset["test"]
-            xs, ws = stack_windows(bptt_windows(te.token, cfg["bptt"]), cfg["bptt"])
-            self.global_eval = (xs, ws)
+            with spans.span("setup/stage/eval", self.phase_timer):
+                xs, ws = stack_windows(bptt_windows(te.token, cfg["bptt"]), cfg["bptt"])
+                self.global_eval = (xs, ws)
 
     def _ensure_eval_staged(self):
         """Streaming mode's lazy eval staging (see :meth:`stage`)."""
@@ -690,6 +733,7 @@ class FedExperiment:
         if self.chaos is not None:
             self.chaos.check(point)
 
+    @_first_round_is_setup
     def train_round(self, params, epoch: int, lr: float, logger: Logger):
         self._chaos("superstep")  # the K=1 dispatch boundary
         user_idx = self.sample_users(epoch)
@@ -933,6 +977,7 @@ class FedExperiment:
                 self._fused = self.evaluator.fused(global_eval=self.global_eval)
         return self._fused
 
+    @_first_round_is_setup
     def train_superstep(self, params, epoch0: int, k: int, logger: Logger):
         """Run rounds ``epoch0 .. epoch0+k-1`` as ONE compiled program
         (``superstep_rounds``): the round boundary leaves the host -- one
@@ -1270,6 +1315,31 @@ class FedExperiment:
     # -- full loop -----------------------------------------------------
 
     def run(self, pivot_metric: str, pivot_mode: str = "max") -> Dict[str, Any]:
+        if self.obs_spec.trace_dir and self.tracer is None \
+                and jax.process_index() == 0:
+            # run tracing (ISSUE 10): one Chrome-trace + events-JSONL
+            # recorder per run; PhaseTimer phases and the set-up and
+            # compile spans file onto the same timeline, driver events land
+            # via _trace_span below.  Attached when the run begins (an
+            # experiment that is built and never run writes nothing) and
+            # handed construction's spans, so trace.json begins with set-up
+            self.tracer = recorder_from(
+                os.path.join(self.obs_spec.trace_dir, self.tag),
+                min([s.t0 for s in self._built_spans] or [time.perf_counter()]))
+            for s in self._built_spans:
+                spans.file_to(self.tracer, s)
+            self.phase_timer.trace = self.tracer
+        try:
+            return self._run(pivot_metric, pivot_mode)
+        finally:
+            if self.tracer is not None:
+                # the trace must survive aborts (the watchdog's whole
+                # point): close on every exit path, a failed resume, split
+                # or staging included
+                self.tracer.close()
+                self.phase_timer.trace = None
+
+    def _run(self, pivot_metric: str, pivot_mode: str) -> Dict[str, Any]:
         cfg = self.cfg
         blob = resume(cfg["output_dir"], self.tag, cfg["resume_mode"])
         check_multihost_resume(blob)
@@ -1278,20 +1348,11 @@ class FedExperiment:
         else:
             data_split, label_split = self.make_splits()
         self.stage(data_split, label_split)
-        params = self.model.init(jax.random.fold_in(self.host_key, 0))
+        with spans.span("setup/init", self.phase_timer):
+            params = self.model.init(jax.random.fold_in(self.host_key, 0))
         last_epoch = 1
         logger = Logger(os.path.join(cfg["output_dir"], "runs", f"train_{self.tag}"),
                         use_tensorboard=bool(cfg.get("use_tensorboard")))
-        if self.obs_spec.trace_dir and self.tracer is None \
-                and jax.process_index() == 0:
-            # run tracing (ISSUE 10): one Chrome-trace + events-JSONL
-            # recorder per run; PhaseTimer phases file onto the same
-            # timeline, driver events land via _trace_span below
-            from ..obs.trace import TraceRecorder
-
-            self.tracer = TraceRecorder(
-                os.path.join(self.obs_spec.trace_dir, self.tag))
-            self.phase_timer.trace = self.tracer
         pivot = -float("inf") if pivot_mode == "max" else float("inf")
         if blob:
             params = _restore_params(blob["params"])
@@ -1333,11 +1394,6 @@ class FedExperiment:
                                   epoch, n_rounds, eval_interval, data_split,
                                   label_split, params)
         finally:
-            if self.tracer is not None:
-                # the trace must survive aborts (the watchdog's whole
-                # point): close on every exit path
-                self.tracer.close()
-                self.phase_timer.trace = None
             if self.ledger is not None and jax.process_index() == 0:
                 # the ledger.npz snapshot the report surface reads (ISSUE
                 # 12): written on every exit path, aborts included
@@ -1682,9 +1738,10 @@ class ArmsExperiment(FedExperiment):
         root (``fold_in(arm_root, 0)``, the solo loop's derivation), so the
         identity arm inits exactly like a solo run."""
         roots = arm_stream_keys(self.host_key, self.arms_spec.seeds)
-        trees = [self.model.init(jax.random.fold_in(roots[e], 0))
-                 for e in range(self.arms_spec.count)]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+        with spans.span("setup/init", self.phase_timer):
+            trees = [self.model.init(jax.random.fold_in(roots[e], 0))
+                     for e in range(self.arms_spec.count)]
+            return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
     def _dispatch(self, params, epoch0: int, k: int, mask):
         """One multiplexed superstep: the engines batch the arms axis; the

@@ -31,6 +31,10 @@ treat them as the tuning signal for codec/schedule choices):
 * **Watchdog** (:mod:`.watchdog`): non-finite counts and a loss-spike
   detector (vs a rolling median) surfaced at fetch boundaries -- loud
   warning by default, configurable abort.
+* **Set-up and compile spans** (:mod:`.spans`, ISSUE 38): one process-wide
+  record of what happens once a program -- building, staging, the first
+  round, every compilation with its program's name and whether the
+  persistent cache served it -- always on, filed onto the same timelines.
 
 This module is import-light (numpy only): config validation and the
 host-side probe assembly live here; :mod:`.probes` is hot-path jax code

@@ -10,7 +10,10 @@ population snapshot from the artifacts a ledger-enabled run leaves behind:
   loss-EMA quantiles;
 * ``events.jsonl`` (optional, the PR 10 trace stream next to it): event
   counts by name plus the watchdog trips, so an aborted run's report leads
-  with the evidence.
+  with the evidence, and the set-up table (ISSUE 38): the run's set-up and
+  compile spans (:mod:`.spans`) with their seconds, the compilations under
+  each and whether the cache served them.  A run traced without a ledger
+  (``trace_dir`` alone) gets the events part alone.
 
 ``--json`` prints the machine-readable snapshot instead of the table.
 Host-side and numpy-only, like the rest of the obs host half -- the
@@ -25,6 +28,7 @@ import os
 import sys
 from typing import Any, Dict, List, Optional
 
+from . import spans
 from .ledger import ClientLedger
 
 
@@ -49,6 +53,7 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
     counts: Dict[str, int] = {}
     watchdog: List[Dict[str, Any]] = []
     moe = {"rounds": 0, "tokens": None, "routed": 0.0, "held": 0.0, "dropped": 0}
+    setup: List[spans.Span] = []  # every span filed with its id
     with open(events_path) as f:
         for line in f:
             line = line.strip()
@@ -60,6 +65,9 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
             if name == "watchdog":
                 watchdog.append(rec.get("args", {}))
             args = rec.get("args", {})
+            if rec.get("ph") == "X" and "id" in args:
+                setup.append(spans.Span(args["id"], name, rec["t"],
+                                        rec["dur_s"], args.get("parent"), args))
             if name == "probes" and "moe_assign" in args:
                 # an expert layer's counters (obs.split_probes), summed
                 # over the run's rounds
@@ -74,6 +82,8 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
            "watchdog_trips": watchdog[:16]}
     if moe["rounds"]:
         out["moe"] = moe
+    if setup:
+        out["setup"] = spans.summarize(setup)
     return out
 
 
@@ -121,7 +131,14 @@ def render_text(rep: Dict[str, Any]) -> str:
              else _fmt_q(lv["loss_ema_quantiles"]))
         lines.append(f"    level {lv['level']:<8g} users {lv['users_last']:<8}"
                      f" participations {lv['participations']:<8} {q}")
-    ev = rep.get("events")
+    lines += render_events(rep.get("events"))
+    return "\n".join(lines)
+
+
+def render_events(ev: Optional[Dict[str, Any]]) -> List[str]:
+    """The events part of the table (all of it for a run without a
+    ledger)."""
+    lines: List[str] = []
     if ev:
         lines.append(f"events -- {ev['path']}")
         lines.append("  " + "  ".join(f"{k}:{v}" for k, v in
@@ -138,7 +155,11 @@ def render_text(rep: Dict[str, Any]) -> str:
         if ev["watchdog_trips"]:
             lines.append(f"  WATCHDOG TRIPPED {len(ev['watchdog_trips'])}x: "
                          f"{ev['watchdog_trips'][0]}")
-    return "\n".join(lines)
+        if ev.get("setup"):
+            lines.append("  set-up (seconds by span, the compilations under "
+                         "each, the longest compilations)")
+            lines += ["    " + l for l in spans.table(ev["setup"])]
+    return lines
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -152,7 +173,18 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--json", action="store_true",
                     help="print the machine-readable snapshot")
     args = ap.parse_args(argv)
-    rep = build_report(find_ledger(args.path), events_path=args.events)
+    try:
+        ledger = find_ledger(args.path)
+    except FileNotFoundError:
+        # a run traced without a ledger: its events alone
+        events = args.events or os.path.join(args.path, "events.jsonl")
+        if not os.path.isfile(events):
+            raise
+        rep = {"events": summarize_events(events)}
+        print(json.dumps(rep) if args.json
+              else "\n".join(render_events(rep["events"])))
+        return 0
+    rep = build_report(ledger, events_path=args.events)
     if args.json:
         print(json.dumps(rep))
     else:

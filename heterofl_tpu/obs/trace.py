@@ -312,3 +312,16 @@ class TraceRecorder:
         self._write_trace()
         self._jsonl.close()
         return self.trace_path
+
+
+def recorder_from(out_dir: str, t_perf: float) -> TraceRecorder:
+    """A recorder whose timeline begins at ``t_perf``, a ``perf_counter``
+    reading taken before it was made: a run's recorder attaches when the
+    run begins and is then handed the set-up spans of the experiment's
+    construction (ISSUE 38, :mod:`.spans`), which must not land left of
+    ``ts`` 0 in ``trace.json``.  The paired wall reading moves with it, so
+    ``events.jsonl``'s ``t`` is unchanged."""
+    rec = TraceRecorder(out_dir)
+    rec._t0_wall += t_perf - rec._t0
+    rec._t0 = t_perf
+    return rec

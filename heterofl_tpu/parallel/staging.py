@@ -66,6 +66,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import spans as _spans
+
 
 def commit_global(x, sharding: NamedSharding):
     """Commit a host (or single-device) value onto ``sharding`` on a mesh
@@ -333,13 +335,23 @@ class PhaseTimer:
         # staticcheck: allow(no-wallclock): host-side phase accounting -- the
         # timer never runs under trace (it wraps dispatch, not computation)
         t0 = time.perf_counter()
+        # the thread's stack of open spans (obs/spans.py): what compiles
+        # inside this phase names it as its parent
+        frame = _spans.Open(name, t0, self)
+        open_spans = _spans.RECORD.stack()
+        open_spans.append(frame)
         try:
             yield
         finally:
             dt = time.perf_counter() - t0  # staticcheck: allow(no-wallclock): host-side phase accounting
+            open_spans.pop()
             self.totals[name] = self.totals.get(name, 0.0) + dt
             self.calls[name] = self.calls.get(name, 0) + 1
-            if self.trace is not None:
+            if frame.id is not None:
+                # something compiled inside: the phase is a span of the
+                # record, filed to the hook with its id
+                _spans.RECORD.closed(frame, dt, open_spans)
+            elif self.trace is not None:
                 self.trace.complete(name, t0, dt, cat="phase")
 
     def snapshot(self) -> Dict[str, float]:

@@ -55,32 +55,6 @@ def default_cache_dir(root: Optional[str] = None) -> str:
     return os.path.join(root, ".jax_cache", cache_fingerprint())
 
 
-def install_cache_counters() -> dict:
-    """Live hit/miss counters for the persistent compile cache.
-
-    Subscribes to jax's monitoring events and returns the counter dict they
-    increment: ``requests`` counts backend compilations that consulted the
-    persistent cache (``/jax/compilation_cache/compile_requests_use_cache``),
-    ``hits`` the retrievals (``.../cache_hits``); misses are the difference
-    (jax emits no explicit miss event).  A bench round whose ``requests``
-    grows compiled a new program shape -- the visibility that keeps
-    superstep recompiles (a new program per K) from silently eating the
-    ~40s flagship compile repeatedly (ISSUE 2 satellite).  Counters stay
-    zero if the cache is disabled."""
-    import jax.monitoring
-
-    counters = {"requests": 0, "hits": 0}
-
-    def _on_event(event, **kwargs):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            counters["requests"] += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            counters["hits"] += 1
-
-    jax.monitoring.register_event_listener(_on_event)
-    return counters
-
-
 def key_scope_version() -> str:
     """Fold ``obs.trace.SCOPE_VERSION`` into every persistent-cache key and
     return the string folded in.
@@ -116,7 +90,9 @@ def enable_persistent_cache(path: Optional[str] = None) -> str:
     Safe to call before or after ``import jax``: the env var covers a
     not-yet-imported jax (and any child processes), and a live config
     update covers an already-imported one.  Every key of the cache carries
-    the scope vocabulary's version (:func:`key_scope_version`).
+    the scope vocabulary's version (:func:`key_scope_version`), and every
+    compilation from here on is a span of the process's record
+    (``obs/spans.py``; installed once, a second call registers nothing).
     """
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or path or default_cache_dir()
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", path)
@@ -126,6 +102,9 @@ def enable_persistent_cache(path: Optional[str] = None) -> str:
 
         jax.config.update("jax_compilation_cache_dir", path)
     key_scope_version()
+    from ..obs import spans
+
+    spans.install()
     return path
 
 

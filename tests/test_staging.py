@@ -130,16 +130,18 @@ def test_tier1_persistent_compile_cache_active():
 
 
 def test_install_cache_counters_counts_compiles():
-    from heterofl_tpu.utils.compile_cache import install_cache_counters
+    """The package's one observer of jax's compile events is the span
+    record (obs/spans.py): ``install()`` returns it, counters and all."""
+    from heterofl_tpu.obs import spans
 
-    c = install_cache_counters()
-    assert set(c) == {"requests", "hits"}
+    c = spans.install().counters
+    assert set(c) == {"compile_requests", "compile_hits", "compile_misses"}
     before = dict(c)
     # a FRESH program shape (unique constant) must consult the enabled
     # persistent cache and strictly bump the request counter -- the strict
     # inequality is the test that the monitoring listener actually fires
     jax.jit(lambda x: x * 3 + 1)(np.arange(931.0)).block_until_ready()
-    assert c["requests"] > before["requests"]
+    assert c["compile_requests"] > before["compile_requests"]
 
 
 def test_metrics_pipeline_batches_and_flushes():
