@@ -54,7 +54,11 @@ factor does not move).  The indexer's leaves get no gradient by construction
 leaf.
 
 The layers are alike, so the whole depth is one ``lax.scan`` over their
-stacked leaves, each layer under ``jax.checkpoint``.
+stacked leaves, each layer under ``jax.checkpoint`` with a save-by-name policy
+(:func:`kept`): for its backward a layer keeps its input, the indexer's 0/1
+choice (frozen and discrete: a second pass could only repeat it) and, where
+the fused kernels run, their forward's output and log-sum-exp, which are the
+backward kernel's residuals.  Everything else it recomputes.
 """
 
 from __future__ import annotations
@@ -65,6 +69,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
 from ..ops.layers import (causal_gq_attention, embed, linear as _linear, masked_layer_norm,
@@ -84,7 +89,22 @@ QUERY_BLOCK = 512
 #: the layer's counters under the names they ride the metrics by
 COUNTERS = {"tokens": "moe_tokens", "assign": "moe_assign",
             "selected": "sparse_selected", "kept_share": "sparse_kept_share",
-            "fused": "sparse_fused"}
+            "fused": "sparse_fused", "saved": "sparse_saved"}
+
+#: the name an indexer's 0/1 blocks carry (``checkpoint_name``; :func:`kept`)
+CHOICE = "sparse_choice"
+
+
+def kept():
+    """What a layer's ``jax.checkpoint`` keeps for the backward beside its
+    input: the values that carry one of three names, the indexer's choice
+    (booleans, a bit of a layer's activations) and the selected-attention
+    kernel's two results.  Where nothing carries a name (a row no longer than
+    ``topk`` runs no indexer, the block loop no kernel) that is the input
+    alone."""
+    from ..ops.pallas_attention import SEL_LSE, SEL_OUT  # Pallas, imported where a Keye model is built
+
+    return jax.checkpoint_policies.save_only_these_names(CHOICE, SEL_OUT, SEL_LSE)
 
 
 def index_keys(lp, h, *, heads: int, head_dim: int, theta: float, topk: int, block: int,
@@ -231,14 +251,19 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         # query tiles of the selected attention, and those the fused kernels took
         tiles = N * -(-S // block) if S > topk else 0
         fused = tiles if selected_attention_tile(S, hd, H // Hkv) is not None else 0
+        # query blocks that end after topk: those whose keys the indexer chooses
+        selecting = N * sum(min(s + block, S) > topk for s in range(0, S, block))
 
-        @jax.checkpoint
+        @partial(jax.checkpoint, policy=kept())
         def layer(x, lp):
-            """``(x, leaves) -> (x, counters)``, the scan's body; it keeps only
-            its input for the backward."""
+            """``(x, leaves) -> (x, counters)``, the scan's body; for the
+            backward it keeps its input and what :func:`kept` names."""
             h = rms(lp["norm1.g"], x)
+            saved = 0
             if S > topk:
                 select, pairs = indexer(lp, h)
+                select = [m if m is None else checkpoint_name(m, CHOICE) for m in select]
+                saved = N * sum(m is not None for m in select)
                 x = x + attention(lp, h, attend=partial(selected_gq_attention, select=select,
                                                         block=block))
             else:  # every causal key is among the topk: no choice to make
@@ -251,6 +276,7 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             counters["selected"] = jnp.stack([pairs[0], jnp.float32(T)])
             counters["kept_share"] = pairs
             counters["fused"] = jnp.stack([jnp.float32(fused), jnp.float32(tiles)])
+            counters["saved"] = jnp.stack([jnp.float32(saved), jnp.float32(selecting)])
             return x + y.reshape(N, S, D), counters
 
         x = embed(params["embedding.tok.w"], labels)
@@ -276,12 +302,14 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                         "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
             # what apply's "counters" holds (summed over the layers); the
             # engines carry them as obs_ probes when telemetry is on.  The
-            # three sparse ones are (numerator, denominator) pairs:
+            # four sparse ones are (numerator, denominator) pairs:
             # obs.split_probes finishes them as selected keys a query,
-            # selected over causal pairs, and the share of the selected
-            # attention's query tiles that the fused kernels took
+            # selected over causal pairs, the share of the selected
+            # attention's query tiles that the fused kernels took, and the
+            # share of the selecting query blocks whose choice the layer
+            # kept for its backward
             "counters": {"moe_tokens": (len(held),), "moe_assign": (3,),
                          "sparse_selected": (2,), "sparse_kept_share": (2,),
-                         "sparse_fused": (2,)}}
+                         "sparse_fused": (2,), "sparse_saved": (2,)}}
     return ModelDef("keye", init, apply, specs, groups, [], meta)
 

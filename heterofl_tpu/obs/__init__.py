@@ -372,11 +372,13 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # over that device's valid slots, steps and expert layers
                 rec[base] = [float(c) for c in x.sum(axis=0)]
             elif base.startswith("sparse_"):
-                # a sparse-attention indexer's counters (ISSUES 35, 36), each
-                # a (numerator, denominator) pair of per-device sums:
+                # a sparse-attention indexer's counters (ISSUES 35, 36, 39),
+                # each a (numerator, denominator) pair of per-device sums:
                 # sparse_selected = keys selected a query, sparse_kept_share
                 # = selected over causal (query, key) pairs, sparse_fused =
-                # query tiles the fused kernels took over query tiles
+                # query tiles the fused kernels took over query tiles,
+                # sparse_saved = selecting query blocks whose choice the
+                # layer kept for its backward over selecting query blocks
                 num, den = (float(c) for c in x.sum(axis=0))
                 rec[base] = num / den if den else 0.0
             elif base == "nonfinite":

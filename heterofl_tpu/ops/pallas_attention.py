@@ -516,7 +516,16 @@ def fused_gq_attention(q, k, v, scale, *, block_q: int, block_k: int, interpret:
 # (``[d, S]``: 4 MB each there), so here THEY are resident and a group's
 # ``dq`` tile is accumulated over the key tiles up to the diagonal, the grid
 # of the forward: five products a tile, one kernel.
+#
+# THE FORWARD'S RESULTS CARRY NAMES.  ``o`` and the log-sum-exp are the primal
+# output and the backward kernel's residuals at once, so a ``jax.checkpoint``
+# whose policy saves ``SEL_OUT`` and ``SEL_LSE`` runs no second forward kernel
+# in its backward (``models/keye.py``'s layer).  Under no such policy a name
+# is the identity.
 # ---------------------------------------------------------------------------
+
+SEL_OUT, SEL_LSE = "sel_out", "sel_lse"
+
 
 def _selection_bias(sel_ref, selects, causal, q0, k0):
     """0 where a pair (key, query) of the tile is kept, ``MASKED`` where not,
@@ -684,8 +693,11 @@ def _sel_flash(q, k, v, sel, tq, tk, first, interpret):
 
 
 def _sel_flash_fwd(q, k, v, sel, tq, tk, first, interpret):
+    from jax.ad_checkpoint import checkpoint_name  # here: the lines above this family stay put
+
     ops = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
     o, lse = _call_sel_fwd(*ops, sel, tq, tk, first, interpret)
+    o, lse = checkpoint_name(o, SEL_OUT), checkpoint_name(lse, SEL_LSE)
     return o, (ops, sel, o, lse)
 
 

@@ -12,16 +12,23 @@ rule gives (`sel_tile_for`) and at any others named, forward and forward +
 backward, alone and inside the layer's attention BLOCK (`models.lfm2.
 gq_attention`: projections, head norms, turn, attention, output projection,
 under `jax.checkpoint` as a layer runs it), which is where a kernel's operand
-layout shows in its neighbours.  Four minutes:
+layout shows in its neighbours.  Four minutes.  `keep` instead times ONE
+LAYER of the model (`make_model` at one layer: embedding, the layer, head and
+loss), forward + backward, and reads its program's bytes with what the
+layer's checkpoint keeps for the backward taken in turn as nothing but its
+input / the indexer's choice / the choice and the kernels' output
+(`models.keye.kept`, the model's own, is the last):
 
     chiprun -- python scripts/sparse_ab.py [seed] [256x512,128x512]   # on the chip
     chiprun -- python scripts/sparse_ab.py attn [seed] [tiles]        # the attention part alone
+    chiprun -- python scripts/sparse_ab.py keep [seed]                # what a layer keeps
     JAX_PLATFORMS=cpu python scripts/sparse_ab.py tiny  # the same code at the tests' size
 
 Every layer's indexer reads the SAME input here (the normed embedding of the
 row), so that the two sides' choices differ by their own arithmetic alone and
 not by what earlier layers handed on.  Milliseconds are host-clock over 5
-calls, best of 3.  The numbers land in `chiprun_out/sparse_ab.json`.
+calls, best of 3.  The numbers land in `chiprun_out/sparse_ab.json` (`keep`:
+`sparse_keep.json`).
 """
 import json
 import os
@@ -111,6 +118,47 @@ def attention_times(a, lp, h, select, block, tiles, interpret):
     return out
 
 
+def keep_times(cfg, seed, tokens):
+    """Milliseconds of loss and gradients of the model cut to one layer, and
+    the bytes of that program (the compiler's own count: temporaries,
+    arguments, output), under each of three policies of the layer's
+    checkpoint; every leaf's gradient against the first policy's."""
+    from heterofl_tpu.ops.pallas_attention import SEL_LSE, SEL_OUT
+
+    cfg = dict(cfg, keye=dict(cfg["keye"], num_hidden_layers=1))
+    save, model_policy = jax.checkpoint_policies.save_only_these_names, keye.kept
+    policies = {"input": lambda: None, "choice": lambda: save(keye.CHOICE),
+                "choice+kernel": lambda: save(keye.CHOICE, SEL_OUT, SEL_LSE)}
+    out, first = {}, None
+    try:
+        for name, policy in policies.items():
+            keye.kept = policy
+            model = make_model(cfg)
+            shapes = {k: tuple(v.shape)
+                      for k, v in jax.eval_shape(model.init, jax.random.key(0)).items()}
+            params = weights.make_params(shapes, seed)
+            step = jax.jit(jax.value_and_grad(
+                lambda p, t: model.apply(p, {"label": t}, train=True)[0]["loss"]))
+            mem = step.lower(params, tokens).compile().memory_analysis()
+            got = jax.tree_util.tree_leaves(step(params, tokens))
+            first = first or got
+            out[name] = {
+                "fwd_bwd": timed(step, params, tokens),
+                "temp_bytes": mem.temp_size_in_bytes, "argument_bytes": mem.argument_size_in_bytes,
+                "output_bytes": mem.output_size_in_bytes,
+                "differs": sum(bool(jnp.any(x != y)) for x, y in zip(got, first))}
+            print(f"one layer, the checkpoint keeps {name}: " + json.dumps(out[name]), flush=True)
+    finally:
+        keye.kept = model_policy
+    return out
+
+
+def dump(name, out):
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", name), "w", encoding="utf-8") as f:
+        json.dump(out, f, indent=1)
+
+
 def main(argv):
     tiny = "tiny" in argv
     tiles = [tuple(int(t) for t in pair.split("x"))
@@ -128,6 +176,11 @@ def main(argv):
         cfg["num_tokens"] = cfg["classes_size"] = config["model"]["num_tokens"]
         model_cfg = config["model"]
     a, S, V = cfg["keye"], cfg["bptt"], cfg["num_tokens"]
+    if "keep" in argv:
+        tokens = jax.random.randint(jax.random.key(seed % (2 ** 31)), (1, S), 0, V)
+        return dump("sparse_keep.json", {
+            "seed": seed, "device": jax.devices()[0].device_kind, "positions": S,
+            "keep": keep_times(cfg, seed, tokens)})
     block = min(keye.QUERY_BLOCK, a["index_topk"])  # the model's
     model = make_model(cfg)
     shapes = {k: tuple(v.shape) for k, v in jax.eval_shape(model.init, jax.random.key(0)).items()}
@@ -214,9 +267,7 @@ def main(argv):
     tiles = ([] if rule is None else [rule]) + [t for t in tiles if t != rule]
     out["tile"], out["attention_ms"] = rule, attention_times(
         a, lp, h, select, block, tiles, interpret=jax.default_backend() != "tpu")
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open(os.path.join("chiprun_out", "sparse_ab.json"), "w", encoding="utf-8") as f:
-        json.dump(out, f, indent=1)
+    dump("sparse_ab.json", out)
 
 
 if __name__ == "__main__":

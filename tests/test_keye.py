@@ -311,6 +311,107 @@ def test_a_row_no_longer_than_topk_is_causal_gq_attention_bit_for_bit():
 
 
 # ---------------------------------------------------------------------------
+# what a layer keeps for its backward (ISSUE 39): the frozen indexer's choice
+# by name, so the backward runs no second indexer and no second top-k
+# ---------------------------------------------------------------------------
+
+def _bare_checkpoint(monkeypatch):
+    """The model as it was before ISSUE 39: each layer under a bare
+    ``jax.checkpoint`` that keeps its input alone."""
+    from heterofl_tpu.models import keye
+
+    monkeypatch.setattr(keye, "kept", lambda: None)
+
+
+def _selection_ops(text):
+    """(indexer score products, `top_k_mask` value loops, its position loops)
+    in a lowered program's text at the tiny size: a block's score is the one
+    "highest" product whose result is ``[2 rows, 4 indexer heads, 16 queries,
+    keys]``; a value loop carries the k-th value ``[2, 16, 1]`` uint32, a
+    position loop the ties' mask."""
+    return (len(re.findall(r"dot_general.*HIGHEST, HIGHEST.*-> tensor<2x4x16x\d+xf32>", text)),
+            len(re.findall(r"stablehlo\.while.*tensor<2x16x1xui32>$", text, re.M)),
+            len(re.findall(r"stablehlo\.while.*tensor<2x16x\d+xi1>.*tensor<2x16x1xi32>$", text,
+                           re.M)))
+
+
+@pytest.mark.parametrize("rate", [1.0, 0.25])
+def test_keye_what_the_layer_keeps_changes_no_bit(rate, monkeypatch):
+    """Loss and every leaf's gradient of the masked model at a level, rows
+    longer than ``topk``: bitwise those of the same model whose layers keep
+    only their input (the second pass could only repeat the choice)."""
+    _, model, params, tokens, lm, _ = _keye_case()
+    got, got_grads = jax.jit(lambda p: _masked_loss_and_grads(model, p, tokens, lm, rate))(params)
+    _bare_checkpoint(monkeypatch)
+    want, want_grads = jax.jit(lambda p: _masked_loss_and_grads(model, p, tokens, lm, rate))(params)
+    assert float(got) == float(want)
+    for name, w in want_grads.items():
+        np.testing.assert_array_equal(got_grads[name], w, err_msg=name)
+
+
+@pytest.mark.parametrize("rate", [1.0, 0.25])
+def test_keye_backward_runs_no_second_indexer_and_no_second_top_k(rate, monkeypatch):
+    """The lowered gradient program holds ONE score product and one pair of
+    `top_k_mask` loops a selecting block (three of four blocks a row; the
+    layers are one scan), as the forward-only program does; with the policy
+    taken off it holds two of each, the layer's recomputation's beside the
+    forward's."""
+    _, model, params, tokens, lm, _ = _keye_case()
+
+    def lowered(fn):
+        return jax.jit(fn).lower(params).as_text()
+
+    def forward(p):
+        pm = mask_params(p, model.specs, model.groups, rate)
+        return model.apply(pm, {"label": tokens}, train=True, width_rate=rate, scaler_rate=rate,
+                           label_mask=lm)[0]["loss"]
+
+    def gradient():  # a function of its own a call: jit's trace cache goes by identity
+        return lowered(lambda p: _masked_loss_and_grads(model, p, tokens, lm, rate))
+
+    assert _selection_ops(lowered(forward)) == (3, 3, 3)
+    assert _selection_ops(gradient()) == (3, 3, 3)
+    _bare_checkpoint(monkeypatch)
+    assert _selection_ops(gradient()) == (6, 6, 6)
+
+
+@pytest.mark.parametrize("policy", ["kept", "bare"])
+def test_keye_named_blocks_are_the_layers_saved_residuals(policy, monkeypatch, capsys):
+    """What the scan over the layers hands the backward: the layer's input
+    ``[L, N, S, D]``, the only float activation of the hidden size, and, under
+    the policy, the three selecting blocks' 0/1 choice ``[L, N, 16, keys]`` as
+    booleans; without it no boolean at all."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    if policy == "bare":
+        _bare_checkpoint(monkeypatch)
+    _, model, params, tokens, lm, _ = _keye_case()
+    print_saved_residuals(
+        lambda p: model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"], params)
+    from_scan = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+                 if "output of scan" in line]
+    blocks = [f"bool[2,2,16,{keys}]" for keys in (32, 48, 64)]
+    assert sorted(from_scan) == sorted(["f32[2,2,64,64]"] + (blocks if policy == "kept" else []))
+
+
+@pytest.mark.parametrize("bptt, blocks", [(64, 3), (48, 2), (16, 0)])
+def test_keye_sparse_saved_counts_the_selecting_blocks(bptt, blocks):
+    """`sparse_saved` = (selecting query blocks whose choice the layer named
+    for its backward, selecting query blocks), 2 layers x 2 rows x the blocks
+    that end after ``topk`` 16; a row no longer than ``topk`` reads 0 of 0,
+    which `obs.split_probes` finishes as 0.0."""
+    from heterofl_tpu.obs import split_probes
+
+    _, model, params, tokens, _, _ = _keye_case(bptt=bptt)
+    out, _ = model.apply(params, {"label": tokens}, train=True)
+    saved = out["counters"]["sparse_saved"]
+    assert [float(c) for c in saved] == [2.0 * 2 * blocks] * 2
+    assert model.meta["counters"]["sparse_saved"] == (2,)
+    _, rounds = split_probes({"obs_sparse_saved": np.asarray(saved)}, 1)
+    assert rounds[0]["sparse_saved"] == (1.0 if blocks else 0.0)
+
+
+# ---------------------------------------------------------------------------
 # what the block loop and the router were before: their callers are left alone
 # ---------------------------------------------------------------------------
 
@@ -563,12 +664,13 @@ def test_keye_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_roun
 
 def test_keye_counters_ride_the_metrics():
     """telemetry='on' carries the indexer's counters out beside the expert
-    layers': `obs_sparse_selected`, `obs_sparse_kept_share` and
-    `obs_sparse_fused`, each a (numerator, denominator) pair of sums a device,
-    finished by `obs.split_probes` as keys selected a query, selected over
-    causal pairs -- at 64 positions and ``topk`` 16: 904 / 64 and 904 / 2,080
-    -- and the share of the selected attention's query tiles that went
-    through the fused kernels: none on the CPU."""
+    layers': `obs_sparse_selected`, `obs_sparse_kept_share`,
+    `obs_sparse_fused` and `obs_sparse_saved`, each a (numerator, denominator)
+    pair of sums a device, finished by `obs.split_probes` as keys selected a
+    query, selected over causal pairs -- at 64 positions and ``topk`` 16: 904
+    / 64 and 904 / 2,080 -- the share of the selected attention's query tiles
+    that went through the fused kernels: none on the CPU -- and the share of
+    the selecting query blocks whose choice the layer kept: all."""
     from heterofl_tpu.obs import split_probes
 
     cfg, data = _round_case()
@@ -576,12 +678,14 @@ def test_keye_counters_ride_the_metrics():
     assert ms["obs_sparse_selected"].shape == ms["obs_sparse_kept_share"].shape == (2 * 2,)
     # 8 clients x 2 layers x 2 rows x 4 query blocks of 16, none through the kernels
     assert ms["obs_sparse_fused"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 2 * 2 * 4]
+    # of those four blocks the three that end after topk select, and their choice is kept
+    assert ms["obs_sparse_saved"].reshape(2, 2).sum(axis=0).tolist() == [8 * 2 * 2 * 3] * 2
     assert ms["obs_moe_tokens"].shape == (2 * 4,) and ms["obs_moe_assign"].shape == (2 * 3,)
     clean, rounds = split_probes(dict(ms), 2)
     rec = rounds[0]
     assert rec["sparse_selected"] == pytest.approx(904 / 64, rel=1e-6)
     assert rec["sparse_kept_share"] == pytest.approx(904 / 2080, rel=1e-6)
-    assert rec["sparse_fused"] == 0.0
+    assert rec["sparse_fused"] == 0.0 and rec["sparse_saved"] == 1.0
     # 8 clients x 1 step x (2 rows x 64 tokens) x top-2, in each of 2 layers
     assert rec["moe_assign"][0] == 8 * 128 * 2 * 2
     assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
@@ -626,6 +730,28 @@ def test_keye_model_takes_the_selected_kernels_where_a_tpu_gives_them_tiles(monk
                                    err_msg=name)
         if ".idx." in name:
             assert not np.any(got_grads[name]), name
+
+
+@pytest.mark.parametrize("policy, kernels", [
+    ("kept", ["sel_attn_bwd", "sel_attn_fwd"]),
+    ("bare", ["sel_attn_bwd", "sel_attn_fwd", "sel_attn_fwd"])])
+def test_keye_gradient_on_the_kernels_runs_one_forward_kernel_a_layer(policy, kernels,
+                                                                      monkeypatch):
+    """The model at shapes the fused kernels tile, jax reporting a TPU: the
+    gradient's program calls ``sel_attn_fwd`` in the forward scan's body and
+    ``sel_attn_bwd`` alone in the backward's, whose residuals ``o`` and the
+    log-sum-exp the layer kept by name; a layer that keeps its input alone
+    (before ISSUE 39) calls the forward kernel again beside the backward."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns
+
+    if policy == "bare":
+        _bare_checkpoint(monkeypatch)
+    _, model, params, tokens, lm, _ = _keye_case(bptt=256, head_dim=128, index_topk=128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(
+        p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"]))(params)
+    assert sorted(e.params["name"] for e in iter_eqns(jaxpr)
+                  if e.primitive.name == "pallas_call") == kernels
 
 
 def test_keye_trains_and_evaluates_through_the_entry_point(tmp_path):
@@ -686,8 +812,9 @@ def test_the_cut_configuration_has_the_parameters_it_states():
 
 def test_the_indexer_carries_its_names(masked_round):
     """`sparse/index` and `sparse/select` reach the round program's `op_name`s
-    under `step/model`, in the forward and in its recomputation only: the
-    indexer has no backward; `sparse/index` holds the indexer's products, its
+    under `step/model` in the forward only: the indexer has no backward, and
+    the layer's recomputation runs none of it since the layer keeps the
+    choice (ISSUE 39); `sparse/index` holds the indexer's products, its
     LayerNorm and its turn, `sparse/select` no product at all; the attention
     stays under `gqa` / `rope` / `attn` and the experts under the shared
     code's scopes."""
@@ -710,8 +837,8 @@ def test_the_indexer_carries_its_names(masked_round):
     for s in trace.SPARSE_SCOPES:
         mine = [n for n in names if f"/{s}/" in n]
         assert any("/jvp(step/model)/" in n for n in mine), s
-        # no gradient passes it: under the backward lies its recomputation alone
-        assert all("/rematted_computation/" in n for n in mine if "transpose(" in n), s
+        # no gradient passes it, and the backward's recomputation does not repeat it
+        assert not any("transpose(" in n for n in mine), s
     index = [n for n in names if "/sparse/index/" in n]
     assert any("dot_general" in n for n in index)
     assert any("/sparse/index/norm/" in n for n in index)

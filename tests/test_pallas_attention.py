@@ -11,6 +11,7 @@ against the oracle's float32 ones: 2^-9 of a term, held to 1 % of the largest
 element (a wrong mask, a dropped tile or a missing head moves O(1))."""
 
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -248,6 +249,77 @@ def test_selected_kernels_are_the_block_loop_under_the_selection(case):
         got = _out_and_grads(fused(select, t, t, t), ops, probe, 0.25)
         _close(got, _out_and_grads(oracle(select, t), ops, probe, 0.25))
         assert not any(np.any(x[..., 8:]) for x in got)  # o, dq, dk, dv
+
+
+def _sel_toy(policy, S=256, t=128):
+    """``(loss(ws, x), ws, x)``: two layers ``x + attention(x * w)`` of the
+    selected kernels (interpret mode; 2 query heads of 128 on one key/value
+    head, the second query block selecting) under ``jax.checkpoint(policy=)``,
+    one after the other, so that a layer's kernels are counted apart."""
+    (q, k, _), _ = _sel_operands(jax.random.key(21), S=S, G=2)
+    select = _indexer_selection(jax.random.key(22), 1, S, t, t)
+    attend = _sel_fused(select, t, t, t)
+
+    @partial(jax.checkpoint, policy=policy)
+    def layer(x, w):
+        return x + attend(x * w, (k + x[:, :1]) * w, k * w, SCALE)
+
+    def loss(ws, x):
+        for w in ws:
+            x = layer(x, w)
+        return jnp.sum(x * x)
+
+    return loss, [jnp.float32(1.0), jnp.float32(0.5)], q
+
+
+def test_a_checkpoint_that_saves_the_forwards_names_runs_no_second_forward_kernel():
+    """``o`` and the log-sum-exp of ``sel_attn_fwd`` carry ``SEL_OUT`` and
+    ``SEL_LSE`` inside the rule's forward, where they are the backward's
+    residuals: under ``save_only_these_names`` a two-layer toy's gradient
+    holds one ``sel_attn_fwd`` and one ``sel_attn_bwd`` a layer, under a bare
+    ``jax.checkpoint`` a second forward a layer (the recomputation); loss and
+    gradients are bitwise equal."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns  # every call, a shared jaxpr's too
+
+    out = {}
+    for name, policy in (("kept", jax.checkpoint_policies.save_only_these_names(
+            PA.SEL_OUT, PA.SEL_LSE)), ("bare", None)):
+        loss, ws, x = _sel_toy(policy)
+        grad = jax.value_and_grad(loss, argnums=(0, 1))
+        kernels = [e.params["name"] for e in iter_eqns(jax.make_jaxpr(grad)(ws, x))
+                   if e.primitive.name == "pallas_call"]
+        out[name] = (sorted(kernels), jax.jit(grad)(ws, x))
+    assert out["kept"][0] == ["sel_attn_bwd"] * 2 + ["sel_attn_fwd"] * 2
+    assert out["bare"][0] == ["sel_attn_bwd"] * 2 + ["sel_attn_fwd"] * 4
+    for got, want in zip(jax.tree_util.tree_leaves(out["kept"][1]),
+                         jax.tree_util.tree_leaves(out["bare"][1])):
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_outside_a_checkpoint_the_names_are_the_identity(grad, monkeypatch):
+    """A direct call of the selected kernels under no policy lowers FOR A TPU
+    to the program it was before the names, the kernels' serialized bodies
+    with their call stacks included: the text of the same call with
+    ``checkpoint_name`` taken out."""
+    import jax.ad_checkpoint
+
+    (q, k, v), probe = _sel_operands(jax.random.key(23), S=256, G=2)
+    select = _indexer_selection(jax.random.key(24), 1, 256, 128, 128)
+
+    def fn(q, k, v):
+        return jnp.sum(PA.fused_selected_attention(q, k, v, SCALE, select, 128, block_q=128,
+                                                   block_k=128) * probe)
+
+    texts = []
+    for name in (jax.ad_checkpoint.checkpoint_name, lambda x, name: x):
+        monkeypatch.setattr(jax.ad_checkpoint, "checkpoint_name", name)
+        # a function of its own a turn (jit's trace cache goes by identity), lowered by
+        # one line: a kernel's body carries the stack of its call
+        fresh = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else partial(fn))
+        texts.append(fresh.trace(q, k, v).lower(lowering_platforms=("tpu",)).as_text())
+    assert texts[0].count("tpu_custom_call") == (2 if grad else 1)
+    assert texts[0] == texts[1]
 
 
 def _sel_shapes(S, d, topk=256, block=128):
