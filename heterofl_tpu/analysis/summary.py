@@ -170,34 +170,38 @@ def module_table(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[in
         add("linear", (bs, in_planes), (bs, cfg["classes_size"]), mods("linear"),
             bs * in_planes * cfg["classes_size"])
     elif "profile" in model.meta:
-        # a family that describes itself (kanana2, lfm2): one row per matrix
-        # leaf (a linear's MACs = tokens x its size; a routed expert sees
-        # top_k / n_experts of the tokens; a depthwise tap leaf [taps,
-        # channels] is its size too) plus the two attention matmuls of each
-        # attention layer and, for a tied head, the head's product; norms,
-        # gates, RoPE, softmax and the router's top-k are not matmul-like and
-        # are left out, as the benchmark's FLOP files (benchmark/flops/) leave
-        # them out
+        # a family that describes itself (kanana2, lfm2, keye, ouro): one row
+        # per matrix leaf (a linear's MACs = tokens x its size; a routed
+        # expert sees top_k / n_experts of the tokens; a depthwise tap leaf
+        # [taps, channels] is its size too) plus the two attention matmuls of
+        # each attention layer and, for a tied head, the head's product;
+        # norms, gates, RoPE, softmax and the router's top-k are not
+        # matmul-like and are left out, as the benchmark's FLOP files
+        # (benchmark/flops/) leave them out.  A looped family (ouro) uses
+        # every leaf but the embedding ``passes`` times a step: its rows hold
+        # the step's MACs, all passes
         prof = model.meta["profile"]
         T = cfg["bptt"]
         ntok = bs * T
+        uses = ntok * prof.get("passes", 1)
         shapes = {k: tuple(v.shape) for k, v in params.items()}
         for name in sorted(shapes):
             shp = shapes[name]
             if len(shp) != 2:
-                add(name, (bs, T, shp[0]), (bs, T, shp[0]), psize[name], ntok * shp[0] * 2)
+                add(name, (bs, T, shp[0]), (bs, T, shp[0]), psize[name], uses * shp[0] * 2)
             elif name.startswith("embedding.") or name == prof.get("tied_head"):
                 add("embedding", (bs, T), (bs, T, shp[1]), psize[name], ntok * shp[1])
             else:
-                toks = ntok * prof["routed_share"] if ".moe.e" in name else ntok
+                toks = uses * prof["routed_share"] if ".moe.e" in name else uses
                 add(name[:-2] if name.endswith(".w") else name, (bs, T, shp[0]),
                     (bs, T, shp[1]), psize[name], toks * shp[0] * shp[1])
         if "tied_head" in prof:
             V, D = shapes[prof["tied_head"]]
             add("head", (bs, T, D), (bs, T, V), 0, ntok * D * V)
+        pairs = bs * prof.get("passes", 1) * (T * (T + 1) // 2)
         for site, (H, dq, dv) in prof["attention"].items():
-            add(f"{site}.qk", (bs, T, H * dq), (bs, H, T, T), 0, bs * H * T * (T + 1) // 2 * dq)
-            add(f"{site}.av", (bs, H, T, T), (bs, T, H * dv), 0, bs * H * T * (T + 1) // 2 * dv)
+            add(f"{site}.qk", (bs, T, H * dq), (bs, H, T, T), 0, H * pairs * dq)
+            add(f"{site}.av", (bs, H, T, T), (bs, T, H * dv), 0, H * pairs * dv)
     else:  # transformer
         from ..config import ceil_width
 

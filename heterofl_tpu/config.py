@@ -39,10 +39,10 @@ CONTROL_KEYS = (
 # data/ import these rather than re-declaring them.
 NORM_TYPES = ("bn", "in", "ln", "gn", "none")
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
-               "resnet152", "transformer", "kanana2", "lfm2", "keye")
+               "resnet152", "transformer", "kanana2", "lfm2", "keye", "ouro")
 #: the families that train on token rows (next- or masked-token loss): the
 #: drivers' and engines' LM paths key on this, not on one family's name
-LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2", "keye")
+LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2", "keye", "ouro")
 # Feature-axis value registries (ISSUE 18): THE declared domains of the
 # engine/placement/store/pod axes, consumed by the axis validators below and
 # by staticcheck's config-lattice pass (staticcheck/lattice.py enumerates
@@ -521,6 +521,26 @@ def process_control(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "rope_theta": 10000000.0,
         "rms_norm_eps": 1e-6,
         "expert_share": [0, 1],
+    }
+    # Ouro-2.6B (model_type ouro, the LoopLM of arXiv:2510.25741): the
+    # published shape (huggingface.co/ByteDance/Ouro-2.6B config.json).  The
+    # whole layer stack runs ``total_ut_steps`` times on the same weights;
+    # ``early_exit_threshold`` acts at inference only.  ``exit_entropy_beta``
+    # (the weight of the exit distribution's entropy in the training loss,
+    # the paper's stage-I objective under a uniform prior) is not a key of
+    # that file.  The benchmark's cut is 4 layers.
+    cfg["ouro"] = {
+        "hidden_size": 2048,
+        "num_hidden_layers": 48,
+        "intermediate_size": 5632,
+        "num_attention_heads": 16,
+        "num_key_value_heads": 16,
+        "head_dim": 128,
+        "total_ut_steps": 4,
+        "early_exit_threshold": 1.0,
+        "exit_entropy_beta": 0.1,
+        "rope_theta": 1000000.0,
+        "rms_norm_eps": 1e-6,
     }
     # Per-dataset hyperparameters (ref src/utils.py:150-212).
     data_name = cfg["data_name"]

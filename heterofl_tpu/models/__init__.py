@@ -21,6 +21,7 @@ from .conv import make_conv
 from .kanana2 import make_kanana2
 from .keye import make_keye
 from .lfm2 import make_lfm2
+from .ouro import make_ouro
 from .resnet import make_resnet
 from .spec import Group, ParamSpec, count_masks, mask_params, param_mask  # noqa: F401
 from .transformer import make_transformer
@@ -36,7 +37,8 @@ RESNET_BLOCKS = {
 # the canonical registry lives in config (jax-free for analysis tooling); keep
 # it in lockstep with the families actually buildable here.  A hard raise, not
 # an assert: the guard must survive `python -O` (advisor r3).
-_BUILDABLE = ("conv",) + tuple(RESNET_BLOCKS) + ("transformer", "kanana2", "lfm2", "keye")
+_BUILDABLE = ("conv",) + tuple(RESNET_BLOCKS) + (
+    "transformer", "kanana2", "lfm2", "keye", "ouro")
 if MODEL_NAMES != _BUILDABLE:
     raise ImportError(
         f"config.MODEL_NAMES {MODEL_NAMES!r} out of lockstep with buildable "
@@ -92,6 +94,9 @@ def make_model(cfg: Dict[str, Any], model_rate: Optional[float] = None) -> Model
                           mask=cfg["mask"], compute_dtype=compute_dtype)
     elif name == "keye":
         model = make_keye(cfg["num_tokens"], cfg["keye"], model_rate,
+                          mask=cfg["mask"], compute_dtype=compute_dtype)
+    elif name == "ouro":
+        model = make_ouro(cfg["num_tokens"], cfg["ouro"], model_rate,
                           mask=cfg["mask"], compute_dtype=compute_dtype)
     else:
         raise ValueError("Not valid model name")

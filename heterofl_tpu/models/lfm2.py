@@ -84,17 +84,25 @@ def conv_mixer(lp, h, *, sc, compute_dtype=None):
 
 
 def gq_attention(lp, h, *, heads: int, kv_heads: int, head_dim: int, theta: float, scale,
-                 sc, head_norm, compute_dtype=None, attend=causal_gq_attention):
+                 sc, head_norm=None, compute_dtype=None, attend=causal_gq_attention):
     """A layer's grouped-query attention on the normed ``h`` ``[N, S, D]``,
     heads first from the projections to the output projection; ``head_norm(x,
-    g)`` the RMSNorm over each head's dims, ``head_dim`` the GLOBAL model's (the
-    rotary frequencies' denominator at every width), ``attend(q, k, v, scale)``."""
+    g)`` the RMSNorm over each head's dims (None: the family has none, and
+    the layer no ``attn.q_norm.g`` / ``attn.k_norm.g``: models/ouro.py),
+    ``head_dim`` the GLOBAL model's (the rotary frequencies' denominator at
+    every width), ``attend(q, k, v, scale)``."""
     q_heads = partial(linear_heads, heads=heads, compute_dtype=compute_dtype)
     kv = partial(linear_heads, heads=kv_heads, compute_dtype=compute_dtype)
     pos = jnp.arange(h.shape[1])
     with scope("gqa"):
-        q = head_norm(sc(q_heads(h, lp["attn.q.w"])), lp["attn.q_norm.g"])
-        k = head_norm(sc(kv(h, lp["attn.k.w"])), lp["attn.k_norm.g"])
+        # (each norm straight after its product, the order the LFM2 and Keye
+        # programs were traced and measured in)
+        q = sc(q_heads(h, lp["attn.q.w"]))
+        if head_norm is not None:
+            q = head_norm(q, lp["attn.q_norm.g"])
+        k = sc(kv(h, lp["attn.k.w"]))
+        if head_norm is not None:
+            k = head_norm(k, lp["attn.k_norm.g"])
         v = sc(kv(h, lp["attn.v.w"]))
     # the norm sits between the product and the turn, so the pair swap is
     # taken on the activations (kanana2 takes its rotary query's on the weight)

@@ -1,0 +1,246 @@
+"""Ouro-2.6B (``model_type: ouro``, the looped language model of "Scaling
+Latent Reasoning via Looped Language Models", arXiv:2510.25741) with HeteroFL
+width scaling.
+
+The published block (huggingface.co/ByteDance/Ouro-2.6B ``config.json``):
+decoder layers, all alike, of plain multi-head attention (``H`` query heads
+on ``H`` key/value heads, half-split RoPE over the whole head, no head norm,
+no bias) and a dense SwiGLU, with TWO RMSNorms round each sub-block (one on
+its input, one on its output before the residual add: a sandwich); untied
+embedding and head.  THE WHOLE STACK RUNS ``R = total_ut_steps`` TIMES on its
+own output and the SAME weights; after every pass the final norm, a
+one-column exit gate and the head read the state, and training minimises the
+loss expected under the exit distribution, less ``beta`` times that
+distribution's entropy.  ``x`` is ``[T, D]``, ``rms(x, g) = x / sqrt(mean(x^2)
++ eps) * g``, ``N`` the layers held:
+
+  layer l:   x = x + rms(attn(rms(x, g1_l)), g2_l);  x = x + rms(swiglu(rms(x, g3_l)), g4_l)
+  attn:      q, k, v = h Wq, h Wk, h Wv -> [T, H, d];  q, k = rope(., pos)
+             out = concat_heads(softmax_causal(q k^T / sqrt(d)) v) Wo
+  pass t:    for l in 0..N-1: x = layer_l(x);  h_t = rms(x, g_f);  x = h_t
+             z_t = h_t W_head (logits);  lam_t = sigmoid(h_t w_gate + b_gate)
+  exit:      p_t = lam_t * prod_{j<t}(1 - lam_j) for t < R;  p_R = prod_{j<R}(1 - lam_j)
+  loss:      mean over target positions i of  sum_t p_t[i] nll(z_t[i], y[i+1]) - beta H(p[i])
+             H(p) = -sum_t p_t log p_t
+
+``R`` = 1 is a plain sandwich-norm decoder under the plain next-token loss
+(``p_1`` = 1, ``H`` = 0; the gate then gets no gradient).  Out of training
+the published exit rule reads, a token, the first pass at which the exit
+distribution's running sum reaches ``early_exit_threshold`` (the last pass if
+none does: at the published 1 always the last); ``loss`` is then that pass's
+negative log-likelihood and ``score`` its logits.  In training every pass
+runs and ``score`` is the last pass's.
+
+HeteroFL slicing (the paper defines none for this family; stated in the
+benchmark configuration's ``assumed``): ``emb`` prefix of the hidden size
+(embedding columns, head rows, every norm gain, every matrix's model-side
+axis, the gate's rows); per-head prefixes in whole rotary pairs of the ``d``
+dims of the query heads and the key/value heads (one ``family``, ``head``); as
+in ``lfm2`` the rotary leaves are STORED with each head's pairs adjacent
+(stored ``2i`` = published ``i``, stored ``2i + 1`` = published ``i + d/2``)
+and turned by ``rope_interleaved``; ``ffn`` prefix of the SwiGLU's width;
+never sliced: the vocabulary, the gate's one column, ``total_ut_steps``.
+Softmax scale ``1/sqrt(active head dims)``; a Scaler after every sliced linear
+except the head and the exit gate (a categorical and a Bernoulli output:
+their logits are read by a softmax and a sigmoid, not by a further layer whose
+input statistics the Scaler is there to keep).  A leaf used ``R`` times a step
+is sliced, carried, decayed and counted like any leaf; its gradient is the sum
+over its uses.
+
+The passes are an outer ``lax.scan`` round the inner ``lax.scan`` over the
+stacked layers (``lfm2``'s run of alike layers), every layer APPLICATION
+under ``jax.checkpoint`` (it keeps its input alone: ``R x N`` states a step);
+the stacked weights are invariants of the outer scan.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..obs.trace import scope
+from ..ops.layers import (embed, exit_log_probs, linear as _linear, masked_logits,
+                          masked_rms_norm, pass_token_nll, scaler, swiglu)
+from .base import ModelDef, layer_leaves, normal_init, uniform_fan_in
+from .lfm2 import gq_attention
+from .spec import Group, ParamSpec
+
+
+def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
+              mask: bool = True, compute_dtype=None) -> ModelDef:
+    """``arch``: ``cfg['ouro']`` (config.process_control) at the GLOBAL widths;
+    ``model_rate`` builds the dense sub-model a client at that rate holds
+    (the sliced strategy and the equivalence tests)."""
+    from ..config import ceil_width
+
+    def cw(n, multiple=1):
+        k = ceil_width(n, model_rate)
+        return -(-k // multiple) * multiple
+
+    D, F = cw(arch["hidden_size"]), cw(arch["intermediate_size"])
+    L, R = int(arch["num_hidden_layers"]), int(arch["total_ut_steps"])
+    H, Hkv = int(arch["num_attention_heads"]), int(arch["num_key_value_heads"])
+    hd = cw(arch["head_dim"], 2)
+    # staticcheck: allow(no-float-coercion): build-time config scalars
+    theta, eps = float(arch["rope_theta"]), float(arch["rms_norm_eps"])
+    # staticcheck: allow(no-float-coercion): build-time config scalars
+    beta, threshold = float(arch["exit_entropy_beta"]), float(arch["early_exit_threshold"])
+    if H % Hkv:
+        raise ValueError(f"{H} query heads do not divide over {Hkv} key/value heads")
+    if R < 1 or L < 1:
+        raise ValueError(f"Not valid total_ut_steps / num_hidden_layers: {R} / {L} (each >= 1)")
+
+    def heads(name, n):
+        return Group(name, n * hd, kind="per_head", num_heads=n, multiple=2,
+                     coupled=False, family="head")
+
+    groups = {
+        "emb": Group("emb", D),
+        "q_head": heads("q_head", H),
+        "kv_head": heads("kv_head", Hkv),
+        "ffn": Group("ffn", F),
+        "gate": Group("gate", 1, kind="full"),
+    }
+    specs: Dict[str, ParamSpec] = {
+        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
+        "norm.g": ParamSpec({0: "emb"}),
+        "head.w": ParamSpec({0: "emb"}, label_axis=1),
+        "exit.w": ParamSpec({0: "emb", 1: "gate"}),
+        "exit.b": ParamSpec({0: "gate"}),
+    }
+    shapes: Dict[str, tuple] = {
+        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens),
+        "exit.w": (D, 1), "exit.b": (1,)}
+
+    def add(name, shape, axis_groups):
+        shapes[name] = shape
+        specs[name] = ParamSpec(axis_groups)
+
+    for i in range(L):
+        p = f"l{i}"
+        for j in (1, 2, 3, 4):  # the sandwich: in and out of the attention, in and out of the SwiGLU
+            add(f"{p}.norm{j}.g", (D,), {0: "emb"})
+        add(f"{p}.attn.q.w", (D, H * hd), {0: "emb", 1: "q_head"})
+        add(f"{p}.attn.k.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
+        add(f"{p}.attn.v.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
+        add(f"{p}.attn.o.w", (H * hd, D), {0: "q_head", 1: "emb"})
+        add(f"{p}.mlp.g.w", (D, F), {0: "emb", 1: "ffn"})
+        add(f"{p}.mlp.u.w", (D, F), {0: "emb", 1: "ffn"})
+        add(f"{p}.mlp.d.w", (F, D), {0: "ffn", 1: "emb"})
+
+    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
+        names = sorted(shapes)
+        params = {}
+        for name, k in zip(names, jax.random.split(key, len(names))):
+            shape = shapes[name]
+            if len(shape) == 1:  # norm gains 1; the gate's bias 0
+                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
+            elif name.startswith("embedding."):
+                params[name] = normal_init(k, shape, 1.0)
+            else:
+                params[name] = uniform_fan_in(k, shape, shape[0])
+        return params
+
+    linear = partial(_linear, compute_dtype=compute_dtype)
+
+    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
+              label_mask=None, bn_mode: str = "batch", bn_state=None,
+              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
+        if "pos_offset" in batch or attn_override is not None:
+            raise ValueError("ouro has no sequence-sharded path (mesh "
+                             "'data' axis must be 1)")
+        labels = batch["label"]
+        N, S = labels.shape
+        emb_act = groups["emb"].active_count(width_rate).astype(jnp.float32)
+        head_act = groups["q_head"].active_count(width_rate).astype(jnp.float32) / H
+        emb_mask = groups["emb"].mask(width_rate)
+
+        def sc(x):
+            return scaler(x, scaler_rate, train)
+
+        def rms(g, x):
+            return masked_rms_norm(x, g, emb_mask, emb_act, eps)
+
+        attention = partial(
+            gq_attention, heads=H, kv_heads=Hkv, head_dim=int(arch["head_dim"]), theta=theta,
+            scale=1.0 / jnp.sqrt(head_act), sc=sc, compute_dtype=compute_dtype)
+
+        @jax.checkpoint
+        def layer(x, lp):
+            """``(x, leaves) -> (x, None)``, the inner scan's body; for the
+            backward it keeps its input alone."""
+            x = x + rms(lp["norm2.g"], attention(lp, rms(lp["norm1.g"], x)))
+            h = rms(lp["norm3.g"], x)
+            y = swiglu(h, lp["mlp.g.w"], lp["mlp.u.w"], lp["mlp.d.w"], sc, compute_dtype)
+            return x + rms(lp["norm4.g"], y), None
+
+        run = [layer_leaves(params, i) for i in range(L)]
+        stacked = {k: jnp.stack([lp[k] for lp in run]) for k in run[0]}
+
+        def one_pass(x, _):
+            """The whole stack once on the shared weights, then the final
+            norm: the normed state is what the head and the gate read and
+            what the next pass starts from."""
+            with scope("loop/pass"):
+                x, _ = lax.scan(layer, x, stacked)
+            with scope("loop/exit"):
+                h = rms(params["norm.g"], x)
+            return h, h
+
+        _, hs = lax.scan(one_pass, embed(params["embedding.tok.w"], labels), None, length=R)
+
+        def head(x_):
+            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
+
+        with scope("loop/head"):
+            # [R, N, S] and the targets' weights [N, S]
+            nll, wt = pass_token_nll(hs, labels, head, sample_weight)
+        with scope("loop/exit"):
+            # no Scaler: the gate's logit is read by a sigmoid alone.  One
+            # column: float32 at "highest" precision costs nothing, and the
+            # exit distribution is then as exact as the reference's
+            gate = jnp.einsum("rnsd,d->rns", hs, params["exit.w"][:, 0],
+                              precision=lax.Precision.HIGHEST) + params["exit.b"][0]
+            logp = exit_log_probs(gate)
+            if train:
+                p, last = jnp.exp(logp), jnp.full((N, S), R - 1)
+                entropy = -jnp.sum(p * logp, axis=0)
+            else:
+                # the published exit rule: the first pass at which the
+                # running sum of p reaches the threshold, else the last
+                reached = jnp.cumsum(jnp.exp(logp[:-1]), axis=0) >= threshold
+                last = jnp.argmax(jnp.concatenate([reached, jnp.ones((1, N, S), bool)]), axis=0)
+                p = jax.nn.one_hot(last, R, axis=0, dtype=jnp.float32)
+                entropy = jnp.zeros((N, S), jnp.float32)
+            count = jnp.sum(wt)
+            loss = jnp.sum((jnp.sum(p * nll, axis=0) - beta * entropy) * wt) \
+                / jnp.maximum(count, 1e-12)
+            # sums over the target positions, each with its count last
+            # (obs.split_probes divides): the exit distribution's mean, each
+            # pass's mean negative log-likelihood, the expected pass
+            ranks = jnp.arange(1, R + 1, dtype=jnp.float32)[:, None, None]
+            counters = {
+                "loop_exit_share": jnp.append(jnp.sum(p * wt, axis=(1, 2)), count),
+                "loop_pass_nll": jnp.append(jnp.sum(nll * wt, axis=(1, 2)), count),
+                "loop_passes": jnp.stack([jnp.sum(ranks * p * wt), count])}
+        read = jnp.take_along_axis(hs, last[None, :, :, None], axis=0)[0]
+        # the logits [N, S, V] a caller may read (training does not: then the
+        # compiler drops them)
+        return {"score": head(read), "loss": loss, "counters": counters}, {}
+
+    meta = {"bn_sizes": {}, "kind": "ouro", "num_tokens": num_tokens,
+            "arch": dict(arch), "shapes": dict(shapes),
+            # what analysis.summary.module_table cannot read off the leaves:
+            # every layer leaf, the final norm, the gate and the head are
+            # used once a pass, the embedding once a step
+            "profile": {"routed_share": 1.0, "passes": R,
+                        "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
+            # what apply's "counters" holds; the engines carry them as obs_
+            # probes when telemetry is on and obs.split_probes finishes them
+            "counters": {"loop_exit_share": (R + 1,), "loop_pass_nll": (R + 1,),
+                         "loop_passes": (2,)}}
+    return ModelDef("ouro", init, apply, specs, groups, [], meta)

@@ -381,6 +381,15 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # layer kept for its backward over selecting query blocks
                 num, den = (float(c) for c in x.sum(axis=0))
                 rec[base] = num / den if den else 0.0
+            elif base.startswith("loop_"):
+                # a looped model's counters (ISSUE 40), each per-device sums
+                # over target positions with their count last: loop_exit_share
+                # = the exit distribution's mean a pass (sums to 1),
+                # loop_pass_nll = each pass's mean negative log-likelihood,
+                # loop_passes = the expected pass (sum of t * p_t), a scalar
+                *nums, den = (float(c) for c in x.sum(axis=0))
+                vals = [n / den if den else 0.0 for n in nums]
+                rec[base] = vals[0] if base == "loop_passes" else vals
             elif base == "nonfinite":
                 rec["nonfinite"] = int(x[0, 0])
             elif base.endswith("_sq"):

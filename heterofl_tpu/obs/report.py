@@ -53,6 +53,7 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
     counts: Dict[str, int] = {}
     watchdog: List[Dict[str, Any]] = []
     moe = {"rounds": 0, "tokens": None, "routed": 0.0, "held": 0.0, "dropped": 0}
+    loop: List[Dict[str, Any]] = []  # a looped model's counters, a round each
     setup: List[spans.Span] = []  # every span filed with its id
     with open(events_path) as f:
         for line in f:
@@ -78,10 +79,20 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
                 moe["routed"] += args["moe_assign"][0]
                 moe["held"] += args["moe_assign"][1]
                 moe["dropped"] += int(args.get("moe_dropped", 0))
+            if name == "probes" and "loop_exit_share" in args:
+                loop.append(args)
     out = {"path": events_path, "events_by_name": counts,
            "watchdog_trips": watchdog[:16]}
     if moe["rounds"]:
         out["moe"] = moe
+    if loop:
+        # a looped model's counters (obs.split_probes), the rounds' mean
+        def mean(key):
+            return [sum(col) / len(loop) for col in zip(*(r[key] for r in loop))]
+
+        out["loop"] = {"rounds": len(loop), "exit_share": mean("loop_exit_share"),
+                       "pass_nll": mean("loop_pass_nll"),
+                       "passes": sum(r["loop_passes"] for r in loop) / len(loop)}
     if setup:
         out["setup"] = spans.summarize(setup)
     return out
@@ -152,6 +163,13 @@ def render_events(ev: Optional[Dict[str, Any]]) -> List[str]:
                 f"({100.0 * share:.2f} %), dropped {moe['dropped']}")
             lines.append("    tokens per held expert: " + " ".join(
                 f"{t:g}" for t in moe["tokens"]))
+        loop = ev.get("loop")
+        if loop:
+            lines.append(
+                f"  loop over {loop['rounds']} rounds: expected pass {loop['passes']:.3f}; "
+                "exit share a pass " + " ".join(f"{p:.4f}" for p in loop["exit_share"])
+                + "; mean negative log-likelihood a pass "
+                + " ".join(f"{v:.4f}" for v in loop["pass_nll"]))
         if ev["watchdog_trips"]:
             lines.append(f"  WATCHDOG TRIPPED {len(ev['watchdog_trips'])}x: "
                          f"{ev['watchdog_trips'][0]}")

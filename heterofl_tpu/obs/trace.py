@@ -101,13 +101,24 @@ MIXER_SCOPES = ("shortconv", "shortconv/gate", "gqa")
 #: :data:`MIXER_SCOPES` is one; read through benchmark/scope_reduce_keye.py.
 SPARSE_SCOPES = ("sparse/index", "sparse/select")
 
+#: What ISSUE 40 added: the loop of models/ouro.py, whose layer stack runs
+#: ``total_ut_steps`` times on shared weights.  ``loop/pass`` = one pass of
+#: the stack (its attention stays under ``gqa`` / ``rope`` / ``attn`` inside
+#: it, its norms and products under ``norm`` / ``linear``); ``loop/head`` =
+#: the head and the cross entropy of every pass's read-out, block by block
+#: (``ops.layers.pass_token_nll``); ``loop/exit`` = the final norm after a
+#: pass, the exit gate, the exit distribution, the mixing of the passes'
+#: losses under it and its entropy.  A fifth tuple for the reason
+#: :data:`MIXER_SCOPES` is one; read through benchmark/scope_reduce_ouro.py.
+LOOP_SCOPES = ("loop/pass", "loop/head", "loop/exit")
+
 #: Version of the vocabulary AND of where it is entered.  jax keeps metadata
 #: out of the persistent compile cache's key, so a program whose only change
 #: is a scope would load the executable cached before the change, without
 #: the new names, silently; ``utils.compile_cache`` folds this number into
 #: the key.  Bump it with every change to :data:`SCOPES` or to where a scope
 #: is entered (one cold compile per program, once).
-SCOPE_VERSION = 5
+SCOPE_VERSION = 6
 
 #: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
 KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
@@ -120,7 +131,7 @@ EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_b
 
 
 def _known(name: str) -> str:
-    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES + SPARSE_SCOPES:
+    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES + SPARSE_SCOPES + LOOP_SCOPES:
         raise ValueError(f"Not valid scope: {name!r} (obs.trace.SCOPES)")
     return name
 

@@ -343,10 +343,60 @@ def next_token_loss(xn: jnp.ndarray, labels: jnp.ndarray, logits_of,
     return jnp.sum(sums) / jnp.maximum(jnp.sum(wt), 1e-12)
 
 
+def pass_token_nll(hs: jnp.ndarray, labels: jnp.ndarray, logits_of,
+                   sample_weight: Optional[jnp.ndarray] = None,
+                   block: int = LOSS_BLOCK) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`next_token_loss` before its mean, under each of ``R`` read-outs
+    of a looped model (models/ouro.py): ``hs`` ``[R, N, S, D]`` the final
+    normed state after each pass, ``labels``, ``logits_of`` and
+    ``sample_weight`` as there.  Returns the negative log-likelihood ``[R, N,
+    S]`` of position ``t`` against token ``t + 1`` and the weight ``[N, S]``
+    of each position's target (zero at a row's last position, which has none
+    and is scored against a filler).  A (pass, position) is a row of its own
+    to the head, so the ``R * N * S`` rows go through it ``block`` at a time,
+    each block under ``jax.checkpoint``: ``[S, V]`` logits of one pass are
+    never held (S = 2,048, V = 49,152: 403 MB a copy, four passes, and cross
+    entropy keeps several)."""
+    R, N, S, D = hs.shape
+    T = R * N * S
+    w = jnp.ones((N, S), jnp.float32) if sample_weight is None else \
+        jnp.broadcast_to(sample_weight, (N, S)).astype(jnp.float32)
+    wt = jnp.concatenate([w[:, 1:] * w[:, :-1], jnp.zeros((N, 1), jnp.float32)], axis=1)
+    tgt = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
+    tgt = jnp.broadcast_to(tgt, (R, N, S)).reshape(T)
+    c = block if T % block == 0 else T
+
+    def block_nll(xs):
+        x_c, t_c = xs
+        logits = logits_of(x_c)
+        with scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.take_along_axis(logp, t_c[:, None], axis=-1)[:, 0]
+
+    nll = lax.map(jax.checkpoint(block_nll), (hs.reshape(T // c, c, D), tgt.reshape(T // c, c)))
+    return nll.reshape(R, N, S), wt
+
+
+def exit_log_probs(gate: jnp.ndarray) -> jnp.ndarray:
+    """The log of a looped model's exit distribution over its ``R`` passes
+    from the exit gate's logits ``gate`` ``[R, ...]`` (pass first): with
+    ``lam_t = sigmoid(gate_t)``, ``p_t = lam_t * prod_{j<t}(1 - lam_j)`` for
+    ``t < R`` and ``p_R = prod_{j<R}(1 - lam_j)``: the last pass takes what is
+    left, whatever its own gate says, so the ``p_t`` sum to 1.  In logs
+    (``log lam = log_sigmoid(g)``, ``log(1 - lam) = log_sigmoid(-g)``), so a
+    saturated gate gives a finite log and a finite gradient.  ``R`` = 1: ``p_1``
+    = 1."""
+    zero = jnp.zeros_like(gate[:1])
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-gate[:-1]), axis=0)   # log prod_{j<=t}(1 - lam_j)
+    return jnp.concatenate([zero, stayed], axis=0) \
+        + jnp.concatenate([jax.nn.log_sigmoid(gate[:-1]), zero], axis=0)
+
+
 # ---------------------------------------------------------------------------
 # Latent attention + shared-and-routed experts (models/kanana2.py); gated
 # short convolution + grouped-query attention (models/lfm2.py); a learned
-# sparse-attention indexer over grouped-query attention (models/keye.py)
+# sparse-attention indexer over grouped-query attention (models/keye.py); a
+# looped decoder's read-outs (models/ouro.py: pass_token_nll, exit_log_probs)
 # ---------------------------------------------------------------------------
 
 @scoped("norm")

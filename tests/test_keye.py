@@ -448,6 +448,45 @@ def _parent_moe_route(h, w_router, bias, top_k, scaling, sum_eps=0.0):
         return sel.astype(jnp.int32), w / total * scaling
 
 
+def _parent_gq_attention(lp, h, *, heads, kv_heads, head_dim, theta, scale, sc, head_norm,
+                         compute_dtype=None, attend=L.causal_gq_attention):
+    """`models.lfm2.gq_attention` as the parent commit had it, word for word
+    (before ISSUE 40 gave ``head_norm`` a None for a family without one)."""
+    from functools import partial
+
+    from heterofl_tpu.obs.trace import scope
+    from heterofl_tpu.ops.layers import heads_linear, linear_heads, rope_interleaved, rope_swap
+
+    q_heads = partial(linear_heads, heads=heads, compute_dtype=compute_dtype)
+    kv = partial(linear_heads, heads=kv_heads, compute_dtype=compute_dtype)
+    pos = jnp.arange(h.shape[1])
+    with scope("gqa"):
+        q = head_norm(sc(q_heads(h, lp["attn.q.w"])), lp["attn.q_norm.g"])
+        k = head_norm(sc(kv(h, lp["attn.k.w"])), lp["attn.k_norm.g"])
+        v = sc(kv(h, lp["attn.v.w"]))
+    q = rope_interleaved(q, rope_swap(q), pos, theta, axis=2, full=head_dim)
+    k = rope_interleaved(k, rope_swap(k), pos, theta, axis=2, full=head_dim)
+    if compute_dtype is not None:
+        q, k, v = (t.astype(compute_dtype) for t in (q, k, v))
+    o = attend(q, k, v, scale)
+    with scope("gqa"):
+        return sc(heads_linear(o.astype(jnp.float32), lp["attn.o.w"], compute_dtype))
+
+
+def _gq_layer(h, wq, wk, wv, wo, gq, gk, head_norm="rms"):
+    """A layer's grouped-query attention as the LFM2 and Keye models call it
+    (4 query heads on 2 key/value heads of 6, an RMSNorm on every head); with
+    ``head_norm`` None, as Ouro calls it."""
+    from heterofl_tpu.models import lfm2
+
+    lp = {"attn.q.w": wq, "attn.k.w": wk, "attn.v.w": wv, "attn.o.w": wo,
+          "attn.q_norm.g": gq, "attn.k_norm.g": gk}
+    norm = None if head_norm is None else (
+        lambda x, g: L.masked_rms_norm(x, g, jnp.ones(6), 6.0, 1e-5))
+    return lfm2.gq_attention(lp, h, heads=4, kv_heads=2, head_dim=6, theta=1e4, scale=0.4,
+                             sc=lambda x: x / 0.5, head_norm=norm)
+
+
 def _seeded(seed, *shapes):
     return [jax.random.normal(k, s) for k, s in
             zip(jax.random.split(jax.random.key(seed), len(shapes)), shapes)]
@@ -463,16 +502,20 @@ UNCHANGED = {
     "sigmoid_moe_route": (
         lambda h, w, b: L.moe_route(h, w, 0.1 * b, 4, 2.5, 1e-6)[1],
         _seeded(23, (40, 12), (12, 16), (16,))),
+    # ISSUE 40: `gq_attention(head_norm=None)`; a caller that passes its head norm
+    "gq_attention_with_its_head_norm": (
+        _gq_layer, _seeded(24, (2, 24, 16), (16, 24), (16, 12), (16, 12), (24, 16), (6,), (6,))),
 }
 
 
 @pytest.mark.parametrize("name", sorted(UNCHANGED))
 def test_the_block_loop_and_the_router_give_their_callers_what_the_parent_gave(name, monkeypatch):
-    """`_causal_blocks` gained a mask and `moe_route` softmax scoring; without
-    them a caller traces to the SAME program as at the parent commit (the
-    jaxprs of value and gradient are equal as text) and returns the same
-    bits.  The parent's two bodies are kept above, word for word, and stand
-    in for this tree's the second time round."""
+    """`_causal_blocks` gained a mask, `moe_route` softmax scoring and (ISSUE
+    40) `gq_attention` a None for its head norm; without them a caller traces
+    to the SAME program as at the parent commit (the jaxprs of value and
+    gradient are equal as text) and returns the same bits.  The parents'
+    bodies are kept above, word for word, and stand in for this tree's the
+    second time round."""
     fn, args = UNCHANGED[name]
 
     def run():
@@ -485,10 +528,37 @@ def test_the_block_loop_and_the_router_give_their_callers_what_the_parent_gave(n
     got, got_text = run()
     monkeypatch.setattr(L, "_causal_blocks", lambda *a: _parent_causal_blocks(*a[:7]))
     monkeypatch.setattr(L, "moe_route", _parent_moe_route)
+    monkeypatch.setattr("heterofl_tpu.models.lfm2.gq_attention", _parent_gq_attention)
     want, want_text = run()
     assert got_text == want_text
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)
+
+
+def test_gq_attention_without_a_head_norm_is_the_layer_with_an_identity_for_one():
+    """`gq_attention(head_norm=None)`, Ouro's call: the parent's layer handed
+    an identity for its head norm, to the bit, value and gradients; the two
+    gains are then not read."""
+    args = UNCHANGED["gq_attention_with_its_head_norm"][1]
+
+    def probe(fn):
+        def total(*a):
+            out = fn(*a)
+            return jnp.sum(out * jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape))
+        return jax.value_and_grad(total, argnums=tuple(range(len(args))))(*args)
+
+    def parent(h, wq, wk, wv, wo, gq, gk):
+        lp = {"attn.q.w": wq, "attn.k.w": wk, "attn.v.w": wv, "attn.o.w": wo,
+              "attn.q_norm.g": gq, "attn.k_norm.g": gk}
+        return _parent_gq_attention(lp, h, heads=4, kv_heads=2, head_dim=6, theta=1e4, scale=0.4,
+                                    sc=lambda x: x / 0.5, head_norm=lambda x, g: x)
+
+    got, want = probe(lambda *a: _gq_layer(*a, head_norm=None)), probe(parent)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    assert not np.asarray(got[1][5]).any() and not np.asarray(got[1][6]).any()
+    with_norm = probe(_gq_layer)
+    assert np.abs(np.asarray(with_norm[0]) - np.asarray(got[0])) > 1e-3 * np.abs(np.asarray(got[0]))
 
 
 # ---------------------------------------------------------------------------

@@ -267,3 +267,19 @@ def test_the_indexers_scopes_are_a_vocabulary_of_their_own():
     with pytest.raises(ValueError, match="Not valid scope"):
         trace.scope("sparse")
     assert trace.SCOPE_VERSION >= 5
+
+
+def test_the_loops_scopes_are_a_vocabulary_of_their_own():
+    """`LOOP_SCOPES` (ISSUE 40) is disjoint from the four older tuples, which
+    the accepted benchmark's readers mirror name for name; `scope()` takes all
+    five and refuses a neighbour of theirs.  That the Ouro round enters them,
+    with the attention's scopes inside `loop/pass`: tests/test_ouro.py."""
+    older = trace.SCOPES + trace.EXTRA_SCOPES + trace.MIXER_SCOPES + trace.SPARSE_SCOPES
+    assert trace.LOOP_SCOPES == ("loop/pass", "loop/head", "loop/exit")
+    assert not set(trace.LOOP_SCOPES) & set(older)
+    for s in older + trace.LOOP_SCOPES:
+        with trace.scope(s):
+            pass
+    with pytest.raises(ValueError, match="Not valid scope"):
+        trace.scope("loop/gate")
+    assert trace.SCOPE_VERSION >= 6

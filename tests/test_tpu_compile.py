@@ -187,6 +187,38 @@ def test_attention_kernels_compile_under_attn(one_chip, family, vmapped, monkeyp
                           and int(m[1]) > widest]
 
 
+@pytest.mark.parametrize("vmapped", [False, True], ids=["cell", "vmap1"])
+def test_gq_kernels_compile_at_heads_of_128_in_groups_of_one(one_chip, vmapped, monkeypatch):
+    """The grouped-query kernels at the Ouro cell's shape (ISSUE 40): one row
+    of 2,048 positions, 16 query heads on 16 key/value heads of 128, so a group
+    is ONE query head and a tile ``[128, 512]`` where the LFM2 cell's is
+    ``[64, 4 x 512]``; bare and under the ``vmap`` over the one client slot of
+    a chunk, with a per-client scale.  Both custom calls carry the ``attn``
+    scope, and no score block goes through HBM."""
+    from heterofl_tpu.ops.layers import causal_gq_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape, lead = (1, 16, 2048, 128), ((1,) if vmapped else ())
+
+    def grads(q, k, v, scale):
+        return jax.grad(lambda *o: jnp.sum(causal_gq_attention(*o, scale) ** 2),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    avals = [jax.ShapeDtypeStruct(lead + shape, jnp.float32, sharding=one_chip)] * 3 \
+        + [jax.ShapeDtypeStruct(lead, jnp.float32, sharding=one_chip)]
+    text = _compile(jax.vmap(grads) if vmapped else grads, *avals,
+                    kernels=("gq_attn_fwd", "gq_attn_bwd"))
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    op_names = sorted(re.search(r'op_name="([^"]*)"', line).group(1) for line in calls)
+    assert len(op_names) == 2
+    assert re.search(r"jvp\(attn\)\)?/gq_attn_fwd/pallas_call$", op_names[0])
+    assert re.search(r"transpose\((vmap\()?jvp\(attn\)\)+/gq_attn_bwd/pallas_call$", op_names[1])
+    found = re.findall(r"f32\[(?:1,)?1,16,(\d+),(\d+)\]", text)
+    assert found and not [m for m in found if int(m[0]) >= 128 and int(m[1]) > 2048]
+    assert not re.search(r"f32\[(?:1,)?1,16,2048,2048\]", text)
+
+
 #: bytes of an element, for the shapes a relayout of the block can have
 _ITEMSIZE = {"f32": 4, "bf16": 2}
 
