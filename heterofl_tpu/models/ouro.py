@@ -49,8 +49,19 @@ over its uses.
 
 The passes are an outer ``lax.scan`` round the inner ``lax.scan`` over the
 stacked layers (``lfm2``'s run of alike layers), every layer APPLICATION
-under ``jax.checkpoint`` (it keeps its input alone: ``R x N`` states a step);
-the stacked weights are invariants of the outer scan.
+under ``jax.checkpoint``; the stacked weights are invariants of the outer scan.
+For the backward an application keeps its input (``R x N`` states a step) and,
+by name (:func:`kept`), what costs more to compute again than to write once
+and read back: where the fused kernels run, the three residuals of
+``gq_attn_bwd`` -- ``gq_attn_fwd``'s output, its log-sum-exp and the kernels'
+bfloat16 operands, so that the backward runs no second forward kernel and no
+second ``q`` / ``k`` / ``v`` product, turn or cast -- and the SwiGLU's
+down-projected output, the input of ``norm4``, so that it runs no second
+down-projection.  NOT the gate and up pre-activations nor the attention's
+projected output: through the two scans a kept value is copied four times
+(into the inner scan's stack, that stack into the outer scan's, and back), and
+on the chip those cost more in traffic than their products do again (PERF.md,
+PR 41).
 """
 
 from __future__ import annotations
@@ -61,13 +72,30 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
-from ..ops.layers import (embed, exit_log_probs, linear as _linear, masked_logits,
-                          masked_rms_norm, pass_token_nll, scaler, swiglu)
+from ..ops.layers import (embed, exit_log_probs, gq_attention_tile, linear as _linear,
+                          masked_logits, masked_rms_norm, pass_token_nll, scaler, swiglu)
 from .base import ModelDef, layer_leaves, normal_init, uniform_fan_in
 from .lfm2 import gq_attention
 from .spec import Group, ParamSpec
+
+#: the name a layer application's SwiGLU output carries (``checkpoint_name``;
+#: :func:`kept`): the down-projected ``y``, the input of the sandwich's ``norm4``
+MLP_OUT = "mlp_out"
+
+
+def kept():
+    """What a layer application's ``jax.checkpoint`` keeps for the backward
+    beside its input: the values that carry one of four names, the three
+    residuals of the attention's backward kernel (``gq_attn_fwd``'s output and
+    log-sum-exp and the kernels' bfloat16 operands, named where the kernel is
+    called; the block loop names nothing) and the SwiGLU's down-projected
+    output.  None: a bare checkpoint, the input alone."""
+    from ..ops.pallas_attention import GQ_LSE, GQ_OPS, GQ_OUT  # Pallas, imported where an Ouro model is built
+
+    return jax.checkpoint_policies.save_only_these_names(GQ_OUT, GQ_LSE, GQ_OPS, MLP_OUT)
 
 
 def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
@@ -168,15 +196,20 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         attention = partial(
             gq_attention, heads=H, kv_heads=Hkv, head_dim=int(arch["head_dim"]), theta=theta,
             scale=1.0 / jnp.sqrt(head_act), sc=sc, compute_dtype=compute_dtype)
+        policy = kept()
+        # layer applications a step, and those whose attention runs the kernel
+        # that names its results under a policy that keeps them
+        applied = R * L
+        named = applied if policy is not None and gq_attention_tile(S, hd) is not None else 0
 
-        @jax.checkpoint
+        @partial(jax.checkpoint, policy=policy)
         def layer(x, lp):
             """``(x, leaves) -> (x, None)``, the inner scan's body; for the
-            backward it keeps its input alone."""
+            backward it keeps its input and what :func:`kept` names."""
             x = x + rms(lp["norm2.g"], attention(lp, rms(lp["norm1.g"], x)))
             h = rms(lp["norm3.g"], x)
             y = swiglu(h, lp["mlp.g.w"], lp["mlp.u.w"], lp["mlp.d.w"], sc, compute_dtype)
-            return x + rms(lp["norm4.g"], y), None
+            return x + rms(lp["norm4.g"], checkpoint_name(y, MLP_OUT)), None
 
         run = [layer_leaves(params, i) for i in range(L)]
         stacked = {k: jnp.stack([lp[k] for lp in run]) for k in run[0]}
@@ -226,7 +259,8 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             counters = {
                 "loop_exit_share": jnp.append(jnp.sum(p * wt, axis=(1, 2)), count),
                 "loop_pass_nll": jnp.append(jnp.sum(nll * wt, axis=(1, 2)), count),
-                "loop_passes": jnp.stack([jnp.sum(ranks * p * wt), count])}
+                "loop_passes": jnp.stack([jnp.sum(ranks * p * wt), count]),
+                "loop_kept": jnp.array([named, applied], jnp.float32)}
         read = jnp.take_along_axis(hs, last[None, :, :, None], axis=0)[0]
         # the logits [N, S, V] a caller may read (training does not: then the
         # compiler drops them)
@@ -241,6 +275,9 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                         "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
             # what apply's "counters" holds; the engines carry them as obs_
             # probes when telemetry is on and obs.split_probes finishes them
+            # (`loop_kept`: a (numerator, denominator) pair, the layer
+            # applications whose attention kernel's results the layer kept
+            # for its backward over the layer applications)
             "counters": {"loop_exit_share": (R + 1,), "loop_pass_nll": (R + 1,),
-                         "loop_passes": (2,)}}
+                         "loop_passes": (2,), "loop_kept": (2,)}}
     return ModelDef("ouro", init, apply, specs, groups, [], meta)

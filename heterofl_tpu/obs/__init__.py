@@ -386,10 +386,13 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # over target positions with their count last: loop_exit_share
                 # = the exit distribution's mean a pass (sums to 1),
                 # loop_pass_nll = each pass's mean negative log-likelihood,
-                # loop_passes = the expected pass (sum of t * p_t), a scalar
+                # loop_passes = the expected pass (sum of t * p_t), a scalar;
+                # loop_kept (ISSUE 41), of layer applications and a scalar
+                # too = the share whose attention kernel's output the layer
+                # kept for its backward
                 *nums, den = (float(c) for c in x.sum(axis=0))
                 vals = [n / den if den else 0.0 for n in nums]
-                rec[base] = vals[0] if base == "loop_passes" else vals
+                rec[base] = vals[0] if base in ("loop_passes", "loop_kept") else vals
             elif base == "nonfinite":
                 rec["nonfinite"] = int(x[0, 0])
             elif base.endswith("_sq"):

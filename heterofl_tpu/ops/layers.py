@@ -841,3 +841,16 @@ def moe_experts(h, sel, w, experts, first: int, sc, compute_dtype=None,
     n_held = jnp.sum(counts)
     assign_ct = jnp.stack([jnp.int32(A), n_held, n_held - computed]).astype(jnp.float32)
     return y, {"tokens": counts.astype(jnp.float32), "assign": assign_ct}
+
+
+def gq_attention_tile(S: int, d: int):
+    """The tile :func:`causal_gq_attention` gives its fused kernels at ``S``
+    positions and heads of ``d`` dims, None where it takes the block loop: its
+    own test, said once more for a caller that counts (``models/ouro.py``), as
+    :func:`selected_attention_tile` is.  (At the file's end: a Mosaic kernel's
+    compile-cache key holds its call stack, so no line above moves.)"""
+    if jax.default_backend() != "tpu":
+        return None
+    from . import pallas_attention
+
+    return pallas_attention.gq_tile_for(S, d)

@@ -449,7 +449,7 @@ def _gq_flash(q, k, v, tq, tk, interpret):
 def _gq_flash_fwd(q, k, v, tq, tk, interpret):
     ops = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
     o, lse = _call_gq_fwd(*ops, tq, tk, interpret)
-    return o, (ops, o, lse)
+    return _gq_named(ops, o, lse)
 
 
 def _gq_flash_bwd(tq, tk, interpret, res, do):
@@ -750,3 +750,29 @@ def fused_selected_attention(q, k, v, scale, select, block: int, *, block_q: int
     qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 2, 3) for x in (q, k, v))
     return jnp.swapaxes(_sel_flash(qt * scale, kt, vt, sel, block_q, block_k, first, interpret),
                         2, 3)
+
+
+# ---------------------------------------------------------------------------
+# WHAT ``gq_attn_bwd`` READS CARRIES NAMES TOO (here, at the file's end, and not
+# beside ``_gq_flash_fwd``: a Mosaic kernel's compile-cache key holds its call
+# stack, so no line above moves).  ``o`` and the log-sum-exp of ``gq_attn_fwd``
+# are the primal output and the backward kernel's residuals at once, and the
+# third residual is the kernels' own operands: ``q`` (scaled), ``k`` and ``v``
+# in bfloat16 with the positions minor, half the bytes of the float32 heads
+# they were cast from.  A ``jax.checkpoint`` whose policy saves ``GQ_OUT``,
+# ``GQ_LSE`` and ``GQ_OPS`` runs in its backward no second forward kernel and
+# nothing of what leads up to it (``models/ouro.py``'s layer: no second ``q`` /
+# ``k`` / ``v`` product, turn or cast).  Under no such policy
+# (``models/lfm2.py``'s layers) a name is the identity.
+# ---------------------------------------------------------------------------
+
+GQ_OUT, GQ_LSE, GQ_OPS = "gq_out", "gq_lse", "gq_ops"
+
+
+def _gq_named(ops, o, lse):
+    """What ``_gq_flash_fwd`` returns, ``(o, residuals)``, each of the three
+    residuals under its name (``o`` in the primal output too)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = checkpoint_name(o, GQ_OUT), checkpoint_name(lse, GQ_LSE)
+    return o, (checkpoint_name(ops, GQ_OPS), o, lse)

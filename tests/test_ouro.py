@@ -449,13 +449,16 @@ def test_ouro_counters_ride_the_metrics(tmp_path):
     1 --, each pass's mean negative log-likelihood -- whose mixture under the
     exit shares is near the logged loss plus beta times an entropy of at most
     log 3 --, and the expected pass, between 1 and 3; `obs.report` renders
-    them."""
+    them.  `obs_loop_kept` (ISSUE 41) rides beside them, a pair a device: 0 of
+    the 8 clients' 3 x 2 layer applications here, where the block loop names
+    nothing."""
     from heterofl_tpu.obs import report, split_probes
 
     cfg, data = _round_case()
     _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
     assert ms["obs_loop_exit_share"].shape == ms["obs_loop_pass_nll"].shape == (2 * 4,)
-    assert ms["obs_loop_passes"].shape == (2 * 2,)
+    assert ms["obs_loop_passes"].shape == ms["obs_loop_kept"].shape == (2 * 2,)
+    assert ms["obs_loop_kept"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 3 * 2]
     # 8 clients x 1 step x 2 rows x 31 target positions, over the two devices
     assert ms["obs_loop_exit_share"].reshape(2, 4)[:, -1].sum() == 8 * 2 * 31
     clean, rounds = split_probes(dict(ms), 2)
@@ -466,6 +469,7 @@ def test_ouro_counters_ride_the_metrics(tmp_path):
     assert all(3.0 < v < 6.0 for v in rec["loop_pass_nll"])  # near log 96 = 4.56
     assert rec["loop_passes"] == pytest.approx(
         sum((t + 1) * p for t, p in enumerate(rec["loop_exit_share"])), rel=1e-5)
+    assert rec["loop_kept"] == 0.0
     assert not [k for k in clean if k.startswith("obs_")]
     events = tmp_path / "events.jsonl"
     events.write_text(json.dumps({"v": 1, "t": 0.0, "name": "probes", "cat": "obs", "ph": "i",
@@ -476,39 +480,202 @@ def test_ouro_counters_ride_the_metrics(tmp_path):
                for line in report.render_events(ev))
 
 
-def test_ouro_model_takes_the_gq_kernels_where_a_tpu_gives_them_tiles(monkeypatch):
-    """The model at shapes the fused kernels tile (heads of 128 in groups of
-    ONE query head a key/value head, rows of 128 positions) with jax reporting
-    a TPU -- the kernels in interpret mode, the one thing steered here: the
-    gradient's program calls `gq_attn_fwd` / `gq_attn_bwd`, and loss and every
-    leaf's gradient are the block loop's of the same model on the CPU to the
-    kernels' bfloat16 operands."""
+#: shapes the fused kernels tile: heads of 128 in groups of ONE query head a
+#: key/value head, rows of 128 positions (three layers, two passes: no two of
+#: the passes, the layers and the rows are as many)
+TILED = dict(bptt=128, head_dim=128, num_attention_heads=2, num_key_value_heads=2,
+             hidden_size=64, intermediate_size=64, total_ut_steps=2, num_hidden_layers=3)
+
+
+def _bare_checkpoint(monkeypatch):
+    """The model as it was before ISSUE 41: each layer application under a
+    bare ``jax.checkpoint`` that keeps its input alone."""
+    from heterofl_tpu.models import ouro
+
+    monkeypatch.setattr(ouro, "kept", lambda: None)
+
+
+def _on_the_kernels(monkeypatch):
+    """jax reports a TPU and the kernels run in interpret mode: the one thing
+    steered in the tests below."""
     from functools import partial
 
     from heterofl_tpu.ops import pallas_attention as PA
-    from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns
 
-    _, model, params, tokens, lm, _ = _ouro_case(
-        bptt=128, head_dim=128, num_attention_heads=2, num_key_value_heads=2, hidden_size=64,
-        intermediate_size=64, total_ut_steps=2)
-    assert PA.gq_tile_for(128, 128) == 128
-
-    def loss_and_grads():
-        return jax.value_and_grad(lambda p: model.apply(
-            p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"])(params)
-
-    want, want_grads = loss_and_grads()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(PA, "fused_gq_attention", partial(PA.fused_gq_attention, interpret=True))
-    jaxpr = jax.make_jaxpr(lambda p: loss_and_grads()[1])(params)
+
+
+def _loss_counters_and_grads(model, params, tokens, lm):
+    def loss(p):
+        out, _ = model.apply(p, {"label": tokens}, train=True, label_mask=lm)
+        return out["loss"], out["counters"]
+
+    (value, counters), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    return value, counters, grads
+
+
+@pytest.mark.parametrize("policy", ["kept", "bare"])
+def test_ouro_model_takes_the_gq_kernels_where_a_tpu_gives_them_tiles(policy, monkeypatch):
+    """The model at shapes the fused kernels tile with jax reporting a TPU:
+    the gradient's program calls `gq_attn_fwd` / `gq_attn_bwd`, and loss and
+    every leaf's gradient are the block loop's of the same model on the CPU to
+    the kernels' bfloat16 operands, whether the layer keeps the kernel's
+    results for its backward (ISSUE 41) or its input alone.  `loop_kept`
+    counts the 2 x 3 layer applications that kept them: all under the policy
+    on the kernels, none on the block loop or under a bare checkpoint."""
+    from heterofl_tpu.obs import split_probes
+    from heterofl_tpu.ops import pallas_attention as PA
+    from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns
+
+    if policy == "bare":
+        _bare_checkpoint(monkeypatch)
+    _, model, params, tokens, lm, _ = _ouro_case(**TILED)
+    assert PA.gq_tile_for(128, 128) == 128 and model.meta["counters"]["loop_kept"] == (2,)
+    want, counters, want_grads = _loss_counters_and_grads(model, params, tokens, lm)
+    assert counters["loop_kept"].tolist() == [0.0, 6.0]
+    _on_the_kernels(monkeypatch)
+    jaxpr = jax.make_jaxpr(lambda p: _loss_counters_and_grads(model, p, tokens, lm)[2])(params)
     kernels = [e.params["name"] for e in iter_eqns(jaxpr) if e.primitive.name == "pallas_call"]
     assert set(kernels) == {"gq_attn_fwd", "gq_attn_bwd"}, kernels
-    got, got_grads = loss_and_grads()
+    got, counters, got_grads = _loss_counters_and_grads(model, params, tokens, lm)
+    assert counters["loop_kept"].tolist() == [6.0 if policy == "kept" else 0.0, 6.0]
+    _, rounds = split_probes({"obs_loop_kept": np.asarray(counters["loop_kept"])}, 1)
+    assert rounds[0]["loop_kept"] == (1.0 if policy == "kept" else 0.0)
     assert float(got) == pytest.approx(float(want), rel=2e-3)
     for name, w in want_grads.items():
         scale = float(jnp.abs(w).max())
         np.testing.assert_allclose(got_grads[name], w, rtol=0, atol=3e-2 * scale + 1e-12,
                                    err_msg=name)
+
+
+def _kernels_by_scan_body(jaxpr):
+    """(the Pallas kernels a program calls outside any `scan`, those each
+    `scan` body calls itself -- not through a scan inside it --, in the
+    program's order; a body that calls none is left out)."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import _sub_jaxprs
+
+    bodies = []
+
+    def walk(jaxpr, mine):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                mine.append(e.params["name"])
+            for sub in _sub_jaxprs(e.params):
+                if e.primitive.name == "scan":
+                    body = walk(sub, [])
+                    if body:
+                        bodies.append(sorted(body))
+                else:
+                    walk(sub, mine)
+        return mine
+
+    return sorted(walk(jaxpr.jaxpr, [])), bodies
+
+
+@pytest.mark.parametrize("family, policy, outside, bodies", [
+    ("ouro", "kept", [], [["gq_attn_fwd"], ["gq_attn_bwd"]]),
+    ("ouro", "bare", [], [["gq_attn_fwd"], ["gq_attn_bwd", "gq_attn_fwd"]]),
+    ("lfm2", "its own", ["gq_attn_bwd", "gq_attn_fwd", "gq_attn_fwd"], [])])
+def test_gradient_on_the_gq_kernels_runs_one_forward_kernel_where_the_layer_keeps_its_results(
+        family, policy, outside, bodies, monkeypatch):
+    """A model at shapes the fused kernels tile, jax reporting a TPU.  Ouro:
+    the gradient's program calls ``gq_attn_fwd`` in the forward scan's body
+    and ``gq_attn_bwd`` ALONE in the backward's, whose residuals ``o`` and the
+    log-sum-exp the layer kept by name; a layer that keeps its input alone
+    (before ISSUE 41) calls the forward kernel again beside the backward.
+    LFM2's one attention layer, a lone layer under ITS bare checkpoint, still
+    calls the forward kernel twice: there the names are the identity."""
+    if family == "lfm2":
+        from benchmark.tests import tiny_lfm2 as tiny
+
+        cfg = tiny.program_cfg(head_dim=64)
+    else:
+        cfg = _ouro_case(**TILED)[0]
+    if policy == "bare":
+        _bare_checkpoint(monkeypatch)
+    model = make_model(cfg)
+    params = model.init(jax.random.key(1))
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(
+        p, {"label": tokens}, train=True)[0]["loss"]))(params)
+    assert _kernels_by_scan_body(jaxpr) == (outside, bodies)
+
+
+@pytest.mark.parametrize("path", ["block loop", "kernels"])
+@pytest.mark.parametrize("policy", ["kept", "bare"])
+def test_ouro_named_values_are_the_layers_saved_residuals(policy, path, monkeypatch, capsys):
+    """What the two scans hand the backward of a layer application, ``[R, L,
+    ...]``: the layer's input ``[N, S, D]`` and, under the policy, the
+    SwiGLU's down-projected output, as large, and where the kernels run their
+    ``o`` ``[N, H, d, S]``, log-sum-exp and three bfloat16 operands; nothing
+    else, and under a bare checkpoint the input alone."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    if policy == "bare":
+        _bare_checkpoint(monkeypatch)
+    if path == "kernels":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, model, params, tokens, lm, _ = _ouro_case(**TILED)
+    print_saved_residuals(
+        lambda p: model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"], params)
+    of_a_layer = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+                  if "output of scan" in line and re.match(r"\w+\[2,3,", line)]
+    layer_input = mlp_out = "f32[2,3,2,128,64]"
+    of_the_kernels = ["f32[2,3,2,2,128,128]", "f32[2,3,2,2,1,1,128]"] + ["bf16[2,3,2,2,128,128]"] * 3
+    named = [mlp_out] + (of_the_kernels if path == "kernels" else [])
+    assert sorted(of_a_layer) == sorted([layer_input] + (named if policy == "kept" else []))
+
+
+@pytest.mark.parametrize("path", ["block loop", "kernels"])
+def test_ouro_keeping_the_named_values_changes_no_number(path, monkeypatch):
+    """Loss and every leaf's gradient under the policy against the bare
+    checkpoint's: a kept value is the value the second forward would have
+    computed from the same inputs.  On the CPU's block loop (where the
+    SwiGLU's output alone carries a name) equal to the bit; on the interpreted
+    kernels to float32 round-off (another compiled program round them)."""
+    _, model, params, tokens, lm, _ = _ouro_case(**TILED)
+    if path == "kernels":
+        _on_the_kernels(monkeypatch)
+    run = jax.jit(lambda p: _loss_counters_and_grads(model, p, tokens, lm))
+    got, _, got_grads = run(params)
+    _bare_checkpoint(monkeypatch)
+    run = jax.jit(lambda p: _loss_counters_and_grads(model, p, tokens, lm))
+    want, _, want_grads = run(params)
+    if path == "block loop":
+        assert float(got) == float(want)
+    else:
+        assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for name, w in want_grads.items():
+        if path == "block loop":
+            np.testing.assert_array_equal(got_grads[name], w, err_msg=name)
+        else:
+            np.testing.assert_allclose(got_grads[name], w, rtol=0, err_msg=name,
+                                       atol=1e-5 * float(jnp.abs(w).max()) + 1e-12)
+
+
+@pytest.mark.parametrize("arch, reports, share", [
+    (dict(TILED), "tpu", 1.0),
+    (dict(TILED), "cpu", 0.0),
+    (dict(TILED, head_dim=32), "tpu", 0.0),   # a head the kernels do not tile
+    (dict(TILED, bptt=96), "tpu", 0.0)],      # no whole tile of positions
+    ids=["tiles", "cpu", "narrow-head", "short-row"])
+def test_ouro_loop_kept_counts_the_applications_on_the_named_kernel(arch, reports, share,
+                                                                   monkeypatch):
+    """`loop_kept` = (layer applications whose attention ran the kernel that
+    names its results under the policy, layer applications): 2 passes x 3
+    layers where a TPU gives the shape tiles, 0 of 6 on a CPU or for a shape
+    the kernels do not tile, where the block loop carries no name."""
+    from heterofl_tpu.obs import split_probes
+
+    _, model, params, tokens, lm, _ = _ouro_case(**arch)
+    if reports == "tpu":
+        _on_the_kernels(monkeypatch)
+    out, _ = model.apply(params, {"label": tokens}, train=True, label_mask=lm)
+    assert out["counters"]["loop_kept"].tolist() == [6.0 * share, 6.0]
+    _, rounds = split_probes({"obs_loop_kept": np.asarray(out["counters"]["loop_kept"])}, 1)
+    assert rounds[0]["loop_kept"] == share
 
 
 def test_ouro_trains_and_evaluates_through_the_entry_point(tmp_path):
