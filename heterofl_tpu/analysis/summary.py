@@ -170,7 +170,7 @@ def module_table(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[in
         add("linear", (bs, in_planes), (bs, cfg["classes_size"]), mods("linear"),
             bs * in_planes * cfg["classes_size"])
     elif "profile" in model.meta:
-        # a family that describes itself (kanana2, lfm2, keye, ouro): one row
+        # a family that describes itself (kanana2, lfm2, keye, ouro, laguna): one row
         # per matrix leaf (a linear's MACs = tokens x its size; a routed
         # expert sees top_k / n_experts of the tokens; a depthwise tap leaf
         # [taps, channels] is its size too) plus the two attention matmuls of
@@ -198,8 +198,11 @@ def module_table(cfg: Dict[str, Any], model_rate: float, batch_size: Optional[in
         if "tied_head" in prof:
             V, D = shapes[prof["tied_head"]]
             add("head", (bs, T, D), (bs, T, V), 0, ntok * D * V)
-        pairs = bs * prof.get("passes", 1) * (T * (T + 1) // 2)
-        for site, (H, dq, dv) in prof["attention"].items():
+        for site, (H, dq, dv, *window) in prof["attention"].items():
+            # a site's (query, key) pairs: every causal one, or a sliding
+            # layer's band (laguna: a fourth entry, its window)
+            w = min(window[0], T) if window and window[0] else T
+            pairs = bs * prof.get("passes", 1) * (w * (w + 1) // 2 + (T - w) * w)
             add(f"{site}.qk", (bs, T, H * dq), (bs, H, T, T), 0, H * pairs * dq)
             add(f"{site}.av", (bs, H, T, T), (bs, T, H * dv), 0, H * pairs * dv)
     else:  # transformer

@@ -39,10 +39,10 @@ CONTROL_KEYS = (
 # data/ import these rather than re-declaring them.
 NORM_TYPES = ("bn", "in", "ln", "gn", "none")
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
-               "resnet152", "transformer", "kanana2", "lfm2", "keye", "ouro")
+               "resnet152", "transformer", "kanana2", "lfm2", "keye", "ouro", "laguna")
 #: the families that train on token rows (next- or masked-token loss): the
 #: drivers' and engines' LM paths key on this, not on one family's name
-LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2", "keye", "ouro")
+LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2", "keye", "ouro", "laguna")
 # Feature-axis value registries (ISSUE 18): THE declared domains of the
 # engine/placement/store/pod axes, consumed by the axis validators below and
 # by staticcheck's config-lattice pass (staticcheck/lattice.py enumerates
@@ -541,6 +541,40 @@ def process_control(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "exit_entropy_beta": 0.1,
         "rope_theta": 1000000.0,
         "rms_norm_eps": 1e-6,
+    }
+    # Laguna-XS.2 (model_type laguna): the published shape
+    # (huggingface.co/poolside/Laguna-XS.2 config.json).  The three lists are
+    # the published ones (a full-attention layer of 48 query heads at 0, 4, 8,
+    # ..., sliding layers of 64 between; layer 0 dense), written as their rule;
+    # the model reads the lists and assumes no period.  ``rope_parameters`` is
+    # one nested group (an override replaces it whole).  ``expert_share`` as
+    # above; the benchmark's cut is layers 0-4 and [0, 16] (16 of 256 experts).
+    cfg["laguna"] = {
+        "hidden_size": 2048,
+        "num_hidden_layers": 40,
+        "layer_types": ["sliding_attention" if i % 4 else "full_attention" for i in range(40)],
+        "mlp_layer_types": ["sparse" if i else "dense" for i in range(40)],
+        "num_attention_heads_per_layer": [64 if i % 4 else 48 for i in range(40)],
+        "num_attention_heads": 48,
+        "num_key_value_heads": 8,
+        "head_dim": 128,
+        "sliding_window": 512,
+        "rope_parameters": {
+            "full_attention": {
+                "rope_theta": 500000.0, "rope_type": "yarn", "factor": 64.0,
+                "original_max_position_embeddings": 4096, "beta_slow": 1.0, "beta_fast": 64.0,
+                "attention_factor": 1.4158883083359672, "partial_rotary_factor": 0.5},
+            "sliding_attention": {
+                "rope_theta": 10000.0, "rope_type": "default", "partial_rotary_factor": 1.0},
+        },
+        "intermediate_size": 8192,
+        "moe_intermediate_size": 512,
+        "shared_expert_intermediate_size": 512,
+        "num_experts": 256,
+        "num_experts_per_tok": 8,
+        "moe_routed_scaling_factor": 2.5,
+        "rms_norm_eps": 1e-6,
+        "expert_share": [0, 1],
     }
     # Per-dataset hyperparameters (ref src/utils.py:150-212).
     data_name = cfg["data_name"]

@@ -20,6 +20,7 @@ from .base import ModelDef  # noqa: F401
 from .conv import make_conv
 from .kanana2 import make_kanana2
 from .keye import make_keye
+from .laguna import make_laguna
 from .lfm2 import make_lfm2
 from .ouro import make_ouro
 from .resnet import make_resnet
@@ -38,7 +39,7 @@ RESNET_BLOCKS = {
 # it in lockstep with the families actually buildable here.  A hard raise, not
 # an assert: the guard must survive `python -O` (advisor r3).
 _BUILDABLE = ("conv",) + tuple(RESNET_BLOCKS) + (
-    "transformer", "kanana2", "lfm2", "keye", "ouro")
+    "transformer", "kanana2", "lfm2", "keye", "ouro", "laguna")
 if MODEL_NAMES != _BUILDABLE:
     raise ImportError(
         f"config.MODEL_NAMES {MODEL_NAMES!r} out of lockstep with buildable "
@@ -98,6 +99,9 @@ def make_model(cfg: Dict[str, Any], model_rate: Optional[float] = None) -> Model
     elif name == "ouro":
         model = make_ouro(cfg["num_tokens"], cfg["ouro"], model_rate,
                           mask=cfg["mask"], compute_dtype=compute_dtype)
+    elif name == "laguna":
+        model = make_laguna(cfg["num_tokens"], cfg["laguna"], model_rate,
+                            mask=cfg["mask"], compute_dtype=compute_dtype)
     else:
         raise ValueError("Not valid model name")
     model.meta["model_rate"] = model_rate

@@ -371,8 +371,13 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # an expert layer's counters (ISSUE 28): per-device sums
                 # over that device's valid slots, steps and expert layers
                 rec[base] = [float(c) for c in x.sum(axis=0)]
-            elif base.startswith("sparse_"):
-                # a sparse-attention indexer's counters (ISSUES 35, 36, 39),
+            elif base.startswith(("sparse_", "swa_")):
+                # a sparse-attention indexer's counters (ISSUES 35, 36, 39)
+                # and the sliding layers' (ISSUE 42: swa_fused = query tiles
+                # the band kernels took over query tiles, swa_pairs = band
+                # over causal pairs, swa_tiles = key tiles visited over key
+                # tiles on or under the diagonal, below 1 where tiles under
+                # the band were skipped),
                 # each a (numerator, denominator) pair of per-device sums:
                 # sparse_selected = keys selected a query, sparse_kept_share
                 # = selected over causal (query, key) pairs, sparse_fused =

@@ -54,6 +54,7 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
     watchdog: List[Dict[str, Any]] = []
     moe = {"rounds": 0, "tokens": None, "routed": 0.0, "held": 0.0, "dropped": 0}
     loop: List[Dict[str, Any]] = []  # a looped model's counters, a round each
+    swa: List[Dict[str, Any]] = []  # the sliding layers' counters, a round each
     setup: List[spans.Span] = []  # every span filed with its id
     with open(events_path) as f:
         for line in f:
@@ -81,6 +82,8 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
                 moe["dropped"] += int(args.get("moe_dropped", 0))
             if name == "probes" and "loop_exit_share" in args:
                 loop.append(args)
+            if name == "probes" and "swa_pairs" in args:
+                swa.append(args)
     out = {"path": events_path, "events_by_name": counts,
            "watchdog_trips": watchdog[:16]}
     if moe["rounds"]:
@@ -93,6 +96,10 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
         out["loop"] = {"rounds": len(loop), "exit_share": mean("loop_exit_share"),
                        "pass_nll": mean("loop_pass_nll"),
                        "passes": sum(r["loop_passes"] for r in loop) / len(loop)}
+    if swa:
+        # the sliding layers' counters (obs.split_probes), the rounds' mean
+        out["swa"] = {"rounds": len(swa), **{k: sum(r[f"swa_{k}"] for r in swa) / len(swa)
+                                             for k in ("fused", "pairs", "tiles")}}
     if setup:
         out["setup"] = spans.summarize(setup)
     return out
@@ -170,6 +177,12 @@ def render_events(ev: Optional[Dict[str, Any]]) -> List[str]:
                 "exit share a pass " + " ".join(f"{p:.4f}" for p in loop["exit_share"])
                 + "; mean negative log-likelihood a pass "
                 + " ".join(f"{v:.4f}" for v in loop["pass_nll"]))
+        swa = ev.get("swa")
+        if swa:
+            lines.append(
+                f"  sliding layers over {swa['rounds']} rounds: band over causal pairs "
+                f"{swa['pairs']:.4f}; key tiles visited of those on or under the diagonal "
+                f"{swa['tiles']:.4f}; query tiles on the band kernels {swa['fused']:.2f}")
         if ev["watchdog_trips"]:
             lines.append(f"  WATCHDOG TRIPPED {len(ev['watchdog_trips'])}x: "
                          f"{ev['watchdog_trips'][0]}")

@@ -112,26 +112,35 @@ SPARSE_SCOPES = ("sparse/index", "sparse/select")
 #: :data:`MIXER_SCOPES` is one; read through benchmark/scope_reduce_ouro.py.
 LOOP_SCOPES = ("loop/pass", "loop/head", "loop/exit")
 
+#: What ISSUE 42 added: the sliding-window layers of models/laguna.py.  ``swa``
+#: = a sliding layer's score / softmax / value part, kernel pair or block loop
+#: (``ops.layers.sliding_gq_attention``), as ``attn`` stays the full layers';
+#: the projections, the output gate and both rotary turns stay under ``gqa`` /
+#: ``rope``, the experts under ``moe/*``.  A sixth tuple for the reason
+#: :data:`MIXER_SCOPES` is one; read through benchmark/scope_reduce_laguna.py.
+WINDOW_SCOPES = ("swa",)
+
 #: Version of the vocabulary AND of where it is entered.  jax keeps metadata
 #: out of the persistent compile cache's key, so a program whose only change
 #: is a scope would load the executable cached before the change, without
 #: the new names, silently; ``utils.compile_cache`` folds this number into
 #: the key.  Bump it with every change to :data:`SCOPES` or to where a scope
 #: is entered (one cold compile per program, once).
-SCOPE_VERSION = 6
+SCOPE_VERSION = 7
 
 #: ``name=`` of every ``pallas_call`` (the kernel's device events carry it)
 KERNELS = ("fused_sgd", "masked_bn_fwd", "masked_bn_bwd", "int8_pack")
 
-#: The kernels ISSUES 29, 34 and 36 added (ops/pallas_attention.py, under
-#: ``attn``): a tuple of its own as :data:`EXTRA_SCOPES` is one (the benchmark
+#: The kernels ISSUES 29, 34, 36 and 42 added (ops/pallas_attention.py, under
+#: ``attn``, the band pair under ``swa`` too): a tuple of its own as :data:`EXTRA_SCOPES` is one (the benchmark
 #: mirrors :data:`KERNELS`); its reader files their time under ``attn``.
 EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_bwd",
-                 "sel_attn_fwd", "sel_attn_bwd")
+                 "sel_attn_fwd", "sel_attn_bwd", "band_attn_fwd", "band_attn_bwd")
 
 
 def _known(name: str) -> str:
-    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES + SPARSE_SCOPES + LOOP_SCOPES:
+    if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES + SPARSE_SCOPES + LOOP_SCOPES \
+            + WINDOW_SCOPES:
         raise ValueError(f"Not valid scope: {name!r} (obs.trace.SCOPES)")
     return name
 

@@ -428,7 +428,8 @@ def rope_swap(x: jnp.ndarray) -> jnp.ndarray:
 
 @scoped("rope")
 def rope_interleaved(x: jnp.ndarray, swapped: jnp.ndarray, pos: jnp.ndarray, theta: float,
-                     axis: int = 1, full: Optional[int] = None) -> jnp.ndarray:
+                     axis: int = 1, full: Optional[int] = None, freqs=None,
+                     factor: Optional[float] = None) -> jnp.ndarray:
     """Rotary embedding on interleaved pairs ``(2i, 2i+1)`` of the last axis
     (``rope_interleave: true``): ``x cos + swapped sin`` with ``swapped`` the
     pair swap of ``x`` (:func:`rope_swap`), ``theta_i = theta^(-2i/full)`` and
@@ -436,13 +437,25 @@ def rope_interleaved(x: jnp.ndarray, swapped: jnp.ndarray, pos: jnp.ndarray, the
     is in the masked full-width model): a client's sliced prefix of whole
     pairs keeps the frequencies of the pairs it holds, and zeros (masked
     pairs) stay zeros.  ``x`` ``[N, S, ..., d]`` and ``pos`` ``[S]``, the
-    positions on ``axis`` (2 for heads-first ``[N, H, S, d]``)."""
+    positions on ``axis`` (2 for heads-first ``[N, H, S, d]``).
+
+    ``freqs``: the GLOBAL head's frequency of every pair instead (a static
+    table of ``full / 2`` numbers, ``theta`` unread: YaRN's blend of two
+    tables, models/laguna.py); ``factor``: what cos and sin are multiplied by
+    (YaRN's ``attention_factor``).  A head of which only a part turns hands
+    that part alone to this function."""
     d = x.shape[-1]
-    inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / (full or d))
+    if freqs is None:
+        inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / (full or d))
+    else:
+        # staticcheck: allow(no-asarray): trace-time static frequency table
+        inv = jnp.repeat(jnp.asarray(freqs, jnp.float32), 2)[:d]
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]           # [S, d]
     view = [1] * x.ndim
     view[axis], view[-1] = x.shape[axis], d
     cos, sin = jnp.cos(ang).reshape(view), jnp.sin(ang).reshape(view)
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     return x * cos + swapped * sin
 
 
@@ -483,7 +496,7 @@ def causal_latent_attention(qn, qr, kn, kr, v, scale, block: int = ATTN_BLOCK):
     return blockwise_latent_attention(qn, qr, kn, kr, v, scale, block)
 
 
-def _causal_blocks(scores, values, qs, ks, v, scale, block: int, select=None):
+def _causal_blocks(scores, values, qs, ks, v, scale, block: int, select=None, window=None):
     """The one blockwise causal softmax loop: query blocks of ``block`` rows
     against the keys up to the block's end, each block under
     ``jax.checkpoint``, so no ``[S, S]`` score matrix of a whole row is ever
@@ -492,15 +505,24 @@ def _causal_blocks(scores, values, qs, ks, v, scale, block: int, select=None):
     ``scores(*q_blocks, *k_blocks)`` gives ``[..., q, k]`` and ``values(p,
     v_block)`` the block's result, positions on axis -2 again.  ``select``
     (:func:`select_keys`): per block None or a further mask ``[N, q, k]`` on
-    its scores, alike for every head."""
+    its scores, alike for every head.  ``window`` (a sliding layer's): query
+    ``i`` sees key ``j`` only if ``i - j < window`` as well, and a block's keys
+    start at the first one its first query sees: what lies wholly below the
+    band is never sliced out, let alone computed."""
     S = v.shape[-2]
+    if window is not None and window >= S:
+        window = None  # the band holds every causal pair: the diagonal alone
     outs = []
     for i, start in enumerate(range(0, S, block)):
         end = min(start + block, S)
+        first = 0 if window is None else max(0, start - window + 1)
 
-        def one(qs_b, ks_b, v_b, *sel_b, start=start, end=end):
+        def one(qs_b, ks_b, v_b, *sel_b, start=start, end=end, first=first):
             s = scores(*qs_b, *ks_b).astype(jnp.float32) * scale
-            keep = jnp.arange(start, end)[:, None] >= jnp.arange(end)[None, :]
+            q_pos, k_pos = jnp.arange(start, end)[:, None], jnp.arange(first, end)[None, :]
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = keep & (q_pos - k_pos < window)
             for m in sel_b:
                 keep = keep & m.reshape(m.shape[:1] + (1,) * (s.ndim - 3) + m.shape[1:])
             s = jnp.where(keep, s, -jnp.inf)
@@ -508,8 +530,8 @@ def _causal_blocks(scores, values, qs, ks, v, scale, block: int, select=None):
 
         sel_b = () if select is None or select[i] is None else (select[i],)
         outs.append(jax.checkpoint(one)(tuple(q[..., start:end, :] for q in qs),
-                                        tuple(k[..., :end, :] for k in ks), v[..., :end, :],
-                                        *sel_b))
+                                        tuple(k[..., first:end, :] for k in ks),
+                                        v[..., first:end, :], *sel_b))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-2)
 
 
@@ -532,29 +554,41 @@ def causal_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK):
     repeated in memory.  Softmax in float32, float32 out ``[N, H, S, d]``.
 
     On a TPU, where the positions make whole tiles and the head dim is a
-    multiple of 64 (``pallas_attention.gq_tile_for``), the fused kernels
-    ``gq_attn_fwd`` / ``gq_attn_bwd``: a score tile lives in VMEM only.
-    Elsewhere (the CPU; a client's narrow slice at its own widths)
+    multiple of 64, the fused kernel pair ``pallas_attention.gq_plan`` names
+    for the layer's (group, head dim, positions): a score tile lives in VMEM
+    only.  Elsewhere (the CPU; a client's narrow slice at its own widths)
     :func:`blockwise_gq_attention` in query blocks of ``block`` rows."""
+    return _planned_gq_attention(q, k, v, scale, block, None)
+
+
+def _planned_gq_attention(q, k, v, scale, block, window):
+    """Grouped-query attention under the diagonal (``window`` None) or under
+    the diagonal and a window, through what ``pallas_attention.gq_plan``
+    gives its shapes on a TPU and through the ``jnp`` block loop elsewhere."""
     if jax.default_backend() == "tpu":
         from . import pallas_attention
 
-        tile = pallas_attention.gq_tile_for(q.shape[2], q.shape[-1])
-        if tile is not None:
-            return pallas_attention.fused_gq_attention(q, k, v, scale, block_q=tile, block_k=tile)
-    return blockwise_gq_attention(q, k, v, scale, block)
+        plan = pallas_attention.gq_plan(q.shape[2], q.shape[-1], q.shape[1] // k.shape[1], window)
+        if plan is not None:
+            pair, tq, tk = plan
+            if pair == "gq":
+                return pallas_attention.fused_gq_attention(q, k, v, scale, block_q=tq, block_k=tk)
+            return pallas_attention.fused_band_attention(q, k, v, scale, window,
+                                                         block_q=tq, block_k=tk)
+    return blockwise_gq_attention(q, k, v, scale, block, window=window)
 
 
-def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK, select=None):
+def blockwise_gq_attention(q, k, v, scale, block: int = ATTN_BLOCK, select=None, window=None):
     """:func:`causal_gq_attention` in plain ``jnp`` (and the fused kernels'
     oracle): latent attention's block loop (:func:`_causal_blocks`) with the
-    query heads grouped by the key/value head they read."""
+    query heads grouped by the key/value head they read; ``window`` a sliding
+    layer's (:func:`sliding_gq_attention`)."""
     N, H, S, d = q.shape
     kv = k.shape[1]
     o = _causal_blocks(
         lambda q_b, k_b: jnp.einsum("ngjqd,ngkd->ngjqk", q_b, k_b),
         lambda p, v_b: jnp.einsum("ngjqk,ngkd->ngjqd", p, v_b),
-        (q.reshape(N, kv, H // kv, S, d),), (k,), v, scale, block, select)
+        (q.reshape(N, kv, H // kv, S, d),), (k,), v, scale, block, select, window)
     return o.reshape(N, H, S, v.shape[-1])
 
 
@@ -854,3 +888,28 @@ def gq_attention_tile(S: int, d: int):
     from . import pallas_attention
 
     return pallas_attention.gq_tile_for(S, d)
+
+
+@scoped("swa")
+def sliding_gq_attention(q, k, v, scale, window: int, block: int = ATTN_BLOCK):
+    """:func:`causal_gq_attention` of a SLIDING layer: query ``i`` sees key
+    ``j`` if ``j <= i`` and ``i - j < window`` (itself and the ``window - 1``
+    before it).  On a TPU the ``band_attn_fwd`` / ``band_attn_bwd`` kernels,
+    which fetch and compute only the key tiles the band crosses; elsewhere the
+    block loop, whose blocks start at the band's lower edge.  Under the scope
+    ``swa``, as the full layers' stays ``attn``."""
+    return _planned_gq_attention(q, k, v, scale, block, window)
+
+
+def sliding_attention_tiles(S: int, d: int, group: int, window: int, block: int = ATTN_BLOCK):
+    """What :func:`sliding_gq_attention` runs at these shapes, for a caller
+    that counts (``models/laguna.py``): ``(fused, visited, causal)`` = whether
+    the kernel pair takes it, and the key tiles (blocks, for the block loop)
+    it visits of those on or under the diagonal, from the grid's extents."""
+    from . import pallas_attention  # Pallas: imported where a Laguna model is built
+
+    plan = pallas_attention.gq_plan(S, d, group, window) if jax.default_backend() == "tpu" \
+        else None
+    tq, tk = (block, block) if plan is None else plan[1:]
+    visited, causal = pallas_attention.band_extent(S, tq, tk, window)
+    return plan is not None, visited, causal

@@ -25,6 +25,39 @@ scores, mask, max, exponent, sum, log-sum-exp, ``delta`` and every
 accumulator float32.  The softmax scale is not a kernel operand: the caller
 multiplies ``q`` by it in float32 before the cast, which also carries a
 per-client scale through ``vmap`` and leaves its gradient to autodiff.
+
+THE RULE: which pair a layer takes, at which tile, and what stays resident.
+``S`` positions, heads of ``d`` dims, ``G`` query heads a key/value head; a
+tile of positions is the largest of :data:`TILES` that divides ``S``; where no
+pair takes the shapes (a client's narrow slice, ragged rows) the caller runs
+the ``jnp`` block loop.
+
+=====================  ==========================  ===========================
+pair                   taken where                 resident over the grid
+=====================  ==========================  ===========================
+``latent_attn_*``      :func:`tile_for`: ``dn``,   backward, keys outer: a
+                       ``dv`` of 128s, ``dr`` of   head's ``dq`` ``[S, dn+dr]``
+                       64s                         float32
+``gq_attn_*``          :func:`gq_plan`: no         backward, keys outer: a
+                       window, ``d`` of 64s, and   group's ``dq`` ``[G, d, S]``
+                       that ``dq`` within          float32 (LFM2 4 x 64 x
+                       :data:`GQ_RESIDENT_BYTES`   2,048: 2 MB; Ouro 1 x 128 x
+                       (or ``d`` not of 128s)      2,048: 1 MB), double-buffered
+``band_attn_*``        :func:`gq_plan`: a window,  both kernels, query tiles
+                       or a ``dq`` beyond that     outer: a key/value head's
+                       (Laguna: 8 x 128 x 8,192 =  ``dk``, ``dv`` ``[d, S]``
+                       34 MB, 6 x ... = 25 MB);    float32 (4 MB each at 128 x
+                       ``d`` of 128s               8,192) and a group's ``dq``
+                                                   tile in scratch
+``sel_attn_*``         :func:`sel_tile_for`: a     as ``band_attn_*``, and the
+                       selection mask; ``d`` of    mask a ``[tk, tq]`` int8
+                       128s                        tile a step
+=====================  ==========================  ===========================
+
+The query tile of the last two is the largest with ``G x tile <= 4,096``
+columns side by side (a score tile ``[key tile, G x tile]`` float32 is 8 MB of
+VMEM at 512 keys); under a window both tiles of the band pair are at most half
+the window (measured: scripts/swa_ab.py, the note in :func:`gq_plan`).
 """
 
 from __future__ import annotations
@@ -306,38 +339,61 @@ def _group_causal(st, q0, k0, tq):
     return jnp.where(q >= k, st, MASKED)
 
 
+def _gq_start(q_ref, q_s, m_s, l_s, acc_s):
+    """A query tile's first key tile: the group's tiles side by side (resident
+    over the key tiles) and the running max, sum and value accumulator."""
+    q_s[...] = _side_by_side(q_ref)
+    m_s[...] = jnp.full_like(m_s, MASKED)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+
+def _gq_softmax_step(st, v_ref, m_s, l_s, acc_s):
+    """One key tile of the online softmax: ``st`` ``[tk, G * tq]`` the tile's
+    (masked) scores, keys first."""
+    m_prev = m_s[...]                                             # [1, G * tq]
+    m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+    pt = jnp.exp(st - m_next)
+    alpha = jnp.exp(m_prev - m_next)
+    l_s[...] = alpha * l_s[...] + jnp.sum(pt, axis=0, keepdims=True)
+    m_s[...] = m_next
+    v = v_ref[...]
+    acc_s[...] = alpha * acc_s[...] + _dot(v, pt.astype(v.dtype), _NN)  # [d, G * tq]
+
+
+def _gq_finish(o_ref, lse_ref, m_s, l_s, acc_s, tq):
+    """A query tile's last key tile: the group's outputs and log-sum-exp."""
+    l = l_s[...]
+    o = acc_s[...] / l
+    for g in range(o_ref.shape[0]):
+        o_ref[g] = o[:, g * tq:(g + 1) * tq]
+    lse_ref[...] = m_s[...] + jnp.log(l)
+
+
+def _gq_bwd_step(st, q, do, k, v_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_s, kt, tk):
+    """One key tile of a query-tiles-outer backward: from the (masked) scores
+    ``st`` ``[tk, G * tq]`` of key tile ``kt`` the key/value head's ``dk`` /
+    ``dv`` at that tile's columns and the group's ``dq`` tile in scratch."""
+    pt = jnp.exp(st - lse_ref[...])                               # lse [1, G * tq]
+    cols = pl.ds(pl.multiple_of(kt * tk, tk), tk)
+    dv_ref[:, cols] += _dot(do, pt.astype(do.dtype), _NT)         # [d, tk]
+    dst = (pt * (_dot(v_ref[...], do, _TN) - delta_ref[...])).astype(q.dtype)
+    dk_ref[:, cols] += _dot(q, dst, _NT)
+    dq_s[...] += _dot(k, dst, _NN)                                # [d, G * tq]
+
+
 def _gq_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, m_s, l_s, acc_s, *, tq: int, tk: int):
     i, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _():
-        q_s[...] = _side_by_side(q_ref)          # resident over the key tiles
-        m_s[...] = jnp.full_like(m_s, MASKED)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    pl.when(j == 0)(partial(_gq_start, q_ref, q_s, m_s, l_s, acc_s))
 
     def step(masked):
         st = _dot(k_ref[...], q_s[...], _TN)                          # [tk, G * tq]
         if masked:
             st = _group_causal(st, i * tq, j * tk, tq)
-        m_prev = m_s[...]                                             # [1, G * tq]
-        m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-        pt = jnp.exp(st - m_next)
-        alpha = jnp.exp(m_prev - m_next)
-        l_s[...] = alpha * l_s[...] + jnp.sum(pt, axis=0, keepdims=True)
-        m_s[...] = m_next
-        v = v_ref[...]
-        acc_s[...] = alpha * acc_s[...] + _dot(v, pt.astype(v.dtype), _NN)  # [d, G * tq]
+        _gq_softmax_step(st, v_ref, m_s, l_s, acc_s)
 
     _when_needed(i, j, tq, tk, step)
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _():
-        l = l_s[...]
-        o = acc_s[...] / l
-        for g in range(o_ref.shape[0]):
-            o_ref[g] = o[:, g * tq:(g + 1) * tq]
-        lse_ref[...] = m_s[...] + jnp.log(l)
+    pl.when(j == pl.num_programs(3) - 1)(partial(_gq_finish, o_ref, lse_ref, m_s, l_s, acc_s, tq))
 
 
 def _gq_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *,
@@ -509,13 +565,10 @@ def fused_gq_attention(q, k, v, scale, *, block_q: int, block_k: int, interpret:
 # masked score gives ``exp(MASKED - real) = 0``, as it does against the
 # log-sum-exp in the backward.
 #
-# THE BACKWARD RUNS QUERY TILES OUTER.  ``gq_attn_bwd`` keeps ``dq`` of a whole
-# group resident over the grid (``[G, d, S]`` float32: 2 MB at 4 heads of 64
-# on rows of 2,048, 34 MB at 8 heads of 128 on rows of 8,192, twice that
-# double-buffered).  A key/value head's ``dk`` and ``dv`` are ``G`` times smaller
-# (``[d, S]``: 4 MB each there), so here THEY are resident and a group's
-# ``dq`` tile is accumulated over the key tiles up to the diagonal, the grid
-# of the forward: five products a tile, one kernel.
+# THE BACKWARD RUNS QUERY TILES OUTER (the header's rule says why: a group's
+# ``dq`` outgrows VMEM at these shapes, a key/value head's ``dk`` and ``dv`` do
+# not), so a group's ``dq`` tile is accumulated over the key tiles up to the
+# diagonal, on the grid of the forward: five products a tile, one kernel.
 #
 # THE FORWARD'S RESULTS CARRY NAMES.  ``o`` and the log-sum-exp are the primal
 # output and the backward kernel's residuals at once, so a ``jax.checkpoint``
@@ -562,36 +615,16 @@ def _sel_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, q_s, m_s, l_s,
                     tq: int, tk: int, first: int):
     i, j = pl.program_id(2), pl.program_id(3)
     G = q_ref.shape[0]
-
-    @pl.when(j == 0)
-    def _():
-        q_s[...] = _side_by_side(q_ref)          # resident over the key tiles
-        m_s[...] = jnp.full_like(m_s, MASKED)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    pl.when(j == 0)(partial(_gq_start, q_ref, q_s, m_s, l_s, acc_s))
 
     def step(bias_of):
         st = _dot(k_ref[...], q_s[...], _TN)                          # [tk, G * tq]
         if bias_of is not None:
             st = st + jnp.tile(bias_of(sel_ref), (1, G))
-        m_prev = m_s[...]                                             # [1, G * tq]
-        m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-        pt = jnp.exp(st - m_next)
-        alpha = jnp.exp(m_prev - m_next)
-        l_s[...] = alpha * l_s[...] + jnp.sum(pt, axis=0, keepdims=True)
-        m_s[...] = m_next
-        v = v_ref[...]
-        acc_s[...] = alpha * acc_s[...] + _dot(v, pt.astype(v.dtype), _NN)  # [d, G * tq]
+        _gq_softmax_step(st, v_ref, m_s, l_s, acc_s)
 
     _when_selected(i, j, tq, tk, first, step)
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _():
-        l = l_s[...]
-        o = acc_s[...] / l
-        for g in range(G):
-            o_ref[g] = o[:, g * tq:(g + 1) * tq]
-        lse_ref[...] = m_s[...] + jnp.log(l)
+    pl.when(j == pl.num_programs(3) - 1)(partial(_gq_finish, o_ref, lse_ref, m_s, l_s, acc_s, tq))
 
 
 def _sel_bwd_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref, delta_ref,
@@ -615,12 +648,7 @@ def _sel_bwd_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref, delta_ref,
         st = _dot(k, q, _TN)                                          # [tk, G * tq]
         if bias_of is not None:
             st = st + jnp.tile(bias_of(sel_ref), (1, G))
-        pt = jnp.exp(st - lse_ref[...])                               # lse [1, G * tq]
-        cols = pl.ds(pl.multiple_of(j * tk, tk), tk)
-        dv_ref[:, cols] += _dot(do, pt.astype(do.dtype), _NT)         # [d, tk]
-        dst = (pt * (_dot(v_ref[...], do, _TN) - delta_ref[...])).astype(q.dtype)
-        dk_ref[:, cols] += _dot(q, dst, _NT)
-        dq_s[...] += _dot(k, dst, _NN)                                # [d, G * tq]
+        _gq_bwd_step(st, q, do, k, v_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_s, j, tk)
 
     _when_selected(i, j, tq, tk, first, step)
 
@@ -715,11 +743,11 @@ _sel_flash.defvjp(_sel_flash_fwd, _sel_flash_bwd)
 
 
 def sel_tile_for(S: int, d: int, group: int):
-    """:func:`gq_tile_for` for the selected kernels, (query tile, key tile):
-    head dims that fill the lanes, whole tiles of positions, and a group's
-    query tiles side by side no wider than 4,096 columns (a score tile
-    ``[key tile, group * query tile]`` float32 is then 8 MB of VMEM; eight
-    heads at 512 were 3 % ahead of 256 in the layer's block, PERF.md, PR 36)."""
+    """:func:`gq_tile_for` for the selected kernels, (query tile, key tile),
+    by the header's rule: head dims that fill the lanes, whole tiles of
+    positions, a group's query tiles side by side no wider than 4,096 columns
+    (eight heads at 512 were 3 % ahead of 256 in the layer's block, PERF.md,
+    PR 36)."""
     if d % LANES:
         return None
     tk = next((t for t in TILES if S % t == 0), None)
@@ -776,3 +804,261 @@ def _gq_named(ops, o, lse):
 
     o, lse = checkpoint_name(o, GQ_OUT), checkpoint_name(lse, GQ_LSE)
     return o, (checkpoint_name(ops, GQ_OPS), o, lse)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention under a BAND (``ops.layers.sliding_gq_attention``,
+# and the diagonal alone where ``gq_attn_bwd``'s resident ``dq`` does not fit:
+# :func:`gq_plan`): kernels ``band_attn_fwd`` / ``band_attn_bwd``.  The
+# grouped-query kernels' layout and group, the selected pair's grid (query
+# tiles outer, ``dk`` / ``dv`` resident), and one thing of their own.
+#
+# THE KEY-TILE AXIS IS THE BAND'S EXTENT.  ``window`` is static: query ``i``
+# sees key ``j`` if ``j <= i`` and ``i - j < window`` (None: the diagonal
+# alone).  Query tile ``i`` meets the key tiles ``first(i) .. last(i)`` only,
+# ``first = max(0, i * tq - window + 1) // tk`` and ``last = (i * tq + tq - 1)
+# // tk``; the grid's last axis has ``max_i(last - first) + 1`` steps (2 at
+# tiles of 512 under a window of 512, 3 at 256, ``S / tk`` without a window)
+# and step ``j`` is key tile ``first(i) + j``: a tile below the band is neither
+# fetched nor computed, as one above the diagonal never was (its step repeats
+# the last tile's index, which fetches nothing, and runs nothing).  Each of
+# the two edges is met only in the tiles it crosses.  A query's first visited
+# tile may hold no key it sees (the band's lower edge cuts a tile's corner
+# off): harmless, for the reason the selected kernels give, since every query
+# sees itself.
+# ---------------------------------------------------------------------------
+
+BAND_OUT, BAND_LSE = "band_out", "band_lse"
+
+
+def _band_first(i, tq, tk, window):
+    """The first key tile query tile ``i`` meets."""
+    return 0 if window is None else jnp.maximum(i * tq - (window - 1), 0) // tk
+
+
+def _band_tiles(S, tq, tk, window):
+    """(first, last) key tile of every query tile of a row of ``S`` positions
+    (the last tile of either axis may be short: the ``jnp`` block loop's)."""
+    return [(0 if window is None else max(0, start - window + 1) // tk,
+             (min(start + tq, S) - 1) // tk) for start in range(0, S, tq)]
+
+
+def band_extent(S: int, tq: int, tk: int, window):
+    """(key tiles visited, key tiles on or under the diagonal) of one head's
+    row of ``S`` positions at query tiles of ``tq`` and key tiles of ``tk``."""
+    tiles = _band_tiles(S, tq, tk, window)
+    return sum(last - first + 1 for first, last in tiles), sum(last + 1 for _, last in tiles)
+
+
+def _band_steps(S, tq, tk, window):
+    """Steps of the grid's key-tile axis: the most key tiles a query tile meets."""
+    return max(last - first + 1 for first, last in _band_tiles(S, tq, tk, window))
+
+
+def _band_scores(k, q, q0, k0, tq, window, diag, low):
+    """A tile's scores ``[tk, G * tq]`` (keys first, a group's query tiles side
+    by side) with the pairs outside the band masked: above the diagonal if
+    ``diag`` (it crosses the tile), below the window if ``low`` (its lower
+    edge does); ``q0`` / ``k0`` the tile's first positions.  The mask is made
+    once for a ``[tk, tq]`` tile, as a bias of 0 or ``MASKED``, and added to
+    every head of the group (under a window every visited tile is crossed by
+    an edge, so the mask is on the kernels' main path)."""
+    st = _dot(k, q, _TN)
+    if not (diag or low):
+        return st
+    shape = (st.shape[0], tq)
+    qp = q0 + lax.broadcasted_iota(jnp.int32, shape, 1)
+    kp = k0 + lax.broadcasted_iota(jnp.int32, shape, 0)
+    keep = qp >= kp if diag else qp - kp < window
+    if diag and low:
+        keep = jnp.logical_and(keep, qp - kp < window)
+    return st + jnp.tile(jnp.where(keep, 0.0, MASKED), (1, st.shape[1] // tq))
+
+
+def _when_in_band(i, kt, tq, tk, window, step):
+    """Run ``step(diag, low)`` for the tile (query tile ``i``, key tile
+    ``kt >= first(i)``) unless it lies above the diagonal; ``diag`` / ``low``
+    say which edge crosses it (static flags: up to four bodies, two without a
+    window)."""
+    needed = kt * tk <= i * tq + tq - 1
+    diag = kt * tk + tk - 1 > i * tq
+    if window is None:
+        pl.when(jnp.logical_and(needed, diag))(partial(step, True, False))
+        pl.when(jnp.logical_and(needed, jnp.logical_not(diag)))(partial(step, False, False))
+        return
+    low = i * tq + tq - 1 - kt * tk >= window
+    for d in (True, False):
+        for w in (True, False):
+            cond = jnp.logical_and(diag if d else jnp.logical_not(diag),
+                                   low if w else jnp.logical_not(low))
+            pl.when(jnp.logical_and(needed, cond))(partial(step, d, w))
+
+
+def _band_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, m_s, l_s, acc_s, *,
+                     tq: int, tk: int, window):
+    i, j = pl.program_id(2), pl.program_id(3)
+    kt = _band_first(i, tq, tk, window) + j
+    pl.when(j == 0)(partial(_gq_start, q_ref, q_s, m_s, l_s, acc_s))
+
+    def step(diag, low):
+        st = _band_scores(k_ref[...], q_s[...], i * tq, kt * tk, tq, window, diag, low)
+        _gq_softmax_step(st, v_ref, m_s, l_s, acc_s)
+
+    _when_in_band(i, kt, tq, tk, window, step)
+    pl.when(j == pl.num_programs(3) - 1)(partial(_gq_finish, o_ref, lse_ref, m_s, l_s, acc_s, tq))
+
+
+def _band_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                     q_s, do_s, dq_s, *, tq: int, tk: int, window):
+    i, j = pl.program_id(2), pl.program_id(3)
+    kt = _band_first(i, tq, tk, window) + j
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _():  # the key/value head's whole sequence, resident over (i, j)
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(j == 0)
+    def _():  # the group's query tile, resident over the key tiles
+        q_s[...] = _side_by_side(q_ref)
+        do_s[...] = _side_by_side(do_ref)
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    def step(diag, low):
+        q, do, k = q_s[...], do_s[...], k_ref[...]
+        st = _band_scores(k, q, i * tq, kt * tk, tq, window, diag, low)
+        _gq_bwd_step(st, q, do, k, v_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_s, kt, tk)
+
+    _when_in_band(i, kt, tq, tk, window, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        for g in range(dq_ref.shape[0]):
+            dq_ref[g] = dq_s[:, g * tq:(g + 1) * tq]
+
+
+def _band_specs(G, d, tq, tk, window):
+    """Block specs of a grid step (row, key/value head, query tile ``i``, step
+    ``j`` of the band), both kernels': a group's query tiles, the key/value
+    tile ``first(i) + j`` (one above the diagonal is never fetched), the row
+    statistics."""
+    def query(i, j):
+        return i
+
+    def key(i, j):
+        return jnp.minimum(_band_first(i, tq, tk, window) + j, (i * tq + tq - 1) // tk)
+
+    return _lane_tile(G, d, tq, query), _lane_tile(None, d, tk, key), _row_stat(G * tq, query)
+
+
+def _call_band_fwd(q, k, v, tq, tk, window, interpret):
+    N, H, d, S = q.shape
+    G = H // k.shape[1]
+    group, kv, stat = _band_specs(G, d, tq, tk, window)
+    return pl.pallas_call(
+        partial(_band_fwd_kernel, tq=tq, tk=tk, window=window),
+        grid=(N, H // G, S // tq, _band_steps(S, tq, tk, window)),
+        in_specs=[group, kv, kv],
+        out_specs=[group, stat],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((N, H // G, S // tq, 1, G * tq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, G * tq), q.dtype)]
+        + [pltpu.VMEM((1, G * tq), jnp.float32)] * 2 + [pltpu.VMEM((d, G * tq), jnp.float32)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="band_attn_fwd",
+    )(q, k, v)
+
+
+def _call_band_bwd(q, k, v, do, lse, delta, tq, tk, window, interpret):
+    N, H, d, S = q.shape
+    G = H // k.shape[1]
+    group, kv, stat = _band_specs(G, d, tq, tk, window)
+    whole = pl.BlockSpec((None, None, d, S), lambda n, g, i, j: (n, g, 0, 0))
+    f32 = jnp.float32
+    return pl.pallas_call(
+        partial(_band_bwd_kernel, tq=tq, tk=tk, window=window),
+        grid=(N, H // G, S // tq, _band_steps(S, tq, tk, window)),
+        in_specs=[group, kv, kv, group, stat, stat],
+        out_specs=[group, whole, whole],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32), jax.ShapeDtypeStruct(k.shape, f32),
+                   jax.ShapeDtypeStruct(v.shape, f32)],
+        scratch_shapes=[pltpu.VMEM((d, G * tq), q.dtype)] * 2
+        + [pltpu.VMEM((d, G * tq), f32)],
+        compiler_params=_params(64),
+        interpret=interpret,
+        name="band_attn_bwd",
+    )(q, k, v, do, lse, delta)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _band_flash(q, k, v, tq, tk, window, interpret):
+    """:func:`_gq_flash` under the band of ``window`` (None: the diagonal
+    alone), query tiles outer."""
+    return _band_flash_fwd(q, k, v, tq, tk, window, interpret)[0]
+
+
+def _band_flash_fwd(q, k, v, tq, tk, window, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+
+    ops = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    o, lse = _call_band_fwd(*ops, tq, tk, window, interpret)
+    o, lse = checkpoint_name(o, BAND_OUT), checkpoint_name(lse, BAND_LSE)
+    return o, (ops, o, lse)
+
+
+def _band_flash_bwd(tq, tk, window, interpret, res, do):
+    (q, k, v), o, lse = res
+    N, H, _, S = q.shape
+    kv = k.shape[1]
+    delta = jnp.sum(o * do, axis=2).reshape(N, kv, H // kv, S // tq, tq)
+    delta = jnp.swapaxes(delta, 2, 3).reshape(lse.shape)  # the log-sum-exp's layout (_row_stat)
+    return _call_band_bwd(q, k, v, do.astype(jnp.bfloat16), lse, delta, tq, tk, window, interpret)
+
+
+_band_flash.defvjp(_band_flash_fwd, _band_flash_bwd)
+
+
+def fused_band_attention(q, k, v, scale, window, *, block_q: int, block_k: int,
+                         interpret: bool = False):
+    """``ops.layers.sliding_gq_attention`` (``window`` None:
+    ``causal_gq_attention``) through the kernels above, operands and result
+    heads first (``[N, H, S, d]``), float32 out; tiles of ``block_q`` queries a
+    head by ``block_k`` keys."""
+    if window is not None and window >= q.shape[2]:
+        window = None
+    qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 2, 3) for x in (q, k, v))
+    return jnp.swapaxes(_band_flash(qt * scale, kt, vt, block_q, block_k, window, interpret),
+                        2, 3)
+
+
+#: the largest ``dq`` (float32, a group's whole sequence) ``gq_attn_bwd`` keeps
+#: resident; beyond it :func:`gq_plan` hands the layer to the band pair
+GQ_RESIDENT_BYTES = 8 << 20
+
+def gq_plan(S: int, d: int, group: int, window=None):
+    """THE RULE (the header's): which kernel pair grouped-query attention
+    takes at ``S`` positions, ``group`` query heads of ``d`` dims a key/value
+    head, under the diagonal alone (``window`` None) or a window as well, and
+    at which tiles: None (the ``jnp`` block loop) or ``(pair, query tile, key
+    tile)`` with ``pair`` ``"gq"`` or ``"band"``."""
+    if window is not None and window >= S:
+        window = None
+    tile = gq_tile_for(S, d)
+    if tile is None:
+        return None
+    if window is None and (d % LANES or group * d * S * 4 <= GQ_RESIDENT_BYTES):
+        return "gq", tile, tile
+    if d % LANES:
+        return None
+    # under a window both tiles are at most half of it: the band then holds two
+    # thirds of the pairs of the tiles it crosses, and the backward's score
+    # tiles stay clear of VMEM's edge (a sliding layer's block of the Laguna
+    # cell, forward + backward: 512 x 512 30.7 ms, 512 x 256 25.4, 256 x 256
+    # 24.2, 256 x 128 24.7, 128 x 128 26.0; my chip call 2, PR 42)
+    most = 4096 // group if window is None else min(4096 // group, max(window // 2, TILES[-1]))
+    tq = next((t for t in TILES if S % t == 0 and t <= most), None)
+    if tq is None:
+        return None
+    tk = tile if window is None else tq
+    return "band", tq, tk

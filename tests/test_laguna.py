@@ -1,0 +1,838 @@
+"""Laguna (``models/laguna.py``, ISSUE 42: sliding-window layers of one head
+count among full-attention layers of another, a RoPE a kind -- YaRN on half a
+head in the full layers --, a per-head output gate, sigmoid-routed experts
+beside a shared one) at a tiny size on the CPU: against the benchmark's plain
+reference, the window's edges, the band kernels against the block loop, the
+rule that picks a kernel pair, its slicing rules, the expert shares, and
+through the engines and the entry point.  A file of its own so that the test
+runner's per-file workers share the family's compiles evenly."""
+
+import functools
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from heterofl_tpu import config as C
+from heterofl_tpu.models import make_model
+from heterofl_tpu.models.spec import count_masks, mask_params
+from heterofl_tpu.ops import layers as L
+from heterofl_tpu.parallel import RoundEngine, make_mesh
+
+LEVELS = [1.0, 0.5, 0.25, 0.125, 0.0625]
+
+
+def _laguna_case(seed=1, bptt=None, **arch):
+    """(cfg, model, seeded params with the gains moved off 1, tokens, a label
+    mask with holes, the reference's model description)."""
+    from benchmark.tests import tiny_laguna as tiny
+
+    cfg = tiny.program_cfg(bptt=bptt or tiny.BPTT, **arch)
+    model = make_model(cfg)
+    params = model.init(jax.random.key(seed))
+    keys = jax.random.split(jax.random.key(seed + 1), len(params))
+    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
+              for (k, v), kk in zip(sorted(params.items()), keys)}
+    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
+                                cfg["num_tokens"])
+    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
+    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
+
+
+def _masked_loss_and_grads(model, params, tokens, lm, rate):
+    """Loss and gradients of the masked full-width model at ``rate``, which is
+    traced as the engines trace it: one program for every level."""
+    def system_loss(p, rate):
+        pm = mask_params(p, model.specs, model.groups, rate)
+        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
+                             scaler_rate=rate, label_mask=lm)
+        return out["loss"]
+
+    if "masked" not in model.meta:  # the case's own program, compiled once
+        model.meta["masked"] = jax.jit(jax.value_and_grad(system_loss))
+    return model.meta["masked"](params, jnp.float32(rate))
+
+
+@functools.lru_cache(maxsize=None)
+def _tiny_case():
+    return _laguna_case()
+
+
+# ---------------------------------------------------------------------------
+# the model against the benchmark's plain reference
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rate", LEVELS)
+def test_laguna_masked_model_is_the_references_dense_submodel(rate):
+    """Loss and gradients of the masked full-width model at rate r against the
+    plain reference on the sliced sub-model: rate 1 is the published model
+    (both masks as boolean matrices, half-split RoPE on the un-permuted heads
+    with the reference's own YaRN table, the gate, one expert at a time),
+    every other level HeteroFL's slice of it.  float32 on both sides, so the
+    two differ by summation order alone, amplified by the Scaler's 1/r; 1e-3
+    of a leaf's largest gradient holds levels a-d and 1e-2 level e, where a
+    norm runs over 8 dims (6e-3 is the most it reads); a bfloat16 product, a
+    window off by one, a mis-sliced head or a table of the wrong kind is off
+    by 3e-2 or more."""
+    from benchmark.reference import common, laguna as ref
+
+    cfg, model, params, tokens, lm, rm = _tiny_case()
+    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
+    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
+    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm))))(sub)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    inside = common.take(grads, index)
+    tol = 1e-2 if rate < 0.1 else 1e-3
+    for k, g in ref_grads.items():
+        g = np.asarray(g)
+        # every leaf is trained, the gate too (an expert no token reached apart)
+        assert np.abs(g).max() > 0 or ".moe.e" in k, k
+        np.testing.assert_allclose(inside[k], g, atol=tol * np.abs(g).max() + 1e-9, err_msg=k)
+        outside = np.ones(grads[k].shape, bool)
+        outside[np.ix_(*index[k])] = False
+        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
+
+
+def test_the_references_step_a_part_at_a_time_is_its_whole_gradient():
+    """`benchmark/reference/laguna.py` trains with `loss_and_grads`, the chain
+    rule a layer's part at a time from the host (every kind of part compiled
+    once a level: its programs fit the chip machine's compile cache), where
+    the tests above differentiate `loss_fn`, the Python loop over the layers:
+    the same loss and, leaf by leaf, the same gradient."""
+    from benchmark.reference import common, laguna as ref
+
+    cfg, model, params, tokens, lm, rm = _tiny_case()
+    index = ref.index({k: v.shape for k, v in params.items()}, rm, 0.25)
+    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
+    arch = ref.arch_of(rm)
+    loss, grads = ref.loss_and_grads(sub, tokens, lm, 0.25, arch)
+    want_loss, want = jax.jit(jax.value_and_grad(common.highest(
+        lambda p: ref.loss_fn(p, tokens, lm, 0.25, arch))))(sub)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+    assert set(grads) == set(want)
+    for k, g in want.items():
+        np.testing.assert_allclose(grads[k], g, atol=1e-5 * float(jnp.abs(g).max()) + 1e-12,
+                                   err_msg=k)
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_reference_layer():
+    """The guide's share test: the routed parts that the shares of a 16-way
+    expert-parallel layer compute (the program's `moe_route` and `moe_experts`,
+    each share told its one expert) add up to the UNCUT reference's expert
+    layer, with what every share computes alike (the gated sliding attention,
+    the router, the shared expert) counted once: for the reference's whole
+    layer ``x -> x1 + y`` (``x1`` the state after the attention), ``y =
+    shared(h) + sum over shares of moe_experts(share)``."""
+    from benchmark.reference import common, laguna as ref
+    from benchmark.tests import tiny_laguna as tiny
+
+    cfg = tiny.program_cfg(expert_share=[0, 1])
+    arch, rm = cfg["laguna"], tiny.reference_model(cfg)
+    model = make_model(cfg)
+    assert model.meta["held_experts"] == list(range(16))
+    whole = model.init(jax.random.key(3))
+    x = jax.random.normal(jax.random.key(4), (2, tiny.BPTT, arch["hidden_size"]))
+    a = ref.arch_of(rm)
+    kind, heads, mlp = dict(a)["layers"][1]
+    assert (kind, mlp) == ("sliding_attention", "sparse")
+    lp = ref._layer_leaves(whole, 1, dict(a)["held"])
+    with jax.default_matmul_precision("highest"):
+        x1 = x + ref.attention_mixer(lp, ref._rms(x, lp["norm1.g"], 1e-6), 1.0, a, kind, heads)
+        y_ref = (ref.layer(lp, x, 1.0, a, kind, heads, True) - x1).reshape(2 * tiny.BPTT, -1)
+    hf = ref._rms(x1, lp["norm2.g"], 1e-6).reshape(2 * tiny.BPTT, -1)
+    sel, w = L.moe_route(hf, whole["l1.moe.router.w"], None, arch["num_experts_per_tok"],
+                         arch["moe_routed_scaling_factor"])
+    np.testing.assert_allclose(np.asarray(w).sum(axis=1), 2.5, rtol=1e-6)  # renormalised, scaled
+    parts = [L.moe_experts(hf, sel, w, [whole[f"l1.moe.e{i}.{m}.w"][None] for m in "gud"], i,
+                           lambda v: v, tile=8) for i in range(16)]
+    shared = L.swiglu(hf, *(whole[f"l1.moe.shared.{m}.w"] for m in "gud"), lambda v: v)
+    np.testing.assert_allclose(shared + sum(y for y, _ in parts), y_ref, rtol=1e-4, atol=1e-5)
+    assert sum(float(c["assign"][1]) for _, c in parts) == sel.size  # every pair once
+    assert float(jnp.abs(y_ref - shared).max()) > 1e-3  # the routed experts add something
+
+
+@pytest.mark.parametrize("share", [5])
+def test_a_share_of_the_model_is_the_reference_given_that_share(share):
+    """The program told it holds one sixteenth of the experts against the
+    reference told the same: loss and the held expert's gradients."""
+    from benchmark.reference import common, laguna as ref
+
+    cfg, model, params, tokens, lm, rm = _laguna_case(expert_share=[share, 16])
+    assert model.meta["held_experts"] == [share]
+    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, 1.0)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(common.highest(
+        lambda p: ref.loss_fn(p, tokens, lm, 1.0, ref.arch_of(rm)))))(params)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    for k in (f"l2.moe.e{share}.g.w", f"l2.moe.e{share}.d.w", "l2.moe.router.w",
+              "l2.moe.shared.u.w"):
+        g = np.asarray(ref_grads[k])
+        np.testing.assert_allclose(grads[k], g, atol=1e-3 * np.abs(g).max() + 1e-9, err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# the window
+# ---------------------------------------------------------------------------
+
+def _qkv(S, H=4, Hkv=2, d=16, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return [jax.random.normal(k, (1, n, S, d)) for k, n in zip(ks, (H, Hkv, Hkv))]
+
+
+def _dense_window(q, k, v, scale, window):
+    """Every pair's score under explicit boolean masks, nothing in blocks."""
+    G = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, G, axis=1) for t in (k, v))
+    i, j = jnp.arange(q.shape[2])[:, None], jnp.arange(q.shape[2])[None, :]
+    keep = (j <= i) & (i - j < window)
+    s = jnp.where(keep, jnp.einsum("nhqd,nhkd->nhqk", q, k) * scale, -jnp.inf)
+    return jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("S, window, block", [
+    (48, 64, 16), (64, 64, 16), (64, 64, 64), (70, 16, 32), (96, 16, 16), (600, 512, 256)],
+    ids=["S<window", "S=window", "one-block", "S-not-a-multiple-of-the-block",
+         "S>window+block", "query-512-at-window-512"])
+def test_the_block_loop_under_a_window_is_the_masked_dense_form(S, window, block):
+    """`blockwise_gq_attention(window=)` against every pair under boolean
+    masks; where the window holds every causal pair (S <= window) it is the
+    causal block loop TO THE BIT, by the same program."""
+    q, k, v = _qkv(S)
+    got = L.blockwise_gq_attention(q, k, v, 0.25, block, window=window)
+    np.testing.assert_allclose(got, _dense_window(q, k, v, 0.25, window), atol=2e-6)
+    if S <= window:
+        np.testing.assert_array_equal(got, L.blockwise_gq_attention(q, k, v, 0.25, block))
+
+
+def test_query_511_sees_key_0_and_query_512_does_not():
+    """A window of 512 = itself and the 511 before it: moving key 0's value
+    moves the output of queries 0..511 and of no later one."""
+    q, k, v = _qkv(600, H=2, Hkv=1, d=8)
+    base = L.blockwise_gq_attention(q, k, v, 0.35, 256, window=512)
+    moved = L.blockwise_gq_attention(q, k, v.at[:, :, 0].add(10.0), 0.35, 256, window=512)
+    changed = np.asarray(jnp.abs(moved - base).max(axis=(0, 1, 3)) > 0)
+    assert changed[:512].all() and not changed[512:].any()
+
+
+def test_a_block_under_a_window_never_slices_the_keys_below_the_band(monkeypatch):
+    """The block loop's key blocks start at the band's lower edge: with S = 96,
+    a window of 16 and blocks of 16, no block reads more than 31 keys (its own
+    16 and the 15 before its first query), where the causal loop reads up to
+    96."""
+    seen = []
+    real = jnp.einsum
+
+    def spy(spec, *ops, **kw):
+        if spec == "ngjqd,ngkd->ngjqk":
+            seen.append(ops[1].shape[-2])
+        return real(spec, *ops, **kw)
+
+    monkeypatch.setattr(jnp, "einsum", spy)
+    q, k, v = _qkv(96)
+    L.blockwise_gq_attention(q, k, v, 0.25, 16, window=16)
+    assert seen == [16] + [31] * 5
+    del seen[:]
+    L.blockwise_gq_attention(q, k, v, 0.25, 16)
+    assert seen == [16, 32, 48, 64, 80, 96]
+
+
+@pytest.mark.parametrize("heads, kv_heads, window, tiles", [
+    (8, 1, 128, (128, 128)), (8, 1, 200, (256, 128)), (6, 1, None, (128, 128)),
+    (4, 2, 512, (128, 256))],
+    ids=["G8-window128", "G8-window200-tq256", "G6-diagonal", "G2-window>=S"])
+def test_band_kernels_in_interpret_mode_are_the_block_loop(heads, kv_heads, window, tiles):
+    """`band_attn_fwd` / `band_attn_bwd` (interpret mode) against the block
+    loop on bfloat16-rounded operands (the kernels' own cast), output and the
+    three gradients: what is left is the probabilities' bfloat16 rounding
+    before the value product (1e-2 of the largest entry; a tile skipped or a
+    mask edge off by one is off by 1e-1)."""
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    S, d = 512 if window == 200 else 256, 128
+    ks = jax.random.split(jax.random.key(3), 4)
+    q, k, v, probe = (jax.random.normal(kk, (1, n, S, d))
+                      for kk, n in zip(ks, (heads, kv_heads, kv_heads, heads)))
+
+    def rounded(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    def fused(q, k, v):
+        return jnp.sum(PA.fused_band_attention(q, k, v, 0.3, window, block_q=tiles[0],
+                                               block_k=tiles[1], interpret=True) * probe)
+
+    def loop(q, k, v):
+        return jnp.sum(L.blockwise_gq_attention(rounded(q * 0.3), rounded(k), rounded(v), 1.0,
+                                                64, window=window) * probe)
+
+    got = jax.value_and_grad(fused, argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loop, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=5e-3)
+    for g, w, name in zip(got[1], want[1], "qkv"):
+        np.testing.assert_allclose(g, w, atol=1e-2 * float(jnp.abs(w).max()), err_msg=name)
+
+
+def test_a_window_of_none_gives_the_accepted_kernels_numbers():
+    """The band pair without a window against `gq_attn_fwd` / `gq_attn_bwd`
+    (both in interpret mode, the same tiles): the same products in the same
+    precision, tile by tile in the same order along the keys, so the output
+    agrees to float32 rounding and the gradients to the order of their sums."""
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    ks = jax.random.split(jax.random.key(5), 4)
+    q, k, v, probe = (jax.random.normal(kk, (1, n, 256, 128)) for kk, n in zip(ks, (4, 2, 2, 4)))
+
+    def loss(fn, **kw):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v, 0.3, **kw) * probe),
+                                  argnums=(0, 1, 2))(q, k, v)
+
+    kw = dict(block_q=128, block_k=128, interpret=True)
+    band, gq = loss(PA.fused_band_attention, window=None, **kw), loss(PA.fused_gq_attention, **kw)
+    np.testing.assert_allclose(band[0], gq[0], rtol=1e-6)
+    for a, b in zip(band[1], gq[1]):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()))
+
+
+def test_the_rule_gives_every_cell_its_pair():
+    """`pallas_attention.gq_plan`, the one rule: the LFM2 and Ouro cells keep
+    `gq_attn_*` at tiles of 512 (their `dq` is 2 MB and 1 MB), both kinds of
+    Laguna layer take the band pair (`dq` would be 34 MB and 25 MB), a client's
+    narrow slice and ragged rows take the block loop, and a window that holds
+    the whole row is no window."""
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    assert PA.gq_plan(2048, 64, 4) == ("gq", 512, 512) == PA.gq_plan(2048, 128, 1)
+    assert PA.gq_plan(8192, 128, 8, 512)[0] == PA.gq_plan(8192, 128, 6)[0] == "band"
+    assert PA.gq_plan(8192, 128, 8, 512)[1:] == (256, 256)  # at most half the window
+    assert PA.gq_plan(8192, 128, 6)[1:] == (512, 512) and PA.gq_plan(8192, 128, 8, 128)[1] == 128
+    assert PA.gq_plan(8192, 128, 8) == ("band", 512, 512)  # the Keye shape under the diagonal
+    assert PA.gq_plan(8192, 64, 4) == ("gq", 512, 512)    # 64-wide heads stay where they were
+    assert PA.gq_plan(2048, 128, 1, 4096) == PA.gq_plan(2048, 128, 1)
+    assert PA.gq_plan(2048, 32, 8, 512) is None and PA.gq_plan(2000, 128, 8, 512) is None
+    assert PA.gq_plan(2048, 64, 8, 512) is None  # a window on 64-wide heads: the block loop
+    for S, tq, tk, window, want in [(8192, 512, 512, 512, (31, 136)), (8192, 256, 256, 512, (93, 528)),
+                                    (8192, 512, 512, None, (136, 136)), (64, 256, 256, 16, (1, 1)),
+                                    (96, 16, 16, 16, (11, 21))]:
+        assert PA.band_extent(S, tq, tk, window) == want, (S, tq, tk, window)
+        if S % tq == 0:
+            assert PA._band_steps(S, tq, tk, window) == (S // tk if window is None else
+                                                         {512: 2, 256: 3, 16: 2}[tk])
+
+
+# ---------------------------------------------------------------------------
+# the two turns
+# ---------------------------------------------------------------------------
+
+def test_yarns_table_is_the_references_and_the_published_numbers():
+    """`models.laguna.rope_frequencies` against the reference's own
+    (`frequencies`, `yarn_range`) at the published numbers: low 5, high 16, the
+    factor 0.1 ln 64 + 1, pairs below `low` untouched, pairs from `high` on
+    divided by 64, and the sliding layers' table the plain one."""
+    from benchmark.reference import laguna as ref
+    from heterofl_tpu.models.laguna import rope_frequencies
+
+    rope = C.process_control(_control_cfg())["laguna"]["rope_parameters"]
+    full, sliding = rope["full_attention"], rope["sliding_attention"]
+    assert ref.yarn_range(full, 64) == (5, 16)
+    freqs, factor = rope_frequencies(full, 64)
+    want, want_factor = ref.frequencies(full, 64)
+    np.testing.assert_allclose(freqs, want, rtol=1e-12)
+    assert factor == want_factor == pytest.approx(0.1 * np.log(64) + 1, rel=1e-9)
+    plain = 5e5 ** (-2.0 * np.arange(32) / 64)
+    np.testing.assert_allclose(freqs[:6], plain[:6], rtol=1e-12)
+    np.testing.assert_allclose(freqs[16:], plain[16:] / 64, rtol=1e-12)
+    assert (np.diff(freqs) < 0).all()
+    freqs, factor = rope_frequencies(sliding, 128)
+    np.testing.assert_allclose(freqs, 1e4 ** (-2.0 * np.arange(64) / 128), rtol=1e-12)
+    assert factor is None and ref.frequencies(sliding, 128)[1] == 1.0
+    with pytest.raises(ValueError, match="Not valid rope_type"):
+        rope_frequencies(dict(full, rope_type="linear"), 64)
+
+
+def _control_cfg():
+    cfg = C.default_cfg()
+    cfg["control"] = C.parse_control_name("1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1")
+    cfg["data_name"], cfg["model_name"] = "WikiText2", "laguna"
+    return cfg
+
+
+def test_a_half_rotary_heads_pass_through_dims_do_not_turn():
+    """A full layer's query and key reach the attention as [turned half |
+    pass-through half]: the second half is the `q.n` / `k.n` product itself,
+    whatever the position; the first half is the table's turn times the
+    factor; a sliding layer's whole head turns."""
+    from heterofl_tpu.models.laguna import gated_gq_attention, rope_frequencies
+
+    cfg, model, params, _, _, _ = _laguna_case()
+    rope = cfg["laguna"]["rope_parameters"]
+    h = jax.random.normal(jax.random.key(4), (1, 64, 128))
+    seen = {}
+
+    def attend(q, k, v, scale):
+        seen.update(q=q, k=k)
+        return jnp.zeros(q.shape[:-1] + (v.shape[-1],))
+
+    lp = {k[3:]: v for k, v in params.items() if k.startswith("l0.")}
+    freqs, factor = rope_frequencies(rope["full_attention"], 16)
+    gated_gq_attention(lp, h, heads=6, kv_heads=2, freqs=freqs, factor=factor, scale=1.0,
+                       sc=lambda x: x, attend=attend)
+    for m, n in (("q", 6), ("k", 2)):
+        rest = (h @ lp[f"attn.{m}.n.w"]).reshape(1, 64, n, 16).swapaxes(1, 2)
+        np.testing.assert_allclose(seen[m][..., 16:], rest, atol=1e-6)
+        raw = (h @ lp[f"attn.{m}.r.w"]).reshape(1, 64, n, 16).swapaxes(1, 2)
+        np.testing.assert_allclose(seen[m][:, :, 0, :16], factor * raw[:, :, 0], rtol=1e-5,
+                                   atol=1e-6)
+        ang = 5 * freqs[0]  # position 5, pair 0 = stored dims 0 and 1
+        turned = factor * (raw[:, :, 5, 0] * np.cos(ang) - raw[:, :, 5, 1] * np.sin(ang))
+        np.testing.assert_allclose(seen[m][:, :, 5, 0], turned, rtol=1e-4, atol=1e-6)
+    lp = {k[3:]: v for k, v in params.items() if k.startswith("l1.")}
+    assert "attn.q.n.w" not in lp and lp["attn.q.r.w"].shape == (128, 8 * 32)
+
+
+def test_rope_interleaved_with_no_table_is_what_it_was():
+    """The shared turn without `freqs` / `factor` traces to the jaxpr it traced
+    to before it took them (the LFM2, Keye, Ouro and Kanana-2 programs), and a
+    table equal to theta's gives its numbers."""
+    x = jax.random.normal(jax.random.key(0), (1, 2, 8, 16))
+    pos = jnp.arange(8)
+    base = L.rope_interleaved(x, L.rope_swap(x), pos, 1e4, axis=2, full=32)
+    table = 1e4 ** (-2.0 * np.arange(16) / 32)
+    np.testing.assert_allclose(
+        L.rope_interleaved(x, L.rope_swap(x), pos, None, axis=2, freqs=table), base, rtol=1e-5,
+        atol=1e-6)
+    np.testing.assert_allclose(
+        L.rope_interleaved(x, L.rope_swap(x), pos, None, axis=2, freqs=table, factor=1.5),
+        1.5 * base, rtol=1e-5, atol=1e-6)
+    text = str(jax.make_jaxpr(lambda x: L.rope_interleaved(x, x, pos, 1e4, axis=2, full=32))(x))
+    assert "repeat" not in text and "1.5" not in text  # no table, no factor: nothing of either
+
+
+# ---------------------------------------------------------------------------
+# slicing
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rate", LEVELS)
+def test_laguna_heads_keep_equal_dims_and_whole_pairs(rate):
+    """Each family of head groups (a kind's rotary dims, a full layer's
+    pass-through dims, the value heads and the output projection's rows) keeps
+    the SAME dims a head at every level, the rotary ones in whole pairs; the
+    gates' columns, the router's and the expert axis are never cut; the
+    geometry check holds the families."""
+    from heterofl_tpu.fed.core import validate_width_geometry
+
+    cfg, model, _, _, _, _ = _laguna_case()
+    families = {}
+    for name, g in model.groups.items():
+        if g.kind == "per_head":
+            hd = g.size // g.num_heads
+            m = np.asarray(g.mask(rate)).reshape(g.num_heads, hd)
+            assert (m == m[0]).all(), name  # every head alike
+            k = int(m[0].sum())
+            assert m[0, :k].all() and k % g.multiple == 0, (name, k)  # a prefix, whole pairs
+            assert int(g.active_count(rate)) == g.num_heads * k
+            families.setdefault(g.family, set()).add(k)
+    want = {"full.rope": max(2, int(np.ceil(16 * rate))), "full.nope": int(np.ceil(16 * rate)),
+            "sliding.rope": max(2, int(np.ceil(32 * rate))), "head": max(2, int(np.ceil(32 * rate)))}
+    assert families == {k: {v} for k, v in want.items()}
+    for name in ("full6.gate", "sliding8.gate", "router"):
+        assert np.asarray(model.groups[name].mask(rate)).all()
+    validate_width_geometry(model, cfg)
+
+
+def test_laguna_counts_follow_width_and_labels():
+    """A client counts for every element of its slice -- for an expert it holds
+    whether or not a token reached it --; embedding rows and head columns
+    follow the labels the client holds."""
+    from benchmark.reference import laguna as ref
+    from benchmark.tests import tiny_laguna as tiny
+
+    cfg = tiny.program_cfg()
+    model = make_model(cfg)
+    shapes = dict(model.meta["shapes"])
+    assert ref.LABEL_AXES == {k: s.label_axis for k, s in model.specs.items()
+                              if s.label_axis is not None}
+    labels = np.zeros(cfg["num_tokens"], np.float32)
+    labels[::3] = 1.0
+    for rate in (1.0, 0.25, 0.0625):
+        cm = count_masks(shapes, model.specs, model.groups, rate, jnp.asarray(labels))
+        index = ref.index(shapes, tiny.reference_model(cfg), rate)
+        for k, shape in shapes.items():
+            want = np.zeros(shape, np.float32)
+            want[np.ix_(*index[k])] = 1.0
+            if k in ref.LABEL_AXES:
+                view = [1] * len(shape)
+                view[ref.LABEL_AXES[k]] = -1
+                want = want * labels.reshape(view)
+            np.testing.assert_array_equal(np.asarray(cm[k]), want, err_msg=f"{k} @ {rate}")
+
+
+def test_the_lists_are_read_and_no_period_is_assumed():
+    """The layer kinds, head counts and feed-forwards come from the three
+    lists: any order builds (two sliding layers first, the dense layer last)
+    and matches the reference; lists of the wrong length or an unknown kind
+    are refused, as heads that do not divide over the key/value heads are."""
+    from benchmark.reference import common, laguna as ref
+    from benchmark.tests import tiny_laguna as tiny
+
+    odd = dict(num_hidden_layers=2, layer_types=["sliding_attention", "full_attention"],
+               mlp_layer_types=["sparse", "dense"], num_attention_heads_per_layer=[4, 2])
+    cfg, model, params, tokens, lm, rm = _laguna_case(**odd)
+    assert "l1.mlp.g.w" in params and "l0.moe.router.w" in params and "l0.attn.q.n.w" not in params
+    loss = jax.jit(lambda p: model.apply(p, {"label": tokens}, train=True,
+                                         label_mask=lm)[0]["loss"])(params)
+    want = jax.jit(common.highest(
+        lambda p: ref.loss_fn(p, tokens, lm, 1.0, ref.arch_of(rm))))(params)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    for bad in (dict(layer_types=["full_attention"] * 4), dict(mlp_layer_types=["moe"] * 5),
+                dict(layer_types=["linear_attention"] * 5),
+                dict(num_attention_heads_per_layer=[6, 8, 8, 8, 5])):
+        with pytest.raises(ValueError, match="layer lists|do not divide"):
+            make_model(tiny.program_cfg(**bad))
+
+
+def test_level_tables_know_the_family_and_count_attention_by_kind():
+    """`level_param_table` counts the sliced sub-model's own leaves, the FLOP
+    table falls with the level, and `analysis.summary.module_table` reads a
+    site's window from ``meta["profile"]``: its matmul rows hold
+    `benchmark/flops/laguna.py`'s forward FLOPs at rate 1, a sliding layer's
+    two products over the band pairs at its 8 heads, a full layer's over the
+    causal pairs at its 6."""
+    from benchmark import harness
+    from benchmark.tests import tiny_laguna as tiny
+    from heterofl_tpu.analysis.summary import module_table
+    from heterofl_tpu.fed.core import level_flop_table, level_param_table
+
+    cfg = tiny.program_cfg()
+    for rate, n in level_param_table(cfg).items():
+        shapes = jax.eval_shape(make_model(cfg, rate).init, jax.random.key(0))
+        assert n == sum(int(np.prod(v.shape)) for v in shapes.values()), rate
+    table = level_flop_table(cfg)
+    assert sorted(table.values(), reverse=True) == [table[r] for r in sorted(table, reverse=True)]
+    flops = harness.load_module("flops", "laguna")
+    model, rows = tiny.reference_model(cfg), 2
+    by_name = {r[0]: r for r in module_table(cfg, 1.0, rows)}
+    macs = sum(r[4] for name, r in by_name.items()
+               if name != "embedding" and not re.search(r"norm\d*\.g$", name))
+    assert 2 * macs == rows * flops.forward_flops(model, 1.0)
+    band, causal = 16 * 17 // 2 + 48 * 16, 64 * 65 // 2
+    assert flops.band_pairs(model) == band and flops.causal_pairs(model) == causal
+    assert by_name["l1.attn.qk"][4] == rows * 8 * band * 32
+    assert by_name["l0.attn.av"][4] == by_name["l4.attn.qk"][4] == rows * 6 * causal * 32
+
+
+# ---------------------------------------------------------------------------
+# through the engines and the entry point
+# ---------------------------------------------------------------------------
+
+def _round_case():
+    """(cfg, data) of 8 users with 2 rows of 32 tokens each (a window of 16
+    binds); every client lacks every fifth token and nobody holds token 3 or
+    4."""
+    from benchmark.tests import tiny_laguna as tiny
+
+    cfg = tiny.program_cfg(control="1_8_0.5_iid_fix_a1-e1_bn_1_1", bptt=32)
+    vocab = cfg["num_tokens"]
+    rows = np.random.default_rng(0).integers(5, vocab, size=(8, 2, 32)).astype(np.int64)
+    lm = np.ones((8, vocab), np.float32)
+    lm[:, :5] = 0.0
+    lm[:, ::5] = 0.0
+    return cfg, (jnp.asarray(rows), jnp.asarray(lm))
+
+
+def _round(cfg, data, chunk, n_dev=1, users=np.arange(8), **extra):
+    cfg = dict(cfg, round_chunk=chunk, **extra)
+    model = make_model(cfg)
+    eng = RoundEngine(model, cfg, make_mesh(n_dev, 1))
+    params0 = model.init(jax.random.key(0))
+    before = {k: np.asarray(v) for k, v in params0.items()}  # the round donates its input
+    out, ms = eng.train_round(params0, jax.random.key(5), 0.5, users, data)
+    return (before, {k: np.asarray(v) for k, v in out.items()},
+            {k: np.asarray(v) for k, v in ms.items()})
+
+
+@pytest.fixture(scope="module")
+def masked_round():
+    cfg, data = _round_case()
+    return (cfg, data) + _round(cfg, data, 1)
+
+
+@pytest.mark.parametrize("level", ["a", "e"])
+def test_laguna_one_whole_local_step_is_the_references(level):
+    """A round of one client of a level, its local step through the masked
+    engine (global-norm clip, momentum SGD with weight decay, the counted
+    average), against the plain reference's round on the same client: every
+    leaf within 1e-4 of its largest entry (3e-4 at level e, whose norms run
+    over 8 dims: the gradients' own float32 rounding, as in the test above)."""
+    from benchmark.reference import common, laguna as ref
+    from benchmark.tests import tiny_laguna as tiny
+
+    cfg, data = _round_case()
+    cfg = dict(cfg, round_chunk=1)
+    rate = C.MODEL_SPLIT_RATE[level]
+    user = next(u for u in range(8) if cfg["model_rate"][u] == rate)
+    model = make_model(cfg)
+    params0 = model.init(jax.random.key(0))
+    before = {k: np.asarray(v) for k, v in params0.items()}
+    eng = RoundEngine(model, cfg, make_mesh(1, 1))
+    out, ms = eng.train_round(params0, jax.random.key(5), 0.1, np.full(8, user), data)
+    config = {"model": tiny.reference_model(cfg),
+              "optimizer": {"momentum": cfg["momentum"], "weight_decay": cfg["weight_decay"]}}
+    client = {"rate": rate, "labels": np.flatnonzero(np.asarray(data[1][user])), "epochs": 1,
+              "rows": np.asarray(data[0][user]), "copies": 1}
+    want, losses = common.run_round(ref, config, before, [client], 0.1, 0)
+    np.testing.assert_allclose(np.asarray(ms["loss_sum"])[0] / np.asarray(ms["n"])[0], losses[0],
+                               rtol=1e-5)
+    tol = 3e-4 if level == "e" else 1e-4
+    for k, v in want.items():
+        np.testing.assert_allclose(np.asarray(out[k]), v, atol=tol * np.abs(v).max() + 1e-9,
+                                   err_msg=k)
+        assert (np.asarray(out[k]) != before[k]).any(), k
+
+
+def test_laguna_masked_round_in_chunks_of_one_is_the_unchunked_round(masked_round):
+    """`round_chunk` 1, the cell's setting: one slot at a time is the round of
+    one vmap over all 8 slots up to the order of float32 sums."""
+    cfg, data, _, out, ms = masked_round
+    _, base, base_ms = _round(cfg, data, None)
+    for k in base:
+        np.testing.assert_allclose(out[k], base[k], rtol=1e-5, atol=3e-6, err_msg=k)
+    for k in ("loss_sum", "n", "rate"):
+        np.testing.assert_allclose(ms[k], base_ms[k], rtol=1e-5)
+    assert np.isfinite(ms["loss_sum"]).all() and (ms["n"] == 2).all()
+
+
+def test_laguna_a_level_e_round_leaves_everything_outside_its_slice(masked_round):
+    """The slicing round-trips: a round of the smallest level alone moves
+    entries inside its slice and leaves everything outside bit for bit; rows
+    of tokens nobody holds come back as they were."""
+    from benchmark.reference import laguna as ref
+    from benchmark.tests import tiny_laguna as tiny
+
+    cfg, data, before, out, _ = masked_round
+    held = np.asarray(data[1]).max(axis=0) > 0
+    changed = out["embedding.tok.w"] != before["embedding.tok.w"]
+    assert not changed[~held].any() and changed[held].any(axis=1).all()
+    changed = out["head.w"] != before["head.w"]
+    assert not changed[:, ~held].any() and changed[:, held].any(axis=0).all()
+    small = [u for u in range(8) if cfg["model_rate"][u] == min(cfg["model_rate"])]
+    _, new, _ = _round(cfg, data, 1, users=np.resize(small, 8))
+    index = ref.index({k: v.shape for k, v in before.items()}, tiny.reference_model(cfg),
+                      min(cfg["model_rate"]))
+    for k, b in before.items():
+        inside = np.zeros(b.shape, bool)
+        inside[np.ix_(*index[k])] = True
+        moved = new[k] != b
+        assert not moved[~inside].any(), k
+        assert moved[inside].any(), k
+
+
+def test_laguna_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_round):
+    """The grouped engine's per-level dense programs take the family as any
+    other (no validator tests a model's name): its round is the masked
+    engine's up to the order of float32 sums through a step at lr 0.5.  What
+    it lacks is the chunked cohort, refused by key at config resolution."""
+    from heterofl_tpu.parallel.grouped import GroupedRoundEngine
+
+    cfg, data, _, base, _ = masked_round
+    cfg = dict(cfg, strategy="grouped")
+    model, users = make_model(cfg), np.arange(8)
+    rates = np.asarray([cfg["model_rate"][u] for u in users], np.float32)
+    out = GroupedRoundEngine(cfg, make_mesh(1, 1)).train_round(
+        model.init(jax.random.key(0)), users, rates, data, 0.5, jax.random.key(5))[0]
+    for k in base:
+        np.testing.assert_allclose(out[k], base[k], atol=5e-3, err_msg=k)
+    with pytest.raises(ValueError, match="round_chunk"):
+        C.resolve_chunk_cfg(dict(cfg, round_chunk=1))
+
+
+def test_nothing_in_the_engines_names_the_family():
+    """`parallel/` and `fed/` take the family through `ModelDef` alone: no file
+    of either names it (the issue's "nothing should change")."""
+    import pathlib
+
+    import heterofl_tpu
+
+    root = pathlib.Path(heterofl_tpu.__file__).parent
+    hits = [str(p) for d in ("parallel", "fed") for p in (root / d).glob("*.py")
+            if "laguna" in p.read_text().lower()]
+    assert not hits
+
+
+def test_laguna_counters_ride_the_metrics(tmp_path):
+    """telemetry='on' carries the counters out: the experts' (`obs_moe_tokens`,
+    `obs_moe_assign`) and the window's three pairs, finished by
+    `obs.split_probes`: `swa_pairs` = band over causal pairs (a window of 16
+    on rows of 32: 392 / 528), `swa_tiles` = 1 here (one block holds the row)
+    and `swa_fused` = 0 (the block loop); `obs.report` renders them."""
+    from heterofl_tpu.obs import report, split_probes
+
+    cfg, data = _round_case()
+    _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
+    for k in ("obs_swa_fused", "obs_swa_pairs", "obs_swa_tiles"):
+        assert ms[k].shape == (2 * 2,), k
+    # 8 clients x 1 step x 3 sliding layers x 2 rows
+    assert ms["obs_swa_pairs"].reshape(2, 2).sum(axis=0).tolist() == [48 * 392.0, 48 * 528.0]
+    clean, rounds = split_probes(dict(ms), 2)
+    rec = rounds[0]
+    assert rec["swa_pairs"] == pytest.approx(392 / 528) and rec["swa_tiles"] == 1.0
+    assert rec["swa_fused"] == 0.0
+    assert rec["moe_dropped"] == 0 and 0.0 < rec["moe_held_share"] < 1.0
+    assert len(rec["moe_tokens"]) == 4
+    assert not [k for k in clean if k.startswith("obs_")]
+    events = tmp_path / "events.jsonl"
+    events.write_text(json.dumps({"v": 1, "t": 0.0, "name": "probes", "cat": "obs", "ph": "i",
+                                  "args": rec}) + "\n")
+    ev = report.summarize_events(str(events))
+    assert ev["swa"]["rounds"] == 1 and ev["swa"]["pairs"] == rec["swa_pairs"]
+    assert any(line.startswith("  sliding layers over 1 rounds: band over causal pairs 0.7424")
+               for line in report.render_events(ev))
+
+
+@pytest.mark.parametrize("S, window, block, fused, below_one", [
+    (64, 16, 256, False, False), (96, 16, 16, False, True), (8192, 512, 256, False, True),
+    (8192, 512, 256, True, True)],
+    ids=["one-block", "S>window+block", "cell-block-loop", "cell-kernels"])
+def test_swa_tiles_is_below_one_once_the_band_leaves_tiles_under_it(S, window, block, fused,
+                                                                    below_one, monkeypatch):
+    """`sliding_attention_tiles`, what the model's `swa_tiles` counts, from the
+    grid's extents: 1 while one block holds the row, below 1 when S > window +
+    block; at the cell's shapes the kernels (tiles of 256) and the block loop
+    (blocks of 256) both visit 93 of 528 key tiles a head's row (3 a query tile
+    but the first two)."""
+    if fused:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    took, visited, causal = L.sliding_attention_tiles(S, 128, 8, window, block)
+    assert took is fused and (visited < causal) is below_one
+    if S == 8192:
+        assert (visited, causal) == (93, 528)
+
+
+def test_laguna_model_takes_the_band_kernels_where_a_tpu_gives_them_tiles(monkeypatch):
+    """Steered to "tpu" at shapes the kernels tile (heads of 128, rows of 256,
+    a window of 128), both kinds of layer go through `fused_band_attention`
+    (here in interpret mode), the sliding ones with the window and the full
+    ones without, `swa_fused` reads 1 and loss and gradients stay the block
+    loop's up to the kernels' bfloat16 operands."""
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    tiled = dict(bptt=256, head_dim=128, sliding_window=128, hidden_size=64,
+                 num_hidden_layers=2, layer_types=["full_attention", "sliding_attention"],
+                 mlp_layer_types=["dense", "sparse"],
+                 num_attention_heads_per_layer=[3, 4], num_key_value_heads=1,
+                 intermediate_size=64)
+    cfg, model, params, tokens, lm, _ = _laguna_case(**tiled)
+
+    def run():
+        def loss(p):
+            out, _ = model.apply(p, {"label": tokens}, train=True, label_mask=lm)
+            return out["loss"], out["counters"]
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    (base, base_c), base_g = run()
+    assert base_c["swa_fused"].tolist() == [0.0, 2.0]  # 1 sliding layer x 2 rows
+    calls = []
+    real = PA.fused_band_attention
+    # the rule asks for 64 MB of residency before it leaves the gq pair: lower
+    # the bar so that the full layer's tiny group goes to the band pair too
+    monkeypatch.setattr(PA, "GQ_RESIDENT_BYTES", 0)
+    monkeypatch.setattr(PA, "fused_band_attention", lambda q, k, v, scale, window, **kw: (
+        calls.append((q.shape[1], window, kw["block_q"], kw["block_k"])),
+        real(q, k, v, scale, window, interpret=True, **kw))[1])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    (loss, counters), grads = run()
+    assert set(calls) == {(3, None, 256, 256), (4, 128, 128, 128)}  # half the window
+    assert counters["swa_fused"].tolist() == [2.0, 2.0]
+    assert counters["swa_tiles"].tolist() == [6.0, 6.0]  # 2 rows x 3 tiles, none under the band
+    assert counters["swa_pairs"][0] < counters["swa_pairs"][1]
+    np.testing.assert_allclose(float(loss), float(base), rtol=2e-3)
+    for k, g in base_g.items():
+        np.testing.assert_allclose(grads[k], g, atol=3e-2 * float(jnp.abs(g).max()) + 1e-9,
+                                   err_msg=k)
+
+
+def test_laguna_trains_and_evaluates_through_the_entry_point(tmp_path):
+    """One whole `FedExperiment.train_round` (masked engine, `round_chunk` 1)
+    and one `evaluate`, built as `entry.common.run_main` builds them from the
+    command line: `--model_name laguna` is all that names the family."""
+    from benchmark.tests import tiny_laguna as tiny
+    from heterofl_tpu.entry.common import FedExperiment, build_cli, cfg_from_args
+    from heterofl_tpu.utils.logger import Logger
+
+    override = {"laguna": dict(tiny.ARCH), "bptt": 32,
+                "batch_size": {"train": 20, "test": 10}, "round_chunk": 1,
+                "num_epochs": {"global": 2, "local": 1}}
+    argv = ["--control_name", "1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1",
+            "--model_name", "laguna", "--data_name", "WikiText2", "--synthetic", "1",
+            "--synthetic_sizes", json.dumps({"train": 20 * 32, "test": 10 * 32}),
+            "--mesh", json.dumps({"clients": 1, "data": 1}),
+            "--output_dir", str(tmp_path), "--override", json.dumps(override)]
+    cfg = C.process_control(cfg_from_args(build_cli("test").parse_args(argv)))
+    exp = FedExperiment(cfg, cfg["init_seed"])
+    assert exp.kind == "transformer" and exp.engine.is_lm and exp.engine._chunk == 1
+    data_split, label_split = exp.make_splits()
+    exp.stage(data_split, label_split)
+    logger = Logger(str(tmp_path / "log"))
+    params = exp.model.init(jax.random.key(0))
+    before = {k: np.asarray(v) for k, v in params.items()}
+    params = exp.train_round(params, 1, 0.1, logger)
+    moved = [k for k, v in params.items() if not np.array_equal(np.asarray(v), before[k])]
+    assert len(moved) == len(before)
+    named = exp.evaluate(params, 1, logger, label_split)
+    assert np.isfinite(named["Global-Loss"]) and named["Global-Perplexity"] > 1.0
+
+
+def test_laguna_tiny_cell_is_correct_and_its_control_is_not(monkeypatch, capsys):
+    """`benchmark/checks.compare` on the tiny configuration, through the
+    benchmark's own command: sound as returned, not `correct` once the check
+    rounds' result has passed through bfloat16 (the test lives with the
+    benchmark's; run here so that the gate holds it)."""
+    from benchmark.tests import test_laguna
+
+    test_laguna.test_a_sound_run_of_the_tiny_cell_is_correct_and_the_control_is_not(
+        monkeypatch, capsys)
+
+
+def test_the_cut_configuration_has_the_parameters_it_states():
+    """490,297,344 from `jax.eval_shape` of the model's own `init` at the
+    configuration's sizes: layer 0 79,794,176, three sliding layers of
+    91,885,568, the full layer with experts 83,464,192, the final norm and the
+    untied vocabulary twice."""
+    from benchmark.tests import test_laguna
+
+    test_laguna.test_the_stated_parameter_count_is_the_programs()
+
+
+# ---------------------------------------------------------------------------
+# the scope ISSUE 42 added (obs.trace.WINDOW_SCOPES)
+# ---------------------------------------------------------------------------
+
+def test_the_window_carries_its_name(masked_round):
+    """`swa` reaches the round program's `op_name`s under `step/model`, forward
+    and backward, and holds the sliding layers' score / softmax / value part
+    alone: the full layers' stays under `attn`, the projections and the gate
+    under `gqa`, both turns under `rope`, the experts under `moe/*`; no
+    instruction is under both `swa` and `attn`."""
+    from heterofl_tpu.obs import trace
+
+    assert trace.WINDOW_SCOPES == ("swa",) and trace.SCOPE_VERSION >= 7
+    cfg, data = masked_round[:2]
+    cfg = dict(cfg, round_chunk=1)
+    model = make_model(cfg)
+    eng = RoundEngine(model, cfg, make_mesh(1, 1))
+    users = np.arange(8, dtype=np.int32)
+    fix = (eng.fix_rates,) if eng.fix_rates is not None else ()
+    args = (model.init(jax.random.key(0)), jax.random.key(0), np.float32(0.1), users, users,
+            *data, *fix)
+    names = ["/" + n for n in re.findall(
+        r'op_name="([^"]+)"', eng._build_train().lower(*args).compile().as_text())]
+    for s in ("swa", "attn", "gqa", "moe/experts", "moe/shared", "moe/router"):
+        mine = [n for n in names if f"/{s}/" in n and "step/model" in n]
+        assert any("/jvp(step/model)/" in n for n in mine), s
+        assert any("transpose(" in n for n in mine), s
+    assert any("/rope/" in n for n in names)
+    assert not [n for n in names if "/swa/" in n and "/attn/" in n]
+    assert not [n for n in names if "/swa/" in n and "/gqa/" in n]
+    assert any(re.search(r"/gqa/linear/dot_general", n) for n in names)

@@ -219,6 +219,45 @@ def test_gq_kernels_compile_at_heads_of_128_in_groups_of_one(one_chip, vmapped, 
     assert not re.search(r"f32\[(?:1,)?1,16,2048,2048\]", text)
 
 
+@pytest.mark.parametrize("vmapped", [False, True], ids=["cell", "vmap1"])
+@pytest.mark.parametrize("heads, window, scope_name", [(64, 512, "swa"), (48, None, "attn")],
+                         ids=["sliding-G8", "full-G6"])
+def test_band_kernels_compile_at_the_laguna_cells_two_layer_kinds(one_chip, heads, window,
+                                                                  scope_name, vmapped, monkeypatch):
+    """The band kernels at the Laguna cell's shapes (ISSUE 42): one row of
+    8,192 positions on 8 key/value heads of 128, a sliding layer's 64 query
+    heads under a window of 512 (groups of 8: `gq_attn_bwd`'s resident `dq`
+    would be 34 MB) and a full layer's 48 under the diagonal alone (groups of
+    6: 25 MB; `pallas_attention.gq_plan` hands both to the band pair); bare
+    and under the ``vmap`` over the one client slot of a chunk, with a
+    per-client scale.  Both custom calls carry the layer kind's scope, and no
+    score block goes through HBM."""
+    from heterofl_tpu.ops.layers import causal_gq_attention, sliding_gq_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lead = (1,) if vmapped else ()
+    attend = causal_gq_attention if window is None else \
+        functools.partial(sliding_gq_attention, window=window)
+
+    def grads(q, k, v, scale):
+        return jax.grad(lambda *o: jnp.sum(attend(*o, scale) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+    avals = [jax.ShapeDtypeStruct(lead + (1, h, 8192, 128), jnp.float32, sharding=one_chip)
+             for h in (heads, 8, 8)] \
+        + [jax.ShapeDtypeStruct(lead, jnp.float32, sharding=one_chip)]
+    text = _compile(jax.vmap(grads) if vmapped else grads, *avals,
+                    kernels=("band_attn_fwd", "band_attn_bwd"))
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    op_names = sorted(re.search(r'op_name="([^"]*)"', line).group(1) for line in calls)
+    assert len(op_names) == 2
+    assert re.search(rf"jvp\({scope_name}\)\)?/band_attn_fwd/pallas_call$", op_names[0])
+    assert re.search(rf"transpose\((vmap\()?jvp\({scope_name}\)\)+/band_attn_bwd/pallas_call$",
+                     op_names[1])
+    assert not re.search(rf"f32\[(?:1,)?1,{heads},\d+,8192\]", text.replace(
+        f"f32[1,{heads},128,8192]", "").replace(f"f32[1,1,{heads},128,8192]", ""))
+
+
 #: bytes of an element, for the shapes a relayout of the block can have
 _ITEMSIZE = {"f32": 4, "bf16": 2}
 
