@@ -47,9 +47,28 @@ input statistics the Scaler is there to keep).  A leaf used ``R`` times a step
 is sliced, carried, decayed and counted like any leaf; its gradient is the sum
 over its uses.
 
-The passes are an outer ``lax.scan`` round the inner ``lax.scan`` over the
-stacked layers (``lfm2``'s run of alike layers), every layer APPLICATION
-under ``jax.checkpoint``; the stacked weights are invariants of the outer scan.
+The passes are a ``lax.scan``; the shared weights are its invariants, so a
+leaf's gradient is the sum over its ``R`` uses.  INSIDE a pass a stack of at
+most :data:`UNROLL_LAYERS` layers is a Python loop over the layers' own leaves
+(ISSUE 43), a longer one an inner ``lax.scan`` over their stacks (``lfm2``'s
+run of alike layers); every layer APPLICATION is under ``jax.checkpoint``
+either way.  What the loop spares is not mathematics but the inner scan's own
+traffic, a fifth of a round at four layers of the published widths (PERF.md, PR
+41): no leaf is stacked ``[N, ...]``, so no application slices its weights
+out, no stacked gradient is zero-filled and added to the passes' carry a whole
+stack at a time (the sum over the passes rides each weight-gradient product
+instead), and a value kept for the backward is copied TWICE -- into the passes'
+``[R, ...]`` stack and back -- where two nested scans copy it four times (into
+the inner ``[N, ...]``, that into ``[R, N, ...]``, and twice back).  What it
+costs is ``N`` layer bodies of code where the scan has one.  The rule is read
+off ``num_hidden_layers`` alone.  The round program compiled for a v5e at the
+published widths (code / its compile-cache entry, MB; PERF.md, PR 43): 4 layers
+86.6 / 17.5 unrolled against 31.8 / 6.3 scanned, 5 layers 104.7 / 21.3, 6
+layers 39.6 / 9.9, 8 layers 51.7 / 12.8 against 30.8 / 7.5 -- every one far
+inside the 192 MiB the chip machine's cache holds, and 8 layers are as deep a
+stage of these widths as one chip's memory trains (8 x 51.4 M parameters at 24
+B); beyond, nothing is measured, so the published 48 layers scan as before.
+
 For the backward an application keeps its input (``R x N`` states a step) and,
 by name (:func:`kept`), what costs more to compute again than to write once
 and read back: where the fused kernels run, the three residuals of
@@ -58,10 +77,8 @@ bfloat16 operands, so that the backward runs no second forward kernel and no
 second ``q`` / ``k`` / ``v`` product, turn or cast -- and the SwiGLU's
 down-projected output, the input of ``norm4``, so that it runs no second
 down-projection.  NOT the gate and up pre-activations nor the attention's
-projected output: through the two scans a kept value is copied four times
-(into the inner scan's stack, that stack into the outer scan's, and back), and
-on the chip those cost more in traffic than their products do again (PERF.md,
-PR 41).
+projected output: chosen on the chip through the two scans, where those cost
+more in traffic than their products do again (PERF.md, PR 41).
 """
 
 from __future__ import annotations
@@ -84,6 +101,11 @@ from .spec import Group, ParamSpec
 #: the name a layer application's SwiGLU output carries (``checkpoint_name``;
 #: :func:`kept`): the down-projected ``y``, the input of the sandwich's ``norm4``
 MLP_OUT = "mlp_out"
+
+#: the longest layer stack a pass applies as a Python loop; a longer one is a
+#: ``lax.scan`` over its stacked leaves (why, and the compiled sizes behind the
+#: number: the module docstring)
+UNROLL_LAYERS = 8
 
 
 def kept():
@@ -204,22 +226,29 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
 
         @partial(jax.checkpoint, policy=policy)
         def layer(x, lp):
-            """``(x, leaves) -> (x, None)``, the inner scan's body; for the
-            backward it keeps its input and what :func:`kept` names."""
+            """``(x, leaves) -> (x, None)``, a scan's body whether or not one
+            runs it; for the backward it keeps its input and what
+            :func:`kept` names."""
             x = x + rms(lp["norm2.g"], attention(lp, rms(lp["norm1.g"], x)))
             h = rms(lp["norm3.g"], x)
             y = swiglu(h, lp["mlp.g.w"], lp["mlp.u.w"], lp["mlp.d.w"], sc, compute_dtype)
             return x + rms(lp["norm4.g"], checkpoint_name(y, MLP_OUT)), None
 
         run = [layer_leaves(params, i) for i in range(L)]
-        stacked = {k: jnp.stack([lp[k] for lp in run]) for k in run[0]}
+        unrolled = L <= UNROLL_LAYERS
+        if not unrolled:
+            stacked = {k: jnp.stack([lp[k] for lp in run]) for k in run[0]}
 
         def one_pass(x, _):
             """The whole stack once on the shared weights, then the final
             norm: the normed state is what the head and the gate read and
             what the next pass starts from."""
             with scope("loop/pass"):
-                x, _ = lax.scan(layer, x, stacked)
+                if unrolled:
+                    for lp in run:
+                        x, _ = layer(x, lp)
+                else:
+                    x, _ = lax.scan(layer, x, stacked)
             with scope("loop/exit"):
                 h = rms(params["norm.g"], x)
             return h, h
@@ -260,7 +289,8 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 "loop_exit_share": jnp.append(jnp.sum(p * wt, axis=(1, 2)), count),
                 "loop_pass_nll": jnp.append(jnp.sum(nll * wt, axis=(1, 2)), count),
                 "loop_passes": jnp.stack([jnp.sum(ranks * p * wt), count]),
-                "loop_kept": jnp.array([named, applied], jnp.float32)}
+                "loop_kept": jnp.array([named, applied], jnp.float32),
+                "loop_unrolled": jnp.array([applied if unrolled else 0, applied], jnp.float32)}
         read = jnp.take_along_axis(hs, last[None, :, :, None], axis=0)[0]
         # the logits [N, S, V] a caller may read (training does not: then the
         # compiler drops them)
@@ -277,7 +307,8 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             # probes when telemetry is on and obs.split_probes finishes them
             # (`loop_kept`: a (numerator, denominator) pair, the layer
             # applications whose attention kernel's results the layer kept
-            # for its backward over the layer applications)
+            # for its backward over the layer applications; `loop_unrolled`:
+            # those run from an unrolled stack over the layer applications)
             "counters": {"loop_exit_share": (R + 1,), "loop_pass_nll": (R + 1,),
-                         "loop_passes": (2,), "loop_kept": (2,)}}
+                         "loop_passes": (2,), "loop_kept": (2,), "loop_unrolled": (2,)}}
     return ModelDef("ouro", init, apply, specs, groups, [], meta)

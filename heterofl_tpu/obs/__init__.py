@@ -394,10 +394,11 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # loop_passes = the expected pass (sum of t * p_t), a scalar;
                 # loop_kept (ISSUE 41), of layer applications and a scalar
                 # too = the share whose attention kernel's output the layer
-                # kept for its backward
+                # kept for its backward; loop_unrolled (ISSUE 43), alike =
+                # the share applied from an unrolled stack, not a scanned one
                 *nums, den = (float(c) for c in x.sum(axis=0))
                 vals = [n / den if den else 0.0 for n in nums]
-                rec[base] = vals[0] if base in ("loop_passes", "loop_kept") else vals
+                rec[base] = vals[0] if base in ("loop_passes", "loop_kept", "loop_unrolled") else vals
             elif base == "nonfinite":
                 rec["nonfinite"] = int(x[0, 0])
             elif base.endswith("_sq"):

@@ -451,14 +451,17 @@ def test_ouro_counters_ride_the_metrics(tmp_path):
     log 3 --, and the expected pass, between 1 and 3; `obs.report` renders
     them.  `obs_loop_kept` (ISSUE 41) rides beside them, a pair a device: 0 of
     the 8 clients' 3 x 2 layer applications here, where the block loop names
-    nothing."""
+    nothing; and `obs_loop_unrolled` (ISSUE 43), alike: all 48 of 48, two
+    layers being a short stack."""
     from heterofl_tpu.obs import report, split_probes
 
     cfg, data = _round_case()
     _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
     assert ms["obs_loop_exit_share"].shape == ms["obs_loop_pass_nll"].shape == (2 * 4,)
     assert ms["obs_loop_passes"].shape == ms["obs_loop_kept"].shape == (2 * 2,)
+    assert ms["obs_loop_unrolled"].shape == (2 * 2,)
     assert ms["obs_loop_kept"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 3 * 2]
+    assert ms["obs_loop_unrolled"].reshape(2, 2).sum(axis=0).tolist() == [8 * 3 * 2, 8 * 3 * 2]
     # 8 clients x 1 step x 2 rows x 31 target positions, over the two devices
     assert ms["obs_loop_exit_share"].reshape(2, 4)[:, -1].sum() == 8 * 2 * 31
     clean, rounds = split_probes(dict(ms), 2)
@@ -469,7 +472,7 @@ def test_ouro_counters_ride_the_metrics(tmp_path):
     assert all(3.0 < v < 6.0 for v in rec["loop_pass_nll"])  # near log 96 = 4.56
     assert rec["loop_passes"] == pytest.approx(
         sum((t + 1) * p for t, p in enumerate(rec["loop_exit_share"])), rel=1e-5)
-    assert rec["loop_kept"] == 0.0
+    assert rec["loop_kept"] == 0.0 and rec["loop_unrolled"] == 1.0
     assert not [k for k in clean if k.startswith("obs_")]
     events = tmp_path / "events.jsonl"
     events.write_text(json.dumps({"v": 1, "t": 0.0, "name": "probes", "cat": "obs", "ph": "i",
@@ -493,6 +496,21 @@ def _bare_checkpoint(monkeypatch):
     from heterofl_tpu.models import ouro
 
     monkeypatch.setattr(ouro, "kept", lambda: None)
+
+
+#: both sides of the rule by which a pass applies its stack (ISSUE 43): the
+#: three layers of `TILED` as a Python loop, and as the inner `lax.scan`
+STACKS = ["unrolled", "scanned"]
+
+
+def _stack(monkeypatch, stack):
+    """The rule's constant as it stands (``TILED``'s three layers lie under
+    it), or moved below them: the one thing steered, read when `apply` runs."""
+    from heterofl_tpu.models import ouro
+
+    assert TILED["num_hidden_layers"] <= ouro.UNROLL_LAYERS
+    if stack == "scanned":
+        monkeypatch.setattr(ouro, "UNROLL_LAYERS", TILED["num_hidden_layers"] - 1)
 
 
 def _on_the_kernels(monkeypatch):
@@ -573,25 +591,31 @@ def _kernels_by_scan_body(jaxpr):
     return sorted(walk(jaxpr.jaxpr, [])), bodies
 
 
-@pytest.mark.parametrize("family, policy, outside, bodies", [
-    ("ouro", "kept", [], [["gq_attn_fwd"], ["gq_attn_bwd"]]),
-    ("ouro", "bare", [], [["gq_attn_fwd"], ["gq_attn_bwd", "gq_attn_fwd"]]),
-    ("lfm2", "its own", ["gq_attn_bwd", "gq_attn_fwd", "gq_attn_fwd"], [])])
+@pytest.mark.parametrize("family, policy, stack, outside, bodies", [
+    ("ouro", "kept", "scanned", [], [["gq_attn_fwd"], ["gq_attn_bwd"]]),
+    ("ouro", "bare", "scanned", [], [["gq_attn_fwd"], ["gq_attn_bwd", "gq_attn_fwd"]]),
+    ("ouro", "kept", "unrolled", [], [["gq_attn_fwd"] * 3, ["gq_attn_bwd"] * 3]),
+    ("ouro", "bare", "unrolled", [], [["gq_attn_fwd"] * 3, ["gq_attn_bwd"] * 3 + ["gq_attn_fwd"] * 3]),
+    ("lfm2", "its own", "its own", ["gq_attn_bwd", "gq_attn_fwd", "gq_attn_fwd"], [])])
 def test_gradient_on_the_gq_kernels_runs_one_forward_kernel_where_the_layer_keeps_its_results(
-        family, policy, outside, bodies, monkeypatch):
+        family, policy, stack, outside, bodies, monkeypatch):
     """A model at shapes the fused kernels tile, jax reporting a TPU.  Ouro:
     the gradient's program calls ``gq_attn_fwd`` in the forward scan's body
     and ``gq_attn_bwd`` ALONE in the backward's, whose residuals ``o`` and the
     log-sum-exp the layer kept by name; a layer that keeps its input alone
     (before ISSUE 41) calls the forward kernel again beside the backward.
-    LFM2's one attention layer, a lone layer under ITS bare checkpoint, still
-    calls the forward kernel twice: there the names are the identity."""
+    Scanned, the bodies are the inner scan's, one layer each; unrolled (ISSUE
+    43) they are the PASSES' scan's, which then holds the three layers' calls
+    and no scan.  LFM2's one attention layer, a lone layer under ITS bare
+    checkpoint, still calls the forward kernel twice: there the names are the
+    identity."""
     if family == "lfm2":
         from benchmark.tests import tiny_lfm2 as tiny
 
         cfg = tiny.program_cfg(head_dim=64)
     else:
         cfg = _ouro_case(**TILED)[0]
+        _stack(monkeypatch, stack)
     if policy == "bare":
         _bare_checkpoint(monkeypatch)
     model = make_model(cfg)
@@ -603,38 +627,51 @@ def test_gradient_on_the_gq_kernels_runs_one_forward_kernel_where_the_layer_keep
     assert _kernels_by_scan_body(jaxpr) == (outside, bodies)
 
 
+@pytest.mark.parametrize("stack", STACKS)
 @pytest.mark.parametrize("path", ["block loop", "kernels"])
 @pytest.mark.parametrize("policy", ["kept", "bare"])
-def test_ouro_named_values_are_the_layers_saved_residuals(policy, path, monkeypatch, capsys):
-    """What the two scans hand the backward of a layer application, ``[R, L,
-    ...]``: the layer's input ``[N, S, D]`` and, under the policy, the
-    SwiGLU's down-projected output, as large, and where the kernels run their
-    ``o`` ``[N, H, d, S]``, log-sum-exp and three bfloat16 operands; nothing
-    else, and under a bare checkpoint the input alone."""
+def test_ouro_named_values_are_the_layers_saved_residuals(policy, path, stack, monkeypatch, capsys):
+    """What the passes' scan hands the backward of a layer application: the
+    layer's input ``[N, S, D]`` and, under the policy, the SwiGLU's
+    down-projected output, as large, and where the kernels run their ``o``
+    ``[N, H, d, S]``, log-sum-exp and three bfloat16 operands; nothing else,
+    and under a bare checkpoint the input alone.  Scanned, each is ONE
+    residual ``[R, L, ...]``, the inner scan's stack stacked again; unrolled
+    (ISSUE 43), ``L`` residuals ``[R, ...]`` and none of ``[R, L, ...]``: one
+    level of stacking.  Beside them the pass's own six (the final norm's),
+    alike on both sides."""
     from jax.ad_checkpoint import print_saved_residuals
 
     if policy == "bare":
         _bare_checkpoint(monkeypatch)
     if path == "kernels":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _stack(monkeypatch, stack)
     _, model, params, tokens, lm, _ = _ouro_case(**TILED)
     print_saved_residuals(
         lambda p: model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"], params)
-    of_a_layer = [line.split()[0] for line in capsys.readouterr().out.splitlines()
-                  if "output of scan" in line and re.match(r"\w+\[2,3,", line)]
-    layer_input = mlp_out = "f32[2,3,2,128,64]"
-    of_the_kernels = ["f32[2,3,2,2,128,128]", "f32[2,3,2,2,1,1,128]"] + ["bf16[2,3,2,2,128,128]"] * 3
+    of_the_scan = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+                   if "output of scan" in line]
+    of_a_pass = ["f32[2,2,128,64]"] * 3 + ["f32[2,2,128,1]"] * 3
+    layer_input = mlp_out = "f32[%s2,128,64]"
+    of_the_kernels = ["f32[%s2,2,128,128]", "f32[%s2,2,1,1,128]"] + ["bf16[%s2,2,128,128]"] * 3
     named = [mlp_out] + (of_the_kernels if path == "kernels" else [])
-    assert sorted(of_a_layer) == sorted([layer_input] + (named if policy == "kept" else []))
+    of_a_layer = [layer_input] + (named if policy == "kept" else [])
+    lead, times = ("2,3,", 1) if stack == "scanned" else ("2,", 3)
+    assert sorted(of_the_scan) == sorted(of_a_pass + [r % lead for r in of_a_layer] * times)
+    assert (stack == "scanned") == any(re.match(r"\w+\[2,3,", r) for r in of_the_scan)
 
 
+@pytest.mark.parametrize("stack", STACKS)
 @pytest.mark.parametrize("path", ["block loop", "kernels"])
-def test_ouro_keeping_the_named_values_changes_no_number(path, monkeypatch):
+def test_ouro_keeping_the_named_values_changes_no_number(path, stack, monkeypatch):
     """Loss and every leaf's gradient under the policy against the bare
-    checkpoint's: a kept value is the value the second forward would have
+    checkpoint's, the stack unrolled or scanned: a kept value is the value
+    the second forward would have
     computed from the same inputs.  On the CPU's block loop (where the
     SwiGLU's output alone carries a name) equal to the bit; on the interpreted
     kernels to float32 round-off (another compiled program round them)."""
+    _stack(monkeypatch, stack)
     _, model, params, tokens, lm, _ = _ouro_case(**TILED)
     if path == "kernels":
         _on_the_kernels(monkeypatch)
@@ -676,6 +713,113 @@ def test_ouro_loop_kept_counts_the_applications_on_the_named_kernel(arch, report
     assert out["counters"]["loop_kept"].tolist() == [6.0 * share, 6.0]
     _, rounds = split_probes({"obs_loop_kept": np.asarray(out["counters"]["loop_kept"])}, 1)
     assert rounds[0]["loop_kept"] == share
+
+
+@pytest.mark.parametrize("path", ["block loop", "kernels"])
+@pytest.mark.parametrize("policy", ["kept", "bare"])
+def test_the_unrolled_stack_is_the_scanned_stack(policy, path, monkeypatch):
+    """ISSUE 43 moves where a pass's layers are applied from, not what they
+    compute: loss, the counters that say nothing of the stack's form and every
+    leaf's gradient -- still the sum over its passes -- of the unrolled stack
+    against the scanned one's.  On the CPU's block loop to 1e-6 of a leaf's
+    largest entry (the same float32 operations; the sum over the passes is
+    formed leaf by leaf and not stack by stack, and the compiler fuses round
+    it as it likes), on the interpreted kernels the same."""
+    if policy == "bare":
+        _bare_checkpoint(monkeypatch)
+    if path == "kernels":
+        _on_the_kernels(monkeypatch)
+    _, model, params, tokens, lm, _ = _ouro_case(**TILED)
+    run = jax.jit(lambda p: _loss_counters_and_grads(model, p, tokens, lm))
+    got, got_counters, got_grads = run(params)
+    _stack(monkeypatch, "scanned")
+    run = jax.jit(lambda p: _loss_counters_and_grads(model, p, tokens, lm))
+    want, want_counters, want_grads = run(params)
+    assert got_counters.pop("loop_unrolled").tolist() == [6.0, 6.0]
+    assert want_counters.pop("loop_unrolled").tolist() == [0.0, 6.0]
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for name, w in want_counters.items():
+        np.testing.assert_allclose(got_counters[name], w, rtol=1e-6, err_msg=name)
+    for name, w in want_grads.items():
+        np.testing.assert_allclose(got_grads[name], w, rtol=0, err_msg=name,
+                                   atol=1e-6 * float(jnp.abs(w).max()) + 1e-12)
+
+
+def _scans(jaxpr, inside=()):
+    """(the lengths of the scans it lies in, its own length) of every `scan`
+    of a program, outermost first."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import _sub_jaxprs
+
+    found = []
+    for e in jaxpr.eqns:
+        mine = inside
+        if e.primitive.name == "scan":
+            mine = inside + (e.params["length"],)
+            found.append(mine)
+        for sub in _sub_jaxprs(e.params):
+            found += _scans(sub, mine)
+    return found
+
+
+def _stacked_leaves(jaxpr, model, layers):
+    """The values of a program that have the shape of a layer's leaf with an
+    axis of ``layers`` in front: a stack of that leaf over the layers."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns
+
+    stacks = {(layers,) + tuple(shape) for name, shape in model.meta["shapes"].items()
+              if name.startswith("l0.")}
+    return sorted({tuple(v.aval.shape) for e in iter_eqns(jaxpr) for v in e.outvars
+                   if tuple(getattr(v.aval, "shape", ())) in stacks})
+
+
+@pytest.mark.parametrize("stack", STACKS)
+def test_a_short_stack_is_one_level_of_stacking(stack, monkeypatch):
+    """The forward program of 2 passes over 3 layers, and the gradient's
+    (beside the loop, the head's scan over its one block of rows).  Unrolled: ONE scan, the passes', with no
+    scan inside it, and no value anywhere shaped as a stack of a layer's leaf
+    over the layers -- no weight is stacked, so none is sliced and no stacked
+    gradient is filled or accumulated.  Scanned: the layers' scan inside the
+    passes', over the stacks of the seven matrices and the four gains."""
+    _stack(monkeypatch, stack)
+    _, model, params, tokens, lm, _ = _ouro_case(**TILED)
+    def loss(p):
+        return model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"]
+
+    for program in (loss, jax.grad(loss)):
+        jaxpr = jax.make_jaxpr(program)(params)
+        of_the_loop = {s for s in _scans(jaxpr.jaxpr) if 2 in s or 3 in s}
+        stacks = _stacked_leaves(jaxpr, model, 3)
+        if stack == "unrolled":
+            assert of_the_loop == {(2,)} and not stacks
+        else:
+            # (the gradient's program also runs what of a layer depends on no
+            # state once for the three layers, ahead of the passes)
+            assert {(2,), (2, 3)} <= of_the_loop <= {(2,), (2, 3), (3,)}
+            # the gains', `q` / `k` / `v`'s, `o`'s and (a SwiGLU as wide as the model) its three's
+            assert stacks == [(3, 64), (3, 64, 64), (3, 64, 256), (3, 256, 64)]
+
+
+@pytest.mark.parametrize("layers, under", [(3, True), (48, False)], ids=["a-stage", "published-depth"])
+def test_the_rule_is_read_off_the_depth(layers, under):
+    """`loop_unrolled` = (layer applications run from an unrolled stack, layer
+    applications): all of them for a stack of at most `UNROLL_LAYERS` layers,
+    as the benchmark cell's 4, none for a longer one -- the published 48
+    layers, at tiny widths here, still build the two nested scans.  No key, no
+    flag, no variable says which: the depth alone."""
+    from heterofl_tpu.models import ouro
+    from heterofl_tpu.obs import split_probes
+
+    assert 4 <= ouro.UNROLL_LAYERS < 48
+    assert (layers <= ouro.UNROLL_LAYERS) == under
+    _, model, params, tokens, lm, _ = _ouro_case(num_hidden_layers=layers)
+    assert model.meta["counters"]["loop_unrolled"] == (2,)
+    forward = jax.jit(lambda p: model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0])
+    nested = [s for s in _scans(jax.make_jaxpr(forward)(params).jaxpr) if len(s) == 2]
+    assert nested == ([] if under else [(3, 48)])
+    counters = forward(params)["counters"]
+    assert counters["loop_unrolled"].tolist() == [3.0 * layers * under, 3.0 * layers]
+    _, rounds = split_probes({"obs_loop_unrolled": np.asarray(counters["loop_unrolled"])}, 1)
+    assert rounds[0]["loop_unrolled"] == float(under)
 
 
 def test_ouro_trains_and_evaluates_through_the_entry_point(tmp_path):
