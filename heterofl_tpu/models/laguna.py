@@ -53,9 +53,24 @@ rate changes the temperature of a decision and not the size of a feature.
 
 Consecutive layers alike in kind, head count and feed-forward run as one
 ``lax.scan`` over their stacked leaves, a lone layer as itself; each layer
-under a bare ``jax.checkpoint`` (it keeps its input alone: the band kernels'
-``BAND_OUT`` / ``BAND_LSE`` names are there for a policy to keep, and none
-does yet).
+under a ``jax.checkpoint`` that keeps, beside the layer's input, what the band
+kernels name (:func:`kept`): ``BAND_OUT`` and ``BAND_LSE``, ``band_attn_fwd``'s
+``o`` ``[N, H, d, S]`` float32 and log-sum-exp, and ``BAND_OPS``, the kernels'
+own operands (``q`` scaled, ``k``, ``v`` in bfloat16 with the positions minor:
+half the bytes of the float32 heads they are cast from).  They are the three
+residuals of ``band_attn_bwd``, so a layer's backward runs no second
+``band_attn_fwd`` and, nothing else in it reading them, no second ``q`` / ``k``
+/ ``v`` product, turn, concatenation or cast; the rest of the layer is
+computed again from its input as before.  BOTH KINDS KEEP ALL THREE, lone or
+scanned: in the benchmark's cell (two lone full layers of 48 heads, a scan of
+three sliding ones of 64; 337 and 438 MB a layer and client) a round read
+3.613 s with nothing kept, 3.489 with the full layers' ``o`` and log-sum-exp,
+3.417 with their operands too, 3.436 with the sliding layers' operands as
+well and **3.235** with everything (PR 44's search, one seed; ``PERF.md`` §6):
+a scanned layer that keeps its operands and not its ``o`` runs its forward
+kernel again from them and gains nothing.  The attention's projected output
+kept too read worse (3.251); the dense layer's SwiGLU output is the layer's
+last value, which no backward reads.
 """
 
 from __future__ import annotations
@@ -70,10 +85,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import scope
-from ..ops.layers import (causal_gq_attention, embed, heads_linear, linear as _linear,
-                          linear_heads, masked_logits, masked_rms_norm, moe_experts, moe_route,
-                          next_token_loss, rope_interleaved, rope_swap, scaler,
-                          sliding_attention_tiles, sliding_gq_attention, swiglu)
+from ..ops.layers import (band_attention_planned, causal_gq_attention, embed, heads_linear,
+                          linear as _linear, linear_heads, masked_logits, masked_rms_norm,
+                          moe_experts, moe_route, next_token_loss, rope_interleaved, rope_swap,
+                          scaler, sliding_attention_tiles, sliding_gq_attention, swiglu)
 from .base import ModelDef, expert_tile, held_experts, layer_leaves, normal_init, uniform_fan_in
 from .spec import Group, ParamSpec
 
@@ -81,7 +96,20 @@ KINDS = ("full_attention", "sliding_attention")
 
 #: the layer's counters under the names they ride the metrics by
 COUNTERS = {"tokens": "moe_tokens", "assign": "moe_assign", "fused": "swa_fused",
-            "pairs": "swa_pairs", "tiles": "swa_tiles"}
+            "pairs": "swa_pairs", "tiles": "swa_tiles", "kept": "band_kept"}
+
+
+def kept():
+    """What a layer's ``jax.checkpoint`` keeps for the backward beside its
+    input: the three residuals of ``band_attn_bwd``, named where the kernel
+    pair is called (``band_attn_fwd``'s output and log-sum-exp and the
+    kernels' bfloat16 operands; the block loop names nothing).  Both kinds of
+    layer keep all three, a lone layer and a scanned run alike (the rows that
+    decided it: the module docstring).  None: a bare checkpoint, the input
+    alone."""
+    from ..ops.pallas_attention import BAND_LSE, BAND_OPS, BAND_OUT  # Pallas, imported where a Laguna model is built
+
+    return jax.checkpoint_policies.save_only_these_names(BAND_OUT, BAND_LSE, BAND_OPS)
 
 
 def rope_frequencies(rope: Dict, rotary_dim: int):
@@ -287,7 +315,7 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 jnp.float32) / Hkv
 
         tile = expert_tile(T, K, E)
-        zero = {}
+        zero = {"kept": jnp.zeros((2,), jnp.float32)}
         if has_moe:
             zero.update(tokens=jnp.zeros((len(held),), jnp.float32),
                         assign=jnp.zeros((3,), jnp.float32))
@@ -308,12 +336,19 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             return {"fused": pair(int(fused), 1), "pairs": pair(band, S * (S + 1) // 2),
                     "tiles": pair(visited, causal)}
 
+        policy = kept()
+
         def layer_of(i):
             """Layer ``i``'s kind as ``(x, leaves) -> (x, counters)``: a
-            ``lax.scan`` body, and a plain call for a lone layer.  It keeps
-            only its input for the backward."""
+            ``lax.scan`` body, and a plain call for a lone layer.  For the
+            backward it keeps its input and what :func:`kept` names."""
             kind, H, sparse = kinds[i], layer_heads[i], mlps[i] == "sparse"
             _, freqs, factor = rotary[kind]
+            # (the layer keeps its kernels' results, its attention is on the
+            # kernels that name them): `band_kept`
+            on_band = band_attention_planned(
+                S, hd, H // Hkv, window if kind == "sliding_attention" else None)
+            band = jnp.array([on_band and policy is not None, on_band], jnp.float32)
             attend = causal_gq_attention if kind == "full_attention" else \
                 partial(sliding_gq_attention, window=window)
             attention = partial(
@@ -321,11 +356,11 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 scale=1.0 / jnp.sqrt(head_dims(kind)), sc=sc, attend=attend,
                 compute_dtype=compute_dtype)
 
-            @jax.checkpoint
+            @partial(jax.checkpoint, policy=policy)
             def layer(x, lp):
                 x = x + attention(lp, rms(lp["norm1.g"], x))
                 h = rms(lp["norm2.g"], x)
-                counters = dict(zero)
+                counters = dict(zero, kept=band)
                 if kind == "sliding_attention":
                     counters.update(swa_counters(H))
                 if not sparse:
@@ -366,8 +401,7 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         # the logits [N, S, V] a caller may read (training does not: then the
         # compiler drops them); the loss takes the head in blocks of positions
         res = {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight)}
-        if counters:
-            res["counters"] = {COUNTERS[k]: v for k, v in counters.items()}
+        res["counters"] = {COUNTERS[k]: v for k, v in counters.items()}
         return res, {}
 
     meta = {"bn_sizes": {}, "kind": "laguna", "num_tokens": num_tokens,
@@ -383,11 +417,9 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
     # what apply's "counters" holds (summed over the layers); the engines carry
     # them as obs_ probes when telemetry is on.  The three swa ones are
     # (numerator, denominator) pairs that obs.split_probes divides
-    meta["counters"] = {}
+    meta["counters"] = {"band_kept": (2,)}
     if has_moe:
         meta["counters"].update(moe_tokens=(len(held),), moe_assign=(3,))
     if has_swa:
         meta["counters"].update(swa_fused=(2,), swa_pairs=(2,), swa_tiles=(2,))
-    if not meta["counters"]:
-        del meta["counters"]
     return ModelDef("laguna", init, apply, specs, groups, [], meta)

@@ -371,7 +371,7 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # an expert layer's counters (ISSUE 28): per-device sums
                 # over that device's valid slots, steps and expert layers
                 rec[base] = [float(c) for c in x.sum(axis=0)]
-            elif base.startswith(("sparse_", "swa_")):
+            elif base.startswith(("sparse_", "swa_", "band_")):
                 # a sparse-attention indexer's counters (ISSUES 35, 36, 39)
                 # and the sliding layers' (ISSUE 42: swa_fused = query tiles
                 # the band kernels took over query tiles, swa_pairs = band
@@ -383,7 +383,9 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # = selected over causal (query, key) pairs, sparse_fused =
                 # query tiles the fused kernels took over query tiles,
                 # sparse_saved = selecting query blocks whose choice the
-                # layer kept for its backward over selecting query blocks
+                # layer kept for its backward over selecting query blocks;
+                # band_kept (ISSUE 44) = Laguna layers whose checkpoint kept
+                # their band kernels' results over layers on the band kernels
                 num, den = (float(c) for c in x.sum(axis=0))
                 rec[base] = num / den if den else 0.0
             elif base.startswith("loop_"):

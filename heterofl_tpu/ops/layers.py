@@ -913,3 +913,16 @@ def sliding_attention_tiles(S: int, d: int, group: int, window: int, block: int 
     tq, tk = (block, block) if plan is None else plan[1:]
     visited, causal = pallas_attention.band_extent(S, tq, tk, window)
     return plan is not None, visited, causal
+
+
+def band_attention_planned(S: int, d: int, group: int, window=None) -> bool:
+    """Whether :func:`causal_gq_attention` (``window`` None) or
+    :func:`sliding_gq_attention` takes the ``band_attn_fwd`` / ``band_attn_bwd``
+    pair at these shapes, whose results and operands carry names: for a caller
+    that counts (``models/laguna.py``), as :func:`gq_attention_tile` is."""
+    if jax.default_backend() != "tpu":
+        return False
+    from . import pallas_attention
+
+    plan = pallas_attention.gq_plan(S, d, group, window)
+    return plan is not None and plan[0] == "band"

@@ -781,29 +781,32 @@ def fused_selected_attention(q, k, v, scale, select, block: int, *, block_q: int
 
 
 # ---------------------------------------------------------------------------
-# WHAT ``gq_attn_bwd`` READS CARRIES NAMES TOO (here, at the file's end, and not
-# beside ``_gq_flash_fwd``: a Mosaic kernel's compile-cache key holds its call
-# stack, so no line above moves).  ``o`` and the log-sum-exp of ``gq_attn_fwd``
-# are the primal output and the backward kernel's residuals at once, and the
-# third residual is the kernels' own operands: ``q`` (scaled), ``k`` and ``v``
-# in bfloat16 with the positions minor, half the bytes of the float32 heads
-# they were cast from.  A ``jax.checkpoint`` whose policy saves ``GQ_OUT``,
-# ``GQ_LSE`` and ``GQ_OPS`` runs in its backward no second forward kernel and
-# nothing of what leads up to it (``models/ouro.py``'s layer: no second ``q`` /
-# ``k`` / ``v`` product, turn or cast).  Under no such policy
+# WHAT ``gq_attn_bwd`` READS CARRIES NAMES TOO (here, below every kernel of the
+# other families, and not beside ``_gq_flash_fwd``: a Mosaic kernel's
+# compile-cache key holds its call stack, so no line above moves; the band
+# pair's forward names its three through the same helper, so an edit here
+# moves the Laguna cell's kernels alone).  ``o`` and the log-sum-exp of
+# ``gq_attn_fwd`` are the primal output and the backward kernel's residuals at
+# once, and the third residual is the kernels' own operands: ``q`` (scaled),
+# ``k`` and ``v`` in bfloat16 with the positions minor, half the bytes of the
+# float32 heads they were cast from.  A ``jax.checkpoint`` whose policy saves
+# ``GQ_OUT``, ``GQ_LSE`` and ``GQ_OPS`` runs in its backward no second forward
+# kernel and nothing of what leads up to it (``models/ouro.py``'s layer: no
+# second ``q`` / ``k`` / ``v`` product, turn or cast).  Under no such policy
 # (``models/lfm2.py``'s layers) a name is the identity.
 # ---------------------------------------------------------------------------
 
 GQ_OUT, GQ_LSE, GQ_OPS = "gq_out", "gq_lse", "gq_ops"
 
 
-def _gq_named(ops, o, lse):
+def _gq_named(ops, o, lse, names=(GQ_OUT, GQ_LSE, GQ_OPS)):
     """What ``_gq_flash_fwd`` returns, ``(o, residuals)``, each of the three
-    residuals under its name (``o`` in the primal output too)."""
+    residuals under its name (``o`` in the primal output too); ``names`` the
+    band pair's for ``_band_flash_fwd``."""
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = checkpoint_name(o, GQ_OUT), checkpoint_name(lse, GQ_LSE)
-    return o, (checkpoint_name(ops, GQ_OPS), o, lse)
+    o, lse = checkpoint_name(o, names[0]), checkpoint_name(lse, names[1])
+    return o, (checkpoint_name(ops, names[2]), o, lse)
 
 
 # ---------------------------------------------------------------------------
@@ -826,9 +829,16 @@ def _gq_named(ops, o, lse):
 # tile may hold no key it sees (the band's lower edge cuts a tile's corner
 # off): harmless, for the reason the selected kernels give, since every query
 # sees itself.
+#
+# WHAT ``band_attn_bwd`` READS CARRIES NAMES, as the grouped-query pair's does
+# (:func:`_gq_named`): ``o`` and the log-sum-exp of ``band_attn_fwd``
+# (``BAND_OUT``, ``BAND_LSE``) and the kernels' own bfloat16 operands
+# (``BAND_OPS``).  ``models/laguna.py``'s layers keep all three, full and
+# sliding, lone and scanned (its ``kept``): their backward runs no second
+# forward kernel and nothing of what leads up to it.
 # ---------------------------------------------------------------------------
 
-BAND_OUT, BAND_LSE = "band_out", "band_lse"
+BAND_OUT, BAND_LSE, BAND_OPS = "band_out", "band_lse", "band_ops"
 
 
 def _band_first(i, tq, tk, window):
@@ -999,12 +1009,9 @@ def _band_flash(q, k, v, tq, tk, window, interpret):
 
 
 def _band_flash_fwd(q, k, v, tq, tk, window, interpret):
-    from jax.ad_checkpoint import checkpoint_name
-
     ops = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
     o, lse = _call_band_fwd(*ops, tq, tk, window, interpret)
-    o, lse = checkpoint_name(o, BAND_OUT), checkpoint_name(lse, BAND_LSE)
-    return o, (ops, o, lse)
+    return _gq_named(ops, o, lse, (BAND_OUT, BAND_LSE, BAND_OPS))
 
 
 def _band_flash_bwd(tq, tk, window, interpret, res, do):

@@ -836,3 +836,248 @@ def test_the_window_carries_its_name(masked_round):
     assert not [n for n in names if "/swa/" in n and "/attn/" in n]
     assert not [n for n in names if "/swa/" in n and "/gqa/" in n]
     assert any(re.search(r"/gqa/linear/dot_general", n) for n in names)
+
+
+# ---------------------------------------------------------------------------
+# what a layer's checkpoint keeps by name (ISSUE 44)
+# ---------------------------------------------------------------------------
+
+#: shapes the band kernels tile (heads of 128, rows of 256, a window of 128)
+TILED = dict(bptt=256, head_dim=128, sliding_window=128, hidden_size=64, num_key_value_heads=1,
+             intermediate_size=64)
+
+
+def _layers(*kinds):
+    """``arch`` of a model whose layers are of these kinds: a full layer on 3
+    query heads and a dense SwiGLU, a sliding one on 4 and experts."""
+    full = [k == "full_attention" for k in kinds]
+    return dict(TILED, num_hidden_layers=len(kinds), layer_types=list(kinds),
+                mlp_layer_types=["dense" if f else "sparse" for f in full],
+                num_attention_heads_per_layer=[3 if f else 4 for f in full])
+
+
+#: a lone full layer, a scanned run of sliding layers, and both at once
+LAYOUTS = {"lone-full": _layers("full_attention"),
+           "scanned-sliding": _layers("sliding_attention", "sliding_attention"),
+           "both": _layers("full_attention", "sliding_attention", "sliding_attention")}
+
+
+def _bare_checkpoints(monkeypatch):
+    """The model as it was before ISSUE 44: every layer under a bare
+    ``jax.checkpoint`` that keeps its input alone."""
+    from heterofl_tpu.models import laguna
+
+    monkeypatch.setattr(laguna, "kept", lambda: None)
+
+
+def _reports_a_tpu(monkeypatch, interpret=False):
+    """jax reporting a TPU, and the rule sending a tiny group's full layer to
+    the band pair too (it asks for 8 MiB of resident ``dq`` before it leaves
+    the ``gq`` pair); ``interpret``: the kernels run, in interpret mode."""
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(PA, "GQ_RESIDENT_BYTES", 0)
+    if interpret:
+        monkeypatch.setattr(PA, "fused_band_attention",
+                            functools.partial(PA.fused_band_attention, interpret=True))
+
+
+def _loss_counters_and_grads(model, params, tokens, lm):
+    def loss(p):
+        out, _ = model.apply(p, {"label": tokens}, train=True, label_mask=lm)
+        return out["loss"], out["counters"]
+
+    (value, counters), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    return value, counters, grads
+
+
+@pytest.mark.parametrize("path", ["block loop", "kernels"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_laguna_keeping_the_named_values_changes_no_number(layout, path, monkeypatch):
+    """Loss and every leaf's gradient under the policy against the bare
+    checkpoint's: a kept value is the value the second forward would have
+    computed from the same inputs.  On the CPU's block loop, which names
+    nothing, equal to the bit; on the interpreted kernels to float32 round-off
+    (another compiled program rounds them in another order)."""
+    _, model, params, tokens, lm, _ = _laguna_case(**LAYOUTS[layout])
+    if path == "kernels":
+        _reports_a_tpu(monkeypatch, interpret=True)
+    got, _, got_grads = jax.jit(lambda p: _loss_counters_and_grads(model, p, tokens, lm))(params)
+    _bare_checkpoints(monkeypatch)
+    want, _, want_grads = jax.jit(lambda p: _loss_counters_and_grads(model, p, tokens, lm))(params)
+    if path == "block loop":
+        assert float(got) == float(want)
+    else:
+        assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for name, w in want_grads.items():
+        if path == "block loop":
+            np.testing.assert_array_equal(got_grads[name], w, err_msg=name)
+        else:
+            np.testing.assert_allclose(got_grads[name], w, rtol=0, err_msg=name,
+                                       atol=1e-5 * float(jnp.abs(w).max()) + 1e-12)
+
+
+def _head_products(jaxpr):
+    """The products of the form `linear_heads` writes (the weight ``[K, H,
+    d]`` against ``[N, S, K]`` over ``K``: a layer's q / k / v projections,
+    forward or computed again; no cotangent's product has the form)."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import iter_eqns
+
+    found = 0
+    for e in iter_eqns(jaxpr):
+        if e.primitive.name == "dot_general":
+            (lc, rc), (lb, _) = e.params["dimension_numbers"]
+            found += (e.invars[0].aval.ndim, e.invars[1].aval.ndim, tuple(lc), tuple(rc),
+                      tuple(lb)) == (3, 3, (0,), (2,), ())
+    return found
+
+
+def _kernels_by_scan_body(jaxpr):
+    """(the Pallas kernels a program calls outside any `scan`, those each
+    `scan` body calls, in the program's order; a body that calls none is left
+    out)."""
+    from heterofl_tpu.staticcheck.jaxpr_walk import _sub_jaxprs
+
+    bodies = []
+
+    def walk(jaxpr, mine):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                mine.append(e.params["name"])
+            for sub in _sub_jaxprs(e.params):
+                if e.primitive.name == "scan":
+                    body = walk(sub, [])
+                    if body:
+                        bodies.append(sorted(body))
+                else:
+                    walk(sub, mine)
+        return mine
+
+    return sorted(walk(jaxpr.jaxpr, [])), bodies
+
+
+FWD, BWD = "band_attn_fwd", "band_attn_bwd"
+
+
+@pytest.mark.parametrize("layout, policy, outside, bodies, products", [
+    # a lone full layer keeps the kernels' results and operands: one forward
+    # kernel, and its five q / k / v products (two column-split) once
+    ("lone-full", "kept", [BWD, FWD], [], 5),
+    ("lone-full", "bare", [BWD, FWD, FWD], [], 10),
+    ("scanned-sliding", "kept", [], [[FWD], [BWD]], 3),
+    ("scanned-sliding", "bare", [], [[FWD], [BWD, FWD]], 6),
+    ("both", "kept", [BWD, FWD], [[FWD], [BWD]], 8),
+    ("both", "bare", [BWD, FWD, FWD], [[FWD], [BWD, FWD]], 16)])
+def test_gradient_on_the_band_kernels_runs_no_second_forward_where_the_layer_keeps_its_results(
+        layout, policy, outside, bodies, products, monkeypatch):
+    """A model at shapes the band kernels tile, jax reporting a TPU: the
+    gradient's program of a layer that keeps ``BAND_OUT`` / ``BAND_LSE`` calls
+    ``band_attn_bwd`` and no second ``band_attn_fwd``, and with ``BAND_OPS``
+    kept none of the layer's q / k / v products a second time (a full layer's
+    five, two of them column-split; a sliding layer's three); under a bare
+    checkpoint (before ISSUE 44) both are there.  A scanned run's calls lie in
+    its two scan bodies, a lone layer's outside any."""
+    if policy == "bare":
+        _bare_checkpoints(monkeypatch)
+    _reports_a_tpu(monkeypatch)
+    _, model, params, tokens, lm, _ = _laguna_case(**LAYOUTS[layout])
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(
+        p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"]))(params)
+    assert _kernels_by_scan_body(jaxpr) == (outside, bodies)
+    assert _head_products(jaxpr) == products
+
+
+@pytest.mark.parametrize("path", ["block loop", "kernels"])
+@pytest.mark.parametrize("policy", ["kept", "bare"])
+def test_laguna_named_values_are_the_layers_saved_residuals(policy, path, monkeypatch, capsys):
+    """What a layer hands its backward: its input ``[N, S, D]`` and, on the
+    kernels under the policy, exactly what :func:`models.laguna.kept` names
+    for its kind: ``o`` ``[N, H, d, S]`` float32, the log-sum-exp and the three
+    bfloat16 operands (positions minor); a scanned run's each as ONE residual
+    ``[L, ...]``.  On the block loop, or under a bare checkpoint, the input
+    alone."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    if policy == "bare":
+        _bare_checkpoints(monkeypatch)
+    if path == "kernels":
+        _reports_a_tpu(monkeypatch)
+    _, model, params, tokens, lm, _ = _laguna_case(**LAYOUTS["both"])
+    print_saved_residuals(
+        lambda p: model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0]["loss"], params)
+    lines = capsys.readouterr().out.splitlines()
+    of_the_scan = sorted(line.split()[0] for line in lines if "output of scan" in line)
+    # the lone layer's: what carries a name, and `o` (a primal output too,
+    # which jax hands on through a `reduce_precision`)
+    of_the_lone = sorted(line.split()[0] for line in lines
+                         if " named " in line or "output of reduce_precision" in line)
+    named = policy == "kept" and path == "kernels"
+    assert of_the_scan == sorted(["f32[2,2,256,64]"] + named * (
+        ["f32[2,2,4,128,256]", "f32[2,2,1,2,1,512]", "bf16[2,2,4,128,256]"]
+        + ["bf16[2,2,1,128,256]"] * 2))
+    assert of_the_lone == sorted(named * (
+        ["f32[2,3,128,256]", "f32[2,1,1,1,768]", "bf16[2,3,128,256]"] + ["bf16[2,1,128,256]"] * 2))
+
+
+@pytest.mark.parametrize("reports, policy, want", [
+    ("cpu", "kept", [0.0, 0.0]), ("tpu", "kept", [3.0, 3.0]), ("tpu", "bare", [0.0, 3.0])])
+def test_band_kept_counts_the_layers_that_kept_their_kernels_results(reports, policy, want,
+                                                                     monkeypatch):
+    """`band_kept` = (layers whose checkpoint kept their kernels' results,
+    layers whose attention took the band pair): 0 of 0 on a CPU, where the
+    block loop runs and a name is the identity; 3 of 3 with tiles and the
+    policy, 0 of 3 under a bare checkpoint; `obs.split_probes` divides."""
+    from heterofl_tpu.obs import split_probes
+
+    if policy == "bare":
+        _bare_checkpoints(monkeypatch)
+    if reports == "tpu":
+        _reports_a_tpu(monkeypatch, interpret=True)
+    _, model, params, tokens, lm, _ = _laguna_case(**LAYOUTS["both"])
+    assert model.meta["counters"]["band_kept"] == (2,)
+    out, _ = model.apply(params, {"label": tokens}, train=True, label_mask=lm)
+    assert out["counters"]["band_kept"].tolist() == want
+    _, rounds = split_probes({"obs_band_kept": np.asarray(out["counters"]["band_kept"])}, 1)
+    assert rounds[0]["band_kept"] == (want[0] / want[1] if want[1] else 0.0)
+
+
+@pytest.mark.parametrize("family", ["lfm2", "ouro"])
+def test_the_band_pairs_names_are_inert_in_the_other_families(family, monkeypatch):
+    """`_gq_named` took the band pair's names as an argument (ISSUE 44); the
+    families on the ``gq`` pair never pass it: their gradient's program on the
+    kernels (interpret mode, jax reporting a TPU) lowers to the same text with
+    the helper as it is and as it was."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from heterofl_tpu.ops import pallas_attention as PA
+
+    if family == "lfm2":
+        from benchmark.tests import tiny_lfm2 as tiny
+
+        cfg = tiny.program_cfg(head_dim=64)
+    else:
+        from benchmark.tests import tiny_ouro as tiny
+
+        cfg = tiny.program_cfg(bptt=128, head_dim=128, num_attention_heads=2,
+                               num_key_value_heads=2, hidden_size=64, intermediate_size=64,
+                               num_hidden_layers=3, total_ut_steps=2)
+    model = make_model(cfg)
+    params = model.init(jax.random.key(1))
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(PA, "fused_gq_attention",
+                        functools.partial(PA.fused_gq_attention, interpret=True))
+
+    def lowered():
+        return jax.jit(jax.grad(lambda p: model.apply(
+            p, {"label": tokens}, train=True)[0]["loss"])).lower(params).as_text()
+
+    now = lowered()
+
+    def as_it_was(ops, o, lse):
+        o, lse = checkpoint_name(o, PA.GQ_OUT), checkpoint_name(lse, PA.GQ_LSE)
+        return o, (checkpoint_name(ops, PA.GQ_OPS), o, lse)
+
+    monkeypatch.setattr(PA, "_gq_named", as_it_was)
+    assert lowered() == now
