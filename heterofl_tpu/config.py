@@ -38,11 +38,146 @@ CONTROL_KEYS = (
 # can validate tags without importing the model/data stacks.  models/ and
 # data/ import these rather than re-declaring them.
 NORM_TYPES = ("bn", "in", "ln", "gn", "none")
+#: THE table of decoder-only language-model families: name -> the published
+#: shape its ``models/<name>.py`` (``make_<name>``) builds at the GLOBAL widths.
+#: ``MODEL_NAMES``, ``LM_MODEL_NAMES``, ``process_control``'s ``cfg[<name>]`` and
+#: what ``models.make_model`` can build are all read off it: a new family is its
+#: module and one row here.
+DECODER_FAMILIES: Dict[str, Dict[str, Any]] = {
+    # Kanana-2-30B-A3B (model_type deepseek_v3): the published shape
+    # (huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json).
+    # ``expert_share`` = [index, of]: this process holds experts
+    # [index * n/of, (index + 1) * n/of) of every expert layer -- one chip's
+    # share of an ``of``-way expert-parallel deployment; the router keeps all
+    # ``n_routed_experts`` columns.  [0, 1] holds every expert.
+    "kanana2": {
+        "hidden_size": 2048,
+        "num_hidden_layers": 48,
+        "first_k_dense_replace": 1,
+        "intermediate_size": 6144,
+        "moe_intermediate_size": 768,
+        "n_routed_experts": 128,
+        "n_shared_experts": 2,
+        "num_experts_per_tok": 6,
+        "routed_scaling_factor": 2.448,
+        "num_attention_heads": 32,
+        "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64,
+        "v_head_dim": 128,
+        "kv_lora_rank": 512,
+        "rope_theta": 1000000.0,
+        "rms_norm_eps": 1e-6,
+        "expert_share": [0, 1],
+    },
+    # LFM2-8B-A1B (model_type lfm2_moe): the published shape
+    # (huggingface.co/LiquidAI/LFM2-8B-A1B config.json); ``head_dim`` =
+    # hidden_size / num_attention_heads and ``conv_dim`` = hidden_size are the
+    # family's convention, not keys of that file.  ``expert_share`` as above.
+    "lfm2": {
+        "hidden_size": 2048,
+        "num_hidden_layers": 24,
+        "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv",
+                        "full_attention", "conv", "conv", "conv", "full_attention",
+                        "conv", "conv", "conv", "full_attention", "conv", "conv",
+                        "conv", "full_attention", "conv", "conv", "full_attention",
+                        "conv", "conv"],
+        "num_dense_layers": 2,
+        "intermediate_size": 7168,
+        "moe_intermediate_size": 1792,
+        "num_experts": 32,
+        "num_experts_per_tok": 4,
+        "routed_scaling_factor": 1.0,
+        "num_attention_heads": 32,
+        "num_key_value_heads": 8,
+        "head_dim": 64,
+        "conv_dim": 2048,
+        "conv_L_cache": 3,
+        "rope_theta": 1000000.0,
+        "norm_eps": 1e-5,
+        "expert_share": [0, 1],
+    },
+    # Keye-VL-2.0-30B-A3B's language model (model_type KeyeVL2): the
+    # published shape (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B
+    # config.json); ``index_*`` are its ``sa_config`` (indexer_head_dim,
+    # indexer_num_heads on one key head, topk) under DeepSeek-V3.2's flat
+    # names, because an override merges one level deep.  ``expert_share`` as
+    # above; the benchmark's cut is 5 layers and [0, 16] (8 of 128 experts).
+    "keye": {
+        "hidden_size": 2048,
+        "num_hidden_layers": 48,
+        "moe_intermediate_size": 768,
+        "num_experts": 128,
+        "num_experts_per_tok": 8,
+        "num_attention_heads": 32,
+        "num_key_value_heads": 4,
+        "head_dim": 128,
+        "index_n_heads": 16,
+        "index_head_dim": 64,
+        "index_topk": 2048,
+        "rope_theta": 10000000.0,
+        "rms_norm_eps": 1e-6,
+        "expert_share": [0, 1],
+    },
+    # Ouro-2.6B (model_type ouro, the LoopLM of arXiv:2510.25741): the
+    # published shape (huggingface.co/ByteDance/Ouro-2.6B config.json).  The
+    # whole layer stack runs ``total_ut_steps`` times on the same weights;
+    # ``early_exit_threshold`` acts at inference only.  ``exit_entropy_beta``
+    # (the weight of the exit distribution's entropy in the training loss,
+    # the paper's stage-I objective under a uniform prior) is not a key of
+    # that file.  The benchmark's cut is 4 layers.
+    "ouro": {
+        "hidden_size": 2048,
+        "num_hidden_layers": 48,
+        "intermediate_size": 5632,
+        "num_attention_heads": 16,
+        "num_key_value_heads": 16,
+        "head_dim": 128,
+        "total_ut_steps": 4,
+        "early_exit_threshold": 1.0,
+        "exit_entropy_beta": 0.1,
+        "rope_theta": 1000000.0,
+        "rms_norm_eps": 1e-6,
+    },
+    # Laguna-XS.2 (model_type laguna): the published shape
+    # (huggingface.co/poolside/Laguna-XS.2 config.json).  The three lists are
+    # the published ones (a full-attention layer of 48 query heads at 0, 4, 8,
+    # ..., sliding layers of 64 between; layer 0 dense), written as their rule;
+    # the model reads the lists and assumes no period.  ``rope_parameters`` is
+    # one nested group (an override replaces it whole).  ``expert_share`` as
+    # above; the benchmark's cut is layers 0-4 and [0, 16] (16 of 256 experts).
+    "laguna": {
+        "hidden_size": 2048,
+        "num_hidden_layers": 40,
+        "layer_types": ["sliding_attention" if i % 4 else "full_attention" for i in range(40)],
+        "mlp_layer_types": ["sparse" if i else "dense" for i in range(40)],
+        "num_attention_heads_per_layer": [64 if i % 4 else 48 for i in range(40)],
+        "num_attention_heads": 48,
+        "num_key_value_heads": 8,
+        "head_dim": 128,
+        "sliding_window": 512,
+        "rope_parameters": {
+            "full_attention": {
+                "rope_theta": 500000.0, "rope_type": "yarn", "factor": 64.0,
+                "original_max_position_embeddings": 4096, "beta_slow": 1.0, "beta_fast": 64.0,
+                "attention_factor": 1.4158883083359672, "partial_rotary_factor": 0.5},
+            "sliding_attention": {
+                "rope_theta": 10000.0, "rope_type": "default", "partial_rotary_factor": 1.0},
+        },
+        "intermediate_size": 8192,
+        "moe_intermediate_size": 512,
+        "shared_expert_intermediate_size": 512,
+        "num_experts": 256,
+        "num_experts_per_tok": 8,
+        "moe_routed_scaling_factor": 2.5,
+        "rms_norm_eps": 1e-6,
+        "expert_share": [0, 1],
+    },
+}
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
-               "resnet152", "transformer", "kanana2", "lfm2", "keye", "ouro", "laguna")
+               "resnet152", "transformer") + tuple(DECODER_FAMILIES)
 #: the families that train on token rows (next- or masked-token loss): the
 #: drivers' and engines' LM paths key on this, not on one family's name
-LM_MODEL_NAMES = ("transformer", "kanana2", "lfm2", "keye", "ouro", "laguna")
+LM_MODEL_NAMES = ("transformer",) + tuple(DECODER_FAMILIES)
 # Feature-axis value registries (ISSUE 18): THE declared domains of the
 # engine/placement/store/pod axes, consumed by the axis validators below and
 # by staticcheck's config-lattice pass (staticcheck/lattice.py enumerates
@@ -448,134 +583,8 @@ def process_control(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "num_layers": 4,
         "dropout": 0.2,
     }
-    # Kanana-2-30B-A3B (model_type deepseek_v3): the published shape
-    # (huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json).
-    # ``expert_share`` = [index, of]: this process holds experts
-    # [index * n/of, (index + 1) * n/of) of every expert layer -- one chip's
-    # share of an ``of``-way expert-parallel deployment; the router keeps all
-    # ``n_routed_experts`` columns.  [0, 1] holds every expert.
-    cfg["kanana2"] = {
-        "hidden_size": 2048,
-        "num_hidden_layers": 48,
-        "first_k_dense_replace": 1,
-        "intermediate_size": 6144,
-        "moe_intermediate_size": 768,
-        "n_routed_experts": 128,
-        "n_shared_experts": 2,
-        "num_experts_per_tok": 6,
-        "routed_scaling_factor": 2.448,
-        "num_attention_heads": 32,
-        "qk_nope_head_dim": 128,
-        "qk_rope_head_dim": 64,
-        "v_head_dim": 128,
-        "kv_lora_rank": 512,
-        "rope_theta": 1000000.0,
-        "rms_norm_eps": 1e-6,
-        "expert_share": [0, 1],
-    }
-    # LFM2-8B-A1B (model_type lfm2_moe): the published shape
-    # (huggingface.co/LiquidAI/LFM2-8B-A1B config.json); ``head_dim`` =
-    # hidden_size / num_attention_heads and ``conv_dim`` = hidden_size are the
-    # family's convention, not keys of that file.  ``expert_share`` as above.
-    cfg["lfm2"] = {
-        "hidden_size": 2048,
-        "num_hidden_layers": 24,
-        "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv",
-                        "full_attention", "conv", "conv", "conv", "full_attention",
-                        "conv", "conv", "conv", "full_attention", "conv", "conv",
-                        "conv", "full_attention", "conv", "conv", "full_attention",
-                        "conv", "conv"],
-        "num_dense_layers": 2,
-        "intermediate_size": 7168,
-        "moe_intermediate_size": 1792,
-        "num_experts": 32,
-        "num_experts_per_tok": 4,
-        "routed_scaling_factor": 1.0,
-        "num_attention_heads": 32,
-        "num_key_value_heads": 8,
-        "head_dim": 64,
-        "conv_dim": 2048,
-        "conv_L_cache": 3,
-        "rope_theta": 1000000.0,
-        "norm_eps": 1e-5,
-        "expert_share": [0, 1],
-    }
-    # Keye-VL-2.0-30B-A3B's language model (model_type KeyeVL2): the
-    # published shape (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B
-    # config.json); ``index_*`` are its ``sa_config`` (indexer_head_dim,
-    # indexer_num_heads on one key head, topk) under DeepSeek-V3.2's flat
-    # names, because an override merges one level deep.  ``expert_share`` as
-    # above; the benchmark's cut is 5 layers and [0, 16] (8 of 128 experts).
-    cfg["keye"] = {
-        "hidden_size": 2048,
-        "num_hidden_layers": 48,
-        "moe_intermediate_size": 768,
-        "num_experts": 128,
-        "num_experts_per_tok": 8,
-        "num_attention_heads": 32,
-        "num_key_value_heads": 4,
-        "head_dim": 128,
-        "index_n_heads": 16,
-        "index_head_dim": 64,
-        "index_topk": 2048,
-        "rope_theta": 10000000.0,
-        "rms_norm_eps": 1e-6,
-        "expert_share": [0, 1],
-    }
-    # Ouro-2.6B (model_type ouro, the LoopLM of arXiv:2510.25741): the
-    # published shape (huggingface.co/ByteDance/Ouro-2.6B config.json).  The
-    # whole layer stack runs ``total_ut_steps`` times on the same weights;
-    # ``early_exit_threshold`` acts at inference only.  ``exit_entropy_beta``
-    # (the weight of the exit distribution's entropy in the training loss,
-    # the paper's stage-I objective under a uniform prior) is not a key of
-    # that file.  The benchmark's cut is 4 layers.
-    cfg["ouro"] = {
-        "hidden_size": 2048,
-        "num_hidden_layers": 48,
-        "intermediate_size": 5632,
-        "num_attention_heads": 16,
-        "num_key_value_heads": 16,
-        "head_dim": 128,
-        "total_ut_steps": 4,
-        "early_exit_threshold": 1.0,
-        "exit_entropy_beta": 0.1,
-        "rope_theta": 1000000.0,
-        "rms_norm_eps": 1e-6,
-    }
-    # Laguna-XS.2 (model_type laguna): the published shape
-    # (huggingface.co/poolside/Laguna-XS.2 config.json).  The three lists are
-    # the published ones (a full-attention layer of 48 query heads at 0, 4, 8,
-    # ..., sliding layers of 64 between; layer 0 dense), written as their rule;
-    # the model reads the lists and assumes no period.  ``rope_parameters`` is
-    # one nested group (an override replaces it whole).  ``expert_share`` as
-    # above; the benchmark's cut is layers 0-4 and [0, 16] (16 of 256 experts).
-    cfg["laguna"] = {
-        "hidden_size": 2048,
-        "num_hidden_layers": 40,
-        "layer_types": ["sliding_attention" if i % 4 else "full_attention" for i in range(40)],
-        "mlp_layer_types": ["sparse" if i else "dense" for i in range(40)],
-        "num_attention_heads_per_layer": [64 if i % 4 else 48 for i in range(40)],
-        "num_attention_heads": 48,
-        "num_key_value_heads": 8,
-        "head_dim": 128,
-        "sliding_window": 512,
-        "rope_parameters": {
-            "full_attention": {
-                "rope_theta": 500000.0, "rope_type": "yarn", "factor": 64.0,
-                "original_max_position_embeddings": 4096, "beta_slow": 1.0, "beta_fast": 64.0,
-                "attention_factor": 1.4158883083359672, "partial_rotary_factor": 0.5},
-            "sliding_attention": {
-                "rope_theta": 10000.0, "rope_type": "default", "partial_rotary_factor": 1.0},
-        },
-        "intermediate_size": 8192,
-        "moe_intermediate_size": 512,
-        "shared_expert_intermediate_size": 512,
-        "num_experts": 256,
-        "num_experts_per_tok": 8,
-        "moe_routed_scaling_factor": 2.5,
-        "rms_norm_eps": 1e-6,
-        "expert_share": [0, 1],
-    }
+    for family, shape in DECODER_FAMILIES.items():
+        cfg[family] = copy.deepcopy(shape)
     # Per-dataset hyperparameters (ref src/utils.py:150-212).
     data_name = cfg["data_name"]
     split = cfg["data_split_mode"]

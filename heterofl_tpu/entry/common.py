@@ -1243,7 +1243,8 @@ class FedExperiment:
                                or self.quarantine.enabled):
             # the quarantine counter rides as an obs_ probe even with
             # telemetry off (ISSUE 15) -- split either way
-            ms, plist = split_probes(ms, self.mesh.shape["clients"])
+            ms, plist = split_probes(ms, self.mesh.shape["clients"],
+                                     counters=self.model.meta.get("counters"))
             if plist:
                 probes = plist[0]
         if uids is not None and self.ledger is not None:
@@ -1386,9 +1387,13 @@ class FedExperiment:
         eval_interval = self.eval_interval
         epoch = last_epoch
         if self.tracer is not None:
+            # with the folds of the model's own counters (name -> fold):
+            # obs.report sums or averages them over the rounds by it
             self.tracer.instant("run-start",
                                 args={"tag": self.tag, "epoch0": int(epoch),
-                                      "rounds": int(n_rounds)})
+                                      "rounds": int(n_rounds),
+                                      "counters": {k: fold for k, (_, fold) in
+                                                   self.model.meta.get("counters", {}).items()}})
         try:
             return self._run_loop(logger, pivot_metric, pivot_mode, pivot,
                                   epoch, n_rounds, eval_interval, data_split,

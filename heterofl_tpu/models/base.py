@@ -6,6 +6,10 @@ pytrees, haiku-style without the framework): ``init(key) -> params`` and
 (global model sizes); per-client width heterogeneity enters only through the
 traced ``width_rate``/``scaler_rate`` scalars and the masks they induce, so
 one compiled program serves every rate level.
+
+Here: the container and the two initialisers every family uses.  What the
+decoder-only language models share beyond that (their leaf book, ``apply``'s
+prologue and tail, the expert layers' helpers) is ``models/decoder.py``.
 """
 
 from __future__ import annotations
@@ -55,40 +59,3 @@ def uniform_fan_in(key: jax.Array, shape, fan_in: int) -> jnp.ndarray:
 
 def normal_init(key: jax.Array, shape, std: float) -> jnp.ndarray:
     return std * jax.random.normal(key, shape, jnp.float32)
-
-
-def held_experts(expert_share, n: int) -> range:
-    """The routed experts a share ``(index, of)`` of an ``of``-way
-    expert-parallel layer of ``n`` experts holds: ``[index * n/of, (index +
-    1) * n/of)``."""
-    index, of = (int(v) for v in expert_share)
-    if of < 1 or n % of or not 0 <= index < of:
-        raise ValueError(f"Not valid expert_share: {list(expert_share)!r} "
-                         f"(index, of) with of dividing the {n} routed experts")
-    return range(index * (n // of), (index + 1) * (n // of))
-
-
-def expert_tile(tokens: int, top_k: int, experts: int) -> int:
-    """Rows a step of the expert loop (``ops.layers.moe_experts``) takes:
-    twice an expert's expected group (``tokens * top_k / experts`` pairs), in
-    whole ``MOE_TILE``s.  An expert is then one step a pass unless its load
-    doubles: its float32 weights are read once, and the loop's trip count
-    stops following the seed's routing (at 256 rows, half an expected group
-    of the LFM2 cell, two seeds' rounds lay 2.7 % apart on the chip and 0.4 %
-    at 1,024, no slower; PERF.md, PR 32)."""
-    from ..ops.layers import MOE_TILE
-
-    return MOE_TILE * max(1, -(-2 * tokens * top_k // (experts * MOE_TILE)))
-
-
-def layer_leaves(params: Dict[str, jnp.ndarray], i: int, held=None) -> Dict[str, jnp.ndarray]:
-    """Layer ``i``'s leaves (``l{i}.*``) without their prefix; with ``held``
-    (an expert layer), its held experts' ``moe.e{j}.{g,u,d}.w`` stacked on a
-    leading axis as ``moe.e.{g,u,d}.w``, in that order."""
-    pre = f"l{i}."
-    lp = {k[len(pre):]: v for k, v in params.items()
-          if k.startswith(pre) and ".moe.e" not in k}
-    if held is not None:
-        for m in "gud":
-            lp[f"moe.e.{m}.w"] = jnp.stack([params[f"{pre}moe.e{j}.{m}.w"] for j in held])
-    return lp

@@ -57,12 +57,13 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import scope
-from ..ops.layers import (causal_latent_attention, embed, heads_linear,
-                          linear as _linear, linear_heads, masked_logits,
-                          masked_rms_norm, moe_experts, moe_route, next_token_loss,
-                          rope_interleaved, rope_swap, scaler, swiglu)
-from .base import ModelDef, held_experts, layer_leaves, normal_init, uniform_fan_in
-from .spec import Group, ParamSpec
+from ..ops.layers import (causal_latent_attention, heads_linear, linear as _linear,
+                          linear_heads, masked_rms_norm, moe_experts, moe_route,
+                          rope_interleaved, rope_swap, swiglu)
+from .base import ModelDef
+from .decoder import (Leaves, alike_runs, decoder, held_experts, layer_leaves, moe_counters,
+                      run_layers)
+from .spec import Group
 
 
 def latent_attention_shapes(D: int, H: int, dn: int, dr: int, dv: int, R: int) -> Dict[str, tuple]:
@@ -115,12 +116,8 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
     """``arch``: ``cfg['kanana2']`` (config.process_control) at the GLOBAL widths;
     ``model_rate`` builds the dense sub-model a client at that rate holds
     (the sliced strategy and the equivalence tests)."""
-    from ..config import ceil_width
-
-    def cw(n, multiple=1):
-        k = ceil_width(n, model_rate)
-        return -(-k // multiple) * multiple
-
+    leaves = Leaves(model_rate)
+    cw, add, add_ffn = leaves.cw, leaves.add, leaves.add_ffn
     D = cw(arch["hidden_size"])
     L, L_dense = int(arch["num_hidden_layers"]), int(arch["first_k_dense_replace"])
     F, Fe = cw(arch["intermediate_size"]), cw(arch["moe_intermediate_size"])
@@ -154,23 +151,7 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         "vocab": Group("vocab", num_tokens, kind="full"),
     }
 
-    specs: Dict[str, ParamSpec] = {
-        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
-        "norm.g": ParamSpec({0: "emb"}),
-        "head.w": ParamSpec({0: "emb"}, label_axis=1),
-    }
-    shapes: Dict[str, tuple] = {
-        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens)}
-
-    def add(name, shape, axis_groups):
-        shapes[name] = shape
-        specs[name] = ParamSpec(axis_groups)
-
-    def add_ffn(prefix, width, group):
-        add(f"{prefix}.g.w", (D, width), {0: "emb", 1: group})
-        add(f"{prefix}.u.w", (D, width), {0: "emb", 1: group})
-        add(f"{prefix}.d.w", (width, D), {0: group, 1: "emb"})
-
+    leaves.stem(num_tokens, D)
     attn_groups = {
         "attn.q.n.w": {0: "emb", 1: "q_nope"}, "attn.q.r.w": {0: "emb", 1: "q_rope"},
         "attn.kv_a.c.w": {0: "emb", 1: "kv_lora"}, "attn.kv_a.r.w": {0: "emb", 1: "k_rope"},
@@ -192,40 +173,10 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             for j in held:
                 add_ffn(f"{p}.moe.e{j}", Fe, "expert")
 
-    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
-        names = sorted(shapes)
-        params = {}
-        for name, k in zip(names, jax.random.split(key, len(names))):
-            shape = shapes[name]
-            if len(shape) == 1:  # norm gains 1; the selection bias 0
-                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
-            elif name.startswith("embedding."):
-                params[name] = normal_init(k, shape, 1.0)
-            else:
-                params[name] = uniform_fan_in(k, shape, shape[0])
-        return params
-
-    linear = partial(_linear, compute_dtype=compute_dtype)
-
-    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
-              label_mask=None, bn_mode: str = "batch", bn_state=None,
-              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
-        if "pos_offset" in batch or attn_override is not None:
-            raise ValueError("kanana2 has no sequence-sharded path (mesh "
-                             "'data' axis must be 1)")
-        labels = batch["label"]
-        N, S = labels.shape
-        T = N * S
-        act = {g: groups[g].active_count(width_rate).astype(jnp.float32)
-               for g in ("emb", "kv_lora", "q_nope", "q_rope")}
-        emb_mask, lora_mask = groups["emb"].mask(width_rate), groups["kv_lora"].mask(width_rate)
+    def body(c, params):
+        N, S, T, sc, rms, act = c.N, c.S, c.T, c.sc, c.rms, c.count
+        lora_mask = c.mask["kv_lora"]
         scale = 1.0 / jnp.sqrt((act["q_nope"] + act["q_rope"]) / H)
-
-        def sc(x):
-            return scaler(x, scaler_rate, train)
-
-        def rms(g, x):
-            return masked_rms_norm(x, g, emb_mask, act["emb"], eps)
 
         attention = partial(
             latent_attention, heads=H, theta=theta, scale=scale, sc=sc,
@@ -241,7 +192,7 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         @jax.checkpoint
         def dense_layer(x, lp):
             x = x + attention(lp, rms(lp["norm1.g"], x))
-            return x + ffn(lp, "mlp", rms(lp["norm2.g"], x))
+            return x + ffn(lp, "mlp", rms(lp["norm2.g"], x)), None
 
         @jax.checkpoint
         def expert_layer(x, lp):
@@ -254,40 +205,18 @@ def make_kanana2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 y = y + ffn(lp, "moe.shared", hf)
             return x + y.reshape(N, S, D), counters
 
-        def leaves(i):
-            return layer_leaves(params, i, held if i >= L_dense else None)
+        # the expert layers are alike (and the dense ones): one scan over
+        # their stacked leaves, so the program holds one layer's code whatever
+        # the depth
+        runs = alike_runs(L, lambda i: i < L_dense,
+                          lambda i: layer_leaves(params, i, held if i >= L_dense else None),
+                          lambda i: dense_layer if i < L_dense else expert_layer)
+        return c.finish(*run_layers(c.embed(), runs))
 
-        x = embed(params["embedding.tok.w"], labels)
-        for i in range(L_dense):
-            x = dense_layer(x, leaves(i))
-        counters = None
-        if L > L_dense:
-            # the expert layers are alike: one scan over their stacked leaves,
-            # so the program holds one layer's code whatever the depth
-            rest = [leaves(i) for i in range(L_dense, L)]
-            x, per_layer = jax.lax.scan(
-                expert_layer, x, {k: jnp.stack([lp[k] for lp in rest]) for k in rest[0]})
-            counters = jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), per_layer)
-        xn = rms(params["norm.g"], x)
-
-        def head(x_):
-            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
-
-        # the logits [N, S, V] a caller may read (evaluation does not, training
-        # does not: then the compiler drops them); the loss takes the head in
-        # blocks of positions
-        res = {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight)}
-        if counters is not None:
-            res["counters"] = {f"moe_{k}": v for k, v in counters.items()}
-        return res, {}
-
-    meta = {"bn_sizes": {}, "kind": "kanana2", "num_tokens": num_tokens,
-            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes),
-            # what analysis.summary.module_table cannot read off the leaves
-            "profile": {"routed_share": K / E,
-                        "attention": {f"l{i}.attn": (H, dn + dr, dv) for i in range(L)}}}
-    if L > L_dense:
-        # what apply's "counters" holds (summed over the expert layers); the
-        # engines carry them as obs_ probes when telemetry is on
-        meta["counters"] = {"moe_tokens": (len(held),), "moe_assign": (3,)}
-    return ModelDef("kanana2", init, apply, specs, groups, [], meta)
+    return decoder(
+        "kanana2", num_tokens, arch, leaves, groups, body, eps=eps, mask=mask,
+        compute_dtype=compute_dtype, counts=("kv_lora", "q_nope", "q_rope"), masks=("kv_lora",),
+        # summed over the expert layers
+        held=held, counters=moe_counters(held) if L > L_dense else None,
+        profile={"routed_share": K / E,
+                 "attention": {f"l{i}.attn": (H, dn + dr, dv) for i in range(L)}})

@@ -72,24 +72,18 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
-from ..ops.layers import (causal_gq_attention, embed, linear as _linear, masked_layer_norm,
-                          masked_logits, masked_rms_norm, moe_experts, moe_route,
-                          next_token_loss, rope_interleaved, rope_swap, scaler, select_keys,
+from ..ops.layers import (causal_gq_attention, masked_layer_norm, masked_rms_norm, moe_experts,
+                          moe_route, rope_interleaved, rope_swap, select_keys,
                           selected_attention_tile, selected_gq_attention)
-from .base import (ModelDef, expert_tile, held_experts, layer_leaves, normal_init,
-                   uniform_fan_in)
-from .lfm2 import gq_attention
-from .spec import Group, ParamSpec
+from .base import ModelDef
+from .decoder import (Leaves, alike_runs, decoder, expert_tile, gq_attention, held_experts,
+                      layer_leaves, moe_counters, run_layers)
+from .spec import Group
 
 #: query rows a block of the selection and of the selected attention takes,
 #: where ``topk`` is not smaller: the published kernel's ``q_chunk_size``
 #: (memory, not mathematics; the blocks before ``topk`` then carry no mask)
 QUERY_BLOCK = 512
-
-#: the layer's counters under the names they ride the metrics by
-COUNTERS = {"tokens": "moe_tokens", "assign": "moe_assign",
-            "selected": "sparse_selected", "kept_share": "sparse_kept_share",
-            "fused": "sparse_fused", "saved": "sparse_saved"}
 
 #: the name an indexer's 0/1 blocks carry (``checkpoint_name``; :func:`kept`)
 CHOICE = "sparse_choice"
@@ -136,12 +130,8 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
     """``arch``: ``cfg['keye']`` (config.process_control) at the GLOBAL widths;
     ``model_rate`` builds the dense sub-model a client at that rate holds
     (the sliced strategy and the equivalence tests)."""
-    from ..config import ceil_width
-
-    def cw(n, multiple=1):
-        k = ceil_width(n, model_rate)
-        return -(-k // multiple) * multiple
-
+    leaves = Leaves(model_rate)
+    cw, add, add_ffn = leaves.cw, leaves.add, leaves.add_ffn
     D, Fe = cw(arch["hidden_size"]), cw(arch["moe_intermediate_size"])
     L = int(arch["num_hidden_layers"])
     E, K = int(arch["num_experts"]), int(arch["num_experts_per_tok"])
@@ -169,27 +159,11 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         "expert": Group("expert", Fe),
         "router": Group("router", E, kind="full"),
     }
-    specs: Dict[str, ParamSpec] = {
-        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
-        "norm.g": ParamSpec({0: "emb"}),
-        "head.w": ParamSpec({0: "emb"}, label_axis=1),
-    }
-    shapes: Dict[str, tuple] = {
-        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens)}
-
-    def add(name, shape, axis_groups):
-        shapes[name] = shape
-        specs[name] = ParamSpec(axis_groups)
-
+    leaves.stem(num_tokens, D)
     for i in range(L):
         p = f"l{i}"
         add(f"{p}.norm1.g", (D,), {0: "emb"})
-        add(f"{p}.attn.q.w", (D, H * hd), {0: "emb", 1: "q_head"})
-        add(f"{p}.attn.k.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
-        add(f"{p}.attn.v.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
-        add(f"{p}.attn.q_norm.g", (hd,), {0: "head"})
-        add(f"{p}.attn.k_norm.g", (hd,), {0: "head"})
-        add(f"{p}.attn.o.w", (H * hd, D), {0: "q_head", 1: "emb"})
+        leaves.add_gq_attention(p, H, Hkv, hd)
         add(f"{p}.idx.q.w", (D, Hi * di), {0: "emb", 1: "iq_head"})
         add(f"{p}.idx.k.w", (D, di), {0: "emb", 1: "ik_head"})
         add(f"{p}.idx.k_norm.g", (di,), {0: "ik_head"})
@@ -198,44 +172,10 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         add(f"{p}.norm2.g", (D,), {0: "emb"})
         add(f"{p}.moe.router.w", (D, E), {0: "emb", 1: "router"})
         for j in held:
-            add(f"{p}.moe.e{j}.g.w", (D, Fe), {0: "emb", 1: "expert"})
-            add(f"{p}.moe.e{j}.u.w", (D, Fe), {0: "emb", 1: "expert"})
-            add(f"{p}.moe.e{j}.d.w", (Fe, D), {0: "expert", 1: "emb"})
+            add_ffn(f"{p}.moe.e{j}", Fe, "expert")
 
-    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
-        names = sorted(shapes)
-        params = {}
-        for name, k in zip(names, jax.random.split(key, len(names))):
-            shape = shapes[name]
-            if len(shape) == 1:  # norm gains 1; the indexer's LayerNorm bias 0
-                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
-            elif name.startswith("embedding."):
-                params[name] = normal_init(k, shape, 1.0)
-            else:
-                params[name] = uniform_fan_in(k, shape, shape[0])
-        return params
-
-    linear = partial(_linear, compute_dtype=compute_dtype)
-
-    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
-              label_mask=None, bn_mode: str = "batch", bn_state=None,
-              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
-        if "pos_offset" in batch or attn_override is not None:
-            raise ValueError("keye has no sequence-sharded path (mesh "
-                             "'data' axis must be 1)")
-        labels = batch["label"]
-        N, S = labels.shape
-        T = N * S
-        act = {g: groups[g].active_count(width_rate).astype(jnp.float32)
-               for g in ("emb", "head", "ik_head")}
-        masks = {g: groups[g].mask(width_rate) for g in ("emb", "head", "ik_head")}
-
-        def sc(x):
-            return scaler(x, scaler_rate, train)
-
-        def rms(g, x):
-            return masked_rms_norm(x, g, masks["emb"], act["emb"], eps)
-
+    def body(c, params):
+        N, S, T, sc, rms, act, masks = c.N, c.S, c.T, c.sc, c.rms, c.count, c.mask
         tile, block = expert_tile(T, K, E), min(QUERY_BLOCK, topk)
         attention = partial(
             gq_attention, heads=H, kv_heads=Hkv, head_dim=int(arch["head_dim"]), theta=theta,
@@ -279,37 +219,24 @@ def make_keye(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             counters["saved"] = jnp.stack([jnp.float32(saved), jnp.float32(selecting)])
             return x + y.reshape(N, S, D), counters
 
-        x = embed(params["embedding.tok.w"], labels)
-        run = [layer_leaves(params, i, held) for i in range(L)]
-        x, per_layer = lax.scan(layer, x, {k: jnp.stack([lp[k] for lp in run]) for k in run[0]})
-        counters = jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), per_layer)
-        xn = rms(params["norm.g"], x)
+        # the layers are alike: the whole depth is one run
+        runs = alike_runs(L, lambda i: 0, lambda i: layer_leaves(params, i, held), lambda i: layer)
+        return c.finish(*run_layers(c.embed(), runs))
 
-        def head(x_):
-            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
-
-        # the logits [N, S, V] a caller may read (training does not: then the
-        # compiler drops them); the loss takes the head in blocks of positions
-        return {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight),
-                "counters": {COUNTERS[k]: v for k, v in counters.items()}}, {}
-
-    meta = {"bn_sizes": {}, "kind": "keye", "num_tokens": num_tokens,
-            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes),
-            # what analysis.summary.module_table cannot read off the leaves
-            # (the attention's two products at every causal pair: an upper bound
-            # where the indexer selects)
-            "profile": {"routed_share": K / E,
-                        "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
-            # what apply's "counters" holds (summed over the layers); the
-            # engines carry them as obs_ probes when telemetry is on.  The
-            # four sparse ones are (numerator, denominator) pairs:
-            # obs.split_probes finishes them as selected keys a query,
-            # selected over causal pairs, the share of the selected
-            # attention's query tiles that the fused kernels took, and the
-            # share of the selecting query blocks whose choice the layer
-            # kept for its backward
-            "counters": {"moe_tokens": (len(held),), "moe_assign": (3,),
-                         "sparse_selected": (2,), "sparse_kept_share": (2,),
-                         "sparse_fused": (2,), "sparse_saved": (2,)}}
-    return ModelDef("keye", init, apply, specs, groups, [], meta)
-
+    return decoder(
+        "keye", num_tokens, arch, leaves, groups, body, eps=eps, mask=mask,
+        compute_dtype=compute_dtype, counts=("head", "ik_head"), masks=("head", "ik_head"),
+        held=held,
+        # summed over the layers.  The four sparse ones finish as selected keys
+        # a query, selected over causal pairs, the share of the selected
+        # attention's query tiles that the fused kernels took, and the share of
+        # the selecting query blocks whose choice the layer kept for its backward
+        counters={**moe_counters(held),
+                  "selected": ("sparse_selected", (2,), "ratio"),
+                  "kept_share": ("sparse_kept_share", (2,), "ratio"),
+                  "fused": ("sparse_fused", (2,), "ratio"),
+                  "saved": ("sparse_saved", (2,), "ratio")},
+        # (the attention's two products at every causal pair: an upper bound
+        # where the indexer selects)
+        profile={"routed_share": K / E,
+                 "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}})

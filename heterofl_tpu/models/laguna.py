@@ -77,7 +77,6 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from itertools import groupby
 from typing import Dict
 
 import jax
@@ -85,18 +84,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.trace import scope
-from ..ops.layers import (band_attention_planned, causal_gq_attention, embed, heads_linear,
-                          linear as _linear, linear_heads, masked_logits, masked_rms_norm,
-                          moe_experts, moe_route, next_token_loss, rope_interleaved, rope_swap,
-                          scaler, sliding_attention_tiles, sliding_gq_attention, swiglu)
-from .base import ModelDef, expert_tile, held_experts, layer_leaves, normal_init, uniform_fan_in
-from .spec import Group, ParamSpec
+from ..ops.layers import (band_attention_planned, causal_gq_attention, heads_linear,
+                          linear as _linear, linear_heads, moe_experts, moe_route,
+                          rope_interleaved, rope_swap, sliding_attention_tiles,
+                          sliding_gq_attention, swiglu)
+from .base import ModelDef
+from .decoder import (Leaves, alike_runs, decoder, expert_tile, held_experts, layer_leaves,
+                      moe_counters, run_layers)
+from .spec import Group
 
 KINDS = ("full_attention", "sliding_attention")
-
-#: the layer's counters under the names they ride the metrics by
-COUNTERS = {"tokens": "moe_tokens", "assign": "moe_assign", "fused": "swa_fused",
-            "pairs": "swa_pairs", "tiles": "swa_tiles", "kept": "band_kept"}
 
 
 def kept():
@@ -181,12 +178,8 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
     """``arch``: ``cfg['laguna']`` (config.process_control) at the GLOBAL
     widths; ``model_rate`` builds the dense sub-model a client at that rate
     holds (the sliced strategy and the equivalence tests)."""
-    from ..config import ceil_width
-
-    def cw(n, multiple=1):
-        k = ceil_width(n, model_rate)
-        return -(-k // multiple) * multiple
-
+    leaves = Leaves(model_rate)
+    cw, add, add_ffn = leaves.cw, leaves.add, leaves.add_ffn
     D, L = cw(arch["hidden_size"]), int(arch["num_hidden_layers"])
     kinds, mlps = list(arch["layer_types"]), list(arch["mlp_layer_types"])
     layer_heads = [int(n) for n in arch["num_attention_heads_per_layer"]]
@@ -231,23 +224,7 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                              coupled=False, family=family)
         return name
 
-    specs: Dict[str, ParamSpec] = {
-        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
-        "norm.g": ParamSpec({0: "emb"}),
-        "head.w": ParamSpec({0: "emb"}, label_axis=1),
-    }
-    shapes: Dict[str, tuple] = {
-        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens)}
-
-    def add(name, shape, axis_groups):
-        shapes[name] = shape
-        specs[name] = ParamSpec(axis_groups)
-
-    def add_ffn(prefix, width, group):
-        add(f"{prefix}.g.w", (D, width), {0: "emb", 1: group})
-        add(f"{prefix}.u.w", (D, width), {0: "emb", 1: group})
-        add(f"{prefix}.d.w", (width, D), {0: group, 1: "emb"})
-
+    leaves.stem(num_tokens, D)
     widths = {}  # kind -> (rotary dims, the rest) of a head at this model_rate
     for i in range(L):
         p, H, kd = f"l{i}", layer_heads[i], short(kinds[i])
@@ -275,39 +252,9 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             for j in held:
                 add_ffn(f"{p}.moe.e{j}", Fe, "expert")
 
-    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
-        names = sorted(shapes)
-        params = {}
-        for name, k in zip(names, jax.random.split(key, len(names))):
-            shape = shapes[name]
-            if len(shape) == 1:  # norm gains
-                params[name] = jnp.ones(shape)
-            elif name.startswith("embedding."):
-                params[name] = normal_init(k, shape, 1.0)
-            else:
-                params[name] = uniform_fan_in(k, shape, shape[0])
-        return params
 
-    linear = partial(_linear, compute_dtype=compute_dtype)
-    has_moe, has_swa = "sparse" in mlps, "sliding_attention" in kinds
-
-    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
-              label_mask=None, bn_mode: str = "batch", bn_state=None,
-              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
-        if "pos_offset" in batch or attn_override is not None:
-            raise ValueError("laguna has no sequence-sharded path (mesh "
-                             "'data' axis must be 1)")
-        labels = batch["label"]
-        N, S = labels.shape
-        T = N * S
-        emb_act = groups["emb"].active_count(width_rate).astype(jnp.float32)
-        emb_mask = groups["emb"].mask(width_rate)
-
-        def sc(x):
-            return scaler(x, scaler_rate, train)
-
-        def rms(g, x):
-            return masked_rms_norm(x, g, emb_mask, emb_act, eps)
+    def body(c, params):
+        N, S, T, sc, rms, width_rate = c.N, c.S, c.T, c.sc, c.rms, c.width_rate
 
         def head_dims(kind):  # the active dims of one head of a layer of this kind
             names = [f"{short(kind)}.k_rope"] + ([f"{short(kind)}.k_nope"] if widths[kind][1] else [])
@@ -315,12 +262,7 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 jnp.float32) / Hkv
 
         tile = expert_tile(T, K, E)
-        zero = {"kept": jnp.zeros((2,), jnp.float32)}
-        if has_moe:
-            zero.update(tokens=jnp.zeros((len(held),), jnp.float32),
-                        assign=jnp.zeros((3,), jnp.float32))
-        if has_swa:
-            zero.update({k: jnp.zeros((2,), jnp.float32) for k in ("fused", "pairs", "tiles")})
+        zero = c.zeros()
 
         def swa_counters(H):
             """What a sliding layer adds, (numerator, denominator) pairs: query
@@ -377,49 +319,28 @@ def make_laguna(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 return x + y.reshape(N, S, D), counters
             return layer
 
-        counters = zero
-        x = embed(params["embedding.tok.w"], labels)
-        alike = groupby(range(L), key=lambda i: (kinds[i], layer_heads[i], mlps[i]))
-        for _, run in alike:
-            run = list(run)
-            leaves = [layer_leaves(params, i, held if mlps[i] == "sparse" else None)
-                      for i in run]
-            if len(run) == 1:
-                x, c = layer_of(run[0])(x, leaves[0])
-            else:
-                # alike layers: one scan over their stacked leaves, so the
-                # program holds one layer's code however long the run
-                x, c = jax.lax.scan(layer_of(run[0]), x,
-                                    {k: jnp.stack([lp[k] for lp in leaves]) for k in leaves[0]})
-                c = jax.tree_util.tree_map(lambda v: jnp.sum(v, axis=0), c)
-            counters = jax.tree_util.tree_map(jnp.add, counters, c)
-        xn = rms(params["norm.g"], x)
+        # alike in kind, head count and feed-forward
+        runs = alike_runs(L, lambda i: (kinds[i], layer_heads[i], mlps[i]),
+                          lambda i: layer_leaves(params, i, held if mlps[i] == "sparse" else None),
+                          layer_of)
+        return c.finish(*run_layers(c.embed(), runs, zero))
 
-        def head(x_):
-            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
-
-        # the logits [N, S, V] a caller may read (training does not: then the
-        # compiler drops them); the loss takes the head in blocks of positions
-        res = {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight)}
-        res["counters"] = {COUNTERS[k]: v for k, v in counters.items()}
-        return res, {}
-
-    meta = {"bn_sizes": {}, "kind": "laguna", "num_tokens": num_tokens,
-            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes),
-            # what analysis.summary.module_table cannot read off the leaves: a
-            # site's heads, its two products' widths and its window (None: every
-            # causal pair)
-            "profile": {"routed_share": K / E,
-                        "attention": {f"l{i}.attn": (
-                            layer_heads[i], sum(widths[kinds[i]]), hd,
-                            window if kinds[i] == "sliding_attention" else None)
-                            for i in range(L)}}}
-    # what apply's "counters" holds (summed over the layers); the engines carry
-    # them as obs_ probes when telemetry is on.  The three swa ones are
-    # (numerator, denominator) pairs that obs.split_probes divides
-    meta["counters"] = {"band_kept": (2,)}
-    if has_moe:
-        meta["counters"].update(moe_tokens=(len(held),), moe_assign=(3,))
-    if has_swa:
-        meta["counters"].update(swa_fused=(2,), swa_pairs=(2,), swa_tiles=(2,))
-    return ModelDef("laguna", init, apply, specs, groups, [], meta)
+    # summed over the layers; the three swa ones: query tiles the kernel pair
+    # took over query tiles, band pairs over causal pairs, key tiles visited
+    # over key tiles on or under the diagonal; `band_kept`: layers whose
+    # checkpoint kept their band kernels' results over layers on the band kernels
+    counters = {"kept": ("band_kept", (2,), "ratio")}
+    if "sparse" in mlps:
+        counters.update(moe_counters(held))
+    if "sliding_attention" in kinds:
+        counters.update({k: (f"swa_{k}", (2,), "ratio") for k in ("fused", "pairs", "tiles")})
+    return decoder(
+        "laguna", num_tokens, arch, leaves, groups, body, eps=eps, mask=mask,
+        compute_dtype=compute_dtype, held=held, counters=counters,
+        # a site's heads, its two products' widths and its window (None: every
+        # causal pair)
+        profile={"routed_share": K / E,
+                 "attention": {f"l{i}.attn": (
+                     layer_heads[i], sum(widths[kinds[i]]), hd,
+                     window if kinds[i] == "sliding_attention" else None)
+                     for i in range(L)}})

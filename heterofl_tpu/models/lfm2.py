@@ -57,19 +57,18 @@ under ``jax.checkpoint``.
 from __future__ import annotations
 
 from functools import partial
-from itertools import groupby
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 from ..obs.trace import scope
-from ..ops.layers import (causal_gq_attention, embed, heads_linear,
-                          linear as _linear, linear_heads, masked_logits, masked_rms_norm,
-                          moe_experts, moe_route, next_token_loss, rope_interleaved,
-                          rope_swap, scaler, short_conv, swiglu)
-from .base import ModelDef, expert_tile, held_experts, layer_leaves, normal_init, uniform_fan_in
-from .spec import Group, ParamSpec
+from ..ops.layers import (linear as _linear, masked_rms_norm, moe_experts, moe_route,
+                          short_conv, swiglu)
+from .base import ModelDef
+from .decoder import (Leaves, alike_runs, decoder, expert_tile, gq_attention, held_experts,
+                      layer_leaves, moe_counters, run_layers)
+from .spec import Group
 
 #: what ``lfm2_moe`` adds to the sum of the chosen scores before dividing
 ROUTE_SUM_EPS = 1e-6
@@ -83,49 +82,13 @@ def conv_mixer(lp, h, *, sc, compute_dtype=None):
         return sc(linear(short_conv(b, c, u, lp["conv.taps.w"]), lp["conv.out.w"]))
 
 
-def gq_attention(lp, h, *, heads: int, kv_heads: int, head_dim: int, theta: float, scale,
-                 sc, head_norm=None, compute_dtype=None, attend=causal_gq_attention):
-    """A layer's grouped-query attention on the normed ``h`` ``[N, S, D]``,
-    heads first from the projections to the output projection; ``head_norm(x,
-    g)`` the RMSNorm over each head's dims (None: the family has none, and
-    the layer no ``attn.q_norm.g`` / ``attn.k_norm.g``: models/ouro.py),
-    ``head_dim`` the GLOBAL model's (the rotary frequencies' denominator at
-    every width), ``attend(q, k, v, scale)``."""
-    q_heads = partial(linear_heads, heads=heads, compute_dtype=compute_dtype)
-    kv = partial(linear_heads, heads=kv_heads, compute_dtype=compute_dtype)
-    pos = jnp.arange(h.shape[1])
-    with scope("gqa"):
-        # (each norm straight after its product, the order the LFM2 and Keye
-        # programs were traced and measured in)
-        q = sc(q_heads(h, lp["attn.q.w"]))
-        if head_norm is not None:
-            q = head_norm(q, lp["attn.q_norm.g"])
-        k = sc(kv(h, lp["attn.k.w"]))
-        if head_norm is not None:
-            k = head_norm(k, lp["attn.k_norm.g"])
-        v = sc(kv(h, lp["attn.v.w"]))
-    # the norm sits between the product and the turn, so the pair swap is
-    # taken on the activations (kanana2 takes its rotary query's on the weight)
-    q = rope_interleaved(q, rope_swap(q), pos, theta, axis=2, full=head_dim)
-    k = rope_interleaved(k, rope_swap(k), pos, theta, axis=2, full=head_dim)
-    if compute_dtype is not None:
-        q, k, v = (t.astype(compute_dtype) for t in (q, k, v))
-    o = attend(q, k, v, scale)
-    with scope("gqa"):
-        return sc(heads_linear(o.astype(jnp.float32), lp["attn.o.w"], compute_dtype))
-
-
 def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
               mask: bool = True, compute_dtype=None) -> ModelDef:
     """``arch``: ``cfg['lfm2']`` (config.process_control) at the GLOBAL widths;
     ``model_rate`` builds the dense sub-model a client at that rate holds
     (the sliced strategy and the equivalence tests)."""
-    from ..config import ceil_width
-
-    def cw(n, multiple=1):
-        k = ceil_width(n, model_rate)
-        return -(-k // multiple) * multiple
-
+    leaves = Leaves(model_rate)
+    cw, add, add_ffn = leaves.cw, leaves.add, leaves.add_ffn
     D, Dc = cw(arch["hidden_size"]), cw(arch["conv_dim"])
     L, L_dense = int(arch["num_hidden_layers"]), int(arch["num_dense_layers"])
     kinds = list(arch["layer_types"])
@@ -158,26 +121,7 @@ def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         "expert": Group("expert", Fe),
         "router": Group("router", E, kind="full"),
     }
-
-    # the tied leaf: looked up by row and multiplied as the head, one label
-    # axis (its rows) for both uses.  Not named ``embedding.*``: normal(0, 1)
-    # rows (the initialisers' rule for that name) read as a head give logits
-    # of the hidden size's scale
-    specs: Dict[str, ParamSpec] = {
-        "tok.w": ParamSpec({1: "emb"}, label_axis=0),
-        "norm.g": ParamSpec({0: "emb"}),
-    }
-    shapes: Dict[str, tuple] = {"tok.w": (num_tokens, D), "norm.g": (D,)}
-
-    def add(name, shape, axis_groups):
-        shapes[name] = shape
-        specs[name] = ParamSpec(axis_groups)
-
-    def add_ffn(prefix, width, group):
-        add(f"{prefix}.g.w", (D, width), {0: "emb", 1: group})
-        add(f"{prefix}.u.w", (D, width), {0: "emb", 1: group})
-        add(f"{prefix}.d.w", (width, D), {0: group, 1: "emb"})
-
+    leaves.stem(num_tokens, D, tied=True)
     for i, kind in enumerate(kinds):
         p = f"l{i}"
         add(f"{p}.norm1.g", (D,), {0: "emb"})
@@ -187,12 +131,7 @@ def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             add(f"{p}.conv.taps.w", (taps, Dc), {1: "conv"})
             add(f"{p}.conv.out.w", (Dc, D), {0: "conv", 1: "emb"})
         else:
-            add(f"{p}.attn.q.w", (D, H * hd), {0: "emb", 1: "q_head"})
-            add(f"{p}.attn.k.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
-            add(f"{p}.attn.v.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
-            add(f"{p}.attn.q_norm.g", (hd,), {0: "head"})
-            add(f"{p}.attn.k_norm.g", (hd,), {0: "head"})
-            add(f"{p}.attn.o.w", (H * hd, D), {0: "q_head", 1: "emb"})
+            leaves.add_gq_attention(p, H, Hkv, hd)
         add(f"{p}.norm2.g", (D,), {0: "emb"})
         if i < L_dense:
             add_ffn(f"{p}.mlp", F, "ffn")
@@ -202,40 +141,9 @@ def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             for j in held:
                 add_ffn(f"{p}.moe.e{j}", Fe, "expert")
 
-    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
-        names = sorted(shapes)
-        params = {}
-        for name, k in zip(names, jax.random.split(key, len(names))):
-            shape = shapes[name]
-            if len(shape) == 1:  # norm gains 1; the selection bias 0
-                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
-            elif name == "tok.w":  # read as the head too: small, as a head's columns
-                params[name] = normal_init(k, shape, 0.02)
-            else:  # the taps [L, channels]: a channel's fan-in is its L taps
-                params[name] = uniform_fan_in(k, shape, shape[0])
-        return params
-
-    linear = partial(_linear, compute_dtype=compute_dtype)
-
-    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
-              label_mask=None, bn_mode: str = "batch", bn_state=None,
-              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
-        if "pos_offset" in batch or attn_override is not None:
-            raise ValueError("lfm2 has no sequence-sharded path (mesh "
-                             "'data' axis must be 1)")
-        labels = batch["label"]
-        N, S = labels.shape
-        T = N * S
-        emb_act = groups["emb"].active_count(width_rate).astype(jnp.float32)
-        head_act = groups["head"].active_count(width_rate).astype(jnp.float32)
-        emb_mask, head_mask = groups["emb"].mask(width_rate), groups["head"].mask(width_rate)
-
-        def sc(x):
-            return scaler(x, scaler_rate, train)
-
-        def rms(g, x):
-            return masked_rms_norm(x, g, emb_mask, emb_act, eps)
-
+    def body(c, params):
+        N, S, T, sc, rms = c.N, c.S, c.T, c.sc, c.rms
+        head_act, head_mask = c.count["head"], c.mask["head"]
         mixers = {
             "conv": partial(conv_mixer, sc=sc, compute_dtype=compute_dtype),
             "full_attention": partial(
@@ -245,14 +153,14 @@ def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         }
 
         tile = expert_tile(T, K, E)
+        zero_counters = c.zeros() if L > L_dense else None
 
-        zero_counters = {"tokens": jnp.zeros((len(held),), jnp.float32),
-                         "assign": jnp.zeros((3,), jnp.float32)}
+        def layer_of(i):
+            """Layer ``i``'s kind as ``(x, leaves) -> (x, counters)``
+            (``decoder.run_layers``).  It keeps only its input for the
+            backward."""
+            kind, dense = kinds[i], i < L_dense
 
-        def layer_of(kind, dense):
-            """One layer of a kind as ``(x, leaves) -> (x, counters)``: a
-            ``lax.scan`` body, and a plain call for a lone layer.  It keeps
-            only its input for the backward."""
             @jax.checkpoint
             def layer(x, lp):
                 x = x + mixers[kind](lp, rms(lp["norm1.g"], x))
@@ -268,42 +176,17 @@ def make_lfm2(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 return x + y.reshape(N, S, D), counters
             return layer
 
-        def leaves(i):
-            return layer_leaves(params, i, held if i >= L_dense else None)
+        # alike in mixer and feed-forward
+        runs = alike_runs(L, lambda i: (kinds[i], i < L_dense),
+                          lambda i: layer_leaves(params, i, held if i >= L_dense else None),
+                          layer_of)
+        return c.finish(*run_layers(c.embed(), runs, zero_counters))
 
-        counters = zero_counters
-        x = embed(params["tok.w"], labels)
-        for (kind, dense), run in groupby(range(L), key=lambda i: (kinds[i], i < L_dense)):
-            run = [leaves(i) for i in run]
-            if len(run) == 1:
-                x, c = layer_of(kind, dense)(x, run[0])
-            else:
-                # alike layers: one scan over their stacked leaves, so the
-                # program holds one layer's code however long the run
-                x, c = jax.lax.scan(layer_of(kind, dense), x,
-                                    {k: jnp.stack([lp[k] for lp in run]) for k in run[0]})
-                c = jax.tree_util.tree_map(lambda v: jnp.sum(v, axis=0), c)
-            counters = jax.tree_util.tree_map(jnp.add, counters, c)
-        xn = rms(params["norm.g"], x)
-
-        def head(x_):  # the tied head: the embedding's rows as columns
-            return masked_logits(linear(x_, params["tok.w"].T), label_mask, mask)
-
-        # the logits [N, S, V] a caller may read (training does not: then the
-        # compiler drops them); the loss takes the head in blocks of positions
-        res = {"score": head(xn), "loss": next_token_loss(xn, labels, head, sample_weight)}
-        if L > L_dense:
-            res["counters"] = {f"moe_{k}": v for k, v in counters.items()}
-        return res, {}
-
-    meta = {"bn_sizes": {}, "kind": "lfm2", "num_tokens": num_tokens,
-            "arch": dict(arch), "held_experts": list(held), "shapes": dict(shapes),
-            # what analysis.summary.module_table cannot read off the leaves
-            "profile": {"routed_share": K / E, "tied_head": "tok.w",
-                        "attention": {f"l{i}.attn": (H, hd, hd) for i, kind in enumerate(kinds)
-                                      if kind == "full_attention"}}}
-    if L > L_dense:
-        # what apply's "counters" holds (summed over the expert layers); the
-        # engines carry them as obs_ probes when telemetry is on
-        meta["counters"] = {"moe_tokens": (len(held),), "moe_assign": (3,)}
-    return ModelDef("lfm2", init, apply, specs, groups, [], meta)
+    return decoder(
+        "lfm2", num_tokens, arch, leaves, groups, body, eps=eps, mask=mask,
+        compute_dtype=compute_dtype, counts=("head",), masks=("head",), held=held,
+        # summed over the expert layers
+        counters=moe_counters(held) if L > L_dense else None,
+        profile={"routed_share": K / E,
+                 "attention": {f"l{i}.attn": (H, hd, hd) for i, kind in enumerate(kinds)
+                               if kind == "full_attention"}})

@@ -92,11 +92,10 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
-from ..ops.layers import (embed, exit_log_probs, gq_attention_tile, linear as _linear,
-                          masked_logits, masked_rms_norm, pass_token_nll, scaler, swiglu)
-from .base import ModelDef, layer_leaves, normal_init, uniform_fan_in
-from .lfm2 import gq_attention
-from .spec import Group, ParamSpec
+from ..ops.layers import exit_log_probs, gq_attention_tile, pass_token_nll, swiglu
+from .base import ModelDef
+from .decoder import Leaves, alike_runs, decoder, gq_attention, layer_leaves, run_layers
+from .spec import Group
 
 #: the name a layer application's SwiGLU output carries (``checkpoint_name``;
 #: :func:`kept`): the down-projected ``y``, the input of the sandwich's ``norm4``
@@ -125,12 +124,8 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
     """``arch``: ``cfg['ouro']`` (config.process_control) at the GLOBAL widths;
     ``model_rate`` builds the dense sub-model a client at that rate holds
     (the sliced strategy and the equivalence tests)."""
-    from ..config import ceil_width
-
-    def cw(n, multiple=1):
-        k = ceil_width(n, model_rate)
-        return -(-k // multiple) * multiple
-
+    leaves = Leaves(model_rate)
+    cw, add, add_ffn = leaves.cw, leaves.add, leaves.add_ffn
     D, F = cw(arch["hidden_size"]), cw(arch["intermediate_size"])
     L, R = int(arch["num_hidden_layers"]), int(arch["total_ut_steps"])
     H, Hkv = int(arch["num_attention_heads"]), int(arch["num_key_value_heads"])
@@ -155,66 +150,18 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         "ffn": Group("ffn", F),
         "gate": Group("gate", 1, kind="full"),
     }
-    specs: Dict[str, ParamSpec] = {
-        "embedding.tok.w": ParamSpec({1: "emb"}, label_axis=0),
-        "norm.g": ParamSpec({0: "emb"}),
-        "head.w": ParamSpec({0: "emb"}, label_axis=1),
-        "exit.w": ParamSpec({0: "emb", 1: "gate"}),
-        "exit.b": ParamSpec({0: "gate"}),
-    }
-    shapes: Dict[str, tuple] = {
-        "embedding.tok.w": (num_tokens, D), "norm.g": (D,), "head.w": (D, num_tokens),
-        "exit.w": (D, 1), "exit.b": (1,)}
-
-    def add(name, shape, axis_groups):
-        shapes[name] = shape
-        specs[name] = ParamSpec(axis_groups)
-
+    leaves.stem(num_tokens, D)
+    add("exit.w", (D, 1), {0: "emb", 1: "gate"})
+    add("exit.b", (1,), {0: "gate"})
     for i in range(L):
         p = f"l{i}"
         for j in (1, 2, 3, 4):  # the sandwich: in and out of the attention, in and out of the SwiGLU
             add(f"{p}.norm{j}.g", (D,), {0: "emb"})
-        add(f"{p}.attn.q.w", (D, H * hd), {0: "emb", 1: "q_head"})
-        add(f"{p}.attn.k.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
-        add(f"{p}.attn.v.w", (D, Hkv * hd), {0: "emb", 1: "kv_head"})
-        add(f"{p}.attn.o.w", (H * hd, D), {0: "q_head", 1: "emb"})
-        add(f"{p}.mlp.g.w", (D, F), {0: "emb", 1: "ffn"})
-        add(f"{p}.mlp.u.w", (D, F), {0: "emb", 1: "ffn"})
-        add(f"{p}.mlp.d.w", (F, D), {0: "ffn", 1: "emb"})
+        leaves.add_gq_attention(p, H, Hkv, hd, head_norm=False)
+        add_ffn(f"{p}.mlp", F, "ffn")
 
-    def init(key: jax.Array) -> Dict[str, jnp.ndarray]:
-        names = sorted(shapes)
-        params = {}
-        for name, k in zip(names, jax.random.split(key, len(names))):
-            shape = shapes[name]
-            if len(shape) == 1:  # norm gains 1; the gate's bias 0
-                params[name] = (jnp.ones if name.endswith(".g") else jnp.zeros)(shape)
-            elif name.startswith("embedding."):
-                params[name] = normal_init(k, shape, 1.0)
-            else:
-                params[name] = uniform_fan_in(k, shape, shape[0])
-        return params
-
-    linear = partial(_linear, compute_dtype=compute_dtype)
-
-    def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,
-              label_mask=None, bn_mode: str = "batch", bn_state=None,
-              sample_weight=None, rng=None, bn_axis=None, attn_override=None):
-        if "pos_offset" in batch or attn_override is not None:
-            raise ValueError("ouro has no sequence-sharded path (mesh "
-                             "'data' axis must be 1)")
-        labels = batch["label"]
-        N, S = labels.shape
-        emb_act = groups["emb"].active_count(width_rate).astype(jnp.float32)
-        head_act = groups["q_head"].active_count(width_rate).astype(jnp.float32) / H
-        emb_mask = groups["emb"].mask(width_rate)
-
-        def sc(x):
-            return scaler(x, scaler_rate, train)
-
-        def rms(g, x):
-            return masked_rms_norm(x, g, emb_mask, emb_act, eps)
-
+    def body(c, params):
+        N, S, train, sc, rms, head_act = c.N, c.S, c.train, c.sc, c.rms, c.count["q_head"]
         attention = partial(
             gq_attention, heads=H, kv_heads=Hkv, head_dim=int(arch["head_dim"]), theta=theta,
             scale=1.0 / jnp.sqrt(head_act), sc=sc, compute_dtype=compute_dtype)
@@ -234,33 +181,26 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             y = swiglu(h, lp["mlp.g.w"], lp["mlp.u.w"], lp["mlp.d.w"], sc, compute_dtype)
             return x + rms(lp["norm4.g"], checkpoint_name(y, MLP_OUT)), None
 
-        run = [layer_leaves(params, i) for i in range(L)]
-        unrolled = L <= UNROLL_LAYERS
-        if not unrolled:
-            stacked = {k: jnp.stack([lp[k] for lp in run]) for k in run[0]}
+        # the layers are alike: one run, gathered (and, where it is scanned,
+        # stacked) once, outside the passes
+        runs = list(alike_runs(L, lambda i: 0, lambda i: layer_leaves(params, i), lambda i: layer,
+                               unroll=UNROLL_LAYERS))
+        unrolled = not runs[0][2]
 
         def one_pass(x, _):
             """The whole stack once on the shared weights, then the final
             norm: the normed state is what the head and the gate read and
             what the next pass starts from."""
             with scope("loop/pass"):
-                if unrolled:
-                    for lp in run:
-                        x, _ = layer(x, lp)
-                else:
-                    x, _ = lax.scan(layer, x, stacked)
+                x, _ = run_layers(x, runs)
             with scope("loop/exit"):
                 h = rms(params["norm.g"], x)
             return h, h
 
-        _, hs = lax.scan(one_pass, embed(params["embedding.tok.w"], labels), None, length=R)
-
-        def head(x_):
-            return masked_logits(linear(x_, params["head.w"]), label_mask, mask)
-
+        _, hs = lax.scan(one_pass, c.embed(), None, length=R)
         with scope("loop/head"):
             # [R, N, S] and the targets' weights [N, S]
-            nll, wt = pass_token_nll(hs, labels, head, sample_weight)
+            nll, wt = pass_token_nll(hs, c.labels, c.head, c.sample_weight)
         with scope("loop/exit"):
             # no Scaler: the gate's logit is read by a sigmoid alone.  One
             # column: float32 at "highest" precision costs nothing, and the
@@ -286,29 +226,31 @@ def make_ouro(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
             # pass's mean negative log-likelihood, the expected pass
             ranks = jnp.arange(1, R + 1, dtype=jnp.float32)[:, None, None]
             counters = {
-                "loop_exit_share": jnp.append(jnp.sum(p * wt, axis=(1, 2)), count),
-                "loop_pass_nll": jnp.append(jnp.sum(nll * wt, axis=(1, 2)), count),
-                "loop_passes": jnp.stack([jnp.sum(ranks * p * wt), count]),
-                "loop_kept": jnp.array([named, applied], jnp.float32),
-                "loop_unrolled": jnp.array([applied if unrolled else 0, applied], jnp.float32)}
+                "exit_share": jnp.append(jnp.sum(p * wt, axis=(1, 2)), count),
+                "pass_nll": jnp.append(jnp.sum(nll * wt, axis=(1, 2)), count),
+                "passes": jnp.stack([jnp.sum(ranks * p * wt), count]),
+                "kept": jnp.array([named, applied], jnp.float32),
+                "unrolled": jnp.array([applied if unrolled else 0, applied], jnp.float32)}
         read = jnp.take_along_axis(hs, last[None, :, :, None], axis=0)[0]
         # the logits [N, S, V] a caller may read (training does not: then the
         # compiler drops them)
-        return {"score": head(read), "loss": loss, "counters": counters}, {}
+        return c.result(c.head(read), loss, counters)
 
-    meta = {"bn_sizes": {}, "kind": "ouro", "num_tokens": num_tokens,
-            "arch": dict(arch), "shapes": dict(shapes),
-            # what analysis.summary.module_table cannot read off the leaves:
-            # every layer leaf, the final norm, the gate and the head are
-            # used once a pass, the embedding once a step
-            "profile": {"routed_share": 1.0, "passes": R,
-                        "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}},
-            # what apply's "counters" holds; the engines carry them as obs_
-            # probes when telemetry is on and obs.split_probes finishes them
-            # (`loop_kept`: a (numerator, denominator) pair, the layer
-            # applications whose attention kernel's results the layer kept
-            # for its backward over the layer applications; `loop_unrolled`:
-            # those run from an unrolled stack over the layer applications)
-            "counters": {"loop_exit_share": (R + 1,), "loop_pass_nll": (R + 1,),
-                         "loop_passes": (2,), "loop_kept": (2,), "loop_unrolled": (2,)}}
-    return ModelDef("ouro", init, apply, specs, groups, [], meta)
+    return decoder(
+        "ouro", num_tokens, arch, leaves, groups, body, eps=eps, mask=mask,
+        compute_dtype=compute_dtype, counts=(("q_head", H),),
+        # sums over the target positions with their count last: the exit
+        # distribution's mean a pass (sums to 1), each pass's mean negative
+        # log-likelihood; pairs: the expected pass (sum of t * p_t), the layer
+        # applications whose attention kernel's results the layer kept for its
+        # backward over the layer applications, and those run from an unrolled
+        # stack over the layer applications
+        counters={"exit_share": ("loop_exit_share", (R + 1,), "mean"),
+                  "pass_nll": ("loop_pass_nll", (R + 1,), "mean"),
+                  "passes": ("loop_passes", (2,), "ratio"),
+                  "kept": ("loop_kept", (2,), "ratio"),
+                  "unrolled": ("loop_unrolled", (2,), "ratio")},
+        # every layer leaf, the final norm, the gate and the head are used
+        # once a pass, the embedding once a step
+        profile={"routed_share": 1.0, "passes": R,
+                 "attention": {f"l{i}.attn": (H, hd, hd) for i in range(L)}})

@@ -317,6 +317,7 @@ def resolve_telemetry_cfg(cfg: Dict[str, Any]) -> TelemetrySpec:
 
 
 def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
+                 counters: Optional[Dict[str, Any]] = None,
                  ) -> Tuple[Dict[str, Any], Optional[List[Dict[str, Any]]]]:
     """Pop the ``obs_*`` probe leaves out of a FETCHED metrics dict and
     finish them into per-round probe records.
@@ -330,6 +331,11 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
     the final sqrt.  ``layout``: ``'flat'`` = device-major concat on the
     last axis (masked engine, grouped slices); ``'span'`` = device axis
     LAST (grouped span, whose metric leaves are ``[k, L, slots]``).
+    ``counters``: the model's own counters as it declares them
+    (``meta['counters']``: name -> (shape, fold)), each per-device sums over
+    that device's valid slots, steps and layers, finished by its fold:
+    ``sum`` = the sums as they are; ``ratio`` = a (numerator, denominator)
+    pair divided; ``mean`` = sums with their count last, each over the count.
     Returns ``(metrics-without-probes, [per-round records] or None)``."""
     keys = [k for k in ms if k.startswith(PROBE_PREFIX)]
     if not keys:
@@ -346,6 +352,7 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 v = v[None]
             canon[name] = v.reshape(v.shape[0], n_dev, -1)
     k_rounds = next(iter(canon.values())).shape[0]
+    folds = {name: fold for name, (_, fold) in (counters or {}).items()}
     rounds: List[Dict[str, Any]] = []
     for r in range(k_rounds):
         rec: Dict[str, Any] = {}
@@ -367,40 +374,17 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
                 # (each device counts its own gated slots) sum across
                 # devices -- and across levels on the grouped span layout
                 rec["quarantined"] = int(round(float(x.sum())))
-            elif base.startswith("moe_"):
-                # an expert layer's counters (ISSUE 28): per-device sums
-                # over that device's valid slots, steps and expert layers
-                rec[base] = [float(c) for c in x.sum(axis=0)]
-            elif base.startswith(("sparse_", "swa_", "band_")):
-                # a sparse-attention indexer's counters (ISSUES 35, 36, 39)
-                # and the sliding layers' (ISSUE 42: swa_fused = query tiles
-                # the band kernels took over query tiles, swa_pairs = band
-                # over causal pairs, swa_tiles = key tiles visited over key
-                # tiles on or under the diagonal, below 1 where tiles under
-                # the band were skipped),
-                # each a (numerator, denominator) pair of per-device sums:
-                # sparse_selected = keys selected a query, sparse_kept_share
-                # = selected over causal (query, key) pairs, sparse_fused =
-                # query tiles the fused kernels took over query tiles,
-                # sparse_saved = selecting query blocks whose choice the
-                # layer kept for its backward over selecting query blocks;
-                # band_kept (ISSUE 44) = Laguna layers whose checkpoint kept
-                # their band kernels' results over layers on the band kernels
-                num, den = (float(c) for c in x.sum(axis=0))
-                rec[base] = num / den if den else 0.0
-            elif base.startswith("loop_"):
-                # a looped model's counters (ISSUE 40), each per-device sums
-                # over target positions with their count last: loop_exit_share
-                # = the exit distribution's mean a pass (sums to 1),
-                # loop_pass_nll = each pass's mean negative log-likelihood,
-                # loop_passes = the expected pass (sum of t * p_t), a scalar;
-                # loop_kept (ISSUE 41), of layer applications and a scalar
-                # too = the share whose attention kernel's output the layer
-                # kept for its backward; loop_unrolled (ISSUE 43), alike =
-                # the share applied from an unrolled stack, not a scanned one
-                *nums, den = (float(c) for c in x.sum(axis=0))
-                vals = [n / den if den else 0.0 for n in nums]
-                rec[base] = vals[0] if base in ("loop_passes", "loop_kept", "loop_unrolled") else vals
+            elif base in folds:
+                *nums, den = total = [float(c) for c in x.sum(axis=0)]
+                if folds[base] == "sum":
+                    rec[base] = total
+                elif folds[base] == "ratio":
+                    rec[base] = nums[0] / den if den else 0.0
+                elif folds[base] == "mean":
+                    rec[base] = [n / den if den else 0.0 for n in nums]
+                else:
+                    raise ValueError(f"Not valid fold of the counter {base!r}: "
+                                     f"{folds[base]!r} ('sum' | 'ratio' | 'mean')")
             elif base == "nonfinite":
                 rec["nonfinite"] = int(x[0, 0])
             elif base.endswith("_sq"):

@@ -52,9 +52,8 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
     """Count events.jsonl records by name; surface the watchdog trips."""
     counts: Dict[str, int] = {}
     watchdog: List[Dict[str, Any]] = []
-    moe = {"rounds": 0, "tokens": None, "routed": 0.0, "held": 0.0, "dropped": 0}
-    loop: List[Dict[str, Any]] = []  # a looped model's counters, a round each
-    swa: List[Dict[str, Any]] = []  # the sliding layers' counters, a round each
+    folds: Dict[str, str] = {}  # the model's counters: name -> fold (`run-start`)
+    seen: Dict[str, List[Any]] = {}  # a declared counter's value, a round each
     setup: List[spans.Span] = []  # every span filed with its id
     with open(events_path) as f:
         for line in f:
@@ -70,36 +69,23 @@ def summarize_events(events_path: str) -> Dict[str, Any]:
             if rec.get("ph") == "X" and "id" in args:
                 setup.append(spans.Span(args["id"], name, rec["t"],
                                         rec["dur_s"], args.get("parent"), args))
-            if name == "probes" and "moe_assign" in args:
-                # an expert layer's counters (obs.split_probes), summed
-                # over the run's rounds
-                moe["rounds"] += 1
-                tok = args.get("moe_tokens", [])
-                moe["tokens"] = list(tok) if moe["tokens"] is None else \
-                    [a + b for a, b in zip(moe["tokens"], tok)]
-                moe["routed"] += args["moe_assign"][0]
-                moe["held"] += args["moe_assign"][1]
-                moe["dropped"] += int(args.get("moe_dropped", 0))
-            if name == "probes" and "loop_exit_share" in args:
-                loop.append(args)
-            if name == "probes" and "swa_pairs" in args:
-                swa.append(args)
+            if name == "run-start":
+                folds.update(args.get("counters", {}))
+            if name == "probes":
+                for key in folds:
+                    if key in args:
+                        seen.setdefault(key, []).append(args[key])
     out = {"path": events_path, "events_by_name": counts,
            "watchdog_trips": watchdog[:16]}
-    if moe["rounds"]:
-        out["moe"] = moe
-    if loop:
-        # a looped model's counters (obs.split_probes), the rounds' mean
-        def mean(key):
-            return [sum(col) / len(loop) for col in zip(*(r[key] for r in loop))]
-
-        out["loop"] = {"rounds": len(loop), "exit_share": mean("loop_exit_share"),
-                       "pass_nll": mean("loop_pass_nll"),
-                       "passes": sum(r["loop_passes"] for r in loop) / len(loop)}
-    if swa:
-        # the sliding layers' counters (obs.split_probes), the rounds' mean
-        out["swa"] = {"rounds": len(swa), **{k: sum(r[f"swa_{k}"] for r in swa) / len(swa)
-                                             for k in ("fused", "pairs", "tiles")}}
+    for key, rounds in seen.items():
+        # a model's counters (obs.split_probes), by family (the name's first
+        # word): a `sum` summed over the run's rounds, a `ratio` or a `mean`
+        # averaged over them
+        family, _, short = key.partition("_")
+        over = 1 if folds[key] == "sum" else len(rounds)
+        out.setdefault(family, {"rounds": len(rounds)})[short] = \
+            [sum(col) / over for col in zip(*rounds)] if isinstance(rounds[0], list) \
+            else sum(rounds) / over
     if setup:
         out["setup"] = spans.summarize(setup)
     return out
@@ -163,11 +149,13 @@ def render_events(ev: Optional[Dict[str, Any]]) -> List[str]:
                                       sorted(ev["events_by_name"].items())))
         moe = ev.get("moe")
         if moe:
-            share = moe["held"] / moe["routed"] if moe["routed"] else 0.0
+            # (pairs routed, pairs on held experts, held pairs not computed)
+            routed, held, dropped = moe["assign"]
+            share = held / routed if routed else 0.0
             lines.append(
                 f"  expert layers over {moe['rounds']} rounds: (token, expert) "
-                f"pairs on held experts {moe['held']:g} of {moe['routed']:g} "
-                f"({100.0 * share:.2f} %), dropped {moe['dropped']}")
+                f"pairs on held experts {held:g} of {routed:g} "
+                f"({100.0 * share:.2f} %), dropped {round(dropped)}")
             lines.append("    tokens per held expert: " + " ".join(
                 f"{t:g}" for t in moe["tokens"]))
         loop = ev.get("loop")

@@ -1569,7 +1569,8 @@ class GroupedRoundEngine(_WireCodecCarry, _SchedBufCarry):
             if self._obs_on or self._quarantine.enabled:
                 return split_probes(host, self.mesh.shape["clients"],
                                     layout="span" if mode == "span"
-                                    else "flat")
+                                    else "flat",
+                                    counters=self.global_model.meta.get("counters"))
             return host, None
 
         def _assemble_train(host):

@@ -770,14 +770,14 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
                 acc = acc[:3] + ({k: acc[3][k] + ctr[k] * g for k in ctr},)
             return (p, opt, acc), None
 
-        # counters a model declares (meta['counters']: name -> shape) ride the
+        # counters a model declares (meta['counters']: name -> (shape, fold)) ride the
         # metrics as obs_ probes when telemetry is on; otherwise the program
         # is the one without them
         counted = self._obs_on and bool(model.meta.get("counters"))
         acc0 = (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
         if counted:
             acc0 += ({k: jnp.zeros(shape, jnp.float32)
-                      for k, shape in model.meta["counters"].items()},)
+                      for k, (shape, _) in model.meta["counters"].items()},)
         (p, _, acc), _ = jax.lax.scan(step, (p, opt, acc0), jnp.arange(E * S),
                                       unroll=self.scan_unroll)
         ms = {"loss_sum": acc[0], "score_sum": acc[1], "n": acc[2]}
@@ -1761,7 +1761,7 @@ class RoundEngine(_WireCodecCarry, _SchedBufCarry):
             """Probe leaves out of a fetched metrics tree (ISSUE 10):
             telemetry-off trees pass through untouched (None probes)."""
             if obs_on:
-                return split_probes(host, n_dev)
+                return split_probes(host, n_dev, counters=self.model.meta.get("counters"))
             return host, None
 
         if eval_mask is None:

@@ -33,8 +33,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from heterofl_tpu.models.decoder import gq_attention  # noqa: E402
 from heterofl_tpu.models.kanana2 import latent_attention, latent_attention_shapes  # noqa: E402
-from heterofl_tpu.models.lfm2 import gq_attention  # noqa: E402
 from heterofl_tpu.ops import layers as L  # noqa: E402
 from heterofl_tpu.ops import pallas_attention as PA  # noqa: E402
 
@@ -103,7 +103,7 @@ def gq_fused(tq, tk=None):
 
 
 def gq_block(tile):
-    """`models.lfm2.gq_attention` with the kernels at ``tile``, or with the
+    """`models.decoder.gq_attention` with the kernels at ``tile``, or with the
     block loop (None): the script answers the one question the layer asks."""
     def f(lp, h, scale):
         asked, PA.gq_tile_for = PA.gq_tile_for, lambda *a: tile

@@ -9,7 +9,7 @@ layer's SELECTED ATTENTION under layer 0's selection, the `jnp` block loop
 (`ops.layers.blockwise_gq_attention(select=)`) against the fused kernels
 (`ops/pallas_attention.py` `sel_attn_fwd` / `sel_attn_bwd`) at the tile the
 rule gives (`sel_tile_for`) and at any others named, forward and forward +
-backward, alone and inside the layer's attention BLOCK (`models.lfm2.
+backward, alone and inside the layer's attention BLOCK (`models.decoder.
 gq_attention`: projections, head norms, turn, attention, output projection,
 under `jax.checkpoint` as a layer runs it), which is where a kernel's operand
 layout shows in its neighbours.  Four minutes.  `keep` instead times ONE
@@ -48,8 +48,7 @@ from benchmark.reference import common, keye as ref  # noqa: E402
 from heterofl_tpu import config as C  # noqa: E402
 from heterofl_tpu.entry.common import build_cli, cfg_from_args  # noqa: E402
 from heterofl_tpu.models import keye, make_model  # noqa: E402
-from heterofl_tpu.models.base import layer_leaves  # noqa: E402
-from heterofl_tpu.models.lfm2 import gq_attention  # noqa: E402
+from heterofl_tpu.models.decoder import gq_attention, layer_leaves  # noqa: E402
 from heterofl_tpu.ops import layers as L  # noqa: E402
 from heterofl_tpu.ops import pallas_attention as PA  # noqa: E402
 

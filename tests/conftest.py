@@ -42,6 +42,29 @@ if not os.path.isdir(jax.config.jax_compilation_cache_dir):
         f"persistent compile cache dir {jax.config.jax_compilation_cache_dir!r} "
         f"does not exist")
 
+# The workers of one run share that directory, and jax writes an entry in
+# place (`LRUCache.put`: `exists()`, then `write_bytes`): a worker that looks
+# a program up while another is writing it reads half an executable and dies
+# in the deserialiser (seen: a segfault under `compiler._cache_read`).  Write
+# under a name of the worker's own and rename: a reader finds no entry or a
+# whole one.  With eviction on, jax's own file lock covers both sides.
+from jax._src import lru_cache as _lru  # noqa: E402
+
+_put_in_place = _lru.LRUCache.put
+
+
+def _put_whole(self, key, val):
+    if self.eviction_enabled or not key:
+        return _put_in_place(self, key, val)
+    path = self.path / f"{key}{_lru._CACHE_SUFFIX}"
+    if not path.exists():
+        mine = path.with_name(f"{path.name}.{os.getpid()}.tmp")
+        mine.write_bytes(val)
+        os.replace(mine, path)
+
+
+_lru.LRUCache.put = _put_whole
+
 import warnings  # noqa: E402
 
 # JAX donation warnings are ERRORS in the gate (ISSUE 3 satellite): a

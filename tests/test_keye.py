@@ -6,7 +6,7 @@ slicing rules, and through the engines and the entry point.  A file of its
 own so that the test runner's per-file workers share the family's compiles
 evenly."""
 
-import json
+import functools
 import re
 
 import jax
@@ -14,105 +14,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decoder_cases import case, masked_loss_and_grads, round_case
 from heterofl_tpu import config as C
 from heterofl_tpu.models import make_model
-from heterofl_tpu.models.spec import Group, count_masks, mask_params
+from heterofl_tpu.models.spec import mask_params
 from heterofl_tpu.ops import layers as L
 from heterofl_tpu.parallel import RoundEngine, make_mesh
 
-LEVELS = [1.0, 0.5, 0.25, 0.125, 0.0625]
-
-
-def _keye_case(seed=1, bptt=None, **arch):
-    """(cfg, model, seeded params with the gains and the indexer's LayerNorm
-    bias moved off their constants, tokens, a label mask with holes, the
-    reference's model description)."""
-    from benchmark.tests import tiny_keye as tiny
-
-    cfg = tiny.program_cfg(bptt=bptt or tiny.BPTT, **arch)
-    model = make_model(cfg)
-    params = model.init(jax.random.key(seed))
-    keys = jax.random.split(jax.random.key(seed + 1), len(params))
-    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
-              for (k, v), kk in zip(sorted(params.items()), keys)}
-    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
-                                cfg["num_tokens"])
-    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
-    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
-
-
-def _masked_loss_and_grads(model, params, tokens, lm, rate):
-    def system_loss(p):
-        pm = mask_params(p, model.specs, model.groups, rate)
-        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
-                             scaler_rate=rate, label_mask=lm)
-        return out["loss"]
-
-    return jax.value_and_grad(system_loss)(params)
+_keye_case = functools.partial(case, "keye")
+_masked_loss_and_grads = masked_loss_and_grads
+_round_case = functools.partial(round_case, "keye")
 
 
 # ---------------------------------------------------------------------------
 # the model against the benchmark's plain reference
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_keye_masked_model_is_the_references_dense_submodel(rate):
-    """Loss and gradients of the masked full-width model at rate r against the
-    plain reference on the sliced sub-model: rate 1 is the published layer
-    (half-split RoPE on the un-permuted heads, repeated key/value heads,
-    `lax.top_k` for the selection), every other level HeteroFL's slice of it.
-    float32 on both sides, so the two differ by summation order alone --
-    amplified by the Scaler's 1/r and, at a near-tie of two router or indexer
-    scores, by a different choice; 1e-3 of a leaf's largest gradient holds
-    both, and a bfloat16 product, a softmax over every causal key or a
-    mis-sliced head is off by 1e-2 or more.  The indexer's leaves get exactly
-    zero, inside the slice and outside."""
-    from benchmark.reference import common, keye as ref
-
-    cfg, model, params, tokens, lm, rm = _keye_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm)))(sub)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in ref_grads.items():
-        g = np.asarray(g)
-        np.testing.assert_allclose(inside[k], g, atol=1e-3 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
-        outside = np.ones(grads[k].shape, bool)
-        outside[np.ix_(*index[k])] = False
-        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
-        if ".idx." in k:  # frozen by construction, in program and reference alike
-            assert not np.asarray(grads[k]).any() and not g.any(), k
-    assert sum(".idx." in k for k in grads) == 5 * cfg["keye"]["num_hidden_layers"]
-
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_keye_sliced_submodel_is_the_masked_model(rate):
-    """HeteroFL's equivalence inside the program: the dense sub-model built at
-    rate r (`make_model(cfg, r)`, what the grouped and sliced engines train)
-    on the slice of the parameters gives the masked full-width model's loss
-    and, inside the slice, its gradients; same float32 sums in another order,
-    so 1e-5 relative on the loss and 1e-4 of a leaf's largest gradient."""
-    from benchmark.reference import common, keye as ref
-
-    cfg, model, params, tokens, lm, rm = _keye_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    small = make_model(cfg, rate)
-    assert {k: tuple(v.shape) for k, v in sub.items()} == small.meta["shapes"]
-    sub_loss, sub_grads = jax.value_and_grad(lambda p: small.apply(
-        p, {"label": tokens}, train=True, scaler_rate=rate, label_mask=lm)[0]["loss"])(sub)
-    np.testing.assert_allclose(float(sub_loss), float(loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in sub_grads.items():
-        g = np.asarray(g)
-        np.testing.assert_allclose(inside[k], g, atol=1e-4 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
-
 
 @pytest.mark.parametrize("level", ["a", "c", "e"])
 def test_keye_one_whole_local_step_is_the_references(level):
@@ -406,8 +322,9 @@ def test_keye_sparse_saved_counts_the_selecting_blocks(bptt, blocks):
     out, _ = model.apply(params, {"label": tokens}, train=True)
     saved = out["counters"]["sparse_saved"]
     assert [float(c) for c in saved] == [2.0 * 2 * blocks] * 2
-    assert model.meta["counters"]["sparse_saved"] == (2,)
-    _, rounds = split_probes({"obs_sparse_saved": np.asarray(saved)}, 1)
+    assert model.meta["counters"]["sparse_saved"] == ((2,), "ratio")
+    _, rounds = split_probes({"obs_sparse_saved": np.asarray(saved)}, 1,
+                             counters=model.meta["counters"])
     assert rounds[0]["sparse_saved"] == (1.0 if blocks else 0.0)
 
 
@@ -477,13 +394,13 @@ def _gq_layer(h, wq, wk, wv, wo, gq, gk, head_norm="rms"):
     """A layer's grouped-query attention as the LFM2 and Keye models call it
     (4 query heads on 2 key/value heads of 6, an RMSNorm on every head); with
     ``head_norm`` None, as Ouro calls it."""
-    from heterofl_tpu.models import lfm2
+    from heterofl_tpu.models import decoder
 
     lp = {"attn.q.w": wq, "attn.k.w": wk, "attn.v.w": wv, "attn.o.w": wo,
           "attn.q_norm.g": gq, "attn.k_norm.g": gk}
     norm = None if head_norm is None else (
         lambda x, g: L.masked_rms_norm(x, g, jnp.ones(6), 6.0, 1e-5))
-    return lfm2.gq_attention(lp, h, heads=4, kv_heads=2, head_dim=6, theta=1e4, scale=0.4,
+    return decoder.gq_attention(lp, h, heads=4, kv_heads=2, head_dim=6, theta=1e4, scale=0.4,
                              sc=lambda x: x / 0.5, head_norm=norm)
 
 
@@ -528,7 +445,7 @@ def test_the_block_loop_and_the_router_give_their_callers_what_the_parent_gave(n
     got, got_text = run()
     monkeypatch.setattr(L, "_causal_blocks", lambda *a: _parent_causal_blocks(*a[:7]))
     monkeypatch.setattr(L, "moe_route", _parent_moe_route)
-    monkeypatch.setattr("heterofl_tpu.models.lfm2.gq_attention", _parent_gq_attention)
+    monkeypatch.setattr("heterofl_tpu.models.decoder.gq_attention", _parent_gq_attention)
     want, want_text = run()
     assert got_text == want_text
     for a, b in zip(got, want):
@@ -565,202 +482,9 @@ def test_gq_attention_without_a_head_norm_is_the_layer_with_an_identity_for_one(
 # slicing: two head families, the untied leaves
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rate", LEVELS)
-def test_keye_both_head_families_keep_equal_dims_and_whole_pairs(rate):
-    """The attention's 4 query heads, 2 key/value heads and head norms keep
-    the SAME dims of a head at every level, in whole rotary pairs, and so do
-    -- with a head size of their own -- the indexer's 4 query heads, its key
-    head and that head's LayerNorm; the indexer's per-head weights and the
-    router's columns are never cut; the geometry check holds each family."""
-    from heterofl_tpu.fed.core import validate_width_geometry
-
-    cfg, model, _, _, _, _ = _keye_case()
-    for family, names, hd in (("head", ("q_head", "kv_head", "head"), 16),
-                              ("index", ("iq_head", "ik_head"), 8)):
-        kept = {}
-        for name in names:
-            g = model.groups[name]
-            assert g.family == family
-            m = np.asarray(g.mask(rate)).reshape(g.num_heads, hd)
-            assert (m == m[0]).all(), name  # every head alike
-            k = int(m[0].sum())
-            assert m[0, :k].all() and k % 2 == 0, (name, k)  # a prefix of whole pairs
-            assert int(g.active_count(rate)) == g.num_heads * k
-            kept[name] = k
-        assert set(kept.values()) == {max(2, int(np.ceil(hd * rate)))}
-    for never in ("index", "router"):
-        assert np.asarray(model.groups[never].mask(rate)).all()
-    validate_width_geometry(model, cfg)
-    model.groups["ik_head"] = Group("ik_head", 8, kind="per_head", num_heads=1, multiple=1,
-                                    coupled=False, family="index")
-    with pytest.raises(ValueError, match="head family 'index' is inconsistent at rate 0.0625"):
-        validate_width_geometry(model, cfg)
-
-
-def test_keye_counts_follow_width_and_labels():
-    """A client counts for every element of its slice (the indexer's leaves
-    too, though no gradient moves them); embedding rows and head columns
-    follow the labels the client holds."""
-    from benchmark.reference import keye as ref
-    from benchmark.tests import tiny_keye as tiny
-
-    cfg = tiny.program_cfg()
-    model = make_model(cfg)
-    shapes = dict(model.meta["shapes"])
-    assert ref.LABEL_AXES == {k: s.label_axis for k, s in model.specs.items()
-                              if s.label_axis is not None}
-    labels = np.zeros(cfg["num_tokens"], np.float32)
-    labels[::3] = 1.0
-    for rate in (1.0, 0.25, 0.0625):
-        cm = count_masks(shapes, model.specs, model.groups, rate, jnp.asarray(labels))
-        index = ref.index(shapes, tiny.reference_model(cfg), rate)
-        for k, shape in shapes.items():
-            want = np.zeros(shape, np.float32)
-            want[np.ix_(*index[k])] = 1.0
-            if k in ref.LABEL_AXES:
-                view = [1] * len(shape)
-                view[ref.LABEL_AXES[k]] = -1
-                want = want * labels.reshape(view)
-            np.testing.assert_array_equal(np.asarray(cm[k]), want, err_msg=f"{k} @ {rate}")
-        assert np.asarray(cm["l1.moe.router.w"]).sum(axis=0).min() > 0  # all 8 columns
-        assert np.asarray(cm["l1.idx.w.w"]).sum(axis=0).min() > 0      # all 4 weights
-
-
-def test_level_tables_know_the_keye_family():
-    """`level_param_table` counts the sliced sub-model's own leaves and the
-    FLOP table falls with the level."""
-    from benchmark.tests import tiny_keye as tiny
-    from heterofl_tpu.fed.core import level_flop_table, level_param_table
-
-    cfg = tiny.program_cfg()
-    for rate, n in level_param_table(cfg).items():
-        shapes = jax.eval_shape(make_model(cfg, rate).init, jax.random.key(0))
-        assert n == sum(int(np.prod(v.shape)) for v in shapes.values()), rate
-    flops = level_flop_table(cfg)
-    assert sorted(flops.values(), reverse=True) == [flops[r] for r in sorted(flops, reverse=True)]
-
-
 # ---------------------------------------------------------------------------
 # through the engines and the entry point
 # ---------------------------------------------------------------------------
-
-def _round_case():
-    """(cfg, data) of 8 users with 2 rows of 64 tokens each; every client
-    lacks every fifth token and nobody holds token 3 or 4."""
-    from benchmark.tests import tiny_keye as tiny
-
-    cfg = tiny.program_cfg(control="1_8_0.5_iid_fix_a1-b1-c1-e1_bn_1_1")
-    vocab = cfg["num_tokens"]
-    rows = np.random.default_rng(0).integers(5, vocab, size=(8, 2, 64)).astype(np.int64)
-    lm = np.ones((8, vocab), np.float32)
-    lm[:, :5] = 0.0
-    lm[:, ::5] = 0.0
-    return cfg, (jnp.asarray(rows), jnp.asarray(lm))
-
-
-def _round(cfg, data, chunk, n_dev=1, users=np.arange(8), **extra):
-    cfg = dict(cfg, round_chunk=chunk, **extra)
-    model = make_model(cfg)
-    eng = RoundEngine(model, cfg, make_mesh(n_dev, 1))
-    params0 = model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params0.items()}  # the round donates its input
-    out, ms = eng.train_round(params0, jax.random.key(5), 0.5, users, data)
-    return (before, {k: np.asarray(v) for k, v in out.items()},
-            {k: np.asarray(v) for k, v in ms.items()})
-
-
-@pytest.fixture(scope="module")
-def masked_round():
-    cfg, data = _round_case()
-    return (cfg, data) + _round(cfg, data, 1)
-
-
-def test_keye_masked_round_in_chunks_of_one_is_the_unchunked_round(masked_round):
-    """`round_chunk` 1, the cell's setting: one slot at a time is the round of
-    one vmap over all 8 slots up to the order of float32 sums (1e-5 relative
-    / 1e-6 absolute; a lost or doubled slot is off by 1e-2)."""
-    cfg, data, _, out, ms = masked_round
-    _, base, base_ms = _round(cfg, data, None)
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], rtol=1e-5, atol=1e-6, err_msg=k)
-    for k in ("loss_sum", "n", "rate"):
-        np.testing.assert_allclose(ms[k], base_ms[k], rtol=1e-5)
-    assert np.isfinite(ms["loss_sum"]).all() and (ms["n"] == 2).all()
-
-
-def test_keye_a_level_e_round_leaves_everything_outside_its_slice(masked_round):
-    """The slicing round-trips: a round of the smallest level alone moves
-    entries inside its slice -- the indexer's by weight decay, which the
-    frozen leaves are not spared -- and leaves everything outside bit for bit,
-    indexer included; rows of tokens nobody holds come back as they were."""
-    from benchmark.reference import keye as ref
-    from benchmark.tests import tiny_keye as tiny
-
-    cfg, data, before, out, _ = masked_round
-    held = np.asarray(data[1]).max(axis=0) > 0
-    changed = out["embedding.tok.w"] != before["embedding.tok.w"]
-    assert not changed[~held].any() and changed[held].any(axis=1).all()
-    small = [u for u in range(8) if cfg["model_rate"][u] == min(cfg["model_rate"])]
-    _, new, _ = _round(cfg, data, 1, users=np.resize(small, 8))
-    index = ref.index({k: v.shape for k, v in before.items()}, tiny.reference_model(cfg),
-                      min(cfg["model_rate"]))
-    for k, b in before.items():
-        inside = np.zeros(b.shape, bool)
-        inside[np.ix_(*index[k])] = True
-        moved = new[k] != b
-        assert not moved[~inside].any(), k
-        assert moved[inside].any() or k.endswith(".b"), k  # a zero bias decays to zero
-    assert (new["l0.idx.q.w"] != before["l0.idx.q.w"]).any()
-
-
-def test_keye_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_round):
-    """The grouped engine's per-level dense programs take the family as any
-    other (no validator tests a model's name): its round is the masked
-    engine's up to the order of float32 sums through a step at lr 0.5.  What
-    it lacks is the chunked cohort, refused by key at config resolution."""
-    from heterofl_tpu.parallel.grouped import GroupedRoundEngine
-
-    cfg, data, _, base, _ = masked_round
-    cfg = dict(cfg, strategy="grouped")
-    model, users = make_model(cfg), np.arange(8)
-    rates = np.asarray([cfg["model_rate"][u] for u in users], np.float32)
-    out = GroupedRoundEngine(cfg, make_mesh(1, 1)).train_round(
-        model.init(jax.random.key(0)), users, rates, data, 0.5, jax.random.key(5))[0]
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], atol=5e-3, err_msg=k)
-    with pytest.raises(ValueError, match="round_chunk"):
-        C.resolve_chunk_cfg(dict(cfg, round_chunk=1))
-
-
-def test_keye_counters_ride_the_metrics():
-    """telemetry='on' carries the indexer's counters out beside the expert
-    layers': `obs_sparse_selected`, `obs_sparse_kept_share`,
-    `obs_sparse_fused` and `obs_sparse_saved`, each a (numerator, denominator)
-    pair of sums a device, finished by `obs.split_probes` as keys selected a
-    query, selected over causal pairs -- at 64 positions and ``topk`` 16: 904
-    / 64 and 904 / 2,080 -- the share of the selected attention's query tiles
-    that went through the fused kernels: none on the CPU -- and the share of
-    the selecting query blocks whose choice the layer kept: all."""
-    from heterofl_tpu.obs import split_probes
-
-    cfg, data = _round_case()
-    _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
-    assert ms["obs_sparse_selected"].shape == ms["obs_sparse_kept_share"].shape == (2 * 2,)
-    # 8 clients x 2 layers x 2 rows x 4 query blocks of 16, none through the kernels
-    assert ms["obs_sparse_fused"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 2 * 2 * 4]
-    # of those four blocks the three that end after topk select, and their choice is kept
-    assert ms["obs_sparse_saved"].reshape(2, 2).sum(axis=0).tolist() == [8 * 2 * 2 * 3] * 2
-    assert ms["obs_moe_tokens"].shape == (2 * 4,) and ms["obs_moe_assign"].shape == (2 * 3,)
-    clean, rounds = split_probes(dict(ms), 2)
-    rec = rounds[0]
-    assert rec["sparse_selected"] == pytest.approx(904 / 64, rel=1e-6)
-    assert rec["sparse_kept_share"] == pytest.approx(904 / 2080, rel=1e-6)
-    assert rec["sparse_fused"] == 0.0 and rec["sparse_saved"] == 1.0
-    # 8 clients x 1 step x (2 rows x 64 tokens) x top-2, in each of 2 layers
-    assert rec["moe_assign"][0] == 8 * 128 * 2 * 2
-    assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
-    assert not [k for k in clean if k.startswith("obs_")]
-
 
 def test_keye_model_takes_the_selected_kernels_where_a_tpu_gives_them_tiles(monkeypatch):
     """The model at shapes the fused kernels tile (heads of 128, rows of 256
@@ -791,7 +515,8 @@ def test_keye_model_takes_the_selected_kernels_where_a_tpu_gives_them_tiles(monk
                         partial(PA.fused_selected_attention, interpret=True))
     got, got_grads, counters = loss_grads_counters()
     assert [float(c) for c in counters["sparse_fused"]] == [8.0, 8.0]
-    _, rounds = split_probes({"obs_sparse_fused": np.asarray(counters["sparse_fused"])}, 1)
+    _, rounds = split_probes({"obs_sparse_fused": np.asarray(counters["sparse_fused"])}, 1,
+                             counters=model.meta["counters"])
     assert rounds[0]["sparse_fused"] == 1.0
     assert float(got) == pytest.approx(float(want), rel=2e-3)
     for name, w in want_grads.items():
@@ -824,48 +549,6 @@ def test_keye_gradient_on_the_kernels_runs_one_forward_kernel_a_layer(policy, ke
                   if e.primitive.name == "pallas_call") == kernels
 
 
-def test_keye_trains_and_evaluates_through_the_entry_point(tmp_path):
-    """One whole `FedExperiment.train_round` (masked engine, `round_chunk` 1)
-    and one `evaluate`, built as `entry.common.run_main` builds them from the
-    command line: `--model_name keye` is all that names the family."""
-    from benchmark.tests import tiny_keye as tiny
-    from heterofl_tpu.entry.common import FedExperiment, build_cli, cfg_from_args
-    from heterofl_tpu.utils.logger import Logger
-
-    override = {"keye": dict(tiny.ARCH), "bptt": 64,
-                "batch_size": {"train": 20, "test": 10}, "round_chunk": 1,
-                "num_epochs": {"global": 2, "local": 1}}
-    argv = ["--control_name", "1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1",
-            "--model_name", "keye", "--data_name", "WikiText2", "--synthetic", "1",
-            "--synthetic_sizes", json.dumps({"train": 20 * 64, "test": 10 * 64}),
-            "--mesh", json.dumps({"clients": 1, "data": 1}),
-            "--output_dir", str(tmp_path), "--override", json.dumps(override)]
-    cfg = C.process_control(cfg_from_args(build_cli("test").parse_args(argv)))
-    exp = FedExperiment(cfg, cfg["init_seed"])
-    assert exp.kind == "transformer" and exp.engine.is_lm and exp.engine._chunk == 1
-    data_split, label_split = exp.make_splits()
-    exp.stage(data_split, label_split)
-    logger = Logger(str(tmp_path / "log"))
-    params = exp.model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params.items()}
-    params = exp.train_round(params, 1, 0.1, logger)
-    moved = [k for k, v in params.items() if not np.array_equal(np.asarray(v), before[k])]
-    assert len(moved) > len(before) // 2
-    named = exp.evaluate(params, 1, logger, label_split)
-    assert np.isfinite(named["Global-Loss"]) and named["Global-Perplexity"] > 1.0
-
-
-def test_keye_tiny_cell_is_correct_and_its_control_is_not(monkeypatch, capsys):
-    """`benchmark/checks.compare` on the tiny configuration, through the
-    benchmark's own command: sound as returned, not `correct` once the check
-    rounds' result has passed through bfloat16 (the test lives with the
-    benchmark's; run here so that the gate holds it)."""
-    from benchmark.tests import test_keye
-
-    test_keye.test_a_sound_run_of_the_tiny_cell_is_correct_and_the_control_is_not(
-        monkeypatch, capsys)
-
-
 def test_the_cut_configuration_has_the_parameters_it_states():
     """373,546,880: five layers of 59,150,720 (attention 18,874,624 with its
     head norms, indexer 2,261,120, router 262,144, eight experts of
@@ -880,7 +563,7 @@ def test_the_cut_configuration_has_the_parameters_it_states():
 # the scopes ISSUE 35 added (obs.trace.SPARSE_SCOPES)
 # ---------------------------------------------------------------------------
 
-def test_the_indexer_carries_its_names(masked_round):
+def test_the_indexer_carries_its_names():
     """`sparse/index` and `sparse/select` reach the round program's `op_name`s
     under `step/model` in the forward only: the indexer has no backward, and
     the layer's recomputation runs none of it since the layer keeps the
@@ -894,7 +577,7 @@ def test_the_indexer_carries_its_names(masked_round):
     assert not set(trace.SPARSE_SCOPES) & set(
         trace.SCOPES + trace.EXTRA_SCOPES + trace.MIXER_SCOPES)
     assert trace.SCOPE_VERSION >= 5  # bumped with the new names (the compile cache's key)
-    cfg, data = masked_round[:2]
+    cfg, data = _round_case()
     cfg = dict(cfg, round_chunk=1)
     model = make_model(cfg)
     eng = RoundEngine(model, cfg, make_mesh(1, 1))

@@ -8,7 +8,6 @@ through the engines and the entry point.  A file of its own so that the test
 runner's per-file workers share the family's compiles evenly."""
 
 import functools
-import json
 import re
 
 import jax
@@ -16,87 +15,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decoder_cases import case, masked_loss_and_grads, round_case, tiny_case
 from heterofl_tpu import config as C
 from heterofl_tpu.models import make_model
-from heterofl_tpu.models.spec import count_masks, mask_params
 from heterofl_tpu.ops import layers as L
 from heterofl_tpu.parallel import RoundEngine, make_mesh
 
-LEVELS = [1.0, 0.5, 0.25, 0.125, 0.0625]
-
-
-def _laguna_case(seed=1, bptt=None, **arch):
-    """(cfg, model, seeded params with the gains moved off 1, tokens, a label
-    mask with holes, the reference's model description)."""
-    from benchmark.tests import tiny_laguna as tiny
-
-    cfg = tiny.program_cfg(bptt=bptt or tiny.BPTT, **arch)
-    model = make_model(cfg)
-    params = model.init(jax.random.key(seed))
-    keys = jax.random.split(jax.random.key(seed + 1), len(params))
-    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
-              for (k, v), kk in zip(sorted(params.items()), keys)}
-    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
-                                cfg["num_tokens"])
-    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
-    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
-
-
-def _masked_loss_and_grads(model, params, tokens, lm, rate):
-    """Loss and gradients of the masked full-width model at ``rate``, which is
-    traced as the engines trace it: one program for every level."""
-    def system_loss(p, rate):
-        pm = mask_params(p, model.specs, model.groups, rate)
-        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
-                             scaler_rate=rate, label_mask=lm)
-        return out["loss"]
-
-    if "masked" not in model.meta:  # the case's own program, compiled once
-        model.meta["masked"] = jax.jit(jax.value_and_grad(system_loss))
-    return model.meta["masked"](params, jnp.float32(rate))
-
-
-@functools.lru_cache(maxsize=None)
-def _tiny_case():
-    return _laguna_case()
+_laguna_case = functools.partial(case, "laguna")
+_masked_loss_and_grads = masked_loss_and_grads
+_tiny_case = functools.partial(tiny_case, "laguna")
+_round_case = functools.partial(round_case, "laguna")
 
 
 # ---------------------------------------------------------------------------
 # the model against the benchmark's plain reference
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_laguna_masked_model_is_the_references_dense_submodel(rate):
-    """Loss and gradients of the masked full-width model at rate r against the
-    plain reference on the sliced sub-model: rate 1 is the published model
-    (both masks as boolean matrices, half-split RoPE on the un-permuted heads
-    with the reference's own YaRN table, the gate, one expert at a time),
-    every other level HeteroFL's slice of it.  float32 on both sides, so the
-    two differ by summation order alone, amplified by the Scaler's 1/r; 1e-3
-    of a leaf's largest gradient holds levels a-d and 1e-2 level e, where a
-    norm runs over 8 dims (6e-3 is the most it reads); a bfloat16 product, a
-    window off by one, a mis-sliced head or a table of the wrong kind is off
-    by 3e-2 or more."""
-    from benchmark.reference import common, laguna as ref
-
-    cfg, model, params, tokens, lm, rm = _tiny_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm))))(sub)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    tol = 1e-2 if rate < 0.1 else 1e-3
-    for k, g in ref_grads.items():
-        g = np.asarray(g)
-        # every leaf is trained, the gate too (an expert no token reached apart)
-        assert np.abs(g).max() > 0 or ".moe.e" in k, k
-        np.testing.assert_allclose(inside[k], g, atol=tol * np.abs(g).max() + 1e-9, err_msg=k)
-        outside = np.ones(grads[k].shape, bool)
-        outside[np.ix_(*index[k])] = False
-        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
-
 
 def test_the_references_step_a_part_at_a_time_is_its_whole_gradient():
     """`benchmark/reference/laguna.py` trains with `loss_and_grads`, the chain
@@ -414,61 +347,6 @@ def test_rope_interleaved_with_no_table_is_what_it_was():
 # slicing
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rate", LEVELS)
-def test_laguna_heads_keep_equal_dims_and_whole_pairs(rate):
-    """Each family of head groups (a kind's rotary dims, a full layer's
-    pass-through dims, the value heads and the output projection's rows) keeps
-    the SAME dims a head at every level, the rotary ones in whole pairs; the
-    gates' columns, the router's and the expert axis are never cut; the
-    geometry check holds the families."""
-    from heterofl_tpu.fed.core import validate_width_geometry
-
-    cfg, model, _, _, _, _ = _laguna_case()
-    families = {}
-    for name, g in model.groups.items():
-        if g.kind == "per_head":
-            hd = g.size // g.num_heads
-            m = np.asarray(g.mask(rate)).reshape(g.num_heads, hd)
-            assert (m == m[0]).all(), name  # every head alike
-            k = int(m[0].sum())
-            assert m[0, :k].all() and k % g.multiple == 0, (name, k)  # a prefix, whole pairs
-            assert int(g.active_count(rate)) == g.num_heads * k
-            families.setdefault(g.family, set()).add(k)
-    want = {"full.rope": max(2, int(np.ceil(16 * rate))), "full.nope": int(np.ceil(16 * rate)),
-            "sliding.rope": max(2, int(np.ceil(32 * rate))), "head": max(2, int(np.ceil(32 * rate)))}
-    assert families == {k: {v} for k, v in want.items()}
-    for name in ("full6.gate", "sliding8.gate", "router"):
-        assert np.asarray(model.groups[name].mask(rate)).all()
-    validate_width_geometry(model, cfg)
-
-
-def test_laguna_counts_follow_width_and_labels():
-    """A client counts for every element of its slice -- for an expert it holds
-    whether or not a token reached it --; embedding rows and head columns
-    follow the labels the client holds."""
-    from benchmark.reference import laguna as ref
-    from benchmark.tests import tiny_laguna as tiny
-
-    cfg = tiny.program_cfg()
-    model = make_model(cfg)
-    shapes = dict(model.meta["shapes"])
-    assert ref.LABEL_AXES == {k: s.label_axis for k, s in model.specs.items()
-                              if s.label_axis is not None}
-    labels = np.zeros(cfg["num_tokens"], np.float32)
-    labels[::3] = 1.0
-    for rate in (1.0, 0.25, 0.0625):
-        cm = count_masks(shapes, model.specs, model.groups, rate, jnp.asarray(labels))
-        index = ref.index(shapes, tiny.reference_model(cfg), rate)
-        for k, shape in shapes.items():
-            want = np.zeros(shape, np.float32)
-            want[np.ix_(*index[k])] = 1.0
-            if k in ref.LABEL_AXES:
-                view = [1] * len(shape)
-                view[ref.LABEL_AXES[k]] = -1
-                want = want * labels.reshape(view)
-            np.testing.assert_array_equal(np.asarray(cm[k]), want, err_msg=f"{k} @ {rate}")
-
-
 def test_the_lists_are_read_and_no_period_is_assumed():
     """The layer kinds, head counts and feed-forwards come from the three
     lists: any order builds (two sliding layers first, the dense layer last)
@@ -493,71 +371,9 @@ def test_the_lists_are_read_and_no_period_is_assumed():
             make_model(tiny.program_cfg(**bad))
 
 
-def test_level_tables_know_the_family_and_count_attention_by_kind():
-    """`level_param_table` counts the sliced sub-model's own leaves, the FLOP
-    table falls with the level, and `analysis.summary.module_table` reads a
-    site's window from ``meta["profile"]``: its matmul rows hold
-    `benchmark/flops/laguna.py`'s forward FLOPs at rate 1, a sliding layer's
-    two products over the band pairs at its 8 heads, a full layer's over the
-    causal pairs at its 6."""
-    from benchmark import harness
-    from benchmark.tests import tiny_laguna as tiny
-    from heterofl_tpu.analysis.summary import module_table
-    from heterofl_tpu.fed.core import level_flop_table, level_param_table
-
-    cfg = tiny.program_cfg()
-    for rate, n in level_param_table(cfg).items():
-        shapes = jax.eval_shape(make_model(cfg, rate).init, jax.random.key(0))
-        assert n == sum(int(np.prod(v.shape)) for v in shapes.values()), rate
-    table = level_flop_table(cfg)
-    assert sorted(table.values(), reverse=True) == [table[r] for r in sorted(table, reverse=True)]
-    flops = harness.load_module("flops", "laguna")
-    model, rows = tiny.reference_model(cfg), 2
-    by_name = {r[0]: r for r in module_table(cfg, 1.0, rows)}
-    macs = sum(r[4] for name, r in by_name.items()
-               if name != "embedding" and not re.search(r"norm\d*\.g$", name))
-    assert 2 * macs == rows * flops.forward_flops(model, 1.0)
-    band, causal = 16 * 17 // 2 + 48 * 16, 64 * 65 // 2
-    assert flops.band_pairs(model) == band and flops.causal_pairs(model) == causal
-    assert by_name["l1.attn.qk"][4] == rows * 8 * band * 32
-    assert by_name["l0.attn.av"][4] == by_name["l4.attn.qk"][4] == rows * 6 * causal * 32
-
-
 # ---------------------------------------------------------------------------
 # through the engines and the entry point
 # ---------------------------------------------------------------------------
-
-def _round_case():
-    """(cfg, data) of 8 users with 2 rows of 32 tokens each (a window of 16
-    binds); every client lacks every fifth token and nobody holds token 3 or
-    4."""
-    from benchmark.tests import tiny_laguna as tiny
-
-    cfg = tiny.program_cfg(control="1_8_0.5_iid_fix_a1-e1_bn_1_1", bptt=32)
-    vocab = cfg["num_tokens"]
-    rows = np.random.default_rng(0).integers(5, vocab, size=(8, 2, 32)).astype(np.int64)
-    lm = np.ones((8, vocab), np.float32)
-    lm[:, :5] = 0.0
-    lm[:, ::5] = 0.0
-    return cfg, (jnp.asarray(rows), jnp.asarray(lm))
-
-
-def _round(cfg, data, chunk, n_dev=1, users=np.arange(8), **extra):
-    cfg = dict(cfg, round_chunk=chunk, **extra)
-    model = make_model(cfg)
-    eng = RoundEngine(model, cfg, make_mesh(n_dev, 1))
-    params0 = model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params0.items()}  # the round donates its input
-    out, ms = eng.train_round(params0, jax.random.key(5), 0.5, users, data)
-    return (before, {k: np.asarray(v) for k, v in out.items()},
-            {k: np.asarray(v) for k, v in ms.items()})
-
-
-@pytest.fixture(scope="module")
-def masked_round():
-    cfg, data = _round_case()
-    return (cfg, data) + _round(cfg, data, 1)
-
 
 @pytest.mark.parametrize("level", ["a", "e"])
 def test_laguna_one_whole_local_step_is_the_references(level):
@@ -592,62 +408,6 @@ def test_laguna_one_whole_local_step_is_the_references(level):
         assert (np.asarray(out[k]) != before[k]).any(), k
 
 
-def test_laguna_masked_round_in_chunks_of_one_is_the_unchunked_round(masked_round):
-    """`round_chunk` 1, the cell's setting: one slot at a time is the round of
-    one vmap over all 8 slots up to the order of float32 sums."""
-    cfg, data, _, out, ms = masked_round
-    _, base, base_ms = _round(cfg, data, None)
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], rtol=1e-5, atol=3e-6, err_msg=k)
-    for k in ("loss_sum", "n", "rate"):
-        np.testing.assert_allclose(ms[k], base_ms[k], rtol=1e-5)
-    assert np.isfinite(ms["loss_sum"]).all() and (ms["n"] == 2).all()
-
-
-def test_laguna_a_level_e_round_leaves_everything_outside_its_slice(masked_round):
-    """The slicing round-trips: a round of the smallest level alone moves
-    entries inside its slice and leaves everything outside bit for bit; rows
-    of tokens nobody holds come back as they were."""
-    from benchmark.reference import laguna as ref
-    from benchmark.tests import tiny_laguna as tiny
-
-    cfg, data, before, out, _ = masked_round
-    held = np.asarray(data[1]).max(axis=0) > 0
-    changed = out["embedding.tok.w"] != before["embedding.tok.w"]
-    assert not changed[~held].any() and changed[held].any(axis=1).all()
-    changed = out["head.w"] != before["head.w"]
-    assert not changed[:, ~held].any() and changed[:, held].any(axis=0).all()
-    small = [u for u in range(8) if cfg["model_rate"][u] == min(cfg["model_rate"])]
-    _, new, _ = _round(cfg, data, 1, users=np.resize(small, 8))
-    index = ref.index({k: v.shape for k, v in before.items()}, tiny.reference_model(cfg),
-                      min(cfg["model_rate"]))
-    for k, b in before.items():
-        inside = np.zeros(b.shape, bool)
-        inside[np.ix_(*index[k])] = True
-        moved = new[k] != b
-        assert not moved[~inside].any(), k
-        assert moved[inside].any(), k
-
-
-def test_laguna_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_round):
-    """The grouped engine's per-level dense programs take the family as any
-    other (no validator tests a model's name): its round is the masked
-    engine's up to the order of float32 sums through a step at lr 0.5.  What
-    it lacks is the chunked cohort, refused by key at config resolution."""
-    from heterofl_tpu.parallel.grouped import GroupedRoundEngine
-
-    cfg, data, _, base, _ = masked_round
-    cfg = dict(cfg, strategy="grouped")
-    model, users = make_model(cfg), np.arange(8)
-    rates = np.asarray([cfg["model_rate"][u] for u in users], np.float32)
-    out = GroupedRoundEngine(cfg, make_mesh(1, 1)).train_round(
-        model.init(jax.random.key(0)), users, rates, data, 0.5, jax.random.key(5))[0]
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], atol=5e-3, err_msg=k)
-    with pytest.raises(ValueError, match="round_chunk"):
-        C.resolve_chunk_cfg(dict(cfg, round_chunk=1))
-
-
 def test_nothing_in_the_engines_names_the_family():
     """`parallel/` and `fed/` take the family through `ModelDef` alone: no file
     of either names it (the issue's "nothing should change")."""
@@ -659,36 +419,6 @@ def test_nothing_in_the_engines_names_the_family():
     hits = [str(p) for d in ("parallel", "fed") for p in (root / d).glob("*.py")
             if "laguna" in p.read_text().lower()]
     assert not hits
-
-
-def test_laguna_counters_ride_the_metrics(tmp_path):
-    """telemetry='on' carries the counters out: the experts' (`obs_moe_tokens`,
-    `obs_moe_assign`) and the window's three pairs, finished by
-    `obs.split_probes`: `swa_pairs` = band over causal pairs (a window of 16
-    on rows of 32: 392 / 528), `swa_tiles` = 1 here (one block holds the row)
-    and `swa_fused` = 0 (the block loop); `obs.report` renders them."""
-    from heterofl_tpu.obs import report, split_probes
-
-    cfg, data = _round_case()
-    _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
-    for k in ("obs_swa_fused", "obs_swa_pairs", "obs_swa_tiles"):
-        assert ms[k].shape == (2 * 2,), k
-    # 8 clients x 1 step x 3 sliding layers x 2 rows
-    assert ms["obs_swa_pairs"].reshape(2, 2).sum(axis=0).tolist() == [48 * 392.0, 48 * 528.0]
-    clean, rounds = split_probes(dict(ms), 2)
-    rec = rounds[0]
-    assert rec["swa_pairs"] == pytest.approx(392 / 528) and rec["swa_tiles"] == 1.0
-    assert rec["swa_fused"] == 0.0
-    assert rec["moe_dropped"] == 0 and 0.0 < rec["moe_held_share"] < 1.0
-    assert len(rec["moe_tokens"]) == 4
-    assert not [k for k in clean if k.startswith("obs_")]
-    events = tmp_path / "events.jsonl"
-    events.write_text(json.dumps({"v": 1, "t": 0.0, "name": "probes", "cat": "obs", "ph": "i",
-                                  "args": rec}) + "\n")
-    ev = report.summarize_events(str(events))
-    assert ev["swa"]["rounds"] == 1 and ev["swa"]["pairs"] == rec["swa_pairs"]
-    assert any(line.startswith("  sliding layers over 1 rounds: band over causal pairs 0.7424")
-               for line in report.render_events(ev))
 
 
 @pytest.mark.parametrize("S, window, block, fused, below_one", [
@@ -753,48 +483,6 @@ def test_laguna_model_takes_the_band_kernels_where_a_tpu_gives_them_tiles(monkey
                                    err_msg=k)
 
 
-def test_laguna_trains_and_evaluates_through_the_entry_point(tmp_path):
-    """One whole `FedExperiment.train_round` (masked engine, `round_chunk` 1)
-    and one `evaluate`, built as `entry.common.run_main` builds them from the
-    command line: `--model_name laguna` is all that names the family."""
-    from benchmark.tests import tiny_laguna as tiny
-    from heterofl_tpu.entry.common import FedExperiment, build_cli, cfg_from_args
-    from heterofl_tpu.utils.logger import Logger
-
-    override = {"laguna": dict(tiny.ARCH), "bptt": 32,
-                "batch_size": {"train": 20, "test": 10}, "round_chunk": 1,
-                "num_epochs": {"global": 2, "local": 1}}
-    argv = ["--control_name", "1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1",
-            "--model_name", "laguna", "--data_name", "WikiText2", "--synthetic", "1",
-            "--synthetic_sizes", json.dumps({"train": 20 * 32, "test": 10 * 32}),
-            "--mesh", json.dumps({"clients": 1, "data": 1}),
-            "--output_dir", str(tmp_path), "--override", json.dumps(override)]
-    cfg = C.process_control(cfg_from_args(build_cli("test").parse_args(argv)))
-    exp = FedExperiment(cfg, cfg["init_seed"])
-    assert exp.kind == "transformer" and exp.engine.is_lm and exp.engine._chunk == 1
-    data_split, label_split = exp.make_splits()
-    exp.stage(data_split, label_split)
-    logger = Logger(str(tmp_path / "log"))
-    params = exp.model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params.items()}
-    params = exp.train_round(params, 1, 0.1, logger)
-    moved = [k for k, v in params.items() if not np.array_equal(np.asarray(v), before[k])]
-    assert len(moved) == len(before)
-    named = exp.evaluate(params, 1, logger, label_split)
-    assert np.isfinite(named["Global-Loss"]) and named["Global-Perplexity"] > 1.0
-
-
-def test_laguna_tiny_cell_is_correct_and_its_control_is_not(monkeypatch, capsys):
-    """`benchmark/checks.compare` on the tiny configuration, through the
-    benchmark's own command: sound as returned, not `correct` once the check
-    rounds' result has passed through bfloat16 (the test lives with the
-    benchmark's; run here so that the gate holds it)."""
-    from benchmark.tests import test_laguna
-
-    test_laguna.test_a_sound_run_of_the_tiny_cell_is_correct_and_the_control_is_not(
-        monkeypatch, capsys)
-
-
 def test_the_cut_configuration_has_the_parameters_it_states():
     """490,297,344 from `jax.eval_shape` of the model's own `init` at the
     configuration's sizes: layer 0 79,794,176, three sliding layers of
@@ -809,7 +497,7 @@ def test_the_cut_configuration_has_the_parameters_it_states():
 # the scope ISSUE 42 added (obs.trace.WINDOW_SCOPES)
 # ---------------------------------------------------------------------------
 
-def test_the_window_carries_its_name(masked_round):
+def test_the_window_carries_its_name():
     """`swa` reaches the round program's `op_name`s under `step/model`, forward
     and backward, and holds the sliding layers' score / softmax / value part
     alone: the full layers' stays under `attn`, the projections and the gate
@@ -818,7 +506,7 @@ def test_the_window_carries_its_name(masked_round):
     from heterofl_tpu.obs import trace
 
     assert trace.WINDOW_SCOPES == ("swa",) and trace.SCOPE_VERSION >= 7
-    cfg, data = masked_round[:2]
+    cfg, data = _round_case()
     cfg = dict(cfg, round_chunk=1)
     model = make_model(cfg)
     eng = RoundEngine(model, cfg, make_mesh(1, 1))
@@ -1035,10 +723,11 @@ def test_band_kept_counts_the_layers_that_kept_their_kernels_results(reports, po
     if reports == "tpu":
         _reports_a_tpu(monkeypatch, interpret=True)
     _, model, params, tokens, lm, _ = _laguna_case(**LAYOUTS["both"])
-    assert model.meta["counters"]["band_kept"] == (2,)
+    assert model.meta["counters"]["band_kept"] == ((2,), "ratio")
     out, _ = model.apply(params, {"label": tokens}, train=True, label_mask=lm)
     assert out["counters"]["band_kept"].tolist() == want
-    _, rounds = split_probes({"obs_band_kept": np.asarray(out["counters"]["band_kept"])}, 1)
+    _, rounds = split_probes({"obs_band_kept": np.asarray(out["counters"]["band_kept"])}, 1,
+                             counters=model.meta["counters"])
     assert rounds[0]["band_kept"] == (want[0] / want[1] if want[1] else 0.0)
 
 

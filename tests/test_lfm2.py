@@ -4,7 +4,7 @@ the CPU: against the benchmark's plain reference, its slicing rules, and
 through the engines and the entry point.  A file of its own so that the test
 runner's per-file workers share the family's compiles evenly."""
 
-import json
+import functools
 import re
 
 import jax
@@ -12,10 +12,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from heterofl_tpu import config as C
+from decoder_cases import case, round_case, run_round
 from heterofl_tpu.models import make_model
-from heterofl_tpu.models.spec import Group, count_masks, mask_params
 from heterofl_tpu.parallel import RoundEngine, make_mesh
+
+_lfm2_case = functools.partial(case, "lfm2")
+_round_case = functools.partial(round_case, "lfm2")
+_round = run_round
 
 
 def _stacked_experts(params, held, layer):
@@ -25,93 +28,6 @@ def _stacked_experts(params, held, layer):
 # ---------------------------------------------------------------------------
 # the model against the benchmark's plain reference
 # ---------------------------------------------------------------------------
-
-LEVELS = [1.0, 0.5, 0.25, 0.125, 0.0625]
-
-
-def _lfm2_case(seed=1, **arch):
-    """(cfg, model, seeded params with the gains and the selection bias moved
-    off their constants, tokens, a label mask with holes, the reference's
-    model description)."""
-    from benchmark.tests import tiny_lfm2 as tiny
-
-    cfg = tiny.program_cfg(**arch)
-    model = make_model(cfg)
-    params = model.init(jax.random.key(seed))
-    keys = jax.random.split(jax.random.key(seed + 1), len(params))
-    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
-              for (k, v), kk in zip(sorted(params.items()), keys)}
-    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
-                                cfg["num_tokens"])
-    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
-    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
-
-
-def _masked_loss_and_grads(model, params, tokens, lm, rate):
-    def system_loss(p):
-        pm = mask_params(p, model.specs, model.groups, rate)
-        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
-                             scaler_rate=rate, label_mask=lm)
-        return out["loss"]
-
-    return jax.value_and_grad(system_loss)(params)
-
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_lfm2_masked_model_is_the_references_dense_submodel(rate):
-    """Loss and gradients of the masked full-width model at rate r against the
-    plain reference on the sliced sub-model: rate 1 is the published layer
-    (half-split RoPE on the un-permuted heads, repeated key/value heads, a
-    shifted-sum convolution), every other level HeteroFL's slice of it.
-    float32 on both sides, so the two differ by summation order alone --
-    amplified by the Scaler's 1/r after each of ~25 linears and, at a
-    near-tie of two router scores, by a different expert choice; 1e-3 of a
-    leaf's largest gradient holds both, and a bfloat16 product (2^-9
-    relative a term), a missing tap or a mis-sliced head is off by 1e-2 or
-    more."""
-    from benchmark.reference import common, lfm2 as ref
-
-    cfg, model, params, tokens, lm, rm = _lfm2_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm)))(sub)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in ref_grads.items():
-        g = np.asarray(g)
-        np.testing.assert_allclose(inside[k], g, atol=1e-3 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
-        outside = np.ones(grads[k].shape, bool)
-        outside[np.ix_(*index[k])] = False
-        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
-    assert not np.asarray(grads["l1.moe.router.b"]).any()  # read by top-k only
-
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_lfm2_sliced_submodel_is_the_masked_model(rate):
-    """HeteroFL's equivalence inside the program: the dense sub-model built at
-    rate r (`make_model(cfg, r)`, what the grouped and sliced engines train)
-    on the slice of the parameters gives the masked full-width model's loss
-    and, inside the slice, its gradients; same float32 sums in another order,
-    so 1e-5 relative on the loss and 1e-4 of a leaf's largest gradient."""
-    from benchmark.reference import common, lfm2 as ref
-
-    cfg, model, params, tokens, lm, rm = _lfm2_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    small = make_model(cfg, rate)
-    assert {k: tuple(v.shape) for k, v in sub.items()} == small.meta["shapes"]
-    sub_loss, sub_grads = jax.value_and_grad(lambda p: small.apply(
-        p, {"label": tokens}, train=True, scaler_rate=rate, label_mask=lm)[0]["loss"])(sub)
-    np.testing.assert_allclose(float(sub_loss), float(loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in sub_grads.items():
-        g = np.asarray(g)
-        np.testing.assert_allclose(inside[k], g, atol=1e-4 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
 
 
 def test_lfm2_the_four_shares_add_up():
@@ -224,134 +140,14 @@ def test_stored_pairs_turn_as_the_published_half_split_rope(kept):
 # slicing: the head family, the tied leaf, the level tables
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rate", LEVELS)
-def test_lfm2_heads_keep_equal_dims_and_whole_pairs(rate):
-    """Grouped-query slicing: the 4 query heads, the 2 key/value heads and
-    the head norms' gains keep the SAME dims of a head at every level, in
-    whole rotary pairs (a prefix of the stored, pair-adjacent order), and the
-    geometry check holds the family to it."""
-    from heterofl_tpu.fed.core import validate_width_geometry
-
-    cfg, model, _, _, _, _ = _lfm2_case()
-    hd = cfg["lfm2"]["head_dim"]
-    kept = {}
-    for name in ("q_head", "kv_head", "head"):
-        g = model.groups[name]
-        m = np.asarray(g.mask(rate)).reshape(g.num_heads, hd)
-        assert (m == m[0]).all(), name  # every head alike
-        k = int(m[0].sum())
-        assert m[0, :k].all() and k % 2 == 0, (name, k)  # a prefix of whole pairs
-        assert int(g.active_count(rate)) == g.num_heads * k
-        kept[name] = k
-    assert len(set(kept.values())) == 1 and kept["head"] == max(2, int(np.ceil(hd * rate)))
-    validate_width_geometry(model, cfg)
-    # a key/value group cut by another rule than the query group's is refused
-    odd = dict(model.groups, kv_head=Group("kv_head", 2 * hd, kind="per_head", num_heads=2,
-                                           multiple=1, coupled=False, family="head"))
-    model.groups.update(odd)
-    with pytest.raises(ValueError, match="head family 'head' is inconsistent at rate 0.0625"):
-        validate_width_geometry(model, cfg)
-
-
-def test_lfm2_counts_follow_width_and_labels_and_the_tied_leaf_counts_once():
-    """A client counts for every element of its slice; the tied leaf's rows
-    follow the labels the client holds -- ONE label axis for the look-up and
-    the head -- and no leaf named ``head`` or ``embedding`` exists beside it."""
-    from benchmark.reference import lfm2 as ref
-    from benchmark.tests import tiny_lfm2 as tiny
-
-    cfg = tiny.program_cfg()
-    model = make_model(cfg)
-    shapes = dict(model.meta["shapes"])
-    assert ref.LABEL_AXES == {"tok.w": 0} == {
-        k: s.label_axis for k, s in model.specs.items() if s.label_axis is not None}
-    assert not [k for k in shapes if k.startswith(("head.", "embedding."))]
-    labels = np.zeros(cfg["num_tokens"], np.float32)
-    labels[::3] = 1.0
-    for rate in (1.0, 0.25, 0.0625):
-        cm = count_masks(shapes, model.specs, model.groups, rate, jnp.asarray(labels))
-        index = ref.index(shapes, tiny.reference_model(cfg), rate)
-        for k, shape in shapes.items():
-            want = np.zeros(shape, np.float32)
-            want[np.ix_(*index[k])] = 1.0
-            if k in ref.LABEL_AXES:
-                view = [1] * len(shape)
-                view[ref.LABEL_AXES[k]] = -1
-                want = want * labels.reshape(view)
-            np.testing.assert_array_equal(np.asarray(cm[k]), want, err_msg=f"{k} @ {rate}")
-        assert np.asarray(cm["l1.moe.router.w"]).sum(axis=0).min() > 0  # all 16 columns
-        assert np.asarray(cm["l1.moe.router.b"]).all()
-
-
-def test_level_tables_know_the_lfm2_family():
-    """`level_param_table` counts the sliced sub-model's own leaves, the FLOP
-    table (with the tied head's product, which no leaf of its own shows) falls
-    with the level."""
-    from benchmark.tests import tiny_lfm2 as tiny
-    from heterofl_tpu.analysis.summary import module_table
-    from heterofl_tpu.fed.core import level_flop_table, level_param_table
-
-    cfg = tiny.program_cfg()
-    counts = level_param_table(cfg)
-    for rate, n in counts.items():
-        shapes = jax.eval_shape(make_model(cfg, rate).init, jax.random.key(0))
-        assert n == sum(int(np.prod(v.shape)) for v in shapes.values()), rate
-    flops = level_flop_table(cfg)
-    assert sorted(flops.values(), reverse=True) == [flops[r] for r in sorted(flops, reverse=True)]
-    rows = {r[0]: r for r in module_table(cfg, 1.0, 2)}
-    a, t = cfg["lfm2"], 2 * cfg["bptt"]
-    assert rows["head"][4] == t * a["hidden_size"] * cfg["num_tokens"]
-    assert rows["l0.conv.taps"][4] == t * 3 * a["conv_dim"]
-    assert rows["l1.attn.qk"][4] == 2 * 4 * (16 * 17 // 2) * a["head_dim"]
-    assert rows["l2.moe.e4.g"][4] == t * 0.25 * a["hidden_size"] * a["moe_intermediate_size"]
-
-
 # ---------------------------------------------------------------------------
 # through the engines and the entry point
 # ---------------------------------------------------------------------------
-
-def _round_case():
-    """(cfg, data) of 8 users with 2 rows of 32 tokens each; every client
-    lacks every fifth token and nobody holds token 3 or 4."""
-    from benchmark.tests import tiny_lfm2 as tiny
-
-    cfg = tiny.program_cfg(control="1_8_0.5_iid_fix_a1-b1-c1-e1_bn_1_1")
-    vocab = cfg["num_tokens"]
-    rows = np.random.default_rng(0).integers(5, vocab, size=(8, 2, 32)).astype(np.int64)
-    lm = np.ones((8, vocab), np.float32)
-    lm[:, :5] = 0.0
-    lm[:, ::5] = 0.0
-    return cfg, (jnp.asarray(rows), jnp.asarray(lm))
-
-
-def _round(cfg, data, chunk, n_dev=1, **extra):
-    cfg = dict(cfg, round_chunk=chunk, **extra)
-    model = make_model(cfg)
-    eng = RoundEngine(model, cfg, make_mesh(n_dev, 1))
-    params0 = model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params0.items()}  # the round donates its input
-    out, ms = eng.train_round(params0, jax.random.key(5), 0.5, np.arange(8), data)
-    return (before, {k: np.asarray(v) for k, v in out.items()},
-            {k: np.asarray(v) for k, v in ms.items()})
-
 
 @pytest.fixture(scope="module")
 def masked_round():
     cfg, data = _round_case()
     return (cfg, data) + _round(cfg, data, 1)
-
-
-def test_lfm2_masked_round_in_chunks_of_one_is_the_unchunked_round(masked_round):
-    """`round_chunk` 1, the cell's setting: one slot at a time is the round of
-    one vmap over all 8 slots up to the order of float32 sums (1e-5 relative
-    / 1e-6 absolute; a lost or doubled slot is off by 1e-2)."""
-    cfg, data, _, out, ms = masked_round
-    _, base, base_ms = _round(cfg, data, None)
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], rtol=1e-5, atol=1e-6, err_msg=k)
-    for k in ("loss_sum", "n", "rate"):
-        np.testing.assert_allclose(ms[k], base_ms[k], rtol=1e-5)
-    assert np.isfinite(ms["loss_sum"]).all() and (ms["n"] == 2 * 2).all()
 
 
 def test_lfm2_tied_rows_aggregate_once_over_the_labels_held(masked_round):
@@ -373,86 +169,6 @@ def test_lfm2_tied_rows_aggregate_once_over_the_labels_held(masked_round):
     kept = int(np.ceil(cfg["lfm2"]["hidden_size"] * min(cfg["model_rate"])))
     moved = np.asarray(new["tok.w"]) != before["tok.w"]
     assert moved[:, :kept].any() and not moved[:, kept:].any()
-
-
-def test_lfm2_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_round):
-    """The grouped engine's per-level dense programs take the family as any
-    other (no validator tests a model's name): its round is the masked
-    engine's up to the order of float32 sums through two steps at lr 0.5.
-    What it lacks is the chunked cohort, refused by key at config
-    resolution."""
-    from heterofl_tpu.parallel.grouped import GroupedRoundEngine
-
-    cfg, data, _, base, _ = masked_round
-    cfg = dict(cfg, strategy="grouped")
-    model, users = make_model(cfg), np.arange(8)
-    rates = np.asarray([cfg["model_rate"][u] for u in users], np.float32)
-    out = GroupedRoundEngine(cfg, make_mesh(1, 1)).train_round(
-        model.init(jax.random.key(0)), users, rates, data, 0.5, jax.random.key(5))[0]
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], atol=5e-3, err_msg=k)
-    with pytest.raises(ValueError, match="round_chunk"):
-        C.resolve_chunk_cfg(dict(cfg, round_chunk=1))
-
-
-def test_lfm2_counters_ride_the_metrics_and_nothing_is_dropped():
-    """telemetry='on' carries the expert layers' counters out as
-    `kanana2`'s: tokens per held expert, pairs routed / on held experts / not
-    computed -- the last always 0."""
-    from heterofl_tpu.obs import split_probes
-
-    cfg, data = _round_case()
-    _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
-    assert ms["obs_moe_tokens"].shape == (2 * 4,) and ms["obs_moe_assign"].shape == (2 * 3,)
-    clean, rounds = split_probes(dict(ms), 2)
-    rec = rounds[0]
-    # 8 clients x 2 steps x (2 rows x 16 tokens) x top-4, in each of 3 expert layers
-    assert rec["moe_assign"][0] == 8 * 2 * 32 * 4 * 3
-    assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
-    assert 0.0 < rec["moe_held_share"] < 1.0
-
-
-def test_lfm2_trains_and_evaluates_through_the_entry_point(tmp_path):
-    """One whole `FedExperiment.train_round` (masked engine, `round_chunk` 1)
-    and one `evaluate`, built as `entry.common.run_main` builds them from the
-    command line: `--model_name lfm2` is all that names the family."""
-    from benchmark.tests import tiny_lfm2 as tiny
-    from heterofl_tpu.entry.common import FedExperiment, build_cli, cfg_from_args
-    from heterofl_tpu.utils.logger import Logger
-
-    override = {"lfm2": dict(tiny.ARCH), "bptt": 16,
-                "batch_size": {"train": 20, "test": 10}, "round_chunk": 1,
-                "num_epochs": {"global": 2, "local": 1}}
-    argv = ["--control_name", "1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1",
-            "--model_name", "lfm2", "--data_name", "WikiText2", "--synthetic", "1",
-            "--synthetic_sizes", json.dumps({"train": 20 * 32, "test": 10 * 16}),
-            "--mesh", json.dumps({"clients": 1, "data": 1}),
-            "--output_dir", str(tmp_path), "--override", json.dumps(override)]
-    cfg = C.process_control(cfg_from_args(build_cli("test").parse_args(argv)))
-    exp = FedExperiment(cfg, cfg["init_seed"])
-    assert exp.kind == "transformer" and exp.engine.is_lm and exp.engine._chunk == 1
-    data_split, label_split = exp.make_splits()
-    exp.stage(data_split, label_split)
-    logger = Logger(str(tmp_path / "log"))
-    params = exp.model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params.items()}
-    params = exp.train_round(params, 1, 0.1, logger)
-    moved = [k for k, v in params.items() if not np.array_equal(np.asarray(v), before[k])]
-    assert len(moved) > len(before) // 2
-    assert not np.asarray(params["l1.moe.router.b"]).any()
-    named = exp.evaluate(params, 1, logger, label_split)
-    assert np.isfinite(named["Global-Loss"]) and named["Global-Perplexity"] > 1.0
-
-
-def test_lfm2_tiny_cell_is_correct_and_its_control_is_not(monkeypatch, capsys):
-    """`benchmark/checks.compare` on the tiny configuration, through the
-    benchmark's own command: sound as returned, not `correct` once the check
-    rounds' result has passed through bfloat16 (the test lives with the
-    benchmark's; run here so that the gate holds it)."""
-    from benchmark.tests import test_lfm2
-
-    test_lfm2.test_a_sound_run_of_the_tiny_cell_is_correct_and_the_control_is_not(
-        monkeypatch, capsys)
 
 
 # ---------------------------------------------------------------------------

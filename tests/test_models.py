@@ -1,11 +1,16 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decoder_cases import case
 from heterofl_tpu import config as C
 from heterofl_tpu.models import make_model
 from heterofl_tpu.models.spec import mask_params
+
+_kanana_case = functools.partial(case, "kanana2")
 
 
 def small_cfg(model_name="conv", data_name="MNIST", norm="bn", control="1_10_0.5_iid_fix_a1_bn_1_1"):
@@ -237,58 +242,6 @@ def test_conv_dimension_numbers_one_owner():
 # Kanana-2 (latent attention, shared + routed experts; ISSUE 28) against the
 # benchmark's plain reference, at a tiny size
 # ---------------------------------------------------------------------------
-
-def _kanana_case(seed=1, **arch):
-    """(cfg, model, seeded params with the gains and the selection bias moved
-    off their constants, tokens, a label mask with holes)."""
-    from benchmark.tests import tiny_kanana2 as tiny
-
-    cfg = tiny.program_cfg(**arch)
-    model = make_model(cfg)
-    params = model.init(jax.random.key(seed))
-    keys = jax.random.split(jax.random.key(seed + 1), len(params))
-    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
-              for (k, v), kk in zip(sorted(params.items()), keys)}
-    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
-                                cfg["num_tokens"])
-    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
-    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
-
-
-@pytest.mark.parametrize("rate", [1.0, 0.5, 0.25, 0.125, 0.0625])
-def test_kanana2_masked_model_is_the_references_dense_submodel(rate):
-    """Loss and gradients of the masked full-width model at rate r against the
-    plain reference on the sliced sub-model: rate 1 is the published layer
-    (a), every other level HeteroFL's slice of it (b).  float32 on both
-    sides, so the two differ by summation order alone -- amplified by the
-    Scaler's 1/r after each of ~30 linears and, at a near-tie of two router
-    scores, by a different expert choice; 1e-3 of a leaf's largest gradient
-    holds both, a missing or mis-sliced term is off by 1e-1 or more."""
-    from benchmark.reference import common, kanana2 as ref
-
-    cfg, model, params, tokens, lm, rm = _kanana_case()
-
-    def system_loss(p):
-        pm = mask_params(p, model.specs, model.groups, rate)
-        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
-                             scaler_rate=rate, label_mask=lm)
-        return out["loss"]
-
-    loss, grads = jax.value_and_grad(system_loss)(params)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm)))(sub)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in ref_grads.items():
-        g = np.asarray(g)
-        np.testing.assert_allclose(inside[k], g, atol=1e-3 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
-        outside = np.ones(grads[k].shape, bool)
-        outside[np.ix_(*index[k])] = False
-        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
-
 
 def _stacked_experts(params, held, layer=1):
     return [jnp.stack([params[f"l{layer}.moe.e{j}.{m}.w"] for j in held]) for m in "gud"]

@@ -438,3 +438,46 @@ def test_driver_telemetry_conflicts_fail_loudly(tmp_path):
     with pytest.raises(ValueError, match="fused superstep"):
         FedExperiment(_driver_cfg(tmp_path, telemetry="on",
                                   strategy="grouped", superstep_rounds=1), 0)
+
+
+# ---------------------------------------------------------------------------
+# a model's own counters: finished by the fold the model declares (ISSUE 45)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("fold, rows, want, over_rounds", [
+    # the per-device sums as they are, summed over a run's rounds
+    ("sum", [[1.0, 2.0, 3.0], [10.0, 20.0, 30.0]], [11.0, 22.0, 33.0], [22.0, 44.0, 66.0]),
+    # a (numerator, denominator) pair divided, averaged over the rounds
+    ("ratio", [[3.0, 4.0], [1.0, 4.0]], 0.5, 0.5),
+    # sums with their count last, each over the count, averaged over the rounds
+    ("mean", [[2.0, 4.0, 2.0], [4.0, 8.0, 2.0]], [1.5, 3.0], [1.5, 3.0]),
+])
+def test_a_declared_counter_reaches_the_record_by_its_fold(fold, rows, want, over_rounds,
+                                                           tmp_path):
+    """`split_probes` finishes a counter the model declares (`meta['counters']`:
+    name -> (shape, fold)) from its per-device sums, in both layouts and for
+    every round of a superstep; a zero count gives 0 and not a division; a fold
+    nobody knows is refused; and `obs.report` sums or averages the rounds'
+    records by the folds a run's `run-start` event carries."""
+    from heterofl_tpu.obs import report
+
+    declared = {"fam_thing": ((len(rows[0]),), fold)}
+    flat = np.asarray([np.concatenate(rows), np.concatenate(rows)])  # [k=2, n_dev * X]
+    clean, rounds = split_probes({"n": np.ones(2), "obs_fam_thing": flat}, 2, counters=declared)
+    assert list(clean) == ["n"] and [r["fam_thing"] for r in rounds] == [want, want]
+    span = np.moveaxis(np.asarray([rows, rows]), 1, -1)  # [k, X, n_dev]
+    _, rounds = split_probes({"obs_fam_thing": span}, 2, layout="span", counters=declared)
+    assert [r["fam_thing"] for r in rounds] == [want, want]
+    if fold != "sum":
+        _, (rec,) = split_probes({"obs_fam_thing": np.zeros(2 * len(rows[0]))}, 2,
+                                 counters=declared)
+        assert rec["fam_thing"] == (0.0 if fold == "ratio" else [0.0] * (len(rows[0]) - 1))
+    with pytest.raises(ValueError, match="Not valid fold of the counter 'fam_thing'"):
+        split_probes({"obs_fam_thing": flat}, 2, counters={"fam_thing": ((3,), "median")})
+    events = tmp_path / "events.jsonl"
+    events.write_text("".join(json.dumps(e) + "\n" for e in [
+        {"v": 1, "t": 0.0, "name": "run-start", "ph": "i",
+         "args": {"counters": {"fam_thing": fold}}}]
+        + [{"v": 1, "t": 0.0, "name": "probes", "cat": "obs", "ph": "i", "args": r}
+           for r in rounds]))
+    assert report.summarize_events(str(events))["fam"] == {"rounds": 2, "thing": over_rounds}

@@ -455,7 +455,8 @@ def test_report_renders_snapshot(tmp_path, capsys):
 def test_report_prints_the_expert_layers_counters(tmp_path, capsys):
     """`probes` events that carry an expert layer's counters (ISSUE 28) sum
     over the run's rounds into one line of the report, dropped pairs
-    included (always 0)."""
+    included (always 0); the run's `run-start` event says that they are
+    sums (ISSUE 45)."""
     from heterofl_tpu.obs import report as R
     from heterofl_tpu.obs.trace import TraceRecorder
 
@@ -465,6 +466,8 @@ def test_report_prints_the_expert_layers_counters(tmp_path, capsys):
     run_dir = tmp_path / "trace" / "run0"
     led.save(str(run_dir / "ledger.npz"))
     rec = TraceRecorder(str(run_dir))
+    rec.instant("run-start", args={"tag": "run0", "epoch0": 0, "rounds": 2,
+                                   "counters": {"moe_tokens": "sum", "moe_assign": "sum"}})
     for epoch in (1, 2):
         rec.instant("probes", cat="obs", args={
             "epoch": epoch, "moe_tokens": [10.0, 20.0, 30.0, 36.0],

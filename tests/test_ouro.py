@@ -6,7 +6,7 @@ its unrolled self, the exit distribution, its slicing rules, and through the
 engines and the entry point.  A file of its own so that the test runner's
 per-file workers share the family's compiles evenly."""
 
-import json
+import functools
 import re
 
 import jax
@@ -14,102 +14,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decoder_cases import case, round_case
 from heterofl_tpu import config as C
 from heterofl_tpu.models import make_model
-from heterofl_tpu.models.spec import count_masks, mask_params
 from heterofl_tpu.ops import layers as L
 from heterofl_tpu.parallel import RoundEngine, make_mesh
 
-LEVELS = [1.0, 0.5, 0.25, 0.125, 0.0625]
-
-
-def _ouro_case(seed=1, bptt=None, **arch):
-    """(cfg, model, seeded params with the gains and the gate's bias moved off
-    their constants, tokens, a label mask with holes, the reference's model
-    description)."""
-    from benchmark.tests import tiny_ouro as tiny
-
-    cfg = tiny.program_cfg(bptt=bptt or tiny.BPTT, **arch)
-    model = make_model(cfg)
-    params = model.init(jax.random.key(seed))
-    keys = jax.random.split(jax.random.key(seed + 1), len(params))
-    params = {k: v + 0.1 * jax.random.normal(kk, v.shape) if v.ndim == 1 else v
-              for (k, v), kk in zip(sorted(params.items()), keys)}
-    tokens = jax.random.randint(jax.random.key(seed + 2), (2, cfg["bptt"]), 0,
-                                cfg["num_tokens"])
-    label_mask = jnp.ones(cfg["num_tokens"]).at[jnp.arange(0, cfg["num_tokens"], 7)].set(0.0)
-    return cfg, model, params, tokens, label_mask, tiny.reference_model(cfg)
-
-
-def _masked_loss_and_grads(model, params, tokens, lm, rate):
-    def system_loss(p):
-        pm = mask_params(p, model.specs, model.groups, rate)
-        out, _ = model.apply(pm, {"label": tokens}, train=True, width_rate=rate,
-                             scaler_rate=rate, label_mask=lm)
-        return out["loss"]
-
-    return jax.value_and_grad(system_loss)(params)
+_ouro_case = functools.partial(case, "ouro")
+_round_case = functools.partial(round_case, "ouro")
 
 
 # ---------------------------------------------------------------------------
 # the model against the benchmark's plain reference
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_ouro_masked_model_is_the_references_dense_submodel(rate):
-    """Loss and gradients of the masked full-width model at rate r against the
-    plain reference on the sliced sub-model: rate 1 is the published model
-    (half-split RoPE on the un-permuted heads, the six layer applications one
-    after another in Python, the exit distribution as products of sigmoids),
-    every other level HeteroFL's slice of it.  float32 on both sides, so the
-    two differ by summation order alone, amplified by the Scaler's 1/r in front
-    of twelve norms; 1e-3 of a leaf's largest gradient holds it (4e-4 is the
-    most any level reads), and a bfloat16 product, a pass too few or a
-    mis-sliced head is off by 1e-2 or more."""
-    from benchmark.reference import common, ouro as ref
-
-    cfg, model, params, tokens, lm, rm = _ouro_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: ref.loss_fn(p, tokens, lm, rate, ref.arch_of(rm)))(sub)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in ref_grads.items():
-        g = np.asarray(g)
-        assert np.abs(g).max() > 0, k  # every leaf is trained, the gate too
-        np.testing.assert_allclose(inside[k], g, atol=1e-3 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
-        outside = np.ones(grads[k].shape, bool)
-        outside[np.ix_(*index[k])] = False
-        assert not np.asarray(grads[k])[outside].any(), k  # nothing outside the slice
-
-
-@pytest.mark.parametrize("rate", LEVELS)
-def test_ouro_sliced_submodel_is_the_masked_model(rate):
-    """HeteroFL's equivalence inside the program: the dense sub-model built at
-    rate r (`make_model(cfg, r)`, what the grouped and sliced engines train)
-    on the slice of the parameters gives the masked full-width model's loss
-    and, inside the slice, its gradients; same float32 sums in another order,
-    so 1e-5 relative on the loss and 1e-3 of a leaf's largest gradient."""
-    from benchmark.reference import common, ouro as ref
-
-    cfg, model, params, tokens, lm, rm = _ouro_case()
-    loss, grads = _masked_loss_and_grads(model, params, tokens, lm, rate)
-    index = ref.index({k: v.shape for k, v in params.items()}, rm, rate)
-    sub = {k: jnp.asarray(v) for k, v in common.take(params, index).items()}
-    small = make_model(cfg, rate)
-    assert {k: tuple(v.shape) for k, v in sub.items()} == small.meta["shapes"]
-    sub_loss, sub_grads = jax.value_and_grad(lambda p: small.apply(
-        p, {"label": tokens}, train=True, scaler_rate=rate, label_mask=lm)[0]["loss"])(sub)
-    np.testing.assert_allclose(float(sub_loss), float(loss), rtol=1e-5)
-    inside = common.take(grads, index)
-    for k, g in sub_grads.items():
-        g = np.asarray(g)
-        np.testing.assert_allclose(inside[k], g, atol=1e-3 * np.abs(g).max() + 1e-9,
-                                   err_msg=k)
-
 
 @pytest.mark.parametrize("level", ["a", "c", "e"])
 def test_ouro_one_whole_local_step_is_the_references(level):
@@ -265,223 +182,9 @@ def test_out_of_training_a_token_reads_the_pass_the_threshold_names():
 # slicing
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rate", LEVELS)
-def test_ouro_heads_keep_equal_dims_and_whole_pairs(rate):
-    """The 4 query heads and the 4 key/value heads keep the SAME dims of a
-    head at every level, in whole rotary pairs; the gate's one column is
-    never cut; the geometry check holds the family."""
-    from heterofl_tpu.fed.core import validate_width_geometry
-
-    cfg, model, _, _, _, _ = _ouro_case()
-    kept = {}
-    for name in ("q_head", "kv_head"):
-        g = model.groups[name]
-        assert g.family == "head"
-        m = np.asarray(g.mask(rate)).reshape(g.num_heads, 32)
-        assert (m == m[0]).all(), name  # every head alike
-        k = int(m[0].sum())
-        assert m[0, :k].all() and k % 2 == 0, (name, k)  # a prefix of whole pairs
-        assert int(g.active_count(rate)) == g.num_heads * k
-        kept[name] = k
-    assert set(kept.values()) == {max(2, int(np.ceil(32 * rate)))}
-    assert np.asarray(model.groups["gate"].mask(rate)).all()
-    validate_width_geometry(model, cfg)
-
-
-def test_ouro_counts_follow_width_and_labels():
-    """A client counts for every element of its slice, a leaf used three
-    times a step once; embedding rows and head columns follow the labels the
-    client holds."""
-    from benchmark.reference import ouro as ref
-    from benchmark.tests import tiny_ouro as tiny
-
-    cfg = tiny.program_cfg()
-    model = make_model(cfg)
-    shapes = dict(model.meta["shapes"])
-    assert ref.LABEL_AXES == {k: s.label_axis for k, s in model.specs.items()
-                              if s.label_axis is not None}
-    labels = np.zeros(cfg["num_tokens"], np.float32)
-    labels[::3] = 1.0
-    for rate in (1.0, 0.25, 0.0625):
-        cm = count_masks(shapes, model.specs, model.groups, rate, jnp.asarray(labels))
-        index = ref.index(shapes, tiny.reference_model(cfg), rate)
-        for k, shape in shapes.items():
-            want = np.zeros(shape, np.float32)
-            want[np.ix_(*index[k])] = 1.0
-            if k in ref.LABEL_AXES:
-                view = [1] * len(shape)
-                view[ref.LABEL_AXES[k]] = -1
-                want = want * labels.reshape(view)
-            np.testing.assert_array_equal(np.asarray(cm[k]), want, err_msg=f"{k} @ {rate}")
-        assert np.asarray(cm["exit.b"]).all()
-
-
-def test_level_tables_know_the_ouro_family_and_count_every_pass():
-    """`level_param_table` counts the sliced sub-model's own leaves, the FLOP
-    table falls with the level, and `analysis.summary.module_table` reads
-    ``meta["profile"]["passes"]``: its matmul rows (every 2-D leaf but the
-    embedding, and the attention's two products) hold `benchmark/flops/ouro.py`'s
-    forward FLOPs at rate 1, every pass counted."""
-    from benchmark import harness
-    from benchmark.tests import tiny_ouro as tiny
-    from heterofl_tpu.analysis.summary import module_table
-    from heterofl_tpu.fed.core import level_flop_table, level_param_table
-
-    cfg = tiny.program_cfg()
-    for rate, n in level_param_table(cfg).items():
-        shapes = jax.eval_shape(make_model(cfg, rate).init, jax.random.key(0))
-        assert n == sum(int(np.prod(v.shape)) for v in shapes.values()), rate
-    table = level_flop_table(cfg)
-    assert sorted(table.values(), reverse=True) == [table[r] for r in sorted(table, reverse=True)]
-    flops = harness.load_module("flops", "ouro")
-    model, rows = tiny.reference_model(cfg), 2
-    for passes in (3, 1):
-        c = dict(cfg, ouro=dict(cfg["ouro"], total_ut_steps=passes))
-        table = module_table(c, 1.0, rows)
-        by_name = {r[0]: r for r in table}
-        macs = sum(r[4] for name, r in by_name.items()  # not the look-up, the gains, the bias
-                   if name != "embedding" and not re.search(r"norm\d*\.g$|^exit\.b$", name))
-        want = rows * flops.forward_flops(dict(model, total_ut_steps=passes), 1.0)
-        assert 2 * macs == want, passes
-        assert by_name["head"][4] == passes * rows * 32 * 128 * 96
-        assert by_name["l1.attn.qk"][4] == passes * rows * 4 * (32 * 33 // 2) * 32
-        assert by_name["embedding"][4] == rows * 32 * 128  # looked up once
-
-
 # ---------------------------------------------------------------------------
 # through the engines and the entry point
 # ---------------------------------------------------------------------------
-
-def _round_case():
-    """(cfg, data) of 8 users with 2 rows of 32 tokens each; every client
-    lacks every fifth token and nobody holds token 3 or 4."""
-    from benchmark.tests import tiny_ouro as tiny
-
-    cfg = tiny.program_cfg(control="1_8_0.5_iid_fix_a1-b1-c1-e1_bn_1_1")
-    vocab = cfg["num_tokens"]
-    rows = np.random.default_rng(0).integers(5, vocab, size=(8, 2, 32)).astype(np.int64)
-    lm = np.ones((8, vocab), np.float32)
-    lm[:, :5] = 0.0
-    lm[:, ::5] = 0.0
-    return cfg, (jnp.asarray(rows), jnp.asarray(lm))
-
-
-def _round(cfg, data, chunk, n_dev=1, users=np.arange(8), **extra):
-    cfg = dict(cfg, round_chunk=chunk, **extra)
-    model = make_model(cfg)
-    eng = RoundEngine(model, cfg, make_mesh(n_dev, 1))
-    params0 = model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params0.items()}  # the round donates its input
-    out, ms = eng.train_round(params0, jax.random.key(5), 0.5, users, data)
-    return (before, {k: np.asarray(v) for k, v in out.items()},
-            {k: np.asarray(v) for k, v in ms.items()})
-
-
-@pytest.fixture(scope="module")
-def masked_round():
-    cfg, data = _round_case()
-    return (cfg, data) + _round(cfg, data, 1)
-
-
-def test_ouro_masked_round_in_chunks_of_one_is_the_unchunked_round(masked_round):
-    """`round_chunk` 1, the cell's setting: one slot at a time is the round of
-    one vmap over all 8 slots up to the order of float32 sums (1e-5 relative
-    / 1e-6 absolute; a lost or doubled slot is off by 1e-2)."""
-    cfg, data, _, out, ms = masked_round
-    _, base, base_ms = _round(cfg, data, None)
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], rtol=1e-5, atol=1e-6, err_msg=k)
-    for k in ("loss_sum", "n", "rate"):
-        np.testing.assert_allclose(ms[k], base_ms[k], rtol=1e-5)
-    assert np.isfinite(ms["loss_sum"]).all() and (ms["n"] == 2).all()
-
-
-def test_ouro_a_level_e_round_leaves_everything_outside_its_slice(masked_round):
-    """The slicing round-trips: a round of the smallest level alone moves
-    entries inside its slice and leaves everything outside bit for bit -- the
-    shared leaves as any leaf; rows of tokens nobody holds come back as they
-    were."""
-    from benchmark.reference import ouro as ref
-    from benchmark.tests import tiny_ouro as tiny
-
-    cfg, data, before, out, _ = masked_round
-    held = np.asarray(data[1]).max(axis=0) > 0
-    changed = out["embedding.tok.w"] != before["embedding.tok.w"]
-    assert not changed[~held].any() and changed[held].any(axis=1).all()
-    changed = out["head.w"] != before["head.w"]
-    assert not changed[:, ~held].any() and changed[:, held].any(axis=0).all()
-    small = [u for u in range(8) if cfg["model_rate"][u] == min(cfg["model_rate"])]
-    _, new, _ = _round(cfg, data, 1, users=np.resize(small, 8))
-    index = ref.index({k: v.shape for k, v in before.items()}, tiny.reference_model(cfg),
-                      min(cfg["model_rate"]))
-    for k, b in before.items():
-        inside = np.zeros(b.shape, bool)
-        inside[np.ix_(*index[k])] = True
-        moved = new[k] != b
-        assert not moved[~inside].any(), k
-        assert moved[inside].any(), k
-
-
-def test_ouro_grouped_engine_trains_the_family_and_refuses_the_chunk(masked_round):
-    """The grouped engine's per-level dense programs take the family as any
-    other (no validator tests a model's name): its round is the masked
-    engine's up to the order of float32 sums through a step at lr 0.5.  What
-    it lacks is the chunked cohort, refused by key at config resolution."""
-    from heterofl_tpu.parallel.grouped import GroupedRoundEngine
-
-    cfg, data, _, base, _ = masked_round
-    cfg = dict(cfg, strategy="grouped")
-    model, users = make_model(cfg), np.arange(8)
-    rates = np.asarray([cfg["model_rate"][u] for u in users], np.float32)
-    out = GroupedRoundEngine(cfg, make_mesh(1, 1)).train_round(
-        model.init(jax.random.key(0)), users, rates, data, 0.5, jax.random.key(5))[0]
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], atol=5e-3, err_msg=k)
-    with pytest.raises(ValueError, match="round_chunk"):
-        C.resolve_chunk_cfg(dict(cfg, round_chunk=1))
-
-
-def test_ouro_counters_ride_the_metrics(tmp_path):
-    """telemetry='on' carries the loop's counters out: `obs_loop_exit_share`
-    and `obs_loop_pass_nll` (a sum a pass over the target positions and their
-    count, a device) and `obs_loop_passes` (a pair), finished by
-    `obs.split_probes` as the exit distribution's mean a pass -- which sums to
-    1 --, each pass's mean negative log-likelihood -- whose mixture under the
-    exit shares is near the logged loss plus beta times an entropy of at most
-    log 3 --, and the expected pass, between 1 and 3; `obs.report` renders
-    them.  `obs_loop_kept` (ISSUE 41) rides beside them, a pair a device: 0 of
-    the 8 clients' 3 x 2 layer applications here, where the block loop names
-    nothing; and `obs_loop_unrolled` (ISSUE 43), alike: all 48 of 48, two
-    layers being a short stack."""
-    from heterofl_tpu.obs import report, split_probes
-
-    cfg, data = _round_case()
-    _, _, ms = _round(cfg, data, 1, n_dev=2, telemetry="on")
-    assert ms["obs_loop_exit_share"].shape == ms["obs_loop_pass_nll"].shape == (2 * 4,)
-    assert ms["obs_loop_passes"].shape == ms["obs_loop_kept"].shape == (2 * 2,)
-    assert ms["obs_loop_unrolled"].shape == (2 * 2,)
-    assert ms["obs_loop_kept"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 3 * 2]
-    assert ms["obs_loop_unrolled"].reshape(2, 2).sum(axis=0).tolist() == [8 * 3 * 2, 8 * 3 * 2]
-    # 8 clients x 1 step x 2 rows x 31 target positions, over the two devices
-    assert ms["obs_loop_exit_share"].reshape(2, 4)[:, -1].sum() == 8 * 2 * 31
-    clean, rounds = split_probes(dict(ms), 2)
-    rec = rounds[0]
-    assert len(rec["loop_exit_share"]) == len(rec["loop_pass_nll"]) == 3
-    assert sum(rec["loop_exit_share"]) == pytest.approx(1.0, rel=1e-5)
-    assert all(p > 0 for p in rec["loop_exit_share"])
-    assert all(3.0 < v < 6.0 for v in rec["loop_pass_nll"])  # near log 96 = 4.56
-    assert rec["loop_passes"] == pytest.approx(
-        sum((t + 1) * p for t, p in enumerate(rec["loop_exit_share"])), rel=1e-5)
-    assert rec["loop_kept"] == 0.0 and rec["loop_unrolled"] == 1.0
-    assert not [k for k in clean if k.startswith("obs_")]
-    events = tmp_path / "events.jsonl"
-    events.write_text(json.dumps({"v": 1, "t": 0.0, "name": "probes", "cat": "obs", "ph": "i",
-                                  "args": rec}) + "\n")
-    ev = report.summarize_events(str(events))
-    assert ev["loop"]["rounds"] == 1 and ev["loop"]["exit_share"] == rec["loop_exit_share"]
-    assert any(line.startswith("  loop over 1 rounds: expected pass")
-               for line in report.render_events(ev))
-
 
 #: shapes the fused kernels tile: heads of 128 in groups of ONE query head a
 #: key/value head, rows of 128 positions (three layers, two passes: no two of
@@ -549,7 +252,8 @@ def test_ouro_model_takes_the_gq_kernels_where_a_tpu_gives_them_tiles(policy, mo
     if policy == "bare":
         _bare_checkpoint(monkeypatch)
     _, model, params, tokens, lm, _ = _ouro_case(**TILED)
-    assert PA.gq_tile_for(128, 128) == 128 and model.meta["counters"]["loop_kept"] == (2,)
+    assert PA.gq_tile_for(128, 128) == 128
+    assert model.meta["counters"]["loop_kept"] == ((2,), "ratio")
     want, counters, want_grads = _loss_counters_and_grads(model, params, tokens, lm)
     assert counters["loop_kept"].tolist() == [0.0, 6.0]
     _on_the_kernels(monkeypatch)
@@ -558,7 +262,8 @@ def test_ouro_model_takes_the_gq_kernels_where_a_tpu_gives_them_tiles(policy, mo
     assert set(kernels) == {"gq_attn_fwd", "gq_attn_bwd"}, kernels
     got, counters, got_grads = _loss_counters_and_grads(model, params, tokens, lm)
     assert counters["loop_kept"].tolist() == [6.0 if policy == "kept" else 0.0, 6.0]
-    _, rounds = split_probes({"obs_loop_kept": np.asarray(counters["loop_kept"])}, 1)
+    _, rounds = split_probes({"obs_loop_kept": np.asarray(counters["loop_kept"])}, 1,
+                             counters=model.meta["counters"])
     assert rounds[0]["loop_kept"] == (1.0 if policy == "kept" else 0.0)
     assert float(got) == pytest.approx(float(want), rel=2e-3)
     for name, w in want_grads.items():
@@ -711,7 +416,8 @@ def test_ouro_loop_kept_counts_the_applications_on_the_named_kernel(arch, report
         _on_the_kernels(monkeypatch)
     out, _ = model.apply(params, {"label": tokens}, train=True, label_mask=lm)
     assert out["counters"]["loop_kept"].tolist() == [6.0 * share, 6.0]
-    _, rounds = split_probes({"obs_loop_kept": np.asarray(out["counters"]["loop_kept"])}, 1)
+    _, rounds = split_probes({"obs_loop_kept": np.asarray(out["counters"]["loop_kept"])}, 1,
+                             counters=model.meta["counters"])
     assert rounds[0]["loop_kept"] == share
 
 
@@ -812,56 +518,15 @@ def test_the_rule_is_read_off_the_depth(layers, under):
     assert 4 <= ouro.UNROLL_LAYERS < 48
     assert (layers <= ouro.UNROLL_LAYERS) == under
     _, model, params, tokens, lm, _ = _ouro_case(num_hidden_layers=layers)
-    assert model.meta["counters"]["loop_unrolled"] == (2,)
+    assert model.meta["counters"]["loop_unrolled"] == ((2,), "ratio")
     forward = jax.jit(lambda p: model.apply(p, {"label": tokens}, train=True, label_mask=lm)[0])
     nested = [s for s in _scans(jax.make_jaxpr(forward)(params).jaxpr) if len(s) == 2]
     assert nested == ([] if under else [(3, 48)])
     counters = forward(params)["counters"]
     assert counters["loop_unrolled"].tolist() == [3.0 * layers * under, 3.0 * layers]
-    _, rounds = split_probes({"obs_loop_unrolled": np.asarray(counters["loop_unrolled"])}, 1)
+    _, rounds = split_probes({"obs_loop_unrolled": np.asarray(counters["loop_unrolled"])}, 1,
+                             counters=model.meta["counters"])
     assert rounds[0]["loop_unrolled"] == float(under)
-
-
-def test_ouro_trains_and_evaluates_through_the_entry_point(tmp_path):
-    """One whole `FedExperiment.train_round` (masked engine, `round_chunk` 1)
-    and one `evaluate`, built as `entry.common.run_main` builds them from the
-    command line: `--model_name ouro` is all that names the family."""
-    from benchmark.tests import tiny_ouro as tiny
-    from heterofl_tpu.entry.common import FedExperiment, build_cli, cfg_from_args
-    from heterofl_tpu.utils.logger import Logger
-
-    override = {"ouro": dict(tiny.ARCH), "bptt": 32,
-                "batch_size": {"train": 20, "test": 10}, "round_chunk": 1,
-                "num_epochs": {"global": 2, "local": 1}}
-    argv = ["--control_name", "1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1",
-            "--model_name", "ouro", "--data_name", "WikiText2", "--synthetic", "1",
-            "--synthetic_sizes", json.dumps({"train": 20 * 32, "test": 10 * 32}),
-            "--mesh", json.dumps({"clients": 1, "data": 1}),
-            "--output_dir", str(tmp_path), "--override", json.dumps(override)]
-    cfg = C.process_control(cfg_from_args(build_cli("test").parse_args(argv)))
-    exp = FedExperiment(cfg, cfg["init_seed"])
-    assert exp.kind == "transformer" and exp.engine.is_lm and exp.engine._chunk == 1
-    data_split, label_split = exp.make_splits()
-    exp.stage(data_split, label_split)
-    logger = Logger(str(tmp_path / "log"))
-    params = exp.model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params.items()}
-    params = exp.train_round(params, 1, 0.1, logger)
-    moved = [k for k, v in params.items() if not np.array_equal(np.asarray(v), before[k])]
-    assert len(moved) == len(before)
-    named = exp.evaluate(params, 1, logger, label_split)
-    assert np.isfinite(named["Global-Loss"]) and named["Global-Perplexity"] > 1.0
-
-
-def test_ouro_tiny_cell_is_correct_and_its_control_is_not(monkeypatch, capsys):
-    """`benchmark/checks.compare` on the tiny configuration, through the
-    benchmark's own command: sound as returned, not `correct` once the check
-    rounds' result has passed through bfloat16 (the test lives with the
-    benchmark's; run here so that the gate holds it)."""
-    from benchmark.tests import test_ouro
-
-    test_ouro.test_a_sound_run_of_the_tiny_cell_is_correct_and_the_control_is_not(
-        monkeypatch, capsys)
 
 
 def test_the_cut_configuration_has_the_parameters_it_states():
@@ -877,7 +542,7 @@ def test_the_cut_configuration_has_the_parameters_it_states():
 # the scopes ISSUE 40 added (obs.trace.LOOP_SCOPES)
 # ---------------------------------------------------------------------------
 
-def test_the_loop_carries_its_names(masked_round):
+def test_the_loop_carries_its_names():
     """`loop/pass`, `loop/head` and `loop/exit` reach the round program's
     `op_name`s under `step/model`, forward and backward; the attention stays
     under `gqa` / `rope` / `attn` INSIDE `loop/pass` -- and ONE pass's code
@@ -889,7 +554,7 @@ def test_the_loop_carries_its_names(masked_round):
     assert not set(trace.LOOP_SCOPES) & set(
         trace.SCOPES + trace.EXTRA_SCOPES + trace.MIXER_SCOPES + trace.SPARSE_SCOPES)
     assert trace.SCOPE_VERSION >= 6  # bumped with the new names (the compile cache's key)
-    cfg, data = masked_round[:2]
+    cfg, data = _round_case()
     cfg = dict(cfg, round_chunk=1)
     model = make_model(cfg)
     eng = RoundEngine(model, cfg, make_mesh(1, 1))

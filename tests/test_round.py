@@ -670,85 +670,3 @@ def test_chunked_round_keeps_padding_slots_and_the_engines_refuse():
         with pytest.raises(ValueError, match="round_chunk"):
             C.resolve_chunk_cfg({"round_chunk": 2, "strategy": strategy})
 
-
-def test_kanana2_grouped_engine_trains_the_family():
-    """The grouped engine's per-level dense programs take the family as any
-    other (no validator tests a model's name): its round is the masked
-    engine's up to the order of float32 sums through two steps at lr 0.5.
-    What it lacks is the chunked cohort, refused by key above."""
-    from heterofl_tpu.parallel.grouped import GroupedRoundEngine
-
-    cfg, data = _chunk_case("kanana2")
-    base, _ = _chunk_round(cfg, data, None)
-    cfg = dict(cfg, strategy="grouped")
-    model, users = make_model(cfg), np.arange(8)
-    rates = np.asarray([cfg["model_rate"][u] for u in users], np.float32)
-    out = GroupedRoundEngine(cfg, make_mesh(1, 1)).train_round(
-        model.init(jax.random.key(0)), users, rates, data, 0.5, jax.random.key(5))[0]
-    for k in base:
-        np.testing.assert_allclose(out[k], base[k], atol=5e-3, err_msg=k)
-
-
-def test_kanana2_counters_ride_the_metrics_and_nothing_is_dropped():
-    """telemetry='on' carries the expert layers' counters out as per-device
-    partial sums (obs.split_probes finishes them): tokens per held expert,
-    pairs routed / on held experts / not computed -- the last always 0."""
-    from heterofl_tpu.obs import split_probes
-
-    cfg, data = _chunk_case("kanana2")
-    _, ms = _chunk_round(cfg, data, 1, n_dev=2, telemetry="on")
-    assert ms["obs_moe_tokens"].shape == (2 * 4,) and ms["obs_moe_assign"].shape == (2 * 3,)
-    clean, rounds = split_probes(dict(ms), 2)
-    assert not any(k.startswith("obs_") for k in clean)
-    rec = rounds[0]
-    # 8 clients x 2 steps x (2 rows x 16 tokens) x top-3, in the one expert layer
-    assert rec["moe_assign"][0] == 8 * 2 * 32 * 3
-    assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
-    assert 0.0 < rec["moe_held_share"] < 1.0
-    _, off = _chunk_round(cfg, data, 1)
-    assert not any(k.startswith("obs_") for k in off)
-
-
-def test_kanana2_trains_and_evaluates_through_the_entry_point(tmp_path):
-    """(f) One whole `FedExperiment.train_round` and one `evaluate`, built as
-    `entry.common.run_main` builds them from the command line."""
-    import json
-
-    from benchmark.tests import tiny_kanana2 as tiny
-    from heterofl_tpu.entry.common import FedExperiment, build_cli, cfg_from_args
-    from heterofl_tpu.utils.logger import Logger
-
-    override = {"kanana2": dict(tiny.ARCH, num_hidden_layers=2), "bptt": 16,
-                "batch_size": {"train": 20, "test": 10}, "round_chunk": 1,
-                "num_epochs": {"global": 2, "local": 1}}
-    argv = ["--control_name", "1_10_0.5_iid_fix_a1-b1-c1-d1-e1_bn_1_1",
-            "--model_name", "kanana2", "--data_name", "WikiText2", "--synthetic", "1",
-            "--synthetic_sizes", json.dumps({"train": 20 * 32, "test": 10 * 16}),
-            "--mesh", json.dumps({"clients": 1, "data": 1}),
-            "--output_dir", str(tmp_path), "--override", json.dumps(override)]
-    cfg = C.process_control(cfg_from_args(build_cli("test").parse_args(argv)))
-    exp = FedExperiment(cfg, cfg["init_seed"])
-    assert exp.kind == "transformer" and exp.engine.is_lm and exp.engine._chunk == 1
-    data_split, label_split = exp.make_splits()
-    exp.stage(data_split, label_split)
-    logger = Logger(str(tmp_path / "log"))
-    params = exp.model.init(jax.random.key(0))
-    before = {k: np.asarray(v) for k, v in params.items()}
-    params = exp.train_round(params, 1, 0.1, logger)
-    moved = [k for k, v in params.items() if not np.array_equal(np.asarray(v), before[k])]
-    assert len(moved) > len(before) // 2
-    # the selection bias gets no gradient and stays at its seeded zeros
-    assert not np.asarray(params["l1.moe.router.b"]).any()
-    named = exp.evaluate(params, 1, logger, label_split)
-    assert np.isfinite(named["Global-Loss"]) and named["Global-Perplexity"] > 1.0
-
-
-def test_kanana2_tiny_cell_is_correct_and_its_control_is_not(monkeypatch, capsys):
-    """(g) `benchmark/checks.compare` on the tiny configuration, through the
-    benchmark's own command: sound as returned, not `correct` once the check
-    rounds' result has passed through bfloat16 (the test lives with the
-    benchmark's; run here so that the gate holds it)."""
-    from benchmark.tests import test_kanana2
-
-    test_kanana2.test_a_sound_run_of_the_tiny_cell_is_correct_and_the_control_is_not(
-        monkeypatch, capsys)

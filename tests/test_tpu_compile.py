@@ -348,7 +348,8 @@ def test_lfm2_mixers_compile_with_their_relayouts_listed(one_chip, monkeypatch, 
       ``attn`` (forward, rematerialised forward, backward) and no float32
       ``[..., 256, <= 2048]`` score block of the block loop in the program.
     """
-    from heterofl_tpu.models.lfm2 import conv_mixer, gq_attention
+    from heterofl_tpu.models.decoder import gq_attention
+    from heterofl_tpu.models.lfm2 import conv_mixer
     from heterofl_tpu.ops.layers import masked_rms_norm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -408,8 +409,8 @@ def test_keye_attention_layer_compiles_with_the_selected_kernels(one_chip, monke
     indexer's own, under ``sparse/index``, stay: they decide the choice)."""
     from functools import partial
 
+    from heterofl_tpu.models.decoder import gq_attention
     from heterofl_tpu.models.keye import index_keys
-    from heterofl_tpu.models.lfm2 import gq_attention
     from heterofl_tpu.ops.layers import masked_layer_norm, masked_rms_norm, selected_gq_attention
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
