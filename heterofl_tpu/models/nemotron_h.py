@@ -78,7 +78,7 @@ import jax.numpy as jnp
 
 from ..obs.trace import scope
 from ..ops.layers import (causal_conv_silu, gated_group_rms_norm, linear as _linear, moe_experts,
-                          moe_route, relu2_ffn, ssm_chunked_scan)
+                          moe_route, relu2_ffn, ssm_chunked_scan, ssm_scan_plan)
 from .base import ModelDef
 from .decoder import (Leaves, alike_runs, decoder, expert_tile, gq_attention, held_experts,
                       layer_leaves, moe_counters, run_layers)
@@ -214,10 +214,13 @@ def make_nemotron_h(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 add(f"{p}.moe.{q}.d.w", (width, D), {0: group, 1: "emb"})
 
     # summed over the layers: `ssm_keep` = (sum of exp(dt A), its count): the
-    # share of the state a position keeps; `ssm_chunks` = chunks scanned
+    # share of the state a position keeps; `ssm_chunks` = chunks scanned;
+    # `ssm_fused` = (state-space layers whose scan the fused kernels took,
+    # state-space layers)
     counters = {}
     if "ssm" in kinds:
-        counters.update(keep=("ssm_keep", (2,), "mean"), chunks=("ssm_chunks", (1,), "sum"))
+        counters.update(keep=("ssm_keep", (2,), "mean"), chunks=("ssm_chunks", (1,), "sum"),
+                        fused=("ssm_fused", (2,), "ratio"))
     if "experts" in kinds:
         counters.update(moe_counters(held))
 
@@ -232,6 +235,8 @@ def make_nemotron_h(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
         tile = expert_tile(T, K, E, groups=TILE_GROUPS)
         zero = c.zeros() if counters else None
         chunks = jnp.full((1,), N * -(-S // chunk), jnp.float32)
+        fused = jnp.stack([jnp.float32(ssm_scan_plan(S, Hs, P, G, Ns, chunk) is not None),
+                           jnp.float32(1.0)])
 
         def layer_of(i):
             """Layer ``i``'s kind as ``(x, leaves) -> (x, counters)``
@@ -243,7 +248,7 @@ def make_nemotron_h(num_tokens: int, arch: Dict, model_rate: float = 1.0, *,
                 h = rms(lp["norm.g"], x)
                 if kind == "ssm":
                     y, keep = ssm(lp, h)
-                    return x + y, dict(zero, keep=keep, chunks=chunks)
+                    return x + y, dict(zero, keep=keep, chunks=chunks, fused=fused)
                 if kind == "attention":
                     return x + attention(lp, h), zero
                 hf = h.reshape(T, D)

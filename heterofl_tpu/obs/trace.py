@@ -182,6 +182,14 @@ def scoped(name: str):
     return deco
 
 
+#: The kernels ISSUE 47 added (ops/pallas_ssm.py, under ``ssm/scan``; the
+#: readers file their time under the scope).  A tuple of its own, and down
+#: here, because :func:`scoped` above sits on every Mosaic kernel's call stack,
+#: which is part of its compile-cache key: a line added above it would compile
+#: every kernel cell cold once.
+SSM_KERNELS = ("ssm_scan_fwd", "ssm_scan_bwd")
+
+
 #: events.jsonl schema, version 1: required fields -> type.  ``dur_s`` is
 #: present exactly on complete ("X") events; ``args`` is a flat JSON
 #: object of event-specific facts.

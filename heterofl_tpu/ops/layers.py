@@ -941,8 +941,8 @@ def band_attention_planned(S: int, d: int, group: int, window=None) -> bool:
 
 # ---------------------------------------------------------------------------
 # A selective state-space mixer (Mamba-2 / SSD; models/nemotron_h.py) and a
-# two-matrix expert.  Plain ``jax.numpy``: no kernel, so nothing here is on a
-# Mosaic call stack
+# two-matrix expert.  Plain ``jax.numpy`` but for the scan's kernel pair on a
+# TPU (ops/pallas_ssm.py): no other family's Mosaic call stack passes here
 # ---------------------------------------------------------------------------
 
 def relu2_ffn(x, wu, wd, sc, compute_dtype=None):
@@ -995,12 +995,25 @@ def ssm_chunked_scan(x, dt, a, b, c, chunk: int):
     decays, their exponentials and the carried state are float32; the
     products take their operands at the default matmul precision, as
     :func:`linear` does.  A row that is no whole number of chunks is padded on
-    the right with ``dt = 0`` (a position that neither decays nor writes)."""
+    the right with ``dt = 0`` (a position that neither decays nor writes).
+
+    On a TPU, where :func:`ssm_scan_plan` finds a block (whole chunks, chunk
+    and state multiples of 128, a group's heads filling whole lanes), what
+    follows ``la = dt a`` runs as the fused kernels ``ssm_scan_fwd`` /
+    ``ssm_scan_bwd`` of ``pallas_ssm``: the same mathematics at the same
+    precision, with a chunk's decay matrices, its decayed scores and the
+    chunks' states in VMEM only.  Elsewhere (the CPU; a client's narrow slice
+    at its own widths; a ragged row) the ``jnp`` form below, which is the
+    kernels' oracle."""
     N, S, H, P = x.shape
     G, Ns = b.shape[2:]
     R, Q = H // G, chunk
     la = dt * a                                                   # [N, S, H] log decay, <= 0
     keep = jnp.stack([jnp.sum(jnp.exp(la)), jnp.float32(N * S * H)])
+    if ssm_scan_plan(S, H, P, G, Ns, chunk) is not None:
+        from . import pallas_ssm
+
+        return pallas_ssm.fused_ssm_scan(x, dt, la, b, c, chunk), keep
     xd = x * dt[..., None]
     pad = -S % Q
     if pad:
@@ -1033,6 +1046,18 @@ def ssm_chunked_scan(x, dt, a, b, c, chunk: int):
     before = jnp.moveaxis(before, 0, 1)                           # [N, nc, G, R, P, Ns]
     y = y + jnp.einsum("nciga,ncgrpa->ncigrp", c, before) * jnp.exp(cum)[..., None]
     return y.reshape(N, nc * Q, H, P)[:, :S], keep
+
+
+def ssm_scan_plan(S: int, H: int, P: int, G: int, Ns: int, chunk: int):
+    """The block :func:`ssm_chunked_scan` gives its fused kernels at ``S``
+    positions, ``H`` heads of ``P`` dims in ``G`` groups, a state of ``Ns``
+    and chunks of ``chunk``, None where it takes the ``jnp`` form: off a TPU,
+    or where ``pallas_ssm.ssm_plan`` takes no such shapes."""
+    if jax.default_backend() != "tpu":
+        return None
+    from . import pallas_ssm
+
+    return pallas_ssm.ssm_plan(S, H, P, G, Ns, chunk)
 
 
 @scoped("ssm/norm")

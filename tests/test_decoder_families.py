@@ -468,11 +468,15 @@ def _laguna_counters(ms, rec, declared, tmp_path):
 
 
 def _nemotron_h_counters(ms, rec, declared, tmp_path):
-    """The scan's two beside the experts': `ssm_keep` (a sum of `exp(dt A)` and
-    its count, a device) finished as the mean share of the state a position
+    """The scan's three beside the experts': `ssm_keep` (a sum of `exp(dt A)`
+    and its count, a device) finished as the mean share of the state a position
     keeps, inside (0, 1) and near 1 (dt about 0.01); `ssm_chunks`, chunks
-    scanned: rows of 32 are two chunks of 16."""
+    scanned: rows of 32 are two chunks of 16; `ssm_fused`, the share of the
+    state-space layers whose scan the fused kernels took: 0 of 24 off a TPU."""
     assert ms["obs_ssm_keep"].shape == (2 * 2,) and ms["obs_ssm_chunks"].shape == (2 * 1,)
+    # 8 clients x 1 step x 3 mixers
+    assert ms["obs_ssm_fused"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 24.0]
+    assert rec["ssm_fused"] == 0.0
     # 8 clients x 1 step x 3 mixers x 2 rows x 2 chunks; x 32 positions x 8 heads
     assert rec["ssm_chunks"] == [8 * 3 * 2 * 2]
     assert ms["obs_ssm_keep"].reshape(2, 2)[:, 1].sum() == 8 * 3 * 2 * 32 * 8

@@ -266,22 +266,55 @@ def _parent_bwd(compute_dtype, tile, res, cts):
 _parent_grouped_swiglu.defvjp(_parent_fwd, _parent_bwd)
 
 
-@pytest.mark.parametrize("family", ["kanana2", "lfm2", "keye", "laguna"])
-def test_the_swiglu_families_programs_are_the_parents_as_text(family, monkeypatch):
-    """The routed loop now takes its expert's body as an argument; for a family
-    whose body is SwiGLU the traced program is the parent's: the jaxpr of its
-    tiny model's loss and gradients, with the parent's loop (above) in the
-    loop's place, is the same text but for the loop's own name."""
-    kwargs = dict(num_hidden_layers=2) if family == "kanana2" else {}
-    cfg, model, params, tokens, lm, _ = case(family, **kwargs)
+SWIGLU_FAMILIES = ("kanana2", "lfm2", "keye", "laguna")
 
-    def text():
+
+def _loss_and_grads_text(family):
+    """The jaxpr of a family's tiny model's loss and gradients, as text."""
+    if family in ("resnet18", "transformer"):
+        from test_models import small_cfg, vision_batch
+
+        cfg = small_cfg(family, data_name="WikiText2" if family == "transformer" else "MNIST")
+        model = make_model(cfg)
+        params = model.init(jax.random.key(0))
+        batch = {"label": jnp.arange(32).reshape(2, 16) % 50} if family == "transformer" \
+            else vision_batch(cfg)
+        extra = dict(rng=jax.random.key(1)) if family == "transformer" else {}
+        fn = jax.value_and_grad(lambda p: model.apply(p, batch, train=True, **extra)[0]["loss"])
+    else:
+        kwargs = dict(num_hidden_layers=2) if family == "kanana2" else {}
+        cfg, model, params, tokens, lm, _ = case(family, **kwargs)
         fn = jax.value_and_grad(lambda p: model.apply(
             p, {"label": tokens}, train=True, width_rate=0.25, scaler_rate=0.25,
             label_mask=lm)[0]["loss"])
-        return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(params)))
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(params)))
 
-    mine = text()
+
+@pytest.mark.parametrize("family", SWIGLU_FAMILIES + ("ouro", "resnet18", "transformer"))
+def test_the_other_families_programs_are_the_parents_as_text(family, monkeypatch):
+    """What this family's PRs changed in shared code leaves the seven other
+    families' traced programs -- the jaxpr of a tiny model's loss and
+    gradients -- the parent's as text.  PR 46: the routed loop takes its
+    expert's body as an argument; for a family whose body is SwiGLU the text
+    with the parent's loop (above) in the loop's place is the same but for the
+    loop's own name.  PR 47: the chunked scan picks a kernel pair by backend
+    and shape; with every entry of it made to raise (`ssm_chunked_scan`, its
+    plan, the kernels' module), on a backend that reports a TPU, no other
+    family's trace reaches one, and the text is what it was."""
+    from heterofl_tpu.ops import pallas_ssm
+
+    mine = _loss_and_grads_text(family)
+    assert "ssm_scan" not in mine
+
+    def unreachable(*a, **kw):
+        raise AssertionError("the chunked scan is Nemotron-H's alone")
+
+    for module, name in ((L, "ssm_chunked_scan"), (L, "ssm_scan_plan"),
+                         (pallas_ssm, "ssm_plan"), (pallas_ssm, "fused_ssm_scan")):
+        monkeypatch.setattr(module, name, unreachable)
+    assert _loss_and_grads_text(family) == mine
+    if family not in SWIGLU_FAMILIES:
+        return
 
     def parents(body, compute_dtype, tile, h, w, rows, slot, tile_expert, n_tiles, ws, inv):
         assert body is L.swiglu and len(ws) == 3
@@ -289,7 +322,7 @@ def test_the_swiglu_families_programs_are_the_parents_as_text(family, monkeypatc
                                       n_tiles, *ws, inv)
 
     monkeypatch.setattr(L, "_grouped_experts", parents)
-    theirs = text()
+    theirs = _loss_and_grads_text(family)
     assert "_parent_grouped_swiglu" in theirs and "_grouped_experts" in mine
     for name, word in (("_grouped_experts_fwd", "FWD"), ("_grouped_experts_bwd", "BWD"),
                        ("_grouped_experts", "LOOP")):
@@ -298,6 +331,39 @@ def test_the_swiglu_families_programs_are_the_parents_as_text(family, monkeypatc
                        ("_parent_grouped_swiglu", "LOOP"), ("_parent_expert_tile", "_expert_tile")):
         theirs = theirs.replace(name, word)
     assert mine == theirs
+
+
+@pytest.mark.parametrize("backend, fused", [("tpu", 3.0), ("cpu", 0.0)])
+def test_the_mixers_take_the_scan_kernels_on_a_tpu_and_say_so(backend, fused, monkeypatch):
+    """The tiny model at shapes `ssm_plan` takes (chunks and a state of 128;
+    four heads of 32 a group fill a lane tile) on a backend that reports a TPU,
+    the kernels in interpret mode: every mixer's scan is the kernel pair (three
+    `ssm_scan_fwd` in the traced loss, none off a TPU), `ssm_fused` reads 3 of
+    3 (0 of 3 off a TPU), and loss and gradients are the `jnp` form's at
+    float32 products."""
+    from heterofl_tpu.ops import pallas_ssm
+
+    cfg, model, params, tokens, lm, _ = case("nemotron_h", bptt=128, chunk_size=128,
+                                             ssm_state_size=128)
+
+    def loss(p):
+        out, _ = model.apply(p, {"label": tokens}, train=True, width_rate=0.5, scaler_rate=0.5,
+                             label_mask=lm)
+        return out["loss"], out["counters"]
+
+    with jax.default_matmul_precision("highest"):
+        (want, _), want_grads = jax.value_and_grad(loss, has_aux=True)(params)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(pallas_ssm, "fused_ssm_scan",
+                            partial(pallas_ssm.fused_ssm_scan, interpret=True))
+        assert str(jax.make_jaxpr(loss)(params)).count("name=ssm_scan_fwd") == fused
+        (got, counters), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    assert np.asarray(counters["ssm_fused"]).tolist() == [fused, 3.0]
+    assert model.meta["counters"]["ssm_fused"] == ((2,), "ratio")
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for k, w in want_grads.items():
+        np.testing.assert_allclose(grads[k], w, rtol=0, atol=5e-5 * float(jnp.abs(w).max()) + 1e-9,
+                                   err_msg=k)
 
 
 def test_the_sixteen_shares_add_up_to_the_uncut_reference_layer():

@@ -183,14 +183,17 @@ def test_the_vocabulary_is_closed():
     ("masked_bn_bwd", "pallas_norm"), ("int8_pack", "quant"),
     ("latent_attn_fwd", "pallas_attention"), ("latent_attn_bwd", "pallas_attention"),
     ("gq_attn_fwd", "pallas_attention"), ("gq_attn_bwd", "pallas_attention"),
-    ("band_attn_fwd", "pallas_attention"), ("band_attn_bwd", "pallas_attention")])
+    ("band_attn_fwd", "pallas_attention"), ("band_attn_bwd", "pallas_attention"),
+    ("ssm_scan_fwd", "pallas_ssm"), ("ssm_scan_bwd", "pallas_ssm")])
 def test_every_pallas_call_is_named(kernel, module):
     import importlib
     import inspect
 
     src = inspect.getsource(importlib.import_module(f"heterofl_tpu.ops.{module}"))
-    assert kernel in trace.KERNELS + trace.EXTRA_KERNELS and f'name="{kernel}"' in src
-    assert not set(trace.KERNELS) & set(trace.EXTRA_KERNELS)
+    kernels = trace.KERNELS + trace.EXTRA_KERNELS + trace.SSM_KERNELS
+    assert kernel in kernels and len(set(kernels)) == len(kernels)
+    # by its name in the call, or (the scan's pair, one call for both) handed to it
+    assert f'name="{kernel}"' in src or f'"{kernel}",' in src
     assert src.count("pallas_call(") == src.count("        name=")
 
 

@@ -306,6 +306,51 @@ def test_the_chunked_scan_compiles_at_the_cells_shapes_and_keeps_no_state_a_posi
     assert not re.search(r"f32\[[\d,]*8192,[\d,]*64,128\]", compiled.as_text())
 
 
+@pytest.mark.parametrize("vmapped", [False, True], ids=["cell", "vmap1"])
+def test_the_scan_kernels_compile_at_the_cells_shapes_under_ssm_scan(one_chip, vmapped,
+                                                                      monkeypatch):
+    """The fused pair (``ops/pallas_ssm.py``, ISSUE 47) through
+    ``ssm_chunked_scan`` at the Nemotron-H cell's REAL shapes (one row of 8,192
+    positions, 64 heads of 64 in 8 groups, a state of 128, chunks of 128),
+    forward and gradient, plain and under the ``vmap`` over the one client
+    slot of a chunk as the cell runs it: two custom calls, both under
+    ``ssm/scan`` (the scope ``ssm_scan_ms.step`` and ``ssm_scan_roofline_pct``
+    read, whatever implements it), no ``[.., 128, 128]`` float32 array a chunk
+    and head -- the decay matrices and the decayed scores the ``jnp`` form
+    writes, 64 x 64 of them a layer -- anywhere in the compiled program, the
+    state before each chunk as the one float32 ``[64, 8, 128, 512]`` between the
+    two kernels, and temporaries under 1 GB (the ``jnp`` form's line above is 3)."""
+    from heterofl_tpu.ops.layers import ssm_chunked_scan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def grads(x, dt, a, b, c):
+        return jax.grad(lambda *o: jnp.sum(ssm_chunked_scan(*o, 128)[0] ** 2),
+                        argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+
+    lead = (1,) if vmapped else ()
+    avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip) for s in (
+        (1, 8192, 64, 64), (1, 8192, 64), (64,), (1, 8192, 8, 128), (1, 8192, 8, 128))]
+    with no_persistent_cache():
+        compiled = jax.jit(jax.vmap(grads) if vmapped else grads).lower(*avals).compile()
+    text = compiled.as_text()
+    op_names = sorted(re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+                      if "custom-call(" in line and "tpu_custom_call" in line)
+    assert len(op_names) == 2, op_names
+    assert re.search(r"jvp\(ssm/scan\)\)?/ssm_scan_fwd/pallas_call$", op_names[0])
+    assert re.search(r"transpose\((vmap\()?jvp\(ssm/scan\)\)+/ssm_scan_bwd/pallas_call$",
+                     op_names[1])
+    # as the benchmark's reader files them: under the scope, the kernel's own component dropped
+    from benchmark import scope_reduce
+
+    monkeypatch.setattr(scope_reduce, "_PAIRS", scope_reduce._PAIRS | {("ssm", "scan")})
+    assert [scope_reduce.scope_of(n) for n in op_names] == [("ssm/scan", "fwd"), ("ssm/scan", "bwd")]
+    assert not re.search(r"f32\[[\d,]*64,[\d,]*128,128\]", text.replace(
+        "f32[1,64,8,128,512]", "").replace("f32[1,1,64,8,128,512]", ""))
+    assert "64,8,128,512]" in text  # the states between the kernels, and nothing else of their size
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 #: bytes of an element, for the shapes a relayout of the block can have
 _ITEMSIZE = {"f32": 4, "bf16": 2}
 
