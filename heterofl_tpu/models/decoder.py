@@ -67,9 +67,11 @@ def expert_tile(tokens: int, top_k: int, experts: int, groups: int = 2) -> int:
 
 def moe_counters(held) -> Dict[str, tuple]:
     """The declaration of what ``ops.layers.moe_experts`` counts: tokens a
-    held expert, and (pairs routed, pairs on held experts, held pairs not
-    computed)."""
-    return {"tokens": ("moe_tokens", (len(held),), "sum"), "assign": ("moe_assign", (3,), "sum")}
+    held expert, (pairs routed, pairs on held experts, held pairs not
+    computed), and (layer applications whose dispatch was the compact one,
+    layer applications)."""
+    return {"tokens": ("moe_tokens", (len(held),), "sum"), "assign": ("moe_assign", (3,), "sum"),
+            "compact": ("moe_compact", (2,), "sum")}
 
 
 def layer_leaves(params: Dict[str, jnp.ndarray], i: int, held=None,

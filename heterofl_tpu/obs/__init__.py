@@ -396,5 +396,9 @@ def split_probes(ms: Dict[str, Any], n_dev: int, layout: str = "flat",
             routed, held, lost = rec["moe_assign"]
             rec["moe_held_share"] = held / routed if routed else 0.0
             rec["moe_dropped"] = int(round(lost))
+        if "moe_compact" in rec:
+            # (layer applications that took the compact dispatch, applications)
+            took, layers = rec["moe_compact"]
+            rec["moe_compact_share"] = took / layers if layers else 0.0
         rounds.append(rec)
     return clean, rounds

@@ -768,15 +768,29 @@ def _tile_weights(e, *stacked):
 # scatter-add, forward or backward.  A trip count known only at run time has
 # no reverse-mode rule, hence the custom one: the backward is the same loop,
 # each tile's cotangents from ``jax.vjp`` of the tile.
+#
+# What lies round the loop -- its result buffer, the combine -- is sized one of
+# two ways.  FULL: by the only static bound on the held pairs, all ``T * K`` of
+# them (``rows``' length).  COMPACT: by :func:`moe_capacity`, two tiles a held
+# expert, and the combine as long as a token's held choices.  Both compute
+# every pair and add a token's terms in the same order, so to the same bits
+# wherever a multiply and an add stay two roundings (XLA:CPU contracts them by
+# what it fuses); which runs is decided by the tiles in use (``lax.cond``),
+# where the shapes make compact worth building.
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _grouped_experts(body, compute_dtype, tile, h, w, rows, slot, tile_expert, n_tiles, ws, inv):
-    """``(out, computed)``: ``out[t] = sum_k w[t, k] * expert(h[t])`` over the
-    pairs with a row; ``computed`` counts the pairs the loop took.  ``body(x,
-    *matrices, sc, compute_dtype)`` is one expert on a tile of rows
-    (:func:`swiglu`, :func:`relu2_ffn`), ``ws`` its matrices, each stacked over
-    the held experts."""
-    K = w.shape[1]
+def moe_capacity(held: int, tile: int, n_rows: int):
+    """The rows the compact dispatch gives the loop's buffers: twice its tile
+    a held expert (a tile is already two expected groups or more,
+    ``models.decoder.expert_tile``), so the fallback runs only where the held
+    experts' load doubles once more.  None where that is more than half the
+    bound ``n_rows``: there the full-size path is the whole program, with no
+    ``cond`` in it (a quarter of the experts held on four choices a token)."""
+    cap = 2 * held * tile
+    return cap if 2 * cap <= n_rows else None
+
+
+def _tiles_forward(body, compute_dtype, tile, n_buf, h, K, rows, tile_expert, n_tiles, ws, inv):
+    """The loop: ``(y [n_buf, D], computed)``, a tile's rows through its expert."""
 
     def step(i, carry):
         y, done = carry
@@ -788,20 +802,14 @@ def _grouped_experts(body, compute_dtype, tile, h, w, rows, slot, tile_expert, n
         return (lax.dynamic_update_slice(y, y_t, (i * tile, 0)),
                 done + jnp.sum((r >= 0).astype(jnp.int32)))
 
-    y, done = lax.fori_loop(0, n_tiles, step,
-                            (jnp.zeros((rows.shape[0], h.shape[1]), h.dtype), jnp.int32(0)))
-    with scope("moe/dispatch"):
-        return sum(w[:, k, None] * _gather_rows(y, slot[:, k]) for k in range(K)), done
+    return lax.fori_loop(0, n_tiles, step,
+                         (jnp.zeros((n_buf, h.shape[1]), h.dtype), jnp.int32(0)))
 
 
-def _grouped_experts_fwd(body, compute_dtype, tile, *args):
-    return _grouped_experts(body, compute_dtype, tile, *args), args
-
-
-def _grouped_experts_bwd(body, compute_dtype, tile, res, cts):
-    h, w, rows, slot, tile_expert, n_tiles, ws, inv = res
-    dout, K = cts[0], w.shape[1]
-    w_flat = w.reshape(-1)
+def _tiles_backward(body, compute_dtype, tile, n_buf, h, w, dout, rows, tile_expert, n_tiles,
+                    ws, inv):
+    """The same loop backward: ``(dx [n_buf, D], dw_rows [n_buf], *dws)``."""
+    K, w_flat = w.shape[1], w.reshape(-1)
 
     def step(i, carry):
         dx, dw_rows, *dws = carry
@@ -821,17 +829,174 @@ def _grouped_experts_bwd(body, compute_dtype, tile, res, cts):
                 lax.dynamic_update_slice(dw_rows, jnp.sum(y_t * d_t, axis=-1), (i * tile,)),
                 *dws)
 
-    dx, dw_rows, *dws = lax.fori_loop(
+    return lax.fori_loop(
         0, n_tiles, step,
-        (jnp.zeros((rows.shape[0], h.shape[1]), h.dtype), jnp.zeros(rows.shape, w.dtype),
+        (jnp.zeros((n_buf, h.shape[1]), h.dtype), jnp.zeros((n_buf,), w.dtype),
          *(jnp.zeros_like(m) for m in ws)))
+
+
+def _held_first(slot):
+    """A token's held choices moved to the front of its ``K``, STABLY:
+    ``(front [K, T], place [K, T, K], m)`` with ``front[i, t]`` the row of
+    token ``t``'s ``i``-th held choice (-1 past its last), ``place[i, t, k]``
+    whether choice ``k`` is that one, and ``m`` the most held choices of any
+    token.  A count along ``K``: no sort."""
+    live = slot >= 0                                               # [T, K]
+    rank = jnp.cumsum(live, axis=1) - 1
+    place = live[None] & (rank[None] == jnp.arange(slot.shape[1])[:, None, None])
+    front = jnp.sum(jnp.where(place, slot[None] + 1, 0), axis=2) - 1
+    return front, place, jnp.max(jnp.sum(live, axis=1))
+
+
+def _rows_summed(y, front, m, scale=None):
+    """``sum_{i < m} scale[i] * y[front[i]]`` ``[T, D]``: the first ``m`` of
+    ``front``'s ``K`` gathers as ONE fused sum, picked by ``lax.switch`` among
+    the ``K + 1`` lengths there are (a loop of ``m`` steps would carry the
+    ``[T, D]`` sum through memory every step).  A token's held choices in
+    their own order and ``x + 0 = x``: the sum over all ``K`` slots, term for
+    term."""
+
+    def first(n):
+        def summed(y, front, scale):
+            if n == 0:
+                return jnp.zeros((front.shape[1], y.shape[1]), y.dtype)
+            return sum(_gather_rows(y, front[i]) if scale is None
+                       else scale[i][:, None] * _gather_rows(y, front[i]) for i in range(n))
+        return summed
+
+    return lax.switch(m, [first(n) for n in range(front.shape[0] + 1)], y, front, scale)
+
+
+def _experts_forward(cap, body, compute_dtype, tile, h, w, rows, slot, tile_expert, n_tiles, ws,
+                     inv):
+    """One dispatch's loop and combine.  ``cap`` None, FULL: the loop's buffer
+    as long as ``rows``, a combine of ``K`` gathers.  COMPACT, where the tiles
+    in use fit ``cap`` rows: a buffer of ``cap`` rows, the combine a token's
+    held choices long."""
+    K = w.shape[1]
+    y, done = _tiles_forward(body, compute_dtype, tile, rows.shape[0] if cap is None else cap,
+                             h, K, rows, tile_expert, n_tiles, ws, inv)
     with scope("moe/dispatch"):
-        dh = sum(_gather_rows(dx, slot[:, k]) for k in range(K))
+        if cap is None:
+            return sum(w[:, k, None] * _gather_rows(y, slot[:, k]) for k in range(K)), done
+        front, place, m = _held_first(slot)
+        w_front = jnp.sum(jnp.where(place, w[None], 0), axis=2)   # one term a sum: exact
+        return _rows_summed(y, front, m, w_front), done
+
+
+def _experts_backward(cap, body, compute_dtype, tile, h, w, rows, slot, tile_expert, n_tiles, ws,
+                      inv, dout):
+    """:func:`_experts_forward`'s cotangents ``(dh, dw, dws)``, sized alike."""
+    dx, dw_rows, *dws = _tiles_backward(
+        body, compute_dtype, tile, rows.shape[0] if cap is None else cap, h, w, dout, rows,
+        tile_expert, n_tiles, ws, inv)
+    with scope("moe/dispatch"):
+        if cap is None:
+            dh = sum(_gather_rows(dx, slot[:, k]) for k in range(w.shape[1]))
+        else:
+            front, _, m = _held_first(slot)
+            dh = _rows_summed(dx, front, m)
         dw = _gather_rows(dw_rows, slot)
-    return dh, dw, None, None, None, None, tuple(dws), jnp.zeros_like(inv)
+    return dh, dw, tuple(dws)
+
+
+def _either_dispatch(fn, body, compute_dtype, tile, n_tiles, ws, rows, *args):
+    """``fn(cap, ...)`` full, or by a ``lax.cond`` on the tiles in use compact,
+    where :func:`moe_capacity` names a capacity for these shapes."""
+    cap = moe_capacity(ws[0].shape[0], tile, rows.shape[0])
+    if cap is None:
+        return fn(None, body, compute_dtype, tile, *args)
+    return lax.cond(n_tiles * tile <= cap, partial(fn, cap, body, compute_dtype, tile),
+                    partial(fn, None, body, compute_dtype, tile), *args)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _grouped_experts(body, compute_dtype, tile, h, w, rows, slot, tile_expert, n_tiles, ws, inv):
+    """``(out, computed)``: ``out[t] = sum_k w[t, k] * expert(h[t])`` over the
+    pairs with a row; ``computed`` counts the pairs the loop took.  ``body(x,
+    *matrices, sc, compute_dtype)`` is one expert on a tile of rows
+    (:func:`swiglu`, :func:`relu2_ffn`), ``ws`` its matrices, each stacked over
+    the held experts."""
+    return _either_dispatch(_experts_forward, body, compute_dtype, tile, n_tiles, ws, rows,
+                            h, w, rows, slot, tile_expert, n_tiles, ws, inv)
+
+
+def _grouped_experts_fwd(body, compute_dtype, tile, *args):
+    return _grouped_experts(body, compute_dtype, tile, *args), args
+
+
+def _grouped_experts_bwd(body, compute_dtype, tile, res, cts):
+    n_tiles, ws, inv = res[5:]
+    # the forward's branch, from the same residual
+    dh, dw, dws = _either_dispatch(_experts_backward, body, compute_dtype, tile, n_tiles, ws,
+                                   res[2], *res, cts[0])
+    return dh, dw, None, None, None, None, dws, jnp.zeros_like(inv)
 
 
 _grouped_experts.defvjp(_grouped_experts_fwd, _grouped_experts_bwd)
+
+
+def _sorted_groups(e, is_held, held: int, tile: int, n_rows: int):
+    """The index arrays of pairs sorted by expert.  ``e`` ``[n]``: a pair's
+    held expert, ``held`` for none (``is_held`` says the same).  Returns
+    ``(counts [held], slot [n], rows [n_rows], tile_expert [n_rows // tile],
+    ends [held])``; ``rows`` names a pair by its place in ``e``, ``ends[j]``
+    counts the tiles of the groups up to and with expert ``j``'s."""
+    n = e.shape[0]
+    onehot = (e[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+    counts = jnp.sum(onehot, axis=0)                          # [held]
+    pos = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    order = jnp.argsort(e, stable=True).astype(jnp.int32)     # pairs by expert
+    starts = jnp.cumsum(counts) - counts                      # of a group in `order`
+    tiles = -(-counts // tile)                                # tiles of a group
+    ends = jnp.cumsum(tiles)
+    slot = jnp.where(is_held, (ends - tiles)[jnp.minimum(e, held - 1)] * tile + pos, -1)
+    tile_expert = jnp.minimum(jnp.searchsorted(ends, jnp.arange(n_rows // tile),
+                                               side="right"), held - 1).astype(jnp.int32)
+    row = jnp.arange(n_rows, dtype=jnp.int32)
+    ex = tile_expert[row // tile]
+    rank = row - (ends - tiles)[ex] * tile                    # of a row in its group
+    rows = jnp.where(rank < counts[ex], order[jnp.minimum(starts[ex] + rank, n - 1)], -1)
+    return counts, slot, rows, tile_expert, ends
+
+
+def _compact_groups(e, is_held, held: int, tile: int, n_rows: int, cap: int, K: int):
+    """:func:`_sorted_groups`'s arrays, to the last entry, where the tiles in
+    use fit ``cap`` rows, for pairs that lie ``K`` a token: the rows' arrays at
+    ``cap`` entries, and no gather but the one that lays the sorted pairs out
+    in tiles (a gather of single integers costs the chip 7 ns an entry; the
+    full-size arrays take five of ``n_rows`` entries and one of ``T * K``).
+    A pair's place in its group is counted, not searched for: the pairs of its
+    expert among the tokens before it (a running count down ``T`` of ``[held,
+    T]`` token counts) and among its own token's earlier choices; a row's
+    expert and its group's numbers are picked by comparing against the
+    ``held`` group ends.  The positions lie on the minor axis throughout."""
+    T = e.shape[0] // K
+    experts = jnp.arange(held, dtype=jnp.int32)
+    hot = (e.reshape(T, K).T[None] == experts[:, None, None]).astype(jnp.int32)   # [held, K, T]
+    a_token = jnp.sum(hot, axis=1)                                # [held, T] pairs of a token
+    counts = jnp.sum(a_token, axis=1)
+    starts = jnp.cumsum(counts) - counts
+    tiles = -(-counts // tile)
+    ends = jnp.cumsum(tiles)
+    base = (ends - tiles) * tile                                  # first row of a group
+    earlier = (jnp.cumsum(a_token, axis=1) - a_token)[:, None, :] + jnp.cumsum(hot, axis=1) - hot
+    slot = jnp.sum(hot * (base[:, None, None] + earlier), axis=0).T.reshape(-1)
+    slot = jnp.where(is_held, slot, -1)
+    order = jnp.argsort(e, stable=True).astype(jnp.int32)[:cap]   # the held pairs come first
+    row = jnp.arange(cap, dtype=jnp.int32)
+    ex = jnp.minimum(jnp.sum(row[None] // tile >= ends[:, None], axis=0), held - 1)
+    mine = ex[None] == experts[:, None]                           # [held, cap]: a row's group
+
+    def of_group(table):
+        return jnp.sum(jnp.where(mine, table[:, None], 0), axis=0)
+
+    rank = row - of_group(base)
+    rows = jnp.where(rank < of_group(counts),
+                     order[jnp.minimum(of_group(starts) + rank, cap - 1)], -1)
+    rest = n_rows - cap
+    return (counts, slot, jnp.concatenate([rows, jnp.full((rest,), -1, jnp.int32)]),
+            jnp.concatenate([ex[::tile], jnp.full((rest // tile,), held - 1, jnp.int32)]), ends)
 
 
 def moe_experts(h, sel, w, experts, first: int, sc, compute_dtype=None,
@@ -852,40 +1017,48 @@ def moe_experts(h, sel, w, experts, first: int, sc, compute_dtype=None,
     computed, whatever the router did.  The pairs are sorted by expert, each
     expert's group padded to whole tiles of ``tile`` rows, and
     :func:`_grouped_experts` runs the tiles in use, so the work follows the
-    pairs there are and not a bound on them (the bound, all ``T * K`` pairs
-    on held experts, is only the row count of the index arrays and of the
-    loop's result buffer).
+    pairs there are and not a bound on them.  The bound, all ``T * K`` pairs
+    on held experts, sizes the FULL dispatch: the sort, the index arrays, the
+    loop's result buffer and a combine of ``K`` gathers.  Where
+    :func:`moe_capacity` names a capacity (a sixteenth of the experts held:
+    one pair in sixteen lands here), a ``lax.cond`` on the tiles in use takes
+    the COMPACT dispatch instead -- index arrays counted over the held pairs'
+    rows alone (of the sort only its first ``capacity`` places are read),
+    buffers of the capacity, a combine as long as a token's held choices --
+    which adds a token's terms in the same order; past the capacity the full one runs.  Alone or
+    in a scan a ``cond`` runs one branch; UNDER A CLIENT ``vmap`` IT RUNS
+    BOTH (``parallel/round_engine.py`` ``_train_slots``: the expert cells run
+    ``round_chunk`` 1, which has none).
 
     Returns ``(y [T, D], counters)``: ``tokens`` ``[held]`` pairs per held
     expert, ``assign`` ``[3]`` = (pairs routed, pairs on held experts, pairs
-    on held experts that were not computed -- always 0)."""
+    on held experts that were not computed -- always 0), ``compact`` ``[2]`` =
+    (1 if the compact dispatch ran, 1)."""
     T, K = sel.shape
     held, A = experts[0].shape[0], T * K
     with scope("moe/dispatch"):
         local = sel.reshape(A) - first
         is_held = (local >= 0) & (local < held)
         e = jnp.where(is_held, local, held)                       # held = none
-        onehot = (e[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
-        counts = jnp.sum(onehot, axis=0)                          # [held]
-        pos = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
-        order = jnp.argsort(e, stable=True).astype(jnp.int32)     # pairs by expert
-        starts = jnp.cumsum(counts) - counts                      # of a group in `order`
-        tiles = -(-counts // tile)                                # tiles of a group
-        ends = jnp.cumsum(tiles)
         n_rows = (A // tile + held) * tile                        # >= any sum of padded groups
-        slot = jnp.where(is_held, (ends - tiles)[jnp.minimum(e, held - 1)] * tile + pos, -1)
-        tile_expert = jnp.minimum(jnp.searchsorted(ends, jnp.arange(n_rows // tile),
-                                                   side="right"), held - 1).astype(jnp.int32)
-        row = jnp.arange(n_rows, dtype=jnp.int32)
-        ex = tile_expert[row // tile]
-        rank = row - (ends - tiles)[ex] * tile                    # of a row in its group
-        rows = jnp.where(rank < counts[ex], order[jnp.minimum(starts[ex] + rank, A - 1)], -1)
+        cap = moe_capacity(held, tile, n_rows)
+        if cap is None:
+            compact = jnp.float32(0.0)
+            counts, slot, rows, tile_expert, ends = _sorted_groups(e, is_held, held, tile, n_rows)
+        else:  # a compare and a reduction say whether the tiles in use fit
+            load = jnp.sum(e[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+            fits = jnp.sum(-(-load // tile)) * tile <= cap
+            compact = fits.astype(jnp.float32)
+            counts, slot, rows, tile_expert, ends = lax.cond(
+                fits, partial(_compact_groups, held=held, tile=tile, n_rows=n_rows, cap=cap, K=K),
+                partial(_sorted_groups, held=held, tile=tile, n_rows=n_rows), e, is_held)
     y, computed = _grouped_experts(body, compute_dtype, tile, h, w.astype(h.dtype), rows,
                                    slot.reshape(T, K), tile_expert, ends[-1], tuple(experts),
                                    sc(jnp.ones((), h.dtype)))
     n_held = jnp.sum(counts)
     assign_ct = jnp.stack([jnp.int32(A), n_held, n_held - computed]).astype(jnp.float32)
-    return y, {"tokens": counts.astype(jnp.float32), "assign": assign_ct}
+    return y, {"tokens": counts.astype(jnp.float32), "assign": assign_ct,
+               "compact": jnp.stack([compact, jnp.float32(1.0)])}
 
 
 def gq_attention_tile(S: int, d: int):

@@ -397,7 +397,16 @@ def _experts(top_k, layers):
         assert rec["moe_assign"][0] == 8 * 2 * 32 * top_k * layers
         assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
         assert 0.0 < rec["moe_held_share"] < 1.0
+        _no_compact_dispatch(ms, rec, 8 * 2 * layers)
     return check
+
+
+def _no_compact_dispatch(ms, rec, applications):
+    """`moe_compact` = (expert layer applications whose dispatch was the
+    compact one, applications): at the tiny shapes (a quarter of the experts
+    held, a tile as long as every pair) no compact branch is built, so none."""
+    assert ms["obs_moe_compact"].shape == (2 * 2,)
+    assert rec["moe_compact"] == [0.0, applications] and rec["moe_compact_share"] == 0.0
 
 
 def _keye_counters(ms, rec, declared, tmp_path):
@@ -419,6 +428,7 @@ def _keye_counters(ms, rec, declared, tmp_path):
     # 8 clients x 1 step x (2 rows x 64 tokens) x top-2, in each of 2 layers
     assert rec["moe_assign"][0] == 8 * 128 * 2 * 2
     assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
+    _no_compact_dispatch(ms, rec, 8 * 2)
 
 
 def _ouro_counters(ms, rec, declared, tmp_path):
@@ -461,6 +471,7 @@ def _laguna_counters(ms, rec, declared, tmp_path):
     assert rec["swa_fused"] == 0.0
     assert rec["moe_dropped"] == 0 and 0.0 < rec["moe_held_share"] < 1.0
     assert len(rec["moe_tokens"]) == 4
+    _no_compact_dispatch(ms, rec, rec["moe_assign"][0] / (2 * 32 * 2))  # pairs over a layer's
     ev, lines = _reported(rec, declared, tmp_path)
     assert ev["swa"]["rounds"] == 1 and ev["swa"]["pairs"] == rec["swa_pairs"]
     assert any(line.startswith("  sliding layers over 1 rounds: band over causal pairs 0.7424")
@@ -486,6 +497,7 @@ def _nemotron_h_counters(ms, rec, declared, tmp_path):
     assert rec["moe_assign"][0] == 8 * 64 * 2 * 3
     assert rec["moe_dropped"] == 0 and sum(rec["moe_tokens"]) == rec["moe_assign"][1]
     assert 0.0 < rec["moe_held_share"] < 1.0
+    _no_compact_dispatch(ms, rec, 8 * 3)
 
 
 @pytest.mark.parametrize("family, check", [

@@ -319,6 +319,125 @@ def test_kanana2_no_token_is_dropped_when_one_expert_takes_everything(tile):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5 * float(jnp.abs(r).max()))
 
 
+def _dispatch_case(load):
+    """A sixteenth of 64 experts held (experts 8-11), tiles of 8 rows, so a
+    capacity of 64 rows: ``(h, sel, w, experts, first, tile)`` under a named
+    load on the held experts.  The weights are powers of two: ``w * y`` is
+    then exact, so a multiply and an add that XLA:CPU contracts into one
+    rounding in one program and not in another (it does, by what it fuses)
+    round alike, and what is compared is the ORDER of a token's sum."""
+    from heterofl_tpu.ops import layers as L
+
+    T, K, E, held, first, tile, D, F = 128, 2, 64, 4, 8, 8, 16, 8
+    ks = jax.random.split(jax.random.key(11), 6)
+    h = jax.random.normal(ks[0], (T, D))
+    experts = [jax.random.normal(k, shape) for k, shape in
+               zip(ks[1:4], ((held, D, F), (held, D, F), (held, F, D)))]
+    if load in ("even", "one-expert"):
+        bias = None if load == "even" else jnp.zeros(E).at[first + 1].set(100.0)
+        sel, _ = L.moe_route(h, jax.random.normal(ks[4], (D, E)), bias, K, 1.0)
+    else:
+        # 32 tokens with both choices on held experts, 16 pairs an expert: two
+        # whole tiles each, every row of the capacity a pair; the rest elsewhere
+        t = np.arange(T)
+        sel = np.where((t < 32)[:, None], first + np.stack([t % 4, (t + 1) % 4], 1),
+                       np.stack([20 + t % 7, 30 + t % 5], 1))
+        if load == "one-row-more":
+            sel[32, 0] = first                                    # a third tile for one expert
+        sel = jnp.asarray(sel, jnp.int32)
+    w = 2.0 ** -jax.random.randint(ks[5], (T, K), 0, 4).astype(jnp.float32)
+    return h, sel, w, experts, first, tile
+
+
+@pytest.mark.parametrize("load, compact", [("even", 1.0), ("one-expert", 0.0),
+                                           ("exactly-capacity", 1.0), ("one-row-more", 0.0)])
+def test_moe_dispatch_is_compact_up_to_its_capacity_and_full_beyond(load, compact):
+    """The dispatch of ``moe_experts`` where a sixteenth of the experts is
+    held.  Even routing (4 pairs an expert) and a load that fills the
+    capacity to its last row take the compact branch; one row more, or a
+    selection bias that sends every token to one held expert, the full one.
+    Either way no pair is dropped and result and gradients are the plain
+    per-expert sum's.  Where compact runs, its index arrays, its result and
+    every cotangent (``h``, ``w``, each expert matrix) EQUAL the full
+    branch's own on the same arguments."""
+    from heterofl_tpu.ops import layers as L
+
+    h, sel, w, experts, first, tile = _dispatch_case(load)
+    (T, K), held = sel.shape, experts[0].shape[0]
+    n_rows = (T * K // tile + held) * tile
+    cap = L.moe_capacity(held, tile, n_rows)
+    assert cap == 2 * held * tile and 2 * cap <= n_rows
+    probe = jax.random.normal(jax.random.key(12), h.shape)
+
+    def grouped(h, w, experts):
+        return L.moe_experts(h, sel, w, experts, first, lambda x: x / 0.5, tile=tile)
+
+    def plain(h, w, experts):  # one expert at a time over all tokens
+        return sum(jnp.sum(jnp.where(sel == first + j, w, 0.0), -1)[:, None]
+                   * L.swiglu(h, *(m[j] for m in experts), lambda x: x / 0.5)
+                   for j in range(held))
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * probe), argnums=(0, 1, 2)))
+
+    y, c = jax.jit(grouped)(h, w, experts)
+    on_held = int(((sel >= first) & (sel < first + held)).sum())
+    assert c["compact"].tolist() == [compact, 1.0]
+    assert c["assign"].tolist() == [T * K, on_held, 0.0] and float(c["tokens"].sum()) == on_held
+    if load == "one-expert":
+        assert float(c["tokens"][1]) == T
+    np.testing.assert_allclose(y, plain(h, w, experts), rtol=1e-5, atol=1e-5)
+    got = both(lambda *a: grouped(*a)[0])(h, w, experts)
+    want = both(plain)(h, w, experts)
+    for g, r in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5 * float(jnp.abs(r).max()))
+
+    if not compact:
+        return
+
+    # the two branches' own functions on the same pairs
+    local = sel.reshape(-1) - first
+    is_held = (local >= 0) & (local < held)
+    e = jnp.where(is_held, local, held)
+    full = L._sorted_groups(e, is_held, held, tile, n_rows)
+    for a, b in zip(full, L._compact_groups(e, is_held, held, tile, n_rows, cap, K)):
+        np.testing.assert_array_equal(a, b)
+    _, slot, rows, tile_expert, ends = full
+    assert int(ends[-1]) * tile <= cap
+    args = (h, w, rows, slot.reshape(T, K), tile_expert, ends[-1], tuple(experts), jnp.float32(2.0))
+    fwd = [jax.jit(functools.partial(L._experts_forward, c, L.swiglu, None, tile))(*args)
+           for c in (None, cap)]                                   # full, compact
+    bwd = [jax.jit(functools.partial(L._experts_backward, c, L.swiglu, None, tile))(*args, probe)
+           for c in (None, cap)]
+    np.testing.assert_array_equal(y, fwd[1][0])
+    for a, b in zip(jax.tree_util.tree_leaves((fwd[0], bwd[0])),
+                    jax.tree_util.tree_leaves((fwd[1], bwd[1]))):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("cell, T, K, E, held, groups, cap", [
+    ("keye", 8192, 8, 128, 8, 2, 16384), ("laguna", 8192, 8, 256, 16, 2, 16384),
+    ("nemotron_h", 8192, 6, 128, 8, 4, 24576), ("kanana2", 4096, 6, 128, 8, None, 4096),
+    ("lfm2", 4096, 4, 32, 8, 2, None)])
+def test_moe_dispatch_builds_its_compact_branch_by_shape(cell, T, K, E, held, groups, cap):
+    """At the expert cells' shapes (traced, nothing runs): a sixteenth of the
+    experts held builds the ``cond`` and its capacity, two tiles a held
+    expert; LFM2's quarter on four choices a token keeps one dispatch, no
+    ``cond`` in its jaxpr."""
+    from heterofl_tpu.models.decoder import expert_tile
+    from heterofl_tpu.ops import layers as L
+
+    tile = L.MOE_TILE if groups is None else expert_tile(T, K, E, groups)
+    assert L.moe_capacity(held, tile, (T * K // tile + held) * tile) == cap
+    D, F = 128, 64
+    shapes = [jax.ShapeDtypeStruct(s, t) for s, t in (
+        ((T, D), jnp.float32), ((T, K), jnp.int32), ((T, K), jnp.float32),
+        ((held, D, F), jnp.float32), ((held, D, F), jnp.float32), ((held, F, D), jnp.float32))]
+    text = str(jax.make_jaxpr(lambda h, sel, w, *experts: L.moe_experts(
+        h, sel, w, experts, 0, lambda x: x, tile=tile))(*shapes))
+    assert ("cond[" in text) == (cap is not None)
+
+
 @pytest.mark.parametrize("heads", [1, 4], ids=["one-key-head", "four-query-heads"])
 def test_rope_swap_of_the_weight_is_the_swap_of_the_product(heads):
     """``swap(h W) = h swap(W)`` exactly (a column of the swapped weight is a

@@ -523,3 +523,22 @@ def test_split_probes_passthrough_without_hist():
                             np.array([0, 2, 4, 6]), data)
     _, probes = split_probes({k: np.asarray(v) for k, v in ms.items()}, 4)
     assert probes and not any(k.startswith("hist_") for k in probes[0])
+
+
+@pytest.mark.parametrize("compact, share", [([[3.0, 6.0], [6.0, 6.0]], 0.75),
+                                            ([[0.0, 6.0], [0.0, 6.0]], 0.0)])
+def test_split_probes_folds_the_expert_layers_counters(compact, share):
+    """An expert layer's counters, a partial sum a device: `moe_assign`
+    finishes as the share of the routed pairs on held experts and the dropped
+    pairs, `moe_compact` as the share of the layer applications whose dispatch
+    was the compact one (0 where no compact branch is built)."""
+    ms = {"obs_moe_assign": np.array([[768.0, 40.0, 0.0], [768.0, 56.0, 0.0]], np.float32).ravel(),
+          "obs_moe_compact": np.array(compact, np.float32).ravel()}
+    clean, rounds = split_probes(ms, 2, counters={"moe_assign": ((3,), "sum"),
+                                                  "moe_compact": ((2,), "sum")})
+    assert not clean and len(rounds) == 1
+    rec = rounds[0]
+    assert rec["moe_assign"] == [1536.0, 96.0, 0.0]
+    assert rec["moe_held_share"] == 0.0625 and rec["moe_dropped"] == 0
+    assert rec["moe_compact"] == [sum(c[0] for c in compact), 12.0]
+    assert rec["moe_compact_share"] == share
