@@ -201,6 +201,35 @@ DECODER_FAMILIES: Dict[str, Dict[str, Any]] = {
         "layer_norm_epsilon": 1e-5,
         "expert_share": [0, 1],
     },
+    # Phi-4-mini-flash-reasoning (model_type phi4flash; the SambaY
+    # decoder-hybrid-decoder of arXiv:2507.06607 with differential attention,
+    # arXiv:2410.05258): the published shape
+    # (huggingface.co/microsoft/Phi-4-mini-flash-reasoning config.json).
+    # ``layer_types`` is the published rule written out (``mb_per_layer`` 2,
+    # half = 16: even layers up to 16 ``mamba``, odd ones below 16
+    # ``sliding``, 17 ``full``, then ``gmu`` / ``cross``;
+    # ``models.phi4flash.layer_types``); layer ``i`` of ``layer_types`` is
+    # PUBLISHED layer ``layer_offset + i`` (differential attention's constant
+    # reads it).  ``d_state``, ``d_conv``, ``expand``, ``dt_rank`` are the
+    # phi4flash configuration class's defaults (the published file has no key
+    # for them).  The benchmark's cut is layers 16-19 (``mamba``,
+    # ``full``, ``gmu``, ``cross``) at ``layer_offset`` 16.
+    "phi4flash": {
+        "hidden_size": 2560,
+        "num_hidden_layers": 32,
+        "mb_per_layer": 2,
+        "layer_types": ["mamba", "sliding"] * 8 + ["mamba", "full"] + ["gmu", "cross"] * 7,
+        "layer_offset": 0,
+        "num_attention_heads": 40,
+        "num_key_value_heads": 20,
+        "sliding_window": 512,
+        "intermediate_size": 10240,
+        "d_state": 16,
+        "d_conv": 4,
+        "expand": 2,
+        "dt_rank": 160,
+        "layer_norm_eps": 1e-5,
+    },
 }
 MODEL_NAMES = ("conv", "resnet18", "resnet34", "resnet50", "resnet101",
                "resnet152", "transformer") + tuple(DECODER_FAMILIES)

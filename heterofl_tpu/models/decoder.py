@@ -10,7 +10,14 @@ counters, and takes from here:
   :class:`~.base.ModelDef` -- ``apply``'s prologue (:class:`Call`) and tail
   (``Call.finish``) and the ``meta`` every reader of a model expects;
 * :func:`alike_runs` / :func:`run_layers`: consecutive alike layers as ONE
-  ``lax.scan`` over their stacked leaves, a lone layer as itself;
+  ``lax.scan`` over their stacked leaves, a lone layer as itself.  WHAT A
+  LAYER HANDS FORWARD: ``run_layers`` threads whatever a family's layers take
+  and return in ``x``'s place, so a family whose later layers read what an
+  earlier one computed (``phi4flash``: a scan's output as memory, a layer's
+  keys and values) makes it a pair ``(x, side)``, ``side`` a dict the
+  producing layer adds to; the FAMILY checks, where it builds its model, that
+  every consumer follows its producer, and a run that a ``lax.scan`` takes
+  must leave the pair's structure as it found it;
 * :func:`gq_attention`, the block three families share, and the expert
   layers' :func:`held_experts`, :func:`expert_tile`, :func:`layer_leaves`.
 
@@ -34,8 +41,8 @@ import jax.numpy as jnp
 from ..config import ceil_width
 from ..obs.trace import scope
 from ..ops.layers import (causal_gq_attention, embed, heads_linear, linear as _linear,
-                          linear_heads, masked_logits, masked_rms_norm, next_token_loss,
-                          rope_interleaved, rope_swap, scaler)
+                          linear_heads, masked_layer_norm, masked_logits, masked_rms_norm,
+                          next_token_loss, rope_interleaved, rope_swap, scaler)
 from .base import ModelDef, normal_init, uniform_fan_in
 from .spec import ParamSpec
 
@@ -210,7 +217,10 @@ def run_layers(x, runs, counters=None):
     """Apply :func:`alike_runs`' runs to ``x``: a stacked run as a
     ``lax.scan`` of its layer, the others layer by layer.  Returns ``(x,
     counters)``, the layers' counters summed onto ``counters`` (None: nothing
-    to start from, and nothing if no layer counts)."""
+    to start from, and nothing if no layer counts).  ``x`` is the layers' own:
+    the hidden state, or any pytree that holds it beside what a layer hands
+    the layers after it (the module docstring); the order of producers and
+    consumers is the family's to check, not this loop's."""
     for layer, lps, scanned in runs:
         if scanned:
             x, c = jax.lax.scan(layer, x, lps)
@@ -265,6 +275,10 @@ class Call:
     def rms(self, g, x):
         return masked_rms_norm(x, g, self.mask["emb"], self.count["emb"], self.d.eps)
 
+    def layer_norm(self, g, b, x):
+        """LayerNorm with a bias over the hidden size's active dims."""
+        return masked_layer_norm(x, g, b, self.mask["emb"], self.count["emb"], self.d.eps)
+
     def embed(self):
         return embed(self.params[self.d.leaves.embedding], self.labels)
 
@@ -284,7 +298,7 @@ class Call:
         """The tail: the final norm, the logits ``[N, S, V]`` a caller may
         read (training does not: then the compiler drops them) and the
         next-token loss, which takes the head in blocks of positions."""
-        xn = self.rms(self.params["norm.g"], x)
+        xn = self.d.norm(self, x) if self.d.norm else self.rms(self.params["norm.g"], x)
         return self.result(self.head(xn),
                            next_token_loss(xn, self.labels, self.head, self.sample_weight),
                            counters)
@@ -292,15 +306,17 @@ class Call:
 
 def decoder(name: str, num_tokens: int, arch: Dict, leaves: Leaves, groups: Dict, body: Callable,
             *, eps: float, mask: bool, compute_dtype=None, counts=(), masks=(),
-            counters: Optional[Dict[str, tuple]] = None, profile: Dict, held=None) -> ModelDef:
+            counters: Optional[Dict[str, tuple]] = None, profile: Dict, held=None,
+            norm: Optional[Callable] = None) -> ModelDef:
     """The family ``name``'s model: ``body(c, params)`` (``c`` the
     :class:`Call`) runs from the embedding to ``c.finish``; ``counts`` /
     ``masks`` the groups beside ``emb`` whose active dims / masks it reads;
     ``counters`` its counters' declaration (the module docstring), ``profile``
     what ``analysis.summary.module_table`` cannot read off the leaves,
-    ``held`` the experts an expert family holds."""
+    ``held`` the experts an expert family holds; ``norm(c, x)`` the family's
+    final norm (None: RMSNorm by ``norm.g``)."""
     d = SimpleNamespace(name=name, groups=groups, leaves=leaves, eps=eps, mask=mask, counts=counts,
-                        masks=masks, counters=counters,
+                        masks=masks, counters=counters, norm=norm,
                         linear=partial(_linear, compute_dtype=compute_dtype))
 
     def apply(params, batch, *, train: bool, width_rate=1.0, scaler_rate=1.0,

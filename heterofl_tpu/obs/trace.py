@@ -134,6 +134,22 @@ WINDOW_SCOPES = ("swa",)
 #: family's was never cached.)
 SSM_SCOPES = ("ssm", "ssm/conv", "ssm/scan", "ssm/norm")
 
+#: What ISSUE 50 added: the two mixers of models/phi4flash.py that no older
+#: scope names.  ``gmu`` = a gated memory unit whole (``(m * silu(h W1)) W2``
+#: on another layer's scan output; its products file under ``gmu/linear``),
+#: ``diff`` = differential attention's combine (the two softmaxes'
+#: difference, the RMSNorm over a pair's value dims and the constant).  The
+#: family's Mamba-1 mixer enters ``ssm``, ``ssm/conv`` and ``ssm/scan`` (it has
+#: no ``ssm/norm``), its attention's projections ``gqa`` and its softmaxes
+#: ``attn`` (``swa`` under a window).  An eighth tuple for the reason
+#: :data:`MIXER_SCOPES` is one; read through
+#: benchmark/scope_reduce_phi4flash.py.  (No bump of :data:`SCOPE_VERSION`, for
+#: PR 46's reason: no program that a cache can hold entered or left a scope --
+#: the eight accepted cells' lowered round programs hash equal to the parent's
+#: -- and the new family's was never cached; a bump would compile every
+#: program of every cell and of the test gate cold once for nothing.)
+SAMBAY_SCOPES = ("gmu", "diff")
+
 #: Version of the vocabulary AND of where it is entered.  jax keeps metadata
 #: out of the persistent compile cache's key, so a program whose only change
 #: is a scope would load the executable cached before the change, without
@@ -154,7 +170,7 @@ EXTRA_KERNELS = ("latent_attn_fwd", "latent_attn_bwd", "gq_attn_fwd", "gq_attn_b
 
 def _known(name: str) -> str:
     if name not in SCOPES + EXTRA_SCOPES + MIXER_SCOPES + SPARSE_SCOPES + LOOP_SCOPES \
-            + WINDOW_SCOPES + SSM_SCOPES:
+            + WINDOW_SCOPES + SSM_SCOPES + SAMBAY_SCOPES:
         raise ValueError(f"Not valid scope: {name!r} (obs.trace.SCOPES)")
     return name
 

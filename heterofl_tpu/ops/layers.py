@@ -573,7 +573,7 @@ def _planned_gq_attention(q, k, v, scale, block, window):
     if jax.default_backend() == "tpu":
         from . import pallas_attention
 
-        plan = pallas_attention.gq_plan(q.shape[2], q.shape[-1], q.shape[1] // k.shape[1], window)
+        plan = pallas_attention.gq_plan(*q.shape[2:], q.shape[1] // k.shape[1], window, v.shape[-1])
         if plan is not None:
             pair, tq, tk = plan
             if pair == "gq":
@@ -1245,3 +1245,146 @@ def gated_group_rms_norm(y, z, g, mask, count, groups: int, eps: float):
     vg = v.reshape(v.shape[:-1] + (groups, v.shape[-1] // groups))
     ms = jnp.sum(vg * vg, axis=-1, keepdims=True) / count
     return (vg / jnp.sqrt(ms + eps)).reshape(v.shape) * g
+
+
+# ---------------------------------------------------------------------------
+# The mixers of a decoder-hybrid-decoder (models/phi4flash.py): a Mamba-1
+# selective scan, differential attention and a gated memory unit.  Plain
+# ``jax.numpy``; the attention's softmaxes through the pairs above.
+# ---------------------------------------------------------------------------
+
+@scoped("ssm/scan")
+def selective_scan(x, dt, a, b, c, chunk: int, block: int):
+    """The selective state-space recurrence of Mamba-1 (arXiv:2312.00752): a
+    decay for every (channel, state) pair, no heads.  Per row, from a zero
+    state ``H`` ``[E, Ns]``:
+
+        H_t = exp(dt_t[:, None] * a) * H_{t-1} + (dt_t * x_t)[:, None] * B_t[None, :]
+        y_t = H_t C_t
+
+    ``x`` / ``dt`` ``[N, S, E]`` (``dt`` after its softplus), ``a`` ``[E, Ns]``
+    (negative), ``b`` / ``c`` ``[N, S, Ns]``; returns ``(y [N, S, E], keep)``
+    with ``keep`` ``[2]`` = (the sum of ``exp(dt a)`` over rows, positions,
+    channels and states, their count): the share of the state a position
+    keeps, over every channel of the arrays it is given.  The skip ``D x`` is
+    the caller's.
+
+    A ``lax.scan`` over the row's chunks of ``chunk`` positions carries ``H``
+    (``[N, Ns, E]``: the channels on the lanes), each chunk under
+    ``jax.checkpoint``: the backward keeps a state a CHUNK and never a state a
+    position (``[S, E, Ns]`` float32 is 2.7 GB a tensor at 8,192 positions and
+    5,120 channels).  Inside a chunk the recurrence is the ASSOCIATIVE
+    combination of (decay, input) pairs, ``(a2 a1, a2 b1 + b2)``, taken in two
+    levels: the chunk's blocks of ``block`` positions side by side, each run
+    position by position from a zero state (what it leaves and its whole
+    decay), the blocks' start states from those one after another, and each
+    block once more from its true start, read by ``C``.  Every factor is a
+    product of decays in (0, 1], so it stays finite for any ``dt``; the
+    cumulative log-decay form's ``exp(-l_j)`` overflows once ``dt a`` summed
+    over a chunk passes 88, which a trained time step can (``dt`` 1 on a decay
+    of 16 does in six positions), and ``lax.associative_scan``'s log-depth
+    tree moves the ``[chunk, E, Ns]`` tensors some sixteen times where this
+    form reads and writes a block's state once a position.  No matrix product:
+    decays, states and the read by ``C`` (a sum over ``Ns``) are float32
+    elementwise.  A row that is no whole number of chunks is padded on the
+    right with ``dt = 0`` (a position that neither decays nor writes)."""
+    N, S, E = x.shape
+    Ns = a.shape[1]
+    at = a.T                                                      # [Ns, E]
+    block = min(block, chunk)
+    Q = -(-min(chunk, S) // block) * block                        # whole blocks
+    pad = -S % Q
+    u = dt * x
+    if pad:
+        dt, u, b, c = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (dt, u, b, c))
+    nc, nb = (S + pad) // Q, Q // block
+
+    def by_chunk(t):  # [N, S, F] -> [chunks, block, N, blocks, F]
+        return jnp.moveaxis(t.reshape(N, nc, nb, block, t.shape[-1]), (1, 3), (0, 1))
+
+    @jax.checkpoint
+    def one(state, chunk_):
+        dt_c, u_c, b_c, c_c = chunk_
+
+        def step(h, t):  # one position of every block: h [N, blocks, Ns, E]
+            decay = jnp.exp(dt_c[t][:, :, None, :] * at)
+            return decay * h + u_c[t][:, :, None, :] * b_c[t][..., None], decay
+
+        # each block from a zero state: what it leaves, and its whole decay
+        h = jnp.zeros((N, nb, Ns, E), jnp.float32)
+        whole, kept = jnp.ones_like(h), jnp.float32(0.0)
+        for t in range(block):
+            h, decay = step(h, t)
+            whole, kept = whole * decay, kept + jnp.sum(decay)
+        # the blocks one after another: the state each starts from
+        starts = []
+        for j in range(nb):
+            starts.append(state)
+            state = whole[:, j] * state + h[:, j]
+        # each block again from its true start, read by C
+        h, ys = jnp.stack(starts, axis=1), []
+        for t in range(block):
+            h, _ = step(h, t)
+            ys.append(jnp.sum(h * c_c[t][..., None], axis=2))     # [N, blocks, E]
+        return state, (jnp.stack(ys, axis=2), kept)
+
+    _, (y, kept) = lax.scan(one, jnp.zeros((N, Ns, E), jnp.float32),
+                            tuple(by_chunk(t) for t in (dt, u, b, c)))
+    y = jnp.moveaxis(y, 0, 1).reshape(N, nc * Q, E)[:, :S]        # [chunks, N, blocks, block, E]
+    # a padded position's decay is 1 for every (channel, state)
+    keep = jnp.stack([jnp.sum(kept) - N * pad * E * Ns, jnp.float32(N * S * E * Ns)])
+    return y, keep
+
+
+def differential_attention_planned(S: int, d: int, group: int, dv: int, window=None) -> bool:
+    """Whether a softmax of :func:`differential_attention` takes a fused
+    kernel pair at these shapes (``pallas_attention.gq_plan`` with the value's
+    own width), for a caller that counts (``models/phi4flash.py``), as
+    :func:`band_attention_planned` is."""
+    if jax.default_backend() != "tpu":
+        return False
+    from . import pallas_attention
+
+    return pallas_attention.gq_plan(S, d, group, window, dv) is not None
+
+
+def differential_attention(q1, q2, k1, k2, v, lam, lam0: float, g_sub, window=None, *, scale,
+                           mask, count, eps: float = 1e-5, block: int = ATTN_BLOCK):
+    """Differential attention (arXiv:2410.05258), the score / softmax / value
+    part and the combine, heads first: a query pair's two heads ``q1`` / ``q2``
+    ``[N, H, S, d]`` against a key pair's ``k1`` / ``k2`` ``[N, Hkv, S, d]`` and
+    ONE value ``v`` ``[N, Hkv, S, dv]`` (a pair's two value heads side by
+    side, ``dv = 2 d``), grouped as :func:`causal_gq_attention` groups them:
+
+        a_j = softmax_mask(q_j k_j^T * scale) v,   j = 1, 2
+        o = RMSNorm_dv(a_1 - lam * a_2; g_sub, eps) * (1 - lam0)
+
+    the mask causal and, with ``window``, ``t - s < window``.  ``lam`` a
+    float32 scalar (learned), ``lam0`` the layer's constant; ``mask`` /
+    ``count`` the 0/1 mask over the value's ``dv`` dims and its active dims (a
+    HeteroFL slice keeps a prefix of each of the two heads).  Returns ``o``
+    ``[N, H, S, dv]`` float32.
+
+    Each softmax is ONE call with the ``dv``-wide value (two score products a
+    pair), through what ``pallas_attention.gq_plan`` gives the shapes with the
+    value's width as its own: the ``gq_attn`` pair under the diagonal alone,
+    the ``jnp`` block loop under a window (the band pair takes no value wider
+    than its keys) and off a TPU.  The subtraction, the sub-norm and the
+    constant under ``diff``."""
+    attend = causal_gq_attention if window is None else partial(sliding_gq_attention, window=window)
+    a1, a2 = (attend(q, k, v, scale, block=block) for q, k in ((q1, k1), (q2, k2)))
+    with scope("diff"):
+        o = (a1 - lam * a2) * mask
+        ms = jnp.sum(o * o, axis=-1, keepdims=True) / count
+        return o / jnp.sqrt(ms + eps) * g_sub * (1.0 - lam0)
+
+
+@scoped("gmu")
+def gated_memory_unit(h, m, w1, w2, sc, compute_dtype=None):
+    """``sc((m * silu(sc(h w1))) w2)``: a gated memory unit (arXiv:2507.06607),
+    the normed ``h`` ``[N, S, D]`` gating, element by element, the memory ``m``
+    ``[N, S, E]`` that ANOTHER layer's scan wrote; ``sc`` the HeteroFL Scaler.
+    A masked channel of ``m`` meets a masked column of ``w1``: the two layers'
+    channels are one width group."""
+    gate = jax.nn.silu(sc(linear(h, w1, compute_dtype=compute_dtype)))
+    return sc(linear(m * gate, w2, compute_dtype=compute_dtype))

@@ -32,27 +32,31 @@ tile of positions is the largest of :data:`TILES` that divides ``S``; where no
 pair takes the shapes (a client's narrow slice, ragged rows) the caller runs
 the ``jnp`` block loop.
 
-=====================  ==========================  ===========================
-pair                   taken where                 resident over the grid
-=====================  ==========================  ===========================
-``latent_attn_*``      :func:`tile_for`: ``dn``,   backward, keys outer: a
-                       ``dv`` of 128s, ``dr`` of   head's ``dq`` ``[S, dn+dr]``
-                       64s                         float32
-``gq_attn_*``          :func:`gq_plan`: no         backward, keys outer: a
-                       window, ``d`` of 64s, and   group's ``dq`` ``[G, d, S]``
-                       that ``dq`` within          float32 (LFM2 4 x 64 x
-                       :data:`GQ_RESIDENT_BYTES`   2,048: 2 MB; Ouro 1 x 128 x
-                       (or ``d`` not of 128s)      2,048: 1 MB), double-buffered
-``band_attn_*``        :func:`gq_plan`: a window,  both kernels, query tiles
-                       or a ``dq`` beyond that     outer: a key/value head's
-                       (Laguna: 8 x 128 x 8,192 =  ``dk``, ``dv`` ``[d, S]``
-                       34 MB, 6 x ... = 25 MB);    float32 (4 MB each at 128 x
-                       ``d`` of 128s               8,192) and a group's ``dq``
-                                                   tile in scratch
-``sel_attn_*``         :func:`sel_tile_for`: a     as ``band_attn_*``, and the
-                       selection mask; ``d`` of    mask a ``[tk, tq]`` int8
-                       128s                        tile a step
-=====================  ==========================  ===========================
+=====================  ==========================  ==============  ===========================
+pair                   taken where                 value width     resident over the grid
+=====================  ==========================  ==============  ===========================
+``latent_attn_*``      :func:`tile_for`: ``dn``,   ``dv`` of 128s  backward, keys outer: a
+                       ``dv`` of 128s, ``dr`` of                   head's ``dq`` ``[S, dn+dr]``
+                       64s                                         float32
+``gq_attn_*``          :func:`gq_plan`: no         any ``dv`` of   backward, keys outer: a
+                       window, ``d`` of 64s, and   64s (None: the  group's ``dq`` ``[G, d, S]``
+                       that ``dq`` within          query's ``d``)  float32 (LFM2 4 x 64 x
+                       :data:`GQ_RESIDENT_BYTES`                   2,048: 2 MB; Ouro 1 x 128 x
+                       (or ``d`` not of 128s)                      2,048: 1 MB), double-buffered
+``gq_attn_*``          a differential pair's       128 on ``d``    as above: 2 x 64 x 8,192 =
+                       softmax: ``d`` 64, group    64              4.2 MB of ``dq``; ``dv`` a
+                       2, 8,192 positions, no                      key tile ``[128, tk]``
+                       window (Phi-4-flash)
+``band_attn_*``        :func:`gq_plan`: a window,  ``dv == d``     both kernels, query tiles
+                       or a ``dq`` beyond that     only (a value   outer: a key/value head's
+                       (Laguna: 8 x 128 x 8,192 =  of its own      ``dk``, ``dv`` ``[d, S]``
+                       34 MB, 6 x ... = 25 MB);    under a window  float32 (4 MB each at 128 x
+                       ``d`` of 128s               takes the       8,192) and a group's ``dq``
+                                                   block loop)     tile in scratch
+``sel_attn_*``         :func:`sel_tile_for`: a     ``dv == d``     as ``band_attn_*``, and the
+                       selection mask; ``d`` of                    mask a ``[tk, tq]`` int8
+                       128s                                        tile a step
+=====================  ==========================  ==============  ===========================
 
 The query tile of the last two is the largest with ``G x tile <= 4,096``
 columns side by side (a score tile ``[key tile, G x tile]`` float32 is 8 MB of
@@ -443,7 +447,7 @@ def _row_stat(rows, tile_of):
 
 def _call_gq_fwd(q, k, v, tq, tk, interpret):
     N, H, d, S = q.shape
-    G = H // k.shape[1]
+    G, dv = H // k.shape[1], v.shape[2]  # the value's width is its own (``gq_plan``'s ``dv``)
 
     def query(i, j):
         return i
@@ -455,12 +459,12 @@ def _call_gq_fwd(q, k, v, tq, tk, interpret):
         partial(_gq_fwd_kernel, tq=tq, tk=tk),
         grid=(N, H // G, S // tq, S // tk),
         in_specs=[_lane_tile(G, d, tq, query), _lane_tile(None, d, tk, key),
-                  _lane_tile(None, d, tk, key)],
-        out_specs=[_lane_tile(G, d, tq, query), _row_stat(G * tq, query)],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                  _lane_tile(None, dv, tk, key)],
+        out_specs=[_lane_tile(G, dv, tq, query), _row_stat(G * tq, query)],
+        out_shape=[jax.ShapeDtypeStruct((N, H, dv, S), jnp.float32),
                    jax.ShapeDtypeStruct((N, H // G, S // tq, 1, G * tq), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((d, G * tq), q.dtype)]
-        + [pltpu.VMEM((1, G * tq), jnp.float32)] * 2 + [pltpu.VMEM((d, G * tq), jnp.float32)],
+        + [pltpu.VMEM((1, G * tq), jnp.float32)] * 2 + [pltpu.VMEM((dv, G * tq), jnp.float32)],
         compiler_params=_params(64),
         interpret=interpret,
         name="gq_attn_fwd",
@@ -469,7 +473,7 @@ def _call_gq_fwd(q, k, v, tq, tk, interpret):
 
 def _call_gq_bwd(q, k, v, do, lse, delta, tq, tk, interpret):
     N, H, d, S = q.shape
-    G = H // k.shape[1]
+    G, dv = H // k.shape[1], v.shape[2]
 
     def key(j, i):
         return j
@@ -482,10 +486,10 @@ def _call_gq_bwd(q, k, v, do, lse, delta, tq, tk, interpret):
         partial(_gq_bwd_kernel, tq=tq, tk=tk),
         grid=(N, H // G, S // tk, S // tq),
         in_specs=[_lane_tile(G, d, tq, query), _lane_tile(None, d, tk, key),
-                  _lane_tile(None, d, tk, key), _lane_tile(G, d, tq, query),
+                  _lane_tile(None, dv, tk, key), _lane_tile(G, dv, tq, query),
                   _row_stat(G * tq, query), _row_stat(G * tq, query)],
         out_specs=[pl.BlockSpec((None, G, d, S), lambda n, g, j, i: (n, g, 0, 0)),
-                   _lane_tile(None, d, tk, key), _lane_tile(None, d, tk, key)],
+                   _lane_tile(None, d, tk, key), _lane_tile(None, dv, tk, key)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32), jax.ShapeDtypeStruct(k.shape, f32),
                    jax.ShapeDtypeStruct(v.shape, f32)],
         compiler_params=_params(64),
@@ -1043,20 +1047,26 @@ def fused_band_attention(q, k, v, scale, window, *, block_q: int, block_k: int,
 #: resident; beyond it :func:`gq_plan` hands the layer to the band pair
 GQ_RESIDENT_BYTES = 8 << 20
 
-def gq_plan(S: int, d: int, group: int, window=None):
+def gq_plan(S: int, d: int, group: int, window=None, dv=None):
     """THE RULE (the header's): which kernel pair grouped-query attention
     takes at ``S`` positions, ``group`` query heads of ``d`` dims a key/value
     head, under the diagonal alone (``window`` None) or a window as well, and
     at which tiles: None (the ``jnp`` block loop) or ``(pair, query tile, key
-    tile)`` with ``pair`` ``"gq"`` or ``"band"``."""
+    tile)`` with ``pair`` ``"gq"`` or ``"band"``.  ``dv`` the value's width
+    where it is not the query's (None: the query's; differential attention
+    reads ONE 128-wide value with two 64-wide queries and keys): the ``gq``
+    pair takes any ``dv`` of 64s, the band pair ``dv == d`` only."""
     if window is not None and window >= S:
         window = None
     tile = gq_tile_for(S, d)
     if tile is None:
         return None
+    own = dv is not None and dv != d
+    if own and dv % (LANES // 2):
+        return None
     if window is None and (d % LANES or group * d * S * 4 <= GQ_RESIDENT_BYTES):
         return "gq", tile, tile
-    if d % LANES:
+    if d % LANES or own:
         return None
     # under a window both tiles are at most half of it: the band then holds two
     # thirds of the pairs of the tiles it crosses, and the backward's score

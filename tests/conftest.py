@@ -83,3 +83,41 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+# Every executable a process loads maps memory, a worker keeps all it ever
+# loaded (jax's caches hold them), and the kernel allows a process
+# `vm.max_map_count` mappings, 65,530 here.  A worker that reaches it dies in
+# whatever compile or cache read comes next (seen with the seventh decoder
+# family's tests in the suite, PR 50: a segfault under `compiler._cache_read`,
+# an abort under `backend_compile_and_load`, each in a test that passes alone;
+# the op-by-op model tests load thousands of small executables).  Past half
+# the limit a worker drops jax's caches after a test: 3 s, and the programs
+# the next tests share come back from the persistent cache.
+def _mappings(path="/proc/self/maps"):
+    try:
+        with open(path, "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:  # not Linux: nothing to count, nothing to do
+        return 0
+
+
+def _map_limit(path="/proc/sys/vm/max_map_count"):
+    try:
+        with open(path) as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530
+
+
+_MAP_LIMIT = _map_limit()
+
+
+@pytest.fixture(autouse=True)
+def _release_executables():
+    yield
+    if _mappings() > _MAP_LIMIT // 2:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()

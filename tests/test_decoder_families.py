@@ -100,6 +100,10 @@ HEADS = {
                    ("router",),
                    Group("kv_head", 2 * 16, kind="per_head", num_heads=2, multiple=2, coupled=False,
                          family="head")),
+    # the query, key and value heads and the sub-norm's two: one family
+    "phi4flash": (lambda r: {"head": int(np.ceil(16 * r))}, (),
+                  Group("sub", 2 * 16, kind="per_head", num_heads=2, multiple=2, coupled=False,
+                        family="head")),
 }
 
 
@@ -152,6 +156,20 @@ def _nemotron_h_counts(ref, shapes, cm):
             assert np.asarray(cm[k]).all(), k
 
 
+def _phi4flash_counts(ref, shapes, cm):
+    if cm is None:  # tied, as lfm2: ONE label axis, no leaf beside it
+        assert ref.LABEL_AXES == {"tok.w": 0}
+        assert not [k for k in shapes if k.startswith(("head.", "embedding."))]
+    else:  # what no level cuts: the l* vectors, W_x's 8 + 8 + 8 columns, the state's 8, the rank's 8
+        for k in ("l0.attn.lq1.w", "l4.attn.lk2.w", "l6.attn.lq2.w"):
+            assert np.asarray(cm[k]).all(), k
+        for k in ("l1.ssm.x.w", "l3.ssm.a_log.w"):
+            assert np.asarray(cm[k]).sum(axis=0).min() > 0, k
+        assert np.asarray(cm["l1.ssm.dt.w"]).sum(axis=1).min() > 0
+        # ONE group for the inner channels of every mamba and gmu layer
+        assert (np.asarray(cm["l3.ssm.skip.g"]) == np.asarray(cm["l5.gmu.in.w"])[0]).all()
+
+
 def _ouro_counts(ref, shapes, cm):
     if cm is not None:
         assert np.asarray(cm["exit.b"]).all()
@@ -159,7 +177,8 @@ def _ouro_counts(ref, shapes, cm):
 
 @pytest.mark.parametrize("family, extra", [("lfm2", _lfm2_counts), ("keye", _keye_counts),
                                            ("ouro", _ouro_counts), ("laguna", _nothing),
-                                           ("nemotron_h", _nemotron_h_counts)])
+                                           ("nemotron_h", _nemotron_h_counts),
+                                           ("phi4flash", _phi4flash_counts)])
 def test_counts_follow_width_and_labels(family, extra):
     """A client counts for every element of its slice -- a frozen leaf's, a
     leaf's used several times a step once, an expert's it holds whether or not
@@ -259,9 +278,32 @@ def _nemotron_h_rows(cfg):
     assert not [n for n in by_name if "scan" in n]
 
 
+def _phi4flash_rows(cfg):
+    """`module_table` holds the family's matrices (the tied head's product
+    too) and, for each attention layer, differential attention's two softmaxes
+    a query pair -- 64-wide scores against a 128-wide value, here 16 and 32 --
+    over the pairs a query sees: a sliding layer's band, every causal pair of
+    the full and the cross layer; the scan is no leaf's and has no row."""
+    from heterofl_tpu.analysis.summary import module_table
+
+    by_name = {r[0]: r for r in module_table(cfg, 1.0, 2)}
+    a, t = cfg["phi4flash"], 2 * cfg["bptt"]
+    assert by_name["l1.ssm.in.x"][4] == by_name["l1.ssm.out"][4] == t * 128 * 256
+    assert by_name["l1.ssm.x"][4] == t * 256 * (8 + 2 * 8) and by_name["l1.ssm.dt"][4] == t * 8 * 256
+    assert by_name["l5.gmu.in"][4] == by_name["l5.gmu.out"][4] == t * 128 * 256
+    assert by_name["l6.mlp.d"][4] == t * 256 * 128 and by_name["head"][4] == t * 128 * cfg["num_tokens"]
+    causal, band = 64 * 65 // 2, 16 * 17 // 2 + (64 - 16) * 16
+    assert a["sliding_window"] == 16
+    for site, pairs in (("l0", band), ("l2", band), ("l4", causal), ("l6", causal)):
+        assert by_name[f"{site}.attn.qk"][4] == 2 * 8 * pairs * 16, site
+        assert by_name[f"{site}.attn.av"][4] == 2 * 8 * pairs * 32, site
+    assert not [n for n in by_name if "scan" in n]
+
+
 @pytest.mark.parametrize("family, rows", [("lfm2", _lfm2_rows), ("keye", _nothing),
                                           ("ouro", _ouro_rows), ("laguna", _laguna_rows),
-                                          ("nemotron_h", _nemotron_h_rows)])
+                                          ("nemotron_h", _nemotron_h_rows),
+                                          ("phi4flash", _phi4flash_rows)])
 def test_level_tables_know_the_family(family, rows):
     """`level_param_table` counts the sliced sub-model's own leaves, the FLOP
     table falls with the level, and `analysis.summary.module_table` holds what
@@ -500,10 +542,33 @@ def _nemotron_h_counters(ms, rec, declared, tmp_path):
     _no_compact_dispatch(ms, rec, 8 * 3)
 
 
+def _phi4flash_counters(ms, rec, declared, tmp_path):
+    """The scan's two as Nemotron-H carries them, and the family's three:
+    `diff_lambda` (a sum of the attention layers' `lam` and their number, a
+    device) finished as their mean, near the mean of `lam0` at published layers
+    13, 15, 17, 19 (the four vectors start small); `diff_fused`, softmaxes a
+    fused kernel pair took over softmaxes: none off a TPU; `side_reads`, layers
+    that read another layer's value: the gated memory unit and the cross layer."""
+    from heterofl_tpu.models.phi4flash import lam0_of
+
+    assert ms["obs_ssm_keep"].shape == ms["obs_diff_lambda"].shape == (2 * 2,)
+    # 8 clients x 1 step x 2 mamba layers x 2 rows x 1 chunk; x 32 positions x 256 channels x 8
+    assert rec["ssm_chunks"] == [8 * 2 * 2]
+    assert ms["obs_ssm_keep"].reshape(2, 2)[:, 1].sum() == 8 * 2 * 2 * 32 * 256 * 8
+    assert len(rec["ssm_keep"]) == 1 and 0.8 < rec["ssm_keep"][0] < 1.0
+    # 8 clients x 4 attention layers, two softmaxes each
+    assert ms["obs_diff_lambda"].reshape(2, 2)[:, 1].sum() == 8 * 4
+    assert rec["diff_lambda"][0] == pytest.approx(np.mean([lam0_of(i) for i in (13, 15, 17, 19)]),
+                                                  abs=0.05)
+    assert ms["obs_diff_fused"].reshape(2, 2).sum(axis=0).tolist() == [0.0, 8 * 4 * 2]
+    assert rec["diff_fused"] == 0.0
+    assert rec["side_reads"] == [8 * 2]
+
+
 @pytest.mark.parametrize("family, check", [
     ("kanana2", _experts(3, 1)), ("lfm2", _experts(4, 3)), ("keye", _keye_counters),
     ("ouro", _ouro_counters), ("laguna", _laguna_counters),
-    ("nemotron_h", _nemotron_h_counters)])
+    ("nemotron_h", _nemotron_h_counters), ("phi4flash", _phi4flash_counters)])
 def test_counters_ride_the_metrics(family, check, tmp_path):
     """telemetry='on' carries the counters a model declares out of a round on
     two devices as per-device partial sums; `obs.split_probes` finishes each by
@@ -543,6 +608,11 @@ def _all_but_the_three_selection_biases_moved(moved, n, params):
         np.asarray(params[f"l{i}.moe.router.b"]).any() for i in (0, 2, 4))
 
 
+def _all_moved_but_perhaps_a_key_bias(moved, n, params):
+    # three layers have keys of their own, whose bias no softmax sees: its gradient is rounding noise
+    assert moved >= n - 3
+
+
 ENTRY = {
     "kanana2": (dict(num_hidden_layers=2), 16, 16,
                 _most_moved_and_the_selection_bias_got_no_gradient),
@@ -551,6 +621,7 @@ ENTRY = {
     "ouro": ({}, 32, 32, _all_moved),
     "laguna": ({}, 32, 32, _all_moved),
     "nemotron_h": ({}, 32, 32, _all_but_the_three_selection_biases_moved),
+    "phi4flash": ({}, 32, 32, _all_moved_but_perhaps_a_key_bias),
 }
 
 

@@ -355,6 +355,59 @@ def test_the_scan_kernels_compile_at_the_cells_shapes_under_ssm_scan(one_chip, v
 _ITEMSIZE = {"f32": 4, "bf16": 2}
 
 
+def test_the_selective_scan_compiles_at_the_cells_shapes_and_keeps_no_state_a_position(one_chip):
+    """`ops.layers.selective_scan`, forward and gradient, at the Phi-4-flash
+    cell's REAL shapes (ISSUE 50: one row of 8,192 positions, 5,120 channels, a
+    state of 16, chunks of 256 in blocks of 16) for the described chip: a state
+    a position would be 2.7 GB a tensor; the compiled program's temporaries
+    stay under 1.5 GB and it holds no `[.., 8192, 16, 5120]` array."""
+    from heterofl_tpu.ops.layers import selective_scan
+
+    def grads(x, dt, a, b, c):
+        return jax.grad(lambda *o: jnp.sum(selective_scan(*o, 256, 16)[0] ** 2),
+                        argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+
+    avals = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in (
+        (1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16), (1, 8192, 16))]
+    with no_persistent_cache():
+        compiled = jax.jit(grads).lower(*avals).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[\d,]*8192,16,5120\]|f32\[[\d,]*8192,5120,16\]", text)
+    assert "tpu_custom_call" not in text  # plain jax.numpy: a kernel is a later PR's
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["cell", "vmap1"])
+def test_differential_attention_compiles_with_one_call_a_softmax(one_chip, vmapped, monkeypatch):
+    """Differential attention at the Phi-4-flash cell's shapes (ISSUE 50): one
+    row of 8,192 positions, 20 query pairs on 10 key pairs of 64 dims against
+    ONE 128-wide value a pair; each softmax is one `gq_attn_fwd` /
+    `gq_attn_bwd` call with the value's own width (`gq_plan(8192, 64, 2, None,
+    128)`), bare and under the `vmap` over the one client slot of a chunk with
+    a per-client scale; every call carries the `attn` scope and not the
+    combine's, and no score block goes through HBM."""
+    from heterofl_tpu.ops.layers import differential_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lead = (1,) if vmapped else ()
+
+    def grads(q1, q2, k1, k2, v, lam, g_sub, scale):
+        return jax.grad(lambda *o: jnp.sum(differential_attention(
+            *o, 0.79, g_sub, None, scale=scale, mask=jnp.ones(128), count=128.0) ** 2),
+            argnums=(0, 1, 2, 3, 4, 5))(q1, q2, k1, k2, v, lam)
+
+    shapes = [(1, 20, 8192, 64)] * 2 + [(1, 10, 8192, 64)] * 2 + [(1, 10, 8192, 128), (), (128,), ()]
+    avals = [jax.ShapeDtypeStruct(lead + s, jnp.float32, sharding=one_chip) for s in shapes]
+    text = _compile(jax.vmap(grads) if vmapped else grads, *avals,
+                    kernels=("gq_attn_fwd", "gq_attn_bwd"))
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    op_names = sorted(re.search(r'op_name="([^"]*)"', line).group(1) for line in calls)
+    assert len(op_names) == 4  # two softmaxes, forward and backward: no split by value halves
+    assert all("attn" in n and "diff" not in n for n in op_names)
+    assert not re.search(r"f32\[(?:1,)?1,\d+,8192,8192\]", text)
+
+
 def _standalone_relayouts(text, floor=4 << 20):
     """(bytes written, instruction) of every ``copy`` / ``transpose`` of at
     least ``floor`` bytes that is an instruction of its own in the entry
